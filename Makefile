@@ -6,7 +6,7 @@ PY ?= python
 LATEST_BENCH := $(shell ls BENCH_r*.json 2>/dev/null | sort -V | tail -1)
 NEW_BENCH ?= /tmp/daft_tpu_bench_new.json
 
-.PHONY: test lint lint-json test-ai test-fusion test-pallas test-mesh test-fault test-oom test-gateway bench bench-ai bench-fusion bench-pallas bench-mesh bench-serve bench-serve-net bench-oom bench-oom-quick bench-tpcds bench-gate bench-compare calibrate-report doctor serve
+.PHONY: test lint lint-json test-ai test-fusion test-pallas test-mesh test-fault test-oom test-gateway bench bench-ai bench-fusion bench-pallas bench-mesh bench-serve bench-serve-net bench-oom bench-oom-quick bench-tpcds bench-gate bench-compare calibrate-report doctor serve chip-smoke
 
 # `make test` includes the lint gate via tests/test_lint.py (tier-1).
 test:
@@ -115,10 +115,22 @@ test-gateway:
 		tests/test_gateway.py -q -p no:cacheprovider
 
 # Run the serving gateway standalone (the network front door). Override:
-# make serve SERVE_ARGS="--port 8642 --demo-rows 200000".
+# make serve SERVE_ARGS="--port 8642 --demo-rows 200000". Runs on whatever
+# device JAX finds; export JAX_PLATFORMS=cpu yourself for a host-only gateway.
 SERVE_ARGS ?= --port 8642 --demo-rows 200000
 serve:
-	env JAX_PLATFORMS=$${JAX_PLATFORMS:-cpu} $(PY) -m daft_tpu.gateway $(SERVE_ARGS)
+	$(PY) -m daft_tpu.gateway $(SERVE_ARGS)
+
+# The chip check: TPC-H through the main path on one TPU, then the real-TPU
+# test tier, one process after the other (a chip belongs to one process at a
+# time). Fails without a TPU. On the builder's tool:
+#   chiprun --timeout 1500 -- make chip-smoke
+# Override: make chip-smoke CHIP_SMOKE_ARGS="--sf 10". Four chips, mesh tier
+# only: chiprun --chips 4 -- python chip_smoke.py --mesh 4
+CHIP_SMOKE_ARGS ?=
+chip-smoke:
+	$(PY) chip_smoke.py $(CHIP_SMOKE_ARGS)
+	$(PY) -m pytest tests_tpu -m tpu -q -p no:cacheprovider
 
 # Out-of-core suite: host memory manager ledger/pressure semantics,
 # streaming-scan split planning + backpressure, tiny-budget (~10% of input
@@ -164,7 +176,7 @@ bench-gate:
 	$(PY) bench.py > $(NEW_BENCH)
 	$(PY) bench.py --compare $(LATEST_BENCH) $(NEW_BENCH)
 
-# Ad-hoc: make bench-compare OLD=BENCH_r04.json NEW=BENCH_r05.json
+# Ad-hoc: make bench-compare OLD=BENCH_SF10_r05.json NEW=BENCH_SF10_r06.json
 bench-compare:
 	$(PY) bench.py --compare $(OLD) $(NEW)
 

@@ -305,6 +305,10 @@ class WorkerProcess:
             paths.append(prev)
         child_env["PYTHONPATH"] = os.pathsep.join(paths)
         child_env.update(env or {})
+        if child_env["DAFT_TPU_DEVICE"] == "off":
+            # no device lease: a chip belongs to one process, so a host-only
+            # child must never reach for the one its parent holds
+            child_env["JAX_PLATFORMS"] = "cpu"
         self._proc = subprocess.Popen(
             [sys.executable, "-m", "daft_tpu.distributed._worker_entry",
              address, worker_id],

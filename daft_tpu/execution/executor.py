@@ -3121,10 +3121,12 @@ def _mesh_repartition(node, n: int) -> Iterator[MicroPartition]:
     rows by destination on device, and the exchanged planes come back in
     (source shard, stream order) — bit-identical partition contents and row
     order versus the host path, asserted in tests and the BENCH_MESH
-    capture. Any runtime failure falls back to host bucketing of the
-    already-collected batches (results identical, rejection counted)."""
+    capture. A column with no device layout falls back to host bucketing of
+    the already-collected batches (results identical, rejection counted); a
+    program that does not lower or run raises."""
     from ..config import execution_config
     from ..ops import counters as _counters
+    from ..ops.grouped_stage import DeviceFallback
 
     cfg = execution_config()
     parts = list(_exec(node.input))
@@ -3149,7 +3151,7 @@ def _mesh_repartition(node, n: int) -> Iterator[MicroPartition]:
         # otherwise fall back to the full host bucket set and hand the
         # consumer duplicated rows
         parts = list(_mesh_repartition_exchange(node, batches, rows, n))
-    except Exception as e:  # device-path failure must never fail the query
+    except DeviceFallback as e:
         _counters.reject("runtime", "repartition: mesh all_to_all fallback",
                          str(e))
         parts = _host_buckets()
@@ -3159,32 +3161,17 @@ def _mesh_repartition(node, n: int) -> Iterator[MicroPartition]:
 def _ring_permute_gate(n: int) -> Optional[bool]:
     """Pallas gate for the fused ring-permute repartition exchange: returns
     the kernel's `interpret` flag when it should engage (True = CPU
-    interpreter, for off-silicon parity under DAFT_TPU_PALLAS=on), None
-    when the standalone all_to_all tier serves the exchange. Mirrors
-    grouped_stage._pallas_gate: mode off / a latched lowering failure /
-    missing pallas keep the XLA tier; auto engages on real silicon only."""
+    interpreter off the chip), None when the standalone all_to_all tier
+    serves the exchange. Engages under pallas_mode="on" ONLY: the kernel
+    issues remote DMAs under a barrier and has no hardware run on record, so
+    `auto` keeps the all_to_all tier on every backend."""
     from ..config import execution_config
 
-    mode = getattr(execution_config(), "pallas_mode", "auto")
-    if mode == "off" or _RING_PERMUTE_BROKEN[0]:
-        return None
-    from ..ops.pallas_kernels import pallas_available
-
-    if not pallas_available():
+    if getattr(execution_config(), "pallas_mode", "auto") != "on":
         return None
     import jax
 
-    on_tpu = jax.default_backend() == "tpu"
-    if mode == "on":
-        return not on_tpu
-    return False if on_tpu else None
-
-
-# process-wide latch: one runtime lowering failure routes every later
-# repartition exchange back onto the all_to_all tier (same discipline as
-# GroupedAggStage._pallas_broken, but the exchange has no stage object)
-_RING_PERMUTE_BROKEN = [False]
-_RING_PERMUTE_LOCK = threading.Lock()
+    return jax.default_backend() != "tpu"
 
 
 def _mesh_repartition_exchange(node, batches: List[RecordBatch], rows: int,
@@ -3194,6 +3181,7 @@ def _mesh_repartition_exchange(node, batches: List[RecordBatch], rows: int,
     from ..core.kernels.hashing import combine_hashes
     from ..core.series import Series
     from ..ops import counters as _counters
+    from ..ops.grouped_stage import DeviceFallback
     from ..ops.mesh_stage import _shard_np, mesh_row_mask, mesh_total
     from ..parallel.distributed import (default_mesh,
                                         sharded_alltoall_repartition_step,
@@ -3207,14 +3195,24 @@ def _mesh_repartition_exchange(node, batches: List[RecordBatch], rows: int,
     mesh = default_mesh(n)
     total = mesh_total(rows, n)
     S = total // n
+    ring = _ring_permute_gate(n)
     cols = []
     dtypes: List = []
+    host_dtypes: List = []  # per column, the dtype its plane comes back as
     for col in big.columns:
         vals = col.to_numpy()
         if vals.dtype == object:
-            raise ValueError(f"column {col.name!r} has no device layout")
-        valid = col.validity_numpy()
-        cols.append((vals, valid))
+            raise DeviceFallback(f"column {col.name!r} has no device layout")
+        host_dtypes.append(vals.dtype)
+        if vals.dtype == np.float64:
+            # a TPU has no IEEE f64: the device holds an f64 plane as a pair
+            # of f32 and hands back other bits than it was given, and its
+            # compiler cannot take the bits of an f64 for the ring kernel's
+            # uint32 words. A repartition must move values bit for bit, so
+            # f64 planes cross as their uint64 host view (64-bit integers
+            # are exact on the device) and are viewed back after the fetch.
+            vals = vals.view(np.uint64)
+        cols.append((vals, col.validity_numpy()))
         dtypes += [vals.dtype, np.bool_]
     flat = []
     ici_bytes = 0
@@ -3224,31 +3222,19 @@ def _mesh_repartition_exchange(node, batches: List[RecordBatch], rows: int,
         # crosses the interconnect once at its padded size
         ici_bytes += n * total * vals.dtype.itemsize + n * total
     args = (_shard_np(mesh, dest, total), mesh_row_mask(mesh, rows, total))
-    ring = _ring_permute_gate(n)
-    counts = None
     if ring is not None:
-        try:
-            step = sharded_ring_repartition_step(mesh, dtypes, interpret=ring)
-            counts, planes = step(*args, *flat)
-            jax.block_until_ready(counts)
-        except Exception as exc:
-            # runtime lowering failure: latch onto the all_to_all tier and
-            # replay the batch — nothing was consumed, the retry is exact
-            with _RING_PERMUTE_LOCK:
-                _RING_PERMUTE_BROKEN[0] = True
-            counts = None
-            _counters.bump("pallas_fallbacks")
-            _counters.reject(
-                "pallas", "in-kernel ring permute failed to lower; "
-                "repartition replayed on the all_to_all tier", str(exc))
-    if counts is None:
+        # a kernel that does not lower raises: no tier replaces it
+        step = sharded_ring_repartition_step(mesh, dtypes, interpret=ring)
+        counts, planes = step(*args, *flat)
+        _counters.bump("mesh_fused_permute_dispatches")
+    else:
         step = sharded_alltoall_repartition_step(mesh, dtypes)
         counts, planes = step(*args, *flat)
         _counters.bump("mesh_alltoall_dispatches")
-    else:
-        _counters.bump("mesh_fused_permute_dispatches")
     counts_np = np.asarray(jax.device_get(counts))
     planes_np = [np.asarray(p) for p in jax.device_get(list(planes))]
+    for i, dt in enumerate(host_dtypes):
+        planes_np[2 * i] = planes_np[2 * i].view(dt)
     _counters.bump("mesh_alltoall_rows", rows)
     _counters.bump("mesh_alltoall_ici_bytes", ici_bytes)
 
@@ -3261,7 +3247,7 @@ def _mesh_repartition_exchange(node, batches: List[RecordBatch], rows: int,
         for i, f in enumerate(node.schema):
             v = [planes_np[2 * i][d * n + j][:c] for j, c in per_src]
             m = [planes_np[2 * i + 1][d * n + j][:c] for j, c in per_src]
-            vv = np.concatenate(v) if v else np.empty(0, dtypes[2 * i])
+            vv = np.concatenate(v) if v else np.empty(0, host_dtypes[i])
             mm = np.concatenate(m) if m else np.empty(0, bool)
             arr = pa.array(vv, mask=~mm) if not mm.all() else pa.array(vv)
             out_cols.append(Series.from_arrow(arr, f.name, dtype=f.dtype))

@@ -115,6 +115,8 @@ class UdfProcessPool:
             (func.fn, func.is_batch, getattr(func, "is_generator", False), func.is_async))
         env = dict(os.environ)
         env.setdefault("DAFT_TPU_DEVICE", "off")
+        if env["DAFT_TPU_DEVICE"] == "off":
+            env["JAX_PLATFORMS"] = "cpu"  # the chip stays with the parent
         env["DAFT_TPU_UDF_AUTHKEY"] = authkey.hex()
         pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
         prev = env.get("PYTHONPATH", "")

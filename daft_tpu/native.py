@@ -2,11 +2,10 @@
 
 Reference parity: the reference compiles its Rust core into the daft.daft
 extension module; here the hot host kernels live in a C ABI shared library with
-a graceful numpy fallback when the library hasn't been built. Build:
-
-    cmake -S native -B native/build && cmake --build native/build
-
-The build drops libdaft_native.so into daft_tpu/_native/.
+a numpy path for hosts without a toolchain. The library is a build product
+outside git (daft_tpu/_native/): get_lib() builds it on first use where it is
+missing or older than its source, build() builds it unconditionally, and
+implementation() reports which path this process uses and why.
 """
 
 from __future__ import annotations
@@ -25,28 +24,37 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SO_PATH = os.path.join(_REPO_ROOT, "daft_tpu", "_native", "libdaft_native.so")
 
 
-def _try_build() -> None:
-    """Best-effort one-shot build if a toolchain is available (dev convenience)."""
-    src_dir = os.path.join(_REPO_ROOT, "native")
-    if not os.path.isdir(src_dir):
-        return
-    try:
-        os.makedirs(os.path.dirname(_SO_PATH), exist_ok=True)
-        subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-             os.path.join(src_dir, "src", "kernels.cpp"), "-o", _SO_PATH],
-            check=True, capture_output=True, timeout=120,
-        )
-    except Exception:  # lint: ignore[broad-except] -- native kernels are optional acceleration;
-        pass  # get_lib() returns None and every caller has a python path
+# why get_lib() serves None (the numpy paths are in use); "" while unknown
+_WHY_NUMPY = ""
+
+
+def build() -> None:
+    """Compile native/src/kernels.cpp into daft_tpu/_native/ (the library is
+    a build product outside git). Raises when there is no compiler or the
+    compile fails. Call it before the first get_lib(): a loaded library must
+    not be overwritten."""
+    os.makedirs(os.path.dirname(_SO_PATH), exist_ok=True)
+    subprocess.run(
+        ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
+         os.path.join(_REPO_ROOT, "native", "src", "kernels.cpp"), "-o", _SO_PATH],
+        check=True, capture_output=True, timeout=300,
+    )
+
+
+def implementation() -> str:
+    """Which host kernels this process uses: "native", or "numpy: <why>"."""
+    return "native" if get_lib() is not None else f"numpy: {_WHY_NUMPY}"
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    global _LIB, _TRIED
+    """The loaded library, or None on a host without a toolchain (every
+    caller has a numpy path); implementation() says which and why."""
+    global _LIB, _TRIED, _WHY_NUMPY
     if _LIB is not None or _TRIED:
         return _LIB
     _TRIED = True
     if os.environ.get("DAFT_TPU_DISABLE_NATIVE"):
+        _WHY_NUMPY = "DAFT_TPU_DISABLE_NATIVE is set"
         return None
     src = os.path.join(_REPO_ROOT, "native", "src", "kernels.cpp")
     stale = (
@@ -54,12 +62,16 @@ def get_lib() -> Optional[ctypes.CDLL]:
         and os.path.getmtime(src) > os.path.getmtime(_SO_PATH)
     )
     if not os.path.exists(_SO_PATH) or stale:
-        _try_build()
+        try:
+            build()
+        except (OSError, subprocess.SubprocessError) as e:
+            _WHY_NUMPY = f"build failed: {e}"
     if not os.path.exists(_SO_PATH):
         return None
     try:
         lib = ctypes.CDLL(_SO_PATH)
-    except OSError:
+    except OSError as e:
+        _WHY_NUMPY = f"load failed: {e}"
         return None
     i64p = ctypes.POINTER(ctypes.c_int64)
     u64p = ctypes.POINTER(ctypes.c_uint64)

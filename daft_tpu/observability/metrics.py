@@ -188,7 +188,7 @@ DEVICE_COUNTER_NAMES = (
     # Pallas kernel tier (ops/pallas_kernels.py: segment-reduce groupby,
     # hash-probe join, in-kernel ICI ring permute)
     "pallas_dispatches",       # grouped-agg batches through the Pallas kernel
-    "pallas_fallbacks",        # Pallas lowering/run failures -> XLA tier
+    "pallas_fallbacks",        # tripwire, asserted 0: a failed kernel raises
     "pallas_probe_dispatches",  # join index planes probed in-kernel
     # intra-host repartition exchanged by the in-kernel ring permute instead
     # of a standalone all_to_all dispatch (mesh_alltoall_dispatches stays 0)

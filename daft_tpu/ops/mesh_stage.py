@@ -809,11 +809,10 @@ def _mesh_pallas_idx_plane(ctx, batch, d, n: int, total: int, mesh):
     digit planes (sharded) matched against the replicated VMEM dim hash
     table via ops/pallas_kernels.hash_probe_index under shard_map — the host
     hash probe and the index-plane upload both disappear. Returns None when
-    the ctx's Pallas probe gate keeps the host tier (mode off, broken latch,
-    chained dim) or when the dim carries filters (the host path folds
-    visibility INTO the indices; the kernel probes raw keys). A kernel
-    failure latches the tier off and returns None — the caller replays the
-    same batch through _mesh_idx_plane, so nothing is lost but time."""
+    the ctx's Pallas probe gate keeps the host tier (mode off, chained dim)
+    or when the dim carries filters (the host path folds visibility INTO the
+    indices; the kernel probes raw keys). A kernel that does not lower
+    raises."""
     from .device_join import series_keyed
     from ..core.kernels.encoding import _common_key_dtype
 
@@ -824,58 +823,48 @@ def _mesh_pallas_idx_plane(ctx, batch, d, n: int, total: int, mesh):
         return None
     from . import pallas_kernels as pk
 
-    try:
-        dim_b = ctx.batches[d.name]
-        kdt = _common_key_dtype(
-            ctx._probe_dtype(batch, d), dim_b.schema[d.key_col].dtype)
-        tbl = ctx._pallas_probe_table_host(d, kdt)
-        anchor = ctx._probe_anchor(batch, d)
-        key_series = dim_b.get_column(d.key_col)
-        ndev = int(mesh.shape[_MESH_AXIS])
+    dim_b = ctx.batches[d.name]
+    kdt = _common_key_dtype(
+        ctx._probe_dtype(batch, d), dim_b.schema[d.key_col].dtype)
+    tbl = ctx._pallas_probe_table_host(d, kdt)
+    anchor = ctx._probe_anchor(batch, d)
+    key_series = dim_b.get_column(d.key_col)
+    ndev = int(mesh.shape[_MESH_AXIS])
 
-        def build():
-            from ..parallel.distributed import _shard_map
+    def build():
+        from ..parallel.distributed import _shard_map
 
-            vals, valid = ctx._probe_values(batch, d, {}, kdt)
-            pv = np.full(total, pk.PROBE_SENTINEL, dtype=np.int64)
-            pm = np.zeros(total, dtype=bool)
-            pv[:n] = vals
-            pm[:n] = valid
-            hi = (pv >> 32).astype(np.int32)
-            lo = (pv & 0xFFFFFFFF).astype(np.uint32).view(np.int32)
-            registry().inc("hbm_h2d_bytes", int(hi.nbytes) + int(lo.nbytes))
-            sharded = NamedSharding(mesh, P(_MESH_AXIS))
-            fh = jax.device_put(hi, sharded)
-            fl = jax.device_put(lo, sharded)
-            rep = NamedSharding(mesh, P())
-            th = jax.device_put(np.asarray(tbl[0]), rep)
-            tl = jax.device_put(np.asarray(tbl[1]), rep)
-            tr = jax.device_put(np.asarray(tbl[2]), rep)
+        vals, valid = ctx._probe_values(batch, d, {}, kdt)
+        pv = np.full(total, pk.PROBE_SENTINEL, dtype=np.int64)
+        pm = np.zeros(total, dtype=bool)
+        pv[:n] = vals
+        pm[:n] = valid
+        hi = (pv >> 32).astype(np.int32)
+        lo = (pv & 0xFFFFFFFF).astype(np.uint32).view(np.int32)
+        registry().inc("hbm_h2d_bytes", int(hi.nbytes) + int(lo.nbytes))
+        sharded = NamedSharding(mesh, P(_MESH_AXIS))
+        fh = jax.device_put(hi, sharded)
+        fl = jax.device_put(lo, sharded)
+        rep = NamedSharding(mesh, P())
+        th = jax.device_put(np.asarray(tbl[0]), rep)
+        tl = jax.device_put(np.asarray(tbl[1]), rep)
+        tr = jax.device_put(np.asarray(tbl[2]), rep)
 
-            def local(fh, fl, th, tl, tr):
-                return pk.hash_probe_index(
-                    fh, fl, th, tl, tr, interpret=interp).astype(jnp.int64)
+        def local(fh, fl, th, tl, tr):
+            return pk.hash_probe_index(
+                fh, fl, th, tl, tr, interpret=interp).astype(jnp.int64)
 
-            step = jax.jit(_shard_map(
-                local, mesh,
-                (P(_MESH_AXIS), P(_MESH_AXIS), P(), P(), P()),
-                P(_MESH_AXIS)))
-            out = step(fh, fl, th, tl, tr)
-            counters.bump("pallas_probe_dispatches")
-            return out
+        step = jax.jit(_shard_map(
+            local, mesh,
+            (P(_MESH_AXIS), P(_MESH_AXIS), P(), P(), P()),
+            P(_MESH_AXIS)))
+        out = step(fh, fl, th, tl, tr)
+        counters.bump("pallas_probe_dispatches")
+        return out
 
-        return series_keyed(
-            anchor, ("mjpdidx", d.key_col, d.parent, total, ndev),
-            (key_series, tbl), build, rebuild_rows=n)
-    except DeviceFallback:
-        raise
-    except Exception as exc:  # noqa: BLE001 - latch + host replay
-        ctx._pallas_probe_broken = True
-        counters.bump("pallas_fallbacks")
-        counters.reject(
-            "pallas", "mesh hash-probe kernel failed; index plane replayed "
-            "on the host probe tier", str(exc))
-        return None
+    return series_keyed(
+        anchor, ("mjpdidx", d.key_col, d.parent, total, ndev),
+        (key_series, tbl), build, rebuild_rows=n)
 
 
 def _mesh_fact_membership(ctx, batch, syn: str, n: int, total: int, mesh):

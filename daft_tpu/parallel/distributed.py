@@ -27,6 +27,7 @@ import numpy as np
 from ..utils import jax_setup  # noqa: F401
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..expressions.expressions import AggExpr, Expression
@@ -142,6 +143,17 @@ def _merge_op(op: str) -> str:
     return {"count": "sum", "sum": "sum", "min": "min", "max": "max"}[op]
 
 
+def _pextreme(x: jnp.ndarray, axis: str, is_min: bool) -> jnp.ndarray:
+    """pmin/pmax over the mesh axis. The chip's compiler lowers a 64-bit
+    all-reduce for sums only ("Supported lowering only of Sum all reduce"),
+    so 64-bit extremes all_gather the per-shard partials (n_dev values per
+    cell) and reduce locally: the same exact answer, one collective."""
+    if jnp.dtype(x.dtype).itemsize == 8:
+        gathered = jax.lax.all_gather(x, axis)
+        return jnp.min(gathered, axis=0) if is_min else jnp.max(gathered, axis=0)
+    return (jax.lax.pmin if is_min else jax.lax.pmax)(x, axis)
+
+
 _STEP_CACHE: Dict[tuple, Callable] = {}
 
 
@@ -235,7 +247,7 @@ def sharded_groupby_step(mesh: Mesh, agg_ops: Sequence[str], capacity: int,
 
         total_nu = _true_unique_count(jnp.sort(all_k))
         overflow = (
-            jax.lax.pmax(local_nu, axis) > capacity
+            _pextreme(local_nu, axis, is_min=False) > capacity
         ) | (total_nu > capacity)
         group_keys = fuk[:capacity]
         group_valid = group_keys != _KEY_SENTINEL
@@ -250,17 +262,8 @@ def sharded_groupby_step(mesh: Mesh, agg_ops: Sequence[str], capacity: int,
 
 
 def _shard_map(local, mesh: Mesh, in_specs, out_specs):
-    """shard_map across the jax spelling drift (check_vma vs check_rep)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(local, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:  # pre-0.8 jax spells it check_rep
-        return shard_map(local, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    return shard_map(local, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 def sharded_gather_step(mesh: Mesh, n_cols: int, axis: str = "dp") -> Callable:
@@ -341,8 +344,8 @@ def sharded_join_agg_step(mesh: Mesh, specs: Sequence[Tuple[str, int]],
                     big = dev._extreme(v.dtype, partial == "min")
                     masked = jnp.where(mask, v, big)
                     red = jnp.min(masked) if partial == "min" else jnp.max(masked)
-                    coll = jax.lax.pmin if partial == "min" else jax.lax.pmax
-                    out[(i, partial)] = (coll(red, axis), cnt > 0)
+                    out[(i, partial)] = (
+                        _pextreme(red, axis, partial == "min"), cnt > 0)
         return out
 
     in_specs = (
@@ -437,8 +440,8 @@ def sharded_join_ungrouped_stage_step(mesh: Mesh, schema: Schema,
                     masked = jnp.where(mask, v, big)
                     red = jnp.min(masked) if partial == "min" \
                         else jnp.max(masked)
-                    coll = jax.lax.pmin if partial == "min" else jax.lax.pmax
-                    out[(name, partial)] = (coll(red, axis), cnt > 0)
+                    out[(name, partial)] = (
+                        _pextreme(red, axis, partial == "min"), cnt > 0)
         return out
 
     in_specs = (
@@ -502,8 +505,7 @@ def sharded_join_grouped_stage_step(mesh: Mesh, schema: Schema,
             if op in ("sum", "count"):
                 merged = jax.lax.psum(table, axis)
             else:
-                coll = jax.lax.pmin if op == "min" else jax.lax.pmax
-                merged = coll(table, axis)
+                merged = _pextreme(table, axis, op == "min")
             ok = cnt > 0 if op != "count" else jnp.ones(cap1, dtype=bool)
             results.append((merged[:capacity], ok[:capacity]))
         return rows[:capacity], overflow, tuple(results)
@@ -586,11 +588,20 @@ def _repart_sort_pack(dest, row_mask, planes, n_dev: int, S: int):
 def _pack_words(mat: jnp.ndarray) -> jnp.ndarray:
     """[n_dev, S] plane of any device dtype -> [n_dev, W] uint32 words,
     bit-exact and invertible by _unpack_words: 64-bit types split into two
-    words, <=32-bit types widen losslessly to one."""
+    words, <=32-bit types widen losslessly to one. 64-bit integers split by
+    shift and truncate: the chip's compiler has no rewrite for a
+    shape-changing 64-bit bitcast. It has none for f64 -> bits either, so on
+    the chip the executor hands f64 planes over as their uint64 host view
+    and this f64 branch serves other backends only."""
     dt = mat.dtype
-    if dt.itemsize == 8:
+    if dt.itemsize == 8 and jnp.issubdtype(dt, jnp.floating):
         return jax.lax.bitcast_convert_type(mat, jnp.uint32) \
             .reshape(mat.shape[0], -1)
+    if dt.itemsize == 8:
+        u = mat.astype(jnp.uint64)
+        pair = jnp.stack([u.astype(jnp.uint32),
+                          (u >> jnp.uint64(32)).astype(jnp.uint32)], axis=-1)
+        return pair.reshape(mat.shape[0], -1)
     if dt == jnp.bool_:
         return mat.astype(jnp.uint32)
     if jnp.issubdtype(dt, jnp.floating):
@@ -609,7 +620,9 @@ def _unpack_words(words: jnp.ndarray, dt, S: int) -> jnp.ndarray:
         pair = words.reshape(words.shape[0], S, 2)
         if jnp.issubdtype(dt, jnp.floating):
             return jax.lax.bitcast_convert_type(pair, jnp.float64)
-        return jax.lax.bitcast_convert_type(pair, jnp.uint64).astype(dt)
+        u = pair[..., 0].astype(jnp.uint64) \
+            | (pair[..., 1].astype(jnp.uint64) << jnp.uint64(32))
+        return u.astype(dt)
     if dt == jnp.bool_:
         return words != 0
     if jnp.issubdtype(dt, jnp.floating):
@@ -632,10 +645,9 @@ def sharded_ring_repartition_step(mesh: Mesh, dtypes: Sequence,
     — every plane (and the counts) bitcast into a single [n_dev, W] uint32
     word buffer so the ring crosses the interconnect exactly once.
 
-    Selected by the executor's repartition exchange under DAFT_TPU_PALLAS
-    (on = engage, interpret off-silicon; auto = silicon only); a runtime
-    lowering failure there latches back onto the all_to_all tier and
-    replays the batch.
+    Selected by the executor's repartition exchange under
+    DAFT_TPU_PALLAS=on only (interpreted off the chip); `auto` keeps the
+    all_to_all tier. A lowering failure raises.
     """
     n_dev = int(mesh.shape[axis])
     dtypes = tuple(dtypes)

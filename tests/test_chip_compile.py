@@ -1,0 +1,160 @@
+"""Ask the v5e's own compiler, with no chip attached, whether it accepts the
+device programs of the TPC-H main path at SF10 shapes.
+
+Interpret mode cannot show what Mosaic or the TPU's 64-bit rewriter refuse
+(an int64 block index, a python-int constant, a slice that cuts the tiling,
+a 64-bit bitcast): these compiles can. This is the only file that describes
+the chip, and it does so inside a fixture: only one process may hold libtpu,
+and every xdist worker imports every test file. Nothing runs here, so a pass
+says "lowers", never "right" or "fast".
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+from daft_tpu.utils import jax_setup  # noqa: F401,E402
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding  # noqa: E402
+from jax.sharding import PartitionSpec as P  # noqa: E402
+
+from daft_tpu.ops import pallas_kernels as pk  # noqa: E402
+
+# SF10 shapes: the Pallas phase of chip_smoke.py feeds 2^20-row buckets; the
+# suppkey grouping has 100,000 groups (cap 2^17) and 13 digit/count planes;
+# orders and part pad to 2^24 and 2^21 probe slots.
+ROWS = 1 << 20
+PLANES = 13
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu here: nothing to ask
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes, **static):
+    compiled = fn.lower(*shapes, **static).compile()
+    return compiled.as_text()
+
+
+def _s(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("cap", [2048, 8192, pk.PALLAS_MAX_SEGMENTS])
+def test_windowed_segment_sum_lowers(one_chip, cap):
+    text = _compile(pk.segment_sum_planes_windowed,
+                    _s(one_chip, (ROWS, PLANES), jnp.float32),
+                    _s(one_chip, (ROWS,), jnp.int32), cap=cap)
+    assert "tpu_custom_call" in text
+    # the cross-window combine outside the kernel stays f64
+    assert "f64" in text
+
+
+@pytest.mark.parametrize("op", ["min", "max"])
+def test_segment_extremes_lower(one_chip, op):
+    text = _compile(pk.segment_extreme_planes,
+                    _s(one_chip, (ROWS, 2), jnp.float32),
+                    _s(one_chip, (ROWS,), jnp.int32),
+                    cap=pk.PALLAS_MAX_SEGMENTS, op=op)
+    assert "tpu_custom_call" in text
+
+
+def test_int64_extremes_lower(one_chip):
+    text = _compile(pk.segment_extreme_int64,
+                    _s(one_chip, (ROWS,), jnp.int64),
+                    _s(one_chip, (ROWS,), jnp.bool_),
+                    _s(one_chip, (ROWS,), jnp.int32),
+                    cap=pk.PALLAS_MAX_SEGMENTS, op="max")
+    assert text.count("tpu_custom_call") >= 3  # one launch per digit plane
+
+
+@pytest.mark.parametrize("slots", [1 << 17, 1 << 21, 1 << 24],
+                         ids=["supplier", "part", "orders"])
+def test_hash_probe_index_lowers(one_chip, slots):
+    fact = _s(one_chip, (ROWS,), jnp.int32)
+    key = _s(one_chip, (1, slots), jnp.int32)
+    text = _compile(pk.hash_probe_index, fact, fact, key, key,
+                    _s(one_chip, (1, slots), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_fused_probe_reduce_lowers(one_chip):
+    slots, planes = 1 << 21, 4
+    fact = _s(one_chip, (ROWS,), jnp.int32)
+    key = _s(one_chip, (1, slots), jnp.int32)
+    text = _compile(pk.hash_probe_segment_sum, fact, fact, fact, key, key,
+                    _s(one_chip, (1, slots), jnp.float32),
+                    _s(one_chip, (slots, planes), jnp.float32), cap=128)
+    assert "tpu_custom_call" in text
+
+
+def test_q6_filter_agg_program_lowers_at_8m_rows(one_chip):
+    """The flagship fused filter-aggregate (TPC-H Q6) at an 8M-row bucket,
+    f64 as the engine runs it."""
+    import __graft_entry__ as graft
+
+    fn, _example = graft.entry()
+    n = 1 << 23
+    col = (_s(one_chip, (n,), jnp.float64), _s(one_chip, (n,), jnp.bool_))
+    compiled = jax.jit(fn).lower(*col, *col, *col).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 3 * n * 8
+
+
+def test_ring_permute_lowers_on_four_chip_mesh(topo):
+    """The in-kernel ICI ring permute inside its shard_map repartition
+    program: one Mosaic kernel, no standalone all-to-all."""
+    from daft_tpu.parallel.distributed import sharded_ring_repartition_step
+
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    sharded = NamedSharding(mesh, P("dp"))
+    total = 4 * 4096
+    # f64 planes cross as their uint64 host view (executor._mesh_repartition_exchange)
+    dtypes = [np.int64, np.bool_, np.uint64, np.bool_, np.int32, np.bool_]
+    step = sharded_ring_repartition_step(mesh, dtypes)
+    shapes = [_s(sharded, (total,), np.int64), _s(sharded, (total,), np.bool_)]
+    shapes += [_s(sharded, (total,), dt) for dt in dtypes]
+    text = step.lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "all-to-all" not in text
+
+
+@pytest.mark.parametrize("dtype", [np.int64, np.float64, np.int32])
+def test_mesh_extreme_collective_lowers(topo, dtype):
+    """The cross-shard min/max of the mesh grouped and join steps. The chip's
+    compiler lowers a 64-bit all-reduce for sums only, so _pextreme must not
+    hand it a 64-bit pmin/pmax."""
+    from daft_tpu.parallel import distributed as dist
+
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+
+    def local(x):
+        return (dist._pextreme(jnp.max(x), "dp", is_min=False),
+                dist._pextreme(jnp.min(x, keepdims=True), "dp", is_min=True))
+
+    step = jax.jit(dist._shard_map(local, mesh, (P("dp"),), (P(), P())))
+    step.lower(_s(NamedSharding(mesh, P("dp")), (4 * 1024,), dtype)).compile()

@@ -239,7 +239,10 @@ def test_idle_pool_liveness_detects_kill9_without_work(monkeypatch, tmp_path):
         # timeout); allow a couple of tick intervals of slack.
         wait_until(lambda: "worker-0" in pool.dead_workers, timeout_s=5.0,
                    what="idle liveness tick declaring the killed worker dead")
-        assert "worker-0" not in pool.workers  # dropped, not zombie-polled
+        # dropped, not zombie-polled (the dispatcher records the death a few
+        # statements before it drops the handle: wait, don't race it)
+        wait_until(lambda: "worker-0" not in pool.workers, timeout_s=5.0,
+                   what="dispatcher dropping the dead worker's handle")
         # the survivor keeps serving
         assert len(pool.run_tasks(_scan_tasks(2))) == 2
     finally:

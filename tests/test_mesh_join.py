@@ -313,11 +313,24 @@ def test_mesh_join_cost_function_scales():
 
 # ---- intra-host all_to_all repartition -----------------------------------------------
 
-def test_alltoall_repartition_bit_identical_zero_wire_bytes():
+def test_alltoall_repartition_bit_identical_zero_wire_bytes(monkeypatch):
     """Hash repartition over ICI: partition contents AND row order match the
     host path exactly (nulls included), with zero shuffle wire bytes while
-    the exchange moved real plane bytes — the co-located-worker wire drop."""
+    the exchange moved real plane bytes — the co-located-worker wire drop.
+    No plane crosses as f64: a TPU holds f64 as a pair of f32 and hands back
+    other bits (seen on four chips, PR 22), so f64 columns ride uint64 views."""
     from daft_tpu.core.recordbatch import RecordBatch
+    from daft_tpu.parallel import distributed as dist
+
+    crossed = []
+    real_step = dist.sharded_alltoall_repartition_step
+
+    def recording_step(mesh, dtypes, *a, **k):
+        crossed.extend(np.dtype(d) for d in dtypes)
+        return real_step(mesh, dtypes, *a, **k)
+
+    monkeypatch.setattr(dist, "sharded_alltoall_repartition_step",
+                        recording_step)
 
     n = 80_000
     rng = np.random.default_rng(5)
@@ -337,6 +350,7 @@ def test_alltoall_repartition_bit_identical_zero_wire_bytes():
     assert counters.mesh_alltoall_ici_bytes > 0
     assert registry().get("shuffle_wire_bytes") == wire0, \
         "co-located repartition wrote shuffle wire bytes"
+    assert np.dtype(np.uint64) in crossed and np.dtype(np.float64) not in crossed
 
     def rows(p):
         bs = [b for b in p.batches if b.num_rows]
@@ -397,22 +411,36 @@ def test_calibrate_tool_suggests_mesh_terms():
 
 
 def test_compile_cache_knob_resolution(monkeypatch):
-    """DAFT_TPU_COMPILE_CACHE_DIR is the canonical persistent-compile-cache
-    knob; the legacy spelling still works; falsy spellings disable."""
-    from daft_tpu.utils.jax_setup import compile_cache_dir
+    """The compile cache is placed from outside: JAX_COMPILATION_CACHE_DIR
+    wins and the program sets none in code; without it the cache is the one
+    fixed .jax_cache/ at the checkout root. The DAFT_TPU_COMPILE_CACHE*
+    knobs are gone."""
+    import importlib
+    import os
 
-    monkeypatch.delenv("DAFT_TPU_COMPILE_CACHE_DIR", raising=False)
-    monkeypatch.delenv("DAFT_TPU_COMPILE_CACHE", raising=False)
-    assert compile_cache_dir().endswith("daft_tpu_xla")
-    monkeypatch.setenv("DAFT_TPU_COMPILE_CACHE_DIR", "/tmp/x1")
-    assert compile_cache_dir() == "/tmp/x1"
-    monkeypatch.setenv("DAFT_TPU_COMPILE_CACHE", "/tmp/legacy")
-    assert compile_cache_dir() == "/tmp/x1", "canonical knob must win"
-    monkeypatch.delenv("DAFT_TPU_COMPILE_CACHE_DIR")
-    assert compile_cache_dir() == "/tmp/legacy"
-    for off in ("0", "off", ""):
-        monkeypatch.setenv("DAFT_TPU_COMPILE_CACHE", off)
-        assert compile_cache_dir() == ""
+    import jax
+
+    from daft_tpu.utils import jax_setup
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("DAFT_TPU_COMPILE_CACHE_DIR", "/tmp/x1")
+        monkeypatch.setenv("DAFT_TPU_COMPILE_CACHE", "/tmp/legacy")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        jax.config.update("jax_compilation_cache_dir", None)
+        importlib.reload(jax_setup)
+        assert jax_setup.compile_cache_dir() == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == jax_setup.compile_cache_dir()
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        jax.config.update("jax_compilation_cache_dir", None)
+        importlib.reload(jax_setup)
+        assert jax_setup.compile_cache_dir() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir is None, \
+            "with the variable set, the program sets no directory in code"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_mesh_probe_static_on_cpu_backend():

@@ -209,10 +209,8 @@ def test_fused_repartition_zero_alltoall_dispatches():
 
 @needs_mesh
 def test_ring_permute_failure_latches_to_alltoall(monkeypatch):
-    """A runtime lowering failure in the fused exchange latches back onto
-    the all_to_all tier and replays the batch exactly — attributed by the
-    fallback counter, with identical partitions."""
-    from daft_tpu.execution import executor as ex
+    """A runtime lowering failure in the fused exchange reaches the caller:
+    no latch, no all_to_all replay, no host-bucket replay."""
     from daft_tpu.parallel import distributed as dist
 
     def broken(*a, **k):
@@ -224,33 +222,21 @@ def test_ring_permute_failure_latches_to_alltoall(monkeypatch):
         "k": rng.integers(0, 97, n).tolist(),
         "v": (rng.random(n) * 10).tolist(),
     })
-    with execution_config_ctx(device_mode="off"):
-        host = df.repartition(8, col("k")).collect()
     monkeypatch.setattr(dist, "sharded_ring_repartition_step", broken)
     counters.reset()
-    try:
-        with execution_config_ctx(device_mode="on", mesh_devices=8,
-                                  device_min_rows=1, pallas_mode="on"):
-            out = df.repartition(8, col("k")).collect()
-        assert counters.pallas_fallbacks > 0
-        assert counters.mesh_alltoall_dispatches > 0
-        assert counters.mesh_fused_permute_dispatches == 0
-        assert ex._RING_PERMUTE_BROKEN[0]
-
-        from daft_tpu.core.recordbatch import RecordBatch
-
-        def rows(p):
-            bs = [b for b in p.batches if b.num_rows]
-            if not bs:
-                return {}
-            b = bs[0] if len(bs) == 1 else RecordBatch.concat(bs)
-            return {c: b.get_column(c).to_pylist() for c in ("k", "v")}
-
-        for a, b in zip(host._result, out._result):
-            assert rows(a) == rows(b)
-    finally:
-        # the latch is process-wide: un-latch so later tests see the kernel
-        ex._RING_PERMUTE_BROKEN[0] = False
+    with execution_config_ctx(device_mode="on", mesh_devices=8,
+                              device_min_rows=1, pallas_mode="on"):
+        with pytest.raises(RuntimeError, match="mosaic lowering failed"):
+            df.repartition(8, col("k")).collect()
+    assert counters.pallas_fallbacks == 0
+    assert counters.mesh_alltoall_dispatches == 0
+    assert counters.mesh_fused_permute_dispatches == 0
+    # auto never engages the kernel: the all_to_all tier serves the exchange
+    counters.reset()
+    with execution_config_ctx(device_mode="on", mesh_devices=8,
+                              device_min_rows=1):
+        df.repartition(8, col("k")).collect()
+    assert counters.mesh_alltoall_dispatches > 0
 
 
 # ---- end-to-end device joins through the probe kernel ------------------------
@@ -328,9 +314,8 @@ def test_device_join_probe_end_to_end_parity():
 
 
 def test_device_join_probe_failure_replays_on_host_tier(monkeypatch):
-    """A probe kernel that fails at runtime latches the context back onto
-    the host index-plane tier and replays the SAME batch — attributed by
-    the fallback counter, bit-identical results."""
+    """A probe kernel that fails at runtime reaches the caller: no latch,
+    no host index-plane replay, no fallback counter."""
     def broken(*a, **k):
         raise RuntimeError("mosaic lowering failed (injected)")
 
@@ -342,14 +327,12 @@ def test_device_join_probe_failure_replays_on_host_tier(monkeypatch):
     pk_live = importlib.import_module("daft_tpu.ops.pallas_kernels")
     monkeypatch.setattr(pk_live, "hash_probe_index", broken)
     fact, d1, d2, d64 = _star_tables()
-    with execution_config_ctx(device_mode="off"):
-        host = _star_query(fact, d1, d2, d64).to_pydict()
     counters.reset()
     with execution_config_ctx(device_mode="on", pallas_mode="on"):
-        dev = _star_query(fact, d1, d2, d64).to_pydict()
-    assert counters.pallas_fallbacks > 0
+        with pytest.raises(RuntimeError, match="mosaic lowering failed"):
+            _star_query(fact, d1, d2, d64).to_pydict()
+    assert counters.pallas_fallbacks == 0
     assert counters.pallas_probe_dispatches == 0
-    _assert_close(host, dev)
 
 
 @needs_mesh

@@ -257,7 +257,7 @@ def test_placement_zero_overhead_on_host_path():
 # ---------------------------------------------------------------------------
 
 def test_explain_placement_costed_decision(monkeypatch):
-    """The auto tier on a 90ms tunneled link cost-rejects a grouped agg to
+    """The auto tier on a slow link (90ms round trip) cost-rejects a grouped agg to
     host; explain_placement must show BOTH per-term tables, the margin, and
     the host verdict — and the placement counters must attribute it."""
     import jax
