@@ -218,15 +218,34 @@ class Series:
         from ..utils import jax_setup  # noqa: F401  (enables x64 before device use)
         import jax.numpy as jnp
 
-        values, validity = self._padded_planes(pad_to, f32)
-        return jnp.asarray(values), jnp.asarray(validity)
+        return self._upload(pad_to, f32, jnp.asarray)
+
+    def _upload(self, pad_to: Optional[int], f32: bool, put):
+        """The one upload body behind to_device / to_device_sharded /
+        to_device_replicated: the padded host planes, then `put` on each (the
+        layout's placement). A `device.upload` span while a recorder is
+        installed; the host's time in it is always counted (`h2d_upload_us`:
+        two clock reads a column, so set-up can be read from counters alone)."""
+        import time
+
+        from ..observability.metrics import registry
+        from ..observability.runtime_stats import profile_span
+
+        t0 = time.perf_counter()
+        with profile_span("device.upload", "device", rows=len(self),
+                          dtype=str(self._dtype)) as sp:
+            values, validity = self._padded_planes(pad_to, f32)
+            if sp is not None:
+                sp.args["bytes"] = int(values.nbytes) + int(validity.nbytes)
+            out = put(values), put(validity)
+        registry().inc("h2d_upload_us", int((time.perf_counter() - t0) * 1e6))
+        return out
 
     def _padded_planes(self, pad_to: Optional[int], f32: bool):
         """Host-side (values, validity) numpy planes padded to `pad_to` rows
         (padding invalid), with the h2d byte attribution every device
-        placement shares — the single body behind to_device /
-        to_device_sharded / to_device_replicated, so padding and accounting
-        can never drift between layouts."""
+        placement shares, so padding and accounting can never drift between
+        layouts."""
         values = self.to_numpy()
         if f32 and values.dtype == np.float64:
             values = values.astype(np.float32)
@@ -258,10 +277,8 @@ class Series:
             raise ValueError(
                 f"to_device_sharded: pad_to={pad_to} not divisible by the "
                 f"{n_dev}-device mesh")
-        values, validity = self._padded_planes(pad_to, f32)
         sharding = NamedSharding(mesh, PartitionSpec(axis))
-        return (jax.device_put(values, sharding),
-                jax.device_put(validity, sharding))
+        return self._upload(pad_to, f32, lambda a: jax.device_put(a, sharding))
 
     def to_device_replicated(self, mesh, pad_to: Optional[int] = None,
                              f32: bool = False):
@@ -276,10 +293,8 @@ class Series:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
 
-        values, validity = self._padded_planes(pad_to, f32)
         sharding = NamedSharding(mesh, PartitionSpec())
-        return (jax.device_put(values, sharding),
-                jax.device_put(validity, sharding))
+        return self._upload(pad_to, f32, lambda a: jax.device_put(a, sharding))
 
     def to_device_cached(self, pad_to: Optional[int] = None, f32: bool = False,
                          mesh=None, axis: str = "dp", replicated: bool = False):
@@ -404,11 +419,22 @@ class Series:
         cached = getattr(self, "_dict_codes", None)
         if cached is not None:
             return cached
+        import time
+
+        from ..observability.metrics import registry
+        from ..observability.runtime_stats import profile_span
         from .kernels.groupby import make_groups
 
-        first_idx, group_ids, _ = make_groups([self])
-        codes = group_ids.astype(np.int32, copy=False)
-        values = self.take(first_idx).to_pylist()
+        # first touch of a key column: a span while a recorder is installed,
+        # the host's time always (`dict_encode_us`, once per column)
+        t0 = time.perf_counter()
+        with profile_span("series.dict_encode", "host", rows=len(self)) as sp:
+            first_idx, group_ids, _ = make_groups([self])
+            codes = group_ids.astype(np.int32, copy=False)
+            values = self.take(first_idx).to_pylist()
+            if sp is not None:
+                sp.args["cardinality"] = len(values)
+        registry().inc("dict_encode_us", int((time.perf_counter() - t0) * 1e6))
         out = (codes, values, len(values))
         object.__setattr__(self, "_dict_codes", out)
         return out

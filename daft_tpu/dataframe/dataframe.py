@@ -495,18 +495,29 @@ class DataFrame:
         return _format_table(mp, self.schema)
 
     # ---- conversions -------------------------------------------------------------
+    def _encoded(self, convert):
+        """The result as one client-side object: run the query (once), then
+        concat its partitions and `convert` them — the `result.encode` span,
+        a root of its own that shares the query's qid."""
+        from ..observability.runtime_stats import profile_span, qid_scope
+
+        with qid_scope():
+            parts = self._materialize()
+            with profile_span("result.encode", "host") as sp:
+                mp = MicroPartition.concat(parts) if parts \
+                    else MicroPartition.empty(self.schema)
+                if sp is not None:
+                    sp.args["rows"] = mp.num_rows
+                return convert(mp)
+
     def to_pydict(self) -> Dict[str, list]:
-        parts = self._materialize()
-        mp = MicroPartition.concat(parts) if parts else MicroPartition.empty(self.schema)
-        return mp.to_pydict()
+        return self._encoded(MicroPartition.to_pydict)
 
     def to_pylist(self) -> List[dict]:
         return list(self.iter_rows())
 
     def to_arrow(self):
-        parts = self._materialize()
-        mp = MicroPartition.concat(parts) if parts else MicroPartition.empty(self.schema)
-        return mp.to_arrow()
+        return self._encoded(MicroPartition.to_arrow)
 
     def to_arrow_iter(self):
         for part in self.iter_partitions():
@@ -514,7 +525,7 @@ class DataFrame:
                 yield from b.to_arrow().to_batches()
 
     def to_pandas(self):
-        return self.to_arrow().to_pandas()
+        return self._encoded(lambda mp: mp.to_arrow().to_pandas())
 
     def to_torch_map_dataset(self):
         from .to_torch import DataFrameMapDataset

@@ -75,6 +75,7 @@ from collections import OrderedDict
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
 from ..observability.metrics import registry
+from ..observability.runtime_stats import profile_span
 
 # ---- identity tokens ---------------------------------------------------------------
 
@@ -350,8 +351,13 @@ class ResidencyManager:
                     registry().inc("hbm_stable_rehits")
                     return e.value
         registry().inc("hbm_cache_misses")
-        value = build()  # outside the lock: builds may re-enter the manager
-        nb = device_nbytes(value)
+        # outside the lock: builds may re-enter the manager. The span names
+        # the slot kind (key[0]: "col", "didx", "pack", ...) behind a miss
+        with profile_span("residency.build", "device", slot=str(key[0])) as sp:
+            value = build()
+            nb = device_nbytes(value)
+            if sp is not None:
+                sp.args["bytes"] = nb
         from ..ops.costmodel import rebuild_cost_estimate
 
         cost = rebuild_cost_estimate(nb, rebuild_rows)

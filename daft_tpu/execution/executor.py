@@ -15,6 +15,7 @@ ops/counters.py records which path actually ran.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import threading
 from typing import Iterator, List, Optional
@@ -28,6 +29,7 @@ from ..device.residency import identity_token
 from ..expressions import ColumnRef, Expression
 from ..expressions.eval import eval_expression, eval_projection
 from ..observability import placement as _placement
+from ..observability.runtime_stats import profile_span as _profile_span
 from ..ops import costmodel as _costmodel
 from ..plan import physical as pp
 from ..utils.env import env_bool as _env_bool
@@ -46,12 +48,15 @@ def _exec(node: pp.PhysicalPlan) -> Iterator[MicroPartition]:
     stage thread behind a bounded channel, so the whole plan executes as
     concurrent tasks with backpressure (reference: pipeline.rs:358 +
     channel.rs)."""
-    from ..observability.runtime_stats import current_collector
+    from ..observability.runtime_stats import current_collector, span_iter
 
     c = current_collector()
     gen = _exec_impl(node)
     if c is not None:
         gen = c.wrap(node, gen)
+    # timeline profiling: one span per physical operator, first pull to
+    # exhaustion, on whichever thread pulls it (`gen` itself with no recorder)
+    gen = span_iter("op." + node.name(), "host", gen)
     if isinstance(node, _STAGE_NODES) and _pipeline_on():
         from .pipeline import spawn_stage
 
@@ -59,6 +64,24 @@ def _exec(node: pp.PhysicalPlan) -> Iterator[MicroPartition]:
         # put-side backpressure to this operator (no-op without a collector)
         gen = spawn_stage(gen, node=node)
     return gen
+
+
+def _decide_span(decide):
+    """One `placement.decide` span over a decider's whole body (peek and
+    pricing included), carrying the tier it chose and whether the verdict
+    came from a cache. The deciders nest (`_select_mesh_tier` asks
+    `_mesh_wins`): a reader takes the union."""
+    @functools.wraps(decide)
+    def decided(*args, **kwargs):
+        with _profile_span("placement.decide", "host",
+                          decider=decide.__name__) as sp:
+            out = decide(*args, **kwargs)
+            if sp is not None:
+                sp.args["tier"] = str(out[0])
+                sp.args["cached"] = bool(getattr(out[-1], "cached", False))
+            return out
+
+    return decided
 
 
 def _pipeline_on() -> bool:
@@ -1457,6 +1480,7 @@ def _join_mesh_width(cfg) -> int:
     return ndev if ndev >= 2 else 0
 
 
+@_decide_span
 def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
                       topn: bool = False, label: str = "join agg",
                       coalesce: float = 1.0, mesh_ndev: int = 0,
@@ -1746,6 +1770,7 @@ def _invalidate_costed_verdicts() -> None:
 _costmodel.on_calibration_reset(_invalidate_costed_verdicts)
 
 
+@_decide_span
 def _select_mesh_tier(node, stream, grouped: bool, cfg):
     """Pick the mesh width for one device agg stage; 0 = single-chip.
 
@@ -1805,6 +1830,7 @@ def _select_mesh_tier(node, stream, grouped: bool, cfg):
     return (ndev if wins else 0), stream, rec
 
 
+@_decide_span
 def _mesh_wins(node, first: MicroPartition, grouped: bool, ndev: int):
     """Cost-model tier decision: mesh vs single-chip vs host for one stage
     shape. Mesh compute divides by the mesh width but pays a multi-device
@@ -2000,6 +2026,7 @@ def _exec_mesh_stage(node, stream, grouped: bool, n_devices: int, cfg,
     return MicroPartition(node.schema, [out.cast_to_schema(node.schema)])
 
 
+@_decide_span
 def _device_wins(node, first: MicroPartition, grouped: bool,
                  second: Optional[MicroPartition] = None,
                  forced: bool = False):

@@ -31,6 +31,7 @@ primitives:
 
 from __future__ import annotations
 
+import contextvars
 import threading
 import time
 from collections import deque
@@ -179,7 +180,10 @@ def spawn_stage(gen: Iterator, maxsize: int = 4, node=None) -> Iterator:
             ch.close(err)
 
     def consume():
-        threading.Thread(target=run, daemon=True, name="daft-stage").start()
+        # a copy of the puller's context: the stage's spans hang under the
+        # operator that started it
+        threading.Thread(target=contextvars.copy_context().run, args=(run,),
+                         daemon=True, name="daft-stage").start()
         yield from ch
 
     return consume()
@@ -198,34 +202,40 @@ def pmap_stream(stream: Iterator, fn: Callable, window: int = 0,
     (static mode) adds nothing to the per-morsel path.
 
     While a SpanRecorder is installed (timeline profiling) every morsel's
-    pool execution is additionally recorded as a "pipeline.morsel" span —
-    the recorder is captured here because pool workers are foreign threads.
+    pool execution is additionally recorded as a "pipeline.morsel" span, and
+    each morsel runs in a copy of the submitting context, so its spans hang
+    under the operator that fanned it out (pool workers are foreign threads:
+    they follow the process-global recorder slot).
     """
-    from ..observability.runtime_stats import current_spans
+    from ..observability.runtime_stats import current_spans, profile_span
     from ..utils.pool import compute_pool
 
     pool = compute_pool()
     if window <= 0:
         window = pool._max_workers
     spans = current_spans()
-    if strategy is not None or spans is not None:
-        inner = fn
+    if strategy is not None:
+        timed = fn
 
         def fn(item, i):  # noqa: F811 — timed wrapper around the caller's fn
             t0 = time.perf_counter()
-            w0 = time.time()
-            out = inner(item, i)
-            dt = time.perf_counter() - t0
-            if strategy is not None:
-                strategy.record(item.num_rows, dt)
-            if spans is not None:
-                spans.record("pipeline.morsel", "compute", w0, w0 + dt,
-                             {"rows": item.num_rows})
+            out = timed(item, i)
+            strategy.record(item.num_rows, time.perf_counter() - t0)
             return out
+    if spans is not None:
+        traced = fn
+
+        def fn(item, i):  # noqa: F811 — the morsel's span around the above
+            with profile_span("pipeline.morsel", "compute", rows=item.num_rows):
+                return traced(item, i)
     futs: deque = deque()
     try:
         for i, item in enumerate(stream):
-            futs.append(pool.submit(fn, item, i))
+            if spans is not None:
+                futs.append(pool.submit(contextvars.copy_context().run,
+                                        fn, item, i))
+            else:
+                futs.append(pool.submit(fn, item, i))
             if len(futs) >= window:
                 yield futs.popleft().result()
         while futs:

@@ -160,6 +160,8 @@ DEVICE_COUNTER_NAMES = (
     "hbm_eviction_bytes",      # device bytes released by evictions
     "hbm_pins",                # entries pinned by an executing query
     "hbm_h2d_bytes",           # host->device column upload bytes
+    "h2d_upload_us",           # host µs in column uploads (pad + device_put)
+    "dict_encode_us",          # host µs dictionary-encoding key columns (first touch)
     "hbm_stable_rehits",       # slots rebound by content identity (repeat sub-plans)
     "hbm_evict_cost_saved",    # µs of rebuild cost avoided vs pure-LRU eviction
     # distributed cache-affinity scheduling (distributed/scheduler.py)

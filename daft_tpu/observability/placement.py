@@ -369,7 +369,10 @@ class _TeeSpans(SpanRecorder):
         self._forward = forward
 
     def record(self, name, cat, t0, t1, args=None) -> None:
-        super().record(name, cat, t0, t1, args)
+        # keeps only what feedback prices (a run's other spans — the join's
+        # insides, launches, residency builds — must not fill the cap)
+        if _span_term(name) is not None:
+            super().record(name, cat, t0, t1, args)
         if self._forward is not None:
             self._forward.record(name, cat, t0, t1, args)
 

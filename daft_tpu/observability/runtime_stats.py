@@ -23,17 +23,30 @@ seconds == compute + starve + blocked by construction, so EXPLAIN ANALYZE's
 stall columns always reconcile with the self-time column.
 
 SpanRecorder is the timeline profiler's sink: coarse wall-clock spans
-(device dispatch, H2D/D2H transfer, coalescer flushes, shuffle fetches)
+(the query's life from plan to result encoding, device dispatch and what the
+host does inside it, H2D/D2H transfer, coalescer flushes, shuffle fetches)
 recorded by the engine only while a recorder is installed — the no-recorder
-path is a single attribute read, preserving the zero-overhead guarantee.
-One process-wide slot (like distributed.shuffle's ShuffleRecorder): workers
-run one task at a time and the driver profiles one query at a time.
+path is a single attribute read and one shared no-op context manager,
+preserving the zero-overhead guarantee. One process-wide slot (like
+distributed.shuffle's ShuffleRecorder): workers run one task at a time and
+the driver profiles one query at a time.
+
+Spans form one tree per query: every recorded span carries `id`, `parent`
+(the innermost span open in the same context, 0 for a root) and the `qid` of
+its query in its `args`; the sink's call stays record(name, cat, t0, t1,
+args). `t0`/`t1` are `time.time()`; for the same extent a
+`jax.profiler.TraceAnnotation` of the span's name is open, so a profiler
+capture holds the program's spans on its own clock above the device
+operations they caused.
 """
 
 from __future__ import annotations
 
+import contextvars
+import itertools
 import threading
 import time
+import uuid
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
@@ -199,6 +212,10 @@ _ACTIVE_SPANS: Optional[SpanRecorder] = None
 # themselves from a query being profiled elsewhere in the process (their
 # device spans must not bleed into that query's recorder, and vice versa)
 _UNSET = object()
+# how many span_scope() overrides are open on any thread: while it is 0 (and
+# no global recorder is set) profile_span answers without a thread-local read
+_SCOPED = 0
+_SCOPED_LOCK = threading.Lock()
 
 
 def current_spans() -> Optional[SpanRecorder]:
@@ -222,7 +239,10 @@ def span_scope(rec: Optional[SpanRecorder]):
     recorder never receives another tenant's spans. Spans recorded from
     pipeline stage/pool threads still follow the global slot — serving
     documents that per-query profiling is a serialized, opt-in path."""
+    global _SCOPED
     prev = getattr(_local, "spans", _UNSET)
+    with _SCOPED_LOCK:
+        _SCOPED += 1
     _local.spans = rec
     try:
         yield
@@ -231,46 +251,205 @@ def span_scope(rec: Optional[SpanRecorder]):
             del _local.spans
         else:
             _local.spans = prev
+        with _SCOPED_LOCK:
+            _SCOPED -= 1
 
 
-@contextmanager
+# ---- the span tree ------------------------------------------------------------------
+# (qid, id of the innermost open span) of the calling context. A contextvars
+# variable, not a thread-local: the threads a query starts (pipeline stage
+# threads, pool morsels) run in a copy of the context that started them, so
+# their spans hang under the operator that spawned them.
+_CURRENT: contextvars.ContextVar = contextvars.ContextVar(
+    "daft_tpu_span", default=None)
+_SPAN_IDS = itertools.count(1)
+_trace_annotation = None  # jax.profiler.TraceAnnotation, once a span records
+
+
+class _NoSpan:
+    """What `profile_span` returns while nothing records: one shared object,
+    no clock read, no allocation."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """One recording span. `open`/`close` take the clocks and give the span
+    its place in the tree (`id`, `parent`, `qid` in `args`); `__enter__`
+    additionally makes it the innermost open span of the context (`_ctx`).
+    With `rec=None` (`timed_span`, no recorder) it only measures `seconds`."""
+
+    __slots__ = ("_rec", "_name", "_cat", "args", "_t0", "_ctx", "_token",
+                 "_ann", "seconds")
+
+    def __init__(self, rec, name: str, cat: str, args: dict):
+        self._rec, self._name, self._cat, self.args = rec, name, cat, args
+        self._ann = self._token = None
+        self.seconds = 0.0
+
+    def open(self) -> "_Span":
+        if self._rec is not None:
+            global _trace_annotation
+            if _trace_annotation is None:
+                from jax.profiler import TraceAnnotation
+
+                _trace_annotation = TraceAnnotation
+            cur = _CURRENT.get()
+            args = self.args
+            # a query names its own qid; below an open span the tree's wins
+            qid = args.pop("qid", "")
+            if cur is not None:
+                qid = cur[0] or qid
+            sid = next(_SPAN_IDS)
+            args["id"], args["parent"], args["qid"] = \
+                sid, (cur[1] if cur is not None else 0), qid
+            self._ctx = (qid, sid)
+            # the same extent on the profiler's own clock: the span sits in
+            # the .xplane.pb above the device operations it caused
+            self._ann = _trace_annotation(self._name)
+            self._ann.__enter__()
+        self._t0 = time.time()
+        return self
+
+    def close(self) -> None:
+        t1 = time.time()
+        self.seconds = t1 - self._t0
+        if self._rec is not None:
+            self._ann.__exit__(None, None, None)
+            self._rec.record(self._name, self._cat, self._t0, t1, self.args)
+
+    def __enter__(self) -> "_Span":
+        self.open()
+        if self._rec is not None:
+            self._token = _CURRENT.set(self._ctx)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._token is not None:
+            _CURRENT.reset(self._token)
+        if exc_type is not None and self._rec is not None:
+            self.args["error"] = exc_type.__name__
+        self.close()
+        return False
+
+
 def profile_span(name: str, cat: str, **args):
     """Record the enclosed block as a timeline span when a SpanRecorder is
-    active; a no-op (no clock read, no record) otherwise. Used at COARSE
-    sites only (a device dispatch, a coalescer flush, a shuffle fetch),
-    never per row."""
+    active: `with profile_span(...) as sp` gives the span (`sp.args` takes
+    what is known only at the end) and makes it the parent of every span
+    opened inside, on this thread or on a thread started from it. With no
+    recorder it returns one shared no-op (`sp` is None): no clock read, no
+    record. Used at COARSE sites only (a device dispatch, a coalescer flush,
+    a shuffle fetch), never per row."""
+    if _ACTIVE_SPANS is None and not _SCOPED:
+        return _NO_SPAN
     rec = current_spans()
     if rec is None:
-        yield
+        return _NO_SPAN
+    return _Span(rec, name, cat, args)
+
+
+def timed_span(name: str, cat: str, **args) -> _Span:
+    """`profile_span` for the caller who needs the extent either way (an
+    event that carries it): always measures `.seconds`, records only while a
+    SpanRecorder is active."""
+    return _Span(current_spans(), name, cat, args)
+
+
+def record_span(name: str, cat: str, t0: float, t1: float, **args) -> None:
+    """Record a span whose extent was measured elsewhere (a listener told
+    how long a compile took), as a leaf under the context's open span."""
+    rec = current_spans()
+    if rec is None:
         return
-    t0 = time.time()
-    try:
-        yield
-    finally:
-        rec.record(name, cat, t0, time.time(), args or None)
+    cur = _CURRENT.get()
+    args["id"] = next(_SPAN_IDS)
+    args["parent"], args["qid"] = (cur[1], cur[0]) if cur is not None else (0, "")
+    rec.record(name, cat, t0, t1, args)
+
+
+def current_qid() -> str:
+    """The qid of the span tree open in this context ("" outside one)."""
+    cur = _CURRENT.get()
+    return cur[0] if cur is not None else ""
+
+
+class _QidScope:
+    __slots__ = ("_token",)
+
+    def __enter__(self):
+        self._token = _CURRENT.set((uuid.uuid4().hex[:12], 0))
+
+    def __exit__(self, *exc) -> bool:
+        _CURRENT.reset(self._token)
+        return False
+
+
+def qid_scope():
+    """Name a qid for the root spans opened inside (a query and the encoding
+    of its result share one) without opening a span. No-op with no recorder
+    or inside an open tree."""
+    if current_spans() is None or _CURRENT.get() is not None:
+        return _NO_SPAN
+    return _QidScope()
 
 
 def span_iter(name: str, cat: str, inner, **args):
     """Stream `inner` through as-is; while a SpanRecorder is active, record
     ONE span covering the whole consumption window (first pull to exhaustion
     or consumer close), with rows/batches accumulated into the span args on
-    top of the caller's. The no-recorder path delegates without timing —
-    the streaming counterpart of profile_span, shared by the shuffle
-    read/fetch sites."""
+    top of the caller's. The span is the context's innermost one during each
+    pull of `inner` and never across a yield, so nested streams and the
+    consumer's own spans keep their true parents. With no recorder this
+    returns `inner` itself — the streaming counterpart of profile_span,
+    shared by the operator, scan and shuffle read/fetch sites."""
+    if _ACTIVE_SPANS is None and not _SCOPED:
+        return inner
     rec = current_spans()
     if rec is None:
-        yield from inner
-        return
-    t0 = time.time()
+        return inner
+    return _span_iter(_Span(rec, name, cat, args), inner)
+
+
+def _span_iter(span: _Span, inner):
+    span.open()
     rows = batches = 0
+    it = iter(inner)
     try:
-        for part in inner:
+        while True:
+            # innermost during the pull, never across the yield
+            token = _CURRENT.set(span._ctx)
+            try:
+                part = next(it)
+            except StopIteration:
+                return
+            finally:
+                _CURRENT.reset(token)
             rows += part.num_rows
             batches += 1
             yield part
+    except Exception as e:
+        span.args["error"] = type(e).__name__
+        raise
     finally:
-        rec.record(name, cat, t0, time.time(),
-                   {**args, "rows": rows, "batches": batches})
+        close = getattr(it, "close", None)
+        if close is not None:  # a consumer that left early: unwind upstream
+            token = _CURRENT.set(span._ctx)  # inside the span, as exhaustion would
+            try:
+                close()
+            finally:
+                _CURRENT.reset(token)
+        span.args["rows"], span.args["batches"] = rows, batches
+        span.close()
 
 
 def format_stats(stats: List[OperatorStats], total_seconds: float) -> str:

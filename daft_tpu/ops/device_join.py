@@ -679,6 +679,11 @@ class _JoinContext:
         batch objects are transient, column Series are not). Chained dims
         additionally depend on the parent's idx array identity, so a parent
         rebuild invalidates the chain."""
+        with profile_span("join.index", "host", dim="*",
+                          bucket=batch.num_rows):
+            return self._indices_for(batch)
+
+    def _indices_for(self, batch) -> Dict[str, np.ndarray]:
         out: Dict[str, np.ndarray] = {}
         n = batch.num_rows
         for d in self.dims:
@@ -833,6 +838,10 @@ class _JoinContext:
         the indices, so the packed row-gather emits rows pre-sorted at zero
         extra cost. Under the Pallas gate the plain (un-permuted) plane is
         probed in-kernel instead; a kernel that does not lower raises."""
+        with profile_span("join.index", "host", dim=dname, bucket=bucket):
+            return self._dev_idx(batch, dname, bucket, perm)
+
+    def _dev_idx(self, batch, dname: str, bucket: int, perm):
         d = next(dd for dd in self.dims if dd.name == dname)
         anchor = self._probe_anchor(batch, d)
         n = batch.num_rows
@@ -841,7 +850,7 @@ class _JoinContext:
             interp = self._pallas_probe_gate(batch, d)
             if interp is not None:
                 return self._pallas_dev_idx(batch, d, bucket, interp)
-            idx_np = self.indices_for(batch)[dname]
+            idx_np = self._indices_for(batch)[dname]
 
             def build():
                 padded = np.full(bucket, -1, dtype=np.int32)
@@ -851,7 +860,7 @@ class _JoinContext:
             return series_keyed(anchor, ("didx", d.key_col, d.parent, bucket),
                                 (idx_np,), build, rebuild_rows=n)
 
-        idx_np = self.indices_for(batch)[dname]
+        idx_np = self._indices_for(batch)[dname]
         pperm_np, _pdev = perm
 
         def build_p():
@@ -1078,6 +1087,11 @@ class _JoinContext:
         plus the join-validity mask. Returns (dcols, code planes dict).
         With `perm` every plane comes back in group-sorted row order (the
         locally-dense aggregation layout) at no extra per-batch gathers."""
+        with profile_span("join.gather", "device", planes=len(needed)):
+            return self._provision(batch, bucket, needed, groupby_cols, perm)
+
+    def _provision(self, batch, bucket: int, needed: Sequence[str],
+                   groupby_cols: Sequence[str], perm):
         spec = self.spec
         dcols: Dict[str, dev.DCol] = {}
         code_out: Dict[str, object] = {}
@@ -1326,20 +1340,35 @@ class DeviceJoinGroupedRun(GroupedAggRun):
             node = g.child if isinstance(g, Alias) else g
             gb_cols.append(node._name)
 
-        total = None if self.force_host_codes else self._dict_product(batch, gb_cols)
+        total = None
+        if not self.force_host_codes:
+            with profile_span("join.codes", "host", strategy="dict",
+                              step="product"):
+                total = self._dict_product(batch, gb_cols)
         with profile_span("device.dispatch", "device", op="join_agg",
                           rows=n, bucket=bucket):
             if total is not None and 0 < total <= min(self.max_segments,
                                                       MAX_MATMUL_SEGMENTS):
                 dcols, code_planes = self.ctx.provision(batch, bucket, needed,
                                                         gb_cols)
-                decode = self._dict_combined_codes(batch, n, bucket, gb_cols,
-                                                   code_planes)
+                with profile_span("join.codes", "host", strategy="dict") as sp:
+                    decode = self._dict_combined_codes(batch, n, bucket,
+                                                       gb_cols, code_planes)
+                    if sp is not None:
+                        sp.args["cap"] = decode.cap
                 prog = stage._jit_for(decode.cap)
-                out = prog(dcols, decode.dcodes, device_row_mask(n, bucket),
-                           jnp.asarray(float(self._row_offset)))
+                mask = device_row_mask(n, bucket)
+                offset = jnp.asarray(float(self._row_offset))
+                with profile_span("device.launch", "device", op="join_agg",
+                                  cap=decode.cap):
+                    out = prog(dcols, decode.dcodes, mask, offset)
             else:
-                decode = self._host_factorized_codes(batch, n, bucket)
+                with profile_span("join.codes", "host", strategy="host") as sp:
+                    decode = self._host_factorized_codes(batch, n, bucket)
+                    if sp is not None:
+                        sp.args["cap"] = decode.cap
+                        if decode.permuted:
+                            sp.args["strategy"] = "host_permuted"
                 if decode.permuted:
                     if stage._sct_specs or stage._use_f64:
                         # statically incompatible with the local-dense program:
@@ -1351,13 +1380,19 @@ class DeviceJoinGroupedRun(GroupedAggRun):
                     dcols, _ = self.ctx.provision(batch, bucket, needed, (),
                                                   perm=(decode.pperm, pdev))
                     prog = stage._jit_local(decode.cap)
-                    out = prog(dcols, decode.local_codes, decode.seg_lo,
-                               device_row_mask(n, bucket))
+                    mask = device_row_mask(n, bucket)
+                    with profile_span("device.launch", "device",
+                                      op="join_agg_local", cap=decode.cap):
+                        out = prog(dcols, decode.local_codes, decode.seg_lo,
+                                   mask)
                 else:
                     dcols, _ = self.ctx.provision(batch, bucket, needed, ())
                     prog = stage._jit_for(decode.cap)
-                    out = prog(dcols, decode.dcodes, device_row_mask(n, bucket),
-                               jnp.asarray(float(self._row_offset)))
+                    mask = device_row_mask(n, bucket)
+                    offset = jnp.asarray(float(self._row_offset))
+                    with profile_span("device.launch", "device", op="join_agg",
+                                      cap=decode.cap):
+                        out = prog(dcols, decode.dcodes, mask, offset)
         decode.row_offset = float(self._row_offset)
         self._row_offset += n
         self._pending.append((out, decode))
@@ -1570,6 +1605,13 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
 
     def finalize_topn(self):
         """(key_rows, agg_results) for the K winners, in final output order."""
+        with profile_span("stage.finalize", "host", op="join_topn") as sp:
+            key_rows, results = self._finalize_topn()
+            if sp is not None:
+                sp.args["groups"] = len(key_rows)
+            return key_rows, results
+
+    def _finalize_topn(self):
         stage = self.stage
         pending, self._pending = self._pending, []
         self._row_offset = 0

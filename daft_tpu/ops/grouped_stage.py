@@ -883,10 +883,13 @@ class GroupedAggRun:
                      for name in stage._input_cols}
         with profile_span("device.dispatch", "device", op="grouped_agg",
                           rows=n, bucket=bucket, groups_cap=decode.cap):
+            mask = device_row_mask(n, bucket)
+            offset = jnp.asarray(float(self._row_offset))
             # a Pallas program that does not lower raises here: no tier
             # replaces it behind the caller's back
-            out = prog(dcols, decode.dcodes, device_row_mask(n, bucket),
-                       jnp.asarray(float(self._row_offset)))
+            with profile_span("device.launch", "device", op="grouped_agg",
+                              cap=decode.cap):
+                out = prog(dcols, decode.dcodes, mask, offset)
         if use_pallas:
             counters.bump("pallas_dispatches")
         self._row_offset += n
@@ -961,6 +964,13 @@ class GroupedAggRun:
         Group order matches the host engine: first occurrence in the stream
         (reconstructed from the on-device first-row-index plane).
         """
+        with profile_span("stage.finalize", "host", op="grouped_agg") as sp:
+            key_rows, results = self._finalize()
+            if sp is not None:
+                sp.args["groups"] = len(key_rows)
+            return key_rows, results
+
+    def _finalize(self):
         stage = self.stage
         pending, self._pending = self._pending, []
         self._row_offset = 0

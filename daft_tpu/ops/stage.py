@@ -188,7 +188,11 @@ class FilterAggRun:
     def _run(self, dcols: Dict[str, dev.DCol], n: int, bucket: int) -> None:
         with profile_span("device.dispatch", "device", op="filter_agg",
                           rows=n, bucket=bucket):
-            res = self.stage._jit_for(bucket)(dcols, device_row_mask(n, bucket))
+            prog = self.stage._jit_for(bucket)
+            mask = device_row_mask(n, bucket)
+            with profile_span("device.launch", "device", op="filter_agg",
+                              bucket=bucket):
+                res = prog(dcols, mask)
         counters.bump("device_stage_batches")
         self._device_partials.append(res)  # stays on device; fetched at finalize
 
@@ -218,6 +222,10 @@ class FilterAggRun:
         self._run(dcols, n, bucket)
 
     def finalize(self) -> Dict[str, Optional[float]]:
+        with profile_span("stage.finalize", "host", op="filter_agg", groups=1):
+            return self._finalize()
+
+    def _finalize(self) -> Dict[str, Optional[float]]:
         with profile_span("device.d2h", "device", op="filter_agg",
                           batches=len(self._device_partials)):
             fetched = [

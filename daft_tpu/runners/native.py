@@ -21,20 +21,37 @@ class Runner:
 
 class NativeRunner(Runner):
     def run_iter(self, builder: LogicalPlanBuilder) -> Iterator[MicroPartition]:
+        """The query's stream. While a SpanRecorder is installed it is the
+        `query` span, the root of the query's span tree: from the first pull
+        to the stream's end, with rows out and the error it died of."""
+        from ..observability.runtime_stats import current_spans, span_iter
+
+        stream = self._run_iter(builder)
+        if current_spans() is None:
+            return stream
+        import uuid
+
+        return span_iter("query", "query", stream, qid=uuid.uuid4().hex[:12])
+
+    def _run_iter(self, builder: LogicalPlanBuilder) -> Iterator[MicroPartition]:
         import time
         import uuid
 
         from ..execution.executor import execute_plan
         from ..observability import (QueryEnd, QueryOptimized, QueryStart,
                                      flight, notify, subscribers_active)
-        from ..observability.runtime_stats import StatsCollector, set_collector
+        from ..observability.runtime_stats import (StatsCollector, current_qid,
+                                                   profile_span, set_collector,
+                                                   timed_span)
         from ..plan.physical import translate
 
         observed = subscribers_active()
         # the flight recorder records EVERY query (bounded ring, anomaly
         # triggers), not just subscriber-observed ones; None when disabled
         frec = flight.recorder()
-        qid = uuid.uuid4().hex[:12] if (observed or frec is not None) else ""
+        # one id for the query's events and its span tree
+        qid = current_qid() or (
+            uuid.uuid4().hex[:12] if (observed or frec is not None) else "")
         t_start = time.perf_counter()
         reg_before = {}
         if observed or frec is not None:
@@ -46,14 +63,18 @@ class NativeRunner(Runner):
             reg_before = registry().snapshot()
         if observed:
             notify("on_query_start", QueryStart(qid, builder.plan.display()))
-        t0 = time.perf_counter()
-        optimized = builder.optimize()
-        phys = translate(optimized.plan)
+        # QueryOptimized carries the plan.* extents: taken either way when a
+        # subscriber listens, only while a recorder is installed otherwise
+        span = timed_span if observed else profile_span
+        with span("plan.optimize", "plan") as sp_opt:
+            optimized = builder.optimize()
+        with span("plan.translate", "plan") as sp_tr:
+            phys = translate(optimized.plan)
         fkey = flight.plan_key(phys.display()) if frec is not None else ""
         if observed:
             notify("on_query_optimized", QueryOptimized(
                 qid, optimized.plan.display(), phys.display(),
-                time.perf_counter() - t0))
+                sp_opt.seconds + sp_tr.seconds))
         from ..observability import placement
         from ..observability.runtime_stats import current_collector
 

@@ -8,8 +8,10 @@ opt into bf16/f32 explicitly where precision allows (SURVEY.md §7 MXU notes).
 from __future__ import annotations
 
 import os
+import time
 
 import jax
+import jax.monitoring
 
 jax.config.update("jax_enable_x64", True)
 
@@ -30,6 +32,22 @@ def compile_cache_dir() -> str:
 
 if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
     jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE)
+
+
+def _compile_span(event: str, seconds: float, **_kw) -> None:
+    """JAX's own report of one XLA compilation, as an `xla.compile` span
+    (end = now) while a SpanRecorder is installed: a timeline then shows
+    which dispatch recompiled. Off, the listener is one recorder look-up."""
+    if event != "/jax/core/compile/backend_compile_duration":
+        return
+    from ..observability import runtime_stats
+
+    if runtime_stats.current_spans() is not None:
+        now = time.time()
+        runtime_stats.record_span("xla.compile", "compile", now - seconds, now)
+
+
+jax.monitoring.register_event_duration_secs_listener(_compile_span)
 
 
 def get_jax():

@@ -148,7 +148,9 @@ def test_span_recorder_profile_span_and_cap():
     assert current_spans() is None
     spans = rec.drain()
     assert [s["name"] for s in spans] == ["a", "b"]
-    assert spans[0]["args"] == {"rows": 5}
+    # the caller's arguments, plus the span's place in the tree (ISSUE 25)
+    assert spans[0]["args"] == {"rows": 5, "id": spans[0]["args"]["id"],
+                                "parent": 0, "qid": ""}
     assert rec.dropped == 1
     # no recorder active: profile_span must not record anywhere
     with profile_span("ghost", "device"):
@@ -175,6 +177,312 @@ def test_device_stage_records_dispatch_spans():
     names = {s["name"] for s in rec.drain()}
     assert "device.dispatch" in names, names
     assert "device.d2h" in names, names
+
+
+# ---------------------------------------------------------------------------
+# The span tree (ISSUE 25): id / parent / qid, the query's life, the inside of
+# the join dispatch, the off path, the profiler's own clock
+# ---------------------------------------------------------------------------
+
+def _star_join_frames(n=20_000):
+    rng = np.random.default_rng(7)
+    fact = daft_tpu.from_pydict({
+        "fk": rng.integers(0, 100, n).tolist(),
+        "v": rng.uniform(0, 1, n).tolist()}).collect()
+    dim = daft_tpu.from_pydict({
+        "k": list(range(100)), "g": [i % 5 for i in range(100)]}).collect()
+    return fact, dim
+
+
+def _star_join(fact, dim):
+    return (fact.join(dim, left_on="fk", right_on="k").groupby("g")
+            .agg(col("v").sum().alias("s")).sort("g"))
+
+
+def _record_star_join(monkeypatch, pipeline_mode):
+    """The spans of one forced-device star join (second run: planes resident,
+    programs compiled), priced although forced so that a decider runs."""
+    monkeypatch.setenv("DAFT_TPU_PLACEMENT_PRICE_FORCED", "1")
+    fact, dim = _star_join_frames()
+    rec = SpanRecorder()
+    with execution_config_ctx(device_mode="on", device_min_rows=1,
+                              mesh_devices=1, pipeline_mode=pipeline_mode):
+        expect = _star_join(fact, dim).to_pydict()
+        set_spans(rec)
+        try:
+            got = _star_join(fact, dim).to_pydict()
+        finally:
+            set_spans(None)
+    assert got == expect
+    assert rec.dropped == 0
+    return rec.drain()
+
+
+@pytest.mark.parametrize("pipeline_mode", ["off", "force"])
+def test_star_join_span_tree(monkeypatch, pipeline_mode):
+    """One `query` root; its descendants are the query's life and the inside
+    of the join dispatch; every span carries qid, id and parent, and a child
+    lies within its parent. Under pipeline_mode="force" the operators run on
+    stage threads, which inherit the context that started them."""
+    spans = _record_star_join(monkeypatch, pipeline_mode)
+    by_id = {s["args"]["id"]: s for s in spans}
+    assert len(by_id) == len(spans)
+    for s in spans:
+        assert {"id", "parent", "qid"} <= set(s["args"]), s
+    roots = [s for s in spans if s["args"]["parent"] == 0]
+    assert sorted(s["name"] for s in roots) == ["query", "result.encode"]
+    query = next(s for s in roots if s["name"] == "query")
+    qid = query["args"]["qid"]
+    assert qid and all(s["args"]["qid"] == qid for s in spans)
+    assert query["args"]["rows"] == 5 and "error" not in query["args"]
+
+    def ancestors(s):
+        while s["args"]["parent"]:
+            s = by_id[s["args"]["parent"]]
+            yield s
+
+    eps = 1e-6  # time.time() pairs taken microseconds apart
+    for s in spans:
+        if s["args"]["parent"]:
+            p = by_id[s["args"]["parent"]]
+            assert p["ts"] - eps <= s["ts"], (s, p)
+            assert s["ts"] + s["dur"] <= p["ts"] + p["dur"] + eps, (s, p)
+    under_query = {s["name"] for s in spans
+                   if any(a is query for a in ancestors(s))}
+    assert {"plan.optimize", "plan.translate", "placement.decide",
+            "device.dispatch", "join.codes", "join.index", "join.gather",
+            "device.launch", "stage.finalize", "device.d2h"} <= under_query
+    assert any(n.startswith("op.DeviceJoinAgg") for n in under_query)
+    # the inside of the dispatch hangs under the dispatch
+    dispatch = next(s for s in spans if s["name"] == "device.dispatch")
+    inside = {s["name"] for s in spans
+              if any(a is dispatch for a in ancestors(s))}
+    assert {"join.codes", "join.index", "join.gather", "device.launch"} <= inside
+    decide = next(s for s in spans if s["name"] == "placement.decide")
+    assert decide["args"]["decider"] == "_join_device_wins"
+    assert {"tier", "cached"} <= set(decide["args"])
+    finalize = next(s for s in spans if s["name"] == "stage.finalize")
+    assert finalize["args"]["groups"] == 5
+    d2h = next(s for s in spans if s["name"] == "device.d2h")
+    assert d2h["args"]["parent"] == finalize["args"]["id"]
+    encode = next(s for s in roots if s["name"] == "result.encode")
+    assert encode["args"]["rows"] == 5 and encode["ts"] >= query["ts"] + query["dur"] - eps
+
+
+def test_first_touch_spans_and_counters():
+    """A first run over fresh tables uploads planes and dictionary-encodes
+    the group key: `device.upload`, `series.dict_encode` and `residency.build`
+    spans (with bytes and the slot kind), and the two always-on counters."""
+    from daft_tpu.observability.metrics import registry
+
+    df = daft_tpu.from_pydict({
+        "k": [f"k{i % 7}" for i in range(5_000)],
+        "v": [float(i) for i in range(5_000)]}).collect()
+    rec = SpanRecorder()
+    before = registry().snapshot()
+    set_spans(rec)
+    try:
+        with execution_config_ctx(device_mode="on", device_min_rows=1,
+                                  mesh_devices=1):
+            df.groupby("k").agg(col("v").sum().alias("s")).to_pydict()
+    finally:
+        set_spans(None)
+    spans = rec.drain()
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    up = by_name["device.upload"][0]["args"]
+    assert up["rows"] == 5_000 and up["bytes"] > 0 and up["dtype"]
+    enc = by_name["series.dict_encode"][0]["args"]
+    assert enc["rows"] == 5_000 and enc["cardinality"] == 7
+    builds = {s["args"]["slot"] for s in by_name["residency.build"]}
+    assert "col" in builds
+    assert all(s["args"]["bytes"] >= 0 for s in by_name["residency.build"])
+    # an upload is the child of the residency build that missed
+    build_ids = {s["args"]["id"] for s in by_name["residency.build"]}
+    assert all(s["args"]["parent"] in build_ids for s in by_name["device.upload"])
+    diff = registry().diff(before)
+    assert diff["h2d_upload_us"] > 0 and diff["dict_encode_us"] > 0
+
+
+NEW_SPAN_NAMES = [
+    "query", "plan.optimize", "plan.translate", "placement.decide",
+    "op.DeviceJoinAgg(1 dims)", "stage.finalize", "result.encode",
+    "join.codes", "join.index", "join.gather", "device.launch",
+    "residency.build", "device.upload", "series.dict_encode", "xla.compile",
+]
+
+
+@pytest.mark.parametrize("name", NEW_SPAN_NAMES)
+def test_new_span_names_are_not_cost_model_terms(name):
+    """placement.feedback sums device.*h2d|dispatch|d2h spans into observed
+    seconds: a span nested inside one of those must never map to a term, or
+    its time would be counted twice."""
+    from daft_tpu.observability.placement import _span_term
+
+    assert _span_term(name) is None
+
+
+def test_feedback_tee_keeps_only_priced_spans():
+    """The placement feedback's own recorder stores what it prices and
+    forwards everything: the join's inner spans cannot fill its cap."""
+    from daft_tpu.observability.placement import _TeeSpans
+
+    outer = SpanRecorder()
+    tee = _TeeSpans(outer)
+    for name in ("device.dispatch", "join.gather", "device.launch", "device.h2d"):
+        tee.record(name, "device", 1.0, 2.0, {"id": 1})
+    assert [s["name"] for s in tee.drain()] == ["device.dispatch", "device.h2d"]
+    assert len(outer.drain()) == 4
+
+
+def test_off_path_no_clock_no_record_no_counter(monkeypatch):
+    """No recorder: profile_span hands out ONE shared no-op, span_iter hands
+    back its input, runtime_stats never reads time.time(), and a host-only
+    query leaves the registry as it was."""
+    from daft_tpu.observability import runtime_stats as rs
+    from daft_tpu.observability.metrics import registry
+
+    assert current_spans() is None
+    a = profile_span("a", "device", rows=1)
+    assert a is profile_span("b", "io") is rs._NO_SPAN
+    with a as sp:
+        assert sp is None
+    stream = iter([_Part()])
+    assert rs.span_iter("op.X", "host", stream) is stream
+
+    class _Clock:
+        perf_counter = staticmethod(time.perf_counter)
+
+        @staticmethod
+        def time():
+            raise AssertionError("time.time() read on the off path")
+
+    monkeypatch.setattr(rs, "time", _Clock)
+    df = daft_tpu.from_pydict({"k": [1, 2, 1, 2], "v": [1.0, 2.0, 3.0, 4.0]})
+    before = registry().snapshot()
+    with execution_config_ctx(device_mode="off"):
+        out = df.where(col("v") > 1).groupby("k").agg(
+            col("v").sum().alias("s")).sort("k").to_pydict()
+    assert out == {"k": [1, 2], "s": [3.0, 6.0]}
+    assert set(registry().diff(before)) <= {"h2d_upload_us", "dict_encode_us"}
+
+
+def test_span_iter_records_error_and_closes_upstream():
+    closed = []
+
+    def inner():
+        try:
+            yield _Part()
+            raise ValueError("boom")
+        finally:
+            closed.append(True)
+
+    rec = SpanRecorder()
+    set_spans(rec)
+    try:
+        from daft_tpu.observability.runtime_stats import span_iter
+
+        with pytest.raises(ValueError):
+            list(span_iter("op.Bad", "host", inner(), tag="x"))
+        # a consumer that leaves early unwinds the upstream generator
+        it = span_iter("op.Early", "host", inner())
+        next(it)
+        it.close()
+    finally:
+        set_spans(None)
+    bad, early = rec.drain()
+    assert bad["args"]["error"] == "ValueError" and bad["args"]["tag"] == "x"
+    assert bad["args"]["rows"] == 1 and bad["args"]["batches"] == 1
+    assert "error" not in early["args"] and early["args"]["rows"] == 1
+    assert closed == [True, True]
+
+
+def test_timed_span_measures_without_a_recorder_and_feeds_query_optimized():
+    """QueryOptimized.seconds comes from the plan.* extents, taken whether or
+    not a recorder is installed."""
+    from daft_tpu.observability import attach_subscriber, detach_subscriber
+    from daft_tpu.observability.runtime_stats import timed_span
+    from daft_tpu.observability.subscribers import Subscriber
+
+    assert current_spans() is None
+    with timed_span("plan.optimize", "plan") as sp:
+        time.sleep(0.002)
+    assert sp.seconds >= 0.002
+
+    class Sub(Subscriber):
+        def __init__(self):
+            self.optimized = []
+
+        def on_query_optimized(self, ev):
+            self.optimized.append(ev)
+
+    sub = Sub()
+    attach_subscriber(sub)
+    try:
+        daft_tpu.from_pydict({"a": [1, 2, 3]}).where(col("a") > 1).to_pydict()
+    finally:
+        detach_subscriber(sub)
+    assert len(sub.optimized) == 1 and 0 < sub.optimized[0].optimize_seconds < 5
+
+
+def test_xla_compile_span_under_a_recorder():
+    """A compilation while a recorder is installed leaves an `xla.compile`
+    span (JAX's own duration, ending when the listener hears of it) under the
+    span that was open."""
+    import jax
+    import jax.numpy as jnp
+
+    from daft_tpu.utils import jax_setup  # noqa: F401  (registers the listener)
+
+    rec = SpanRecorder()
+    set_spans(rec)
+    try:
+        with profile_span("device.dispatch", "device") as outer:
+            jax.jit(lambda x: x * 3 + 17)(jnp.arange(7)).block_until_ready()
+    finally:
+        set_spans(None)
+    spans = rec.drain()
+    compiles = [s for s in spans if s["name"] == "xla.compile"]
+    assert compiles, [s["name"] for s in spans]
+    assert all(s["args"]["parent"] == outer.args["id"] and s["dur"] > 0
+               for s in compiles)
+    # off: the listener records nowhere
+    jax.jit(lambda x: x * 5 + 19)(jnp.arange(7)).block_until_ready()
+    assert rec.drain() == []
+
+
+def test_profiler_capture_holds_the_programs_spans(tmp_path):
+    """Under jax.profiler (CPU backend) the written .xplane.pb holds the
+    program's spans as events on the profiler's own clock."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    rng = np.random.default_rng(3)
+    df = daft_tpu.from_pydict({
+        "k": rng.integers(0, 4, 10_000).tolist(),
+        "v": rng.uniform(0, 1, 10_000).tolist()}).collect()
+    q = lambda: df.groupby("k").agg(col("v").sum().alias("s")).to_pydict()  # noqa: E731
+    with execution_config_ctx(device_mode="on", device_min_rows=1, mesh_devices=1):
+        q()
+        rec = SpanRecorder()
+        set_spans(rec)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            q()
+        finally:
+            jax.profiler.stop_trace()
+            set_spans(None)
+    recorded = {s["name"] for s in rec.drain()}
+    files = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    assert files
+    events = {e.name for plane in ProfileData.from_file(files[-1]).planes
+              for line in plane.lines for e in line.events}
+    assert "device.dispatch" in events
+    assert {"query", "plan.optimize", "device.launch", "stage.finalize",
+            "result.encode"} <= events & recorded
 
 
 # ---------------------------------------------------------------------------
