@@ -33,9 +33,12 @@ class Series:
     # process-wide HBM residency manager (daft_tpu/device/residency.py), keyed
     # by _rtoken — a monotonic identity token that, unlike id(), is never
     # reused after GC. __weakref__ lets the manager drop entries when the
-    # Series dies.
+    # Series dies. _root/_roff are the lineage of a zero-copy view (slice):
+    # the unsliced column it views and the row it starts at. The manager keys
+    # a view's slots on (root, offset, length), so a fresh morsel of a
+    # resident column finds what its rows built on the last query.
     __slots__ = ("_name", "_dtype", "_arrow", "_pyobjs", "_device_cache",
-                 "_dict_codes", "_rtoken", "__weakref__")
+                 "_dict_codes", "_rtoken", "_root", "_roff", "__weakref__")
 
     def __init__(self, name: str, dtype: DataType, arrow: Optional[pa.Array], pyobjs: Optional[list] = None):
         self._name = name
@@ -330,7 +333,8 @@ class Series:
 
     def __getstate__(self):
         """Pickle for cross-process shipping (distributed tasks/UDF workers):
-        device residency and dictionary caches are process-local — drop them."""
+        device residency, dictionary caches and a view's lineage are
+        process-local — drop them."""
         return (self._name, self._dtype, self._arrow, self._pyobjs)
 
     def __setstate__(self, state):
@@ -443,7 +447,25 @@ class Series:
     def slice(self, start: int, end: int) -> "Series":
         if self._pyobjs is not None:
             return Series(self._name, self._dtype, None, self._pyobjs[start:end])
-        return Series(self._name, self._dtype, self._arrow.slice(start, end - start))
+        out = Series(self._name, self._dtype, self._arrow.slice(start, end - start))
+        # a zero-copy view remembers what it views: a slice of a slice
+        # composes to the same root (python-object columns copy: no lineage)
+        root, base = self.lineage()
+        out._root = root
+        out._roff = base + min(start, len(self))  # Arrow clamps the same way
+        return out
+
+    def lineage(self):
+        """(root, offset): the unsliced column this Series views zero-copy
+        and the row of it the view starts at; (self, 0) for a column that is
+        no view. Only `slice` of an Arrow-backed column makes a view: take,
+        filter, cast and computed expressions make new data. Process-local,
+        dropped on pickle. device/residency.py keys slots on it and `concat`
+        glues contiguous views back to their root."""
+        root = getattr(self, "_root", None)
+        if root is None:
+            return self, 0
+        return root, self._roff
 
     def head(self, n: int) -> "Series":
         return self.slice(0, min(n, len(self)))
@@ -476,6 +498,19 @@ class Series:
             for s in series_list:
                 objs.extend(s._pyobjs)
             return cls(first._name, first._dtype, None, objs)
+        # views of one root that tile a contiguous range in order glue back to
+        # that range without a copy: the root itself when they cover it, with
+        # its dictionary codes and residency slots
+        root, start = first.lineage()
+        pos = start
+        for s in series_list:
+            r, off = s.lineage()
+            if r is not root or off != pos:
+                break
+            pos += len(s)
+        else:
+            whole = start == 0 and pos == len(root)
+            return root if whole else root.slice(start, pos)
         return cls(first._name, first._dtype, _combine(pa.concat_arrays([s._arrow for s in series_list])))
 
     # ---- casts --------------------------------------------------------------------

@@ -12,13 +12,19 @@ some back.
 
 Design:
 
-- Entries are keyed by (anchor Series identity token, structural key). The
-  anchor is the long-lived Series the cached value derives from; the token is
-  a monotonic int (never reused, unlike CPython ``id``). Entries additionally
-  carry a ``deps`` tuple compared by object IDENTITY on lookup (the
-  series_keyed contract from ops/device_join.py: strong refs held in the
-  entry, so a freed object can never alias a new one) and an optional
-  ``literals`` tuple compared by VALUE — query-shape caches key on the filter
+- Entries are keyed by (identity of the anchor's DATA, structural key). The
+  anchor is the Series the cached value derives from. A column that is no
+  view is identified by its token: a monotonic int (never reused, unlike
+  CPython ``id``). A zero-copy view of a column (``Series.slice``: the
+  morsels a pipeline cuts a resident table into on every query) is
+  identified by its lineage, (token of the root column, row offset, length),
+  so a fresh view object finds the slots its rows built before, and an entry
+  lives as long as the ROOT does. Entries additionally carry a ``deps``
+  tuple compared on lookup: Series by the same data identity (stored as
+  tokens, which are never reused, so nothing is kept alive and a freed
+  object can never alias a new one), anything else by object IDENTITY with a
+  strong ref held in the entry. An optional ``literals`` tuple is compared by
+  VALUE — query-shape caches key on the filter
   STRUCTURE and store the literals, so a session issuing the same query with
   varying predicate literals reuses one slot per shape instead of
   accumulating one entry per literal (ADVICE r5 medium).
@@ -53,7 +59,9 @@ Design:
   evicting buffers an in-flight program still needs (and the byte accounting
   staying honest while it happens).
 
-- Observability: hbm_cache_hits / hbm_cache_misses / hbm_evictions /
+- Observability: hbm_cache_hits / hbm_cache_misses / hbm_lineage_hits (hits
+  under another object than the one the entry was built under: what only
+  lineage could find) / hbm_evictions /
   hbm_eviction_bytes / hbm_pins counters plus hbm_bytes_resident /
   hbm_bytes_high_water gauges in the process metrics registry
   (observability/metrics.py), so per-query deltas land in QueryEnd.metrics,
@@ -61,7 +69,8 @@ Design:
 
 Zero-overhead contract: a host-only query never touches the manager (nothing
 imports jax here; entries only appear when a device path uploads), and lookup
-cost is one dict probe + identity compares.
+cost is one dict probe + identity compares: a column's content is hashed
+(stable keys) only once the identity probe has missed.
 """
 
 from __future__ import annotations
@@ -101,6 +110,49 @@ def identity_token(obj) -> int:
                 # object without the slot: degrade to id() (advisory callers only)
                 return id(obj)
         return tok
+
+
+def data_identity(obj):
+    """(owner, ident) of the data `obj` holds. A zero-copy view of a column
+    (``Series.lineage``) is identified by (token of its root, offset, length)
+    and owned by the root; a view of all of its root, and any object that is
+    no view, by the token of the column itself. `ident` is what slots are
+    keyed on and Series deps compared by; `owner` is the object whose
+    lifetime an entry follows."""
+    lineage = getattr(obj, "lineage", None)
+    if lineage is not None:
+        root, off = lineage()
+        if root is not obj:
+            n = len(obj)
+            if off == 0 and n == len(root):
+                return root, identity_token(root)
+            return root, (identity_token(root), off, n)
+    return obj, identity_token(obj)
+
+
+class _SeriesDep:
+    """A Series among an entry's deps, held as the identity of its data:
+    tokens are never reused, so no strong ref is needed and none is kept."""
+
+    __slots__ = ("ident",)
+
+    def __init__(self, ident):
+        self.ident = ident
+
+
+def _dep_identities(deps) -> tuple:
+    """Deps as an entry stores and compares them: a Series by the identity
+    of its data, anything else (cached index arrays, tables) as itself."""
+    return tuple(_SeriesDep(data_identity(d)[1])
+                 if getattr(d, "lineage", None) is not None else d
+                 for d in deps)
+
+
+def _same_deps(stored: tuple, deps: tuple) -> bool:
+    return len(stored) == len(deps) and all(
+        a is b or (type(a) is _SeriesDep and type(b) is _SeriesDep
+                   and a.ident == b.ident)
+        for a, b in zip(stored, deps))
 
 
 # ---- expression structure keys -----------------------------------------------------
@@ -262,7 +314,7 @@ def set_pin_observation(obs: Optional["_PinObservation"]) -> None:
 
 class _Entry:
     __slots__ = ("deps", "literals", "value", "nbytes", "pins", "anchor_ref",
-                 "stable", "cost")
+                 "built_ref", "stable", "cost")
 
     def __init__(self, deps: tuple, literals, value, nbytes: int,
                  stable: Optional[int] = None, cost: float = 0.0):
@@ -272,6 +324,7 @@ class _Entry:
         self.nbytes = nbytes
         self.pins = 0
         self.anchor_ref = None  # keeps the death-callback weakref alive
+        self.built_ref = None   # the object built under (hbm_lineage_hits)
         self.stable = stable    # cross-process slot key (None = identity-only)
         self.cost = cost        # estimated rebuild seconds (eviction ordering)
 
@@ -310,9 +363,13 @@ class ResidencyManager:
                      rebuild_rows: int = 0):
         """Return the cached value for (anchor, key), building it when absent.
 
-        Hit requires every object in `deps` IDENTICAL to the stored tuple and
-        `literals` EQUAL to the stored ones; a mismatch rebuilds in place —
-        the slot is reused, never duplicated.
+        The slot is found by the identity of the anchor's DATA: a zero-copy
+        view (a morsel of a resident column) by (root, offset, length), so a
+        fresh view object of the same rows hits; anything else by its token.
+        Hit requires every Series in `deps` to hold the same data by that
+        rule, every other dep IDENTICAL to the stored one, and `literals`
+        EQUAL to the stored ones; a mismatch rebuilds in place — the slot is
+        reused, never duplicated.
 
         Deps-free slots (column planes, dictionary-code planes — values that
         are pure functions of the anchor's content) additionally carry a
@@ -325,27 +382,23 @@ class ResidencyManager:
         `rebuild_rows` is the host-side row count the build re-factorizes
         (dictionary codes, join indices); with the entry's device bytes it
         prices the rebuild for cost-weighted eviction."""
-        full_key = (identity_token(anchor), key)
-        deps = tuple(deps)
-        stable = stable_slot_key(anchor, key) if not deps else None
+        owner, ident = data_identity(anchor)
+        full_key = (ident, key)
+        deps = _dep_identities(deps)
         with self._lock:
             self._sweep_dead()
             e = self._entries.get(full_key)
-            if e is not None and len(e.deps) == len(deps) \
-                    and all(a is b for a, b in zip(e.deps, deps)) \
+            if e is not None and _same_deps(e.deps, deps) \
                     and e.literals == literals:
-                # hit: re-measure (values may have lazily grown device planes)
-                nb = device_nbytes(e.value)
-                if nb != e.nbytes:
-                    self._bytes += nb - e.nbytes
-                    e.nbytes = nb
-                    self._note_bytes()
-                self._entries.move_to_end(full_key)
-                self._pin(full_key, e)
-                registry().inc("hbm_cache_hits")
-                return e.value
-            if stable is not None:
-                e = self._stable_rebind(stable, full_key, anchor, literals)
+                return self._hit(full_key, e, anchor)
+        # only now, the identity probe having missed, is the column hashed:
+        # a hit never pays for a fingerprint (outside the lock: it reads the
+        # whole column)
+        stable = stable_slot_key(anchor, key) if not deps else None
+        if stable is not None:
+            with self._lock:
+                e = self._stable_rebind(stable, full_key, owner, anchor,
+                                        literals)
                 if e is not None:
                     registry().inc("hbm_cache_hits")
                     registry().inc("hbm_stable_rehits")
@@ -384,13 +437,30 @@ class ResidencyManager:
                 self._stable[stable] = full_key
             self._entries[full_key] = e
             self._bytes += nb
-            self._watch_anchor(anchor, full_key, e)
+            self._watch_anchor(owner, anchor, full_key, e)
             self._pin(full_key, e)
             self._note_bytes()
             self._evict_over_budget()
         return value
 
-    def _stable_rebind(self, stable: int, full_key: tuple, anchor,
+    def _hit(self, full_key: tuple, e: _Entry, anchor):
+        """The identity probe found the entry (lock held): re-measure (values
+        may have lazily grown device planes), touch, pin, count."""
+        nb = device_nbytes(e.value)
+        if nb != e.nbytes:
+            self._bytes += nb - e.nbytes
+            e.nbytes = nb
+            self._note_bytes()
+        self._entries.move_to_end(full_key)
+        self._pin(full_key, e)
+        registry().inc("hbm_cache_hits")
+        if e.built_ref is not None and e.built_ref() is not anchor:
+            # another object than the one the entry was built under viewing
+            # the same rows: a hit only lineage could find
+            registry().inc("hbm_lineage_hits")
+        return e.value
+
+    def _stable_rebind(self, stable: int, full_key: tuple, owner, anchor,
                        literals) -> Optional[_Entry]:
         """Move a deps-free entry with matching content identity to a new
         anchor (called under the lock). Returns the entry on success."""
@@ -411,7 +481,7 @@ class ResidencyManager:
             self._bytes += nb - e.nbytes
             e.nbytes = nb
             self._note_bytes()
-        self._watch_anchor(anchor, full_key, e)
+        self._watch_anchor(owner, anchor, full_key, e)
         self._pin(full_key, e)
         return e
 
@@ -419,11 +489,9 @@ class ResidencyManager:
         """Advisory residency probe for the cost model (no deps/literal check,
         no LRU touch, no counters): True when a buffer for this slot is
         currently registered, i.e. the h2d transfer for it is already paid."""
-        tok = getattr(anchor, "_rtoken", None)
-        if tok is None:
-            return False
+        full_key = (data_identity(anchor)[1], key)
         with self._lock:
-            return (tok, key) in self._entries
+            return full_key in self._entries
 
     # ---- pinning -------------------------------------------------------------------
     @contextlib.contextmanager
@@ -653,7 +721,12 @@ class ResidencyManager:
         registry().set_gauge("hbm_bytes_high_water", float(self._high_water))
 
     # ---- anchor lifetime -----------------------------------------------------------
-    def _watch_anchor(self, anchor, full_key: tuple, e: _Entry) -> None:
+    def _watch_anchor(self, owner, anchor, full_key: tuple,
+                      e: _Entry) -> None:
+        """The entry goes when `owner` is collected: the anchor itself, or
+        the root column a view anchor views (a morsel object may die, its
+        rows stay resident). `anchor` is remembered weakly as the object the
+        entry was built (or rebound) under."""
         dead = self._dead
 
         def _on_collect(_ref, _key=full_key, _dead=dead):
@@ -662,7 +735,9 @@ class ResidencyManager:
         try:
             # the weakref must outlive the anchor for the callback to fire —
             # the entry itself holds it
-            e.anchor_ref = weakref.ref(anchor, _on_collect)
+            e.anchor_ref = weakref.ref(owner, _on_collect)
+            e.built_ref = e.anchor_ref if anchor is owner \
+                else weakref.ref(anchor)
         except TypeError:
             pass  # not weakref-able: entry lives until evicted by LRU
 
@@ -744,6 +819,7 @@ class ResidencyManager:
                 "hbm_entries": len(self._entries),
                 "hbm_cache_hits": reg.get("hbm_cache_hits"),
                 "hbm_cache_misses": reg.get("hbm_cache_misses"),
+                "hbm_lineage_hits": reg.get("hbm_lineage_hits"),
                 "hbm_evictions": reg.get("hbm_evictions"),
                 "hbm_eviction_bytes": reg.get("hbm_eviction_bytes"),
                 "hbm_pins": reg.get("hbm_pins"),

@@ -1363,9 +1363,11 @@ def _run_device_join(node, label: str, make_run, assemble,
                     run.feed_batch(first_b)
             else:
                 # coalesce fact morsels like the agg paths: one gather-join
-                # dispatch per super-batch. Single-batch facts (the resident-
-                # table repeat-query case) pass through identity-preserving,
-                # so series_keyed caches on the stored batch still hit.
+                # dispatch per super-batch. A single-batch flush hands the
+                # batch through as it is, and one of several contiguous
+                # morsels of a resident table is a zero-copy range of it
+                # (Series.concat), so series_keyed slots, keyed on the rows
+                # a batch views and not on its objects, hit on a repeat query.
                 coalescer = _make_coalescer(run.feed_batch, cfg)
                 feed = coalescer.add if coalescer is not None else run.feed_batch
                 for part in fact_stream:
@@ -3093,9 +3095,11 @@ def _concat_parts(parts: List[MicroPartition], schema) -> RecordBatch:
     if not batches:
         return RecordBatch.empty(schema)
     if len(batches) == 1:
-        # zero-copy: preserves batch identity, so device-join caches keyed on
-        # the stored batch survive across queries over resident tables
         return batches[0]
+    # the morsels a Project cut a resident table into glue back to the
+    # table's own columns without a copy (Series.concat: contiguous views of
+    # one root), so a dim keeps its identity, its dictionary codes and its
+    # residency slots across queries; anything else concatenates
     return RecordBatch.concat(batches)
 
 

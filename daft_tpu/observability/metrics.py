@@ -156,6 +156,7 @@ DEVICE_COUNTER_NAMES = (
     # HBM residency manager (daft_tpu/device/residency.py)
     "hbm_cache_hits",          # residency lookups served from HBM
     "hbm_cache_misses",        # residency lookups that built/uploaded
+    "hbm_lineage_hits",        # hits under another view object of the same rows
     "hbm_evictions",           # entries evicted under the HBM budget
     "hbm_eviction_bytes",      # device bytes released by evictions
     "hbm_pins",                # entries pinned by an executing query

@@ -409,16 +409,23 @@ def try_capture_join_agg(agg_plan) -> Optional[JoinAggSpec]:
 def series_keyed(anchor, key: tuple, deps: tuple, build, literals=None,
                  rebuild_rows: int = 0):
     """Cache ``build()`` in the process-wide HBM residency manager, anchored
-    on `anchor` Series' identity under `key`, valid while every object in
-    `deps` is IDENTICAL (strong refs held in the entry, so a freed object can
-    never alias a new one via id() reuse) and `literals` compare EQUAL.
+    on the identity of `anchor` Series' DATA under `key`, valid while every
+    Series in `deps` holds the same data, every other object in `deps` is
+    IDENTICAL (a strong ref is held in the entry, so a freed object can never
+    alias a new one via id() reuse) and `literals` compare EQUAL.
 
-    This is the identity spine of the join runtime: per-rep plan objects (and
-    the RecordBatches a pruning Project re-creates) are transient, but the
-    underlying column Series of a collected table are stable — so join
-    indices, padded device index planes, visibility planes, and synthetic dim
-    columns key on Series identity and survive across queries/reps. Without
-    it every rep re-uploads fact-bucket-sized arrays.
+    This is the identity spine of the join runtime. Per-rep plan objects, the
+    RecordBatches a pruning Project re-creates and the morsel views a
+    pipeline cuts a resident table into on every query are all transient;
+    what is stable is the resident column and the rows of it a view covers.
+    The manager therefore identifies a Series by its lineage
+    (device/residency.py data_identity: root column, row offset, length; a
+    column that is no view is its own root), so join indices, padded device
+    index planes, visibility planes and synthetic dim columns survive across
+    queries/reps whichever object presents the rows. Without it every rep
+    re-probes the dims and re-uploads fact-bucket-sized arrays. `take`,
+    `filter`, `cast` and computed columns make new data: their slots live
+    and die with the object.
 
     `literals` carries the per-query predicate literal values for slots whose
     `key` is the filter STRUCTURE: varying-literal queries then reuse ONE slot
@@ -500,9 +507,12 @@ def _gather_rows(mat, idx):
 class _JoinContext:
     """Materialized dims + per-fact-batch index/gather preparation.
 
-    Everything expensive is cached keyed on Series IDENTITY (series_keyed):
+    Everything expensive is cached keyed on the identity of a Series' DATA
+    (series_keyed: a resident column, or the rows of one a morsel views):
     host join indices, padded device index planes, dim visibility planes,
-    synthetic dim columns. Per-query work is then only: tiny per-query
+    synthetic dim columns. Per-fact-batch slots anchor on a column of the
+    fact batch (_probe_anchor, fact_anchor), so each morsel has its own and
+    finds it again on the next query. Per-query work is then only: tiny per-query
     literal uploads + the async gather/agg dispatches + ONE d2h fetch.
     Dim filters that are device-evaluable over numeric resident columns are
     computed ON DEVICE (no dim-sized visibility upload at all); the host
@@ -666,19 +676,30 @@ class _JoinContext:
 
     # ---- per fact batch -----------------------------------------------------------
     def _probe_anchor(self, batch, d: DimSpec):
-        """The stable Series that join-index caches for dim `d` key on: the
-        fact probe column, or (chained) the parent dim's providing column."""
-        side, colname = d.parent
-        if side == "fact":
-            return batch.get_column(colname)
-        return self.batches[side].get_column(colname)
+        """The Series that per-fact-batch caches for dim `d` key on: the fact
+        column that probes `d`, or (chained) the one that probes the root of
+        d's chain. Always a column of the fact batch, so the slot follows the
+        batch's rows: every morsel of a resident fact has its own (a slot
+        anchored on the parent dim's column would be shared, and rebuilt, by
+        all morsels of equal length), and a fresh view of the same rows finds
+        it again."""
+        root = self._root_of(d.name)
+        return batch.get_column(
+            next(dd for dd in self.dims if dd.name == root).parent[1])
+
+    def fact_anchor(self, batch):
+        """The anchor of slots built from a fact batch's rows through the
+        join as a whole (joined group codes, their cardinality): the column
+        that probes the first adjacent dim."""
+        return self._probe_anchor(batch, self._adjacent()[0])
 
     def indices_for(self, batch) -> Dict[str, np.ndarray]:
-        """Static per-fact-row dim indices. Cached per dim on the PROBE
-        Series' identity (survives re-projected fact batches across reps —
-        batch objects are transient, column Series are not). Chained dims
-        additionally depend on the parent's idx array identity, so a parent
-        rebuild invalidates the chain."""
+        """Static per-fact-row dim indices. Cached per dim on the identity of
+        the fact PROBE column's data (batch objects and the morsel views a
+        pipeline cuts are transient; the rows of a resident column they view
+        are not). Chained dims additionally depend on the parent dim's link
+        column and on the parent's idx array identity, so a parent rebuild
+        invalidates the chain."""
         with profile_span("join.index", "host", dim="*",
                           bucket=batch.num_rows):
             return self._indices_for(batch)
@@ -694,7 +715,8 @@ class _JoinContext:
             anchor = self._probe_anchor(batch, d)
             deps: tuple = (key_series,)
             if d.parent[0] != "fact":
-                deps = deps + (out[d.parent[0]],)
+                link = self.batches[d.parent[0]].get_column(d.parent[1])
+                deps = deps + (link, out[d.parent[0]])
 
             def build(d=d, kdt=kdt, key_series=key_series, snapshot=dict(out)):
                 probe_vals, probe_valid = self._probe_values(batch, d, snapshot, kdt)
@@ -1474,7 +1496,7 @@ class DeviceJoinGroupedRun(GroupedAggRun):
                     else dim_b.get_column(name)
                 key_cols.append((side, src))
 
-        anchor = key_cols[0][1]
+        anchor = ctx.fact_anchor(batch)
         deps = tuple(s for _side, s in key_cols) + tuple(
             idxs[side] for side, _s in key_cols if side != "fact")
 
@@ -1777,7 +1799,7 @@ def estimate_joined_cardinality(ctx: _JoinContext, batch, groupby) -> int:
                 else ctx.batches[side].get_column(name)
             sources.append((side, src))
 
-    anchor = sources[0][1]
+    anchor = ctx.fact_anchor(batch)
     deps = tuple(s for _sd, s in sources) + tuple(
         idxs[sd] for sd, _s in sources if sd != "fact")
 

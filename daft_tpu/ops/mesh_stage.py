@@ -1053,7 +1053,7 @@ class MeshJoinGroupedRun(_MeshJoinRunBase):
                 src = ctx.syn_series[side][name] if name.startswith("__syn_") \
                     else ctx.batches[side].get_column(name)
                 key_cols.append((side, src))
-        anchor = key_cols[0][1]
+        anchor = ctx.fact_anchor(batch)
         deps = tuple(s for _side, s in key_cols) + tuple(
             idxs[side] for side, _s in key_cols if side != "fact")
 

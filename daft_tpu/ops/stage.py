@@ -267,8 +267,9 @@ class DispatchCoalescer:
     One dispatch then covers N morsels and the RTT amortizes N-fold;
     finalize's d2h fetch is unchanged (packed aggregate rows ∝ groups, never
     the bucket). A single-batch flush hands the ORIGINAL batch through
-    untouched, so batch-identity-keyed device caches (device_join
-    series_keyed slots, resident-table repeat queries) still hit.
+    untouched, and contiguous morsels of one resident table concatenate to a
+    zero-copy range of it, so device caches keyed on the rows a batch views
+    (device_join series_keyed slots, resident-table repeat queries) still hit.
 
     Counters (coarse, per flush — never per row): ``coalesce_morsels_in`` /
     ``dispatch_coalesced`` give the amortization factor,

@@ -274,3 +274,192 @@ def test_auto_mode_cpu_backend_stays_on_host(star):
         counters.rejections
     with execution_config_ctx(device_mode="off"):
         assert out == q().to_pydict()
+
+
+# ---- repeat queries over morselized resident tables: slots follow the data ------------
+#
+# q3- and q5-shaped star joins (benchmark/queries/tpch.py) over tables that the
+# pipeline cuts into morsels on every query: a fact of four morsels, an
+# `orders` dim over two morsels (cut by its Project and glued together again).
+
+_MORSEL = 2048
+
+
+def _days(y, m, d):
+    import datetime
+
+    return datetime.date(y, m, d)
+
+
+def _tpch_like(seed=5, orders_shuffle=None):
+    import datetime
+
+    rng = np.random.default_rng(seed)
+    n_l, n_o, n_c, n_s = 7000, 5000, 500, 100
+    assert n_l > 3 * _MORSEL and n_o > 2 * _MORSEL
+    day0 = datetime.date(1994, 1, 1)
+    dates = lambda n: [day0 + datetime.timedelta(days=int(x)) for x in rng.integers(0, 730, n)]
+    o_custkey = rng.integers(0, n_c, n_o)
+    if orders_shuffle is not None:      # equal shape, the keys dealt out differently
+        o_custkey = np.random.default_rng(orders_shuffle).permutation(o_custkey)
+    t = {
+        "region": {"r_regionkey": list(range(5)),
+                   "r_name": ["AFRICA", "AMERICA", "ASIA", "EUROPE", "MIDDLE EAST"]},
+        "nation": {"n_nationkey": list(range(25)), "n_name": [f"N{i:02d}" for i in range(25)],
+                   "n_regionkey": [i % 5 for i in range(25)]},
+        "customer": {"c_custkey": list(range(n_c)),
+                     "c_mktsegment": rng.choice(["BUILDING", "MACHINERY", "AUTOMOBILE"], n_c).tolist(),
+                     "c_nationkey": rng.integers(0, 25, n_c).tolist()},
+        "supplier": {"s_suppkey": list(range(n_s)),
+                     "s_nationkey": rng.integers(0, 25, n_s).tolist()},
+        "orders": {"o_orderkey": list(range(n_o)), "o_custkey": o_custkey.tolist(),
+                   "o_orderdate": dates(n_o),
+                   "o_shippriority": rng.integers(0, 2, n_o).tolist()},
+        "lineitem": {"l_orderkey": np.sort(rng.integers(0, n_o, n_l)).tolist(),
+                     "l_suppkey": rng.integers(0, n_s, n_l).tolist(),
+                     "l_extendedprice": rng.uniform(900, 90000, n_l).round(2).tolist(),
+                     "l_discount": (rng.integers(0, 11, n_l) / 100).tolist(),
+                     "l_shipdate": dates(n_l)},
+    }
+    return {name: daft_tpu.from_pydict(cols).collect() for name, cols in t.items()}
+
+
+def _q3_shaped(t, segment="BUILDING", cut=(1995, 3, 15)):
+    return (t["customer"].where(col("c_mktsegment") == segment)
+            .join(t["orders"], left_on="c_custkey", right_on="o_custkey")
+            .where(col("o_orderdate") < _days(*cut))
+            .join(t["lineitem"], left_on="o_orderkey", right_on="l_orderkey")
+            .where(col("l_shipdate") > _days(*cut))
+            .groupby(col("o_orderkey").alias("l_orderkey"), "o_orderdate", "o_shippriority")
+            .agg((col("l_extendedprice") * (1 - col("l_discount"))).sum().alias("revenue"))
+            .select("l_orderkey", "revenue", "o_orderdate", "o_shippriority")
+            .sort(["revenue", "o_orderdate"], desc=[True, False])
+            .limit(10))
+
+
+def _q5_shaped(t, region="ASIA", cut=(1994, 1, 1)):
+    return (t["region"].where(col("r_name") == region)
+            .join(t["nation"], left_on="r_regionkey", right_on="n_regionkey")
+            .join(t["customer"], left_on="n_nationkey", right_on="c_nationkey")
+            .join(t["orders"], left_on="c_custkey", right_on="o_custkey")
+            .where((col("o_orderdate") >= _days(*cut)) & (col("o_orderdate") < _days(1995, 1, 1)))
+            .join(t["lineitem"], left_on="o_orderkey", right_on="l_orderkey")
+            .join(t["supplier"], left_on=["l_suppkey", "n_nationkey"],
+                  right_on=["s_suppkey", "s_nationkey"])
+            .groupby("n_name")
+            .agg((col("l_extendedprice") * (1 - col("l_discount"))).sum().alias("revenue"))
+            .sort("revenue", desc=True))
+
+
+def _morselized(device_mode):
+    return execution_config_ctx(device_mode=device_mode, morsel_size_rows=_MORSEL,
+                                pipeline_mode="force")
+
+
+def _host_answer(q):
+    with _morselized("off"):
+        return q().to_pydict()
+
+
+_WARM = ("hbm_cache_misses", "hbm_h2d_bytes", "hbm_lineage_hits")
+
+
+def _device_run(q):
+    """(answer, registry deltas, join dispatches) of one forced device run."""
+    from daft_tpu.observability.metrics import registry
+
+    before = {k: registry().get(k) for k in _WARM}
+    jb = counters.device_join_batches
+    with _morselized("on"):
+        out = q().to_pydict()
+    return (out, {k: registry().get(k) - before[k] for k in _WARM},
+            counters.device_join_batches - jb)
+
+
+@pytest.fixture(scope="module")
+def tpch_like():
+    return _tpch_like()
+
+
+@pytest.mark.parametrize("shape", [_q3_shaped, _q5_shaped], ids=["q3", "q5"])
+def test_morselized_repeat_query_hits_every_slot(tpch_like, shape):
+    from daft_tpu.device.residency import manager
+
+    manager().clear()
+    q = lambda: shape(tpch_like)
+    host = _host_answer(q)
+    first, cold, dispatches = _device_run(q)
+    assert dispatches >= 4, "one join dispatch per fact morsel"
+    assert cold["hbm_cache_misses"] > 0 and cold["hbm_h2d_bytes"] > 0
+    _assert_close(host, first)
+    for _ in range(2):      # from the second execution on: nothing built, nothing uploaded
+        again, warm, d = _device_run(q)
+        assert warm["hbm_cache_misses"] == 0 and warm["hbm_h2d_bytes"] == 0, warm
+        assert warm["hbm_lineage_hits"] > 0, "fresh morsel objects found their rows' slots"
+        assert d == dispatches, "the dispatch shape is what it was"
+        assert again == first
+    manager().clear()
+
+
+def test_morselized_dim_keeps_its_columns(tpch_like, monkeypatch):
+    """A dim over two morsels is cut by its Project and glued together again:
+    the join context sees the table's own columns, not copies."""
+    from daft_tpu.ops.device_join import _JoinContext
+
+    seen = []
+    real = _JoinContext.__init__
+
+    def spy(self, spec, dim_batches):
+        seen.append(dim_batches)
+        real(self, spec, dim_batches)
+
+    monkeypatch.setattr(_JoinContext, "__init__", spy)
+    with _morselized("on"):
+        _q3_shaped(tpch_like).to_pydict()
+    orders = tpch_like["orders"]._result[0].batches[0]
+    assert orders.num_rows > 2 * _MORSEL, "the dim must be large enough to be morselized"
+    (dim,) = [b for b in seen[-1].values() if b.num_rows == orders.num_rows]
+    for name in dim.column_names():
+        assert dim.get_column(name) is orders.get_column(name)
+
+
+@pytest.mark.parametrize("shape", [_q3_shaped, _q5_shaped], ids=["q3", "q5"])
+def test_rebound_dim_of_equal_shape_gives_its_own_answer(shape):
+    """The stale-answer guard: `orders` rebound to a table of equal shape and
+    schema whose customer keys differ must never be served the first table's
+    join indices."""
+    from daft_tpu.device.residency import manager
+
+    manager().clear()
+    t = _tpch_like()
+    first, _, _ = _device_run(lambda: shape(t))
+    _device_run(lambda: shape(t))                           # warm: every slot resident
+    t2 = dict(t, orders=_tpch_like(orders_shuffle=99)["orders"])
+    assert t2["orders"].count_rows() == t["orders"].count_rows()
+    host2 = _host_answer(lambda: shape(t2))
+    dev2, _, _ = _device_run(lambda: shape(t2))
+    _assert_close(host2, dev2)
+    assert dev2 != first, "the fixture must make the two answers differ"
+    back, _, _ = _device_run(lambda: shape(t))              # and the first table's again
+    assert back == first
+    manager().clear()
+
+
+@pytest.mark.parametrize("shape,variants", [
+    (_q3_shaped, [dict(segment="MACHINERY"), dict(cut=(1995, 6, 1)), dict()]),
+    (_q5_shaped, [dict(region="EUROPE"), dict(cut=(1994, 6, 1)), dict()]),
+], ids=["q3", "q5"])
+def test_changed_literal_gives_the_new_answer(tpch_like, shape, variants):
+    from daft_tpu.device.residency import manager
+
+    manager().clear()
+    base, _, _ = _device_run(lambda: shape(tpch_like))
+    _device_run(lambda: shape(tpch_like))
+    answers = []
+    for kw in variants:
+        q = lambda: shape(tpch_like, **kw)
+        dev, _, _ = _device_run(q)
+        _assert_close(_host_answer(q), dev)
+        answers.append(dev)
+    assert answers[0] != base and answers[1] != base and answers[2] == base
+    manager().clear()

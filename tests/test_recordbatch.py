@@ -138,3 +138,45 @@ def test_cast_to_schema():
     target = Schema.from_pydict({"a": DataType.float64(), "b": DataType.string()})
     out = b.cast_to_schema(target)
     assert out.to_pydict() == {"a": [1.0, 2.0], "b": [None, None]}
+
+
+# ---- RecordBatch.concat of morsel views (what _concat_parts does to a dim) ------------
+
+def _table(n=90):
+    return RecordBatch.from_pydict({
+        "k": list(range(n)),
+        "s": [None if i % 5 == 0 else f"s{i % 4}" for i in range(n)],
+        "f": [i / 2 for i in range(n)],
+    })
+
+
+@pytest.mark.parametrize("bounds,glued", [
+    ([(0, 30), (30, 60), (60, 90)], "root"),     # the morsels of a whole table
+    ([(30, 60), (60, 90)], "range"),             # a contiguous part of it
+    ([(60, 90), (0, 60)], None),                 # out of order
+    ([(0, 30), (60, 90)], None),                 # a gap
+    ([(0, 40), (30, 90)], None),                 # overlapping
+], ids=["covers", "range", "out_of_order", "gap", "overlap"])
+def test_concat_of_morsel_views(bounds, glued):
+    table = _table()
+    parts = [table.slice(a, b) for a, b in bounds]
+    out = RecordBatch.concat(parts)
+    want = {c: [x for p in parts for x in p.to_pydict()[c]] for c in table.column_names()}
+    assert out.to_pydict() == want
+    assert out.schema == table.schema and out.num_rows == sum(b - a for a, b in bounds)
+    for c in table.column_names():
+        col, root = out.get_column(c), table.get_column(c)
+        if glued == "root":
+            assert col is root                   # the table's own column: identity kept
+        elif glued == "range":
+            assert col.lineage() == (root, bounds[0][0])
+        else:
+            assert col.lineage() == (col, 0)     # copied as before
+
+
+def test_concat_mixed_views_and_new_data_copies():
+    table = _table()
+    parts = [table.slice(0, 45), table.slice(45, 90).take(np.arange(45))]
+    out = RecordBatch.concat(parts)
+    assert out.to_pydict() == table.to_pydict()
+    assert all(out.get_column(c) is not table.get_column(c) for c in table.column_names())

@@ -4,6 +4,8 @@ safety, cache hits with zero re-transfer, and the zero-overhead host-path
 guard. Device paths run with device_mode="on" on the CPU backend (jit
 semantics identical to TPU)."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -409,4 +411,297 @@ def test_rebuild_in_place_keeps_pin():
             assert m.bytes_resident() > 1
         # scope closed: the pin released exactly once, budget re-enforces
         assert m.entry_count() == 0
+    m.clear()
+
+
+# ---- slots follow the data: lineage of zero-copy views --------------------------------
+
+def _lineage_root(n=4096):
+    from daft_tpu.core.series import Series
+
+    return Series.from_pylist([(i * 7) % 1000 for i in range(n)], "c")
+
+
+def _plane(s):
+    import jax.numpy as jnp
+
+    return jnp.asarray(s.to_numpy())
+
+
+def _delta(names, fn):
+    before = {k: registry().get(k) for k in names}
+    fn()
+    return {k: registry().get(k) - before[k] for k in names}
+
+
+_LOOKUP = ("hbm_cache_hits", "hbm_cache_misses", "hbm_lineage_hits", "hbm_stable_rehits")
+
+
+@pytest.mark.parametrize("again,hits,lineage,stable,misses", [
+    # a separately made slice of the same rows: the slot, and only lineage finds it
+    (lambda r, o: r.slice(1024, 2048), 1, 1, 0, 0),
+    # a slice of a slice resolves to the root
+    (lambda r, o: r.slice(512, 3072).slice(512, 1536), 1, 1, 0, 0),
+    # other rows of the root: another slot
+    (lambda r, o: r.slice(1024, 2049), 0, 0, 0, 1),
+    (lambda r, o: r.slice(0, 1024), 0, 0, 0, 1),
+    # the same rows copied (new data): not by lineage; the deps-free slot may
+    # still rebind through its content key, as before
+    (lambda r, o: r.slice(1024, 2048).take(list(range(1024))), 1, 0, 1, 0),
+    # another root of equal content, same range: likewise
+    (lambda r, o: o.slice(1024, 2048), 1, 0, 1, 0),
+], ids=["same_range", "slice_of_slice", "other_length", "other_offset",
+        "copied_rows", "other_root_equal_content"])
+def test_view_slot_identity(again, hits, lineage, stable, misses):
+    m = manager()
+    m.clear()
+    root, other = _lineage_root(), _lineage_root()
+    first = root.slice(1024, 2048)
+    m.get_or_build(first, ("plane",), (), lambda: _plane(first))
+    second = again(root, other)
+    d = _delta(_LOOKUP, lambda: m.get_or_build(
+        second, ("plane",), (), lambda: _plane(second)))
+    assert d == {"hbm_cache_hits": hits, "hbm_cache_misses": misses,
+                 "hbm_lineage_hits": lineage, "hbm_stable_rehits": stable}
+    m.clear()
+
+
+def test_same_object_hit_is_not_a_lineage_hit():
+    m = manager()
+    m.clear()
+    root = _lineage_root()
+    view = root.slice(0, 100)
+    for anchor in (root, view):
+        m.get_or_build(anchor, ("plane",), (), lambda a=anchor: _plane(a))
+        d = _delta(_LOOKUP, lambda a=anchor: m.get_or_build(
+            a, ("plane",), (), lambda: _plane(a)))
+        assert d["hbm_cache_hits"] == 1 and d["hbm_lineage_hits"] == 0
+    m.clear()
+
+
+def test_view_of_all_of_its_root_is_the_root():
+    m = manager()
+    m.clear()
+    root = _lineage_root()
+    m.get_or_build(root, ("plane",), (), lambda: _plane(root))
+    whole = root.slice(0, len(root))
+    assert m.is_resident(whole, ("plane",))
+    d = _delta(_LOOKUP, lambda: m.get_or_build(whole, ("plane",), (), lambda: _plane(whole)))
+    assert d["hbm_cache_hits"] == 1 and d["hbm_lineage_hits"] == 1
+    assert m.entry_count() == 1
+    m.clear()
+
+
+def test_python_object_column_has_no_lineage():
+    from daft_tpu import DataType
+    from daft_tpu.core.series import Series
+
+    m = manager()
+    m.clear()
+    root = Series.from_pylist([object() for _ in range(64)], "p", DataType.python())
+    a, b = root.slice(8, 16), root.slice(8, 16)
+    m.get_or_build(a, ("k",), (), lambda: 1)
+    d = _delta(_LOOKUP, lambda: m.get_or_build(b, ("k",), (), lambda: 2))
+    assert d["hbm_cache_misses"] == 1 and d["hbm_cache_hits"] == 0
+    m.clear()
+
+
+@pytest.mark.parametrize("dep,hit", [
+    (lambda dim, other: dim.slice(0, 256), True),        # the same rows, another object
+    (lambda dim, other: dim.slice(0, 255), False),       # other rows
+    (lambda dim, other: other.slice(0, 256), False),     # equal content, another root:
+    (lambda dim, other: other, False),                   # never served another's index
+], ids=["same_rows", "other_rows", "other_root_view", "other_root"])
+def test_series_deps_compare_by_lineage(dep, hit):
+    m = manager()
+    m.clear()
+    fact, dim, other = _lineage_root(), _lineage_root(512), _lineage_root(512)
+    idx = np.arange(8)                                   # a non-Series dep stays `is`
+    m.get_or_build(fact.slice(0, 1024), ("uki",), (dim.slice(0, 256), idx), lambda: "first")
+    d = _delta(_LOOKUP, lambda: m.get_or_build(
+        fact.slice(0, 1024), ("uki",), (dep(dim, other), idx), lambda: "second"))
+    assert (d["hbm_cache_hits"], d["hbm_cache_misses"]) == ((1, 0) if hit else (0, 1))
+    assert m.entry_count() == 1, "a mismatch rebuilds in place"
+    # an equal but distinct array is not the cached one
+    d = _delta(_LOOKUP, lambda: m.get_or_build(
+        fact.slice(0, 1024), ("uki",), (dep(dim, other), np.arange(8)), lambda: "third"))
+    assert d["hbm_cache_misses"] == 1
+    m.clear()
+
+
+def test_literals_still_compared_for_views():
+    m = manager()
+    m.clear()
+    root = _lineage_root()
+    m.get_or_build(root.slice(0, 64), ("vis",), (), lambda: "a", literals=(1,))
+    assert m.get_or_build(root.slice(0, 64), ("vis",), (), lambda: "b", literals=(1,)) == "a"
+    assert m.get_or_build(root.slice(0, 64), ("vis",), (), lambda: "c", literals=(2,)) == "c"
+    assert m.entry_count() == 1
+    m.clear()
+
+
+def test_view_entries_live_with_the_root_not_the_view():
+    import gc
+
+    m = manager()
+    m.clear()
+    root = _lineage_root()
+    view = root.slice(100, 200)
+    m.get_or_build(view, ("plane",), (), lambda: _plane(view))
+    del view
+    gc.collect()
+    assert m.entry_count() == 1, "a morsel object died: its rows are still resident"
+    again = root.slice(100, 200)
+    assert _delta(_LOOKUP, lambda: m.get_or_build(
+        again, ("plane",), (), lambda: _plane(again)))["hbm_lineage_hits"] == 1
+    del again, root
+    gc.collect()
+    assert m.entry_count() == 0, "the root died: nothing can present these rows again"
+
+
+def test_series_deps_are_not_kept_alive():
+    import gc
+    import weakref
+
+    m = manager()
+    m.clear()
+    fact, dim = _lineage_root(), _lineage_root(128)
+    m.get_or_build(fact, ("uki",), (dim,), lambda: "idx")
+    ref = weakref.ref(dim)
+    del dim
+    gc.collect()
+    assert ref() is None, "a Series dep is held as a token, never reused"
+    m.clear()
+
+
+def test_pin_scope_and_budget_eviction_hold_for_views():
+    import jax.numpy as jnp
+
+    m = manager()
+    m.clear()
+    root = _lineage_root()
+    with execution_config_ctx(hbm_budget_bytes=1):       # below any entry's size
+        with m.pin_scope():
+            for lo in (0, 1024, 2048):
+                m.get_or_build(root.slice(lo, lo + 1024), ("plane",), (),
+                               lambda: jnp.ones(1024))
+            assert m.entry_count() == 3                  # pinned: over budget, all held
+            # a fresh view of pinned rows hits, and does not pin twice
+            pins = registry().get("hbm_pins")
+            m.get_or_build(root.slice(1024, 2048), ("plane",), (), lambda: jnp.ones(1024))
+            assert registry().get("hbm_pins") == pins
+        assert m.entry_count() == 0                      # scope closed: budget enforced
+    evicted = registry().get("hbm_evictions")
+    with execution_config_ctx(hbm_budget_bytes=3 * 1024 * 4):
+        for lo in (0, 512, 1024, 1536, 2048):            # distinct content, 4 KiB each
+            part = root.slice(lo, lo + 512)
+            m.get_or_build(part, ("plane", lo), (), lambda: jnp.ones(1024, dtype=jnp.float32))
+        assert m.bytes_resident() <= 3 * 1024 * 4
+        assert registry().get("hbm_evictions") > evicted
+    m.clear()
+
+
+def test_unpickled_view_has_no_lineage_and_rebinds_by_content():
+    import pickle
+
+    m = manager()
+    m.clear()
+    root = _lineage_root()
+    view = root.slice(1024, 2048)
+    view.to_device_cached(1024, f32=True)
+    h2d = registry().get("hbm_h2d_bytes")
+    copy = pickle.loads(pickle.dumps(view))
+    assert copy.lineage() == (copy, 0)
+    d = _delta(_LOOKUP, lambda: copy.to_device_cached(1024, f32=True))
+    assert d == {"hbm_cache_hits": 1, "hbm_cache_misses": 0,
+                 "hbm_lineage_hits": 0, "hbm_stable_rehits": 1}
+    assert registry().get("hbm_h2d_bytes") == h2d and m.entry_count() == 1
+    m.clear()
+
+
+def test_hit_does_not_fingerprint_the_column(monkeypatch):
+    """The content hash (to_numpy + blake2b over the column) is for the
+    stable-key rebind: computed once the identity probe has missed, never on
+    a hit."""
+    from daft_tpu.core.series import Series
+
+    m = manager()
+    m.clear()
+    root = _lineage_root()
+    calls = []
+    real = Series.content_fingerprint
+    monkeypatch.setattr(Series, "content_fingerprint",
+                        lambda self: calls.append(len(self)) or real(self))
+    m.get_or_build(root.slice(0, 512), ("plane",), (), lambda: 1)
+    assert calls == [512]                                # the miss hashed the morsel once
+    for _ in range(3):
+        m.get_or_build(root.slice(0, 512), ("plane",), (), lambda: 2)
+    assert calls == [512]
+    m.clear()
+
+
+def test_scan_query_has_no_lineage_hits():
+    """One unsliced batch a query: every anchor is its own root, every hit is
+    under the object the entry was built under."""
+    manager().clear()
+    fact = daft_tpu.from_pydict({
+        "k": [i % 5 for i in range(4096)], "v": [float(i) for i in range(4096)],
+    }).collect()
+    with execution_config_ctx(device_mode="on"):
+        q = lambda: fact.where(col("v") > lit(10.0)).agg(col("v").sum().alias("s")).to_pydict()
+        first = q()
+        again = []
+        d = _delta(_LOOKUP + ("hbm_h2d_bytes",), lambda: again.append(q()))
+    assert again == [first]
+    assert d["hbm_cache_hits"] > 0 and d["hbm_cache_misses"] == 0
+    assert d["hbm_lineage_hits"] == 0 and d["hbm_h2d_bytes"] == 0
+    manager().clear()
+
+
+def test_concurrent_lookups_of_views_keep_the_books():
+    """Stage threads and pool morsels look slots up at once. Fresh views of
+    eight ranges of one root from more threads than cores: every lookup gets
+    its own range's value, one entry a range stays, and the byte count is the
+    sum of what the entries hold."""
+    import sys
+    import threading
+
+    import jax.numpy as jnp
+
+    from daft_tpu.device.residency import device_nbytes
+
+    m = manager()
+    m.clear()
+    root = _lineage_root(8 * 256)
+    dim = _lineage_root(64)
+    wrong, stop = [], time.monotonic() + 20.0
+
+    def worker(seed):
+        rng = np.random.default_rng(seed)
+        for _ in range(150):
+            if time.monotonic() > stop:
+                wrong.append("timed out")
+                return
+            lo = int(rng.integers(0, 8)) * 256
+            view = root.slice(lo, lo + 256)
+            plane = m.get_or_build(view, ("plane",), (), lambda: jnp.full(256, lo))
+            idx = m.get_or_build(view, ("uki",), (dim.slice(0, 64),), lambda: ("idx", lo))
+            if int(plane[0]) != lo or idx != ("idx", lo):
+                wrong.append((lo, int(plane[0]), idx))
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(s,)) for s in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30.0)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert wrong == []
+    assert m.entry_count() == 16                         # 8 ranges x 2 slots
+    with m._lock:
+        assert m._bytes == sum(device_nbytes(e.value) for e in m._entries.values())
     m.clear()

@@ -178,3 +178,95 @@ def test_to_device_padding():
     vals, validity = s.to_device(pad_to=8)
     assert vals.shape == (8,)
     assert validity.tolist() == [True, False, True, False, False, False, False, False]
+
+
+# ---- lineage of zero-copy views (Series.slice) and concat's glue ----------------------
+
+def _root(n=100, nulls=False, strings=False):
+    if strings:
+        vals = [None if nulls and i % 7 == 0 else f"v{i % 13}" for i in range(n)]
+    else:
+        vals = [None if nulls and i % 7 == 0 else i * 3 for i in range(n)]
+    return Series.from_pylist(vals, "c")
+
+
+def test_slice_records_lineage_and_composes():
+    root = _root()
+    assert root.lineage() == (root, 0)           # a column is its own root
+    v = root.slice(10, 60)
+    assert v.lineage() == (root, 10)
+    vv = v.slice(5, 20)                          # a slice of a slice: same root
+    assert vv.lineage() == (root, 15) and len(vv) == 15
+    assert vv.to_pylist() == root.to_pylist()[15:30]
+    assert root.head(7).lineage() == (root, 0)
+
+
+@pytest.mark.parametrize("make", [
+    lambda r: r.take([3, 4, 5]),
+    lambda r: r.filter(Series.from_pylist([i % 2 == 0 for i in range(len(r))])),
+    lambda r: r.cast(DataType.float64()),
+    lambda r: r + r,
+    lambda r: r.rename("other"),
+    lambda r: Series.from_pylist([object(), object()], "p", DataType.python()).slice(0, 1),
+], ids=["take", "filter", "cast", "computed", "rename", "pyobjs_slice"])
+def test_new_data_has_no_lineage(make):
+    out = make(_root())
+    assert out.lineage() == (out, 0)
+
+
+def test_lineage_dropped_on_pickle():
+    import pickle
+
+    root = _root()
+    v = pickle.loads(pickle.dumps(root.slice(10, 20)))
+    assert v.lineage() == (v, 0)
+    assert v.to_pylist() == root.to_pylist()[10:20]
+
+
+# (name, the parts cut from a root `r` of 100 rows (`o`: another root of equal
+# content), expected: "root", a (start, end)
+# range of the root, or None for a copy)
+_CONCAT_CASES = [
+    ("covers_root", lambda r, o: [r.slice(0, 40), r.slice(40, 70), r.slice(70, 100)], "root"),
+    ("inner_range", lambda r, o: [r.slice(10, 40), r.slice(40, 55)], (10, 55)),
+    ("slices_of_slices", lambda r, o: [r.slice(20, 80).slice(0, 30), r.slice(50, 100)], (20, 100)),
+    ("with_empty_view", lambda r, o: [r.slice(0, 50), r.slice(50, 50), r.slice(50, 100)], "root"),
+    ("single_view", lambda r, o: [r.slice(5, 9)], (5, 9)),
+    ("out_of_order", lambda r, o: [r.slice(40, 100), r.slice(0, 40)], None),
+    ("gap", lambda r, o: [r.slice(0, 40), r.slice(50, 100)], None),
+    ("overlap", lambda r, o: [r.slice(0, 50), r.slice(40, 100)], None),
+    ("two_roots", lambda r, o: [r.slice(0, 50), o.slice(50, 100)], None),
+    ("non_view_part", lambda r, o: [r.slice(0, 50), r.slice(50, 100).take(list(range(50)))], None),
+    ("root_twice", lambda r, o: [r, r], None),
+]
+
+
+@pytest.mark.parametrize("kind", ["ints", "nulls", "strings"])
+@pytest.mark.parametrize("name,cut,expect", _CONCAT_CASES,
+                         ids=[c[0] for c in _CONCAT_CASES])
+def test_concat_glues_contiguous_views(name, cut, expect, kind):
+    root = _root(nulls=kind != "ints", strings=kind == "strings")
+    other = _root(nulls=kind != "ints", strings=kind == "strings")  # equal content
+    parts = cut(root, other)
+    out = Series.concat(parts)
+    want = [x for p in parts for x in p.to_pylist()]
+    # values, validity and dtype are the same whichever way it went
+    assert out.to_pylist() == want
+    assert out.dtype == root.dtype and out.name == "c"
+    assert out.null_count() == sum(x is None for x in want)
+    if expect == "root":
+        assert out is root
+    elif expect is None:
+        assert out.lineage() == (out, 0), "a copy is new data"
+        assert out is not root
+    else:
+        assert out.lineage() == (root, expect[0]) and len(out) == expect[1] - expect[0]
+        # zero-copy: the values buffer is the root's own
+        assert out.to_arrow().buffers()[-1].address == root.to_arrow().buffers()[-1].address
+
+
+def test_concat_of_views_keeps_dict_codes():
+    root = _root(strings=True)
+    codes = root.dict_codes()
+    out = Series.concat([root.slice(0, 33), root.slice(33, 100)])
+    assert out is root and out.dict_codes() is codes
