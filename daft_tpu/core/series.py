@@ -50,7 +50,9 @@ class Series:
     @classmethod
     def from_arrow(cls, arr, name: str = "series", dtype: Optional[DataType] = None) -> "Series":
         arr = _combine(arr)
+        encoded = None
         if pa.types.is_dictionary(arr.type):
+            encoded = arr
             arr = arr.dictionary_decode()
         inferred = DataType.from_arrow(arr.type)
         if dtype is None:
@@ -59,7 +61,27 @@ class Series:
         target = dtype.to_arrow() if not dtype.is_python() else None
         if target is not None and arr.type != target:
             arr = arr.cast(target)
-        return cls(name, dtype, arr)
+        out = cls(name, dtype, arr)
+        if encoded is not None and dtype == inferred:
+            out._keep_dictionary(encoded)
+        return out
+
+    def _keep_dictionary(self, encoded: "pa.DictionaryArray") -> None:
+        """A column that arrives dictionary-encoded (a Parquet reader asked
+        for it so, `io/parquet.py`) keeps the encoding as its `dict_codes`:
+        the integer codes are renumbered to first-occurrence order, which
+        costs a fifth of hashing the strings again. Strings and binary
+        without nulls only, as `_arrow_dict_codes`."""
+        if encoded.null_count or encoded.dictionary.null_count \
+                or not (self._dtype.is_string() or self._dtype.is_binary()):
+            return
+        import pandas as pd
+
+        codes, used = pd.factorize(encoded.indices.to_numpy(zero_copy_only=False))
+        values = encoded.dictionary.take(pa.array(used)).to_pylist()
+        if len(set(values)) != len(values):
+            return  # a dictionary that repeats a value is no encoding to keep
+        self._dict_codes = (codes.astype(np.int32, copy=False), values, len(values))
 
     @classmethod
     def from_pylist(cls, data: Sequence[Any], name: str = "series", dtype: Optional[DataType] = None) -> "Series":
@@ -433,15 +455,43 @@ class Series:
         # the host's time always (`dict_encode_us`, once per column)
         t0 = time.perf_counter()
         with profile_span("series.dict_encode", "host", rows=len(self)) as sp:
-            first_idx, group_ids, _ = make_groups([self])
-            codes = group_ids.astype(np.int32, copy=False)
-            values = self.take(first_idx).to_pylist()
+            fast = self._arrow_dict_codes()
+            if fast is not None:
+                codes, values = fast
+            else:
+                first_idx, group_ids, _ = make_groups([self])
+                codes = group_ids.astype(np.int32, copy=False)
+                values = self.take(first_idx).to_pylist()
             if sp is not None:
                 sp.args["cardinality"] = len(values)
         registry().inc("dict_encode_us", int((time.perf_counter() - t0) * 1e6))
         out = (codes, values, len(values))
         object.__setattr__(self, "_dict_codes", out)
         return out
+
+    def arrow_encodes(self) -> bool:
+        """Whether `dict_codes` of this column is Arrow's own dictionary-encode
+        (`_arrow_dict_codes`): strings and binary without nulls."""
+        dt = self._dtype
+        return self._pyobjs is None and (dt.is_string() or dt.is_binary()) \
+            and not self._arrow.null_count
+
+    def _arrow_dict_codes(self):
+        """(codes int32, values) of a string or binary column without nulls,
+        straight from Arrow's hash dictionary-encode, whose dictionary is in
+        first-occurrence order as `dict_codes` promises: the general path
+        (`make_groups`) widens the same codes to int64, factorizes them again
+        and takes the firsts back out, twice the time for nothing here (a
+        streamed scan encodes its group keys once a morsel). None where the
+        general path has to run."""
+        if not self.arrow_encodes():
+            return None
+        arr = self._arrow
+        if isinstance(arr, pa.ChunkedArray):
+            arr = arr.combine_chunks()
+        de = arr.dictionary_encode()
+        codes = de.indices.to_numpy(zero_copy_only=False).astype(np.int32, copy=False)
+        return codes, de.dictionary.to_pylist()
 
     # ---- selection kernels --------------------------------------------------------
     def slice(self, start: int, end: int) -> "Series":

@@ -394,7 +394,8 @@ class ResidencyManager:
         # only now, the identity probe having missed, is the column hashed:
         # a hit never pays for a fingerprint (outside the lock: it reads the
         # whole column)
-        stable = stable_slot_key(anchor, key) if not deps else None
+        stable = stable_slot_key(anchor, key) \
+            if not deps and not getattr(self._tl, "transient", False) else None
         if stable is not None:
             with self._lock:
                 e = self._stable_rebind(stable, full_key, owner, anchor,
@@ -495,15 +496,23 @@ class ResidencyManager:
 
     # ---- pinning -------------------------------------------------------------------
     @contextlib.contextmanager
-    def pin_scope(self):
+    def pin_scope(self, transient: bool = False):
         """Scope one query execution: every entry touched inside is pinned
         (never evicted) until exit; eviction re-runs at exit so the budget is
-        re-enforced once the query's working set is released."""
+        re-enforced once the query's working set is released.
+
+        `transient`: the stage is fed from a stream (a file scan), so the
+        planes it builds belong to morsels that die with the query and that
+        no later anchor can hold the content of: their builds skip the
+        content fingerprint (`stable_slot_key` reads and hashes the whole
+        column), which only a later rebind could repay."""
         scopes = getattr(self._tl, "scopes", None)
         if scopes is None:
             scopes = self._tl.scopes = []
         pinned: set = set()
         scopes.append(pinned)
+        was_transient = getattr(self._tl, "transient", False)
+        self._tl.transient = was_transient or transient
         obs = current_pin_observation()
         if obs is not None:
             # under the manager lock: concurrent scope EXITS iterate
@@ -516,6 +525,7 @@ class ResidencyManager:
             yield self
         finally:
             scopes.pop()
+            self._tl.transient = was_transient
             with self._lock:
                 if obs is not None:
                     # admission calibration (serving/prepared.py): record the

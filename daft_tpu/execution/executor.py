@@ -15,6 +15,7 @@ ops/counters.py records which path actually ran.
 
 from __future__ import annotations
 
+import contextvars
 import functools
 import itertools
 import threading
@@ -475,7 +476,8 @@ def _streaming_scan(node) -> Iterator[MicroPartition]:
     "scan.stream" span while the timeline profiler is active."""
     from ..memory import manager as _host_manager
     from ..observability.metrics import registry
-    from ..observability.runtime_stats import current_collector, span_iter
+    from ..observability.runtime_stats import (current_collector, current_spans,
+                                               span_iter)
     from ..utils.pool import compute_pool
 
     mgr = _host_manager()
@@ -508,6 +510,8 @@ def _streaming_scan(node) -> Iterator[MicroPartition]:
             acc[0] = acc[1] = acc[2] = 0
 
     def task_parts(task) -> Iterator[MicroPartition]:
+        if task.size_bytes:
+            reg.inc("scan_file_bytes", task.size_bytes)
         inner = task.read()
         for part in span_iter("scan.stream", "scan", inner,
                               source=task.source_label):
@@ -541,6 +545,7 @@ def _streaming_scan(node) -> Iterator[MicroPartition]:
         def read_task(task):
             return list(task_parts(task))
 
+        recording = current_spans() is not None
         window = compute_pool()._max_workers
         futures = []
         ti = 0
@@ -548,7 +553,14 @@ def _streaming_scan(node) -> Iterator[MicroPartition]:
             while ti < len(node.tasks) and len(futures) < window:
                 if budgeted and mgr.under_pressure():
                     mgr.wait_for_headroom()
-                futures.append(compute_pool().submit(read_task, node.tasks[ti]))
+                if recording:
+                    # the pool thread works in a copy of this context, so the
+                    # task's scan.stream span (and the scan.decode spans under
+                    # it) hang under the scan's operator and carry the qid
+                    futures.append(compute_pool().submit(
+                        contextvars.copy_context().run, read_task, node.tasks[ti]))
+                else:
+                    futures.append(compute_pool().submit(read_task, node.tasks[ti]))
                 ti += 1
             for part in futures.pop(0).result():
                 yield count(part)
@@ -719,6 +731,9 @@ def _exec_device_agg(node) -> MicroPartition:
     from ..ops.region import node_region_ops
 
     region_ops = node_region_ops(node)
+    # morsels of a file scan die with the query: their planes are uploaded
+    # without a content fingerprint (residency.pin_scope)
+    streamed = not _resident_source_rec(node.input)
     if grouped:
         from ..ops.grouped_stage import DeviceFallback, try_build_grouped_agg_stage
 
@@ -734,7 +749,7 @@ def _exec_device_agg(node) -> MicroPartition:
         try:
             # pin the query's resident planes so a tight HBM budget cannot
             # evict buffers this run still reads; released at scope exit
-            with _placement.feedback(prec) as fb, _residency().pin_scope():
+            with _placement.feedback(prec) as fb, _residency().pin_scope(transient=streamed):
                 for part in stream:
                     buffered.append(part)
                     fed_rows += part.num_rows
@@ -764,7 +779,7 @@ def _exec_device_agg(node) -> MicroPartition:
     feed = coal.add if coal is not None else run.feed_batch
     fed_rows = 0
     d0 = _counters.device_stage_batches
-    with _placement.feedback(prec) as fb, _residency().pin_scope():
+    with _placement.feedback(prec) as fb, _residency().pin_scope(transient=streamed):
         for part in stream:
             fed_rows += part.num_rows
             for b in part.batches:
@@ -1731,6 +1746,24 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
     return tier, rec
 
 
+def _dict_build_rows(key_series, rows: int, cal) -> int:
+    """What the dictionary builds of one batch's group keys cost, as rows at
+    `cal.host_factorize_rate` (the unit the grouped cost functions take): a
+    key whose dictionary is cached costs nothing, a string or binary key
+    without nulls is encoded by Arrow at `host_dict_encode_rate`
+    (Series._arrow_dict_codes), any other goes through make_groups at the
+    full rate."""
+    total = 0.0
+    for s in key_series:
+        if getattr(s, "_dict_codes", None) is not None:
+            continue
+        if s.arrow_encodes():
+            total += rows * cal.host_factorize_rate / cal.host_dict_encode_rate
+        else:
+            total += rows
+    return int(total)
+
+
 def _resident_source_rec(n) -> bool:
     """True if every leaf under `n` is an in-memory scan (resident table)."""
     kids = n.children()
@@ -1891,10 +1924,8 @@ def _mesh_wins(node, first: MicroPartition, grouped: bool, ndev: int):
         # keys amortize (cached per Series), host-mode keys re-factorize per
         # run at full price — disagreeing here would under-price one tier
         if stage.dict_keys:
-            dict_rows = sum(
-                batch.num_rows for s in key_series
-                if getattr(s, "_dict_codes", None) is None)
-            single_fact_rows = dict_rows // amort
+            single_fact_rows = _dict_build_rows(key_series, batch.num_rows,
+                                                cal) // amort
         else:
             single_fact_rows = batch.num_rows
         n_planes = (len(stage._mm_specs) + len(stage._ext_specs)
@@ -2097,10 +2128,8 @@ def _device_wins(node, first: MicroPartition, grouped: bool,
         cap_est = _pad_groups(min(card, 2 * MAX_MATMUL_SEGMENTS))
         if stage.dict_keys:
             # dictionary builds are cached per Series -> amortized like uploads
-            dict_rows = sum(
-                batch.num_rows for s in key_series
-                if getattr(s, "_dict_codes", None) is None)
-            factorize_cost_rows = dict_rows // amort
+            factorize_cost_rows = _dict_build_rows(key_series, batch.num_rows,
+                                                   cal) // amort
         else:
             # host-mode keys re-factorize on every run: full price, no amortization
             factorize_cost_rows = batch.num_rows

@@ -10,13 +10,15 @@ prune IO before any byte is read.
 from __future__ import annotations
 
 import os
-from typing import Any, List, Optional, Union
+from typing import Any, Iterator, List, Optional, Tuple, Union
 
 import pyarrow as pa
 import pyarrow.dataset as pads
 import pyarrow.parquet as pq
 
 from ..core.micropartition import MicroPartition
+from ..observability.metrics import registry
+from ..observability.runtime_stats import profile_span
 from ..schema import Schema
 from .paths import expand_paths
 from .scan import Pushdowns, ScanOperator, ScanTask
@@ -35,7 +37,10 @@ def _scan_batch_rows() -> int:
 class ParquetScanOperator(ScanOperator):
     def __init__(self, path: Union[str, List[str]], schema: Optional[Schema] = None,
                  row_groups_per_task: Optional[int] = None, **_options):
-        self._paths = expand_paths(path, (".parquet", ".pq"))
+        with profile_span("scan.plan", "scan", step="glob") as sp:
+            self._paths = expand_paths(path, (".parquet", ".pq"))
+            if sp is not None:
+                sp.args["files"] = len(self._paths)
         if not self._paths:
             raise FileNotFoundError(f"no parquet files matched {path!r}")
         self._schema = schema
@@ -50,7 +55,8 @@ class ParquetScanOperator(ScanOperator):
 
             # schema inference from the first file (reference: schema_inference.rs);
             # remote objects read only the footer via ranged reads
-            self._schema = Schema.from_arrow(pq.read_schema(open_input(self._paths[0])))
+            with profile_span("scan.plan", "scan", step="schema", files=1):
+                self._schema = Schema.from_arrow(pq.read_schema(open_input(self._paths[0])))
         return self._schema
 
     def can_absorb_select(self) -> bool:
@@ -76,6 +82,18 @@ class ParquetScanOperator(ScanOperator):
         return float(total)
 
     def to_scan_tasks(self, pushdowns: Pushdowns) -> List[ScanTask]:
+        with profile_span("scan.plan", "scan", step="tasks") as sp:
+            tasks, pruned = self._plan_tasks(pushdowns)
+            if sp is not None:
+                sp.args.update(files=len(self._paths), tasks=len(tasks),
+                               row_groups_pruned=pruned)
+        if pruned:
+            registry().inc("scan_row_groups_pruned", pruned)
+        return tasks
+
+    def _plan_tasks(self, pushdowns: Pushdowns):
+        """(the scan tasks, row groups whose statistics proved them empty of
+        matches and that no task reads)."""
         schema = self.schema()
         columns = pushdowns.columns
         out_schema = Schema([schema[c] for c in columns]) if columns is not None else schema
@@ -86,7 +104,10 @@ class ParquetScanOperator(ScanOperator):
 
         split_bytes = execution_config().scan_split_bytes
         tasks = []
+        pruned = 0
         conjuncts = _zone_map_conjuncts(pushdowns.filters) if pushdowns.filters is not None else []
+        filter_columns = _referenced_columns(pushdowns.filters) \
+            if arrow_filter is not None else frozenset()
         for path in self._paths:
             remote = is_remote(path)
             size = os.path.getsize(path) if os.path.exists(path) else None
@@ -100,18 +121,24 @@ class ParquetScanOperator(ScanOperator):
             if conjuncts:
                 if md is not None:
                     if _prunable_md(md, conjuncts):
+                        pruned += md.num_row_groups
                         continue  # zone map proved no row can match
-                elif remote and _file_prunable(path, conjuncts):
-                    continue  # same proof via ranged footer reads
+                elif remote:
+                    groups = _file_prunable(path, conjuncts)
+                    if groups:
+                        pruned += groups
+                        continue  # same proof via ranged footer reads
             if want_split and md is not None:
-                split = _row_group_split_tasks(
+                split, excluded = _row_group_split_tasks(
                     path, md, columns, out_schema, conjuncts,
                     split_bytes or size, self._row_groups_per_task)
                 if split is not None:
                     tasks.extend(split)
+                    pruned += excluded
                     continue
             tasks.append(ScanTask(
-                read=_make_reader(path, columns, arrow_filter, pushdowns.limit, out_schema),
+                read=_make_reader(path, columns, arrow_filter, pushdowns.limit, out_schema,
+                                  md=md, filter_columns=filter_columns),
                 schema=out_schema,
                 size_bytes=size,
                 # remote readers don't evaluate the predicate; the executor
@@ -120,7 +147,54 @@ class ParquetScanOperator(ScanOperator):
                 limit_applied=False,
                 source_label=path,
             ))
-        return tasks
+        return tasks, pruned
+
+
+def _referenced_columns(expr) -> frozenset:
+    from ..expressions import ColumnRef
+
+    return frozenset(e._name for e in expr.walk() if isinstance(e, ColumnRef))
+
+
+# a column chunk that the writer dictionary-encoded throughout and whose
+# pages hold at most this many bytes a value is read as a dictionary
+_DICT_READ_MAX_BYTES_PER_VALUE = 2
+
+
+def _dictionary_candidates(columns, out_schema: Schema, skip=frozenset()) -> List[str]:
+    """The string and binary columns a reader is asked for, less `skip`."""
+    return sorted(f.name for f in out_schema
+                  if (f.dtype.is_string() or f.dtype.is_binary())
+                  and f.name not in skip
+                  and (columns is None or f.name in columns))
+
+
+def _dictionary_columns(md, candidates: List[str]) -> List[str]:
+    """Those of `candidates` to read AS dictionaries (`read_dictionary`): the
+    columns every row group of the file stores dictionary-encoded in a few
+    bits a value (a writer that fell back to plain pages for a chunk makes it
+    large). pyarrow then hands over the file's own codes instead of building
+    every string, and `Series.from_arrow` keeps them as the column's
+    `dict_codes`, so a grouped stage fed from the scan does not hash a key
+    column again in every morsel. [] on any doubt."""
+    if md is None or not candidates or md.num_row_groups == 0:
+        return []
+    try:
+        rg0 = md.row_group(0)
+        index = {rg0.column(i).path_in_schema: i for i in range(rg0.num_columns)}
+        out = []
+        for name in candidates:
+            i = index.get(name)
+            if i is None:
+                continue
+            chunks = [md.row_group(g).column(i) for g in range(md.num_row_groups)]
+            if all(c.has_dictionary_page and c.total_uncompressed_size
+                   <= _DICT_READ_MAX_BYTES_PER_VALUE * max(c.num_values, 1)
+                   for c in chunks):
+                out.append(name)
+        return out
+    except Exception:  # lint: ignore[broad-except] -- unreadable statistics: read plain
+        return []
 
 
 def _zone_map_conjuncts(expr) -> List[tuple]:
@@ -195,34 +269,38 @@ def _prunable_md(md, conjuncts: List[tuple]) -> bool:
     return md.num_row_groups > 0
 
 
-def _file_prunable(path: str, conjuncts: List[tuple]) -> bool:
+def _file_prunable(path: str, conjuncts: List[tuple]) -> int:
     """Remote-object variant of _prunable_md: reads just the footer via
-    ranged gets; never prunes on metadata trouble."""
+    ranged gets; never prunes on metadata trouble. Returns the row groups
+    the file's pruning spares (0: the file has to be read)."""
     from .object_store import open_input
 
     try:
-        return _prunable_md(pq.ParquetFile(open_input(path)).metadata, conjuncts)
+        md = pq.ParquetFile(open_input(path)).metadata
+        return md.num_row_groups if _prunable_md(md, conjuncts) else 0
     except Exception:  # lint: ignore[broad-except] -- never prune on metadata trouble
-        return False
+        return 0
 
 
 def _row_group_split_tasks(path: str, md, columns, out_schema: Schema,
                            conjuncts: List[tuple], split_bytes: int,
-                           row_groups_per_task: Optional[int]) -> Optional[List[ScanTask]]:
+                           row_groups_per_task: Optional[int]
+                           ) -> Tuple[Optional[List[ScanTask]], int]:
     """Split one large local parquet file into row-group-aligned ScanTasks
     so no single scan task materializes more than ~split_bytes (reference:
     daft-scan's ScanTask-per-row-group splitting). `md` is the caller's
     already-parsed footer metadata. Row groups a zone-map conjunct excludes
-    are dropped at plan time. Returns None when the file can't split (one
-    row group, everything pruned into one task) — the caller falls back to
-    the whole-file task.
+    are dropped at plan time. Returns (tasks, row groups dropped); tasks is
+    None when the file can't split (one row group, everything pruned into
+    one task) — the caller falls back to the whole-file task, which reads
+    every row group.
 
     Split tasks read via ``ParquetFile.iter_batches(row_groups=...)`` with
     column pruning but WITHOUT the arrow predicate (``filters_applied`` is
     False, so the executor re-applies the pushed filter post-scan — exactly
     the remote-reader contract)."""
     if md.num_row_groups <= 1:
-        return None
+        return None, 0
     groups: List[List[int]] = []
     sizes: List[int] = []
     rows: List[int] = []
@@ -253,19 +331,18 @@ def _row_group_split_tasks(path: str, md, columns, out_schema: Schema,
         sizes.append(cur_bytes)
         rows.append(cur_rows)
     if len(groups) <= 1:
-        return None
+        return None, 0
+
+    dict_cols = _dictionary_columns(md, _dictionary_candidates(columns, out_schema))
 
     def make_read(rgs: List[int]):
         def read():
-            pf = pq.ParquetFile(path)
-            for rb in pf.iter_batches(batch_size=_scan_batch_rows(),
-                                      row_groups=rgs, columns=columns):
-                t = pa.Table.from_batches([rb])
-                yield MicroPartition.from_arrow(t).cast_to_schema(out_schema)
+            pf = pq.ParquetFile(path, read_dictionary=dict_cols or None)
+            yield from _decoded(pf.iter_batches(batch_size=_scan_batch_rows(),
+                                                row_groups=rgs, columns=columns),
+                                out_schema, None, len(rgs))
 
         return _maybe_prefetch(read)
-
-    from ..observability.metrics import registry
 
     registry().inc("scan_tasks_split", len(groups))
     return [
@@ -279,7 +356,7 @@ def _row_group_split_tasks(path: str, md, columns, out_schema: Schema,
             source_label=f"{path}[rg{g[0]}..{g[-1]}]",
         )
         for g, nb, nr in zip(groups, sizes, rows)
-    ]
+    ], md.num_row_groups - sum(len(g) for g in groups)
 
 
 def _maybe_prefetch(read_factory):
@@ -309,7 +386,47 @@ def _maybe_prefetch(read_factory):
     return read_prefetched
 
 
-def _make_reader(path: str, columns, arrow_filter, limit, out_schema: Schema):
+def _decoded(batches, out_schema: Schema, limit: Optional[int],
+             row_groups: Optional[int]) -> Iterator[MicroPartition]:
+    """The morsels of one scan task: one MicroPartition for each record batch
+    pyarrow decodes, up to `limit` rows. Each pull of `batches` (read,
+    decompress, decode) and the wrapping of what it gave is one `scan.decode`
+    span, a leaf under the task's `scan.stream`; the pull that finds the
+    input exhausted is one too (a pushed-down filter can read a whole file to
+    find no further match). Counts what was decoded (`RecordBatch.nbytes` of
+    what pyarrow returned: no walk of the buffers) and the row groups the
+    task was given, once, as the task ends."""
+    it = iter(batches)
+    produced = decoded = 0
+    try:
+        while limit is None or produced < limit:
+            with profile_span("scan.decode", "scan") as sp:
+                rb = next(it, None)
+                if rb is None:
+                    return
+                nbytes = rb.nbytes
+                decoded += nbytes
+                t = pa.Table.from_batches([rb])
+                if limit is not None and produced + t.num_rows > limit:
+                    t = t.slice(0, limit - produced)
+                produced += t.num_rows
+                mp = MicroPartition.from_arrow(t).cast_to_schema(out_schema)
+                if sp is not None:
+                    sp.args.update(rows=t.num_rows, bytes=nbytes)
+            yield mp
+    finally:
+        reg = registry()
+        if decoded:
+            reg.inc("scan_decoded_bytes", decoded)
+        if row_groups:
+            reg.inc("scan_row_groups", row_groups)
+
+
+def _make_reader(path: str, columns, arrow_filter, limit, out_schema: Schema,
+                 md=None, filter_columns=frozenset()):
+    """The reader of one whole file. `md`: the file's footer, where the plan
+    has read it already (else the reader does). `filter_columns`: what
+    `arrow_filter` reads."""
     from .object_store import is_remote
 
     if is_remote(path):
@@ -320,32 +437,30 @@ def _make_reader(path: str, columns, arrow_filter, limit, out_schema: Schema):
             # ranges; predicate re-applied by the executor (filters_applied is
             # False for remote tasks)
             pf = pq.ParquetFile(open_input(path))
-            produced = 0
-            for rb in pf.iter_batches(batch_size=_scan_batch_rows(), columns=columns):
-                if limit is not None and produced >= limit:
-                    return
-                t = pa.Table.from_batches([rb])
-                if limit is not None and produced + t.num_rows > limit:
-                    t = t.slice(0, limit - produced)
-                produced += t.num_rows
-                yield MicroPartition.from_arrow(t).cast_to_schema(out_schema)
+            dict_cols = _dictionary_columns(
+                pf.metadata, _dictionary_candidates(columns, out_schema))
+            if dict_cols:
+                pf = pq.ParquetFile(open_input(path), metadata=pf.metadata,
+                                    read_dictionary=dict_cols)
+            yield from _decoded(
+                pf.iter_batches(batch_size=_scan_batch_rows(), columns=columns),
+                out_schema, limit, pf.metadata.num_row_groups)
 
         return _maybe_prefetch(read_remote)
 
     def read():
-        ds = pads.dataset(path, format="parquet")
+        # not the columns the scanner evaluates the pushed-down filter on
+        candidates = _dictionary_candidates(columns, out_schema, filter_columns)
+        footer = md if md is not None or not candidates else _local_metadata(path)
+        dict_cols = _dictionary_columns(footer, candidates)
+        fmt = pads.ParquetFileFormat(read_options=pads.ParquetReadOptions(
+            dictionary_columns=dict_cols)) if dict_cols else "parquet"
+        ds = pads.dataset(path, format=fmt)
         scanner = ds.scanner(columns=columns, filter=arrow_filter,
                              batch_size=_scan_batch_rows())
-        produced = 0
-        for rb in scanner.to_batches():
-            if limit is not None and produced >= limit:
-                return
-            t = pa.Table.from_batches([rb])
-            if limit is not None and produced + t.num_rows > limit:
-                t = t.slice(0, limit - produced)
-            produced += t.num_rows
-            mp = MicroPartition.from_arrow(t)
-            yield mp.cast_to_schema(out_schema)
+        groups = footer.num_row_groups if footer is not None else sum(
+            f.num_row_groups for f in ds.get_fragments())
+        yield from _decoded(scanner.to_batches(), out_schema, limit, groups)
 
     return _maybe_prefetch(read)
 

@@ -319,6 +319,15 @@ MEMORY_COUNTER_NAMES = (
     "scan_bytes",               # logical bytes through BUDGETED streaming scans
                                 # (sizing morsels walks arrow buffers — skipped
                                 # on the unbudgeted zero-overhead path)
+    "scan_file_bytes",          # on-disk bytes of the files / row groups of the
+                                # scan tasks a streaming scan started to read
+                                # (ScanTask.size_bytes; unknown sizes, as a
+                                # remote object's, count nothing)
+    "scan_decoded_bytes",       # Arrow bytes the parquet readers decoded:
+                                # RecordBatch.nbytes of what pyarrow returned
+                                # (after its filter), no buffer walk
+    "scan_row_groups",          # parquet row groups handed to readers
+    "scan_row_groups_pruned",   # row groups zone maps excluded at plan time
     "scan_tasks_split",         # scan tasks produced by row-group splitting
     "scan_tasks_merged",        # small scan tasks absorbed by task merging
     "scan_backpressure_stalls", # times a scan stalled on host memory pressure

@@ -177,6 +177,11 @@ class Calibration:
     # DAFT_TPU_COST_PALLAS_PROBE_RATE (tools/calibrate.py suggests both
     # Pallas rates from placement-ledger samples).
     pallas_probe_cell_rate: float = 2e12
+    # Arrow's hash dictionary-encode of a string or binary key column without
+    # nulls (Series._arrow_dict_codes): 2.7 ms for 131,072 rows of TPC-H's
+    # l_returnflag on the builder's host (PR 28), against 4.9 ms through
+    # make_groups, which host_factorize_rate prices at 16 ms.
+    host_dict_encode_rate: float = 4e7
 
 
 _CAL: Optional[Calibration] = None

@@ -188,6 +188,20 @@ def resolve_key_series(batch, groupby, n: int):
 _CARD_SAMPLE_ROWS = 8192
 
 
+def _count_distinct(s) -> int:
+    """Distinct values of a (small) Series, nulls one value: in Arrow where
+    the column is Arrow's, so a key column of every morsel of a stream is
+    not turned into Python objects to be counted."""
+    if s._pyobjs is None:
+        try:
+            import pyarrow.compute as pc
+
+            return int(pc.count_distinct(s.to_arrow(), mode="all").as_py())
+        except Exception:  # lint: ignore[broad-except] -- a type Arrow cannot hash: count in Python
+            pass
+    return len(set(s.to_pylist()))
+
+
 def estimate_key_cardinality(key_series) -> int:
     """Cheap lower-bound estimate of the combined group-key cardinality from the
     first _CARD_SAMPLE_ROWS rows (cached per Series). A sample can only
@@ -201,7 +215,7 @@ def estimate_key_cardinality(key_series) -> int:
             k = cached[2]
         else:
             head = s.head(_CARD_SAMPLE_ROWS)
-            k = len(set(head.to_pylist()))
+            k = _count_distinct(head)
             if len(s) > _CARD_SAMPLE_ROWS and k > _CARD_SAMPLE_ROWS // 2:
                 # sample is near-saturated: extrapolate proportionally
                 k = max(k, int(k * (len(s) / _CARD_SAMPLE_ROWS)))

@@ -182,6 +182,21 @@ def test_write_then_read_parquet_s3(s3_server, df):
     assert back == df.sort("id").to_pydict()
 
 
+def test_s3_reader_takes_a_dictionary_encoded_string_column_as_a_dictionary(s3_server, df):
+    """The ranged-read reader asks for the file's own dictionaries too (one
+    footer read serves the choice and the read), and the column keeps the
+    codes as its `dict_codes` (PR 28)."""
+    df.write_parquet("s3://bkt/tbl_dict").to_pydict()
+    parts = list(daft_tpu.read_parquet("s3://bkt/tbl_dict/*.parquet").iter_partitions())
+    cols = [b.get_column("s") for p in parts for b in p.batches]
+    assert sum(len(c) for c in cols) == 2000
+    for c in cols:
+        codes, values, k = c._dict_codes
+        assert k == 3 and sorted(values) == ["x", "y", "z"]
+        assert [values[i] for i in codes] == c.to_pylist()
+    assert [x for c in cols for x in c.to_pylist()] == df.to_pydict()["s"]
+
+
 def test_s3_parquet_with_pushdowns(s3_server, df):
     df.write_parquet("s3://bkt/tbl2").to_pydict()
     out = (daft_tpu.read_parquet("s3://bkt/tbl2/*.parquet")
