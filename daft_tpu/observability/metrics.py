@@ -132,6 +132,8 @@ DEVICE_COUNTER_NAMES = (
     "mesh_unavailable_fallbacks",  # forced mesh_devices > local devices -> single-chip
     "mesh_capacity_growths",   # mesh group-table capacity grown mid-run (recompile)
     "device_join_batches",     # batches through the gather-join device stages
+    "join_provision_calls",    # join dispatches whose columns came from one traced program
+    "join_provision_traces",   # provisioning programs traced (0 on a repeat query shape)
     "device_topn_runs",        # join+agg+TopN fused device programs completed
     "mesh_join_runs",          # device joins executed via the mesh-sharded tier
     # intra-host ICI repartition (jax.lax.all_to_all over the local mesh —

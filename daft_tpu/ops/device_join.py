@@ -33,8 +33,9 @@ then runs the untouched host plan (exact same semantics, tested side-by-side).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -494,14 +495,87 @@ def _gather_col(arr, arr_valid, idx):
     return arr[safe], arr_valid[safe] & ok
 
 
-@jax.jit
 def _gather_rows(mat, idx):
     """One gather of a packed [P, N] dim matrix along its MINOR axis — the
-    per-batch join. The pack is TRANSPOSED ([planes, rows], not [rows,
-    planes]) because TPU tiled layouts pad the minor dimension to 128 lanes:
-    a [64M, 5] gather output would materialize as [64M, 128] — 32GB — and
-    OOM (observed at SF10); [5, 64M] pads only the 5 to 8 sublanes."""
+    per-batch join, traced inside the provisioning program. The pack is
+    TRANSPOSED ([planes, rows], not [rows, planes]) because TPU tiled layouts
+    pad the minor dimension to 128 lanes: a [64M, 5] gather output would
+    materialize as [64M, 128] — 32GB — and OOM (observed at SF10); [5, 64M]
+    pads only the 5 to 8 sublanes."""
     return mat[:, jnp.clip(idx, 0, mat.shape[1] - 1)]
+
+
+@dataclass(frozen=True)
+class _ProvisionLayout:
+    """What one provisioning program is specialised on beside the shapes of
+    its arguments: the structure the packs carry and the order of the columns
+    asked for. It comes from the plan's shape and the dims' dictionaries,
+    never from a filter literal, so a query with another literal finds the
+    program the first one traced."""
+    packs: tuple     # per adjacent dim: its pack's ok row, None = existence check only
+    columns: tuple   # per dim column handed on: (name, adjacent dim, digit rows, validity row)
+    codes: tuple     # per group-by column: (adjacent dim or -1 = fact-side plane, row or position, radix)
+    cap: int         # the combined codes are clipped to [0, cap); 0 where no codes are asked for
+
+
+class _CodePlan(NamedTuple):
+    """The dictionary strategy's group codes, as the host knows them before
+    the dispatch (DeviceJoinGroupedRun._dict_code_plan)."""
+    cols: tuple                     # group-by columns, most significant first
+    radices: tuple                  # per column, from the dictionaries' sizes
+    cap: int                        # padded product of the sizes
+    fact_planes: Dict[str, object]  # fact-side column -> resident code plane
+
+
+@functools.lru_cache(maxsize=256)
+def _provision_program(layout: _ProvisionLayout):
+    """The jitted program that turns one fact batch's cache hits (each
+    adjacent dim's packed matrix and index plane, the fact-side code planes)
+    into what the stage's program takes: the gathered dim columns with
+    `__join_ok__`, and the radix-combined group codes where `layout.codes`
+    asks for them. One call a dispatch; kept at module level under the
+    layout because a _JoinContext lives for one query and the stages' own
+    programs live by structure."""
+
+    def run(mats, idxs, fact_codes):
+        counters.bump("join_provision_traces")   # runs when traced, not when called
+        gathered = []
+        ok = None
+        for mat, didx, ok_row in zip(mats, idxs, layout.packs):
+            aok = didx >= 0
+            rows = None
+            if ok_row is not None:
+                rows = _gather_rows(mat, didx)      # [P, bucket]
+                aok = aok & (rows[ok_row] > 0.5)
+            gathered.append(rows)
+            ok = aok if ok is None else (ok & aok)
+        dcols: Dict[str, dev.DCol] = {"__join_ok__": (ok, jnp.ones_like(ok))}
+        for name, a, digits, valid_row in layout.columns:
+            rows = gathered[a]
+            if len(digits) == 1:
+                v = rows[digits[0]]
+            else:
+                # wide integers: base-2^24 digit planes, most significant
+                # first, each exact in f32. Recombined in int64 and handed on
+                # as int64, NOT f64: the stage compiler's f32 fcast would
+                # quantize a float plane past 2^24, silently corrupting
+                # SUM/MIN/MAX over wide int dim columns (ADVICE r5 high); int
+                # planes pass fcast untouched and the isum/i64-scatter agg
+                # paths receive exact values
+                v = rows[digits[0]].astype(jnp.int32).astype(jnp.int64)
+                for d in digits[1:]:
+                    v = v * (1 << 24) + rows[d].astype(jnp.int32).astype(jnp.int64)
+            dcols[name] = (v, rows[valid_row] > 0.5)
+        combined = None
+        for a, at, radix in layout.codes:
+            plane = fact_codes[at] if a < 0 else gathered[a][at].astype(jnp.int32)
+            combined = plane * radix if combined is None else combined + plane * radix
+        if combined is not None:
+            # join-miss garbage is masked anyway
+            combined = jnp.clip(combined, 0, layout.cap - 1)
+        return dcols, combined
+
+    return jax.jit(run)
 
 
 class _JoinContext:
@@ -513,7 +587,12 @@ class _JoinContext:
     synthetic dim columns. Per-fact-batch slots anchor on a column of the
     fact batch (_probe_anchor, fact_anchor), so each morsel has its own and
     finds it again on the next query. Per-query work is then only: tiny per-query
-    literal uploads + the async gather/agg dispatches + ONE d2h fetch.
+    literal uploads + ONE d2h fetch, and per fact batch the look-ups that find
+    the cached arrays, one call of the traced provisioning program
+    (_provision_program: row gathers, join-validity mask, plane splits,
+    wide-integer recombination, group codes) and one of the stage's program.
+    The context itself lives for one query; the provisioning programs live at
+    module level under their layout, so a repeat query traces nothing.
     Dim filters that are device-evaluable over numeric resident columns are
     computed ON DEVICE (no dim-sized visibility upload at all); the host
     part (strings etc.) is evaluated once per query shape and its upload
@@ -1013,9 +1092,11 @@ class _JoinContext:
         None when the subtree is a pure existence check (idx >= 0 suffices).
 
         Returns (mat, layout, code_layout, ok_col, wide) where layout[col] =
-        (val_idx, valid_idx); 64-bit int columns split into hi/lo f32 digit
-        planes (wide[col] = (hi_idx, lo_idx, valid_idx)) and recombine in f64
-        after the fact gather, preserving exact values past 2^24."""
+        (val_idx, valid_idx); 32- and 64-bit int columns split into two or
+        three base-2^24 f32 digit planes (wide[col] = (digit rows, most
+        significant first, then valid_idx)), which the provisioning program
+        recombines in int64 after the fact gather, preserving exact values
+        past 2^24."""
         spec = self.spec
         vals, codes = self._needed_split(needed, groupby_cols)
         sub = [adj.name] + [d.name for d in self.dims
@@ -1060,8 +1141,8 @@ class _JoinContext:
                 v, m = planes[c]
                 kind = str(getattr(v, "dtype", ""))
                 if kind in ("int64", "uint64"):
-                    # 3-digit split: every |v| < 2^53 (f64's own limit — the
-                    # consumer pipeline) recombines exactly after the gather
+                    # 3-digit split: recombines exactly after the gather (the
+                    # consumer pipeline holds |v| < 2^53, f64's own limit)
                     hi = jnp.floor_divide(v, 1 << 48).astype(jnp.float32)
                     mid = jnp.mod(jnp.floor_divide(v, 1 << 24),
                                   1 << 24).astype(jnp.float32)
@@ -1103,34 +1184,38 @@ class _JoinContext:
         return series_keyed(series, ("permplane", bucket), (pperm_np,), build)
 
     def provision(self, batch, bucket: int, needed: Sequence[str],
-                  groupby_cols: Sequence[str] = (), perm=None):
+                  codes: Optional[_CodePlan] = None, perm=None):
         """All device columns for one fact batch: fact planes resident; ONE
         packed row-gather per adjacent dim serves every dim value/code plane
-        plus the join-validity mask. Returns (dcols, code planes dict).
+        plus the join-validity mask, inside ONE call of the traced
+        provisioning program (_provision_program). Returns (dcols, combined
+        group codes, None unless `codes` asks for the dictionary strategy's).
         With `perm` every plane comes back in group-sorted row order (the
-        locally-dense aggregation layout) at no extra per-batch gathers."""
+        locally-dense aggregation layout) at no extra per-batch gathers.
+
+        The host's part is the look-ups that find the arrays (every one a
+        cache hit on a repeat query) and the layout key; no array is touched
+        outside the program."""
         with profile_span("join.gather", "device", planes=len(needed)):
-            return self._provision(batch, bucket, needed, groupby_cols, perm)
+            return self._provision(batch, bucket, needed, codes, perm)
 
     def _provision(self, batch, bucket: int, needed: Sequence[str],
-                   groupby_cols: Sequence[str], perm):
+                   codes: Optional[_CodePlan], perm):
         spec = self.spec
+        gb_cols, radices, cap, fact_code_planes = codes or _CodePlan((), (), 0, {})
+        adj_of: Dict[str, int] = {}
+        mats, idxs, ok_rows, layouts = [], [], [], []
+        for a, adj in enumerate(self._adjacent()):
+            adj_of[adj.name] = a
+            idxs.append(self.dev_idx(batch, adj.name, bucket, perm=perm))
+            mat, layout, code_layout, ok_row, wide = \
+                self.packed_plane(adj, needed, gb_cols) or (None, {}, {}, None, {})
+            mats.append(mat)
+            ok_rows.append(ok_row)
+            layouts.append((layout, code_layout, wide))
+
         dcols: Dict[str, dev.DCol] = {}
-        code_out: Dict[str, object] = {}
-        ok_total = None
-        gathered: Dict[str, tuple] = {}
-
-        for adj in self._adjacent():
-            didx = self.dev_idx(batch, adj.name, bucket, perm=perm)
-            pack = self.packed_plane(adj, needed, groupby_cols)
-            aok = didx >= 0
-            if pack is not None:
-                mat, layout, code_layout, ok_col, wide = pack
-                rows = _gather_rows(mat, didx)      # [P, bucket]
-                gathered[adj.name] = (rows, layout, code_layout, wide)
-                aok = aok & (rows[ok_col] > 0.5)
-            ok_total = aok if ok_total is None else (ok_total & aok)
-
+        columns = []
         for name in needed:
             side = spec.col_side.get(name)
             if side == "fact":
@@ -1149,39 +1234,31 @@ class _JoinContext:
                 continue
             if name == "__join_ok__" or side is None:
                 continue
-            rows, layout, _cl, wide = gathered[self._root_of(side)]
-            if name in wide:
-                w = wide[name]
-                if len(w) == 4:       # 64-bit: hi*2^48 + mid*2^24 + lo
-                    v = (rows[w[0]].astype(jnp.float64) * (1 << 48)
-                         + rows[w[1]].astype(jnp.float64) * (1 << 24)
-                         + rows[w[2]].astype(jnp.float64))
-                else:                 # 32-bit: hi*2^24 + lo
-                    v = (rows[w[0]].astype(jnp.float64) * (1 << 24)
-                         + rows[w[1]].astype(jnp.float64))
-                # hand the plane back as int64 (exact: digits recombine below
-                # 2^53), NOT f64 — the stage compiler's f32 fcast would
-                # quantize an f64 plane past 2^24, silently corrupting
-                # SUM/MIN/MAX over wide int dim columns (ADVICE r5 high);
-                # int planes pass fcast untouched and the isum/i64-scatter
-                # agg paths receive exact values
-                dcols[name] = (jnp.round(v).astype(jnp.int64),
-                               rows[w[-1]] > 0.5)
+            a = adj_of[self._root_of(side)]
+            layout, _code_layout, wide = layouts[a]
+            if name in wide:        # digit rows, then the validity row
+                columns.append((name, a, tuple(wide[name][:-1]), wide[name][-1]))
             else:
                 vi, mi = layout[name]
-                dcols[name] = (rows[vi], rows[mi] > 0.5)
+                columns.append((name, a, (vi,), mi))
 
-        for name in groupby_cols:
+        code_cols = []
+        fact_codes = []
+        for name, radix in zip(gb_cols, radices):
             side = spec.col_side.get(name)
-            if side is None or side == "fact":
-                continue
-            rows, _l, code_layout, _w = gathered[self._root_of(side)]
-            code_out[name] = rows[code_layout[name]].astype(jnp.int32)
+            if side == "fact":
+                code_cols.append((-1, len(fact_codes), radix))
+                fact_codes.append(fact_code_planes[name])
+            else:
+                a = adj_of[self._root_of(side)]
+                code_cols.append((a, layouts[a][1][name], radix))
 
-        if ok_total is None:
-            ok_total = jnp.ones(bucket, dtype=bool)
-        dcols["__join_ok__"] = (ok_total, jnp.ones(bucket, dtype=bool))
-        return dcols, code_out
+        prog = _provision_program(_ProvisionLayout(
+            tuple(ok_rows), tuple(columns), tuple(code_cols), cap))
+        gathered, combined = prog(tuple(mats), tuple(idxs), tuple(fact_codes))
+        counters.bump("join_provision_calls")
+        dcols.update(gathered)
+        return dcols, combined
 
     def device_cols(self, batch, bucket: int, needed: Sequence[str]) -> Dict[str, dev.DCol]:
         dcols, _codes = self.provision(batch, bucket, needed)
@@ -1371,13 +1448,13 @@ class DeviceJoinGroupedRun(GroupedAggRun):
                           rows=n, bucket=bucket):
             if total is not None and 0 < total <= min(self.max_segments,
                                                       MAX_MATMUL_SEGMENTS):
-                dcols, code_planes = self.ctx.provision(batch, bucket, needed,
-                                                        gb_cols)
                 with profile_span("join.codes", "host", strategy="dict") as sp:
-                    decode = self._dict_combined_codes(batch, n, bucket,
-                                                       gb_cols, code_planes)
+                    decode, codes = self._dict_code_plan(batch, n, bucket,
+                                                         gb_cols)
                     if sp is not None:
                         sp.args["cap"] = decode.cap
+                dcols, decode.dcodes = self.ctx.provision(batch, bucket, needed,
+                                                          codes=codes)
                 prog = stage._jit_for(decode.cap)
                 mask = device_row_mask(n, bucket)
                 offset = jnp.asarray(float(self._row_offset))
@@ -1399,7 +1476,7 @@ class DeviceJoinGroupedRun(GroupedAggRun):
                             "local-dense path cannot serve 64-bit scatter "
                             "extremes / f64-exact stages")
                     _pp, pdev, _l, _s = decode.fact_codes.perm_layout()
-                    dcols, _ = self.ctx.provision(batch, bucket, needed, (),
+                    dcols, _ = self.ctx.provision(batch, bucket, needed,
                                                   perm=(decode.pperm, pdev))
                     prog = stage._jit_local(decode.cap)
                     mask = device_row_mask(n, bucket)
@@ -1408,7 +1485,7 @@ class DeviceJoinGroupedRun(GroupedAggRun):
                         out = prog(dcols, decode.local_codes, decode.seg_lo,
                                    mask)
                 else:
-                    dcols, _ = self.ctx.provision(batch, bucket, needed, ())
+                    dcols, _ = self.ctx.provision(batch, bucket, needed)
                     prog = stage._jit_for(decode.cap)
                     mask = device_row_mask(n, bucket)
                     offset = jnp.asarray(float(self._row_offset))
@@ -1436,41 +1513,35 @@ class DeviceJoinGroupedRun(GroupedAggRun):
             total *= max(k, 1)
         return total
 
-    def _dict_combined_codes(self, batch, n: int, bucket: int, gb_cols,
-                             code_planes: Dict[str, object]) -> _Decode:
-        """Radix-combine per-column dictionary codes on device (fact codes
-        resident per Series; dim codes rode the packed row-gather)."""
+    def _dict_code_plan(self, batch, n: int, bucket: int, gb_cols):
+        """The host's side of the dictionary strategy: each group-by column's
+        dictionary and radix, and the resident code planes of the fact-side
+        columns (dim codes ride the packed row-gather). Returns the _Decode,
+        still without its codes, and the _CodePlan from which the
+        provisioning program radix-combines the planes on the device."""
         ctx = self.ctx
         spec = ctx.spec
-        encoded = []     # (device codes[bucket], values, K)
+        dicts = []           # (values, K) per group-by column
+        fact_codes: Dict[str, object] = {}
         for name in gb_cols:
             side = spec.col_side.get(name)
             if side == "fact":
                 s = batch.get_column(name)
                 codes, values, k = s.dict_codes()
-                encoded.append((cached_dict_code_plane(s, codes, n, bucket),
-                                values, k))
+                fact_codes[name] = cached_dict_code_plane(s, codes, n, bucket)
             else:
-                src = ctx._dim_source(side, name)
-                _codes, values, k = src.dict_codes()
-                encoded.append((code_planes[name], values, k))
-        total = 1
-        for _, _, k in encoded:
-            total *= max(k, 1)
-        cap = _pad_groups(total)
+                _codes, values, k = ctx._dim_source(side, name).dict_codes()
+            dicts.append((values, k))
         radices = []
         mult = 1
-        for _, _, k in reversed(encoded):
+        for _, k in reversed(dicts):
             radices.append(mult)
             mult *= max(k, 1)
         radices.reverse()
-        combined = encoded[0][0] * radices[0]
-        for (dc, _, _), r in zip(encoded[1:], radices[1:]):
-            combined = combined + dc * r
-        combined = jnp.clip(combined, 0, cap - 1)  # join-miss garbage is masked anyway
-        return _Decode(cap=cap, dcodes=combined,
-                       dicts=[(vals, k) for _, vals, k in encoded],
-                       radices=radices, key_rows=None)
+        cap = _pad_groups(mult)
+        return (_Decode(cap=cap, dcodes=None, dicts=dicts, radices=radices,
+                        key_rows=None),
+                _CodePlan(tuple(gb_cols), tuple(radices), cap, fact_codes))
 
     def _host_factorized_codes(self, batch, n: int, bucket: int) -> _Decode:
         """Joined-key group codes via host factorize over the static join

@@ -222,12 +222,14 @@ def test_tpch_q3_q10_ride_device_topn():
 
 
 def test_wide_int_dim_planes_exact_past_2_24(star):
-    """Dim-side int64/int32 columns ride the packed f32 gather as digit
-    planes and recombine exactly — and must STAY exact through the stage
-    compiler (ADVICE r5 high: the f64 recombine was downcast to f32 by fcast,
-    quantizing values past 2^24). SUM/MIN/MAX over wide int dim columns must
-    match the host bit-for-bit."""
+    """Dim-side int64/int32/uint32 columns ride the packed f32 gather as digit
+    planes and recombine exactly inside the traced provisioning program — and
+    must STAY exact through the stage compiler (ADVICE r5 high: the f64
+    recombine was downcast to f32 by fcast, quantizing values past 2^24).
+    SUM/MIN/MAX over wide int dim columns must match the host bit-for-bit:
+    past 2^24, negative, null, just under 2^53 and, unsigned, past 2^31."""
     fact, _, _ = star
+    top = (1 << 53) - 1
     wide = daft_tpu.from_pydict({
         "w_k": list(range(500)),
         # int64 values far past 2^24 (and sums past 2^32)
@@ -235,8 +237,21 @@ def test_wide_int_dim_planes_exact_past_2_24(star):
         # int32 values past 2^24 (f32 quantizes these)
         "w_mid": np.asarray([16_777_216 + i * 3 for i in range(500)],
                             dtype=np.int32),
+        # int64: negative digits, nulls, and the last value f64 still holds
+        "w_neg": [None if i % 11 == 0 else
+                  (top - i if i % 3 == 0 else -(1 << 40) * (i + 1) - i)
+                  for i in range(500)],
+        # int32 down to its minimum
+        "w_neg32": np.asarray([-(1 << 31) + i * 5 if i % 2 else -(16_777_217 + i)
+                               for i in range(500)], dtype=np.int32),
+        # uint32 past 2^31 (no int32 holds these)
+        "w_u32": np.asarray([(1 << 31) + 12_345 + i * 1_000_003 if i % 2
+                             else (1 << 32) - 1 - i for i in range(500)],
+                            dtype=np.uint32),
         "w_grp": [f"g{i % 5}" for i in range(500)],
     }).collect()
+    assert wide.schema["w_neg"].dtype == daft_tpu.DataType.int64()
+    assert wide.schema["w_u32"].dtype == daft_tpu.DataType.uint32()
 
     def q():
         return (fact.join(wide, left_on="f_k1", right_on="w_k")
@@ -246,14 +261,112 @@ def test_wide_int_dim_planes_exact_past_2_24(star):
                      col("w_big").max().alias("mx64"),
                      col("w_mid").sum().alias("s32"),
                      col("w_mid").min().alias("mn32"),
-                     col("w_mid").max().alias("mx32"))
+                     col("w_mid").max().alias("mx32"),
+                     col("w_neg").min().alias("mnneg"),
+                     col("w_neg").max().alias("mxneg"),
+                     col("w_neg").count().alias("cneg"),
+                     col("w_neg32").sum().alias("sneg32"),
+                     col("w_neg32").min().alias("mnneg32"),
+                     col("w_u32").sum().alias("su32"),
+                     col("w_u32").min().alias("mnu32"),
+                     col("w_u32").max().alias("mxu32"))
                 .sort("w_grp"))
 
-    host, dev, jb = _both(q)
+    host, dev, jb = _both(q)          # _both zeroes the counters before the device run
     assert jb > 0, "device join path never ran"
-    # bit-for-bat integer equality — no float tolerance
+    assert counters.join_provision_calls == jb, "the traced program served every dispatch"
+    assert max(host["mxneg"]) >= top - 500 and min(host["mnneg"]) < -(1 << 48)
+    assert max(host["mxu32"]) > (1 << 31)
+    # bit-for-bit integer equality — no float tolerance
     for c in host:
         assert host[c] == dev[c], (c, host[c], dev[c])
+
+
+# ---- the five routes into _JoinContext.provision all take the traced program ----------
+
+
+def _route_dict(fact, d1, cut):
+    return (fact.join(d1, left_on="f_k1", right_on="d1_k")
+            .where((col("f_q") > cut) & (col("d1_w") < float(cut)))
+            .groupby("d1_grp")
+            .agg(col("f_v").sum().alias("sv"), (col("f_v") * col("d1_w")).sum().alias("svw"))
+            .sort("d1_grp"))
+
+
+def _route_host(fact, d1, cut):
+    # dictionary product 500 x 500 is past the matmul ceiling; the true count (500) is not
+    return (fact.join(d1, left_on="f_k1", right_on="d1_k")
+            .where(col("f_q") > cut)
+            .groupby("f_k1", "d1_k")
+            .agg(col("f_v").sum().alias("sv"), col("d1_w").sum().alias("sw"))
+            .sort("f_k1"))
+
+
+def _route_host_permuted(fact, d1, cut):
+    # some 13,000 true groups: past the ceiling, so rows go group-sorted
+    return (fact.join(d1, left_on="f_k1", right_on="d1_k")
+            .where(col("d1_w") < float(cut))
+            .groupby("f_k1", "f_q")
+            .agg(col("f_v").sum().alias("sv"), col("d1_w").sum().alias("sw"))
+            .sort(["f_k1", "f_q"]))
+
+
+def _route_topn(fact, d1, cut):
+    return (fact.join(d1, left_on="f_k1", right_on="d1_k")
+            .where(col("f_q") > cut)
+            .groupby("f_k1", "d1_k2")
+            .agg((col("f_v") * col("d1_w")).sum().alias("rev"))
+            .sort(["rev", "f_k1"], desc=[True, False]).limit(7))
+
+
+def _route_ungrouped(fact, d1, cut):
+    return (fact.join(d1, left_on="f_k1", right_on="d1_k")
+            .where((col("f_q") > cut) & col("d1_grp").is_in(["g1", "g3"]))
+            .agg(col("f_v").sum().alias("s"), col("d1_w").sum().alias("w"),
+                 col("f_v").count().alias("c")))
+
+
+@pytest.mark.parametrize("shape,route", [
+    (_route_dict, ("grouped", True, False)),
+    (_route_host, ("grouped", False, False)),
+    (_route_host_permuted, ("grouped", False, True)),
+    (_route_topn, ("topn", False, False)),
+    (_route_ungrouped, ("ungrouped", False, False)),
+], ids=["dict_codes", "host_codes", "host_codes_permuted", "topn", "ungrouped"])
+def test_every_route_provisions_through_one_traced_program(star, monkeypatch, shape, route):
+    """The same star query twice, then with another filter literal: the
+    provisioning program is traced on the first run only (a literal is no
+    part of its key), serves every join dispatch, and the answers are the
+    host tier's."""
+    from daft_tpu.ops import device_join as dj
+
+    fact, d1, _ = star
+    seen = []
+    real = dj._JoinContext.provision
+
+    def spy(self, batch, bucket, needed, codes=None, perm=None):
+        seen.append((codes is not None, perm is not None))
+        return real(self, batch, bucket, needed, codes=codes, perm=perm)
+
+    monkeypatch.setattr(dj._JoinContext, "provision", spy)
+    dj._provision_program.cache_clear()      # other tests may have traced this layout
+    traced = []
+    for cut in (10, 10, 25):
+        q = lambda: shape(fact, d1, cut)
+        with execution_config_ctx(device_mode="off"):
+            host = q().to_pydict()
+        counters.reset()
+        with execution_config_ctx(device_mode="on"):
+            dev = q().to_pydict()
+        assert counters.device_join_batches > 0, counters.rejections
+        assert counters.join_provision_calls == counters.device_join_batches
+        kind, by_dict, permuted = route
+        assert set(seen) == {(by_dict, permuted)}, seen
+        assert (counters.device_topn_runs > 0) == (kind == "topn")
+        assert (counters.device_stage_batches > 0) == (kind == "ungrouped")
+        traced.append(counters.join_provision_traces)
+        _assert_close(host, dev)
+    assert traced[0] >= 1 and traced[1:] == [0, 0], traced
 
 
 def test_auto_mode_cpu_backend_stays_on_host(star):

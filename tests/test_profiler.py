@@ -269,6 +269,59 @@ def test_star_join_span_tree(monkeypatch, pipeline_mode):
     assert encode["args"]["rows"] == 5 and encode["ts"] >= query["ts"] + query["dur"] - eps
 
 
+@pytest.mark.parametrize("keys", [("g",), ("fk", "k")], ids=["dict_codes", "host_codes"])
+def test_every_join_dispatch_holds_its_parts(keys):
+    """What `benchmark/layer_metrics/join.*_ms.py` and `stages.launch_ms` read:
+    every `device.dispatch` of the join path holds `join.codes`, `join.index`,
+    `join.gather` and `device.launch` at least once, by parent chain and by
+    containment in time (the harness's sink keeps no ids), with `join.index`
+    inside `join.gather` now that the gather's span covers the look-ups and
+    the one call of the traced program."""
+    fact, dim = _star_join_frames()
+    # a column the query leaves out: the pruning Project is what the pipeline cuts into morsels
+    fact = fact.with_column("unused", col("v") * 2).collect()
+    q = lambda: (fact.join(dim, left_on="fk", right_on="k").groupby(*keys)
+                 .agg(col("v").sum().alias("s")).sort(list(keys)))
+    rec = SpanRecorder()
+    with execution_config_ctx(device_mode="on", device_min_rows=1, mesh_devices=1,
+                              morsel_size_rows=4096, pipeline_mode="force"):
+        expect = q().to_pydict()
+        set_spans(rec)
+        try:
+            got = q().to_pydict()
+        finally:
+            set_spans(None)
+    assert got == expect and rec.dropped == 0
+    spans = rec.drain()
+    by_id = {s["args"]["id"]: s for s in spans}
+
+    def under(s, top):
+        while s["args"]["parent"]:
+            s = by_id[s["args"]["parent"]]
+            if s is top:
+                return True
+        return False
+
+    eps = 1e-6
+    dispatches = [s for s in spans if s["name"] == "device.dispatch"]
+    assert len(dispatches) >= 4, "one join dispatch per fact morsel"
+    parts = ("join.codes", "join.index", "join.gather", "device.launch")
+    for d in dispatches:
+        inside = [s for s in spans if under(s, d)]
+        names = [s["name"] for s in inside]
+        for part in parts:
+            assert part in names, (part, names)
+        assert names.count("join.gather") == 1 and names.count("device.launch") == 1
+        for s in inside:
+            assert d["ts"] - eps <= s["ts"] and s["ts"] + s["dur"] <= d["ts"] + d["dur"] + eps
+        gather = next(s for s in inside if s["name"] == "join.gather")
+        assert any(s["name"] == "join.index" and under(s, gather) for s in inside)
+    # no part of a join dispatch outside one
+    for s in spans:
+        if s["name"] in ("join.gather", "device.launch"):
+            assert any(under(s, d) for d in dispatches), s
+
+
 def test_first_touch_spans_and_counters():
     """A first run over fresh tables uploads planes and dictionary-encodes
     the group key: `device.upload`, `series.dict_encode` and `residency.build`
