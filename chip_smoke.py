@@ -204,8 +204,7 @@ def phase_host(tables, queries) -> dict:
     return answers
 
 
-_ZERO_AFTER = ("pallas_fallbacks", "mesh_unavailable_fallbacks",
-               "device_udf_fallbacks")
+_ZERO_AFTER = ("mesh_unavailable_fallbacks", "device_udf_fallbacks")
 
 
 def phase_forced(tables, queries, host) -> None:
@@ -318,9 +317,7 @@ def phase_pallas(tables, host_check: bool = True) -> None:
                  device_batches=int(snap.get("device_grouped_batches", 0)
                                     + snap.get("device_stage_batches", 0)),
                  **{counter: n},
-                 pallas_fallbacks=int(snap.get("pallas_fallbacks", 0)),
                  rejections=rej)
-            check(snap.get("pallas_fallbacks", 0) == 0, "pallas_fallbacks != 0")
             check((n > 0) == (mode == "on"),
                   f"{name}: {counter} under pallas_mode={mode}", got=n)
         check(res["on"] == res["off"],
@@ -411,11 +408,10 @@ def _repartition_check(tables, n: int, counter: str) -> None:
     ms = (time.perf_counter() - t0) * 1e3
     exchanges = {k: getattr(counters, k) for k in (
         "mesh_alltoall_dispatches", "mesh_fused_permute_dispatches",
-        "mesh_alltoall_ici_bytes", "pallas_fallbacks")}
+        "mesh_alltoall_ici_bytes")}
     emit(phase="mesh", repartition_rows=orders.count_rows(), ms=round(ms, 1),
          **exchanges, rejections=dict(counters.rejections))
     check(exchanges[counter] > 0, f"repartition: {counter} is 0")
-    check(exchanges["pallas_fallbacks"] == 0, "pallas_fallbacks != 0")
 
     def part(p):
         bs = [b for b in p.batches if b.num_rows]

@@ -2,7 +2,6 @@
 
     python -m daft_tpu info                 # engine/backend/device summary
     python -m daft_tpu sql "SELECT ..."     # run SQL over registered files
-    python -m daft_tpu bench                # run the TPC-H benchmark
     python -m daft_tpu schema PATH          # print a file's inferred schema
 """
 
@@ -10,7 +9,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import sys
 
 
 def _cmd_info(_args) -> int:
@@ -71,16 +69,6 @@ def _cmd_schema(args) -> int:
     return 0
 
 
-def _cmd_bench(_args) -> int:
-    import runpy
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, root)
-    runpy.run_path(os.path.join(root, "bench.py"), run_name="__main__")
-    return 0
-
-
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(prog="daft_tpu")
     sub = p.add_subparsers(dest="cmd", required=True)
@@ -93,10 +81,9 @@ def main(argv=None) -> int:
     sp.add_argument("--json", action="store_true")
     sc = sub.add_parser("schema")
     sc.add_argument("path")
-    sub.add_parser("bench")
     args = p.parse_args(argv)
-    return {"info": _cmd_info, "sql": _cmd_sql, "schema": _cmd_schema,
-            "bench": _cmd_bench}[args.cmd](args)
+    return {"info": _cmd_info, "sql": _cmd_sql,
+            "schema": _cmd_schema}[args.cmd](args)
 
 
 if __name__ == "__main__":

@@ -33,7 +33,7 @@ class ExecutionConfig:
     # the planner collapse a Filter/Project chain under an Aggregate into ONE
     # fused device region (one h2d/d2h + one coalesced dispatch stream for
     # the whole chain); "off" restores the legacy capture (peel at most the
-    # one directly-adjacent Filter) — an A/B switch for the fusion microbench
+    # one directly-adjacent Filter) — an A/B switch for tests/test_fused_region.py
     # and a containment valve, not a perf knob.
     region_mode: str = field(
         default_factory=lambda: os.environ.get("DAFT_TPU_REGION", "on")

@@ -87,7 +87,7 @@ class DataFrame:
         wait, shuffle volumes, straggler flags, per-worker attribution) from
         the run's QueryTrace, plus the per-query metrics-registry deltas
         (device batches, shuffle bytes) so engine-path attribution is in the
-        report, not only in bench.py.
+        report.
 
         `profile="trace.json"` additionally writes the query's timeline as
         Chrome trace-event JSON (QueryTrace.to_chrome_trace) — open it in

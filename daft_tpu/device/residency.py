@@ -65,7 +65,7 @@ Design:
   hbm_eviction_bytes / hbm_pins counters plus hbm_bytes_resident /
   hbm_bytes_high_water gauges in the process metrics registry
   (observability/metrics.py), so per-query deltas land in QueryEnd.metrics,
-  EXPLAIN ANALYZE's engine-counter table, worker heartbeats, and bench.py.
+  EXPLAIN ANALYZE's engine-counter table and worker heartbeats.
 
 Zero-overhead contract: a host-only query never touches the manager (nothing
 imports jax here; entries only appear when a device path uploads), and lookup
@@ -819,7 +819,7 @@ class ResidencyManager:
             return len(self._entries)
 
     def stats(self) -> dict:
-        """Registry-consistent snapshot for bench/test assertions."""
+        """Registry-consistent snapshot (the dashboard's /metrics, tests)."""
         reg = registry()
         with self._lock:
             self._sweep_dead()

@@ -138,7 +138,7 @@ class ShuffleFetchServer:
         self._closed = False
         self._threads: List[threading.Thread] = []
         # served-request counters (reference: flight_server metrics); mirrored
-        # into the metrics registry so EXPLAIN ANALYZE / bench can attribute
+        # into the metrics registry so EXPLAIN ANALYZE and /metrics can attribute
         # transport traffic
         self._stats_lock = threading.Lock()
         self.requests = 0

@@ -114,7 +114,7 @@ class QueryTrace:
                         acc[k] = acc.get(k, 0) + v
         if result.shuffle:
             # mirror into the driver's registry so the per-query metrics diff
-            # (QueryEnd.metrics, bench snapshot) carries cluster-wide volume
+            # (QueryEnd.metrics) carries cluster-wide volume
             for k in ("bytes_written", "rows_written", "bytes_fetched",
                       "rows_fetched"):
                 v = result.shuffle.get(k, 0)
@@ -138,7 +138,7 @@ class QueryTrace:
             # device-path attribution crosses the process boundary the same
             # way: a device-leased worker's dispatches/coalescing land in the
             # driver's per-query diff (distributed EXPLAIN ANALYZE engine
-            # counters, QueryEnd.metrics, bench snapshot). Curated list —
+            # counters, QueryEnd.metrics). Curated list —
             # shuffle counters are mirrored above from result.shuffle, and
             # gauges don't sum across processes.
             for k in _MIRRORED_ENGINE_COUNTERS:

@@ -718,7 +718,7 @@ def _exec_device_agg(node) -> MicroPartition:
     site = "grouped agg" if grouped else "agg"
     if prec is None and cfg.device_mode == "on":
         # forced run: recorded so the ledger attributes the dispatch; priced
-        # too under DAFT_TPU_PLACEMENT_PRICE_FORCED so forced captures yield
+        # too under DAFT_TPU_PLACEMENT_PRICE_FORCED so forced runs yield
         # predicted-vs-observed calibration samples (the calibrate tool)
         if _env_bool("DAFT_TPU_PLACEMENT_PRICE_FORCED", False):
             first = next(stream, None)
@@ -974,8 +974,8 @@ def _unwrap_udf_agg_input(agg_input):
 def _note_region(node, region_ops, dispatches: int) -> None:
     """Attribution for one completed fused-region run: every device dispatch
     the region issued covered len(region_ops) operators in one RTT. Counted
-    only for genuine regions (>= 2 fused ops) so the bench-derived
-    fused_dispatch_ratio measures fusion, not bare aggs; the EXPLAIN ANALYZE
+    only for genuine regions (>= 2 fused ops) so ops_fused / dispatches
+    measures fusion, not bare aggs; the EXPLAIN ANALYZE
     line makes the amortization visible per node."""
     if dispatches <= 0 or len(region_ops) < 2:
         return
@@ -1334,7 +1334,7 @@ def _run_device_join(node, label: str, make_run, assemble,
                 if batch0 is not None:
                     # forced run, priced anyway: the ledger record carries
                     # every tier's CostBreakdown (mesh arm included) so
-                    # forced captures yield calibration samples + the
+                    # forced runs yield calibration samples + the
                     # three-way what-if in EXPLAIN PLACEMENT; `chosen` is
                     # pinned to the tier that executes below
                     _t, prec = _join_device_wins(
@@ -2074,7 +2074,7 @@ def _device_wins(node, first: MicroPartition, grouped: bool,
     `forced=True` (device_mode=on with DAFT_TPU_PLACEMENT_PRICE_FORCED) runs
     the SAME pricing but only to populate the ledger — the verdict is ignored
     by the caller and the record is marked forced, so the calibrate tool gets
-    predicted-vs-observed samples from forced captures too.
+    predicted-vs-observed samples from forced runs too.
     """
     from ..config import execution_config
     from ..ops import costmodel
@@ -2285,7 +2285,7 @@ def _two_phase_agg(child: pp.PhysicalPlan, groupby, aggs, ungrouped: bool,
     resource_manager.rs memory gating). Tracked bytes release as buffers
     flush to disk and unconditionally when the operator finishes.
     """
-    from . import memory as mem
+    from .. import memory as mem
 
     budget = mem.operator_budget()
     try:
@@ -2297,7 +2297,7 @@ def _two_phase_agg(child: pp.PhysicalPlan, groupby, aggs, ungrouped: bool,
 
 def _two_phase_agg_impl(child: pp.PhysicalPlan, groupby, aggs, ungrouped: bool,
                         stream, node, budget) -> RecordBatch:
-    from . import memory as mem
+    from .. import memory as mem
     from ..plan.agg_split import split_aggs
     from ..utils.pool import pool_map
 
@@ -2416,7 +2416,7 @@ def _ungrouped_agg_spilled(child: pp.PhysicalPlan, aggs, stream,
     split individually stream partials from the spill; anything else gathers
     only its value column (one column, not the whole table). Reference:
     blocking_sink.rs memory gating + grouped spill strategies."""
-    from . import memory as mem
+    from .. import memory as mem
     from ..core.series import Series
     from ..expressions import col as _col
     from ..expressions.expressions import AggExpr, Alias
@@ -2500,7 +2500,7 @@ def _sort_exec(node: pp.PhysSort) -> Iterator[MicroPartition]:
     the input stream in order, the per-run sort is stable (np.lexsort), and
     the merge breaks cross-run ties by run index — exactly the order a
     stable sort of the whole stream produces."""
-    from . import memory as mem
+    from .. import memory as mem
     from ..observability.metrics import registry
     from ..observability.runtime_stats import profile_span
 
@@ -2572,7 +2572,7 @@ _MERGE_FANIN = 16
 def _merge_sorted_runs(node: pp.PhysSort, runs) -> Iterator[MicroPartition]:
     """Merge sorted spill runs into one globally sorted stream, cascading
     through intermediate runs while the fan-in exceeds _MERGE_FANIN."""
-    from . import memory as mem
+    from .. import memory as mem
     from ..observability.metrics import registry
 
     live = [f for f in runs if f.rows > 0]
@@ -2840,7 +2840,7 @@ def _window_exec(node) -> Iterator[MicroPartition]:
 
     Output row order: under budget, original input order (results scatter
     back); spilled, rows come out grouped by spill partition."""
-    from . import memory as mem
+    from .. import memory as mem
     from ..observability.runtime_stats import profile_span
     from .window import eval_window
 
@@ -2902,7 +2902,7 @@ def _join_exec(node: pp.HashJoin) -> Iterator[MicroPartition]:
     over budget, both sides Grace-partition into K co-partitioned spill files
     by join-key hash and the join runs per partition (correct for every join
     type since equal keys land in the same partition)."""
-    from . import memory as mem
+    from .. import memory as mem
 
     budget = mem.operator_budget()
     try:
@@ -2912,7 +2912,7 @@ def _join_exec(node: pp.HashJoin) -> Iterator[MicroPartition]:
 
 
 def _join_exec_impl(node: pp.HashJoin, budget) -> Iterator[MicroPartition]:
-    from . import memory as mem
+    from .. import memory as mem
     from ..observability.runtime_stats import profile_span
 
     right_it = _batch_iter(_exec(node.right))
@@ -3180,10 +3180,10 @@ def _mesh_repartition(node, n: int) -> Iterator[MicroPartition]:
     with the exact partition_by_hash function, each shard stable-sorts its
     rows by destination on device, and the exchanged planes come back in
     (source shard, stream order) — bit-identical partition contents and row
-    order versus the host path, asserted in tests and the BENCH_MESH
-    capture. A column with no device layout falls back to host bucketing of
-    the already-collected batches (results identical, rejection counted); a
-    program that does not lower or run raises."""
+    order versus the host path (tests/test_mesh_join.py). A column with no
+    device layout falls back to host bucketing of the already-collected
+    batches (results identical, rejection counted); a program that does not
+    lower or run raises."""
     from ..config import execution_config
     from ..ops import counters as _counters
     from ..ops.grouped_stage import DeviceFallback

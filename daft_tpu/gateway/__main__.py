@@ -5,7 +5,7 @@
 Prints ``gateway listening on HOST:PORT`` once the socket is bound (tests
 and scripts parse this line to learn the chosen port when --port 0), then
 serves until SIGINT/SIGTERM. ``--demo-rows N`` registers a deterministic
-demo table ``t`` (the BENCH_SERVE shape: k = i%601, v = float(i%8191),
+demo table ``t`` (k = i%601, v = float(i%8191),
 w = i%97) — deterministic ON PURPOSE: the same rows on every launch means
 the same source content fingerprints, which is what lets a relaunched
 gateway resume its predecessor's committed checkpoints and hit its persisted

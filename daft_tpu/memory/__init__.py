@@ -4,8 +4,7 @@ This package is the HOST-side counterpart of the HBM ResidencyManager
 (device/residency.py): one byte ledger every memory-hungry site admits
 against (``manager()``), plus the disk-spill machinery (compressed Arrow IPC
 spill files, Grace hash partitions, sorted runs) those sites switch to when
-the ledger says no. ``execution/memory.py`` remains as the backward-
-compatible view over this package.
+the ledger says no.
 """
 
 from .manager import (HostMemoryManager, LedgerBudget, QueryMemoryScope,

@@ -6,7 +6,7 @@ sites currently hold, with a budget resolved from config
 (``DAFT_TPU_MEMORY_LIMIT``), per-operator admission handles, pressure
 callbacks, and ``host_bytes_tracked`` / ``host_bytes_high_water`` gauges in
 the process metrics registry so per-query deltas land in QueryEnd.metrics,
-EXPLAIN ANALYZE, the Prometheus exposition, and bench JSON.
+EXPLAIN ANALYZE and the Prometheus exposition.
 
 Budget semantics (config.memory_limit_bytes):
 
@@ -231,23 +231,6 @@ class HostMemoryManager:
                 if scope in self._scopes:
                     self._scopes.remove(scope)
 
-    # ---- introspection -------------------------------------------------------------
-    def stats(self) -> dict:
-        """Registry-consistent snapshot for bench/test assertions."""
-        reg = registry()
-        limit = self.limit_bytes()  # outside the ledger lock (reads config)
-        with self._cond:
-            tracked, high = self._tracked, self._high_water
-        return {
-            "host_limit_bytes": limit,
-            "host_bytes_tracked": tracked,
-            "host_bytes_high_water": high,
-            "spill_bytes": reg.get("spill_bytes"),
-            "spill_wire_bytes": reg.get("spill_wire_bytes"),
-            "spill_runs": reg.get("spill_runs"),
-            "scan_backpressure_stalls": reg.get("scan_backpressure_stalls"),
-        }
-
     def clear(self) -> None:
         """Drop ledger state (test hook). Does not reset registry counters —
         memory.reset_counters() owns those."""
@@ -358,6 +341,5 @@ def manager() -> HostMemoryManager:
 
 
 def operator_budget() -> LedgerBudget:
-    """Admission handle against the process ledger for one blocking operator
-    (the re-homed successor of execution.memory.MemoryBudget)."""
+    """Admission handle against the process ledger for one blocking operator."""
     return _MANAGER.operator_budget()

@@ -47,8 +47,7 @@ Attribution: spill_batches / spill_bytes (logical) / spill_wire_bytes
 counters in the process registry (observability/metrics.py), plus the async
 overlap split (spill_write_seconds vs spill_write_wall_seconds,
 spill_read_seconds vs spill_read_wall_seconds, spill_prefetch_inflight) so
-spill activity reaches QueryEnd.metrics, EXPLAIN ANALYZE, /metrics, and
-bench JSON.
+spill activity reaches QueryEnd.metrics, EXPLAIN ANALYZE and /metrics.
 """
 
 from __future__ import annotations

@@ -43,12 +43,11 @@ from .subscribers import Subscriber, attach_subscriber, detach_subscriber
 # the placement_* counters and the cost_* calibration/error gauges.
 # v10: adds the flight_anomaly record kind (observability/flight.py — kind,
 # detail, query_id, tenant, dump_path); query_end.metrics may carry the
-# flight_* counters; bench captures gain per_query_profile (per-query
-# operator compute/starve/blocked splits + counter deltas).
+# flight_* counters.
 # v11: adds the gateway_query record kind (daft_tpu/gateway/ — tenant,
 # seconds, rows, source executed|result_cache|checkpoint, bytes_streamed,
-# prepared_handle; see events.GatewayQueryRecord); query_end.metrics and
-# serve captures may carry the gateway_*/result_cache_* counters.
+# prepared_handle; see events.GatewayQueryRecord); query_end.metrics may
+# carry the gateway_*/result_cache_* counters.
 SCHEMA_VERSION = 11
 
 

@@ -6,8 +6,7 @@ subscribers snapshot per query. Here the registry is the single home for
 engine-path attribution counters (device batches, shuffle bytes, fetch-server
 requests); `ops/counters.py` re-exports the device names for backward
 compatibility, and runners record a per-query `diff()` into QueryEnd so
-device/shuffle attribution lands in EXPLAIN ANALYZE and the event log instead
-of only in bench.py.
+device/shuffle attribution lands in EXPLAIN ANALYZE and the event log.
 
 Zero-overhead contract: nothing in the engine's hot path reads the registry;
 writes only happen on coarse events (a device dispatch, a shuffle file, a
@@ -122,7 +121,7 @@ _REGISTRY = MetricsRegistry()
 # happens to load or the first increment lands.
 
 # Device/mesh/UDF path attribution. ops/counters.py re-exports this group as
-# COUNTER_NAMES (PEP 562 attribute views + the scoped test/bench reset).
+# COUNTER_NAMES (PEP 562 attribute views + the scoped test reset).
 DEVICE_COUNTER_NAMES = (
     "device_stage_batches",    # batches through FilterAggStage (ungrouped)
     "device_grouped_batches",  # batches through GroupedAggStage
@@ -186,14 +185,12 @@ DEVICE_COUNTER_NAMES = (
     # whole-stage fused regions (ops/region.py capture + executor wiring):
     # a dispatch of a node whose fused chain spans >= 2 operators counts
     # once here and len(chain) times in ops_fused, so
-    # ops_fused / dispatches = mean operators amortized per RTT (the
-    # fused_dispatch_ratio bench derivation).
+    # ops_fused / dispatches = mean operators amortized per RTT.
     "device_region_dispatches",   # device dispatches issued by fused regions
     "device_region_ops_fused",    # operators covered by those dispatches
     # Pallas kernel tier (ops/pallas_kernels.py: segment-reduce groupby,
     # hash-probe join, in-kernel ICI ring permute)
     "pallas_dispatches",       # grouped-agg batches through the Pallas kernel
-    "pallas_fallbacks",        # tripwire, asserted 0: a failed kernel raises
     "pallas_probe_dispatches",  # join index planes probed in-kernel
     # intra-host repartition exchanged by the in-kernel ring permute instead
     # of a standalone all_to_all dispatch (mesh_alltoall_dispatches stays 0)
@@ -201,7 +198,7 @@ DEVICE_COUNTER_NAMES = (
 )
 
 # Serving-tier counters OUTSIDE the ops/counters.py reset scope (cancellation
-# is resolved on the session thread; a bench/test device-counter reset must
+# is resolved on the session thread; a test's device-counter reset must
 # not wipe it mid-session).
 SERVING_COUNTER_NAMES = (
     "serve_cancelled_total",
@@ -300,9 +297,8 @@ SPILL_COUNTER_NAMES = (
     # threads=0 path never touches these, preserving the compat guard).
     # Overlap discipline mirrors the PR 5 shuffle fetch split: cumulative
     # off-thread seconds vs the wall seconds the CALLER actually paid
-    # (queue-full stalls + finish joins / prefetch-queue waits); the derived
-    # spill_io_overlap_seconds = max(write - write_wall, 0) +
-    # max(read - read_wall, 0) is attached by bench.py.
+    # (queue-full stalls + finish joins / prefetch-queue waits): the IO the
+    # pool hid is max(write - write_wall, 0) + max(read - read_wall, 0).
     "spill_write_seconds",       # cumulative IO-thread compress+write time
     "spill_write_wall_seconds",  # wall seconds spill writes cost the producer
     "spill_read_seconds",        # cumulative IO-thread decode time (prefetch)
@@ -360,7 +356,7 @@ DECLARED_GAUGES = (
     # cost-model observability (ops/costmodel.py + observability/placement.py)
     "cost_model_error_ratio",  # last dispatched stage: observed/predicted s/row
     # the effective Calibration terms, exported at calibrate() so every
-    # scrape and bench capture states the calibration the process ran under
+    # scrape states the calibration the process ran under
     "cost_rtt_s",
     "cost_h2d_bytes_per_s",
     "cost_d2h_bytes_per_s",

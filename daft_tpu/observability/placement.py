@@ -2,7 +2,7 @@
 
 Every auto-tier placement decision the executor makes (device agg, grouped
 agg, mesh tier, gather join, TopN join, device UDF) used to collapse into a
-one-line rejection string — EXPLAIN, /metrics, and bench captures could not
+one-line rejection string — EXPLAIN and /metrics could not
 say WHICH cost term kept a query on host or how wrong the prediction was
 versus the dispatch the engine actually timed. This module is the missing
 record:
@@ -15,8 +15,7 @@ record:
 - :class:`PlacementLedger` — the process-wide, bounded, lock-disciplined sink
   (cap ``DAFT_TPU_PLACEMENT_LEDGER``, drops counted — the SpanRecorder
   discipline). Serves ``df.explain_placement()``, the dashboard's
-  ``/api/placement``, bench placement verdicts, and the
-  ``daft_tpu.tools.calibrate`` report.
+  ``/api/placement`` and the ``daft_tpu.tools.calibrate`` report.
 - :func:`query_scope` — per-query record isolation. The scope rides the same
   thread-local-plus-stage-thread propagation as the stats collector
   (pipeline.spawn_stage), so concurrent serving queries never bleed records
@@ -328,9 +327,9 @@ class PlacementLedger:
 
     def error_summary(self) -> dict:
         """Aggregate prediction-error stats over records with feedback:
-        {"samples": n, "median": r, "max": r} — what bench captures record
-        and `bench.py --compare` gates drift on (error_ratio 1.0 = the model
-        predicted the dispatch exactly; 10.0 = 10x too optimistic)."""
+        {"samples": n, "median": r, "max": r}, served by the dashboard's
+        /api/placement (error_ratio 1.0 = the model predicted the dispatch
+        exactly; 10.0 = 10x too optimistic)."""
         ratios = sorted(r.error_ratio for r in self.records()
                         if r.error_ratio is not None)
         if not ratios:

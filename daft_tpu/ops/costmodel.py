@@ -167,8 +167,8 @@ class Calibration:
     udf_host_flops_per_s: float = 5e9
     # Pallas blocked segment-reduce (ops/pallas_kernels.py): one-hot tiles
     # built in VMEM, so cells stream compute-bound instead of HBM-bound.
-    # Conservative v5e default (~20x the XLA one-hot cell rate); measured
-    # captures should override via DAFT_TPU_COST_PALLAS_RATE. Defaulted so
+    # Conservative v5e default (~20x the XLA one-hot cell rate), not measured;
+    # overridden via DAFT_TPU_COST_PALLAS_RATE. Defaulted so
     # old call sites construct.
     pallas_cell_rate: float = 1e12
     # Pallas hash-probe join (ops/pallas_kernels.py hash_probe_index): fact
@@ -195,7 +195,7 @@ _RESET_HOOKS: List[Callable[[], None]] = []
 _HOOK_LOCK = threading.Lock()
 
 # The calibration terms exported as gauges (observability/metrics.py declares
-# them) so /metrics, QueryEnd.metrics, and every bench JSON state the
+# them) so /metrics and QueryEnd.metrics state the
 # calibration the process actually ran under.
 _CAL_GAUGES = (
     ("cost_rtt_s", "rtt_s"),
@@ -223,9 +223,8 @@ def current_calibration() -> Optional[Calibration]:
 
 def calibration_dict() -> Dict[str, float]:
     """The effective calibration terms as a flat dict ({} when the process
-    never calibrated) — recorded into every bench JSON and served by the
-    dashboard's /api/placement so each capture states the terms it ran
-    under."""
+    never calibrated) — served by the dashboard's /api/placement, printed by
+    `chip_smoke.py` and read by `tools/calibrate.py`."""
     cal = _CAL
     if cal is None:
         return {}
@@ -393,8 +392,8 @@ def _probe_mesh_terms(rtt: float):
 
 
 def _export_calibration_gauges(cal: Calibration) -> None:
-    """Publish the effective terms as gauges so every scrape/bench capture
-    states the calibration it ran under (satellite: cost_rtt_s & co)."""
+    """Publish the effective terms as gauges so every scrape states the
+    calibration it ran under (cost_rtt_s & co)."""
     from ..observability.metrics import registry
 
     reg = registry()

@@ -6,13 +6,13 @@ JAX device; tests assert these to prove the engine selected the device path
 
 The counters live in the process-wide MetricsRegistry
 (observability/metrics.py) so the same numbers reach EXPLAIN ANALYZE, the
-event log (QueryEnd.metrics), the dashboard, and bench.py. Module attribute
+event log (QueryEnd.metrics), the dashboard and /metrics. Module attribute
 reads (``counters.device_stage_batches``) keep working via PEP 562
 ``__getattr__`` — they read the registry.
 
 `rejections` records WHY a plan/stage stayed on host (capture bailed, cost
-model chose host, runtime DeviceFallback): {reason: count}. bench.py prints it
-so a host-only number is attributable, not silent (VERDICT r4 next #1).
+model chose host, runtime DeviceFallback): {reason: count}, so a host-only
+number is attributable, not silent (`chip_smoke.py` prints it).
 """
 
 from __future__ import annotations
@@ -70,7 +70,7 @@ def snapshot() -> Dict[str, float]:
 
 
 def reset() -> None:
-    """Zero the DEVICE counters and the rejection record (test/bench hook).
+    """Zero the DEVICE counters and the rejection record (test hook).
     Scoped to COUNTER_NAMES: other subsystems' registry counters (shuffle,
     fetch server) are not this module's to wipe — full wipes go through
     registry().reset(); per-query attribution uses snapshot/diff instead.

@@ -541,10 +541,8 @@ def try_build_mesh_grouped_agg_stage(schema: Schema,
 # selects this tier when the cost model's mesh arm wins (or mesh_devices
 # forces it): fact morsels shard over the local mesh, dim planes replicate as
 # resident HBM slots, the DispatchCoalescer feeds super-batches dispatch-only,
-# and finalize pays ONE d2h. Joins are the engine's headline raw-speed loss —
-# every rejection in BENCH_r05 reads "host wins" against a SINGLE chip; this
-# tier divides the join+agg compute by the mesh width so star shapes can win
-# honestly.
+# and finalize pays ONE d2h. The tier divides the join+agg compute by the mesh
+# width, so a star shape the host wins against a single chip can still win here.
 
 
 class _MeshJoinCodes:

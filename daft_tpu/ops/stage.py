@@ -275,7 +275,7 @@ class DispatchCoalescer:
     ``dispatch_coalesced`` give the amortization factor,
     ``bucket_fill_rows`` / ``bucket_capacity_rows`` the padding efficiency —
     the counter DELTAS are the per-query source of truth (they land in
-    QueryEnd.metrics; bench.py derives its capture-wide ratio from them).
+    QueryEnd.metrics).
     The ``bucket_fill_ratio`` gauge is this coalescer's running fill /
     capacity, published for dashboard convenience — it is a process-wide
     last-writer-wins value, so with several coalesced stages or concurrent

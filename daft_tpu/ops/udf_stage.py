@@ -28,7 +28,7 @@ stage with the same machinery the relational device path earned in PRs 2-8:
   ``hbm_bytes_resident``, published in heartbeat digests (deps-free slots
   carry stable keys), and repeat queries re-upload NOTHING
   (``device_udf_weight_h2d_bytes`` stays flat — counter-asserted in
-  ``BENCH_SUITE=ai``). No private ``_params_dev`` allocations remain.
+  ``tests/test_device_udf.py``). No private ``_params_dev`` allocations remain.
 
 - **Fusion**: when a ``DeviceUdfProject`` feeds a device agg stage, the
   ``FusedUdfAggFeeder`` hands the UDF's OUTPUT device plane straight into
@@ -37,8 +37,8 @@ stage with the same machinery the relational device path earned in PRs 2-8:
 Host fallback (``host_eval_device_func``) shares the same jit program,
 prepare/pad/finish pipeline and null semantics, executed eagerly per batch
 without stage/coalescer/residency machinery — bit-identical to the device
-tier whenever the dispatch shapes match (single-batch inputs; the
-``BENCH_SUITE=ai`` classify pipeline is shape-robust via argmax).
+tier whenever the dispatch shapes match (single-batch inputs; a classify
+pipeline is shape-robust via argmax).
 """
 
 from __future__ import annotations

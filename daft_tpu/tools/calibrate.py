@@ -15,9 +15,8 @@ Modes:
   priced breakdown AND an observation — works on any backend, including the
   CPU CI one.
 - ``--ledger FILE.json``: read records previously dumped with
-  ``daft_tpu.observability.placement.ledger().snapshot()`` (e.g. the
-  ``placement_records`` a bench capture can write) instead of running the
-  probe workload.
+  ``daft_tpu.observability.placement.ledger().snapshot()`` instead of
+  running the probe workload.
 - ``--json``: machine-readable output (the report dict) instead of text.
 
 Suggestion mechanics (coarse on purpose — the model only needs to be right

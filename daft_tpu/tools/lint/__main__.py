@@ -65,8 +65,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="python -m daft_tpu.tools.lint")
     ap.add_argument("paths", nargs="*", help="files/dirs (default: daft_tpu/)")
     ap.add_argument("--json", action="store_true",
-                    help="machine-readable findings + per-rule counts "
-                    "(bench.py-style tooling diffs these across PRs)")
+                    help="machine-readable findings + per-rule counts")
     ap.add_argument("--baseline", default=DEFAULT_BASELINE)
     ap.add_argument("--no-baseline", action="store_true")
     ap.add_argument("--write-baseline", action="store_true",

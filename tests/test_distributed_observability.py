@@ -125,7 +125,7 @@ def test_distributed_explain_analyze_renders_stage_skew(dist_runner):
     assert "min/median/max task" in report
     assert "shuffle:" in report and "final:" in report
     assert "worker-0" in report or "worker-1" in report
-    # device/shuffle attribution appears in the report, not only bench.py
+    # device/shuffle attribution appears in the report
     assert "== Engine Counters ==" in report
     assert "shuffle_bytes_written" in report
 

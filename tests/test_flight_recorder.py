@@ -1,7 +1,7 @@
 """Flight recorder: bounded ring + drop accounting, zero-overhead off-switch,
 per-kind anomaly triggers (slow query EMA, query error, ledger pressure,
 device fallback, worker death), multi-tenant dump no-bleed under a threaded
-serving hammer, and the doctor CLI over committed captures and fresh dumps."""
+serving hammer, and the doctor CLI over fresh dumps."""
 
 import json
 import os
@@ -319,23 +319,6 @@ def test_serving_hammer_dump_has_no_cross_tenant_bleed(monkeypatch, tmp_path):
 # doctor CLI
 # ---------------------------------------------------------------------------
 
-def test_doctor_compare_names_regressed_operators_and_counters():
-    """The committed SF10 r04->r05 pair (the 0.62x out-of-core regression)
-    must produce concrete attribution: the worst queries ranked, the
-    device-tier disengagement, and the streaming-scan/host-ledger tax."""
-    out = subprocess.run(
-        [sys.executable, "-m", "daft_tpu.tools.doctor", "--compare",
-         "BENCH_SF10_r04.json", "BENCH_SF10_r05.json"],
-        capture_output=True, text=True, cwd=REPO, timeout=120)
-    assert out.returncode == 0, out.stderr
-    text = out.stdout
-    assert "q1" in text and "46.8" in text          # worst offender, ranked
-    assert "device_batches: 4 -> 0" in text
-    assert "rss_high_water_bytes" in text
-    assert "streaming-scan / host-ledger" in text
-    assert "cpu backend" in text                    # host_reasons surfaced
-
-
 def test_doctor_reads_flight_dump(monkeypatch, tmp_path):
     rec = _recorder(monkeypatch, tmp_path)
     rec.record("admission", tenant="t0", query_id="qa", wait_s=0.25,
@@ -356,55 +339,3 @@ def test_doctor_reads_flight_dump(monkeypatch, tmp_path):
     assert "admission wait" in out.stdout
 
 
-def test_compare_tolerates_captures_without_profiles(tmp_path, capsys):
-    """Satellite: old captures (no per_query_profile) flow through
-    bench.compare's attribution section cleanly — shape-tolerant loading,
-    capture-level fallback attribution."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    old = {"metric": "m", "value": 100.0, "per_query_ms": {"q1": 100.0},
-           "metrics": {"scan_rows": 10}}
-    new = {"metric": "m", "value": 50.0, "per_query_ms": {"q1": 300.0},
-           "metrics": {"scan_rows": 10, "spill_bytes": 4096},
-           "device_batches": 0}
-    po, pn = tmp_path / "old.json", tmp_path / "new.json"
-    po.write_text(json.dumps(old))
-    pn.write_text(json.dumps(new))
-    assert bench.compare(str(po), str(pn)) >= 1
-    text = capsys.readouterr().out
-    assert "attribution (top regressed queries):" in text
-    assert "3.00x slower" in text
-    assert "per_query_profile" in text      # degraded-mode notice, not a crash
-    assert "worst offenders" in text
-
-
-def test_compare_attributes_operator_deltas_from_profiles(tmp_path, capsys):
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-
-    def prof(scan_s, agg_s, stall_ms):
-        return {"q1": {"operators": [
-            {"name": "StreamingScan", "rows": 1000, "seconds": scan_s,
-             "compute": scan_s * 0.2, "starve": scan_s * 0.7,
-             "blocked": scan_s * 0.1},
-            {"name": "HashAggregate", "rows": 7, "seconds": agg_s,
-             "compute": agg_s, "starve": 0.0, "blocked": 0.0},
-        ], "counters": {"scan_stall_ms": stall_ms}}}
-
-    old = {"metric": "m", "value": 100.0, "per_query_ms": {"q1": 100.0},
-           "per_query_profile": prof(0.05, 0.04, 0)}
-    new = {"metric": "m", "value": 40.0, "per_query_ms": {"q1": 900.0},
-           "per_query_profile": prof(0.80, 0.05, 740)}
-    po, pn = tmp_path / "old.json", tmp_path / "new.json"
-    po.write_text(json.dumps(old))
-    pn.write_text(json.dumps(new))
-    assert bench.compare(str(po), str(pn)) >= 1
-    text = capsys.readouterr().out
-    assert "operator StreamingScan: +0.750s" in text
-    assert "counter scan_stall_ms: +740" in text

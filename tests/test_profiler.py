@@ -1,11 +1,9 @@
 """Query timeline profiler: stall-attributed operator time, Chrome-trace
 export with per-worker task lanes and device spans, the Prometheus /metrics
-surface, straggler detection, spill-counter registry plumbing, and the bench
-perf-regression gate (ISSUE 6)."""
+surface, straggler detection and spill-counter registry plumbing."""
 
 import json
 import os
-import sys
 import time
 import urllib.request
 
@@ -785,7 +783,7 @@ def test_dashboard_trace_download_and_endpoints():
 # ---------------------------------------------------------------------------
 
 def test_spill_counters_flow_through_registry():
-    from daft_tpu.execution import memory as mem
+    from daft_tpu import memory as mem
     from daft_tpu.observability.metrics import registry
 
     rng = np.random.default_rng(5)
@@ -800,11 +798,10 @@ def test_spill_counters_flow_through_registry():
     diff = registry().diff(before)
     assert diff.get("spill_batches", 0) > 0, diff
     assert diff.get("spill_bytes", 0) > 0, diff
-    # the historical module attributes are a live view over the registry
-    assert mem.spills == registry().get("spill_batches")
-    assert mem.spill_bytes == registry().get("spill_bytes")
+    # reset_counters zeroes the spill vocabulary in the registry
     mem.reset_counters()
-    assert mem.spills == 0 and mem.spill_bytes == 0
+    assert registry().get("spill_batches") == 0
+    assert registry().get("spill_bytes") == 0
 
 
 # ---------------------------------------------------------------------------
@@ -833,50 +830,3 @@ def test_event_log_round_trip(tmp_path):
             assert f in o, o
         assert o["seconds"] == pytest.approx(
             o["compute_seconds"] + o["starve_seconds"] + o["blocked_seconds"])
-
-
-# ---------------------------------------------------------------------------
-# bench.py --compare perf gate (satellite)
-# ---------------------------------------------------------------------------
-
-def _bench_mod():
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    import bench
-
-    return bench
-
-
-def test_bench_compare_flags_regressions(tmp_path, capsys):
-    bench = _bench_mod()
-    old = {"metric": "tpch_sf1", "value": 1000.0,
-           "per_query_ms": {"q1": 100.0, "q3": 200.0, "q6": 50.0}}
-    new_ok = {"metric": "tpch_sf1", "value": 1040.0,
-              "per_query_ms": {"q1": 95.0, "q3": 198.0, "q6": 49.0}}
-    new_bad = {"metric": "tpch_sf1", "value": 900.0,
-               "per_query_ms": {"q1": 100.0, "q3": 260.0, "q6": 50.0}}
-    po, pok, pbad = (tmp_path / n for n in ("old.json", "ok.json", "bad.json"))
-    po.write_text(json.dumps(old))
-    pok.write_text(json.dumps(new_ok))
-    pbad.write_text(json.dumps(new_bad))
-
-    assert bench.compare(str(po), str(pok)) == 0
-    out = capsys.readouterr().out
-    assert "OK: no regressions" in out
-
-    n = bench.compare(str(po), str(pbad))
-    out = capsys.readouterr().out
-    assert n == 2  # q3 (+30%) and the headline rows/sec (-10%)
-    assert "REGRESSION" in out and "q3" in out
-    # within-tolerance jitter never trips the gate
-    new_jitter = {"metric": "tpch_sf1", "value": 980.0,
-                  "per_query_ms": {"q1": 103.0, "q3": 204.0, "q6": 51.0}}
-    pj = tmp_path / "jitter.json"
-    pj.write_text(json.dumps(new_jitter))
-    assert bench.compare(str(po), str(pj)) == 0
-    # a query missing from NEW is lost coverage -> counted as a regression
-    new_dropped = {"metric": "tpch_sf1", "value": 1000.0,
-                   "per_query_ms": {"q1": 100.0, "q6": 50.0}}
-    pd = tmp_path / "dropped.json"
-    pd.write_text(json.dumps(new_dropped))
-    assert bench.compare(str(po), str(pd)) == 1
-    assert "missing from NEW" in capsys.readouterr().out

@@ -14,7 +14,7 @@ import pytest
 
 import daft_tpu
 from daft_tpu.config import execution_config, execution_config_ctx
-from daft_tpu.execution import memory as mem
+from daft_tpu import memory as mem
 from daft_tpu.observability.metrics import registry
 
 
@@ -132,18 +132,40 @@ def test_gc_sweeps_dead_pid_tmp_not_live(tmp_path):
 
 
 def test_merge_microbench_tier1():
-    """The bench-oom quick mode's body as a tier-1 gate: a >=32-run external
-    sort is bit-identical (asserted inside), the carry-preserving merge
-    keys each row once per level (far below the old re-argsort bound), and
-    the prefetch high-water respects the knob."""
-    import bench
-
-    r = bench.merge_microbench(80_000)
-    assert r["runs"] >= 32, f"expected a >=32-run cascade, got {r['runs']}"
-    assert 0 < r["merge_sort_rows"] < r["old_merge_bound_rows"], \
-        "merge argsort volume not below the old per-round re-sort bound"
-    assert r["prefetch_high_water"] <= r["prefetch_depth"]
-    assert r["metrics"].get("spill_io_overlap_ratio", 0) >= 0
+    """A synthetic sort forced through a >=32-run external merge under a
+    tiny fixed budget is bit-identical to the in-memory sort, the
+    carry-preserving merge keys each row once per level (far below the old
+    re-argsort bound), and the prefetch high-water respects the knob."""
+    rows = 80_000
+    rng = np.random.default_rng(7)
+    df = daft_tpu.from_pydict({
+        "k": rng.integers(0, rows, size=rows),
+        "g": rng.integers(0, 997, size=rows),
+        "v": rng.standard_normal(rows),
+    }).into_batches(max(rows // 64, 256)).collect()
+    input_bytes = sum(p.size_bytes() for p in df.iter_partitions())
+    with execution_config_ctx(memory_limit_bytes=0, device_mode="off"):
+        expected = df.sort(["k", "g"]).to_pydict()
+    # ~48 runs: deep enough that the fan-in cascade (intermediate merges)
+    # engages, so the sort-rows bound below exercises multi-level merging
+    budget = max(input_bytes // 48, 48 << 10)
+    before = registry().snapshot()
+    with execution_config_ctx(memory_limit_bytes=budget, device_mode="off"):
+        out = df.sort(["k", "g"]).to_pydict()
+    diff = registry().diff(before)
+    assert out == expected, "budgeted merge diverged from in-memory sort"
+    runs = int(diff.get("spill_runs", 0))
+    assert runs >= 32, f"expected a >=32-run cascade, got {runs}"
+    merge_rows = int(diff.get("spill_merge_sort_rows", 0))
+    # each row is keyed/argsorted at most once per merge level (cascade +
+    # final), and single-source stretches skip the argsort entirely; the
+    # old merge's bound was ~rows x fan-in (rows x runs // 2 here)
+    levels = 1 + (1 if diff.get("spill_merge_passes", 0) else 0)
+    assert 0 < merge_rows <= rows * (levels + 1), (
+        f"spill_merge_sort_rows={merge_rows} outside the carry-preserving "
+        f"bound for {rows} rows x {levels} merge level(s)")
+    assert registry().snapshot().get("spill_prefetch_inflight", 0) <= \
+        execution_config().spill_prefetch_batches
 
 
 def test_sync_compat_path_touches_no_async_counters():

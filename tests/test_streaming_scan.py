@@ -20,7 +20,7 @@ N_ROWS = 40_000
 
 @pytest.fixture(autouse=True)
 def _clean():
-    from daft_tpu.execution import memory as mem
+    from daft_tpu import memory as mem
 
     mem.reset_counters()
     manager().clear()

@@ -3,10 +3,11 @@
 Each test runs the same query with device_mode="on" (device stages asserted
 via counters) and device_mode="off", and compares results. Data is kept small
 (buckets of 512-8192 rows) so per-test compiles stay in seconds; the point is
-MXU/Mosaic NUMERICS and real-device behavior, not scale (bench.py covers
-scale). Reference test-strategy parity: SURVEY.md §4 — the reference asserts
-engine results against precomputed answers; here the host engine (validated
-against pandas in tests/) is the oracle.
+MXU/Mosaic NUMERICS and real-device behavior, not scale (the benchmark's
+cells cover scale: BENCHMARK.json). Reference test-strategy parity:
+SURVEY.md §4 — the reference asserts engine results against precomputed
+answers; here the host engine (validated against pandas in tests/) is the
+oracle.
 """
 
 from __future__ import annotations
