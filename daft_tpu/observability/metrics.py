@@ -125,6 +125,10 @@ _REGISTRY = MetricsRegistry()
 DEVICE_COUNTER_NAMES = (
     "device_stage_batches",    # batches through FilterAggStage (ungrouped)
     "device_grouped_batches",  # batches through GroupedAggStage
+    # how the one-hot tier's program reduced a dispatch's chunks
+    # (grouped_stage._reduce_form: a function of the group capacity)
+    "device_grouped_reduce_select",  # a masked sum a group, on the VPU
+    "device_grouped_reduce_matmul",  # the one-hot product, on the MXU
     "device_stage_runs",       # completed device agg node executions
     "mesh_grouped_runs",       # grouped aggs executed via the mesh-sharded path
     "mesh_dispatches",         # multi-device shard_map/pjit dispatches issued

@@ -54,7 +54,7 @@ from . import counters
 from . import device_eval as dev
 from .grouped_stage import (DeviceFallback, GroupedAggRun, GroupedAggStage,
                             MAX_MATMUL_SEGMENTS, _Decode,
-                            _pad_groups, cached_dict_code_plane,
+                            _pad_groups, cached_dict_code_plane, count_reduce,
                             try_build_grouped_agg_stage)
 from .stage import FilterAggRun, FilterAggStage, device_row_mask, pad_bucket
 
@@ -1455,12 +1455,13 @@ class DeviceJoinGroupedRun(GroupedAggRun):
                         sp.args["cap"] = decode.cap
                 dcols, decode.dcodes = self.ctx.provision(batch, bucket, needed,
                                                           codes=codes)
-                prog = stage._jit_for(decode.cap)
+                prog, form = stage._program_for(decode.cap)
                 mask = device_row_mask(n, bucket)
                 offset = jnp.asarray(float(self._row_offset))
                 with profile_span("device.launch", "device", op="join_agg",
-                                  cap=decode.cap):
+                                  cap=decode.cap, reduce=form):
                     out = prog(dcols, decode.dcodes, mask, offset)
+                count_reduce(form)
             else:
                 with profile_span("join.codes", "host", strategy="host") as sp:
                     decode = self._host_factorized_codes(batch, n, bucket)
@@ -1486,12 +1487,13 @@ class DeviceJoinGroupedRun(GroupedAggRun):
                                    mask)
                 else:
                     dcols, _ = self.ctx.provision(batch, bucket, needed)
-                    prog = stage._jit_for(decode.cap)
+                    prog, form = stage._program_for(decode.cap)
                     mask = device_row_mask(n, bucket)
                     offset = jnp.asarray(float(self._row_offset))
                     with profile_span("device.launch", "device", op="join_agg",
-                                      cap=decode.cap):
+                                      cap=decode.cap, reduce=form):
                         out = prog(dcols, decode.dcodes, mask, offset)
+                    count_reduce(form)
         decode.row_offset = float(self._row_offset)
         self._row_offset += n
         self._pending.append((out, decode))

@@ -1,15 +1,23 @@
-"""Device grouped-aggregation stage: MXU segment reduction via chunked one-hot matmul.
+"""Device grouped-aggregation stage: chunked segment reduction without scatters.
 
 The TPU answer to hash-table grouped aggregation (reference:
 src/daft-local-execution/src/sinks/grouped_aggregate.rs). Design, driven by
 how a TPU behaves (see ops/costmodel.py):
 
-- **Reduction = matmul, not scatter.** TPU scatter-adds serialize; a one-hot
-  [chunk x groups] matrix times the value planes runs on the MXU instead (the
-  ratio is not measured on this chip). Rows are processed in chunks
-  under ``lax.scan``; per-chunk f32 partial tables are combined into an f64
-  accumulator, bounding float error to one chunk (~1e-6 relative) while keeping
-  all heavy work in f32 (TPU f64 is software-emulated).
+- **Reduction = masked sums or a matmul, not scatter.** TPU scatter-adds
+  serialize. Rows are processed in chunks under ``lax.scan``; a chunk's f32
+  [groups x planes] table is, for a handful of groups, one masked sum a cell
+  on the VPU ("select"), and for hundreds to 4,096 a one-hot [chunk x groups]
+  matrix times the value planes on the MXU ("matmul"; _reduce_form chooses
+  from the group capacity). Per-chunk f32 partial tables are combined into an
+  f64 accumulator, bounding float error to one chunk (~1e-6 relative) while
+  keeping all heavy work in f32 (TPU f64 is software-emulated).
+- **Each resident plane is read once.** The loop runs over tiles of the
+  *input* planes (views of the resident arrays, 128 rows to a line as the
+  chip tiles them); predicate, agg children, segment ids and planes are
+  evaluated for the tile inside the step that reduces them, so no array of
+  the bucket's length but the inputs exists (_build; measured in PERF.md §6,
+  PR 31: q1 at SF10 56 ms -> 9 ms of device time).
 - **Group codes come from per-column dictionaries, not per-query factorize.**
   When the group keys are plain columns, each key column is dictionary-encoded
   once per Series (cached — resident tables never re-factorize; see
@@ -17,9 +25,11 @@ how a TPU behaves (see ops/costmodel.py):
   device. Arbitrary key expressions fall back to per-batch host factorize.
 - **min/max = chunked masked broadcasts** (no scatter): per chunk,
   ``where(onehot, v, ±inf).min(axis=rows)``; int/temporal extremes accumulate
-  in f64 (exact to 2^53), floats in f32.
-- **Integer sums keep exact int64 semantics** via segment_sum (the one scatter
-  left; rare in practice and priced by the cost model).
+  in f64 (exact to 2^53), floats in f32. The first row of a group (the
+  stream's group order) is the least int32 position, widened at [groups].
+- **Integer sums are exact**: 8-bit digit planes whose chunk partials stay
+  under 2^24 (_classify_planes); 64-bit extremes keep the one scatter left
+  (rare in practice and priced by the cost model).
 - **One fetch per run.** feed_batch only *dispatches* (async); every per-batch
   result stays on device until finalize(), which fetches all pending tables in
   a single device_get, so the run pays the d2h round trip exactly once.
@@ -226,12 +236,49 @@ def estimate_key_cardinality(key_series) -> int:
 
 
 def _chunk_for(bucket: int, cap: int) -> int:
-    """Rows per scan step: keep the materialized one-hot (chunk x cap+1 f32)
-    around 32MB, never below 512 rows, never above the bucket."""
+    """Rows whose f32 partial table is summed before the f64 combine: at most
+    65,536 (an 8-bit digit's partial stays under 2^24, a float sum's rounding
+    stays one chunk's), halved while a chunk's one-hot (chunk x cap+1 f32,
+    which only the matmul form of _reduce_form builds) is over 32MB, never
+    below 512 rows, never above the bucket."""
     c = 65536
     while c * (cap + 1) * 4 > (1 << 25) and c > 512:
         c >>= 1
     return min(c, bucket)
+
+
+# group capacity up to which a chunk is reduced by one masked sum a group and
+# plane on the VPU; above it by the one-hot product on the MXU. Measured on
+# the chip over 2^24 rows (PERF.md §6, PR 31), select / matmul in ms: 11
+# planes 2.9 / 10.3 at cap 8, 5.7 / 10.7 at 16, 7.9 / 11.1 at 32, 25.0 / 12.6
+# at 64; 3 planes 1.8 / 3.3, 3.1 / 3.3, 4.6 / 4.1, 17.0 / 5.7
+SELECT_MAX_GROUPS = 16
+# chunks a loop step of the select form takes together: a step of one chunk
+# costs q1 at SF10 51.8 ms, of four 13.2, of sixteen 8.5, of sixty-four 10.1
+_SELECT_STEP_CHUNKS = 16
+# rows to a line of a tile, as the chip lays a plane out
+_LANES = 128
+
+
+def _reduce_form(cap: int) -> str:
+    """How a chunk's rows become its [cap, planes] table, from the group
+    capacity alone: "select" (for each group, a masked sum of each plane: the
+    VPU's work grows with cap x planes and no one-hot exists) for a handful
+    of groups, "matmul" (one-hot [chunk, cap+1] times planes [chunk, P] at
+    Precision.HIGHEST: the MXU's) for the rest up to MAX_MATMUL_SEGMENTS."""
+    return "select" if cap <= SELECT_MAX_GROUPS else "matmul"
+
+
+def count_reduce(form: str) -> None:
+    """One dispatch of _build's program, counted under its reduce form."""
+    if form == "select":
+        counters.bump("device_grouped_reduce_select")
+    elif form == "matmul":
+        counters.bump("device_grouped_reduce_matmul")
+
+
+def _counts_all(agg: AggExpr) -> bool:
+    return agg.op == "count" and agg.params.get("mode", "valid") == "all"
 
 
 class GroupedAggStage:
@@ -262,8 +309,13 @@ class GroupedAggStage:
         """Assign each aggregation's partials to matmul / extreme / scatter slots.
 
         mm plane 0 is always the kept-row count ("rows"): it decides group
-        existence and serves count(mode=all). Every agg also gets a valid-count
-        plane (validity of the result = count > 0, matching host semantics).
+        existence and serves count(mode=all). Every agg also reads a
+        valid-count plane (validity of the result = count > 0, matching host
+        semantics). Planes that are the same by construction are reduced
+        once: one count plane and one sum plane (or digit group) a distinct
+        child expression, whatever the data; a spec names the first agg that
+        asked, and every agg's slots point at the shared plane (TPC-H q1: 16
+        planes -> 11).
 
         Integer sums ride the MXU as EXACT 8-bit bit-slice planes ("isum"):
         v mod 2^24 split into three 8-bit digits plus a negative-count plane,
@@ -280,15 +332,24 @@ class GroupedAggStage:
         self._ext_specs: List[Tuple[int, str, bool]] = [(-1, "min", True)]  # first-row idx
         self._sct_specs: List[Tuple[int, str]] = []
         self._agg_slots: List[Dict[str, Tuple[str, int]]] = []
+        shared: Dict[Tuple[str, str], tuple] = {}
         for i, (_name, agg) in enumerate(self.aggs):
             child_dt = agg.child.to_field(self.schema).dtype
             is_float = child_dt.is_floating()
+            child = repr(agg.child)
             slots: Dict[str, Tuple[str, int]] = {}
-            slots["count"] = ("mm", len(self._mm_specs))
-            self._mm_specs.append((i, "count"))
+            if _counts_all(agg):
+                slots["count"] = ("mm", 0)
+            else:
+                if (child, "count") not in shared:
+                    shared[child, "count"] = ("mm", len(self._mm_specs))
+                    self._mm_specs.append((i, "count"))
+                slots["count"] = shared[child, "count"]
             if agg.op in ("sum", "mean"):
-                if is_float or child_dt.is_boolean() or self._use_f64:
-                    slots["sum"] = ("mm", len(self._mm_specs))
+                if (child, "sum") in shared:
+                    pass
+                elif is_float or child_dt.is_boolean() or self._use_f64:
+                    shared[child, "sum"] = ("mm", len(self._mm_specs))
                     self._mm_specs.append((i, "sum"))
                 else:
                     # exact int sum via bit-slice matmul planes (see above).
@@ -302,9 +363,10 @@ class GroupedAggStage:
                         nd = max(1, (max(hi - lo, 1).bit_length() + 7) // 8)
                     else:
                         lo, nd = 0, 8
-                    slots["sum"] = ("imm", len(self._mm_specs), nd, lo)
+                    shared[child, "sum"] = ("imm", len(self._mm_specs), nd, lo)
                     self._mm_specs.extend(
                         [(i, f"isum{k}:{lo}") for k in range(nd)])
+                slots["sum"] = shared[child, "sum"]
             elif agg.op in ("min", "max"):
                 if is_float or _f64_exact_dtype(child_dt):
                     # extremes ride the chunked broadcast path; f64 planes for
@@ -335,115 +397,193 @@ class GroupedAggStage:
     def start_run(self) -> "GroupedAggRun":
         return GroupedAggRun(self)
 
-    def _build(self, cap: int) -> Callable:
+    def _chunk_planes(self, cap: int, fdt, radices: Tuple[int, ...]) -> Callable:
+        """The plane evaluator of _build's loop: for a tile of rows (the
+        tile's view of every input), the segment ids with filtered and
+        padding rows sent to `cap`, the mm planes in _mm_specs order, and a
+        (values, mask) pair for each agg of _ext_specs and _sct_specs. Each
+        distinct child expression is evaluated once a tile. With `radices`,
+        `codes` is the key columns' dictionary-code planes and the segment id
+        their radix sum."""
         schema = self.schema
-        fdt = jnp.float64 if self._use_f64 else jnp.float32
         pred_fn = (dev.build_device_expr(self.predicate, schema, float_dtype=fdt)
                    if self.predicate is not None else None)
-        child_fns = []
-        for name, agg in self.aggs:
-            count_all = agg.op == "count" and agg.params.get("mode", "valid") == "all"
-            child_fns.append((dev.build_device_expr(agg.child, schema, float_dtype=fdt),
-                              count_all))
-
+        child_key = [repr(agg.child) for _name, agg in self.aggs]
+        child_fns = {key: dev.build_device_expr(agg.child, schema, float_dtype=fdt)
+                     for key, (_name, agg) in zip(child_key, self.aggs)}
         mm_specs, ext_specs, sct_specs = self._mm_specs, self._ext_specs, self._sct_specs
 
-        def stage(cols: Dict[str, dev.DCol], codes: jnp.ndarray,
-                  row_mask: jnp.ndarray, row_offset: jnp.ndarray):
-            bucket = codes.shape[0]
-            chunk = _chunk_for(bucket, cap)
-            n_chunks = bucket // chunk
+        def planes(cols: Dict[str, dev.DCol], codes, row_mask):
             if pred_fn is not None:
                 pv, pm = pred_fn(cols)
                 keep = pv.astype(bool) & pm & row_mask
             else:
                 keep = row_mask
+            if radices:
+                codes = sum(c * np.int32(r) for c, r in zip(codes, radices))
             seg = jnp.where(keep, codes, cap).astype(jnp.int32)
+            seen: Dict[str, tuple] = {}
 
-            # evaluate each agg child once; derive (value, combined mask)
-            evaluated = []
-            for fn, count_all in child_fns:
-                v, m = fn(cols)
-                v = v + jnp.zeros(jnp.shape(seg), dtype=v.dtype) if jnp.shape(v) != jnp.shape(seg) else v
-                mask = keep if count_all else dev._broadcast_valid(v, m) & keep
-                evaluated.append((v, mask))
+            def child(agg_idx: int):
+                key = child_key[agg_idx]
+                if key not in seen:
+                    v, m = child_fns[key](cols)
+                    v = jnp.broadcast_to(v, jnp.shape(seg))
+                    seen[key] = (v, dev._broadcast_valid(v, m) & keep)
+                return seen[key]
 
-            pdt = fdt
-            # matmul planes (f32; f64 in exact mode), MXU chunk-reduce, f64 combine
-            planes = []
+            mm = []
             for agg_idx, kind in mm_specs:
                 if kind == "rows":
-                    planes.append(keep.astype(pdt))
+                    mm.append(keep.astype(fdt))
                 elif kind == "count":
-                    planes.append(evaluated[agg_idx][1].astype(pdt))
+                    mm.append(child(agg_idx)[1].astype(fdt))
                 elif kind.startswith("isum"):
-                    v, mask = evaluated[agg_idx]
-                    planes.append(jnp.where(mask, _isum_digit(v, kind), 0.0)
-                                  .astype(pdt))
+                    v, mask = child(agg_idx)
+                    mm.append(jnp.where(mask, _isum_digit(v, kind), 0.0)
+                              .astype(fdt))
                 else:  # float/bool sum
-                    v, mask = evaluated[agg_idx]
-                    planes.append(jnp.where(mask, v.astype(pdt), 0.0))
+                    v, mask = child(agg_idx)
+                    mm.append(jnp.where(mask, v.astype(fdt), 0.0))
+            ext = [child(agg_idx) for agg_idx, _op, _f64 in ext_specs[1:]]
+            sct = [child(agg_idx) for agg_idx, _kind in sct_specs]
+            return seg, mm, ext, sct
 
-            # extreme planes: masked-out rows carry the identity
-            ext_planes = []
-            for agg_idx, op, use_f64 in ext_specs:
-                dt = jnp.float64 if use_f64 else jnp.float32
-                big = jnp.asarray(jnp.inf if op == "min" else -jnp.inf, dt)
-                if agg_idx < 0:  # first-occurrence row index (global, for ordering)
-                    v = jnp.arange(bucket, dtype=jnp.float64) + row_offset
-                    mask = keep
-                else:
-                    v, mask = evaluated[agg_idx]
-                ext_planes.append(jnp.where(mask, v.astype(dt), big))
+        return planes
 
-            segr = seg.reshape(n_chunks, chunk)
-            mm_xs = jnp.stack(planes, axis=-1).reshape(n_chunks, chunk, len(planes))
-            ext_xs = tuple(p.reshape(n_chunks, chunk) for p in ext_planes)
+    def _build(self, cap: int, form: Optional[str] = None,
+               radices: Tuple[int, ...] = ()) -> Callable:
+        """The one-hot tier's program (cap <= MAX_MATMUL_SEGMENTS): ONE loop
+        over tiles of the input planes, taken as views of the resident arrays.
+        A step evaluates predicate, agg children and planes for its tile only
+        (_chunk_planes) and reduces them at once, so no array of `bucket` rows
+        but the inputs exists. Each chunk of _chunk_for rows gives an f32
+        [cap, P] partial, combined in f64 in the carry; the first-row index is
+        the least int32 position of a group's kept rows, widened to f64 (and
+        offset by the stream position) at [cap] after the loop; int64 scatter
+        slots accumulate exactly a tile at a time.
+
+        How a tile's rows become its table is _reduce_form's choice (`form`
+        overrides it for tests): "select" takes _SELECT_STEP_CHUNKS chunks a
+        step as a [chunks, rows] tile and reduces along the rows; "matmul"
+        takes one chunk a step and contracts its one-hot on the MXU.
+
+        `codes` is the segment-id plane or, with `radices` (the dictionary
+        route), the tuple of the key columns' code planes, combined a tile at
+        a time like every other input."""
+        fdt = jnp.float64 if self._use_f64 else jnp.float32
+        planes_of = self._chunk_planes(cap, fdt, radices)
+        ext_specs, sct_specs = self._ext_specs[1:], self._sct_specs
+        n_mm = len(self._mm_specs)
+        form = form or _reduce_form(cap)
+        none = jnp.iinfo(jnp.int32).max  # "no kept row": past every position
+        i64 = jnp.iinfo(jnp.int64)
+        sct_ident = {"sum": 0, "min": i64.max, "max": i64.min}
+        sct_fn = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
+                  "max": jax.ops.segment_max}
+
+        def ext_big(op, use_f64):
+            return jnp.asarray(jnp.inf if op == "min" else -jnp.inf,
+                               jnp.float64 if use_f64 else jnp.float32)
+
+        def stage(cols: Dict[str, dev.DCol], codes, row_mask: jnp.ndarray,
+                  row_offset: jnp.ndarray):
+            bucket = row_mask.shape[0]
+            chunk = _chunk_for(bucket, cap)
+            n_chunks = bucket // chunk
+            # rows lie 128 to a line, as the chip tiles them, so a tile is a
+            # view of a resident plane and not a copy in another layout
+            lanes = min(_LANES, chunk)
+            sub = min(_SELECT_STEP_CHUNKS, n_chunks) if form == "select" else 1
+            tile = (sub, chunk // lanes, lanes)
+            n_steps = n_chunks // sub
+            # position of a row in its chunk
+            local = (jax.lax.broadcasted_iota(jnp.int32, tile, 1) * lanes
+                     + jax.lax.broadcasted_iota(jnp.int32, tile, 2))
+
+            def view(x):
+                return x.reshape((n_steps,) + tile)
 
             def body(carry, xs):
-                acc_mm, acc_ext = carry
-                s, v = xs[0], xs[1]
-                ext_ch = xs[2:]
-                oh = s[:, None] == jnp.arange(cap + 1, dtype=jnp.int32)[None, :]
-                acc_mm = acc_mm + jnp.matmul(
-                    oh.astype(v.dtype).T, v,
-                    precision=jax.lax.Precision.HIGHEST).astype(jnp.float64)
-                new_ext = []
-                for (agg_idx, op, use_f64), ev_ch, acc in zip(ext_specs, ext_ch, acc_ext):
-                    dt = jnp.float64 if use_f64 else jnp.float32
-                    big = jnp.asarray(jnp.inf if op == "min" else -jnp.inf, dt)
-                    w = jnp.where(oh, ev_ch[:, None].astype(dt), big)
-                    red = jnp.min(w, axis=0) if op == "min" else jnp.max(w, axis=0)
-                    new_ext.append(jnp.minimum(acc, red) if op == "min" else jnp.maximum(acc, red))
-                return (acc_mm, tuple(new_ext)), None
+                acc_mm, acc_first, acc_ext, acc_sct = carry
+                step, ccols, ccodes, cmask = xs
+                seg, mm, ext, sct = planes_of(ccols, ccodes, cmask)
+                if form == "select":
+                    hits = [seg == g for g in range(cap)]
 
-            acc_mm0 = jnp.zeros((cap + 1, len(planes)), dtype=jnp.float64)
-            acc_ext0 = tuple(
-                jnp.full((cap + 1,), jnp.inf if op == "min" else -jnp.inf,
-                         dtype=jnp.float64 if use_f64 else jnp.float32)
-                for _, op, use_f64 in ext_specs)
-            (acc_mm, acc_ext), _ = jax.lax.scan(body, (acc_mm0, acc_ext0),
-                                                (segr, mm_xs) + ext_xs)
+                    def per_group(x, fill, red):
+                        """[cap, sub]: `red` over each chunk's rows of a group."""
+                        return jnp.stack([red(jnp.where(hit, x, fill), axis=(1, 2))
+                                          for hit in hits])
 
-            # exact int64 partials: the remaining scatters (priced by the cost model)
-            scts = []
-            for agg_idx, kind in sct_specs:
-                v, mask = evaluated[agg_idx]
-                if kind == "sum":
-                    sv = jnp.where(mask, v.astype(jnp.int64), jnp.zeros((), jnp.int64))
-                    scts.append(jax.ops.segment_sum(sv, seg, num_segments=cap + 1)[:cap])
+                    # [cap, P, sub] f32 partials, one a chunk
+                    part = jnp.stack([per_group(p, 0.0, jnp.sum) for p in mm], axis=1)
+                    acc_mm = acc_mm + part.astype(jnp.float64).sum(axis=-1)
+                    first = per_group(local, chunk, jnp.min)
+                    base = (step * sub + jnp.arange(sub, dtype=jnp.int32)) * chunk
+                    first = jnp.min(jnp.where(first < chunk, first + base, none),
+                                    axis=1)
+
+                    def extreme(v, big, red):
+                        return red(per_group(v, big, red), axis=1)
                 else:
-                    info = jnp.iinfo(jnp.int64)
-                    ident = info.max if kind == "min" else info.min
-                    sv = jnp.where(mask, v.astype(jnp.int64), jnp.asarray(ident, jnp.int64))
-                    fn = jax.ops.segment_min if kind == "min" else jax.ops.segment_max
-                    scts.append(fn(sv, seg, num_segments=cap + 1)[:cap])
+                    # the one-hot keeps the column of the filtered rows: with
+                    # a power of two of columns the product ran 2.5 to 5
+                    # times slower on the chip (cap 128 to 512; PERF.md §6)
+                    oh = seg.reshape(chunk)[:, None] \
+                        == jnp.arange(cap + 1, dtype=jnp.int32)[None, :]
+                    v = jnp.stack([p.reshape(chunk) for p in mm], axis=-1)
+                    # HIGHEST: the MXU's default rounds its inputs to bf16
+                    part = jnp.matmul(oh.astype(v.dtype).T, v,
+                                      precision=jax.lax.Precision.HIGHEST)
+                    acc_mm = acc_mm + part[:cap].astype(jnp.float64)
+                    oh = oh[:, :cap]
 
-            return {
-                "mm": acc_mm[:cap],
-                "ext": tuple(a[:cap] for a in acc_ext),
-                "sct": tuple(scts),
-            }
+                    def extreme(x, fill, red):
+                        """[cap]: `red` over the chunk's rows of a group."""
+                        return red(jnp.where(oh, x.reshape(chunk)[:, None], fill),
+                                   axis=0)
+
+                    first = extreme(local, chunk, jnp.min)
+                    first = jnp.where(first < chunk, first + step * chunk, none)
+                new_ext = []
+                for (_i, op, use_f64), (v, mask), acc in zip(ext_specs, ext, acc_ext):
+                    big = ext_big(op, use_f64)
+                    v = jnp.where(mask, v.astype(big.dtype), big)
+                    new_ext.append(jnp.minimum(acc, extreme(v, big, jnp.min))
+                                   if op == "min" else
+                                   jnp.maximum(acc, extreme(v, big, jnp.max)))
+                acc_first = jnp.minimum(acc_first, first)
+                # exact int64 partials: the remaining scatters (priced by the
+                # cost model), a tile at a time
+                new_sct = []
+                for (_i, kind), (v, mask), acc in zip(sct_specs, sct, acc_sct):
+                    sv = jnp.where(mask, v.astype(jnp.int64),
+                                   jnp.asarray(sct_ident[kind], jnp.int64))
+                    t = sct_fn[kind](sv.reshape(-1), seg.reshape(-1),
+                                     num_segments=cap + 1)[:cap]
+                    new_sct.append(acc + t if kind == "sum" else
+                                   jnp.minimum(acc, t) if kind == "min" else
+                                   jnp.maximum(acc, t))
+                return (acc_mm, acc_first, tuple(new_ext), tuple(new_sct)), None
+
+            carry0 = (
+                jnp.zeros((cap, n_mm), jnp.float64),
+                jnp.full((cap,), none, jnp.int32),
+                tuple(jnp.full((cap,), b, b.dtype)
+                      for b in (ext_big(op, f) for _i, op, f in ext_specs)),
+                tuple(jnp.full((cap,), sct_ident[kind], jnp.int64)
+                      for _i, kind in sct_specs))
+            xs = (jnp.arange(n_steps, dtype=jnp.int32),
+                  {name: (view(v), view(m)) for name, (v, m) in cols.items()},
+                  jax.tree_util.tree_map(view, codes), view(row_mask))
+            (acc_mm, acc_first, acc_ext, acc_sct), _ = jax.lax.scan(
+                body, carry0, xs)
+            # group order is first occurrence in the stream: the position in
+            # this batch, offset by the rows fed before it (+inf = no row)
+            first = jnp.where(acc_first < none,
+                              acc_first.astype(jnp.float64) + row_offset, jnp.inf)
+            return {"mm": acc_mm, "ext": (first,) + acc_ext, "sct": acc_sct}
 
         return jax.jit(stage)
 
@@ -554,17 +694,27 @@ class GroupedAggStage:
 
         return jax.jit(stage)
 
-    def _jit_for(self, cap: int, rows: int = 0) -> Callable:
+    def _program_for(self, cap: int, rows: int = 0,
+                     radices: Tuple[int, ...] = ()) -> Tuple[Callable, str]:
+        """The jitted program that serves `cap` groups, and how it reduces:
+        "pallas" (the kernel tier, when its gate admits the shape), "select"
+        or "matmul" (_build's two forms, up to MAX_MATMUL_SEGMENTS), "sort".
+        Only _build's program takes the key columns' code planes and their
+        `radices` in place of the segment ids."""
         interp = self._pallas_gate(cap, rows)
         if interp is not None:
-            key = ("pallas", cap)
-            if key not in self._jitted:
-                self._jitted[key] = self._build_pallas(cap, interpret=interp)
-            return self._jitted[key]
-        if cap not in self._jitted:
-            self._jitted[cap] = (self._build(cap) if cap <= MAX_MATMUL_SEGMENTS
-                                 else self._build_sorted(cap))
-        return self._jitted[cap]
+            key, form = ("pallas", cap), "pallas"
+        elif cap <= MAX_MATMUL_SEGMENTS:
+            key = (cap, radices) if radices else cap
+            form = _reduce_form(cap)
+        else:
+            key, form = cap, "sort"
+        if key not in self._jitted:
+            self._jitted[key] = (
+                self._build_pallas(cap, interpret=interp) if form == "pallas"
+                else self._build_sorted(cap) if form == "sort"
+                else self._build(cap, radices=radices))
+        return self._jitted[key], form
 
     def _pallas_eligible(self) -> bool:
         """Exactness contract for the Pallas tier (ops/pallas_kernels.py):
@@ -889,8 +1039,15 @@ class GroupedAggRun:
             return
         bucket = pad_bucket(n)
         decode = self._codes_for(batch, n, bucket)
-        use_pallas = stage._pallas_gate(decode.cap, n) is not None
-        prog = stage._jit_for(decode.cap, rows=n)
+        by_dict = decode.code_planes is not None
+        prog, form = stage._program_for(
+            decode.cap, n, tuple(decode.radices) if by_dict else ())
+        if not by_dict:
+            codes = decode.dcodes
+        elif form in ("select", "matmul"):
+            codes = decode.code_planes
+        else:  # the other tiers take the segment ids: combined here, eagerly
+            codes = sum(c * r for c, r in zip(decode.code_planes, decode.radices))
         with profile_span("device.h2d", "device", rows=n, bucket=bucket):
             dcols = {name: batch.get_column(name).to_device_cached(
                          bucket, f32=not stage._use_f64)
@@ -902,10 +1059,11 @@ class GroupedAggRun:
             # a Pallas program that does not lower raises here: no tier
             # replaces it behind the caller's back
             with profile_span("device.launch", "device", op="grouped_agg",
-                              cap=decode.cap):
-                out = prog(dcols, decode.dcodes, mask, offset)
-        if use_pallas:
+                              cap=decode.cap, reduce=form):
+                out = prog(dcols, codes, mask, offset)
+        if form == "pallas":
             counters.bump("pallas_dispatches")
+        count_reduce(form)
         self._row_offset += n
         self._pending.append((out, decode))
         counters.bump("device_grouped_batches")
@@ -928,7 +1086,7 @@ class GroupedAggRun:
                 total *= max(k, 1)
             if 0 < total <= MAX_SORT_SEGMENTS:
                 cap = _pad_groups(total)
-                # radix-combine per-column codes on device (codes cached per Series)
+                # per-column code planes on the device (cached per Series)
                 dcode_cols = [cached_dict_code_plane(s, codes, n, bucket)
                               for s, (codes, _, _) in zip(key_series, encoded)]
                 radices = []
@@ -937,12 +1095,12 @@ class GroupedAggRun:
                     radices.append(mult)
                     mult *= max(k, 1)
                 radices.reverse()
-                combined = dcode_cols[0] * radices[0]
-                for dc, r in zip(dcode_cols[1:], radices[1:]):
-                    combined = combined + dc * r
-                return _Decode(cap=cap, dcodes=combined,
+                # the segment id is the radix sum of the planes: the one-hot
+                # tier's program takes it a tile at a time (feed_batch)
+                return _Decode(cap=cap, dcodes=None,
                                dicts=[(vals, k) for _, vals, k in encoded],
-                               radices=radices, key_rows=None)
+                               radices=radices, key_rows=None,
+                               code_planes=tuple(dcode_cols))
 
         # fallback: host factorize of the full key rows for this batch (cached on
         # the batch so repeated queries over resident tables skip re-factorizing)
@@ -1085,8 +1243,7 @@ def results_from_tables(stage: GroupedAggStage, mm_acc, ext_acc, sct_acc):
     results = []
     for i, ((_name, agg), slots) in enumerate(zip(stage.aggs, stage._agg_slots)):
         op = agg.op
-        count_all = op == "count" and agg.params.get("mode", "valid") == "all"
-        cnt = mm_acc[:, 0] if count_all else mm_acc[:, slots["count"][1]]
+        cnt = mm_acc[:, slots["count"][1]]
         if op == "count":
             results.append((cnt.astype(np.int64), np.ones(g, dtype=bool)))
             continue
@@ -1161,9 +1318,10 @@ class _Decode:
 
     def __init__(self, cap: int, dcodes, dicts, radices, key_rows,
                  fact_codes=None, local_codes=None, seg_lo=None,
-                 host_firsts=None, pperm=None):
+                 host_firsts=None, pperm=None, code_planes=None):
         self.cap = cap
-        self.dcodes = dcodes
+        self.dcodes = dcodes        # segment-id plane (None with code_planes)
+        self.code_planes = code_planes  # per key column (dict mode, unjoined)
         self.dicts = dicts          # [(values, K)] per key column (dict mode)
         self.radices = radices
         self.key_rows = key_rows    # first-occurrence key tuples (host mode)

@@ -29,7 +29,7 @@ shard_map) so a mesh repartition and its consuming stage compile into one
 program with zero standalone jax.lax.all_to_all dispatches
 (parallel/distributed.sharded_ring_repartition_step).
 
-Selected by grouped_stage._jit_for / device_join / the executor's repartition
+Selected by grouped_stage._program_for / device_join / the executor's repartition
 exchange when DAFT_TPU_PALLAS allows it (auto gates on the costmodel's
 pallas_cell_rate / pallas_probe_cell_rate arms). Results are pinned by
 interpret-mode tests; that the chip's compiler accepts each kernel at TPC-H

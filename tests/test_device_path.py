@@ -362,3 +362,203 @@ def test_config_rejects_unknown_modes():
             pass
     # valid values construct fine
     ExecutionConfig(device_mode="on", pipeline_mode="force")
+
+
+# ---- the one-hot tier's program: planes evaluated inside the chunk loop (PR 31) ----
+
+
+def _device_and_host(q, df):
+    counters.reset()
+    with execution_config_ctx(device_mode="on"):
+        dev_out = q(df).to_pydict()
+    assert counters.device_grouped_batches > 0, "device grouped stage never fed"
+    forms = (counters.device_grouped_reduce_select,
+             counters.device_grouped_reduce_matmul)
+    with execution_config_ctx(device_mode="off"):
+        host_out = q(df).to_pydict()
+    return dev_out, host_out, forms
+
+
+@pytest.mark.parametrize("groups,form", [(1, "select"), (8, "select"),
+                                         (16, "select"), (32, "matmul"),
+                                         (4096, "matmul")])
+def test_grouped_device_equals_host_at_group_counts(groups, form):
+    """Every kind of plane (float sum, exact integer sum, count, a date-like
+    extreme in float64, a 64-bit extreme by scatter) at each size of group
+    table; the counters say which reduce form served it."""
+    from daft_tpu.datatype import DataType
+
+    rng = np.random.default_rng(groups)
+    n = 40_000
+    df = daft_tpu.from_pydict({
+        "k": (rng.permutation(n) % groups).tolist(),
+        "v": rng.uniform(-50, 50, n).tolist(),
+        "q": rng.integers(-1000, 1000, n).tolist(),
+        "w": (rng.integers(-(1 << 60), 1 << 60, n) | 1).tolist(),
+    })
+
+    def q(d):
+        return (d.groupby("k")
+                .agg(col("v").sum().alias("sv"), col("v").mean().alias("mv"),
+                     col("q").sum().alias("sq"), col("v").count().alias("cv"),
+                     col("q").cast(DataType.int32()).min().alias("lo"),
+                     col("w").max().alias("hi"))
+                .sort("k"))
+
+    dev_out, host_out, (n_select, n_matmul) = _device_and_host(q, df)
+    assert (n_select > 0, n_matmul > 0) == (form == "select", form == "matmul")
+    for name in ("k", "sq", "cv", "lo", "hi"):
+        assert dev_out[name] == host_out[name], name
+    np.testing.assert_allclose(dev_out["sv"], host_out["sv"], rtol=1e-5, atol=1e-3)
+    np.testing.assert_allclose(dev_out["mv"], host_out["mv"], rtol=1e-5, atol=1e-5)
+
+
+def test_grouped_nulls_and_a_chunk_the_filter_empties():
+    """200,000 rows are four chunks of 65,536: the filter keeps no row of the
+    first, and the children carry nulls."""
+    rng = np.random.default_rng(11)
+    n = 200_000
+    vals = rng.uniform(0, 10, n)
+    df = daft_tpu.from_pydict({
+        "i": list(range(n)),
+        "k": rng.choice(["a", "b", "c", None], n).tolist(),
+        "v": [None if j % 7 == 0 else float(vals[j]) for j in range(n)],
+        "q": [None if j % 5 == 0 else int(j % 1000) for j in range(n)],
+    })
+
+    def q(d):
+        return (d.where(col("i") >= 70_000).groupby("k")
+                .agg(col("v").sum().alias("sv"), col("v").count().alias("cv"),
+                     col("q").sum().alias("sq"), col("q").mean().alias("mq"),
+                     col("q").count().alias("cq"))
+                .sort("k"))
+
+    dev_out, host_out, _forms = _device_and_host(q, df)
+    for name in ("k", "cv", "sq", "cq"):
+        assert dev_out[name] == host_out[name], name
+    np.testing.assert_allclose(dev_out["sv"], host_out["sv"], rtol=1e-6)
+    np.testing.assert_allclose(dev_out["mq"], host_out["mq"], rtol=1e-12)
+
+
+def test_grouped_integer_sums_past_2_24_are_exact():
+    rng = np.random.default_rng(12)
+    n = 150_000
+    df = daft_tpu.from_pydict({
+        "k": rng.integers(0, 5, n).tolist(),
+        "q": rng.integers(-(1 << 40), 1 << 40, n).tolist(),
+        "one": [1] * n,
+    })
+    q = lambda d: (d.groupby("k")
+                   .agg(col("q").sum().alias("s"), col("one").sum().alias("ones"))
+                   .sort("k"))
+    dev_out, host_out, _forms = _device_and_host(q, df)
+    assert dev_out == host_out
+    assert min(dev_out["ones"]) > 1 << 14 and max(map(abs, dev_out["s"])) > 1 << 24
+
+
+def test_grouped_float_sums_of_200k_rows_against_float64():
+    """float32 planes, float32 partials of at most 65,536 rows, combined in
+    float64: within 1e-6 of the sums taken in float64 throughout."""
+    rng = np.random.default_rng(13)
+    n = 200_000
+    k = rng.integers(0, 6, n)
+    price = rng.uniform(900, 105000, n)
+    disc = rng.uniform(0, 0.1, n)
+    df = daft_tpu.from_pydict({"k": k.tolist(), "price": price.tolist(),
+                               "disc": disc.tolist()})
+    q = lambda d: (d.groupby("k")
+                   .agg(col("price").sum().alias("sp"),
+                        (col("price") * (1 - col("disc"))).sum().alias("sd"))
+                   .sort("k"))
+    counters.reset()
+    with execution_config_ctx(device_mode="on"):
+        dev_out = q(df).to_pydict()
+    assert counters.device_grouped_batches > 0
+    # the device sees the float32 of each value, as the configuration states
+    np.testing.assert_allclose(
+        dev_out["sp"], np.bincount(k, weights=price), rtol=1e-6)
+    np.testing.assert_allclose(
+        dev_out["sd"], np.bincount(k, weights=price * (1 - disc)), rtol=1e-6)
+
+
+def _two_batches():
+    """Batch one, 150,000 rows: group "a" throughout; "z" at row 5 (which the
+    filter drops) and from row 140,000 on (the third chunk); "b" only in rows
+    the filter drops. Batch two, 1,000 rows: "y", then "b", "z", "a"."""
+    from daft_tpu.core.recordbatch import RecordBatch
+    from daft_tpu.core.series import Series
+    from daft_tpu.datatype import DataType
+    from daft_tpu.schema import Schema
+
+    schema = Schema.from_pydict({"k": DataType.string(), "v": DataType.float64()})
+
+    def batch(keys, vals):
+        cols = [Series.from_pylist(keys, "k"),
+                Series.from_numpy(np.asarray(vals, dtype=np.float64), "v",
+                                  DataType.float64())]
+        return RecordBatch(schema, cols, len(keys))
+
+    n1 = 150_000
+    keys = ["a"] * n1
+    vals = np.ones(n1)
+    keys[5], vals[5] = "z", -1.0
+    for j in range(140_000, n1, 2):
+        keys[j] = "z"
+    for j in range(100, 200):
+        keys[j], vals[j] = "b", -1.0
+    keys2 = (["y", "b", "z", "a"] * 250)
+    return schema, [batch(keys, vals), batch(keys2, np.full(1000, 2.0))]
+
+
+@pytest.mark.parametrize("form", ["select", "matmul"])
+def test_group_order_is_first_kept_occurrence_across_batches(form):
+    from daft_tpu.ops.grouped_stage import GroupedAggStage
+
+    schema, batches = _two_batches()
+    stage = GroupedAggStage(schema, col("v") > 0, [col("k")],
+                            [("s", col("v").sum()), ("n", col("v").count(mode="all"))])
+    stage._jitted[8] = stage._build(8, form=form)
+    run = stage.start_run()
+    for b in batches:
+        run.feed_batch(b)
+    key_rows, results = run.finalize()
+    assert key_rows == [("a",), ("z",), ("y",), ("b",)]
+    sums, counts = results[0][0], results[1][0]
+    assert list(counts) == [150_000 - 5_000 - 101 + 250, 5_000 + 250, 250, 250]
+    np.testing.assert_allclose(sums, [144_899 + 500, 5_000 + 500, 500, 500])
+
+
+@pytest.mark.parametrize("form", ["select", "matmul"])
+def test_aggs_that_share_a_child_share_their_planes(form):
+    """sum, mean and count of one child read one count plane and one sum
+    plane; count(mode="all") reads the kept-rows plane."""
+    from daft_tpu.ops.grouped_stage import GroupedAggStage
+
+    schema, batches = _two_batches()
+    stage = GroupedAggStage(schema, None, [col("k")], [
+        ("s", col("v").sum()), ("m", col("v").mean()), ("c", col("v").count()),
+        ("n", col("v").count(mode="all"))])
+    assert len(stage._mm_specs) == 3
+    stage._jitted[8] = stage._build(8, form=form)
+    run = stage.start_run()
+    run.feed_batch(batches[1])
+    key_rows, results = run.finalize()
+    assert key_rows == [("y",), ("b",), ("z",), ("a",)]
+    (s, _), (m, _), (c, _), (n_all, _) = results
+    assert list(c) == list(n_all) == [250] * 4
+    np.testing.assert_allclose(s, [500.0] * 4)
+    np.testing.assert_allclose(m, [2.0] * 4)
+
+
+def test_count_all_counts_rows_with_null_children():
+    df = daft_tpu.from_pydict({
+        "k": ["x", "y", "x", "y", "x"],
+        "v": [1.0, None, None, 4.0, 5.0],
+    })
+    q = lambda d: (d.groupby("k")
+                   .agg(col("v").count(mode="all").alias("n"),
+                        col("v").count().alias("c"), col("v").sum().alias("s"))
+                   .sort("k"))
+    dev_out, host_out, _forms = _device_and_host(q, df)
+    assert dev_out == host_out
+    assert dev_out["n"] == [3, 2] and dev_out["c"] == [2, 1]
