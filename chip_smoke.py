@@ -452,8 +452,8 @@ def phase_ring(tables, n: int) -> None:
 
 
 def phase_mesh(tables, n: int) -> None:
-    """The mesh tier against one chip: q1/q6 through the mesh grouped and
-    filter-agg stages, q12/q14 through the mesh join tier, one hash
+    """The mesh tier against one chip: q1/q6 through the grouped and
+    filter-agg stages sharded over the mesh, q12/q14 through the mesh join tier, one hash
     repartition over the all_to_all step, and where one resident sharded
     plane's shards live."""
     from benchmarking.tpch.queries import ALL_QUERIES
@@ -489,7 +489,7 @@ def phase_mesh(tables, n: int) -> None:
         _repartition_check(tables, n, "mesh_alltoall_dispatches")
 
     # where one sharded plane that q1 left resident lives
-    from daft_tpu.ops.mesh_stage import mesh_total
+    from daft_tpu.ops.stage import mesh_total
 
     series = next(tables["lineitem"].iter_partitions()).batches[0] \
         .get_column("l_quantity")

@@ -65,11 +65,14 @@ class ExecutionConfig:
     device_amortize_runs: int = field(
         default_factory=lambda: _env_int("DAFT_TPU_DEVICE_AMORTIZE", 64)
     )
-    # HBM residency budget (daft_tpu/device/residency.py): total device bytes
+    # HBM residency budget (daft_tpu/device/residency.py): bytes of ONE device
     # the engine may keep cached across queries (resident column planes, join
     # index planes, packed dim matrices). Positive = bytes; 0 (default) = auto
     # (3/4 of jax.Device.memory_stats()['bytes_limit'] when the backend
-    # reports it, else unbounded); negative = unbounded. Over budget, the
+    # reports it, else unbounded); negative = unbounded. On a mesh the budget
+    # is still a device's: a plane row-sharded over N devices counts one
+    # shard against it (1/N of its bytes), a replicated plane a copy, so four
+    # chips hold four times the rows of one under the same budget. Over budget, the
     # manager evicts least-recently-used unpinned entries; buffers pinned by
     # an executing query are never evicted mid-run.
     hbm_budget_bytes: int = field(
@@ -209,15 +212,18 @@ class ExecutionConfig:
     pipeline_mode: str = field(
         default_factory=lambda: os.environ.get("DAFT_TPU_PIPELINE", "on")
     )
-    # Multi-chip in-mesh SPMD execution (ops/mesh_stage.py over the
-    # parallel/distributed.py kernels): qualifying device agg stages execute
-    # sharded across a local device mesh — per-shard compute + one ICI
-    # collective (psum / all_gather table merge) inside ONE jit program.
-    #   - 0 (default) = auto: the cost model's ICI tier decides host vs
-    #     single-chip vs mesh per stage shape; the mesh must WIN its
-    #     placement, never be config-forced.
-    #   - 1 = single-chip only (mesh machinery never imported — the
-    #     zero-overhead off switch).
+    # Multi-chip execution over a mesh of this host's devices. A qualifying
+    # filter-aggregate or grouped-aggregate stage shards each batch's rows
+    # over the mesh and runs the single chip's program on every shard (f32
+    # planes resident per shard, predicate and group codes on the device, no
+    # collective; the shards' partial tables are combined in f64 on the host:
+    # ops/stage.py over_shards); a star join may take the mesh join tier
+    # (ops/mesh_stage.py).
+    #   - 0 (default) = auto: the cost model decides host vs single-chip vs
+    #     mesh per stage shape; the mesh must WIN its placement
+    #     (executor._mesh_wins), never be config-forced.
+    #   - 1 = single-chip only (no mesh is built — the zero-overhead off
+    #     switch).
     #   - N >= 2 = force an N-device mesh for qualifying stages; if fewer
     #     local devices exist the stage falls back to single-chip LOUDLY
     #     (counters.mesh_unavailable_fallbacks + a rejection record).

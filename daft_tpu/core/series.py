@@ -312,8 +312,8 @@ class Series:
         feed: the probe is then a purely local gather on each shard, no
         collective until the reduce. h2d attribution counts the host bytes
         once (the broadcast fan-out is the link's business, not the
-        ledger's); residency accounting still sees N per-device copies via
-        device_nbytes."""
+        ledger's); residency accounting counts the copy each device holds
+        (device_nbytes reckons per device, as the budget does)."""
         from ..utils import jax_setup  # noqa: F401
         import jax
         from jax.sharding import NamedSharding, PartitionSpec

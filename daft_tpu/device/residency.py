@@ -29,8 +29,10 @@ Design:
   varying predicate literals reuses one slot per shape instead of
   accumulating one entry per literal (ADVICE r5 medium).
 
-- Byte accounting walks each entry's value and sums jax.Array buffer sizes
-  (host numpy arrays are free — they are the host memory manager's problem).
+- Byte accounting walks each entry's value and sums what its jax.Arrays hold
+  on one device (device_nbytes: a plane sharded over a mesh counts a shard, a
+  replicated one a copy), because the budget is one device's HBM; host numpy
+  arrays are free — they are the host memory manager's problem.
   Values that lazily materialize device planes after being stored (e.g. the
   factorized-codes holder in device_join) are re-measured on every cache hit,
   so accounting converges without a registration protocol.
@@ -220,9 +222,13 @@ def stable_slot_key(anchor, key: tuple) -> Optional[int]:
 
 
 def device_nbytes(value) -> int:
-    """Total bytes of jax device arrays reachable from `value` (tuples, lists,
-    dicts, and objects exposing a ``device_nbytes()`` hook). Host numpy arrays
-    count zero — the budget is HBM, not RAM."""
+    """Bytes that the jax device arrays reachable from `value` (tuples,
+    lists, dicts, and objects exposing a ``device_nbytes()`` hook) hold on the
+    device that holds most of them: the budget is one device's HBM
+    (budget_bytes), so a plane row-sharded over a mesh of N devices counts a
+    shard (1/N of its global bytes), a replicated plane one whole copy, a
+    single-chip plane itself. Host numpy arrays count zero — the budget is
+    HBM, not RAM."""
     jax_mod = sys.modules.get("jax")
     if jax_mod is None:
         return 0
@@ -235,15 +241,12 @@ def device_nbytes(value) -> int:
         x = stack.pop()
         if isinstance(x, arr_t):
             try:
-                # sum per-device shard bytes, not the logical global size: a
-                # replicated plane on an 8-chip mesh really holds 8 copies in
-                # HBM, and a row-sharded plane's shards sum back to its global
-                # bytes — either way the budget sees physical occupancy
-                shards = getattr(x, "addressable_shards", None)
-                if shards:
-                    total += sum(int(s.data.nbytes) for s in shards)
-                else:
-                    total += int(x.nbytes)
+                per_device: Dict[object, int] = {}
+                for s in getattr(x, "addressable_shards", None) or ():
+                    per_device[s.device] = per_device.get(s.device, 0) \
+                        + int(s.data.nbytes)
+                total += max(per_device.values()) if per_device \
+                    else int(x.nbytes)
             except Exception:  # lint: ignore[broad-except] -- byte accounting is best-effort
                 try:
                     total += int(x.nbytes)

@@ -615,6 +615,12 @@ def _exec_device_agg(node) -> MicroPartition:
     when the measured cost model (ops/costmodel.py: live-calibrated d2h round
     trip + h2d bandwidth for non-resident columns + compute-rate terms) says
     the device beats the host numpy/C++ path for this stage's shape.
+
+    The device stage is one path whatever the devices: _select_mesh_tier says
+    how many local devices the run's dispatches shard their rows over (forced
+    by mesh_devices >= 2, or because the mesh won its placement), and the
+    same stage, feed loop, coalescer, pin scope and finalize run it
+    (stage.start_run(mesh_devices=...)); 0 or 1 is the single chip.
     """
     import itertools
 
@@ -689,6 +695,9 @@ def _exec_device_agg(node) -> MicroPartition:
                              stream=s, node=node)
         return MicroPartition(node.schema, [out.cast_to_schema(node.schema)])
 
+    # how many local devices the stage's dispatches shard their rows over:
+    # 0 or 1 is the single chip
+    mesh_n = 0
     if not use_device:
         # 3-way auto tier: a compute-bound stage can lose to the host on ONE
         # chip yet win across the mesh (compute / mesh width). _mesh_wins
@@ -698,23 +707,25 @@ def _exec_device_agg(node) -> MicroPartition:
             import jax
 
             if jax.default_backend() not in ("cpu",):
-                mesh_n, stream, mrec = _select_mesh_tier(node, stream,
+                mesh_n, stream, prec = _select_mesh_tier(node, stream,
                                                          grouped, cfg)
-                if mesh_n:
-                    return _exec_mesh_stage(node, stream, grouped, mesh_n,
-                                            cfg, _host_agg, prec=mrec)
-        return _host_agg(stream)
+        if not mesh_n:
+            return _host_agg(stream)
+    elif cfg.mesh_devices != 1:
+        mesh_n, stream, mrec = _select_mesh_tier(node, stream, grouped, cfg)
+        if mesh_n:
+            prec = mrec
 
     from ..core.series import Series
     from ..device.residency import manager as _residency
 
     in_schema = node.input.schema
-    mesh_n = 0
-    if cfg.mesh_devices != 1:
-        mesh_n, stream, mrec = _select_mesh_tier(node, stream, grouped, cfg)
     if mesh_n:
-        return _exec_mesh_stage(node, stream, grouped, mesh_n, cfg, _host_agg,
-                                prec=mrec)
+        from ..observability.runtime_stats import current_collector
+
+        c = current_collector()
+        if c is not None:
+            c.annotate(node, f"mesh: {mesh_n} devices")
     site = "grouped agg" if grouped else "agg"
     if prec is None and cfg.device_mode == "on":
         # forced run: recorded so the ledger attributes the dispatch; priced
@@ -740,7 +751,7 @@ def _exec_device_agg(node) -> MicroPartition:
         stage = try_build_grouped_agg_stage(
             in_schema, node.predicate, node.groupby, node.aggregations)
         assert stage is not None, "planner emitted DeviceGroupedAgg for a non-qualifying plan"
-        run = stage.start_run()
+        run = stage.start_run(mesh_devices=mesh_n)
         coal = _make_coalescer(run.feed_batch, cfg)
         feed = coal.add if coal is not None else run.feed_batch
         buffered: List[MicroPartition] = []
@@ -774,7 +785,7 @@ def _exec_device_agg(node) -> MicroPartition:
 
     stage = try_build_filter_agg_stage(in_schema, node.predicate, node.aggregations)
     assert stage is not None, "planner emitted DeviceFilterAgg for a non-qualifying plan"
-    run = stage.start_run()
+    run = stage.start_run(mesh_devices=mesh_n)
     coal = _make_coalescer(run.feed_batch, cfg)
     feed = coal.add if coal is not None else run.feed_batch
     fed_rows = 0
@@ -1813,10 +1824,14 @@ def _select_mesh_tier(node, stream, grouped: bool, cfg):
     LOUD fallback (counter + rejection record) when fewer exist — the old
     gate fell back silently. Auto (mesh_devices == 0): the mesh must WIN its
     placement, never be config-forced — the first morsel's shape is costed
-    (ops/costmodel.py mesh_*_cost) and the mesh tier is taken only when it
-    beats BOTH the single-chip device and the host; verdicts are cached per
-    stage shape like the join decision cache. Returns (n_devices, stream,
-    placement_record) with any peeked partition chained back."""
+    (_mesh_wins) and the mesh tier is taken only when it beats BOTH the
+    single-chip device and the host; verdicts are cached per stage shape like
+    the join decision cache (not per residency: like _decision_key, a repeat
+    whose residency differs reuses the verdict; _mesh_wins itself reads
+    residency in the f32 layout the sharded stage asks for, so the first,
+    deciding query of a warm table is not priced as an upload). Returns
+    (n_devices, stream, placement_record) with any peeked partition chained
+    back."""
     import jax
 
     from ..ops import counters as _counters
@@ -1868,29 +1883,41 @@ def _select_mesh_tier(node, stream, grouped: bool, cfg):
 @_decide_span
 def _mesh_wins(node, first: MicroPartition, grouped: bool, ndev: int):
     """Cost-model tier decision: mesh vs single-chip vs host for one stage
-    shape. Mesh compute divides by the mesh width but pays a multi-device
-    dispatch premium and the ICI collective; uploads amortize exactly like
-    the single-chip decision when the source table is resident. Returns
-    (wins, placement_record) — the record carries all THREE tiers'
+    shape. The mesh runs the single chip's program on every shard, so its
+    arm is the single-chip arm at rows / ndev, with residency probed in the
+    layout the sharded stage asks for, plus what spanning the devices adds
+    (costmodel.over_mesh: the launch premium and the fetch of one partial
+    table a shard, as _probe_mesh_terms measured them). Uploads amortize
+    exactly like the single-chip decision when the source table is resident.
+    Returns (wins, placement_record) — the record carries all THREE tiers'
     CostBreakdowns (mesh / device / host)."""
     from ..config import execution_config
     from ..ops import costmodel, counters as _counters
-    from ..ops.stage import _decompose_agg, pad_bucket
+    from ..ops.stage import mesh_total, pad_bucket
 
     batch = next((b for b in first.batches if b.num_rows > 0), None)
     if batch is None:
         return False, None
     rows = first.num_rows
+    shard_rows = max((rows + ndev - 1) // ndev, 1)
     cal = costmodel.calibrate()
     coal = _coalesce_horizon([first])
     amort = max(execution_config().device_amortize_runs, 1) \
         if _resident_source_rec(node.input) else 1
-    # mesh planes shard to a per-device bucket; same quantization as
-    # ops/mesh_stage.mesh_total, computed inline so a rejected tier never
-    # imports the mesh machinery
-    per = pad_bucket(max((batch.num_rows + ndev - 1) // ndev, 1))
-    mesh_pad = per * ndev
+    mesh_pad = mesh_total(batch.num_rows, ndev)
     bucket = pad_bucket(batch.num_rows)
+
+    def plane_bytes(stage, pad_to, mesh_devices):
+        """(non-resident, resident) bytes of the stage's input planes in one
+        layout: the dtype the stage uploads, 5 B a row with validity."""
+        nonres = res = 0
+        for c in stage._input_cols:
+            if batch.get_column(c).is_device_resident(
+                    pad_to, f32=not stage._use_f64, mesh_devices=mesh_devices):
+                res += batch.num_rows * 5
+            else:
+                nonres += batch.num_rows * 5
+        return nonres, res
 
     if grouped:
         from ..ops.grouped_stage import (MAX_MATMUL_SEGMENTS, _pad_groups,
@@ -1905,40 +1932,30 @@ def _mesh_wins(node, first: MicroPartition, grouped: bool, ndev: int):
         key_series = resolve_key_series(batch, stage.groupby, batch.num_rows)
         card = max(estimate_key_cardinality(key_series), 1)
         cap_est = _pad_groups(min(card, 2 * MAX_MATMUL_SEGMENTS))
-        nonres_single = sum(
-            batch.num_rows * 5 for c in stage._input_cols
-            if not batch.get_column(c).is_device_resident(bucket, f32=True))
-        # mesh planes are f64 (9B/row with validity) under their own slot keys
-        nonres_mesh = sum(
-            batch.num_rows * 9 for c in stage._input_cols
-            if not batch.get_column(c).is_device_resident(
-                mesh_pad, f32=False, mesh_devices=ndev))
-        n_cols = sum(len(_decompose_agg(agg.op)) for _n, agg in stage.aggs)
-        # mesh keys always host-factorize, but the codes are cached on the
-        # key Series (ops/mesh_stage._batch_group_codes), so resident-table
-        # repeats amortize like uploads
-        mesh_cost = costmodel.mesh_grouped_cost(
-            cal, rows, nonres_mesh // amort, n_cols, cap_est, ndev,
-            factorize_rows=rows // amort, coalesce=coal)
-        # single-chip factorize pricing MUST match _device_wins: dictionary
-        # keys amortize (cached per Series), host-mode keys re-factorize per
-        # run at full price — disagreeing here would under-price one tier
+        # factorize pricing MUST match _device_wins, on both arms: dictionary
+        # keys amortize (cached per Series, and shared by the two layouts'
+        # code planes), host-mode keys re-factorize per run at full price
         if stage.dict_keys:
-            single_fact_rows = _dict_build_rows(key_series, batch.num_rows,
-                                                cal) // amort
+            fact_rows = _dict_build_rows(key_series, batch.num_rows,
+                                         cal) // amort
         else:
-            single_fact_rows = batch.num_rows
+            fact_rows = batch.num_rows
         n_planes = (len(stage._mm_specs) + len(stage._ext_specs)
                     + len(stage._sct_specs))
-        if card > MAX_MATMUL_SEGMENTS:
-            single_cost = costmodel.device_grouped_sort_cost(
-                cal, rows, nonres_single // amort, n_planes=n_planes,
-                factorize_rows=single_fact_rows, coalesce=coal)
-        else:
-            single_cost = costmodel.device_grouped_cost(
-                cal, rows, nonres_single // amort, n_mm=len(stage._mm_specs),
+
+        def arm(arm_rows, nonres, res):
+            if card > MAX_MATMUL_SEGMENTS:
+                return costmodel.device_grouped_sort_cost(
+                    cal, arm_rows, nonres // amort, n_planes=n_planes,
+                    factorize_rows=fact_rows, coalesce=coal,
+                    resident_bytes=res)
+            return costmodel.device_grouped_cost(
+                cal, arm_rows, nonres // amort, n_mm=len(stage._mm_specs),
                 n_ext=len(stage._ext_specs), n_sct=len(stage._sct_specs),
-                cap=cap_est, factorize_rows=single_fact_rows, coalesce=coal)
+                cap=cap_est, factorize_rows=fact_rows, coalesce=coal,
+                resident_bytes=res)
+
+        table_bytes = cap_est * n_planes * 8
         host_cost = costmodel.host_agg_cost(
             cal, rows, len(node.aggregations), grouped=True,
             has_predicate=node.predicate is not None)
@@ -1950,21 +1967,20 @@ def _mesh_wins(node, first: MicroPartition, grouped: bool, ndev: int):
         if stage is None:
             return False, None
         n_partials = max(len(stage.aggs), 1)
-        nonres_single = sum(
-            batch.num_rows * 5 for c in stage._input_cols
-            if not batch.get_column(c).is_device_resident(bucket, f32=True))
-        nonres_mesh = sum(
-            batch.num_rows * 9 for c in stage._input_cols
-            if not batch.get_column(c).is_device_resident(
-                mesh_pad, f32=False, mesh_devices=ndev))
-        mesh_cost = costmodel.mesh_ungrouped_cost(
-            cal, rows, nonres_mesh // amort, n_partials, ndev, coalesce=coal)
-        single_cost = costmodel.device_ungrouped_cost(
-            cal, rows, nonres_single // amort, n_partials=n_partials,
-            coalesce=coal)
+
+        def arm(arm_rows, nonres, res):
+            return costmodel.device_ungrouped_cost(
+                cal, arm_rows, nonres // amort, n_partials=n_partials,
+                coalesce=coal, resident_bytes=res)
+
+        table_bytes = n_partials * 16
         host_cost = costmodel.host_agg_cost(
             cal, rows, len(node.aggregations), grouped=False,
             has_predicate=node.predicate is not None)
+    single_cost = arm(rows, *plane_bytes(stage, bucket, 0))
+    mesh_cost = costmodel.over_mesh(
+        arm(shard_rows, *plane_bytes(stage, mesh_pad, ndev)), cal, ndev,
+        table_bytes)
     wins = mesh_cost < single_cost and mesh_cost < host_cost
     if not wins:
         _counters.reject(
@@ -1979,84 +1995,6 @@ def _mesh_wins(node, first: MicroPartition, grouped: bool, ndev: int):
         "mesh tier", chosen, rows, device=single_cost, host=host_cost,
         mesh=mesh_cost, detail=f"{ndev} devices")
     return wins, rec
-
-
-def _exec_mesh_stage(node, stream, grouped: bool, n_devices: int, cfg,
-                     host_agg, prec=None) -> MicroPartition:
-    """Run a DeviceFilterAgg/DeviceGroupedAgg node sharded across the local
-    mesh (ops/mesh_stage.py) — the engine's scale-out execution tier.
-
-    Identical streaming contract to the single-chip stages: the adaptive
-    morsel stream and DispatchCoalescer feed super-batches (no whole-input
-    materialization), resident planes pin for the query's duration, and a
-    runtime DeviceFallback reruns the buffered stream on host. Attribution:
-    counters.mesh_dispatches / mesh_grouped_runs, the mesh profile-span
-    lanes, and the EXPLAIN ANALYZE operator annotation "mesh: N devices".
-    """
-    from ..device.residency import manager as _residency
-    from ..observability.runtime_stats import current_collector
-    from ..ops import mesh_stage as ms
-    from ..ops.grouped_stage import DeviceFallback
-
-    in_schema = node.input.schema
-    c = current_collector()
-    if c is not None:
-        c.annotate(node, f"mesh: {n_devices} devices")
-
-    if grouped:
-        stage = ms.try_build_mesh_grouped_agg_stage(
-            in_schema, node.predicate, node.groupby, node.aggregations,
-            n_devices)
-        assert stage is not None, \
-            "planner emitted DeviceGroupedAgg for a non-qualifying plan"
-        run = stage.start_run()
-        coal = _make_coalescer(run.feed_batch, cfg)
-        feed = coal.add if coal is not None else run.feed_batch
-        buffered: List[MicroPartition] = []
-        fed_rows = 0
-        try:
-            with _placement.feedback(prec) as fb, _residency().pin_scope():
-                for part in stream:
-                    buffered.append(part)
-                    fed_rows += part.num_rows
-                    for b in part.batches:
-                        feed(b)
-                if coal is not None:
-                    coal.close()
-                fb.set_rows(fed_rows)
-                key_rows, results = run.finalize()
-        except DeviceFallback:
-            return host_agg(itertools.chain(buffered, stream))
-        return _grouped_output(node.schema, node.groupby, node.aggregations,
-                               key_rows, results)
-
-    from ..core.series import Series
-
-    stage = ms.try_build_mesh_filter_agg_stage(
-        in_schema, node.predicate, node.aggregations, n_devices)
-    assert stage is not None, \
-        "planner emitted DeviceFilterAgg for a non-qualifying plan"
-    run = stage.start_run()
-    coal = _make_coalescer(run.feed_batch, cfg)
-    feed = coal.add if coal is not None else run.feed_batch
-    fed_rows = 0
-    # no buffering: the ungrouped mesh run has no DeviceFallback site, so the
-    # stream flows straight through like the single-chip path
-    with _placement.feedback(prec) as fb, _residency().pin_scope():
-        for part in stream:
-            fed_rows += part.num_rows
-            for b in part.batches:
-                feed(b)
-        if coal is not None:
-            coal.close()
-        fb.set_rows(fed_rows)
-        final = run.finalize()
-    cols = []
-    for name, _agg in stage.aggs:
-        f = node.schema[name]
-        cols.append(Series.from_pylist([final[name]], f.name, dtype=f.dtype))
-    out = RecordBatch(node.schema, cols, 1)
-    return MicroPartition(node.schema, [out.cast_to_schema(node.schema)])
 
 
 @_decide_span

@@ -130,10 +130,14 @@ DEVICE_COUNTER_NAMES = (
     "device_grouped_reduce_select",  # a masked sum a group, on the VPU
     "device_grouped_reduce_matmul",  # the one-hot product, on the MXU
     "device_stage_runs",       # completed device agg node executions
+    # a stage dispatch whose rows were sharded over more than one local device
+    # (stage.note_mesh_dispatch): it is counted as a device_stage_batches or
+    # device_grouped_batches dispatch too
+    "device_mesh_batches",     # dispatches that spanned more than one device
+    "device_mesh_shards",      # devices summed over those dispatches
     "mesh_grouped_runs",       # grouped aggs executed via the mesh-sharded path
     "mesh_dispatches",         # multi-device shard_map/pjit dispatches issued
     "mesh_unavailable_fallbacks",  # forced mesh_devices > local devices -> single-chip
-    "mesh_capacity_growths",   # mesh group-table capacity grown mid-run (recompile)
     "device_join_batches",     # batches through the gather-join device stages
     "join_provision_calls",    # join dispatches whose columns came from one traced program
     "join_provision_traces",   # provisioning programs traced (0 on a repeat query shape)

@@ -154,10 +154,11 @@ class Calibration:
     host_agg_rate: float          # host value-ops per sec (vectorized numpy)
     host_factorize_rate: float    # host group-key factorize rows per sec
     host_probe_rate: float        # host hash-join probe rows per sec per dim
-    # mesh (multi-chip SPMD) tier: one dispatch spans every local chip, so it
-    # pays an extra multi-device launch/synchronization overhead on top of
-    # rtt_s, and its cross-shard exchange moves bytes over ICI. Defaulted so
-    # single-chip call sites can construct a Calibration without mesh terms.
+    # mesh (multi-chip SPMD) dispatches: one spans every local chip, so it
+    # pays an extra multi-device launch and the gathering of a partial from
+    # every shard on top of rtt_s (over_mesh); the join tier's cross-shard
+    # exchange moves bytes over ICI. Defaulted so single-chip call sites can
+    # construct a Calibration without mesh terms.
     ici_bytes_per_s: float = 4.5e10  # per-link ICI collective bandwidth
     mesh_dispatch_s: float = 2e-3    # extra fixed cost of a multi-device dispatch
     # device-UDF tier (ops/udf_stage.py): model-forward throughput on the
@@ -329,9 +330,12 @@ _STATIC_MESH_DISPATCH_S = 2e-3
 
 def _probe_mesh_terms(rtt: float):
     """(ici_bytes_per_s, mesh_dispatch_s) measured on the local mesh:
-    best-of-2 timings of a tiny psum (the multi-device launch premium over
-    the single-chip rtt) and a ~4MB all_gather (collective bandwidth — each
-    device receives the full array, so bytes-moved = nbytes x mesh width).
+    best-of-2 timings of a tiny sharded program whose result is one partial a
+    shard, fetched (what a sharded aggregate dispatch adds to the single
+    chip's rtt: the launch on every device and the gathering of the shards'
+    partials) and a ~4MB all_gather (collective bandwidth, for the join
+    tier — each device receives the full array, so bytes-moved = nbytes x
+    mesh width).
     Static v5e constants when fewer than 2 local devices exist or the probe
     fails (the tier gate rejects meshes there regardless)."""
     try:
@@ -355,9 +359,9 @@ def _probe_mesh_terms(rtt: float):
         P = PartitionSpec
 
         def small(x):
-            return jax.lax.psum(jnp.sum(x), "dp")
+            return jnp.sum(x)[None]
 
-        sprobe = jax.jit(_shard_map(small, mesh, (P("dp"),), P()))
+        sprobe = jax.jit(_shard_map(small, mesh, (P("dp"),), P("dp")))
         xs = jax.device_put(np.ones(8 * n, np.float32),
                             NamedSharding(mesh, P("dp")))
         jax.device_get(sprobe(xs))  # compile outside the timed region
@@ -562,45 +566,20 @@ def device_ungrouped_cost(cal: Calibration, rows: int, nonresident_bytes: int,
     return out
 
 
-def mesh_ungrouped_cost(cal: Calibration, rows: int, nonresident_bytes: int,
-                        n_partials: int, n_devices: int,
-                        coalesce: float = 1.0,
-                        resident_bytes: int = 0) -> CostBreakdown:
-    """One mesh filter+ungrouped-agg dispatch: the per-shard reduce runs on
-    rows/N, the combine is one psum of n_partials scalars over ICI, and the
-    dispatch pays the multi-device launch premium on top of the (coalesce-
-    amortized) round trip. Upload bytes are the same as single-chip — shards
-    split the data, they don't duplicate it."""
-    n = max(n_devices, 1)
-    out = _base_terms(cal, nonresident_bytes, coalesce, resident_bytes)
-    out.add("mesh_dispatch", cal.mesh_dispatch_s)
-    out.add("compute", rows * max(n_partials, 1) / (cal.mm_plane_rows_per_s * n))
-    out.add("ici", max(n_partials, 1) * 8 * n / cal.ici_bytes_per_s)
-    return out
-
-
-def mesh_grouped_cost(cal: Calibration, rows: int, nonresident_bytes: int,
-                      n_cols: int, cap: int, n_devices: int,
-                      factorize_rows: int, coalesce: float = 1.0,
-                      resident_bytes: int = 0) -> CostBreakdown:
-    """One mesh exact-groupby dispatch (parallel/distributed.py
-    sharded_groupby_step): per shard an O(s log s) sort/unique over s = rows/N
-    plus one segmented reduce per value plane, then an all_gather table merge
-    moving cap x (n_cols + 1) x 8 bytes from every device over ICI. Host key
-    factorize is unchanged (full rows — it happens before sharding)."""
-    import math
-
-    n = max(n_devices, 1)
-    shard = max(rows // n, 1)
-    logn = max(math.log2(max(shard, 2)), 1.0)
-    cap = max(cap, 16)
-    out = _base_terms(cal, nonresident_bytes, coalesce, resident_bytes)
-    out.add("mesh_dispatch", cal.mesh_dispatch_s)
-    out.add("compute", shard * logn / cal.mm_plane_rows_per_s
-            + shard * max(n_cols, 1) / cal.mm_plane_rows_per_s)
-    out.add("ici", cap * (max(n_cols, 1) + 1) * 8 * n / cal.ici_bytes_per_s)
-    out.add("factorize", factorize_rows / cal.host_factorize_rate)
-    return out
+def over_mesh(shard_cost: CostBreakdown, cal: Calibration, n_devices: int,
+              table_bytes: int) -> CostBreakdown:
+    """A sharded aggregate dispatch (ops/stage.over_shards): `shard_cost` is
+    the single-chip arm priced at one shard's rows (every device runs the
+    single chip's program on its shard at once; upload bytes are the whole
+    batch's, shards split the data and do not duplicate it). Spanning the
+    devices adds the multi-device launch premium on top of the round trip,
+    and the fetch of one partial table of `table_bytes` from every device
+    but the first, which the single chip's fetch already is. No collective
+    runs, so there is no ICI term."""
+    shard_cost.add("mesh_dispatch", cal.mesh_dispatch_s)
+    shard_cost.add("combine",
+                   max(n_devices - 1, 0) * table_bytes / cal.d2h_bytes_per_s)
+    return shard_cost
 
 
 def device_join_agg_cost(cal: Calibration, rows: int, upload_bytes: int,

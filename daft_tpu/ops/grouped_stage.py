@@ -34,6 +34,13 @@ how a TPU behaves (see ops/costmodel.py):
   result stays on device until finalize(), which fetches all pending tables in
   a single device_get, so the run pays the d2h round trip exactly once.
 
+- **Several chips run the same program.** A run started with
+  ``mesh_devices`` > 1 shards each batch's planes by rows over a mesh of
+  local devices (stage.over_shards); every shard runs the program above at
+  its own bucket and returns its [groups x planes] partial; finalize merges
+  the shards' tables on the host like those of successive batches. No
+  collective, no second implementation (_over_mesh).
+
 Static shapes: rows pad to power-of-two buckets, the group table pads to a
 power-of-two capacity, with one trash segment for filtered/padding rows. The
 jit cache is bounded by O(log rows · log groups) per stage structure.
@@ -59,7 +66,8 @@ from ..observability.runtime_stats import profile_span
 from ..schema import Schema
 from . import counters
 from . import device_eval as dev
-from .stage import device_row_mask, pad_bucket
+from .stage import (MESH_AXIS, device_row_mask, local_mesh, mesh_total,
+                    note_mesh_dispatch, over_shards, pad_bucket, shard_rows)
 
 _MIN_GROUP_CAP = 8
 # segment-count ceiling for the matmul path: beyond this the one-hot FLOPs and
@@ -158,22 +166,27 @@ def _isum_digit(v, kind: str):
     return ((u >> (8 * k)) & 255).astype(jnp.float32)
 
 
-def cached_dict_code_plane(src, codes: np.ndarray, rows: int, cap: int):
+def cached_dict_code_plane(src, codes: np.ndarray, rows: int, cap: int,
+                           mesh=None):
     """Device plane of dictionary codes padded to `cap`, registered in the
     HBM residency manager anchored on the Series (THE one implementation —
     grouped stages and the join stage share it, so the
-    padding-rows-are-code-0 invariant lives in one place)."""
+    padding-rows-are-code-0 invariant lives in one place). With `mesh` the
+    plane is row-sharded over it, under a slot key of its own like a column
+    plane's (Series.to_device_cached)."""
     from ..device.residency import manager
 
     def build():
         padded = np.zeros(cap, dtype=np.int32)
         padded[:rows] = codes
-        return jnp.asarray(padded)
+        return jnp.asarray(padded) if mesh is None \
+            else shard_rows(mesh, padded, cap)
 
+    key = ("dictcodes", cap) if mesh is None else \
+        ("dictcodes", cap, "mesh", int(mesh.shape[MESH_AXIS]), MESH_AXIS)
     # rebuild_rows: losing this plane re-runs the host dictionary factorize
     # over the source rows — weigh that in cost-ordered eviction
-    return manager().get_or_build(src, ("dictcodes", cap), (), build,
-                                  rebuild_rows=rows)
+    return manager().get_or_build(src, key, (), build, rebuild_rows=rows)
 
 
 def resolve_key_series(batch, groupby, n: int):
@@ -394,8 +407,10 @@ class GroupedAggStage:
                     cols.append(c)
         return cols
 
-    def start_run(self) -> "GroupedAggRun":
-        return GroupedAggRun(self)
+    def start_run(self, mesh_devices: int = 1) -> "GroupedAggRun":
+        """A fresh accumulator; with `mesh_devices` > 1 its dispatches shard
+        each batch's rows over that many local devices."""
+        return GroupedAggRun(self, mesh_devices)
 
     def _chunk_planes(self, cap: int, fdt, radices: Tuple[int, ...]) -> Callable:
         """The plane evaluator of _build's loop: for a tile of rows (the
@@ -452,7 +467,7 @@ class GroupedAggStage:
         return planes
 
     def _build(self, cap: int, form: Optional[str] = None,
-               radices: Tuple[int, ...] = ()) -> Callable:
+               radices: Tuple[int, ...] = (), mesh=None) -> Callable:
         """The one-hot tier's program (cap <= MAX_MATMUL_SEGMENTS): ONE loop
         over tiles of the input planes, taken as views of the resident arrays.
         A step evaluates predicate, agg children and planes for its tile only
@@ -470,7 +485,11 @@ class GroupedAggStage:
 
         `codes` is the segment-id plane or, with `radices` (the dictionary
         route), the tuple of the key columns' code planes, combined a tile at
-        a time like every other input."""
+        a time like every other input.
+
+        Over `mesh` every device runs this program on its shard of the rows
+        (_over_mesh): the loop, its tiles and the [cap, P] partial are one
+        chip's at the shard's bucket."""
         fdt = jnp.float64 if self._use_f64 else jnp.float32
         planes_of = self._chunk_planes(cap, fdt, radices)
         ext_specs, sct_specs = self._ext_specs[1:], self._sct_specs
@@ -585,9 +604,9 @@ class GroupedAggStage:
                               acc_first.astype(jnp.float64) + row_offset, jnp.inf)
             return {"mm": acc_mm, "ext": (first,) + acc_ext, "sct": acc_sct}
 
-        return jax.jit(stage)
+        return jax.jit(_over_mesh(stage, mesh))
 
-    def _build_sorted(self, cap: int) -> Callable:
+    def _build_sorted(self, cap: int, mesh=None) -> Callable:
         """High-cardinality path (cap > MAX_MATMUL_SEGMENTS): sort-based
         segmented reduction instead of one-hot matmuls. All ops are
         XLA-native and scatter-free — argsort the segment ids, reduce runs
@@ -692,16 +711,19 @@ class GroupedAggStage:
 
             return {"mm": acc_mm, "ext": tuple(exts), "sct": tuple(scts)}
 
-        return jax.jit(stage)
+        return jax.jit(_over_mesh(stage, mesh))
 
     def _program_for(self, cap: int, rows: int = 0,
-                     radices: Tuple[int, ...] = ()) -> Tuple[Callable, str]:
+                     radices: Tuple[int, ...] = (),
+                     mesh_devices: int = 1) -> Tuple[Callable, str]:
         """The jitted program that serves `cap` groups, and how it reduces:
         "pallas" (the kernel tier, when its gate admits the shape), "select"
         or "matmul" (_build's two forms, up to MAX_MATMUL_SEGMENTS), "sort".
         Only _build's program takes the key columns' code planes and their
-        `radices` in place of the segment ids."""
-        interp = self._pallas_gate(cap, rows)
+        `radices` in place of the segment ids. With `mesh_devices` > 1 the
+        program is the same one run on every shard of that many devices (the
+        XLA tiers only: the kernel tier stays a single chip's)."""
+        interp = self._pallas_gate(cap, rows) if mesh_devices <= 1 else None
         if interp is not None:
             key, form = ("pallas", cap), "pallas"
         elif cap <= MAX_MATMUL_SEGMENTS:
@@ -709,11 +731,14 @@ class GroupedAggStage:
             form = _reduce_form(cap)
         else:
             key, form = cap, "sort"
+        if mesh_devices > 1:
+            key = (key, "mesh", mesh_devices)
         if key not in self._jitted:
+            mesh = local_mesh(mesh_devices)
             self._jitted[key] = (
                 self._build_pallas(cap, interpret=interp) if form == "pallas"
-                else self._build_sorted(cap) if form == "sort"
-                else self._build(cap, radices=radices))
+                else self._build_sorted(cap, mesh) if form == "sort"
+                else self._build(cap, radices=radices, mesh=mesh))
         return self._jitted[key], form
 
     def _pallas_eligible(self) -> bool:
@@ -1022,12 +1047,32 @@ class GroupedAggStage:
         return jax.jit(stage)
 
 
+def _over_mesh(stage: Callable, mesh) -> Callable:
+    """A grouped program of (cols, codes, row_mask, row_offset) as it runs:
+    as it is on one chip, or over `mesh` on every shard (stage.over_shards),
+    each shard's first-row positions offset by the rows of the shards before
+    it, so the groups' order is that of the whole batch."""
+    if mesh is None:
+        return stage
+
+    def on_shard(cols, codes, row_mask, row_offset):
+        before = jax.lax.axis_index(MESH_AXIS).astype(jnp.float64) \
+            * row_mask.shape[0]
+        return stage(cols, codes, row_mask, row_offset + before)
+
+    return over_shards(on_shard, mesh, replicated_tail=1)
+
+
 class GroupedAggRun:
     """Per-run accumulator. Dispatches stay async; device tables are fetched in
-    ONE device_get at finalize, then merged on the host (vectorized by slot)."""
+    ONE device_get at finalize, then merged on the host (vectorized by slot).
+    With `mesh_devices` > 1 a dispatch's rows are sharded over that many local
+    devices and its result holds one table a shard, merged like the tables of
+    successive batches."""
 
-    def __init__(self, stage: GroupedAggStage):
+    def __init__(self, stage: GroupedAggStage, mesh_devices: int = 1):
         self.stage = stage
+        self.mesh_devices = max(int(mesh_devices), 1)
         # (device_out, decode) where decode resolves segment -> key tuple + presence
         self._pending: List[Tuple[dict, "_Decode"]] = []
         self._row_offset = 0
@@ -1037,11 +1082,14 @@ class GroupedAggRun:
         n = batch.num_rows
         if n == 0:
             return
-        bucket = pad_bucket(n)
-        decode = self._codes_for(batch, n, bucket)
+        ndev = self.mesh_devices
+        mesh = local_mesh(ndev)
+        bucket = pad_bucket(n) if mesh is None else mesh_total(n, ndev)
+        decode = self._codes_for(batch, n, bucket, mesh)
+        decode.shards = ndev
         by_dict = decode.code_planes is not None
         prog, form = stage._program_for(
-            decode.cap, n, tuple(decode.radices) if by_dict else ())
+            decode.cap, n, tuple(decode.radices) if by_dict else (), ndev)
         if not by_dict:
             codes = decode.dcodes
         elif form in ("select", "matmul"):
@@ -1050,25 +1098,27 @@ class GroupedAggRun:
             codes = sum(c * r for c, r in zip(decode.code_planes, decode.radices))
         with profile_span("device.h2d", "device", rows=n, bucket=bucket):
             dcols = {name: batch.get_column(name).to_device_cached(
-                         bucket, f32=not stage._use_f64)
+                         bucket, f32=not stage._use_f64, mesh=mesh)
                      for name in stage._input_cols}
         with profile_span("device.dispatch", "device", op="grouped_agg",
                           rows=n, bucket=bucket, groups_cap=decode.cap):
-            mask = device_row_mask(n, bucket)
+            mask = device_row_mask(n, bucket, mesh)
             offset = jnp.asarray(float(self._row_offset))
             # a Pallas program that does not lower raises here: no tier
             # replaces it behind the caller's back
             with profile_span("device.launch", "device", op="grouped_agg",
-                              cap=decode.cap, reduce=form):
+                              cap=decode.cap, reduce=form, devices=ndev):
                 out = prog(dcols, codes, mask, offset)
         if form == "pallas":
             counters.bump("pallas_dispatches")
+        if ndev > 1:
+            note_mesh_dispatch(ndev)
         count_reduce(form)
         self._row_offset += n
         self._pending.append((out, decode))
         counters.bump("device_grouped_batches")
 
-    def _codes_for(self, batch, n: int, bucket: int) -> "_Decode":
+    def _codes_for(self, batch, n: int, bucket: int, mesh=None) -> "_Decode":
         """Segment codes for one batch: device dictionary combine when the keys
         are plain columns with small combined cardinality, else host factorize.
 
@@ -1087,7 +1137,7 @@ class GroupedAggRun:
             if 0 < total <= MAX_SORT_SEGMENTS:
                 cap = _pad_groups(total)
                 # per-column code planes on the device (cached per Series)
-                dcode_cols = [cached_dict_code_plane(s, codes, n, bucket)
+                dcode_cols = [cached_dict_code_plane(s, codes, n, bucket, mesh)
                               for s, (codes, _, _) in zip(key_series, encoded)]
                 radices = []
                 mult = 1
@@ -1126,7 +1176,9 @@ class GroupedAggRun:
                 "sort-path segment ceiling")
         codes = np.full(bucket, cap, dtype=np.int32)
         codes[:n] = group_ids
-        return _Decode(cap=cap, dcodes=jnp.asarray(codes), dicts=None,
+        dcodes = jnp.asarray(codes) if mesh is None \
+            else shard_rows(mesh, codes, bucket)
+        return _Decode(cap=cap, dcodes=dcodes, dicts=None,
                        radices=None, key_rows=key_rows)
 
     def finalize(self):
@@ -1146,14 +1198,15 @@ class GroupedAggRun:
         stage = self.stage
         pending, self._pending = self._pending, []
         self._row_offset = 0
+        counters.bump("device_stage_runs")
+        if self.mesh_devices > 1:
+            counters.bump("mesh_grouped_runs")
         if not pending:
-            counters.bump("device_stage_runs")
             return [], [(np.empty(0), np.empty(0, dtype=bool)) for _ in stage.aggs]
 
         with profile_span("device.d2h", "device", op="grouped_agg",
                           batches=len(pending)):
             fetched = jax.device_get([out for out, _ in pending])  # one round trip
-        counters.bump("device_stage_runs")
 
         # host merge across batches: key tuple -> slot, vectorized per table
         key_slot: Dict[tuple, int] = {}
@@ -1165,7 +1218,14 @@ class GroupedAggRun:
         sct_parts: List[List[np.ndarray]] = []
         slot_maps: List[np.ndarray] = []
 
-        for out, decode in zip(fetched, (d for _, d in pending)):
+        tables = []  # (table, decode): a sharded dispatch gave one a shard
+        for out, (_dev_out, decode) in zip(fetched, pending):
+            if decode.shards > 1:
+                tables += [(jax.tree_util.tree_map(lambda x, s=s: x[s], out), decode)
+                           for s in range(decode.shards)]
+            else:
+                tables.append((out, decode))
+        for out, decode in tables:
             mm = np.asarray(out["mm"])
             rows = mm[:, 0]
             present = np.flatnonzero(rows > 0)
@@ -1332,6 +1392,7 @@ class _Decode:
         self.host_firsts = host_firsts  # np first-occurrence row per group
         self.pperm = pperm              # np bucket-long row permutation
         self.row_offset = 0.0
+        self.shards = 1                 # tables a dispatch's result holds
 
     @property
     def permuted(self) -> bool:

@@ -1,35 +1,31 @@
-"""In-mesh SPMD device stages: the hot device paths sharded across local chips.
+"""The mesh join tier: star joins sharded across the local chips.
 
-Streaming counterparts of ops/stage.py (FilterAggStage) and
-ops/grouped_stage.py (GroupedAggStage) that execute each dispatch as ONE jit
-program spanning every device of a local mesh (parallel/distributed.py
-kernels): rows are data-parallel sharded along the 'dp' axis, elementwise +
-local-reduce work runs per shard, and the cross-shard exchange is a single ICI
-collective (psum for ungrouped partials, an all_gather table merge for the
-exact sharded groupby). The host shuffle stays reserved for cross-host
-exchange — this is the two-tier design of SURVEY §7.
+The filter-aggregate and grouped-aggregate stages have no classes here: a
+`FilterAggRun` or `GroupedAggRun` started with ``mesh_devices`` > 1
+(ops/stage.py, ops/grouped_stage.py) shards each batch's f32 planes,
+validity, dictionary code planes and row mask over the 'dp' axis of a mesh of
+local devices and runs the single chip's own program on every shard
+(``stage.over_shards``): predicate, aggregate children and group codes are
+evaluated on the device inside the program's loop, nothing row-wide happens
+on the host, and no collective runs. Each shard returns its partial table
+(q1: [8, 11]); finalize fetches them in its one device_get and combines them
+in f64 on the host exactly as it combines the partial tables of successive
+batches, the first-row index carrying each shard's row offset so the groups'
+order is the single chip's. A mesh of one device is a chip.
 
-Contract parity is the point: both stage families expose the same
-``start_run() / feed_batch() / finalize()`` shape as their single-chip
-siblings, so the executor's adaptive morsel stream and DispatchCoalescer feed
-them super-batches with NO whole-input materialization (this replaces the r2
-``_exec_mesh_grouped`` experiment, which gathered the entire input via
-``_concat_parts(list(stream))`` before touching the mesh). Feeds only
-*dispatch* (async); every per-batch result stays on device until finalize's
-single device_get — the d2h round trip is paid once per run, mesh or not.
+What is left in this module is the join tier (MeshJoin*): fact morsels
+row-sharded over the mesh, dim planes replicated as resident HBM slots, the
+probe a local gather, the cross-shard reduce one ICI collective
+(parallel/distributed.py kernels), behind the same ``feed_batch() /
+finalize()`` contract. Its planes keep their native dtypes (f64 floats, int64
+sums): no cell of the benchmark runs it (ROADMAP D1).
 
 Residency: sharded column planes go through ``Series.to_device_cached(mesh=)``
-so repeat queries hit 8x-aggregate-HBM resident shards with zero re-upload,
-and they participate in the executor's pin scopes like any single-chip plane.
-
-Exactness: int64 sums ride jax x64 end to end (upload preserves dtype, the
-segment/psum reduces accumulate in int64 — the PR-2 quantization lesson);
-float work stays f64 on this path, trading the single-chip f32 fast path for
-bit-parity with the host across all three tiers.
+so repeat queries hit resident shards with zero re-upload, and they
+participate in the executor's pin scopes like any single-chip plane.
 
 Zero-overhead contract: nothing imports this module unless the executor's
-tier gate actually selects the mesh (mesh off => no mesh imports, no mesh
-allocations).
+join tier gate actually selects the mesh.
 """
 
 from __future__ import annotations
@@ -44,61 +40,22 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..expressions.expressions import AggExpr, Alias, ColumnRef, Expression
+from ..expressions.expressions import AggExpr, Alias, Expression
 from ..observability.metrics import registry
 from ..observability.runtime_stats import profile_span
-from ..schema import Schema
 from . import counters
-from .grouped_stage import DeviceFallback, _pad_groups, resolve_key_series
-from .stage import _combine_partials, _decompose_agg, pad_bucket
-from ..parallel.distributed import (default_mesh, sharded_filter_agg_step,
-                                    sharded_gather_step, sharded_groupby_step,
+from .grouped_stage import DeviceFallback, _pad_groups
+from .stage import (MESH_AXIS as _MESH_AXIS, _combine_partials,
+                    _decompose_agg, mesh_row_mask, mesh_total, pad_bucket,
+                    shard_rows as _shard_np)
+from ..parallel.distributed import (default_mesh, sharded_gather_step,
+                                    sharded_groupby_step,
                                     sharded_join_agg_step,
                                     sharded_join_grouped_stage_step,
                                     sharded_join_ungrouped_stage_step)
 
-_MESH_AXIS = "dp"
-
-
-def mesh_total(n: int, n_devices: int) -> int:
-    """Global padded row count for an n-row batch sharded over n_devices:
-    each shard pads to a power-of-two bucket (jit cache stays O(log rows))."""
-    per = pad_bucket(max((n + n_devices - 1) // n_devices, 1))
-    return per * n_devices
-
-
-_ROW_MASK_CACHE: Dict[tuple, jax.Array] = {}
 # concurrent serving queries share this module's caches (PR 8 discipline)
 _CACHE_LOCK = threading.Lock()
-
-
-def mesh_row_mask(mesh, n: int, total: int) -> jax.Array:
-    """Row-sharded bool[total] marking the first n rows real (cached — the
-    mask depends only on (n, total, mesh size), and re-uploading it per
-    dispatch would ship `total` bytes for nothing)."""
-    key = (n, total, int(mesh.shape[_MESH_AXIS]))
-    with _CACHE_LOCK:
-        cached = _ROW_MASK_CACHE.get(key)
-    if cached is None:
-        m = np.zeros(total, dtype=bool)
-        m[:n] = True
-        cached = jax.device_put(m, NamedSharding(mesh, P(_MESH_AXIS)))
-        with _CACHE_LOCK:
-            _ROW_MASK_CACHE[key] = cached
-            while len(_ROW_MASK_CACHE) > 64:
-                _ROW_MASK_CACHE.pop(next(iter(_ROW_MASK_CACHE)))
-    return cached
-
-
-def _shard_np(mesh, arr: np.ndarray, total: int) -> jax.Array:
-    """Row-shard one host array over the mesh (padded with zeros to total),
-    with h2d attribution like Series.to_device."""
-    if len(arr) < total:
-        pad_shape = (total - len(arr),) + arr.shape[1:]
-        arr = np.concatenate([arr, np.zeros(pad_shape, dtype=arr.dtype)])
-    registry().inc("hbm_h2d_bytes", int(arr.nbytes))
-    return jax.device_put(arr, NamedSharding(mesh, P(_MESH_AXIS)))
-
 
 def _replicate_np(mesh, arr: np.ndarray) -> jax.Array:
     """Broadcast one host array to every device (replicated dim planes for
@@ -110,246 +67,6 @@ def _replicate_np(mesh, arr: np.ndarray) -> jax.Array:
 def _note_dispatch(n_devices: int) -> None:
     counters.bump("mesh_dispatches")
     registry().set_gauge("mesh_devices_used", float(n_devices))
-
-
-# ---- ungrouped: filter + aggregate ---------------------------------------------------
-
-
-class MeshFilterAggStage:
-    """Compiled mesh filter→ungrouped-agg program (immutable + shareable,
-    like FilterAggStage): predicate and agg children evaluate on device per
-    shard, reductions lower to per-shard partials + one psum over ICI."""
-
-    def __init__(self, schema: Schema, predicate: Optional[Expression],
-                 aggs: Sequence[Tuple[str, AggExpr]], n_devices: int):
-        self.schema = schema
-        self.predicate = predicate
-        self.aggs = list(aggs)
-        self.n_devices = int(n_devices)
-        self._step = None
-        cols: List[str] = []
-        exprs: List[Expression] = [a.child for _, a in self.aggs]
-        if predicate is not None:
-            exprs.append(predicate)
-        for e in exprs:
-            for c in e.referenced_columns():
-                if c not in cols:
-                    cols.append(c)
-        self._input_cols = cols
-
-    def start_run(self) -> "MeshFilterAggRun":
-        return MeshFilterAggRun(self)
-
-    def _step_for(self, mesh):
-        if self._step is None:
-            self._step = sharded_filter_agg_step(
-                mesh, self.schema, self.predicate, self.aggs)
-        return self._step
-
-
-class MeshFilterAggRun:
-    """Per-run accumulator: dispatches stay async, partials stay replicated
-    on device; finalize fetches them all in one device_get and combines on
-    host exactly like the single-chip FilterAggRun."""
-
-    def __init__(self, stage: MeshFilterAggStage):
-        self.stage = stage
-        self._pending: List[Dict] = []
-
-    def feed_batch(self, batch) -> None:
-        n = batch.num_rows
-        if n == 0:
-            return
-        stage = self.stage
-        mesh = default_mesh(stage.n_devices)
-        total = mesh_total(n, stage.n_devices)
-        with profile_span("device.mesh_h2d", "device", rows=n, total=total,
-                          devices=stage.n_devices):
-            dcols = {name: batch.get_column(name).to_device_cached(
-                         total, f32=False, mesh=mesh)
-                     for name in stage._input_cols}
-        step = stage._step_for(mesh)
-        with profile_span("device.mesh_dispatch", "device",
-                          op="mesh_filter_agg", rows=n,
-                          devices=stage.n_devices):
-            out = step(dcols, mesh_row_mask(mesh, n, total))
-        _note_dispatch(stage.n_devices)
-        self._pending.append(out)
-
-    def finalize(self) -> Dict[str, Optional[float]]:
-        pending, self._pending = self._pending, []
-        with profile_span("device.mesh_d2h", "device", op="mesh_filter_agg",
-                          batches=len(pending)):
-            fetched = [
-                {k: (v[0].item(), bool(v[1])) for k, v in res.items()}
-                for res in jax.device_get(pending)  # one round trip
-            ]
-        out = {}
-        for name, agg in self.stage.aggs:
-            if not fetched:
-                out[name] = 0 if agg.op == "count" else None
-            else:
-                out[name] = _combine_partials(agg.op, fetched, name)
-        counters.bump("device_stage_runs")
-        return out
-
-
-# ---- grouped -------------------------------------------------------------------------
-
-
-class MeshGroupedStage:
-    """Compiled mesh filter→grouped-agg program family.
-
-    Group keys factorize per batch on the host (any dtype; nulls are their
-    own group, preserving host semantics) into dense int64 codes; the EXACT
-    sharded groupby (per-shard sort/unique + segment-reduce, one all_gather
-    table merge over ICI) reduces the value planes. The optional predicate is
-    applied host-side per morsel — bit-identical to the host filter by
-    construction. Aggregates decompose into kernel partials (mean -> sum +
-    count) so per-batch group tables merge exactly across the stream on
-    finalize.
-    """
-
-    def __init__(self, schema: Schema, predicate: Optional[Expression],
-                 groupby: Sequence[Expression],
-                 aggs: Sequence[Tuple[str, AggExpr]], n_devices: int,
-                 initial_capacity: int = 16):
-        self.schema = schema
-        self.predicate = predicate
-        self.groupby = list(groupby)
-        self.aggs = list(aggs)
-        self.n_devices = int(n_devices)
-        self.initial_capacity = max(int(initial_capacity), 16)
-        # kernel column layout: one sharded value plane per PARTIAL op
-        self._kernel_ops: List[str] = []
-        self._agg_slots: List[List[Tuple[str, int]]] = []
-        for _name, agg in self.aggs:
-            slots = []
-            for partial in _decompose_agg(agg.op):
-                slots.append((partial, len(self._kernel_ops)))
-                self._kernel_ops.append(partial)
-            self._agg_slots.append(slots)
-
-    def start_run(self) -> "MeshGroupedRun":
-        return MeshGroupedRun(self)
-
-
-class MeshGroupedRun:
-    """Per-run accumulator for MeshGroupedStage.
-
-    Group-table capacity is run-wide and exact: the host factorize knows each
-    batch's true group count before dispatch, so a batch whose groups exceed
-    the current capacity grows it (counters.mesh_capacity_growths — a
-    recompile at the new static shape, the streaming analogue of
-    groupby_host's overflow retry) instead of ever overflowing on device; the
-    kernel's overflow flag is still checked at finalize as a hard invariant.
-    """
-
-    def __init__(self, stage: MeshGroupedStage):
-        self.stage = stage
-        self._cap = _pad_groups(stage.initial_capacity)
-        # (device_out, key_rows) per fed batch; fetched once at finalize
-        self._pending: List[Tuple[tuple, list]] = []
-
-    def feed_batch(self, batch) -> None:
-        stage = self.stage
-        if batch.num_rows == 0:
-            return
-        if stage.predicate is not None:
-            batch = _host_filter_batch(batch, stage.predicate)
-            if batch.num_rows == 0:
-                return
-        n = batch.num_rows
-        mesh = default_mesh(stage.n_devices)
-        total = mesh_total(n, stage.n_devices)
-
-        key_series = resolve_key_series(batch, stage.groupby, n)
-        codes, num_groups, key_rows = _batch_group_codes(key_series, stage.groupby, n)
-        need = num_groups + 1  # one slot spare for the sentinel
-        while self._cap < need:
-            self._cap <<= 1
-            counters.bump("mesh_capacity_growths")
-
-        with profile_span("device.mesh_h2d", "device", rows=n, total=total,
-                          devices=stage.n_devices):
-            dcodes = _cached_code_plane(key_series, stage.groupby, codes, n,
-                                        total, mesh)
-            row_mask = mesh_row_mask(mesh, n, total)
-            flat: List[jax.Array] = []
-            for (_name, agg), slots in zip(stage.aggs, stage._agg_slots):
-                dv, dm = _value_planes(batch, agg, n, total, mesh, row_mask)
-                for _partial, _idx in slots:
-                    flat += [dv, dm]
-
-        step = sharded_groupby_step(mesh, stage._kernel_ops, self._cap)
-        with profile_span("device.mesh_dispatch", "device",
-                          op="mesh_grouped_agg", rows=n,
-                          groups_cap=self._cap, devices=stage.n_devices):
-            out = step(dcodes, row_mask, *flat)
-        _note_dispatch(stage.n_devices)
-        self._pending.append((out, key_rows))
-
-    def finalize(self):
-        """Returns (key_rows, agg_results) in first-occurrence stream order —
-        the same contract as GroupedAggRun.finalize, so the executor's
-        _grouped_output assembles both paths identically."""
-        stage = self.stage
-        pending, self._pending = self._pending, []
-        if not pending:
-            counters.bump("device_stage_runs")
-            counters.bump("mesh_grouped_runs")
-            return [], [(np.empty(0), np.empty(0, dtype=bool))
-                        for _ in stage.aggs]
-        with profile_span("device.mesh_d2h", "device", op="mesh_grouped_agg",
-                          batches=len(pending)):
-            fetched = jax.device_get([out for out, _ in pending])
-
-        key_slot: Dict[tuple, int] = {}
-        key_order: List[tuple] = []
-        # per kernel col: slot -> (value, ok)
-        acc: List[Dict[int, tuple]] = [{} for _ in stage._kernel_ops]
-        for (gk, gv, overflow, results), (_out, key_rows) in zip(
-                fetched, pending):
-            if bool(np.asarray(overflow)):
-                raise DeviceFallback(
-                    "mesh group table overflow despite exact host capacity")
-            gk = np.asarray(gk)
-            present = np.flatnonzero(np.asarray(gv))
-            for local in present:  # gk ascending == dense-code == first-seen
-                key = key_rows[int(gk[local])]
-                slot = key_slot.get(key)
-                if slot is None:
-                    slot = len(key_order)
-                    key_slot[key] = slot
-                    key_order.append(key)
-                for j, op in enumerate(stage._kernel_ops):
-                    val = np.asarray(results[j][0])[local]
-                    ok = bool(np.asarray(results[j][1])[local])
-                    cur = acc[j].get(slot)
-                    if cur is None:
-                        acc[j][slot] = (val, ok)
-                    else:
-                        acc[j][slot] = _merge_partial(op, cur, (val, ok))
-
-        g = len(key_order)
-        out_results = []
-        for (_name, agg), slots in zip(stage.aggs, stage._agg_slots):
-            op = agg.op
-            if op == "mean":
-                sums = _column(acc[slots[0][1]], g)
-                cnts = _column(acc[slots[1][1]], g)
-                cnt_v = np.maximum(cnts[0].astype(np.float64), 1.0)
-                vals = sums[0].astype(np.float64) / cnt_v
-                valid = cnts[0].astype(np.int64) > 0
-                out_results.append((vals, valid))
-            else:
-                vals, valid = _column(acc[slots[0][1]], g)
-                if op == "count":
-                    valid = np.ones(g, dtype=bool)
-                out_results.append((vals, valid))
-        counters.bump("device_stage_runs")
-        counters.bump("mesh_grouped_runs")
-        return key_order, out_results
 
 
 def _merge_partial(op: str, a: tuple, b: tuple) -> tuple:
@@ -377,159 +94,6 @@ def _column(slot_map: Dict[int, tuple], g: int) -> Tuple[np.ndarray, np.ndarray]
     valid = np.array([slot_map.get(i, (0, False))[1] for i in range(g)],
                      dtype=bool)
     return np.asarray(vals), valid
-
-
-def _host_filter_batch(batch, predicate: Expression):
-    """Host predicate over one RecordBatch (exact host filter semantics)."""
-    from ..expressions.eval import eval_expression
-
-    mask = eval_expression(batch, predicate)
-    if len(mask) == 1 and batch.num_rows != 1:
-        val = mask.to_pylist()[0]
-        return batch if val else batch.head(0)
-    return batch.filter_by_mask(mask)
-
-
-def _batch_group_codes(key_series, groupby, n: int):
-    """Dense first-occurrence group codes + key tuples for one batch's keys,
-    cached on the FIRST key Series (long-lived — column pruning and
-    projection rebuild the RecordBatch every run, but the underlying stored
-    Series survive, so repeat queries over a resident table factorize once)."""
-    from ..device.residency import identity_token
-
-    gb_key = (("__mesh_group_codes__",) + tuple(str(e) for e in groupby)
-              + tuple(identity_token(s) for s in key_series) + (n,))
-    anchor = key_series[0]
-    cache = getattr(anchor, "_mesh_group_cache", None)
-    if cache is None:
-        cache = {}
-        try:
-            object.__setattr__(anchor, "_mesh_group_cache", cache)
-        except AttributeError:
-            pass  # non-settable anchor: degrade to per-call factorize
-    if gb_key in cache:
-        group_ids, num_groups, key_rows = cache[gb_key]
-    else:
-        from ..core.kernels.groupby import make_groups
-
-        first_idx, group_ids, _ = make_groups(key_series)
-        num_groups = len(first_idx)
-        key_rows = list(zip(*[s.take(first_idx).to_pylist()
-                              for s in key_series])) if num_groups else []
-        cache[gb_key] = (group_ids, num_groups, key_rows)
-        if len(cache) > 8:
-            cache.pop(next(iter(cache)))
-    return group_ids.astype(np.int64, copy=False), num_groups, key_rows
-
-
-def _cached_code_plane(key_series, groupby, codes: np.ndarray, n: int,
-                      total: int, mesh) -> jax.Array:
-    """Row-sharded int64 code plane, registered in the residency manager
-    anchored on the first key Series with the remaining key Series as
-    identity deps — a repeat query over a resident table re-shards nothing
-    (losing the plane re-runs the host factorize: rebuild_rows prices it)."""
-    from ..device.residency import manager
-
-    key = ("meshcodes", tuple(str(e) for e in groupby), n, total,
-           int(mesh.shape[_MESH_AXIS]))
-
-    def build():
-        padded = np.zeros(total, dtype=np.int64)
-        padded[:n] = codes
-        registry().inc("hbm_h2d_bytes", int(padded.nbytes))
-        return jax.device_put(padded, NamedSharding(mesh, P(_MESH_AXIS)))
-
-    return manager().get_or_build(key_series[0], key, tuple(key_series[1:]),
-                                  build, rebuild_rows=n)
-
-
-def _value_planes(batch, agg: AggExpr, n: int, total: int, mesh, row_mask):
-    """Sharded (values, valid) planes for one aggregate's child expression.
-
-    Bare columns ride Series.to_device_cached(mesh=...) — repeat queries hit
-    resident shards; computed expressions evaluate host-side per batch and
-    upload fresh (no long-lived anchor to cache on). count(mode=all) swaps
-    the validity plane for the row mask so nulls count but padding never
-    does, matching host count semantics.
-    """
-    from ..expressions.eval import eval_expression, _broadcast
-
-    count_all = agg.op == "count" and agg.params.get("mode", "valid") == "all"
-    node = agg.child
-    while isinstance(node, Alias):
-        node = node.child
-    if isinstance(node, ColumnRef):
-        s = batch.get_column(node._name)
-    else:
-        s = eval_expression(batch, agg.child)
-    if len(s) == 1 and n != 1:
-        s = _broadcast(s, n)
-    if isinstance(node, ColumnRef):
-        dv, dm = s.to_device_cached(total, f32=False, mesh=mesh)
-    else:
-        vals = s.to_numpy()
-        if not (np.issubdtype(vals.dtype, np.number)
-                or vals.dtype == np.bool_):
-            raise DeviceFallback(
-                f"mesh grouped stage: non-numeric value dtype {vals.dtype}")
-        dv = _shard_np(mesh, vals, total)
-        dm = _shard_np(mesh, s.validity_numpy(), total)
-    if count_all:
-        dm = row_mask
-    return dv, dm
-
-
-# ---- stage caches --------------------------------------------------------------------
-
-_FILTER_STAGE_CACHE: Dict[tuple, MeshFilterAggStage] = {}
-_GROUPED_STAGE_CACHE: Dict[tuple, MeshGroupedStage] = {}
-
-
-def try_build_mesh_filter_agg_stage(schema: Schema,
-                                    predicate: Optional[Expression],
-                                    agg_exprs: Sequence[Expression],
-                                    n_devices: int) -> Optional[MeshFilterAggStage]:
-    """Mesh ungrouped stage if every expression qualifies (same envelope as
-    the single-chip FilterAggStage — the planner already gated capture)."""
-    from .stage import stage_cache_key, try_build_filter_agg_stage
-
-    key = stage_cache_key(schema, predicate, agg_exprs) + (int(n_devices),)
-    if key in _FILTER_STAGE_CACHE:
-        return _FILTER_STAGE_CACHE[key]
-    single = try_build_filter_agg_stage(schema, predicate, agg_exprs)
-    if single is None:
-        return None
-    stage = MeshFilterAggStage(schema, predicate, single.aggs, n_devices)
-    with _CACHE_LOCK:
-        _FILTER_STAGE_CACHE[key] = stage
-    return stage
-
-
-def try_build_mesh_grouped_agg_stage(schema: Schema,
-                                     predicate: Optional[Expression],
-                                     groupby: Sequence[Expression],
-                                     agg_exprs: Sequence[Expression],
-                                     n_devices: int,
-                                     initial_capacity: int = 16
-                                     ) -> Optional[MeshGroupedStage]:
-    """Mesh grouped stage if the aggs qualify (keys are unconstrained — they
-    factorize on host). Cached by structure + mesh width like every stage."""
-    from .grouped_stage import try_build_grouped_agg_stage
-    from .stage import stage_cache_key
-
-    key = stage_cache_key(schema, predicate,
-                          list(groupby) + list(agg_exprs)) \
-        + (int(n_devices), int(initial_capacity))
-    if key in _GROUPED_STAGE_CACHE:
-        return _GROUPED_STAGE_CACHE[key]
-    single = try_build_grouped_agg_stage(schema, predicate, groupby, agg_exprs)
-    if single is None:
-        return None
-    stage = MeshGroupedStage(schema, predicate, single.groupby, single.aggs,
-                             n_devices, initial_capacity=initial_capacity)
-    with _CACHE_LOCK:
-        _GROUPED_STAGE_CACHE[key] = stage
-    return stage
 
 
 # ---- sharded join fact feed ----------------------------------------------------------

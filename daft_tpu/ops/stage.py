@@ -46,30 +46,111 @@ def pad_bucket(n: int) -> int:
     return b
 
 
-_ROW_MASK_CACHE: Dict[Tuple[int, int], object] = {}
+# the axis a mesh of local devices shards rows over (parallel/distributed.py's)
+MESH_AXIS = "dp"
+
+_ROW_MASK_CACHE: Dict[Tuple[int, int, int], object] = {}
 # concurrent serving queries share this module's caches (PR 8 discipline)
 _CACHE_LOCK = threading.Lock()
 
 
-def device_row_mask(n: int, bucket: int):
-    """bool[bucket] with the first n rows set, cached on device.
+def _row_sharded(mesh, host: np.ndarray):
+    """A host array placed row-sharded over `mesh`."""
+    from jax.sharding import NamedSharding, PartitionSpec
 
-    The mask depends only on (n, bucket); without the cache every dispatch
-    re-uploads bucket bytes (8MB at bucket=8M).
+    return jax.device_put(host, NamedSharding(mesh, PartitionSpec(MESH_AXIS)))
+
+
+def device_row_mask(n: int, bucket: int, mesh=None):
+    """bool[bucket] with the first n rows set, cached on device; with `mesh`,
+    row-sharded over it.
+
+    The mask depends only on (n, bucket, mesh size); without the cache every
+    dispatch re-uploads bucket bytes (8MB at bucket=8M).
     """
-    key = (n, bucket)
+    key = (n, bucket, 1 if mesh is None else int(mesh.shape[MESH_AXIS]))
     with _CACHE_LOCK:
         cached = _ROW_MASK_CACHE.get(key)
     if cached is not None:
         return cached
     m = np.zeros(bucket, dtype=bool)
     m[:n] = True
-    dev_mask = jnp.asarray(m)  # h2d upload stays outside the lock
+    # h2d upload stays outside the lock
+    dev_mask = jnp.asarray(m) if mesh is None else _row_sharded(mesh, m)
     with _CACHE_LOCK:
         _ROW_MASK_CACHE[key] = dev_mask
         while len(_ROW_MASK_CACHE) > 64:
             _ROW_MASK_CACHE.pop(next(iter(_ROW_MASK_CACHE)))
     return dev_mask
+
+
+def mesh_row_mask(mesh, n: int, total: int):
+    """device_row_mask over a mesh, under the name and argument order the
+    mesh join tier and the repartition step call it by."""
+    return device_row_mask(n, total, mesh)
+
+
+def shard_rows(mesh, arr: np.ndarray, total: int):
+    """Row-shard one host array over the mesh (padded with zeros to total),
+    with h2d attribution like Series.to_device."""
+    from ..observability.metrics import registry
+
+    if len(arr) < total:
+        pad_shape = (total - len(arr),) + arr.shape[1:]
+        arr = np.concatenate([arr, np.zeros(pad_shape, dtype=arr.dtype)])
+    registry().inc("hbm_h2d_bytes", int(arr.nbytes))
+    return _row_sharded(mesh, arr)
+
+
+def mesh_total(n: int, n_devices: int) -> int:
+    """Global padded row count for an n-row batch sharded over n_devices:
+    each shard pads to a power-of-two bucket (jit cache stays O(log rows))."""
+    per = pad_bucket(max((n + n_devices - 1) // n_devices, 1))
+    return per * n_devices
+
+
+def local_mesh(n_devices: int):
+    """The mesh of the first `n_devices` local devices a stage run shards its
+    rows over, or None for one device: a mesh of one device is a chip."""
+    if n_devices <= 1:
+        return None
+    from ..parallel.distributed import default_mesh
+
+    return default_mesh(n_devices, MESH_AXIS)
+
+
+def over_shards(fn: Callable, mesh, replicated_tail: int = 0) -> Callable:
+    """`fn`, a stage program written for one chip's rows, run by every device
+    of `mesh` on its own shard of the row-sharded arguments (the last
+    `replicated_tail` arguments go to every shard whole). No collective: each
+    output comes back with a leading axis of one entry a shard, [n_devices,
+    ...], and the run's finalize combines the shards' partials on the host
+    exactly as it combines the partials of successive batches."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def on_shard(*args):
+        return jax.tree_util.tree_map(lambda x: x[None], fn(*args))
+
+    def program(*args):
+        n_rows = len(args) - replicated_tail
+        # check_vma off: a scan's carry starts from constants and ends
+        # depending on the shard
+        return shard_map(on_shard, mesh=mesh,
+                         in_specs=(P(MESH_AXIS),) * n_rows + (P(),) * replicated_tail,
+                         out_specs=P(MESH_AXIS), check_vma=False)(*args)
+
+    return program
+
+
+def note_mesh_dispatch(n_devices: int) -> None:
+    """One dispatch that spanned `n_devices` > 1 devices."""
+    from ..observability.metrics import registry
+
+    counters.bump("device_mesh_batches")
+    counters.bump("device_mesh_shards", n_devices)
+    counters.bump("mesh_dispatches")
+    registry().set_gauge("mesh_devices_used", float(n_devices))
 
 
 def _decompose_agg(op: str) -> List[str]:
@@ -98,6 +179,17 @@ def _combine_partials(op: str, parts: List[Dict[str, Tuple[float, bool]]], name:
     return min(good) if op == "min" else max(good)
 
 
+def _shard_parts(res: Dict) -> List[Dict[str, Tuple[float, bool]]]:
+    """One fetched dispatch result as the parts _combine_partials takes: one,
+    or (a sharded dispatch, whose values carry a leading shard axis) one a
+    shard."""
+    first = next(iter(res.values()))[0]
+    if np.ndim(first) == 0:
+        return [{k: (v[0].item(), bool(v[1])) for k, v in res.items()}]
+    return [{k: (v[0][s].item(), bool(v[1][s])) for k, v in res.items()}
+            for s in range(len(first))]
+
+
 class FilterAggStage:
     """Compiled scan→filter→ungrouped-agg program (the TPC-H Q6 shape).
 
@@ -111,7 +203,7 @@ class FilterAggStage:
         self.schema = schema
         self.predicate = predicate
         self.aggs = list(aggs)
-        self._jitted: Dict[int, Callable] = {}
+        self._jitted: Dict[Tuple[int, int], Callable] = {}
         self._input_cols = self._referenced_columns()
         # float min/max must be EXACT (downstream equality joins against the
         # aggregate — TPC-H Q15 — would otherwise never match): such stages run
@@ -131,10 +223,14 @@ class FilterAggStage:
                     cols.append(c)
         return cols
 
-    def start_run(self) -> "FilterAggRun":
-        return FilterAggRun(self)
+    def start_run(self, mesh_devices: int = 1) -> "FilterAggRun":
+        """A fresh accumulator; with `mesh_devices` > 1 its dispatches shard
+        each batch's rows over that many local devices."""
+        return FilterAggRun(self, mesh_devices)
 
-    def _build(self) -> Callable:
+    def _build(self, mesh=None) -> Callable:
+        """The program of one chip's rows; over `mesh`, every device runs it
+        on its shard and the partials come back one a shard (over_shards)."""
         schema = self.schema
         fdt = jnp.float64 if self._use_f64 else jnp.float32
         pred_fn = (dev.build_device_expr(self.predicate, schema, float_dtype=fdt)
@@ -162,15 +258,16 @@ class FilterAggStage:
                     out[(name, partial_op)] = (val, ok)
             return out
 
-        return jax.jit(stage)
+        return jax.jit(stage if mesh is None else over_shards(stage, mesh))
 
-    def _jit_for(self, bucket: int) -> Callable:
+    def _jit_for(self, bucket: int, mesh_devices: int = 1) -> Callable:
         # one program serves every bucket (shapes differ per call; jit retraces
         # per shape internally) — keyed anyway so future bucket-specialized
         # programs stay cheap to add
-        if bucket not in self._jitted:
-            self._jitted[bucket] = self._build()
-        return self._jitted[bucket]
+        key = (bucket, mesh_devices)
+        if key not in self._jitted:
+            self._jitted[key] = self._build(local_mesh(mesh_devices))
+        return self._jitted[key]
 
 
 class FilterAggRun:
@@ -181,19 +278,26 @@ class FilterAggRun:
     trip is paid once per run, not once per batch.
     """
 
-    def __init__(self, stage: FilterAggStage):
+    def __init__(self, stage: FilterAggStage, mesh_devices: int = 1):
         self.stage = stage
+        self.mesh_devices = max(int(mesh_devices), 1)
         self._device_partials: List[Dict] = []
 
-    def _run(self, dcols: Dict[str, dev.DCol], n: int, bucket: int) -> None:
+    def _run(self, dcols: Dict[str, dev.DCol], n: int, bucket: int,
+             mesh=None) -> None:
+        """One dispatch over planes of `bucket` rows: on the default device,
+        or with `mesh` (the planes row-sharded over it) on every device of it."""
+        ndev = self.mesh_devices if mesh is not None else 1
         with profile_span("device.dispatch", "device", op="filter_agg",
                           rows=n, bucket=bucket):
-            prog = self.stage._jit_for(bucket)
-            mask = device_row_mask(n, bucket)
+            prog = self.stage._jit_for(bucket, ndev)
+            mask = device_row_mask(n, bucket, mesh)
             with profile_span("device.launch", "device", op="filter_agg",
-                              bucket=bucket):
+                              bucket=bucket, devices=ndev):
                 res = prog(dcols, mask)
         counters.bump("device_stage_batches")
+        if ndev > 1:
+            note_mesh_dispatch(ndev)
         self._device_partials.append(res)  # stays on device; fetched at finalize
 
     def feed(self, columns: Dict[str, Tuple[np.ndarray, np.ndarray]], n: int) -> None:
@@ -214,12 +318,14 @@ class FilterAggRun:
     def feed_batch(self, batch) -> None:
         """Feed a host RecordBatch (referenced columns go to device, cached)."""
         n = batch.num_rows
-        bucket = pad_bucket(n)
+        mesh = local_mesh(self.mesh_devices)
+        bucket = pad_bucket(n) if mesh is None else mesh_total(n, self.mesh_devices)
         f32 = not self.stage._use_f64
         with profile_span("device.h2d", "device", rows=n, bucket=bucket):
-            dcols = {name: batch.get_column(name).to_device_cached(bucket, f32=f32)
+            dcols = {name: batch.get_column(name).to_device_cached(
+                         bucket, f32=f32, mesh=mesh)
                      for name in self.stage._input_cols}
-        self._run(dcols, n, bucket)
+        self._run(dcols, n, bucket, mesh)
 
     def finalize(self) -> Dict[str, Optional[float]]:
         with profile_span("stage.finalize", "host", op="filter_agg", groups=1):
@@ -228,10 +334,8 @@ class FilterAggRun:
     def _finalize(self) -> Dict[str, Optional[float]]:
         with profile_span("device.d2h", "device", op="filter_agg",
                           batches=len(self._device_partials)):
-            fetched = [
-                {k: (v[0].item(), bool(v[1])) for k, v in res.items()}
-                for res in jax.device_get(self._device_partials)  # one round trip
-            ]
+            fetched = [part for res in jax.device_get(self._device_partials)  # one round trip
+                       for part in _shard_parts(res)]
         out = {}
         for name, agg in self.stage.aggs:
             if not fetched:

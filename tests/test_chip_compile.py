@@ -158,3 +158,76 @@ def test_mesh_extreme_collective_lowers(topo, dtype):
 
     step = jax.jit(dist._shard_map(local, mesh, (P("dp"),), (P(), P())))
     step.lower(_s(NamedSharding(mesh, P("dp")), (4 * 1024,), dtype)).compile()
+
+
+# ---- the sharded stages at tpch-sf30-4chip's shapes --------------------------------------
+# 180 M lineitem rows over four chips: a 2^26-row bucket a chip, the bucket
+# one chip pads SF10 to, so every chip runs the one-chip cell's tile shape.
+SHARD_ROWS = 1 << 26
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "collective-permute",
+               "all-to-all")
+
+
+def _lineitem_planes(stage, sharding, rows):
+    ints = {"l_shipdate", "l_suppkey"}
+    return {name: (_s(sharding, (rows,), jnp.int32 if name in ints else jnp.float32),
+                   _s(sharding, (rows,), jnp.bool_)) for name in stage._input_cols}
+
+
+def test_sharded_q1_program_is_the_single_chips_on_every_shard(topo, one_chip):
+    """q1's grouped program over the 2x2 mesh at SF30: what each chip holds
+    and runs is what the one chip holds and runs at SF10 (same arguments a
+    device, the same [16, 512, 128] tiles, no temporary as long as the
+    rows), and no collective: the shards' tables leave as they are."""
+    import test_grouped_stage_program as tg
+
+    stage = tg._q1_stage()
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    rows = NamedSharding(mesh, P("dp"))
+    total = 4 * SHARD_ROWS
+    over = stage._build(8, radices=(2, 1), mesh=mesh).lower(
+        _lineitem_planes(stage, rows, total),
+        (_s(rows, (total,), jnp.int32),) * 2, _s(rows, (total,), jnp.bool_),
+        _s(NamedSharding(mesh, P()), (), jnp.float64)).compile()
+    single = stage._build(8, radices=(2, 1)).lower(
+        _lineitem_planes(stage, one_chip, SHARD_ROWS),
+        (_s(one_chip, (SHARD_ROWS,), jnp.int32),) * 2,
+        _s(one_chip, (SHARD_ROWS,), jnp.bool_), _s(one_chip, (), jnp.float64)).compile()
+    mem, mem1 = over.memory_analysis(), single.memory_analysis()
+    assert mem.argument_size_in_bytes == mem1.argument_size_in_bytes
+    assert mem.temp_size_in_bytes <= max(mem1.temp_size_in_bytes, 1 << 20)
+    # one [cap, planes] f64 table and its [cap] companions a shard
+    assert mem.output_size_in_bytes < 64 * 1024
+    text = over.as_text()
+    assert not [c for c in COLLECTIVES if c in text]
+    assert "[1,16,512,128]" in text and "f64[67108864]" not in text \
+        and "s64[67108864]" not in text
+
+
+def test_sharded_q6_program_lowers_without_a_collective(topo):
+    import datetime
+
+    import test_grouped_stage_program as tg
+    from daft_tpu import col, lit
+    from daft_tpu.ops.stage import try_build_filter_agg_stage
+
+    def day(y, m, d):
+        return lit(datetime.date(y, m, d))
+
+    pred = ((col("l_shipdate") >= day(1994, 1, 1)) & (col("l_shipdate") < day(1995, 1, 1))
+            & (col("l_discount") >= 0.05) & (col("l_discount") <= 0.07)
+            & (col("l_quantity") < 24))
+    stage = try_build_filter_agg_stage(
+        tg._schema(), pred, [(col("l_extendedprice") * col("l_discount")).sum().alias("revenue")])
+    assert stage is not None and not stage._use_f64
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    rows = NamedSharding(mesh, P("dp"))
+    total = 4 * SHARD_ROWS
+    compiled = stage._build(mesh).lower(_lineitem_planes(stage, rows, total),
+                                        _s(rows, (total,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    # four columns' f32 planes and validity, and the row mask, a shard
+    assert mem.argument_size_in_bytes == SHARD_ROWS * (4 * 5 + 1)
+    text = compiled.as_text()
+    assert not [c for c in COLLECTIVES if c in text]
+    assert "f64[67108864]" not in text

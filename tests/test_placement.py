@@ -60,9 +60,10 @@ def test_cost_breakdown_terms_cover_every_tier():
     join = costmodel.device_join_agg_cost(cal, 100_000, 1_000_000, 3, 2, 1,
                                           0, 64, 4096, 100_000)
     assert {"rtt", "h2d", "compute", "d2h", "factorize"} <= set(join.terms)
-    mesh = costmodel.mesh_grouped_cost(cal, 1_000_000, 0, 4, 1024, 8,
-                                       factorize_rows=1_000_000)
-    assert {"mesh_dispatch", "ici", "compute", "factorize"} <= set(mesh.terms)
+    mesh = costmodel.over_mesh(costmodel.device_grouped_cost(
+        cal, 1_000_000 // 8, 0, n_mm=4, n_ext=1, n_sct=0, cap=1024,
+        factorize_rows=1_000_000), cal, 8, 1024 * 5 * 8)
+    assert {"mesh_dispatch", "combine", "compute", "factorize"} <= set(mesh.terms)
     hj = costmodel.host_join_agg_cost(cal, 100_000, 3, 2, True, False)
     assert "probe" in hj.terms
     udf = costmodel.device_udf_cost(cal, 4096, 4096 * 1024, 1e9, 4096 * 512)
