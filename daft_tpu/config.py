@@ -191,10 +191,11 @@ class ExecutionConfig:
     spill_prefetch_batches: int = field(
         default_factory=lambda: _env_int("DAFT_TPU_SPILL_PREFETCH_BATCHES", 2)
     )
-    # Streaming-scan split/merge target (io/parquet.py split planning +
+    # Streaming-scan split/merge bound (io/parquet.py split planning +
     # io/scan.py merge_small_tasks): files larger than this split into
-    # row-group-aligned tasks, runs of smaller files merge toward it — so
-    # one in-flight scan task never materializes more than ~this many bytes.
+    # row-group-aligned tasks, runs of smaller files merge toward the scan's
+    # bytes over the pool's width and never past this — so one in-flight
+    # scan task never materializes more than ~this many bytes.
     # 0 disables split/merge (one task per file, the pre-streaming planning).
     scan_split_bytes: int = field(
         default_factory=lambda: _env_int("DAFT_TPU_SCAN_SPLIT_BYTES", 128 * 1024 * 1024)

@@ -87,13 +87,13 @@ def _decide_span(decide):
 
 def _pipeline_on() -> bool:
     from ..config import execution_config
-    from ..utils.pool import compute_pool
+    from ..utils.pool import pool_width
 
     mode = execution_config().pipeline_mode
     if mode == "force":
         return True
     # on a single-core host, fan-out and stage threads are pure overhead
-    return mode == "on" and compute_pool()._max_workers > 1
+    return mode == "on" and pool_width() > 1
 
 
 def _map_op(stream: Iterator[MicroPartition], fn) -> Iterator[MicroPartition]:
@@ -137,7 +137,7 @@ def _exec_impl(node: pp.PhysicalPlan) -> Iterator[MicroPartition]:
         return
 
     if isinstance(node, pp.TaskScan):
-        from ..utils.pool import compute_pool
+        from ..utils.pool import compute_pool, pool_width
 
         remaining = node.post_limit
 
@@ -152,7 +152,7 @@ def _exec_impl(node: pp.PhysicalPlan) -> Iterator[MicroPartition]:
         if len(node.tasks) > 1 and remaining is None:
             # IO-parallel scan with a bounded in-flight window: parallelism without
             # buffering the whole dataset ahead of the consumer
-            window = compute_pool()._max_workers
+            window = pool_width()
             futures = []
             ti = 0
             while ti < len(node.tasks) or futures:
@@ -464,25 +464,27 @@ def _streaming_scan(node) -> Iterator[MicroPartition]:
     """Execute a StreamingScan: morsels yielded incrementally, never a whole
     source in host RAM.
 
-    Tasks are pre-split toward scan_split_bytes (io/parquet.py row-group
-    planning), so even the IO-parallel window holds at most
-    window x split-target bytes in flight. Backpressure is two-layered: the
-    bounded stage channel (pipeline.py — StreamingScan is a stage node)
-    limits morsels between scan and consumer, and the host memory ledger's
-    pressure signal (daft_tpu/memory) stalls the scan — boundedly, never as
-    a correctness gate — while a downstream blocking operator is at the
-    memory wall and about to spill. Attribution: scan_batches/rows/bytes,
+    Tasks are split at scan_split_bytes (io/parquet.py row-group planning)
+    and never merged past it (io/scan.py merge_small_tasks), so even the
+    IO-parallel window holds at most window x scan_split_bytes in flight.
+    Backpressure is two-layered: the bounded stage channel (pipeline.py —
+    StreamingScan is a stage node) limits morsels between scan and consumer,
+    and the host memory ledger's pressure signal (daft_tpu/memory) stalls
+    the scan — boundedly, never as a correctness gate — while a downstream
+    blocking operator is at the memory wall and about to spill. Attribution:
+    scan_tasks (the tasks the scan was given), scan_batches/rows/bytes,
     scan_backpressure_stalls + scan_stall_ms counters, and a per-task
     "scan.stream" span while the timeline profiler is active."""
     from ..memory import manager as _host_manager
     from ..observability.metrics import registry
     from ..observability.runtime_stats import (current_collector, current_spans,
                                                span_iter)
-    from ..utils.pool import compute_pool
+    from ..utils.pool import compute_pool, pool_width
 
     mgr = _host_manager()
     budgeted = mgr.limit_bytes() > 0
     reg = registry()
+    reg.inc("scan_tasks", len(node.tasks))
     c = current_collector()
     if c is not None:
         c.annotate(node, f"streaming: {len(node.tasks)} tasks")
@@ -546,7 +548,7 @@ def _streaming_scan(node) -> Iterator[MicroPartition]:
             return list(task_parts(task))
 
         recording = current_spans() is not None
-        window = compute_pool()._max_workers
+        window = pool_width()
         futures = []
         ti = 0
         while ti < len(node.tasks) or futures:

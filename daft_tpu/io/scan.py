@@ -57,10 +57,24 @@ class ScanTask:
     source_label: str = ""
 
 
-def merge_small_tasks(tasks: List[ScanTask], target_bytes: int) -> List[ScanTask]:
-    """Coalesce runs of small adjacent ScanTasks toward `target_bytes` so a
-    many-tiny-files source doesn't pay per-task scheduling/IO overhead (the
-    merge half of scan split planning; io/parquet.py owns the split half).
+# Runs of files smaller than this merge however wide the pool is: under it a
+# thread more costs more than it buys. A task that is its own future costs
+# 1.3-2.2 ms over the same files read inside a merged task (the pool hand-off
+# and its wake-ups; 64 files of 100 rows), and a thread decodes 180-560 MB of
+# file bytes a second (all 16 columns of lineitem, q1's 7), so halving a task
+# of s bytes saves s / 2r and pays that: even at 0.5-2.5 MB. PERF.md section 6
+# (PR 33) has the readings.
+MERGE_FLOOR_BYTES = 4 * 1024 * 1024
+
+
+def merge_small_tasks(tasks: List[ScanTask], target_bytes: int, window: int) -> List[ScanTask]:
+    """Coalesce runs of small adjacent ScanTasks so a many-tiny-files source
+    doesn't pay per-task scheduling/IO overhead, without narrowing the scan
+    below the `window` of tasks it is run at (the merge half of scan split
+    planning; io/parquet.py owns the split half). Runs merge toward the
+    tasks' known bytes over `window`, at least MERGE_FLOOR_BYTES and at most
+    `target_bytes` (scan_split_bytes): tasks only get smaller than under
+    `target_bytes` alone.
 
     Only tasks with a KNOWN size merge, and only while every merged member
     agrees on filters_applied (a merged task must be re-filterable as one
@@ -69,6 +83,8 @@ def merge_small_tasks(tasks: List[ScanTask], target_bytes: int) -> List[ScanTask
     sequentially, so row order matches the unmerged plan exactly."""
     if target_bytes <= 0 or len(tasks) <= 1:
         return tasks
+    known = sum(t.size_bytes for t in tasks if t.size_bytes is not None)
+    target_bytes = min(target_bytes, max(known // window, MERGE_FLOOR_BYTES))
 
     out: List[ScanTask] = []
     group: List[ScanTask] = []

@@ -334,6 +334,8 @@ MEMORY_COUNTER_NAMES = (
                                 # (after its filter), no buffer walk
     "scan_row_groups",          # parquet row groups handed to readers
     "scan_row_groups_pruned",   # row groups zone maps excluded at plan time
+    "scan_tasks",               # scan tasks streaming scans were given, after
+                                # split and merge (one scan.stream span each)
     "scan_tasks_split",         # scan tasks produced by row-group splitting
     "scan_tasks_merged",        # small scan tasks absorbed by task merging
     "scan_backpressure_stalls", # times a scan stalled on host memory pressure

@@ -71,10 +71,11 @@ class TaskScan(PhysicalPlan):
 
 
 class StreamingScan(TaskScan):
-    """Out-of-core scan: tasks arrive pre-split/merged toward
-    ``scan_split_bytes`` (row-group splits in io/parquet.py, small-file
-    merging in io/scan.py) and the executor streams morsels incrementally
-    under the host memory ledger (execution/executor.py _streaming_scan) —
+    """Out-of-core scan: tasks arrive split at ``scan_split_bytes`` (row-group
+    splits in io/parquet.py) and merged toward the scan's bytes over the
+    pool's width, never past ``scan_split_bytes`` (io/scan.py), and the
+    executor streams morsels incrementally under the host memory ledger
+    (execution/executor.py _streaming_scan) —
     a source is never materialized whole, and a fast scan paces itself
     against memory pressure from downstream spilling operators. Subclasses
     TaskScan so the distributed planner's task partitioning and every
@@ -471,8 +472,9 @@ def translate(plan: lp.LogicalPlan, config: Any = None) -> PhysicalPlan:
         target = getattr(cfg, "scan_split_bytes", 0)
         if target and len(tasks) > 1:
             from ..io.scan import merge_small_tasks
+            from ..utils.pool import pool_width
 
-            tasks = merge_small_tasks(tasks, target)
+            tasks = merge_small_tasks(tasks, target, pool_width())
         post_filter = None
         post_limit = plan.pushdowns.limit
         if plan.pushdowns.filters is not None:

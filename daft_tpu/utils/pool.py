@@ -20,6 +20,12 @@ def compute_pool() -> ThreadPoolExecutor:
     return _POOL
 
 
+def pool_width() -> int:
+    """Threads of the compute pool: the in-flight window of a streaming scan,
+    and so the width the planner keeps a scan's task list at."""
+    return compute_pool()._max_workers
+
+
 def pool_map(fn, items):
     """Map over items in the pool; falls back to serial for 0/1 items."""
     items = list(items)

@@ -117,6 +117,41 @@ def test_decode_spans_hang_under_their_tasks_stream_in_the_querys_tree(tmp_path,
     assert by_id[tasks["args"]["parent"]]["name"] == "plan.translate"
 
 
+@pytest.mark.parametrize("config, tasks", [
+    (dict(pipeline_mode="off", scan_split_bytes=0), 4),
+    (dict(pipeline_mode="force", scan_split_bytes=0), 4),
+    (dict(pipeline_mode="force"), 1),  # four files under the merge floor: one task
+])
+def test_scan_tasks_counts_the_tasks_a_streaming_scan_was_given(tmp_path, config, tasks):
+    """Counted once a streaming scan, on both arms: as many as the query has
+    `scan.stream` spans, pre-declared, so `QueryEnd.metrics` carries it."""
+    from daft_tpu.observability import Subscriber, attach_subscriber, detach_subscriber
+    from daft_tpu.observability.metrics import DECLARED_COUNTERS
+
+    assert "scan_tasks" in DECLARED_COUNTERS
+
+    class Ends(Subscriber):
+        def __init__(self):
+            self.ends = []
+
+        def on_query_end(self, e):
+            self.ends.append(e)
+
+    paths = write_files(tmp_path, 4)
+    reg = registry()
+    before = reg.get("scan_tasks")
+    sub = Ends()
+    attach_subscriber(sub)
+    try:
+        with execution_config_ctx(device_mode="off", **config):
+            _, spans = recorded(lambda: total(paths))
+    finally:
+        detach_subscriber(sub)
+    assert reg.get("scan_tasks") - before == tasks
+    assert sum(1 for s in spans if s["name"] == "scan.stream") == tasks
+    assert [e.metrics.get("scan_tasks") for e in sub.ends] == [tasks]
+
+
 def test_scan_counters_count_bytes_row_groups_and_what_zone_maps_pruned(tmp_path):
     # two row groups a file, so that a file splits by `row_groups_per_task`
     paths = write_files(tmp_path, 3, row_group_size=ROWS // 2)

@@ -122,6 +122,106 @@ def test_small_file_merge(tmp_path):
     assert out["a"] == list(range(n_files * rows))  # order preserved
 
 
+MIB = 1 << 20
+DEFAULT_SPLIT = 128 * MIB
+
+
+@pytest.mark.parametrize("sizes, window, split, expected", [
+    # files that a pool thread each can take stay a task each ...
+    pytest.param([48] * 6, 8, 128, [48] * 6, id="six-equal-files-window-8"),
+    # ... and on a narrow pool they merge in balance, up to scan_split_bytes
+    pytest.param([48] * 6, 2, 128, [96, 96, 96], id="six-equal-files-window-2"),
+    pytest.param([27] * 6, 2, 128, [81, 81], id="six-equal-files-a-share-each-window-2"),
+    # five full files and a shorter last one, as `write_table` cuts a table
+    pytest.param([27] * 5 + [19], 8, 128, [27] * 5 + [19], id="cut-table-window-8"),
+    pytest.param([27] * 5 + [19], 2, 128, [54, 54, 46], id="cut-table-window-2"),
+    pytest.param([27] * 5 + [19], 1, 128, [108, 46], id="cut-table-window-1-is-the-old-rule"),
+    # tiny files merge whatever the width: a task under the floor buys nothing
+    pytest.param([1 / 128] * 8, 8, 1024, [1 / 16], id="eight-tiny-files"),
+    pytest.param([1] * 16, 8, 128, [4, 4, 4, 4], id="floor-holds-against-the-window"),
+    # many small files: balanced groups of a window's share, none over the bound
+    pytest.param([1] * 1000, 8, 128, [125] * 8, id="thousand-files-window-8"),
+    pytest.param([1] * 1000, 2, 128, [128] * 7 + [104], id="thousand-files-window-2-bound-holds"),
+    pytest.param([10] * 100, 1, 64, [60] * 16 + [40], id="scan-split-bytes-is-the-upper-bound"),
+    pytest.param([1] * 8, 8, 0, [1] * 8, id="zero-turns-merge-off"),
+    # a file at or over the target is a task of its own and ends the run
+    pytest.param([1, 1, 200, 1, 1], 4, 128, [2, 200, 2], id="large-file-between-small"),
+    pytest.param([1, None, 1, 1], 8, 128, [1, None, 2], id="unknown-size-never-merges"),
+])
+def test_merge_plan_keeps_the_scan_as_wide_as_its_window(sizes, window, split, expected):
+    """`merge_small_tasks` as a function of (file sizes, window, scan_split_bytes),
+    sizes in MiB: the plan's task sizes, in order."""
+    from daft_tpu.io.scan import ScanTask, merge_small_tasks
+    from daft_tpu.schema import Schema
+
+    schema = Schema([])
+    tasks = [ScanTask(read=lambda k=k: iter([k]), schema=schema,
+                      size_bytes=None if mib is None else int(mib * MIB),
+                      num_rows=10, source_label=f"f{k}")
+             for k, mib in enumerate(sizes)]
+    merged = merge_small_tasks(tasks, int(split * MIB), window)
+    assert [t.size_bytes for t in merged] == \
+        [None if mib is None else int(mib * MIB) for mib in expected]
+    assert all(t.size_bytes <= split * MIB for t in merged if "merged" in t.source_label)
+    # every file once, in order
+    assert [k for t in merged for k in t.read()] == list(range(len(sizes)))
+    assert sum(t.num_rows for t in merged) == 10 * len(sizes)
+
+
+@pytest.mark.parametrize("flags", [
+    [True, False, True, False],
+    [True, True, False, False],
+])
+def test_merge_never_crosses_filters_applied(flags):
+    from daft_tpu.io.scan import ScanTask, merge_small_tasks
+    from daft_tpu.schema import Schema
+
+    schema = Schema([])
+    tasks = [ScanTask(read=lambda: iter(()), schema=schema, size_bytes=1000,
+                      filters_applied=f) for f in flags]
+    merged = merge_small_tasks(tasks, DEFAULT_SPLIT, 8)
+    runs = [f for k, f in enumerate(flags) if k == 0 or flags[k - 1] != f]
+    assert [t.filters_applied for t in merged] == runs
+
+
+@pytest.fixture
+def pool_of_eight(monkeypatch):
+    """The scan's window is the compute pool's width, which is the host's core
+    count: pin it, so the plan below is the same on every machine."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from daft_tpu.utils import pool
+
+    eight = ThreadPoolExecutor(max_workers=8, thread_name_prefix="daft-compute")
+    monkeypatch.setattr(pool, "_POOL", eight)
+    yield
+    eight.shutdown(wait=True)
+
+
+def test_a_directory_of_files_is_a_task_a_file_under_the_defaults(tmp_path, pool_of_eight):
+    """Files a thread can be kept busy with (over the merge floor, under
+    scan_split_bytes) are read a task each, in file order."""
+    from daft_tpu.io.scan import MERGE_FLOOR_BYTES
+
+    n_files, rows = 6, 64
+    for i in range(n_files):
+        t = pa.table({"k": list(range(i * rows, (i + 1) * rows)),
+                      # incompressible, so the file is as large as its rows
+                      "pad": pa.array([os.urandom(MERGE_FLOOR_BYTES // rows + 4096)
+                                       for _ in range(rows)], pa.binary())})
+        pq.write_table(t, tmp_path / f"part-{i:03d}.parquet")
+        assert MERGE_FLOOR_BYTES < os.path.getsize(tmp_path / f"part-{i:03d}.parquet") \
+            < DEFAULT_SPLIT // n_files
+    reg = registry()
+    before = {n: reg.get(n) for n in ("scan_tasks", "scan_tasks_merged")}
+    df = dt.read_parquet(str(tmp_path)).select("k")
+    assert len(_streaming_scans(_physical(df))[0].tasks) == n_files
+    out = df.to_pydict()
+    assert out["k"] == list(range(n_files * rows))
+    assert reg.get("scan_tasks") - before["scan_tasks"] == n_files
+    assert reg.get("scan_tasks_merged") - before["scan_tasks_merged"] == 0
+
+
 def test_scan_backpressure_stalls_bounded(big_file):
     """A saturated ledger makes the scan stall (counted) but NEVER deadlock:
     the wait is bounded pacing, so the query still completes exactly."""
