@@ -160,21 +160,31 @@ def _same_deps(stored: tuple, deps: tuple) -> bool:
 # ---- expression structure keys -----------------------------------------------------
 
 
+def literal_nodes(expr) -> list:
+    """The Literal nodes of `expr` in walk order: the order of
+    expr_structure's literals, and the order in which a compiled device
+    program numbers the slots it takes their values in
+    (ops/device_eval.build_device_expr)."""
+    from ..expressions.expressions import Literal
+
+    return [node for node in expr.walk() if isinstance(node, Literal)]
+
+
 def expr_structure(expr) -> Tuple[str, tuple]:
     """(skeleton, literals) for one expression: the skeleton is the repr with
     every literal masked, the literals are (dtype-repr, value) pairs in walk
     order. Two predicates differing only in literal values share a skeleton —
     the residency cache keys on the skeleton and compares the literals on
-    lookup, so varying-literal queries reuse one slot per query shape."""
+    lookup, so varying-literal queries reuse one slot per query shape; the
+    aggregate stages key their compiled programs on the skeleton and the
+    dtype reprs and take the values as arguments (ops/stage.py)."""
     from ..expressions.expressions import Literal
 
-    lits = []
-    for node in expr.walk():
-        if isinstance(node, Literal):
-            lits.append((repr(node.dtype), node.value))
+    lits = tuple((repr(node.dtype), node.value) for node in literal_nodes(expr))
+    # an expression without literals is its own skeleton
     masked = expr.transform(
-        lambda n: Literal("?") if isinstance(n, Literal) else None)
-    return repr(masked), tuple(lits)
+        lambda n: Literal("?") if isinstance(n, Literal) else None) if lits else expr
+    return repr(masked), lits
 
 
 def exprs_structure(exprs: Iterable) -> Tuple[tuple, tuple]:

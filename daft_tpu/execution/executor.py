@@ -721,7 +721,6 @@ def _exec_device_agg(node) -> MicroPartition:
     from ..core.series import Series
     from ..device.residency import manager as _residency
 
-    in_schema = node.input.schema
     if mesh_n:
         from ..observability.runtime_stats import current_collector
 
@@ -744,16 +743,16 @@ def _exec_device_agg(node) -> MicroPartition:
     from ..ops.region import node_region_ops
 
     region_ops = node_region_ops(node)
+    bound = node.bound_stage()
+    assert bound is not None, f"planner emitted {node.name()} for a non-qualifying plan"
+    stage, literals = bound
     # morsels of a file scan die with the query: their planes are uploaded
     # without a content fingerprint (residency.pin_scope)
     streamed = not _resident_source_rec(node.input)
     if grouped:
-        from ..ops.grouped_stage import DeviceFallback, try_build_grouped_agg_stage
+        from ..ops.grouped_stage import DeviceFallback
 
-        stage = try_build_grouped_agg_stage(
-            in_schema, node.predicate, node.groupby, node.aggregations)
-        assert stage is not None, "planner emitted DeviceGroupedAgg for a non-qualifying plan"
-        run = stage.start_run(mesh_devices=mesh_n)
+        run = stage.start_run(literals, mesh_devices=mesh_n)
         coal = _make_coalescer(run.feed_batch, cfg)
         feed = coal.add if coal is not None else run.feed_batch
         buffered: List[MicroPartition] = []
@@ -783,11 +782,7 @@ def _exec_device_agg(node) -> MicroPartition:
         return _grouped_output(node.schema, node.groupby, node.aggregations,
                                key_rows, results)
 
-    from ..ops.stage import try_build_filter_agg_stage
-
-    stage = try_build_filter_agg_stage(in_schema, node.predicate, node.aggregations)
-    assert stage is not None, "planner emitted DeviceFilterAgg for a non-qualifying plan"
-    run = stage.start_run(mesh_devices=mesh_n)
+    run = stage.start_run(literals, mesh_devices=mesh_n)
     coal = _make_coalescer(run.feed_batch, cfg)
     feed = coal.add if coal is not None else run.feed_batch
     fed_rows = 0
@@ -1018,7 +1013,6 @@ def _try_fused_udf_agg(node, cfg) -> Optional[MicroPartition]:
     from ..observability.runtime_stats import current_collector
     from ..ops import counters as _counters
     from ..ops.grouped_stage import DeviceFallback
-    from ..ops.stage import try_build_filter_agg_stage
 
     udf_node, rename = _unwrap_udf_agg_input(node.input)
     if udf_node is None:
@@ -1027,10 +1021,10 @@ def _try_fused_udf_agg(node, cfg) -> Optional[MicroPartition]:
     if call is None:
         return None
     internal = udf_node.udf_expr.name()
-    agg_stage = try_build_filter_agg_stage(node.input.schema, node.predicate,
-                                           node.aggregations)
-    if agg_stage is None:
+    bound = node.bound_stage()
+    if bound is None:
         return None
+    agg_stage, literals = bound
     # split the agg program's columns into the UDF output plane(s) and the
     # passthrough columns, mapping agg-visible names to UDF-input sources
     udf_plane_names = [c for c in agg_stage._input_cols
@@ -1050,7 +1044,7 @@ def _try_fused_udf_agg(node, cfg) -> Optional[MicroPartition]:
     from ..ops.region import node_region_ops
 
     udf_stage = build_device_udf_stage(call.func, call.args, internal)
-    agg_run = agg_stage.start_run()
+    agg_run = agg_stage.start_run(literals)
     in_stream = _exec(udf_node.input)
     buffered: List[MicroPartition] = []
     # the UDF plane feeds the agg program in the SAME dispatch, so the
@@ -1482,6 +1476,8 @@ def _decision_key(node, rows: int, cfg, topn: bool, layout: tuple) -> tuple:
         cfg.batch_fill_target, cfg.morsel_size_rows, layout,
         # the mesh arm reads the mesh knob: flipping it re-decides the tier
         cfg.mesh_devices,
+        # with its literals' values: _device_join_wins prices the host arm by
+        # the predicate's selectivity, which it reads from them
         repr(spec.predicate),
         tuple(repr(g) for g in spec.groupby),
         tuple(repr(a) for a in spec.aggregations),
@@ -1860,11 +1856,13 @@ def _select_mesh_tier(node, stream, grouped: bool, cfg):
         return 0, stream, None
     from ..ops.stage import pad_bucket
 
+    # the compiled stage, so the query's shape and not its literals' values:
+    # _mesh_wins prices rows, planes and residency and reads no value
+    bound = node.bound_stage()
+    if bound is None:
+        return 0, stream, None
     key = (grouped, ndev, pad_bucket(first.num_rows),
-           cfg.batch_fill_target, cfg.morsel_size_rows,
-           repr(node.predicate),
-           tuple(repr(g) for g in getattr(node, "groupby", ())),
-           tuple(repr(a) for a in node.aggregations))
+           cfg.batch_fill_target, cfg.morsel_size_rows, bound[0])
     wins = _MESH_TIER_CACHE.get(key)
     rec = None
     if wins is None:
@@ -1900,6 +1898,9 @@ def _mesh_wins(node, first: MicroPartition, grouped: bool, ndev: int):
     batch = next((b for b in first.batches if b.num_rows > 0), None)
     if batch is None:
         return False, None
+    bound = node.bound_stage()
+    if bound is None:
+        return False, None
     rows = first.num_rows
     shard_rows = max((rows + ndev - 1) // ndev, 1)
     cal = costmodel.calibrate()
@@ -1924,13 +1925,9 @@ def _mesh_wins(node, first: MicroPartition, grouped: bool, ndev: int):
     if grouped:
         from ..ops.grouped_stage import (MAX_MATMUL_SEGMENTS, _pad_groups,
                                          estimate_key_cardinality,
-                                         resolve_key_series,
-                                         try_build_grouped_agg_stage)
+                                         resolve_key_series)
 
-        stage = try_build_grouped_agg_stage(
-            node.input.schema, node.predicate, node.groupby, node.aggregations)
-        if stage is None:
-            return False, None
+        stage = bound[0]
         key_series = resolve_key_series(batch, stage.groupby, batch.num_rows)
         card = max(estimate_key_cardinality(key_series), 1)
         cap_est = _pad_groups(min(card, 2 * MAX_MATMUL_SEGMENTS))
@@ -1962,12 +1959,7 @@ def _mesh_wins(node, first: MicroPartition, grouped: bool, ndev: int):
             cal, rows, len(node.aggregations), grouped=True,
             has_predicate=node.predicate is not None)
     else:
-        from ..ops.stage import try_build_filter_agg_stage
-
-        stage = try_build_filter_agg_stage(
-            node.input.schema, node.predicate, node.aggregations)
-        if stage is None:
-            return False, None
+        stage = bound[0]
         n_partials = max(len(stage.aggs), 1)
 
         def arm(arm_rows, nonres, res):
@@ -2024,6 +2016,9 @@ def _device_wins(node, first: MicroPartition, grouped: bool,
     batch = next((b for b in first.batches if b.num_rows > 0), None)
     if batch is None:
         return False, None
+    bound = node.bound_stage()
+    if bound is None:
+        return False, None
     rows = first.num_rows
     cal = costmodel.calibrate()
     coal = _coalesce_horizon([first] if second is None else [first, second])
@@ -2046,12 +2041,7 @@ def _device_wins(node, first: MicroPartition, grouped: bool,
                     - (2 if node.predicate is not None else 1), 0)
 
     if grouped:
-        from ..ops.grouped_stage import try_build_grouped_agg_stage
-
-        stage = try_build_grouped_agg_stage(
-            node.input.schema, node.predicate, node.groupby, node.aggregations)
-        if stage is None:
-            return False, None
+        stage = bound[0]
         bucket = pad_bucket(batch.num_rows)
         nonres = res = 0
         for c in stage._input_cols:
@@ -2103,12 +2093,7 @@ def _device_wins(node, first: MicroPartition, grouped: bool,
         detail = (f"{len(node.groupby)} keys, {len(node.aggregations)} aggs, "
                   f"~{card} groups")
     else:
-        from ..ops.stage import try_build_filter_agg_stage
-
-        stage = try_build_filter_agg_stage(node.input.schema, node.predicate,
-                                           node.aggregations)
-        if stage is None:
-            return False, None
+        stage = bound[0]
         bucket = pad_bucket(batch.num_rows)
         nonres = res = 0
         for c in stage._input_cols:

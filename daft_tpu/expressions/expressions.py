@@ -656,7 +656,10 @@ class Function(Expression):
         return Field(self.name(), dtype)
 
     def __repr__(self):
-        inner = ", ".join(repr(a) for a in self.args)
+        # the keyword arguments too: caches key compiled programs and resident
+        # slots on an expression's repr, and round(x, 1) is not round(x, 0)
+        inner = ", ".join([repr(a) for a in self.args]
+                          + [f"{k}={v!r}" for k, v in sorted(self.kwargs.items())])
         return f"{self.fname}({inner})"
 
 

@@ -130,6 +130,11 @@ DEVICE_COUNTER_NAMES = (
     "device_grouped_reduce_select",  # a masked sum a group, on the VPU
     "device_grouped_reduce_matmul",  # the one-hot product, on the MXU
     "device_stage_runs",       # completed device agg node executions
+    # the aggregate stages take a query's literal values as arguments (ops/
+    # stage.py, grouped_stage.py): a program is traced for a query shape, a
+    # bucket and a mesh width, never for a value
+    "device_stage_program_traces",  # stage programs traced (0 on a repeat query shape)
+    "device_literal_args",     # literal values passed to stage programs, summed over launches
     # a stage dispatch whose rows were sharded over more than one local device
     # (stage.note_mesh_dispatch): it is counted as a device_stage_batches or
     # device_grouped_batches dispatch too

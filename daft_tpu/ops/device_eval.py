@@ -14,7 +14,9 @@ if_else. Padding rows ride along as invalid and are masked out at aggregation.
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional, Tuple
+import datetime
+import itertools
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -22,6 +24,7 @@ from ..utils import jax_setup  # noqa: F401  — enables x64 before any jnp use
 import jax.numpy as jnp
 
 from ..datatype import DataType
+from ..device.residency import literal_nodes
 from ..expressions.expressions import (
     AggExpr,
     Alias,
@@ -136,97 +139,303 @@ def _temporal_operands_aligned(exprs, schema: Schema) -> bool:
     return all(dt == temporal[0] for dt in temporal)
 
 
+_DATE_EPOCH_ORDINAL = datetime.date(1970, 1, 1).toordinal()
+
+
+def literal_jax_dtype(dtype: DataType, fdt):
+    """The dtype a literal of `dtype` has inside a device program whose float
+    compute dtype is `fdt`."""
+    dt = dtype.to_jax()
+    return fdt if dt in (jnp.float64, jnp.float32) else dt
+
+
+def literal_host_value(dtype: DataType, value):
+    """A non-null literal's value as it goes to the device: temporal columns
+    live there as their arrow storage ints (date32 -> days, timestamp -> epoch
+    in the column's unit), so a temporal literal is converted on the host."""
+    if not dtype.is_temporal():
+        return value
+    if dtype.kind == "date" and type(value) is datetime.date:
+        return value.toordinal() - _DATE_EPOCH_ORDINAL
+    import pyarrow as pa
+
+    storage = pa.int32() if dtype.kind == "date" else pa.int64()
+    return pa.scalar(value, type=dtype.to_arrow()).cast(storage).as_py()
+
+
+class _Slot(Expression):
+    """A literal's place in a compiled expression: `index` is its position
+    among the literals in walk order (device/residency.literal_nodes), and
+    whether it is the null literal is all that is kept of its value."""
+
+    def __init__(self, index: int, null: bool):
+        self.index = index
+        self.null = null
+
+
+class LiteralSlots:
+    """The literal slots of a stage's expressions, taken in the order
+    `exprs_structure(exprs)` lists their literals: what of them is part of the
+    program's shape (each slot's dtype; a null literal, which stays a
+    constant) and how one execution's values travel to it. A launch pays one
+    small transfer whatever the number of literals: every value goes as one
+    or two 32-bit words of one uint32 host array (`pack`), and the traced
+    function rebuilds the slots' 0-d values from the words (`unpack`); a
+    float64 slot (a stage that computes in float64) rides in a second array,
+    since the chip cannot rebuild one from its bits. `run_values` more
+    int64s a launch (a grouped run's row offset) take the array's last words
+    (`with_run_values`, `run_value`). Holds no value."""
+
+    def __init__(self, exprs: Sequence[Expression], float_dtype, run_values: int = 0):
+        per_expr = [literal_nodes(e) for e in exprs]
+        nodes = [n for of_expr in per_expr for n in of_expr]
+        # the first slot of each expression: build_device_expr's `first_slot`
+        self.offsets = list(itertools.accumulate(
+            (len(of_expr) for of_expr in per_expr), initial=0))[:-1]
+        self.dtypes = [n.dtype for n in nodes]
+        # per slot: None (a null literal), ("f64", position) or
+        # ("words", first word, the slot's dtype in the program)
+        self._where: List[Optional[tuple]] = []
+        n_words = n_f64 = 0
+        for n in nodes:
+            if n.value is None:
+                self._where.append(None)
+                continue
+            dt = np.dtype(literal_jax_dtype(n.dtype, float_dtype))
+            if dt == np.float64:
+                self._where.append(("f64", n_f64))
+                n_f64 += 1
+            else:
+                self._where.append(("words", n_words, dt))
+                n_words += 2 if dt.kind in "iu" and dt.itemsize == 8 else 1
+        self._literal_words, self._n_f64 = n_words, n_f64
+        self.run_values = run_values
+        # values an execution passes: every slot but the null literals
+        self.n_args = sum(w is not None for w in self._where)
+
+    def __len__(self) -> int:
+        return len(self.dtypes)
+
+    def pack(self, literals: Sequence[Tuple[str, object]]) -> Tuple[np.ndarray, ...]:
+        """One execution's literals ((dtype-repr, value) pairs, as
+        exprs_structure gives them) as host arrays: the literals' words, and
+        the float64 values where there are any."""
+        if len(literals) != len(self.dtypes) or any(
+                (where is None) != (literals[i][1] is None)
+                for i, where in enumerate(self._where)):
+            raise ValueError(
+                f"the program has {len(self.dtypes)} literal slots "
+                f"({self.n_args} with a value); the run was given {literals!r}")
+        words = np.zeros(self._literal_words, dtype=np.uint32)
+        f64s = np.zeros(self._n_f64, dtype=np.float64)
+        for where, dtype, (_repr, value) in zip(self._where, self.dtypes, literals):
+            if where is None:
+                continue
+            value = literal_host_value(dtype, value)
+            if where[0] == "f64":
+                f64s[where[1]] = value
+                continue
+            _kind, at, dt = where
+            if dt.kind == "f":
+                words[at] = np.float32(value).view(np.uint32)
+            elif dt.kind == "b":
+                words[at] = bool(value)
+            else:
+                _put_int(words, at, int(value), wide=dt.itemsize == 8)
+        return (words, f64s) if self._n_f64 else (words,)
+
+    def with_run_values(self, packed: Tuple[np.ndarray, ...], values: Sequence[int] = ()
+                        ) -> Tuple[np.ndarray, ...]:
+        """The program's literal argument for one launch: `pack`'s arrays,
+        the launch's run values behind the literals' words (a fresh array: a
+        transfer may still read the last launch's); nothing where the program
+        takes no word at all."""
+        if len(values) != self.run_values:
+            raise ValueError(f"the program takes {self.run_values} run values, not {values!r}")
+        words = packed[0]
+        if values:
+            tail = np.zeros(2 * len(values), dtype=np.uint32)
+            for k, v in enumerate(values):
+                _put_int(tail, 2 * k, int(v), wide=True)
+            words = np.concatenate([words, tail])
+        return ((words,) if len(words) else ()) + packed[1:]
+
+    def arg_shapes(self) -> Tuple[Tuple[tuple, np.dtype], ...]:
+        """(shape, dtype) of each array of a launch's literal argument: what a
+        program is lowered with where there are no values
+        (tests/test_chip_compile.py)."""
+        n_words = self._literal_words + 2 * self.run_values
+        return (((((n_words,), np.dtype(np.uint32)),) if n_words else ())
+                + ((((self._n_f64,), np.dtype(np.float64)),) if self._n_f64 else ()))
+
+    def _words_and_f64s(self, arrays):
+        has_words = bool(self._literal_words + 2 * self.run_values)
+        return (arrays[0] if has_words else None,
+                arrays[-1] if self._n_f64 else None)
+
+    def unpack(self, arrays) -> list:
+        """The slots' 0-d values in the program's dtypes, from a launch's
+        literal argument (traceable); None at a null literal's slot."""
+        words, f64s = self._words_and_f64s(arrays)
+        out: list = []
+        for where in self._where:
+            if where is None:
+                out.append(None)
+            elif where[0] == "f64":
+                out.append(f64s[where[1]])
+            else:
+                _kind, at, dt = where
+                if dt.kind == "f":
+                    out.append(_bits_as(words[at], jnp.float32).astype(dt))
+                elif dt.kind == "b":
+                    out.append(words[at] != 0)
+                elif dt.itemsize == 8:
+                    out.append(_int64_of(words, at).astype(dt))
+                else:
+                    out.append(_bits_as(words[at], jnp.int32).astype(dt))
+        return out
+
+    def run_value(self, arrays, k: int):
+        """The k-th run value of a launch, a 0-d int64 (traceable)."""
+        return _int64_of(self._words_and_f64s(arrays)[0], self._literal_words + 2 * k)
+
+
+def _put_int(words: np.ndarray, at: int, value: int, wide: bool) -> None:
+    """`value` in two's complement: its low word at `at`, its high behind."""
+    words[at] = value & 0xFFFFFFFF
+    if wide:
+        words[at + 1] = (value >> 32) & 0xFFFFFFFF
+
+
+def _bits_as(word, dtype):
+    import jax
+
+    return jax.lax.bitcast_convert_type(word, dtype)
+
+
+def _int64_of(words, at: int):
+    """The int64 whose low and high words stand at `at` (no 64-bit bitcast:
+    the chip's compiler has none that changes a shape)."""
+    low = words[at].astype(jnp.int64)
+    high = _bits_as(words[at + 1], jnp.int32).astype(jnp.int64)
+    return (high << 32) | low
+
+
 def build_device_expr(expr: Expression, schema: Schema,
-                      float_dtype=None) -> Callable[[Dict[str, DCol]], DCol]:
-    """Return fn(cols) -> (values, validity); traceable under jit.
+                      float_dtype=None, first_slot: int = 0
+                      ) -> Callable[[Dict[str, DCol], Sequence], DCol]:
+    """Return fn(cols, lits) -> (values, validity); traceable under jit.
+
+    What is compiled is the expression's skeleton: its non-null literals are
+    slots, numbered from `first_slot` in walk order (the order of
+    `expr_structure`'s literals), and `lits[k]` is the 0-d value of slot k in
+    the program's dtype (LiteralSlots.unpack), so a program traced once
+    serves every value. No value of `expr` itself is read: a caller whose
+    program is keyed on the values uses build_constant_device_expr. A slot's
+    dtype, a null literal, the number of an IsIn's items and cast targets are
+    part of the skeleton.
 
     ``float_dtype`` sets the device float compute dtype (default float64).
     The stage compilers pass float32: TPU f64 is software-emulated (~5x slower,
-    measured on v5e), so elementwise work runs in f32 and aggregation recovers
+    measured on v5e) and column data is f32-exact in practice; sums keep
     precision with f64 partial combines (ops/grouped_stage.py chunked merge).
     """
     fdt = float_dtype or jnp.float64
+    numbering = itertools.count(first_slot)
+    # transform reaches the literals, which are leaves, in walk order
+    skeleton = expr.transform(
+        lambda n: _Slot(next(numbering), n.value is None) if isinstance(n, Literal) else None)
 
     def fcast(v):
         return v.astype(fdt) if v.dtype in (jnp.float64, jnp.float32) and v.dtype != fdt else v
 
-    def ev(node: Expression, cols: Dict[str, DCol]) -> DCol:
-        if isinstance(node, ColumnRef):
-            v, m = cols[node._name]
-            return fcast(v), m
-        if isinstance(node, Literal):
-            if node.value is None:
-                return jnp.zeros((), dtype=fdt), jnp.zeros((), dtype=bool)
-            dt = node.dtype.to_jax()
-            if dt in (jnp.float64, jnp.float32):
-                dt = fdt
-            value = node.value
-            if node.dtype.is_temporal():
-                # temporal columns live on device as their arrow storage ints
-                # (date32 -> days, timestamp -> epoch in the column's unit)
-                import pyarrow as pa
+    def run(cols: Dict[str, DCol], lits: Sequence) -> DCol:
+        def ev(node: Expression, cols: Dict[str, DCol]) -> DCol:
+            if isinstance(node, ColumnRef):
+                v, m = cols[node._name]
+                return fcast(v), m
+            if isinstance(node, _Slot):
+                if node.null:
+                    return jnp.zeros((), dtype=fdt), jnp.zeros((), dtype=bool)
+                return lits[node.index], jnp.ones((), dtype=bool)
+            if isinstance(node, Alias):
+                return ev(node.child, cols)
+            if isinstance(node, Cast):
+                v, m = ev(node.child, cols)
+                target = node.dtype.to_jax()
+                if target in (jnp.float64, jnp.float32):
+                    target = fdt
+                return v.astype(target), m
+            if isinstance(node, UnaryOp):
+                v, m = ev(node.child, cols)
+                if node.op == "not":
+                    return ~v.astype(bool), m
+                if node.op == "neg":
+                    return -v, m
+                if node.op == "abs":
+                    return jnp.abs(v), m
+                if node.op == "is_null":
+                    val = ~m & jnp.ones(jnp.shape(v), dtype=bool)
+                    return val, jnp.ones_like(val)
+                if node.op == "not_null":
+                    val = m & jnp.ones(jnp.shape(v), dtype=bool)
+                    return val, jnp.ones_like(val)
+                raise ValueError(node.op)
+            if isinstance(node, BinaryOp):
+                lv, lm = ev(node.left, cols)
+                rv, rm = ev(node.right, cols)
+                return _binop(node.op, lv, lm, rv, rm, fdt)
+            if isinstance(node, Between):
+                v, m = ev(node.child, cols)
+                lo, lom = ev(node.lower, cols)
+                hi, him = ev(node.upper, cols)
+                val = (v >= lo) & (v <= hi)
+                return val, m & lom & him
+            if isinstance(node, IsIn):
+                # host semantics: null input -> False, result never null
+                v, m = ev(node.child, cols)
+                acc = jnp.zeros(jnp.shape(v), dtype=bool)
+                for item in node.items:
+                    iv, im = ev(item, cols)
+                    acc = acc | ((v == iv) & im)
+                val = acc & m
+                return val, jnp.ones_like(val)
+            if isinstance(node, IfElse):
+                pv, pm = ev(node.predicate, cols)
+                tv, tm = ev(node.if_true, cols)
+                fv, fm = ev(node.if_false, cols)
+                cond = pv.astype(bool)
+                tv, fv = _promote_pair(tv, fv)
+                val = jnp.where(cond, tv, fv)
+                # arrow semantics (matches host pc.if_else): null predicate -> null
+                valid = pm & jnp.where(cond, tm & jnp.ones_like(cond), fm & jnp.ones_like(cond))
+                return val, valid
+            if isinstance(node, Function):
+                return _fn_node(node, ev, cols, fdt)
+            raise ValueError(f"not device-evaluable: {type(node).__name__}")
 
-                storage = pa.int32() if node.dtype.kind == "date" else pa.int64()
-                value = pa.scalar(value, type=node.dtype.to_arrow()).cast(storage).as_py()
-            return jnp.asarray(value, dtype=dt), jnp.ones((), dtype=bool)
-        if isinstance(node, Alias):
-            return ev(node.child, cols)
-        if isinstance(node, Cast):
-            v, m = ev(node.child, cols)
-            target = node.dtype.to_jax()
-            if target in (jnp.float64, jnp.float32):
-                target = fdt
-            return v.astype(target), m
-        if isinstance(node, UnaryOp):
-            v, m = ev(node.child, cols)
-            if node.op == "not":
-                return ~v.astype(bool), m
-            if node.op == "neg":
-                return -v, m
-            if node.op == "abs":
-                return jnp.abs(v), m
-            if node.op == "is_null":
-                val = ~m & jnp.ones(jnp.shape(v), dtype=bool)
-                return val, jnp.ones_like(val)
-            if node.op == "not_null":
-                val = m & jnp.ones(jnp.shape(v), dtype=bool)
-                return val, jnp.ones_like(val)
-            raise ValueError(node.op)
-        if isinstance(node, BinaryOp):
-            lv, lm = ev(node.left, cols)
-            rv, rm = ev(node.right, cols)
-            return _binop(node.op, lv, lm, rv, rm, fdt)
-        if isinstance(node, Between):
-            v, m = ev(node.child, cols)
-            lo, lom = ev(node.lower, cols)
-            hi, him = ev(node.upper, cols)
-            val = (v >= lo) & (v <= hi)
-            return val, m & lom & him
-        if isinstance(node, IsIn):
-            # host semantics: null input -> False, result never null
-            v, m = ev(node.child, cols)
-            acc = jnp.zeros(jnp.shape(v), dtype=bool)
-            for item in node.items:
-                iv, im = ev(item, cols)
-                acc = acc | ((v == iv) & im)
-            val = acc & m
-            return val, jnp.ones_like(val)
-        if isinstance(node, IfElse):
-            pv, pm = ev(node.predicate, cols)
-            tv, tm = ev(node.if_true, cols)
-            fv, fm = ev(node.if_false, cols)
-            cond = pv.astype(bool)
-            tv, fv = _promote_pair(tv, fv)
-            val = jnp.where(cond, tv, fv)
-            # arrow semantics (matches host pc.if_else): null predicate -> null
-            valid = pm & jnp.where(cond, tm & jnp.ones_like(cond), fm & jnp.ones_like(cond))
-            return val, valid
-        if isinstance(node, Function):
-            return _fn_node(node, ev, cols, fdt)
-        raise ValueError(f"not device-evaluable: {type(node).__name__}")
+        return ev(skeleton, cols)
+
+    return run
+
+
+def build_constant_device_expr(expr: Expression, schema: Schema,
+                               float_dtype=None) -> Callable[[Dict[str, DCol]], DCol]:
+    """Return fn(cols) -> (values, validity) with the literal values of
+    `expr` itself as constants of the traced program: for the callers whose
+    compiled programs are kept under the values (the mesh join steps of
+    parallel/distributed.py, a dim filter's visibility plane). The aggregate
+    stages keep theirs under the skeleton, use build_device_expr and pass
+    each execution's values."""
+    fdt = float_dtype or jnp.float64
+    fn = build_device_expr(expr, schema, float_dtype=fdt)
+    constants = [None if n.value is None
+                 else (literal_host_value(n.dtype, n.value), literal_jax_dtype(n.dtype, fdt))
+                 for n in literal_nodes(expr)]
 
     def run(cols: Dict[str, DCol]) -> DCol:
-        return ev(expr, cols)
+        return fn(cols, [None if c is None else jnp.asarray(c[0], dtype=c[1])
+                         for c in constants])
 
     return run
 

@@ -692,7 +692,7 @@ class _JoinContext:
         def build():
             vis = None
             for f in devf:
-                fn = dev.build_device_expr(f, d.base.schema)
+                fn = dev.build_constant_device_expr(f, d.base.schema)
                 dcols = {c: b.get_column(c).to_device_cached(cap_d, f32=True)
                          for c in f.referenced_columns()}
                 v, m = fn(dcols)
@@ -1410,7 +1410,7 @@ class DeviceJoinGroupedRun(GroupedAggRun):
     max_segments = 1 << 16
 
     def __init__(self, stage: GroupedAggStage, ctx: _JoinContext):
-        super().__init__(stage)
+        super().__init__(stage, _join_stage_literals(ctx.spec))
         self.ctx = ctx
 
     # TopN runs force the host-factorize path (dense first-occurrence ids
@@ -1457,10 +1457,10 @@ class DeviceJoinGroupedRun(GroupedAggRun):
                                                           codes=codes)
                 prog, form = stage._program_for(decode.cap)
                 mask = device_row_mask(n, bucket)
-                offset = jnp.asarray(float(self._row_offset))
+                lit_args = self.literals.args((self._row_offset,))
                 with profile_span("device.launch", "device", op="join_agg",
                                   cap=decode.cap, reduce=form):
-                    out = prog(dcols, decode.dcodes, mask, offset)
+                    out = prog(dcols, decode.dcodes, mask, lit_args)
                 count_reduce(form)
             else:
                 with profile_span("join.codes", "host", strategy="host") as sp:
@@ -1481,18 +1481,21 @@ class DeviceJoinGroupedRun(GroupedAggRun):
                                                   perm=(decode.pperm, pdev))
                     prog = stage._jit_local(decode.cap)
                     mask = device_row_mask(n, bucket)
+                    # (the host supplies this layout's first rows: the
+                    # program reads no row offset)
+                    lit_args = self.literals.args((self._row_offset,))
                     with profile_span("device.launch", "device",
                                       op="join_agg_local", cap=decode.cap):
                         out = prog(dcols, decode.local_codes, decode.seg_lo,
-                                   mask)
+                                   mask, lit_args)
                 else:
                     dcols, _ = self.ctx.provision(batch, bucket, needed)
                     prog, form = stage._program_for(decode.cap)
                     mask = device_row_mask(n, bucket)
-                    offset = jnp.asarray(float(self._row_offset))
+                    lit_args = self.literals.args((self._row_offset,))
                     with profile_span("device.launch", "device", op="join_agg",
                                       cap=decode.cap, reduce=form):
-                        out = prog(dcols, decode.dcodes, mask, offset)
+                        out = prog(dcols, decode.dcodes, mask, lit_args)
                     count_reduce(form)
         decode.row_offset = float(self._row_offset)
         self._row_offset += n
@@ -1829,7 +1832,7 @@ def try_capture_join_topn(plan):
 
 class DeviceJoinUngroupedRun(FilterAggRun):
     def __init__(self, stage: FilterAggStage, ctx: _JoinContext):
-        super().__init__(stage)
+        super().__init__(stage, _join_stage_literals(ctx.spec))
         self.ctx = ctx
 
     def feed_batch(self, batch) -> None:
@@ -1901,6 +1904,14 @@ def estimate_joined_cardinality(ctx: _JoinContext, batch, groupby) -> int:
     return series_keyed(anchor,
                         ("jcard",) + tuple(repr(g) for g in groupby),
                         deps, build)
+
+
+def _join_stage_literals(spec: JoinAggSpec) -> tuple:
+    """The literal values of one execution of build_join_stage(spec)'s
+    stage: what its runs pass to the stage's programs."""
+    from .stage import stage_literals
+
+    return stage_literals(_with_join_ok(spec.predicate), spec.aggregations)
 
 
 def build_join_stage(spec: JoinAggSpec):

@@ -66,8 +66,10 @@ from ..observability.runtime_stats import profile_span
 from ..schema import Schema
 from . import counters
 from . import device_eval as dev
-from .stage import (MESH_AXIS, device_row_mask, local_mesh, mesh_total,
-                    note_mesh_dispatch, over_shards, pad_bucket, shard_rows)
+from .stage import (MESH_AXIS, _LiteralBinding, compile_stage_exprs, device_aggs,
+                    device_row_mask, local_mesh, mesh_total, note_mesh_dispatch,
+                    note_program_trace, over_shards, pad_bucket, shard_rows,
+                    stage_cache_key, stage_structure, unwrap_aggs)
 
 _MIN_GROUP_CAP = 8
 # segment-count ceiling for the matmul path: beyond this the one-hot FLOPs and
@@ -295,7 +297,15 @@ def _counts_all(agg: AggExpr) -> bool:
 
 
 class GroupedAggStage:
-    """Compiled filter→grouped-agg program (immutable; see start_run())."""
+    """Compiled filter→grouped-agg program (immutable; see start_run()).
+
+    Like FilterAggStage, compiled for the skeleton of the predicate and of
+    the aggregates' inputs: their literals' values are arguments of every
+    program (dev.LiteralSlots), and the expressions kept here serve for their
+    structure alone. What of a value does set the structure is in the cache
+    key (try_build_grouped_agg_stage): which inputs are the same expression
+    (they share planes) and an integer sum's static bounds (its digit
+    planes). The group keys are evaluated on the host, values and all."""
 
     def __init__(self, schema: Schema, predicate: Optional[Expression],
                  groupby: Sequence[Expression], aggs: Sequence[Tuple[str, AggExpr]]):
@@ -316,6 +326,13 @@ class GroupedAggStage:
             agg.op in ("min", "max")
             and agg.child.to_field(schema).dtype.is_floating()
             for _n, agg in self.aggs)
+        self._slot_exprs = ([] if predicate is None else [predicate]) \
+            + [agg.child for _n, agg in self.aggs]
+        # a launch's row offset (the rows the run fed before it) rides with
+        # the literals' values: one small transfer a launch for both
+        self.slots = dev.LiteralSlots(
+            self._slot_exprs, jnp.float64 if self._use_f64 else jnp.float32,
+            run_values=1)
         self._classify_planes()
 
     def _classify_planes(self) -> None:
@@ -407,10 +424,12 @@ class GroupedAggStage:
                     cols.append(c)
         return cols
 
-    def start_run(self, mesh_devices: int = 1) -> "GroupedAggRun":
-        """A fresh accumulator; with `mesh_devices` > 1 its dispatches shard
-        each batch's rows over that many local devices."""
-        return GroupedAggRun(self, mesh_devices)
+    def start_run(self, literals: Sequence = (), mesh_devices: int = 1) -> "GroupedAggRun":
+        """A fresh accumulator for one execution, whose literal values are
+        `literals` (stage.stage_literals of that execution's predicate and
+        aggregates); with `mesh_devices` > 1 its dispatches shard each
+        batch's rows over that many local devices."""
+        return GroupedAggRun(self, literals, mesh_devices)
 
     def _chunk_planes(self, cap: int, fdt, radices: Tuple[int, ...]) -> Callable:
         """The plane evaluator of _build's loop: for a tile of rows (the
@@ -420,17 +439,18 @@ class GroupedAggStage:
         distinct child expression is evaluated once a tile. With `radices`,
         `codes` is the key columns' dictionary-code planes and the segment id
         their radix sum."""
-        schema = self.schema
-        pred_fn = (dev.build_device_expr(self.predicate, schema, float_dtype=fdt)
-                   if self.predicate is not None else None)
+        pred_fn, fns = compile_stage_exprs(self, fdt)
+        # inputs that are the same expression, values and all, are evaluated
+        # once, through the first's slots (which inputs are is in the cache key)
         child_key = [repr(agg.child) for _name, agg in self.aggs]
-        child_fns = {key: dev.build_device_expr(agg.child, schema, float_dtype=fdt)
-                     for key, (_name, agg) in zip(child_key, self.aggs)}
+        child_fns: Dict[str, Callable] = {}
+        for key, fn in zip(child_key, fns):
+            child_fns.setdefault(key, fn)
         mm_specs, ext_specs, sct_specs = self._mm_specs, self._ext_specs, self._sct_specs
 
-        def planes(cols: Dict[str, dev.DCol], codes, row_mask):
+        def planes(cols: Dict[str, dev.DCol], codes, row_mask, lits):
             if pred_fn is not None:
-                pv, pm = pred_fn(cols)
+                pv, pm = pred_fn(cols, lits)
                 keep = pv.astype(bool) & pm & row_mask
             else:
                 keep = row_mask
@@ -442,7 +462,7 @@ class GroupedAggStage:
             def child(agg_idx: int):
                 key = child_key[agg_idx]
                 if key not in seen:
-                    v, m = child_fns[key](cols)
+                    v, m = child_fns[key](cols, lits)
                     v = jnp.broadcast_to(v, jnp.shape(seg))
                     seen[key] = (v, dev._broadcast_valid(v, m) & keep)
                 return seen[key]
@@ -505,8 +525,13 @@ class GroupedAggStage:
             return jnp.asarray(jnp.inf if op == "min" else -jnp.inf,
                                jnp.float64 if use_f64 else jnp.float32)
 
+        slots = self.slots
+
         def stage(cols: Dict[str, dev.DCol], codes, row_mask: jnp.ndarray,
-                  row_offset: jnp.ndarray):
+                  lit_args, rows_before=0.0):
+            note_program_trace()
+            lits = slots.unpack(lit_args)
+            row_offset = _row_offset(slots, lit_args, rows_before)
             bucket = row_mask.shape[0]
             chunk = _chunk_for(bucket, cap)
             n_chunks = bucket // chunk
@@ -526,7 +551,7 @@ class GroupedAggStage:
             def body(carry, xs):
                 acc_mm, acc_first, acc_ext, acc_sct = carry
                 step, ccols, ccodes, cmask = xs
-                seg, mm, ext, sct = planes_of(ccols, ccodes, cmask)
+                seg, mm, ext, sct = planes_of(ccols, ccodes, cmask, lits)
                 if form == "select":
                     hits = [seg == g for g in range(cap)]
 
@@ -615,23 +640,21 @@ class GroupedAggStage:
         and read each segment's total at its end position via searchsorted.
         O(n log n + G) — lifts the r3 VERDICT's 4096-group device ceiling to
         MAX_SORT_SEGMENTS."""
-        schema = self.schema
         fdt = jnp.float64 if self._use_f64 else jnp.float32
-        pred_fn = (dev.build_device_expr(self.predicate, schema, float_dtype=fdt)
-                   if self.predicate is not None else None)
-        child_fns = []
-        for name, agg in self.aggs:
-            count_all = agg.op == "count" and agg.params.get("mode", "valid") == "all"
-            child_fns.append((dev.build_device_expr(agg.child, schema, float_dtype=fdt),
-                              count_all))
+        pred_fn, fns = compile_stage_exprs(self, fdt)
+        child_fns = [(fn, _counts_all(agg)) for fn, (_name, agg) in zip(fns, self.aggs)]
+        slots = self.slots
 
         mm_specs, ext_specs, sct_specs = self._mm_specs, self._ext_specs, self._sct_specs
 
         def stage(cols: Dict[str, dev.DCol], codes: jnp.ndarray,
-                  row_mask: jnp.ndarray, row_offset: jnp.ndarray):
+                  row_mask: jnp.ndarray, lit_args, rows_before=0.0):
+            note_program_trace()
+            lits = slots.unpack(lit_args)
+            row_offset = _row_offset(slots, lit_args, rows_before)
             bucket = codes.shape[0]
             if pred_fn is not None:
-                pv, pm = pred_fn(cols)
+                pv, pm = pred_fn(cols, lits)
                 keep = pv.astype(bool) & pm & row_mask
             else:
                 keep = row_mask
@@ -639,7 +662,7 @@ class GroupedAggStage:
 
             evaluated = []
             for fn, count_all in child_fns:
-                v, m = fn(cols)
+                v, m = fn(cols, lits)
                 v = v + jnp.zeros(jnp.shape(seg), dtype=v.dtype) if jnp.shape(v) != jnp.shape(seg) else v
                 mask = keep if count_all else dev._broadcast_valid(v, m) & keep
                 evaluated.append((v, mask))
@@ -801,28 +824,26 @@ class GroupedAggStage:
         the XLA tiers, and the trace refuses one that slips through)."""
         from . import pallas_kernels as pk
 
-        schema = self.schema
         fdt = jnp.float32
-        pred_fn = (dev.build_device_expr(self.predicate, schema, float_dtype=fdt)
-                   if self.predicate is not None else None)
-        child_fns = []
-        for name, agg in self.aggs:
-            count_all = agg.op == "count" and agg.params.get("mode", "valid") == "all"
-            child_fns.append((dev.build_device_expr(agg.child, schema, float_dtype=fdt),
-                              count_all))
+        pred_fn, fns = compile_stage_exprs(self, fdt)
+        child_fns = [(fn, _counts_all(agg)) for fn, (_name, agg) in zip(fns, self.aggs)]
+        slots = self.slots
 
         mm_specs, ext_specs = self._mm_specs, self._ext_specs
         sct_specs = self._sct_specs
 
         def stage(cols: Dict[str, dev.DCol], codes: jnp.ndarray,
-                  row_mask: jnp.ndarray, row_offset: jnp.ndarray):
+                  row_mask: jnp.ndarray, lit_args, rows_before=0.0):
+            note_program_trace()
+            lits = slots.unpack(lit_args)
+            row_offset = _row_offset(slots, lit_args, rows_before)
             bucket = codes.shape[0]
             if bucket >= pk.MAX_PALLAS_BUCKET:
                 raise ValueError(
                     f"pallas tier: bucket {bucket} exceeds f32-exact "
                     f"first-row-index range {pk.MAX_PALLAS_BUCKET}")
             if pred_fn is not None:
-                pv, pm = pred_fn(cols)
+                pv, pm = pred_fn(cols, lits)
                 keep = pv.astype(bool) & pm & row_mask
             else:
                 keep = row_mask
@@ -830,7 +851,7 @@ class GroupedAggStage:
 
             evaluated = []
             for fn, count_all in child_fns:
-                v, m = fn(cols)
+                v, m = fn(cols, lits)
                 v = v + jnp.zeros(jnp.shape(seg), dtype=v.dtype) \
                     if jnp.shape(v) != jnp.shape(seg) else v
                 mask = keep if count_all else dev._broadcast_valid(v, m) & keep
@@ -940,15 +961,10 @@ class GroupedAggStage:
         Exactness matches the matmul path: digit planes for int sums, f64
         accumulators, f64 extreme planes.
         """
-        schema = self.schema
         fdt = jnp.float64 if self._use_f64 else jnp.float32
-        pred_fn = (dev.build_device_expr(self.predicate, schema, float_dtype=fdt)
-                   if self.predicate is not None else None)
-        child_fns = []
-        for name, agg in self.aggs:
-            count_all = agg.op == "count" and agg.params.get("mode", "valid") == "all"
-            child_fns.append((dev.build_device_expr(agg.child, schema, float_dtype=fdt),
-                              count_all))
+        pred_fn, fns = compile_stage_exprs(self, fdt)
+        child_fns = [(fn, _counts_all(agg)) for fn, (_name, agg) in zip(fns, self.aggs)]
+        slots = self.slots
         mm_specs = self._mm_specs
         ext_specs = self._ext_specs[1:]  # first-row index comes from the host
         if self._sct_specs:
@@ -959,12 +975,14 @@ class GroupedAggStage:
                 "local-dense path does not run in f64-exact mode")
 
         def stage(cols: Dict[str, dev.DCol], local_codes: jnp.ndarray,
-                  seg_lo: jnp.ndarray, row_mask: jnp.ndarray):
+                  seg_lo: jnp.ndarray, row_mask: jnp.ndarray, lit_args):
+            note_program_trace()
+            lits = slots.unpack(lit_args)
             bucket = local_codes.shape[0]
             chunk = min(CHUNK_LOCAL, bucket)
             n_chunks = bucket // chunk
             if pred_fn is not None:
-                pv, pm = pred_fn(cols)
+                pv, pm = pred_fn(cols, lits)
                 keep = pv.astype(bool) & pm & row_mask
             else:
                 keep = row_mask
@@ -972,7 +990,7 @@ class GroupedAggStage:
 
             evaluated = []
             for fn, count_all in child_fns:
-                v, m = fn(cols)
+                v, m = fn(cols, lits)
                 v = v + jnp.zeros(jnp.shape(lc), dtype=v.dtype) \
                     if jnp.shape(v) != jnp.shape(lc) else v
                 mask = keep if count_all else dev._broadcast_valid(v, m) & keep
@@ -1047,18 +1065,26 @@ class GroupedAggStage:
         return jax.jit(stage)
 
 
+def _row_offset(slots: dev.LiteralSlots, lit_args, rows_before):
+    """The f64 position of a dispatch's first row in its run's stream: the
+    launch's run value (the rows fed before it), and over a mesh the rows of
+    the shards before this one."""
+    return slots.run_value(lit_args, 0).astype(jnp.float64) + rows_before
+
+
 def _over_mesh(stage: Callable, mesh) -> Callable:
-    """A grouped program of (cols, codes, row_mask, row_offset) as it runs:
-    as it is on one chip, or over `mesh` on every shard (stage.over_shards),
-    each shard's first-row positions offset by the rows of the shards before
-    it, so the groups' order is that of the whole batch."""
+    """A grouped program of (cols, codes, row_mask, lit_args) as it runs: as
+    it is on one chip, or over `mesh` on every shard (stage.over_shards; the
+    literals' values and the row offset whole on each), each shard's
+    first-row positions offset by the rows of the shards before it, so the
+    groups' order is that of the whole batch."""
     if mesh is None:
         return stage
 
-    def on_shard(cols, codes, row_mask, row_offset):
+    def on_shard(cols, codes, row_mask, lit_args):
         before = jax.lax.axis_index(MESH_AXIS).astype(jnp.float64) \
             * row_mask.shape[0]
-        return stage(cols, codes, row_mask, row_offset + before)
+        return stage(cols, codes, row_mask, lit_args, before)
 
     return over_shards(on_shard, mesh, replicated_tail=1)
 
@@ -1068,11 +1094,14 @@ class GroupedAggRun:
     ONE device_get at finalize, then merged on the host (vectorized by slot).
     With `mesh_devices` > 1 a dispatch's rows are sharded over that many local
     devices and its result holds one table a shard, merged like the tables of
-    successive batches."""
+    successive batches. `literals` are the values of this execution's
+    literals; every launch passes them to the program."""
 
-    def __init__(self, stage: GroupedAggStage, mesh_devices: int = 1):
+    def __init__(self, stage: GroupedAggStage, literals: Sequence = (),
+                 mesh_devices: int = 1):
         self.stage = stage
         self.mesh_devices = max(int(mesh_devices), 1)
+        self.literals = _LiteralBinding(stage.slots, literals)
         # (device_out, decode) where decode resolves segment -> key tuple + presence
         self._pending: List[Tuple[dict, "_Decode"]] = []
         self._row_offset = 0
@@ -1103,12 +1132,12 @@ class GroupedAggRun:
         with profile_span("device.dispatch", "device", op="grouped_agg",
                           rows=n, bucket=bucket, groups_cap=decode.cap):
             mask = device_row_mask(n, bucket, mesh)
-            offset = jnp.asarray(float(self._row_offset))
+            lit_args = self.literals.args((self._row_offset,))
             # a Pallas program that does not lower raises here: no tier
             # replaces it behind the caller's back
             with profile_span("device.launch", "device", op="grouped_agg",
                               cap=decode.cap, reduce=form, devices=ndev):
-                out = prog(dcols, codes, mask, offset)
+                out = prog(dcols, codes, mask, lit_args)
         if form == "pallas":
             counters.bump("pallas_dispatches")
         if ndev > 1:
@@ -1412,46 +1441,65 @@ _STAGE_CACHE: Dict[tuple, GroupedAggStage] = {}
 _CACHE_LOCK = threading.Lock()
 
 
-def try_build_grouped_agg_stage(schema: Schema, predicate: Optional[Expression],
-                                groupby: Sequence[Expression],
-                                agg_exprs: Sequence[Expression]) -> Optional[GroupedAggStage]:
-    """Build a device grouped-agg stage if predicate + agg value exprs qualify.
+def grouped_stage_cache_key(schema: Schema, predicate: Optional[Expression],
+                            groupby: Sequence[Expression],
+                            agg_exprs: Sequence[Expression],
+                            structure: Optional[Tuple[tuple, tuple]] = None) -> tuple:
+    """stage_cache_key's skeletons and slot dtypes, and what of the values
+    does set the program's structure: the group keys as they are (the host
+    evaluates them), which aggregates' inputs are the same expression (they
+    share their planes), and the inputs' static integer bounds (they size an
+    exact integer sum's digit planes)."""
+    inputs, bounds = [], []
+    for _name, agg in unwrap_aggs(agg_exprs) or ():
+        inputs.append(repr(agg.child))
+        bounds.append(_static_int_bounds(agg.child))
+    return stage_cache_key(schema, predicate, agg_exprs, structure=structure, static=(
+        tuple(repr(g) for g in groupby),
+        tuple(inputs.index(child) for child in inputs),
+        tuple(bounds)))
+
+
+def bind_grouped_agg_stage(schema: Schema, predicate: Optional[Expression],
+                           groupby: Sequence[Expression],
+                           agg_exprs: Sequence[Expression]
+                           ) -> Optional[Tuple[GroupedAggStage, tuple]]:
+    """(stage, literals) for filter+groupby+agg, or None if any piece
+    doesn't qualify: stage.bind_filter_agg_stage's contract for the grouped
+    stage. One walk of the expressions gives both.
 
     Group keys run host-side (factorize handles any dtype) or via cached
     per-column dictionaries, so they are unconstrained beyond being
-    non-aggregate expressions. Stages (compiled programs only) are cached by
-    structure so repeated runs reuse jitted executables; run state lives in
-    GroupedAggRun.
+    non-aggregate expressions. Stages (compiled programs only, no literal
+    value) are cached by structure (grouped_stage_cache_key), so runs of a
+    query with whatever literal values reuse the jitted executables. Run
+    state and the values live in GroupedAggRun.
     """
-    from .stage import stage_cache_key
+    structure = stage_structure(predicate, agg_exprs)
+    key = grouped_stage_cache_key(schema, predicate, groupby, agg_exprs, structure)
+    stage = _STAGE_CACHE.get(key)
+    if stage is None:
+        if not groupby:
+            return None
+        if predicate is not None and not dev.is_device_evaluable(predicate, schema):
+            return None
+        aggs = device_aggs(schema, agg_exprs)
+        if aggs is None:
+            return None
+        for g in groupby:
+            for node in g.walk():
+                if isinstance(node, AggExpr):
+                    return None
+        stage = GroupedAggStage(schema, predicate, groupby, aggs)
+        with _CACHE_LOCK:
+            _STAGE_CACHE[key] = stage
+    return stage, structure[1]
 
-    key = stage_cache_key(schema, predicate, list(groupby) + list(agg_exprs))
-    if key in _STAGE_CACHE:
-        return _STAGE_CACHE[key]
-    if not groupby:
-        return None
-    if predicate is not None and not dev.is_device_evaluable(predicate, schema):
-        return None
-    aggs: List[Tuple[str, AggExpr]] = []
-    for e in agg_exprs:
-        name = e.name()
-        inner = e
-        while isinstance(inner, Alias):
-            inner = inner.child
-        if not isinstance(inner, AggExpr):
-            return None
-        if inner.op not in ("sum", "mean", "min", "max", "count"):
-            return None
-        if inner.op == "count" and inner.params.get("mode", "valid") == "null":
-            return None
-        if not dev.is_device_evaluable(inner.child, schema):
-            return None
-        aggs.append((name, inner))
-    for g in groupby:
-        for node in g.walk():
-            if isinstance(node, AggExpr):
-                return None
-    stage = GroupedAggStage(schema, predicate, groupby, aggs)
-    with _CACHE_LOCK:
-        _STAGE_CACHE[key] = stage
-    return stage
+
+def try_build_grouped_agg_stage(schema: Schema, predicate: Optional[Expression],
+                                groupby: Sequence[Expression],
+                                agg_exprs: Sequence[Expression]) -> Optional[GroupedAggStage]:
+    """bind_grouped_agg_stage's stage alone: for whoever asks whether the
+    device can run the shape, or prices it."""
+    bound = bind_grouped_agg_stage(schema, predicate, groupby, agg_exprs)
+    return None if bound is None else bound[0]

@@ -269,12 +269,18 @@ def try_build_mesh_join_stage(spec, n_devices: int) -> Optional[MeshJoinStage]:
         cached = _JOIN_STAGE_CACHE.get(key, _UNSET)
     if cached is not _UNSET:
         return cached
+    # the shared stage says only whether the shape qualifies: it is kept
+    # under the skeleton and its expressions are the first query's. The mesh
+    # steps compile the values in (parallel/distributed.py), so the
+    # predicate, the keys and the aggregates are THIS spec's own.
     stage, grouped = build_join_stage(spec)
     mesh_stage: Optional[MeshJoinStage] = None
     if stage is not None:
+        from .stage import unwrap_aggs
+
         mesh_stage = MeshJoinStage(spec, spec.predicate,
-                                   getattr(stage, "groupby", None),
-                                   stage.aggs, n_devices, grouped)
+                                   spec.groupby if grouped else None,
+                                   unwrap_aggs(spec.aggregations), n_devices, grouped)
         for c, _src in mesh_stage.col_specs:
             dt = spec.schema[c].dtype
             if not (dt.is_numeric() or dt.is_boolean() or dt.is_temporal()):

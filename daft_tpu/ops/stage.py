@@ -190,12 +190,76 @@ def _shard_parts(res: Dict) -> List[Dict[str, Tuple[float, bool]]]:
             for s in range(len(first))]
 
 
+def stage_structure(predicate: Optional[Expression], exprs) -> Tuple[tuple, tuple]:
+    """(skeletons, literals) of a stage's expressions in slot order: the
+    predicate's literals, then each aggregate's (device/residency.
+    exprs_structure: the repo's one definition of a skeleton)."""
+    from ..device.residency import exprs_structure
+
+    return exprs_structure(([] if predicate is None else [predicate]) + list(exprs))
+
+
+def stage_literals(predicate: Optional[Expression], exprs) -> tuple:
+    """The literals of one execution, as a run takes them (start_run)."""
+    return stage_structure(predicate, exprs)[1]
+
+
+def note_program_trace() -> None:
+    """Called inside the traced function of a stage program: counts the
+    programs traced (a new shape, bucket or mesh width), never a launch."""
+    counters.bump("device_stage_program_traces")
+
+
+class _LiteralBinding:
+    """What a run holds of its execution's literals: the (dtype-repr, value)
+    pairs until the first dispatch, then the arrays the program takes them
+    as, packed once for all of the run's dispatches. A launch carries them as
+    host arrays (one small transfer inside the call). A filter-aggregate
+    run's are the same at every dispatch, so one that dispatches a second
+    time on a single device puts them there once and every later launch
+    passes those: a run of one dispatch, the common one over a resident
+    table, pays the one transfer in its call (an explicit device_put costs
+    more than that: 0.7 against 0.46 ms on the chip's host, PERF.md section 6)
+    and a run of many pays two. Not over a mesh: a program compiled for host
+    arrays is traced and compiled again for arrays committed to the mesh.
+    Nor where a launch's run values travel with them (a grouped run's row
+    offset is another number at every launch)."""
+
+    def __init__(self, slots: dev.LiteralSlots, literals: Sequence):
+        self._slots = slots
+        self._literals = tuple(literals)
+        self._packed: Optional[tuple] = None
+        self._launches = 0
+        self._on_device: Optional[tuple] = None
+
+    def args(self, run_values: Sequence[int] = (), mesh=None) -> tuple:
+        """The program's literal argument for one launch (dev.LiteralSlots:
+        one small array, whatever the number of literals), with the launch's
+        run values where the program takes any."""
+        slots = self._slots
+        if self._packed is None:
+            with profile_span("device.literals", "host", slots=slots.n_args):
+                self._packed = slots.pack(self._literals)
+        counters.bump("device_literal_args", slots.n_args)
+        self._launches += 1
+        host = slots.with_run_values(self._packed, run_values)
+        if slots.run_values or mesh is not None or self._launches == 1:
+            return host
+        if self._on_device is None:
+            with profile_span("device.literals", "host", slots=slots.n_args):
+                self._on_device = jax.device_put(host)
+        return self._on_device
+
+
 class FilterAggStage:
     """Compiled scan→filter→ungrouped-agg program (the TPC-H Q6 shape).
 
     Immutable + shareable: holds only the expression structure and the jit
-    cache. Call start_run() for a fresh accumulator, feed it batches, then
-    finalize().
+    cache. The program is compiled for the expressions' skeleton: a literal's
+    value is an argument of it (dev.LiteralSlots), so the expressions kept
+    here serve for their structure alone and no value of theirs is read.
+    Call start_run(literals) for a fresh accumulator bound to one execution's
+    values, feed it batches, then finalize().
     """
 
     def __init__(self, schema: Schema, predicate: Optional[Expression],
@@ -211,6 +275,10 @@ class FilterAggStage:
         self._use_f64 = any(
             agg.op in ("min", "max") and agg.child.to_field(schema).dtype.is_floating()
             for _n, agg in self.aggs)
+        self._slot_exprs = ([] if predicate is None else [predicate]) \
+            + [agg.child for _n, agg in self.aggs]
+        self.slots = dev.LiteralSlots(
+            self._slot_exprs, jnp.float64 if self._use_f64 else jnp.float32)
 
     def _referenced_columns(self) -> List[str]:
         cols: List[str] = []
@@ -223,33 +291,37 @@ class FilterAggStage:
                     cols.append(c)
         return cols
 
-    def start_run(self, mesh_devices: int = 1) -> "FilterAggRun":
-        """A fresh accumulator; with `mesh_devices` > 1 its dispatches shard
-        each batch's rows over that many local devices."""
-        return FilterAggRun(self, mesh_devices)
+    def start_run(self, literals: Sequence = (), mesh_devices: int = 1) -> "FilterAggRun":
+        """A fresh accumulator for one execution, whose literal values are
+        `literals` (stage_literals of that execution's predicate and
+        aggregates); with `mesh_devices` > 1 its dispatches shard each
+        batch's rows over that many local devices."""
+        return FilterAggRun(self, literals, mesh_devices)
 
     def _build(self, mesh=None) -> Callable:
         """The program of one chip's rows; over `mesh`, every device runs it
-        on its shard and the partials come back one a shard (over_shards)."""
-        schema = self.schema
+        on its shard and the partials come back one a shard (over_shards).
+        Its last argument is the literals' values (LiteralSlots.pack), whole
+        on every shard."""
         fdt = jnp.float64 if self._use_f64 else jnp.float32
-        pred_fn = (dev.build_device_expr(self.predicate, schema, float_dtype=fdt)
-                   if self.predicate is not None else None)
+        slots = self.slots
+        pred_fn, child_fns = compile_stage_exprs(self, fdt)
         agg_specs = []
-        for name, agg in self.aggs:
-            child_fn = dev.build_device_expr(agg.child, schema, float_dtype=fdt)
+        for (name, agg), child_fn in zip(self.aggs, child_fns):
             count_all = agg.op == "count" and agg.params.get("mode", "valid") == "all"
             agg_specs.append((name, agg.op, count_all, child_fn))
 
-        def stage(cols: Dict[str, dev.DCol], row_mask):
+        def stage(cols: Dict[str, dev.DCol], row_mask, lit_args):
+            note_program_trace()
+            lits = slots.unpack(lit_args)
             if pred_fn is not None:
-                pv, pm = pred_fn(cols)
+                pv, pm = pred_fn(cols, lits)
                 keep = pv.astype(bool) & pm & row_mask
             else:
                 keep = row_mask
             out = {}
             for name, op, count_all, child_fn in agg_specs:
-                v, m = child_fn(cols)
+                v, m = child_fn(cols, lits)
                 m = dev._broadcast_valid(v, m) & keep
                 if count_all:
                     m = dev._broadcast_valid(v, keep)
@@ -258,7 +330,8 @@ class FilterAggStage:
                     out[(name, partial_op)] = (val, ok)
             return out
 
-        return jax.jit(stage if mesh is None else over_shards(stage, mesh))
+        return jax.jit(stage if mesh is None
+                       else over_shards(stage, mesh, replicated_tail=1))
 
     def _jit_for(self, bucket: int, mesh_devices: int = 1) -> Callable:
         # one program serves every bucket (shapes differ per call; jit retraces
@@ -270,17 +343,29 @@ class FilterAggStage:
         return self._jitted[key]
 
 
+def compile_stage_exprs(stage, fdt) -> Tuple[Optional[Callable], List[Callable]]:
+    """(predicate, [an aggregate's input, in stage.aggs order]) of a filter-
+    or grouped-aggregate stage as fn(cols, lits): the skeletons, their literal
+    slots numbered as stage.slots packs them."""
+    fns = [dev.build_device_expr(e, stage.schema, float_dtype=fdt, first_slot=first)
+           for e, first in zip(stage._slot_exprs, stage.slots.offsets)]
+    return (None, fns) if stage.predicate is None else (fns[0], fns[1:])
+
+
 class FilterAggRun:
-    """Per-run accumulator for a FilterAggStage (fresh per query execution).
+    """Per-run accumulator for a FilterAggStage (fresh per query execution),
+    bound to that execution's literal values.
 
     feed only *dispatches* (async); per-batch partial pytrees stay on device
     until finalize(), which fetches them all in ONE device_get — the d2h round
     trip is paid once per run, not once per batch.
     """
 
-    def __init__(self, stage: FilterAggStage, mesh_devices: int = 1):
+    def __init__(self, stage: FilterAggStage, literals: Sequence = (),
+                 mesh_devices: int = 1):
         self.stage = stage
         self.mesh_devices = max(int(mesh_devices), 1)
+        self.literals = _LiteralBinding(stage.slots, literals)
         self._device_partials: List[Dict] = []
 
     def _run(self, dcols: Dict[str, dev.DCol], n: int, bucket: int,
@@ -292,9 +377,10 @@ class FilterAggRun:
                           rows=n, bucket=bucket):
             prog = self.stage._jit_for(bucket, ndev)
             mask = device_row_mask(n, bucket, mesh)
+            lit_args = self.literals.args(mesh=mesh)
             with profile_span("device.launch", "device", op="filter_agg",
                               bucket=bucket, devices=ndev):
-                res = prog(dcols, mask)
+                res = prog(dcols, mask, lit_args)
         counters.bump("device_stage_batches")
         if ndev > 1:
             note_mesh_dispatch(ndev)
@@ -447,43 +533,87 @@ class DispatchCoalescer:
 _STAGE_CACHE: Dict[tuple, FilterAggStage] = {}
 
 
-def stage_cache_key(schema: Schema, predicate, exprs) -> tuple:
+def stage_cache_key(schema: Schema, predicate, exprs, static: tuple = (),
+                    structure: Optional[Tuple[tuple, tuple]] = None) -> tuple:
+    """What a compiled stage is cached under: the schema, the skeletons of
+    the predicate and the aggregates (literals masked), each literal's dtype
+    and whether it is null (`x < 5` and `x < 5.0` are two programs, a null
+    literal stays a constant of its program), and whatever else of the
+    expressions sets the program's structure (`static`). No literal's value:
+    the cache is bounded by the query shapes a session uses. `structure` is
+    stage_structure(predicate, exprs) where the caller has it already."""
+    skels, lits = structure or stage_structure(predicate, exprs)
     return (
         tuple((f.name, repr(f.dtype)) for f in schema),
-        repr(predicate),
-        tuple(repr(e) for e in exprs),
+        predicate is not None,
+        skels,
+        tuple((dtype, value is None) for dtype, value in lits),
+        static,
     )
 
 
-def try_build_filter_agg_stage(schema: Schema, predicate: Optional[Expression],
-                               agg_exprs: Sequence[Expression]) -> Optional[FilterAggStage]:
-    """Build a device stage for filter+ungrouped-agg if every expression qualifies.
-
-    Stages (compiled programs only — no run state) are cached by
-    (schema, predicate, aggs) structure so repeated runs of the same query reuse
-    the jitted executables instead of retracing.
-    """
-    key = stage_cache_key(schema, predicate, agg_exprs)
-    if key in _STAGE_CACHE:
-        return _STAGE_CACHE[key]
-    if predicate is not None and not dev.is_device_evaluable(predicate, schema):
-        return None
+def unwrap_aggs(agg_exprs: Sequence[Expression]) -> Optional[List[Tuple[str, AggExpr]]]:
+    """[(output name, aggregate)] of a stage's aggregate expressions, their
+    aliases taken off; None where one of them is no aggregate."""
     aggs: List[Tuple[str, AggExpr]] = []
     for e in agg_exprs:
-        name = e.name()
         inner = e
         while isinstance(inner, Alias):
             inner = inner.child
         if not isinstance(inner, AggExpr):
             return None
-        if inner.op not in ("sum", "mean", "min", "max", "count"):
+        aggs.append((e.name(), inner))
+    return aggs
+
+
+def device_aggs(schema: Schema, agg_exprs: Sequence[Expression]
+                ) -> Optional[List[Tuple[str, AggExpr]]]:
+    """unwrap_aggs where every aggregate is one the device stages compute
+    over an input they can evaluate; else None."""
+    aggs = unwrap_aggs(agg_exprs)
+    for _name, agg in aggs or ():
+        if agg.op not in ("sum", "mean", "min", "max", "count"):
             return None
-        if inner.op == "count" and inner.params.get("mode", "valid") == "null":
+        if agg.op == "count" and agg.params.get("mode", "valid") == "null":
             return None
-        if not dev.is_device_evaluable(inner.child, schema):
+        if not dev.is_device_evaluable(agg.child, schema):
             return None
-        aggs.append((name, inner))
-    stage = FilterAggStage(schema, predicate, aggs)
-    with _CACHE_LOCK:
-        _STAGE_CACHE[key] = stage
-    return stage
+    return aggs
+
+
+def bind_filter_agg_stage(schema: Schema, predicate: Optional[Expression],
+                          agg_exprs: Sequence[Expression]
+                          ) -> Optional[Tuple[FilterAggStage, tuple]]:
+    """(stage, literals) for filter+ungrouped-agg if every expression
+    qualifies: the compiled stage of the expressions' shape and the values of
+    THESE expressions' literals, which a run of it takes (start_run). One walk
+    of the expressions gives both.
+
+    Stages (compiled programs only — no run state, no literal value) are
+    cached by the (schema, predicate, aggs) skeletons, so runs of a query
+    with whatever literal values reuse the jitted executables instead of
+    retracing. Whoever needs an expression WITH its values (another tier's
+    program, a selectivity) reads the query's own, never `stage.predicate`
+    or `stage.aggs`, which are those of the first query of the shape.
+    """
+    structure = stage_structure(predicate, agg_exprs)
+    key = stage_cache_key(schema, predicate, agg_exprs, structure=structure)
+    stage = _STAGE_CACHE.get(key)
+    if stage is None:
+        if predicate is not None and not dev.is_device_evaluable(predicate, schema):
+            return None
+        aggs = device_aggs(schema, agg_exprs)
+        if aggs is None:
+            return None
+        stage = FilterAggStage(schema, predicate, aggs)
+        with _CACHE_LOCK:
+            _STAGE_CACHE[key] = stage
+    return stage, structure[1]
+
+
+def try_build_filter_agg_stage(schema: Schema, predicate: Optional[Expression],
+                               agg_exprs: Sequence[Expression]) -> Optional[FilterAggStage]:
+    """bind_filter_agg_stage's stage alone: for whoever asks whether the
+    device can run the shape, or prices it."""
+    bound = bind_filter_agg_stage(schema, predicate, agg_exprs)
+    return None if bound is None else bound[0]

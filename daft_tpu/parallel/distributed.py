@@ -106,10 +106,10 @@ def sharded_filter_agg_step(mesh: Mesh, schema: Schema, predicate: Optional[Expr
     With row-sharded inputs, XLA lowers the reductions to per-shard partials plus a
     psum over ICI — no explicit collective code needed beyond the sharding contract.
     """
-    pred_fn = dev.build_device_expr(predicate, schema) if predicate is not None else None
+    pred_fn = dev.build_constant_device_expr(predicate, schema) if predicate is not None else None
     agg_specs = []
     for name, agg in aggs:
-        child_fn = dev.build_device_expr(agg.child, schema)
+        child_fn = dev.build_constant_device_expr(agg.child, schema)
         count_all = agg.op == "count" and agg.params.get("mode", "valid") == "all"
         agg_specs.append((name, agg.op, count_all, child_fn))
 
@@ -413,9 +413,9 @@ def sharded_join_ungrouped_stage_step(mesh: Mesh, schema: Schema,
     Returns fn(row_mask, idxs_tuple, *flat) -> {(name, partial): (val, ok)}
     replicated — combined across batches with ops.stage._combine_partials.
     """
-    pred_fn = dev.build_device_expr(predicate, schema) \
+    pred_fn = dev.build_constant_device_expr(predicate, schema) \
         if predicate is not None else None
-    built = [(name, op, count_all, dev.build_device_expr(child, schema))
+    built = [(name, op, count_all, dev.build_constant_device_expr(child, schema))
              for name, op, count_all, child in agg_specs]
     col_specs = tuple((str(n), int(s)) for n, s in col_specs)
 
@@ -479,9 +479,9 @@ def sharded_join_grouped_stage_step(mesh: Mesh, schema: Schema,
       (rows[cap] int64, overflow scalar, ((vals[cap], ok[cap]) per slot))
     replicated; rows = real joined rows per group (group_valid = rows > 0).
     """
-    pred_fn = dev.build_device_expr(predicate, schema) \
+    pred_fn = dev.build_constant_device_expr(predicate, schema) \
         if predicate is not None else None
-    built = [(op, count_all, dev.build_device_expr(child, schema))
+    built = [(op, count_all, dev.build_constant_device_expr(child, schema))
              for op, count_all, child in slot_specs]
     col_specs = tuple((str(n), int(s)) for n, s in col_specs)
     cap1 = capacity + 1  # spare slot: masked/garbage codes land there

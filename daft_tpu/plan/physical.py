@@ -233,7 +233,7 @@ class DeviceFilterAgg(_Unary):
 
     def __init__(self, input: PhysicalPlan, predicate: Optional[Expression],
                  aggregations: List[Expression], schema: Schema,
-                 region_ops=None):
+                 region_ops=None, bound_stage=None):
         super().__init__(input, schema)
         self.predicate = predicate
         self.aggregations = aggregations
@@ -241,6 +241,18 @@ class DeviceFilterAgg(_Unary):
         # ("filter", "project", "agg") — attribution + EXPLAIN only; the
         # fused semantics live in predicate/aggregations themselves.
         self.region_ops = tuple(region_ops) if region_ops else None
+        self._bound_stage = bound_stage  # where the translation has it
+
+    def bound_stage(self):
+        """(compiled stage of this node's shape, this node's literal values)
+        (ops/stage.bind_filter_agg_stage), assembled once a node: the
+        placement deciders and the run of one execution share it."""
+        if self._bound_stage is None:
+            from ..ops.stage import bind_filter_agg_stage
+
+            self._bound_stage = bind_filter_agg_stage(
+                self.input.schema, self.predicate, self.aggregations)
+        return self._bound_stage
 
     def name(self) -> str:
         if self.region_ops and len(self.region_ops) > 2:
@@ -305,12 +317,23 @@ class DeviceGroupedAgg(_Unary):
 
     def __init__(self, input: PhysicalPlan, predicate: Optional[Expression],
                  groupby: List[Expression], aggregations: List[Expression], schema: Schema,
-                 region_ops=None):
+                 region_ops=None, bound_stage=None):
         super().__init__(input, schema)
         self.predicate = predicate
         self.groupby = groupby
         self.aggregations = aggregations
         self.region_ops = tuple(region_ops) if region_ops else None
+        self._bound_stage = bound_stage  # where the translation has it
+
+    def bound_stage(self):
+        """(compiled stage of this node's shape, this node's literal values)
+        (ops/grouped_stage.bind_grouped_agg_stage), assembled once a node."""
+        if self._bound_stage is None:
+            from ..ops.grouped_stage import bind_grouped_agg_stage
+
+            self._bound_stage = bind_grouped_agg_stage(
+                self.input.schema, self.predicate, self.groupby, self.aggregations)
+        return self._bound_stage
 
     def name(self) -> str:
         if self.region_ops and len(self.region_ops) > 2:
@@ -608,26 +631,26 @@ def translate(plan: lp.LogicalPlan, config: Any = None) -> PhysicalPlan:
                                        plan.aggregations, ops)]
             for cand in cands:
                 if plan.groupby:
-                    from ..ops.grouped_stage import try_build_grouped_agg_stage
+                    from ..ops.grouped_stage import bind_grouped_agg_stage
 
-                    if try_build_grouped_agg_stage(
+                    bound = bind_grouped_agg_stage(
                         cand.source.schema, cand.predicate, cand.groupby,
-                        cand.aggregations
-                    ) is not None:
+                        cand.aggregations)
+                    if bound is not None:
                         return DeviceGroupedAgg(
                             translate(cand.source, config), cand.predicate,
                             cand.groupby, cand.aggregations, plan.schema,
-                            region_ops=cand.ops)
+                            region_ops=cand.ops, bound_stage=bound)
                 else:
-                    from ..ops.stage import try_build_filter_agg_stage
+                    from ..ops.stage import bind_filter_agg_stage
 
-                    if try_build_filter_agg_stage(
-                        cand.source.schema, cand.predicate, cand.aggregations
-                    ) is not None:
+                    bound = bind_filter_agg_stage(
+                        cand.source.schema, cand.predicate, cand.aggregations)
+                    if bound is not None:
                         return DeviceFilterAgg(
                             translate(cand.source, config), cand.predicate,
                             cand.aggregations, plan.schema,
-                            region_ops=cand.ops)
+                            region_ops=cand.ops, bound_stage=bound)
         child = translate(plan.input, config)
         if plan.groupby:
             return HashAggregate(child, plan.groupby, plan.aggregations, plan.schema)

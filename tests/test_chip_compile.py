@@ -168,6 +168,13 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "collective-permute
                "all-to-all")
 
 
+def _literal_args(stage, sharding):
+    """The stage's literal argument (one uint32 array of the values' words;
+    a float64 stage's float64 values in a second), whole on every device of
+    `sharding`."""
+    return tuple(_s(sharding, shape, dt) for shape, dt in stage.slots.arg_shapes())
+
+
 def _lineitem_planes(stage, sharding, rows):
     ints = {"l_shipdate", "l_suppkey"}
     return {name: (_s(sharding, (rows,), jnp.int32 if name in ints else jnp.float32),
@@ -188,11 +195,11 @@ def test_sharded_q1_program_is_the_single_chips_on_every_shard(topo, one_chip):
     over = stage._build(8, radices=(2, 1), mesh=mesh).lower(
         _lineitem_planes(stage, rows, total),
         (_s(rows, (total,), jnp.int32),) * 2, _s(rows, (total,), jnp.bool_),
-        _s(NamedSharding(mesh, P()), (), jnp.float64)).compile()
+        _literal_args(stage, NamedSharding(mesh, P()))).compile()
     single = stage._build(8, radices=(2, 1)).lower(
         _lineitem_planes(stage, one_chip, SHARD_ROWS),
         (_s(one_chip, (SHARD_ROWS,), jnp.int32),) * 2,
-        _s(one_chip, (SHARD_ROWS,), jnp.bool_), _s(one_chip, (), jnp.float64)).compile()
+        _s(one_chip, (SHARD_ROWS,), jnp.bool_), _literal_args(stage, one_chip)).compile()
     mem, mem1 = over.memory_analysis(), single.memory_analysis()
     assert mem.argument_size_in_bytes == mem1.argument_size_in_bytes
     assert mem.temp_size_in_bytes <= max(mem1.temp_size_in_bytes, 1 << 20)
@@ -223,11 +230,16 @@ def test_sharded_q6_program_lowers_without_a_collective(topo):
     mesh = Mesh(np.array(topo.devices), ("dp",))
     rows = NamedSharding(mesh, P("dp"))
     total = 4 * SHARD_ROWS
-    compiled = stage._build(mesh).lower(_lineitem_planes(stage, rows, total),
-                                        _s(rows, (total,), jnp.bool_)).compile()
+    compiled = stage._build(mesh).lower(
+        _lineitem_planes(stage, rows, total), _s(rows, (total,), jnp.bool_),
+        _literal_args(stage, NamedSharding(mesh, P()))).compile()
     mem = compiled.memory_analysis()
-    # four columns' f32 planes and validity, and the row mask, a shard
-    assert mem.argument_size_in_bytes == SHARD_ROWS * (4 * 5 + 1)
+    # four columns' f32 planes and validity, and the row mask, a shard; and
+    # the five literals' values whole, as one array of 32-bit words (two
+    # dates, two floats, and two words of an int64), padded to the chip's
+    # least allocation
+    assert stage.slots.arg_shapes() == (((6,), np.dtype("uint32")),)
+    assert 0 < mem.argument_size_in_bytes - SHARD_ROWS * (4 * 5 + 1) <= 1024
     text = compiled.as_text()
     assert not [c for c in COLLECTIVES if c in text]
     assert "f64[67108864]" not in text

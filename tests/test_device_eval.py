@@ -9,7 +9,7 @@ import jax.numpy as jnp
 
 from daft_tpu import DataType, RecordBatch
 from daft_tpu.expressions import col, lit
-from daft_tpu.ops.device_eval import build_device_expr, device_agg, is_device_evaluable
+from daft_tpu.ops.device_eval import build_constant_device_expr, device_agg, is_device_evaluable
 
 
 def run_both(batch: RecordBatch, expr):
@@ -18,7 +18,7 @@ def run_both(batch: RecordBatch, expr):
     schema = batch.schema
     names = expr.referenced_columns()
     cols = {n: batch.get_column(n).to_device() for n in names}
-    fn = build_device_expr(expr, schema)
+    fn = build_constant_device_expr(expr, schema)
     jitted = jax.jit(lambda c: fn(c))
     vals, valid = jitted(cols)
     vals = np.asarray(vals)
@@ -118,7 +118,7 @@ def test_padding_invariance():
     """
     b = RecordBatch.from_pydict({"a": [1, 2, None, 4, 0]})
     expr = (col("a") * 2 + 1).fill_null(-1)
-    fn = build_device_expr(expr, b.schema)
+    fn = build_constant_device_expr(expr, b.schema)
     v8 = fn({"a": b.get_column("a").to_device(pad_to=8)})
     v5 = fn({"a": b.get_column("a").to_device()})
     np.testing.assert_array_equal(np.asarray(v8[0])[:5], np.asarray(v5[0]))

@@ -513,12 +513,13 @@ def _two_batches():
 @pytest.mark.parametrize("form", ["select", "matmul"])
 def test_group_order_is_first_kept_occurrence_across_batches(form):
     from daft_tpu.ops.grouped_stage import GroupedAggStage
+    from daft_tpu.ops.stage import stage_literals
 
     schema, batches = _two_batches()
     stage = GroupedAggStage(schema, col("v") > 0, [col("k")],
                             [("s", col("v").sum()), ("n", col("v").count(mode="all"))])
     stage._jitted[8] = stage._build(8, form=form)
-    run = stage.start_run()
+    run = stage.start_run(stage_literals(col("v") > 0, []))
     for b in batches:
         run.feed_batch(b)
     key_rows, results = run.finalize()

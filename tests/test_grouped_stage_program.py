@@ -80,7 +80,8 @@ def _args(stage, bucket):
     ints = {"l_shipdate", "l_suppkey"}
     cols = {name: (s(jnp.int32 if name in ints else fdt), s(jnp.bool_))
             for name in stage._input_cols}
-    return cols, s(jnp.int32), s(jnp.bool_), jax.ShapeDtypeStruct((), jnp.float64)
+    lits = tuple(jax.ShapeDtypeStruct(shape, dt) for shape, dt in stage.slots.arg_shapes())
+    return cols, s(jnp.int32), s(jnp.bool_), lits
 
 
 def _program_jaxpr(fn, *args):

@@ -453,3 +453,46 @@ def test_mesh_probe_static_on_cpu_backend():
 
     ici, meshd = _probe_mesh_terms(0.001)
     assert ici == _STATIC_ICI_BPS and meshd == _STATIC_MESH_DISPATCH_S
+
+
+@pytest.mark.parametrize("grouped", [False, True], ids=["ungrouped", "grouped"])
+@pytest.mark.parametrize("mesh_devices", [8, 1], ids=["mesh", "single-chip"])
+def test_join_aggregate_input_literals_are_this_querys(star, grouped, mesh_devices):
+    """The join tiers take a query's aggregates from the query: the
+    aggregate stage that says whether the shape qualifies is kept under the
+    skeleton (ops/stage.bind_filter_agg_stage), so its expressions are those
+    of the FIRST query of the shape, and a tier that compiles values in (the
+    mesh join steps) must not read them there. Two queries of one skeleton,
+    another literal inside the aggregates' inputs and in the predicate, each
+    against the host's answer."""
+    fact, dim = star
+
+    def q(scale, bias, cut):
+        j = (fact.join(dim, left_on="fk", right_on="dk")
+             .where(col("weight") < cut))
+        aggs = [(col("qty") * scale).sum().alias("s"),
+                (col("weight") + bias).mean().alias("m"),
+                (col("qty") + int(bias)).max().alias("hi")]
+        if grouped:
+            return j.groupby("grp").agg(*aggs).sort("grp")
+        return j.agg(*aggs)
+
+    values = [(2.0, 1.0, 9.0), (3.0, 5.0, 6.0), (2.0, 1.0, 9.0)]
+    with execution_config_ctx(device_mode="off"):
+        host = [q(*v).to_pydict() for v in values]
+    assert host[0]["s"] != host[1]["s"] and host[0]["m"] != host[1]["m"]
+    counters.reset()
+    with execution_config_ctx(device_mode="on", mesh_devices=mesh_devices,
+                              device_min_rows=1):
+        device = [q(*v).to_pydict() for v in values]
+    if mesh_devices > 1:
+        assert counters.mesh_join_runs == len(values), "mesh join tier never ran"
+    else:
+        assert counters.device_join_batches > 0 and counters.mesh_join_runs == 0
+    for got, want in zip(device, host):
+        assert got.get("grp") == want.get("grp")
+        assert got["hi"] == want["hi"]
+        np.testing.assert_allclose(np.array(got["s"], dtype=float),
+                                   np.array(want["s"], dtype=float), rtol=1e-6)
+        np.testing.assert_allclose(np.array(got["m"], dtype=float),
+                                   np.array(want["m"], dtype=float), rtol=1e-6)

@@ -242,8 +242,10 @@ def test_mesh_empty_after_filter():
 
 
 def _q1_shaped_stage(tpch):
-    """q1's stage, as the executor builds it, and lineitem's one batch."""
+    """q1's stage, as the executor builds it, the literal values of this q1
+    (a run takes them), and lineitem's one batch."""
     from daft_tpu.ops.grouped_stage import try_build_grouped_agg_stage
+    from daft_tpu.ops.stage import stage_literals
 
     node = _device_agg_node(tpch["queries"].q1(tpch["tables"]))
     stage = try_build_grouped_agg_stage(node.input.schema, node.predicate,
@@ -251,7 +253,7 @@ def _q1_shaped_stage(tpch):
     assert stage is not None and stage.dict_keys and not stage._use_f64
     (part,) = node.input.partitions
     (batch,) = part.batches
-    return stage, batch
+    return stage, stage_literals(node.predicate, node.aggregations), batch
 
 
 def _device_agg_node(df):
@@ -272,11 +274,11 @@ def test_shard_tables_add_up_to_the_single_chips(tpch, ndev):
     positions lie inside that shard's stretch of the batch."""
     from daft_tpu.ops.stage import mesh_total
 
-    stage, batch = _q1_shaped_stage(tpch)
-    run = stage.start_run(mesh_devices=ndev)
+    stage, literals, batch = _q1_shaped_stage(tpch)
+    run = stage.start_run(literals, mesh_devices=ndev)
     run.feed_batch(batch)
     (out, decode), = run._pending
-    one = stage.start_run()
+    one = stage.start_run(literals)
     one.feed_batch(batch)
     (single, _), = one._pending
     mm, single_mm = np.asarray(out["mm"]), np.asarray(single["mm"])
@@ -319,8 +321,8 @@ def test_no_operand_at_row_width_is_float64_or_int64(tpch, ndev):
     sharded = [a for a in long if len(a.sharding.device_set) == ndev]
     assert len(sharded) >= 7, "the planes are not sharded over the mesh"
 
-    stage, batch = _q1_shaped_stage(tpch)
-    run = stage.start_run(mesh_devices=ndev)
+    stage, literals, batch = _q1_shaped_stage(tpch)
+    run = stage.start_run(literals, mesh_devices=ndev)
     seen = {}
     real = stage._program_for
 
