@@ -248,12 +248,14 @@ class Series:
     def _upload(self, pad_to: Optional[int], f32: bool, put):
         """The one upload body behind to_device / to_device_sharded /
         to_device_replicated: the padded host planes, then `put` on each (the
-        layout's placement). A `device.upload` span while a recorder is
-        installed; the host's time in it is always counted (`h2d_upload_us`:
-        two clock reads a column, so set-up can be read from counters alone)."""
+        layout's placement): a column at a time and a transfer a plane, which
+        is how a plane that stays resident arrives (a streamed morsel's planes
+        go together: ops/stage.batch_planes). A `device.upload` span while a
+        recorder is installed; the host's time in it is always counted
+        (`h2d_upload_us`: two clock reads a column, so set-up can be read from
+        counters alone)."""
         import time
 
-        from ..observability.metrics import registry
         from ..observability.runtime_stats import profile_span
 
         t0 = time.perf_counter()
@@ -263,27 +265,34 @@ class Series:
             if sp is not None:
                 sp.args["bytes"] = int(values.nbytes) + int(validity.nbytes)
             out = put(values), put(validity)
-        registry().inc("h2d_upload_us", int((time.perf_counter() - t0) * 1e6))
+        note_upload(t0, transfers=2, planes=2)
         return out
 
-    def _padded_planes(self, pad_to: Optional[int], f32: bool):
+    def _padded_planes(self, pad_to: Optional[int], f32: bool,
+                       own_validity: bool = True):
         """Host-side (values, validity) numpy planes padded to `pad_to` rows
         (padding invalid), with the h2d byte attribution every device
         placement shares, so padding and accounting can never drift between
-        layouts."""
+        layouts. With `own_validity` false a column without nulls gives None
+        for its validity plane and counts no byte for it: the caller has that
+        plane on the device already (rows valid, padding not: a dispatch's
+        row mask)."""
         values = self.to_numpy()
         if f32 and values.dtype == np.float64:
             values = values.astype(np.float32)
-        validity = self.validity_numpy()
+        validity = self.validity_numpy() \
+            if own_validity or self.null_count() else None
         if pad_to is not None and pad_to > len(self):
             pad = pad_to - len(self)
             pad_shape = (pad,) + values.shape[1:]
             values = np.concatenate([values, np.zeros(pad_shape, dtype=values.dtype)])
-            validity = np.concatenate([validity, np.zeros(pad, dtype=bool)])
+            if validity is not None:
+                validity = np.concatenate([validity, np.zeros(pad, dtype=bool)])
         from ..observability.metrics import registry
 
         # h2d attribution: a fully-resident repeat query shows a zero delta
-        registry().inc("hbm_h2d_bytes", int(values.nbytes) + int(validity.nbytes))
+        registry().inc("hbm_h2d_bytes", int(values.nbytes)
+                       + (int(validity.nbytes) if validity is not None else 0))
         return values, validity
 
     def to_device_sharded(self, mesh, pad_to: int, f32: bool = False,
@@ -321,6 +330,12 @@ class Series:
         sharding = NamedSharding(mesh, PartitionSpec())
         return self._upload(pad_to, f32, lambda a: jax.device_put(a, sharding))
 
+    @staticmethod
+    def plane_slot(pad_to: Optional[int], f32: bool) -> tuple:
+        """The residency slot key of a column's (values, validity) planes on
+        one device."""
+        return ("col", pad_to, bool(f32))
+
     def to_device_cached(self, pad_to: Optional[int] = None, f32: bool = False,
                          mesh=None, axis: str = "dp", replicated: bool = False):
         """to_device through the process-wide HBM residency manager.
@@ -340,7 +355,7 @@ class Series:
 
         if mesh is None:
             return manager().get_or_build(
-                self, ("col", pad_to, bool(f32)), (),
+                self, self.plane_slot(pad_to, f32), (),
                 lambda: self.to_device(pad_to, f32=f32))
         if replicated:
             key = ("col", pad_to, bool(f32), "meshR", int(mesh.shape[axis]),
@@ -379,7 +394,7 @@ class Series:
             fam = "meshR" if replicated else "mesh"
             return manager().is_resident(
                 self, ("col", pad_to, bool(f32), fam, int(mesh_devices), axis))
-        return manager().is_resident(self, ("col", pad_to, bool(f32)))
+        return manager().is_resident(self, self.plane_slot(pad_to, f32))
 
     def content_fingerprint(self) -> Optional[int]:
         """64-bit CONTENT hash of this column (dtype + length + values +
@@ -1019,6 +1034,23 @@ class Series:
 
 
 # ---- helpers ---------------------------------------------------------------------
+
+
+def note_upload(t0: Optional[float], transfers: int, planes: int) -> None:
+    """Count an upload on the h2d path: the calls that moved host planes to
+    the device and the planes they carried (planes over transfers says how
+    many travel together: 1 where a plane is put by itself), and the host's
+    seconds in it since `t0`, a `time.perf_counter()` reading (None: a plane
+    whose time was never part of `h2d_upload_us`)."""
+    import time
+
+    from ..observability.metrics import registry
+
+    reg = registry()
+    if t0 is not None:
+        reg.inc("h2d_upload_us", int((time.perf_counter() - t0) * 1e6))
+    reg.inc("h2d_transfers", transfers)
+    reg.inc("h2d_planes", planes)
 
 
 def _repeat_array(a: pa.Array, n: int) -> pa.Array:

@@ -342,6 +342,21 @@ class _Entry:
         self.cost = cost        # estimated rebuild seconds (eviction ordering)
 
 
+class _Miss:
+    """A slot a lookup did not find: where its value goes once built."""
+
+    __slots__ = ("owner", "anchor", "full_key", "deps", "literals", "stable")
+
+    def __init__(self, owner, anchor, full_key: tuple, deps: tuple, literals,
+                 stable: Optional[int]):
+        self.owner = owner
+        self.anchor = anchor
+        self.full_key = full_key
+        self.deps = deps
+        self.literals = literals
+        self.stable = stable
+
+
 class ResidencyManager:
     """Process-wide registry of device-resident buffers with LRU eviction."""
 
@@ -395,6 +410,67 @@ class ResidencyManager:
         `rebuild_rows` is the host-side row count the build re-factorizes
         (dictionary codes, join indices); with the entry's device bytes it
         prices the rebuild for cost-weighted eviction."""
+        hit, found = self._lookup(anchor, key, deps, literals)
+        if hit:
+            return found
+        # outside the lock: builds may re-enter the manager. The span names
+        # the slot kind (key[0]: "col", "didx", "pack", ...) behind a miss
+        with profile_span("residency.build", "device", slot=str(key[0])) as sp:
+            value = build()
+            nb = device_nbytes(value)
+            if sp is not None:
+                sp.args["bytes"] = nb
+        with self._lock:
+            self._store(found, value, nb, rebuild_rows)
+            self._note_bytes()
+            self._evict_over_budget()
+        return value
+
+    def get_or_build_many(self, slots, build: Callable[[list], list]) -> list:
+        """The values of the deps-free slots `slots`, (anchor, key,
+        rebuild_rows) each, in their order: every slot is probed as
+        get_or_build probes it (a hit counts, touches and pins as there), and
+        the absent ones are built by ONE call, `build(indices into slots)`,
+        which returns their values in that order, so that a caller can bring
+        what the device lacks in one transfer. Each value is then an entry of
+        its own, counted as a miss, pinned in the open scope and weighed
+        against the budget like a slot get_or_build built; the books are
+        brought up to date once for all of them."""
+        out: list = [None] * len(slots)
+        missed = []
+        for i, (anchor, key, _rows) in enumerate(slots):
+            hit, found = self._lookup(anchor, key, (), None)
+            if hit:
+                out[i] = found
+            else:
+                missed.append((i, found))
+        if not missed:
+            return out
+        indices = [i for i, _miss in missed]
+        with profile_span("residency.build", "device",
+                          slot=str(slots[indices[0]][1][0]),
+                          slots=len(indices)) as sp:
+            values = build(indices)
+            sizes = [device_nbytes(v) for v in values]
+            if sp is not None:
+                sp.args["bytes"] = sum(sizes)
+        with self._lock:
+            for (i, miss), value, nb in zip(missed, values, sizes):
+                self._store(miss, value, nb, slots[i][2])
+                out[i] = value
+            self._note_bytes()
+            self._evict_over_budget()
+        return out
+
+    def in_transient_scope(self) -> bool:
+        """True on a thread inside pin_scope(transient=True): what it builds
+        belongs to morsels that die with the query."""
+        return getattr(self._tl, "transient", False)
+
+    def _lookup(self, anchor, key: tuple, deps: tuple, literals):
+        """(True, value) where the slot is found, by identity or by a rebind
+        of equal content; else (False, the _Miss _store files the built value
+        under), with the miss counted."""
         owner, ident = data_identity(anchor)
         full_key = (ident, key)
         deps = _dep_identities(deps)
@@ -403,12 +479,12 @@ class ResidencyManager:
             e = self._entries.get(full_key)
             if e is not None and _same_deps(e.deps, deps) \
                     and e.literals == literals:
-                return self._hit(full_key, e, anchor)
+                return True, self._hit(full_key, e, anchor)
         # only now, the identity probe having missed, is the column hashed:
         # a hit never pays for a fingerprint (outside the lock: it reads the
         # whole column)
         stable = stable_slot_key(anchor, key) \
-            if not deps and not getattr(self._tl, "transient", False) else None
+            if not deps and not self.in_transient_scope() else None
         if stable is not None:
             with self._lock:
                 e = self._stable_rebind(stable, full_key, owner, anchor,
@@ -416,46 +492,42 @@ class ResidencyManager:
                 if e is not None:
                     registry().inc("hbm_cache_hits")
                     registry().inc("hbm_stable_rehits")
-                    return e.value
+                    return True, e.value
         registry().inc("hbm_cache_misses")
-        # outside the lock: builds may re-enter the manager. The span names
-        # the slot kind (key[0]: "col", "didx", "pack", ...) behind a miss
-        with profile_span("residency.build", "device", slot=str(key[0])) as sp:
-            value = build()
-            nb = device_nbytes(value)
-            if sp is not None:
-                sp.args["bytes"] = nb
+        return False, _Miss(owner, anchor, full_key, deps, literals, stable)
+
+    def _store(self, miss: "_Miss", value, nb: int, rebuild_rows: int) -> None:
+        """File a built value under the slot `miss` names and pin it in the
+        open scope (lock held; the caller notes the bytes and enforces the
+        budget afterwards)."""
         from ..ops.costmodel import rebuild_cost_estimate
 
-        cost = rebuild_cost_estimate(nb, rebuild_rows)
-        with self._lock:
-            old = self._entries.pop(full_key, None)
-            e = _Entry(deps, literals, value, nb, stable=stable, cost=cost)
-            if old is not None:
-                self._bytes -= old.nbytes
-                if old.stable is not None:
-                    self._stable.pop(old.stable, None)
-                # rebuild-in-place: active pin scopes hold this slot by KEY —
-                # the replacement inherits the pin count so it cannot be
-                # evicted mid-query and scope exits balance exactly
-                e.pins = old.pins
-            if stable is not None:
-                # a stale same-content slot under another identity (e.g. a
-                # literal change arriving via a re-unpickled anchor) would
-                # duplicate device bytes — drop it unless a query holds it
-                prev_full = self._stable.get(stable)
-                if prev_full is not None and prev_full != full_key:
-                    prev = self._entries.get(prev_full)
-                    if prev is not None and prev.pins == 0:
-                        self._drop_entry(prev_full, prev)
-                self._stable[stable] = full_key
-            self._entries[full_key] = e
-            self._bytes += nb
-            self._watch_anchor(owner, anchor, full_key, e)
-            self._pin(full_key, e)
-            self._note_bytes()
-            self._evict_over_budget()
-        return value
+        full_key, stable = miss.full_key, miss.stable
+        old = self._entries.pop(full_key, None)
+        e = _Entry(miss.deps, miss.literals, value, nb, stable=stable,
+                   cost=rebuild_cost_estimate(nb, rebuild_rows))
+        if old is not None:
+            self._bytes -= old.nbytes
+            if old.stable is not None:
+                self._stable.pop(old.stable, None)
+            # rebuild-in-place: active pin scopes hold this slot by KEY —
+            # the replacement inherits the pin count so it cannot be
+            # evicted mid-query and scope exits balance exactly
+            e.pins = old.pins
+        if stable is not None:
+            # a stale same-content slot under another identity (e.g. a
+            # literal change arriving via a re-unpickled anchor) would
+            # duplicate device bytes — drop it unless a query holds it
+            prev_full = self._stable.get(stable)
+            if prev_full is not None and prev_full != full_key:
+                prev = self._entries.get(prev_full)
+                if prev is not None and prev.pins == 0:
+                    self._drop_entry(prev_full, prev)
+            self._stable[stable] = full_key
+        self._entries[full_key] = e
+        self._bytes += nb
+        self._watch_anchor(miss.owner, miss.anchor, full_key, e)
+        self._pin(full_key, e)
 
     def _hit(self, full_key: tuple, e: _Entry, anchor):
         """The identity probe found the entry (lock held): re-measure (values

@@ -176,6 +176,8 @@ DEVICE_COUNTER_NAMES = (
     "hbm_pins",                # entries pinned by an executing query
     "hbm_h2d_bytes",           # host->device column upload bytes
     "h2d_upload_us",           # host µs in column uploads (pad + device_put)
+    "h2d_transfers",           # calls that moved host planes to the device (upload path)
+    "h2d_planes",              # host planes those calls carried (÷ h2d_transfers: a morsel's travel together)
     "dict_encode_us",          # host µs dictionary-encoding key columns (first touch)
     "hbm_stable_rehits",       # slots rebound by content identity (repeat sub-plans)
     "hbm_evict_cost_saved",    # µs of rebuild cost avoided vs pure-LRU eviction

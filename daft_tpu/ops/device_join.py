@@ -54,9 +54,10 @@ from . import counters
 from . import device_eval as dev
 from .grouped_stage import (DeviceFallback, GroupedAggRun, GroupedAggStage,
                             MAX_MATMUL_SEGMENTS, _Decode,
-                            _pad_groups, cached_dict_code_plane, count_reduce,
+                            _pad_groups, count_reduce,
                             try_build_grouped_agg_stage)
-from .stage import FilterAggRun, FilterAggStage, device_row_mask, pad_bucket
+from .stage import (FilterAggRun, FilterAggStage, batch_planes,
+                    cached_dict_code_plane, device_row_mask, pad_bucket)
 
 
 # ======================================================================================
@@ -1215,6 +1216,12 @@ class _JoinContext:
             layouts.append((layout, code_layout, wide))
 
         dcols: Dict[str, dev.DCol] = {}
+        if perm is None:
+            # the batch's own columns as they are: one request for all
+            dcols, _codes = batch_planes(
+                batch, [name for name in needed
+                        if spec.col_side.get(name) == "fact"
+                        and name not in spec.fact_synthetic], bucket, True)
         columns = []
         for name in needed:
             side = spec.col_side.get(name)
@@ -1228,9 +1235,6 @@ class _JoinContext:
                 elif perm is not None:
                     dcols[name] = self._permuted_fact_plane(
                         batch.get_column(name), bucket, perm)
-                else:
-                    dcols[name] = batch.get_column(name).to_device_cached(
-                        bucket, f32=True)
                 continue
             if name == "__join_ok__" or side is None:
                 continue

@@ -66,8 +66,8 @@ from ..observability.runtime_stats import profile_span
 from ..schema import Schema
 from . import counters
 from . import device_eval as dev
-from .stage import (MESH_AXIS, _LiteralBinding, compile_stage_exprs, device_aggs,
-                    device_row_mask, local_mesh, mesh_total, note_mesh_dispatch,
+from .stage import (MESH_AXIS, _LiteralBinding, batch_planes, compile_stage_exprs,
+                    device_aggs, device_row_mask, local_mesh, mesh_total, note_mesh_dispatch,
                     note_program_trace, over_shards, pad_bucket, shard_rows,
                     stage_cache_key, stage_structure, unwrap_aggs)
 
@@ -166,29 +166,6 @@ def _isum_digit(v, kind: str):
         else v.astype(jnp.int64)
     u = vi - jnp.int64(int(lo))
     return ((u >> (8 * k)) & 255).astype(jnp.float32)
-
-
-def cached_dict_code_plane(src, codes: np.ndarray, rows: int, cap: int,
-                           mesh=None):
-    """Device plane of dictionary codes padded to `cap`, registered in the
-    HBM residency manager anchored on the Series (THE one implementation —
-    grouped stages and the join stage share it, so the
-    padding-rows-are-code-0 invariant lives in one place). With `mesh` the
-    plane is row-sharded over it, under a slot key of its own like a column
-    plane's (Series.to_device_cached)."""
-    from ..device.residency import manager
-
-    def build():
-        padded = np.zeros(cap, dtype=np.int32)
-        padded[:rows] = codes
-        return jnp.asarray(padded) if mesh is None \
-            else shard_rows(mesh, padded, cap)
-
-    key = ("dictcodes", cap) if mesh is None else \
-        ("dictcodes", cap, "mesh", int(mesh.shape[MESH_AXIS]), MESH_AXIS)
-    # rebuild_rows: losing this plane re-runs the host dictionary factorize
-    # over the source rows — weigh that in cost-ordered eviction
-    return manager().get_or_build(src, key, (), build, rebuild_rows=rows)
 
 
 def resolve_key_series(batch, groupby, n: int):
@@ -1116,19 +1093,20 @@ class GroupedAggRun:
         bucket = pad_bucket(n) if mesh is None else mesh_total(n, ndev)
         decode = self._codes_for(batch, n, bucket, mesh)
         decode.shards = ndev
-        by_dict = decode.code_planes is not None
+        by_dict = decode.key_codes is not None
         prog, form = stage._program_for(
             decode.cap, n, tuple(decode.radices) if by_dict else (), ndev)
+        with profile_span("device.h2d", "device", rows=n, bucket=bucket):
+            # the keys' code planes come with the columns (a slot a Series)
+            dcols, code_planes = batch_planes(
+                batch, stage._input_cols, bucket, not stage._use_f64, mesh,
+                key_codes=decode.key_codes or ())
         if not by_dict:
             codes = decode.dcodes
         elif form in ("select", "matmul"):
-            codes = decode.code_planes
+            codes = tuple(code_planes)
         else:  # the other tiers take the segment ids: combined here, eagerly
-            codes = sum(c * r for c, r in zip(decode.code_planes, decode.radices))
-        with profile_span("device.h2d", "device", rows=n, bucket=bucket):
-            dcols = {name: batch.get_column(name).to_device_cached(
-                         bucket, f32=not stage._use_f64, mesh=mesh)
-                     for name in stage._input_cols}
+            codes = sum(c * r for c, r in zip(code_planes, decode.radices))
         with profile_span("device.dispatch", "device", op="grouped_agg",
                           rows=n, bucket=bucket, groups_cap=decode.cap):
             mask = device_row_mask(n, bucket, mesh)
@@ -1165,21 +1143,20 @@ class GroupedAggRun:
                 total *= max(k, 1)
             if 0 < total <= MAX_SORT_SEGMENTS:
                 cap = _pad_groups(total)
-                # per-column code planes on the device (cached per Series)
-                dcode_cols = [cached_dict_code_plane(s, codes, n, bucket, mesh)
-                              for s, (codes, _, _) in zip(key_series, encoded)]
                 radices = []
                 mult = 1
                 for _, _, k in reversed(encoded):
                     radices.append(mult)
                     mult *= max(k, 1)
                 radices.reverse()
-                # the segment id is the radix sum of the planes: the one-hot
-                # tier's program takes it a tile at a time (feed_batch)
+                # the segment id is the radix sum of the keys' code planes,
+                # which feed_batch brings to the device with the batch's
+                # columns: the one-hot tier's program takes it a tile at a time
                 return _Decode(cap=cap, dcodes=None,
                                dicts=[(vals, k) for _, vals, k in encoded],
                                radices=radices, key_rows=None,
-                               code_planes=tuple(dcode_cols))
+                               key_codes=[(s, codes) for s, (codes, _, _)
+                                          in zip(key_series, encoded)])
 
         # fallback: host factorize of the full key rows for this batch (cached on
         # the batch so repeated queries over resident tables skip re-factorizing)
@@ -1407,10 +1384,12 @@ class _Decode:
 
     def __init__(self, cap: int, dcodes, dicts, radices, key_rows,
                  fact_codes=None, local_codes=None, seg_lo=None,
-                 host_firsts=None, pperm=None, code_planes=None):
+                 host_firsts=None, pperm=None, key_codes=None):
         self.cap = cap
-        self.dcodes = dcodes        # segment-id plane (None with code_planes)
-        self.code_planes = code_planes  # per key column (dict mode, unjoined)
+        self.dcodes = dcodes        # segment-id plane (None with key_codes)
+        # [(key Series, its rows' dictionary codes on the host)] (dict mode,
+        # unjoined): their device planes travel with the batch's columns
+        self.key_codes = key_codes
         self.dicts = dicts          # [(values, K)] per key column (dict mode)
         self.radices = radices
         self.key_rows = key_rows    # first-occurrence key tuples (host mode)

@@ -102,6 +102,112 @@ def shard_rows(mesh, arr: np.ndarray, total: int):
     return _row_sharded(mesh, arr)
 
 
+def _padded_codes(codes: np.ndarray, rows: int, cap: int) -> np.ndarray:
+    """Dictionary codes as the device takes them: int32, padded to `cap` with
+    code 0 (THE one place the padding-rows-are-code-0 invariant lives). Codes
+    that fill their bucket go as they are, uncopied."""
+    if rows == cap:
+        return np.ascontiguousarray(codes, dtype=np.int32)
+    padded = np.zeros(cap, dtype=np.int32)
+    padded[:rows] = codes
+    return padded
+
+
+def _codes_slot(cap: int, mesh=None) -> tuple:
+    """The residency slot key of a Series' dictionary code plane."""
+    return ("dictcodes", cap) if mesh is None else \
+        ("dictcodes", cap, "mesh", int(mesh.shape[MESH_AXIS]), MESH_AXIS)
+
+
+def cached_dict_code_plane(src, codes: np.ndarray, rows: int, cap: int,
+                           mesh=None):
+    """Device plane of dictionary codes padded to `cap`, registered in the
+    HBM residency manager anchored on the Series (grouped stages and the
+    join stage share it). With `mesh` the plane is row-sharded over it, under
+    a slot key of its own like a column plane's (Series.to_device_cached)."""
+    from ..core.series import note_upload
+    from ..device.residency import manager
+
+    def build():
+        padded = _padded_codes(codes, rows, cap)
+        note_upload(None, transfers=1, planes=1)
+        return jnp.asarray(padded) if mesh is None \
+            else shard_rows(mesh, padded, cap)
+
+    # rebuild_rows: losing this plane re-runs the host dictionary factorize
+    # over the source rows — weigh that in cost-ordered eviction
+    return manager().get_or_build(src, _codes_slot(cap, mesh), (), build,
+                                  rebuild_rows=rows)
+
+
+def batch_planes(batch, names: Sequence[str], bucket: int, f32: bool,
+                 mesh=None, key_codes: Sequence[Tuple[object, np.ndarray]] = ()):
+    """What a dispatch over `batch` reads from the device: ({name: (values,
+    validity)} for the columns `names`, padded to `bucket` rows; [a code
+    plane for each (key Series, its rows' dictionary codes) of `key_codes`]).
+    Every plane is looked up in the residency manager, and the device is
+    brought what it lacks; how follows the lifetime of the data.
+
+    Planes that stay (a resident table at first touch, a mesh placement) are
+    built a column at a time, each with a validity plane of its own, by
+    Series.to_device_cached and cached_dict_code_plane: the tiers that read
+    them later take a column's own validity, and a table's padded host planes
+    are never all held at once. Planes of a streamed morsel, which die with
+    the query (ResidencyManager.pin_scope(transient=True)), cost a fixed price
+    a transfer and not their bytes, so what the morsel lacks is padded first
+    and moved in ONE transfer, the code planes with it; and a column without
+    nulls uploads no validity plane at all: on the device its validity is
+    the dispatch's row mask (rows valid, padding not), the array the program
+    is passed as `row_mask` anyway. Each plane is still a slot of its own in
+    the manager, pinned for the scope and weighed against the budget."""
+    import time
+
+    from ..core.series import note_upload
+    from ..device.residency import manager
+
+    n = batch.num_rows
+    cols = [batch.get_column(name) for name in names]
+    mgr = manager()
+    if mesh is not None or not mgr.in_transient_scope():
+        dcols = {name: s.to_device_cached(bucket, f32=f32, mesh=mesh)
+                 for name, s in zip(names, cols)}
+        return dcols, [cached_dict_code_plane(s, codes, n, bucket, mesh)
+                       for s, codes in key_codes]
+
+    def host_planes(i: int) -> tuple:
+        """Slot i's planes on the host: (values,) or (values, validity) of a
+        column, (codes,) of a key."""
+        if i >= len(cols):
+            return (_padded_codes(key_codes[i - len(cols)][1], n, bucket),)
+        values, validity = cols[i]._padded_planes(bucket, f32, own_validity=False)
+        return (values,) if validity is None else (values, validity)
+
+    def build(missing: List[int]) -> list:
+        t0 = time.perf_counter()
+        with profile_span("device.upload", "device", rows=n) as sp:
+            host = [host_planes(i) for i in missing]
+            flat = [p for planes in host for p in planes]
+            if sp is not None:
+                sp.args.update(bytes=sum(int(p.nbytes) for p in flat),
+                               planes=len(flat))
+            placed = iter(jax.device_put(flat))
+            row_mask = device_row_mask(n, bucket)
+            out = []
+            for i, planes in zip(missing, host):
+                first = next(placed)
+                if i >= len(cols):
+                    out.append(first)
+                else:
+                    out.append((first, next(placed) if len(planes) == 2 else row_mask))
+        note_upload(t0, transfers=1, planes=len(flat))
+        return out
+
+    slots = [(s, s.plane_slot(bucket, f32), 0) for s in cols] \
+        + [(s, _codes_slot(bucket), n) for s, _codes in key_codes]
+    planes = mgr.get_or_build_many(slots, build)
+    return dict(zip(names, planes)), planes[len(cols):]
+
+
 def mesh_total(n: int, n_devices: int) -> int:
     """Global padded row count for an n-row batch sharded over n_devices:
     each shard pads to a power-of-two bucket (jit cache stays O(log rows))."""
@@ -408,9 +514,8 @@ class FilterAggRun:
         bucket = pad_bucket(n) if mesh is None else mesh_total(n, self.mesh_devices)
         f32 = not self.stage._use_f64
         with profile_span("device.h2d", "device", rows=n, bucket=bucket):
-            dcols = {name: batch.get_column(name).to_device_cached(
-                         bucket, f32=f32, mesh=mesh)
-                     for name in self.stage._input_cols}
+            dcols, _codes = batch_planes(batch, self.stage._input_cols, bucket,
+                                         f32, mesh)
         self._run(dcols, n, bucket, mesh)
 
     def finalize(self) -> Dict[str, Optional[float]]:

@@ -705,3 +705,132 @@ def test_concurrent_lookups_of_views_keep_the_books():
     with m._lock:
         assert m._bytes == sum(device_nbytes(e.value) for e in m._entries.values())
     m.clear()
+
+
+# ---- a batch's planes asked for at once (PR 35) -----------------------------------------
+
+H2D = ("h2d_transfers", "h2d_planes", "hbm_h2d_bytes", "hbm_cache_misses", "hbm_cache_hits")
+
+
+def _h2d():
+    return {k: registry().get(k) for k in H2D}
+
+
+def test_a_resident_table_uploads_a_column_at_a_time_and_once():
+    """First touch of a table that stays: every column by itself, a transfer a
+    plane, its own validity plane among them; the second query uploads nothing."""
+    m = manager()
+    m.clear()
+    n = 3_000                                             # bucket 4,096
+    df = daft_tpu.from_pydict({
+        "g": ["a", "b", "c"] * (n // 3),
+        "v": [float(i) for i in range(n)],
+        "w": [float(i % 7) for i in range(n)],
+    }).collect()
+
+    def query():
+        return df.groupby("g").agg(col("v").sum().alias("s"), col("w").mean().alias("m")).sort("g")
+
+    with execution_config_ctx(device_mode="off"):
+        want = query().to_pydict()
+    with execution_config_ctx(device_mode="on", device_min_rows=1):
+        before = _h2d()
+        first = query().to_pydict()
+        touched = _h2d()
+        second = query().to_pydict()
+        after = _h2d()
+    assert first == second
+    assert first["g"] == want["g"] and first["s"] == pytest.approx(want["s"], rel=1e-6)
+    delta = {k: touched[k] - before[k] for k in H2D}
+    # v and w: a float32 value plane and a bool validity plane each, then g's code plane
+    assert delta["h2d_transfers"] == delta["h2d_planes"] == 2 * 2 + 1
+    assert delta["hbm_h2d_bytes"] == 2 * 4096 * (4 + 1)
+    assert delta["hbm_cache_misses"] == 3
+    again = {k: after[k] - touched[k] for k in H2D}
+    assert again["hbm_h2d_bytes"] == again["h2d_transfers"] == again["h2d_planes"] == 0
+    assert again["hbm_cache_misses"] == 0 and again["hbm_cache_hits"] == 3
+    m.clear()
+
+
+def test_a_streamed_batchs_planes_are_pinned_in_their_scope_and_go_with_it():
+    """batch_planes inside a transient scope: one transfer, the planes slots of
+    their own and pinned while the scope is open whatever the budget, evicted
+    at its exit; a column without nulls reads the dispatch's row mask."""
+    from daft_tpu.core.recordbatch import RecordBatch
+    from daft_tpu.ops.stage import batch_planes, device_row_mask
+
+    m = manager()
+    m.clear()
+    n = 700                                               # bucket 1,024
+    batch = RecordBatch.from_pydict({
+        "x": [float(i) for i in range(n)],
+        "y": [None if i % 5 == 0 else float(i) for i in range(n)],
+        "k": ["p", "q"] * (n // 2),
+    })
+    key = batch.get_column("k")
+    codes = key.dict_codes()[0]
+    with execution_config_ctx(hbm_budget_bytes=1):        # below any plane's size
+        with m.pin_scope(transient=True):
+            before = _h2d()
+            pins = registry().get("hbm_pins")
+            dcols, dcodes = batch_planes(batch, ["x", "y"], 1024, True, key_codes=[(key, codes)])
+            delta = {k: _h2d()[k] - before[k] for k in H2D}
+            assert delta["h2d_transfers"] == 1
+            assert delta["h2d_planes"] == 4               # x, y, y's validity, k's codes
+            assert delta["hbm_h2d_bytes"] == 2 * 1024 * 4 + 1024
+            assert delta["hbm_cache_misses"] == 3 and registry().get("hbm_pins") == pins + 3
+            assert m.entry_count() == 3                   # over budget, pinned, all held
+            assert dcols["x"][1] is device_row_mask(n, 1024)
+            assert dcols["y"][1] is not dcols["x"][1]
+            assert np.asarray(dcols["y"][1]).sum() == n - n // 5
+            assert np.asarray(dcodes[0])[:n].tolist() == codes.tolist()
+            assert not np.asarray(dcodes[0])[n:].any()
+            # asked again in the same scope: found, nothing moves
+            again, _ = batch_planes(batch, ["x", "y"], 1024, True, key_codes=[(key, codes)])
+            assert again["x"][0] is dcols["x"][0] and again["y"][1] is dcols["y"][1]
+            assert _h2d()["h2d_transfers"] == before["h2d_transfers"] + 1
+        assert m.entry_count() == 0                       # scope closed: budget enforced
+    m.clear()
+
+
+def test_outside_a_transient_scope_a_batchs_planes_are_built_one_by_one():
+    from daft_tpu.core.recordbatch import RecordBatch
+    from daft_tpu.ops.stage import batch_planes, device_row_mask
+
+    m = manager()
+    m.clear()
+    batch = RecordBatch.from_pydict({"x": [1.0, 2.0, 3.0], "y": [4.0, None, 6.0]})
+    before = _h2d()
+    with m.pin_scope():
+        dcols, dcodes = batch_planes(batch, ["x", "y"], 512, True)
+    delta = {k: _h2d()[k] - before[k] for k in H2D}
+    assert dcodes == []
+    assert delta["h2d_transfers"] == delta["h2d_planes"] == 4
+    assert delta["hbm_h2d_bytes"] == 2 * 512 * (4 + 1)
+    assert dcols["x"][1] is not device_row_mask(3, 512)   # a validity plane of the column's own
+    assert np.asarray(dcols["x"][1]).tolist() == [True] * 3 + [False] * 509
+    assert dcols["x"] is batch.get_column("x").to_device_cached(512, f32=True)
+    m.clear()
+
+
+def test_get_or_build_many_builds_what_is_absent_in_one_call():
+    from daft_tpu.core.series import Series
+
+    m = manager()
+    m.clear()
+    a, b, c = (Series.from_pylist([float(i)], n) for i, n in enumerate("abc"))
+    m.get_or_build(b, ("t",), (), lambda: "b-was-here")
+    calls = []
+
+    def build(missing):
+        calls.append(list(missing))
+        return [f"built-{i}" for i in missing]
+
+    slots = [(a, ("t",), 0), (b, ("t",), 0), (c, ("t",), 5)]
+    assert m.get_or_build_many(slots, build) == ["built-0", "b-was-here", "built-2"]
+    assert calls == [[0, 2]]
+    assert m.get_or_build_many(slots, build) == ["built-0", "b-was-here", "built-2"]
+    assert calls == [[0, 2]]                              # all found: no build
+    assert m.get_or_build(c, ("t",), (), lambda: "no") == "built-2"
+    assert m.entry_count() == 3
+    m.clear()

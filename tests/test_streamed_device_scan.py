@@ -254,3 +254,112 @@ def test_the_decision_prices_an_arrow_encoded_key_at_its_own_rate():
     assert _dict_build_rows([strings, ints], 1000, cal) == 1200
     strings.dict_codes()                                       # cached: nothing left to build
     assert _dict_build_rows([strings], 1000, cal) == 0
+
+
+# ---- a morsel's planes go to the device in one transfer (PR 35) -------------------------
+
+
+def ungrouped(paths):
+    return (dt.read_parquet(paths).where(col("qty") < 25.0)
+            .agg(col("price").sum().alias("p"), col("qty").mean().alias("q"),
+                 col("price").count().alias("n")))
+
+
+H2D = ("h2d_transfers", "h2d_planes", "hbm_h2d_bytes", "hbm_cache_misses", "hbm_pins")
+
+
+def on_the_device(query, paths):
+    """(answer, counter deltas, dispatches, `device.upload` spans) of `query`
+    over `paths`, forced onto the device tier, a dispatch a file."""
+    from daft_tpu.ops import counters
+
+    reg = registry()
+    before = {k: reg.get(k) for k in H2D}
+    d0 = counters.device_grouped_batches + counters.device_stage_batches
+    rec = SpanRecorder()
+    with execution_config_ctx(device_mode="on", device_min_rows=1, batch_fill_target=0.0):
+        set_spans(rec)
+        try:
+            got = query(paths).to_pydict()
+        finally:
+            set_spans(None)
+    uploads = [s for s in rec.drain() if s["name"] == "device.upload"]
+    return (got, {k: reg.get(k) - before[k] for k in H2D},
+            counters.device_grouped_batches + counters.device_stage_batches - d0, uploads)
+
+
+# the scanner applies `ungrouped`'s filter: about 2,900 of a file's 6,000 rows reach the stage
+@pytest.mark.parametrize("query, values, codes, bucket", [(grouped, 2, 2, 8192), (ungrouped, 2, 0, 4096)],
+                         ids=["grouped", "ungrouped"])
+def test_a_streamed_dispatch_makes_one_transfer_and_uploads_no_validity(tmp_path, query, values, codes,
+                                                                        bucket):
+    """No column of the files has a null: every dispatch moves its value
+    planes (and the grouped stage's two code planes) in one transfer, no
+    validity plane among them, and `hbm_h2d_bytes` counts the value planes."""
+    paths = write_files(tmp_path)
+    got, delta, dispatches, uploads = on_the_device(query, paths)
+    assert dispatches == len(paths)
+    assert delta["h2d_transfers"] == dispatches
+    assert delta["h2d_planes"] == (values + codes) * dispatches
+    assert delta["hbm_h2d_bytes"] == values * dispatches * bucket * 4   # float32 value planes alone
+    # a plane is still a slot of its own in the manager, pinned for the query
+    assert delta["hbm_cache_misses"] == delta["hbm_pins"] == (values + codes) * dispatches
+    assert [s["args"]["planes"] for s in uploads] == [values + codes] * dispatches
+    assert sum(s["args"]["bytes"] for s in uploads) == (values + codes) * dispatches * bucket * 4
+    with execution_config_ctx(device_mode="off"):
+        want = query(paths).to_pydict()
+    assert got["n"] == want["n"]
+    assert got["p"] == pytest.approx(want["p"], rel=1e-5)
+    assert got["q"] == pytest.approx(want["q"], rel=1e-5)
+
+
+def write_nullable(where, case):
+    """Two files of the grouped/ungrouped queries' columns; `case` says where
+    the nulls are and whether a file's rows fill their bucket."""
+    rows = 8192 if case == "fills_bucket" else 5000
+    rng = np.random.default_rng(11)
+    paths = []
+    for k in range(2):
+        qty = rng.integers(1, 50, rows).astype(np.float64)
+        price = rng.uniform(1, 1000, rows)
+        qty_mask = price_mask = None
+        if case == "some_nulls":
+            qty_mask, price_mask = rng.random(rows) < 0.2, rng.random(rows) < 0.3
+        elif case == "all_nulls":
+            price_mask = np.ones(rows, dtype=bool)
+        t = pa.table({
+            "flag": pa.array([("A", "N", "R")[i % 3] for i in range(rows)], pa.large_string()),
+            "status": pa.array([("F", "O")[(i // 7) % 2] for i in range(rows)], pa.string()),
+            "qty": pa.array(qty, mask=qty_mask),
+            "price": pa.array(price, mask=price_mask),
+        })
+        path = os.path.join(str(where), f"{case}.{k}.parquet")
+        pq.write_table(t, path)
+        paths.append(path)
+    return paths
+
+
+@pytest.mark.parametrize("query", [grouped, ungrouped], ids=["grouped", "ungrouped"])
+@pytest.mark.parametrize("case", ["fills_bucket", "shorter_than_bucket", "some_nulls", "all_nulls"])
+def test_a_streamed_stage_answers_as_the_host_tier_does(tmp_path, case, query):
+    """A column without nulls reads the dispatch's row mask as its validity, a
+    column with nulls its own plane from the same transfer; padding rows count
+    for nothing either way."""
+    paths = write_nullable(tmp_path, case)
+    with execution_config_ctx(device_mode="off"):
+        want = query(paths).to_pydict()
+    got, delta, dispatches, _uploads = on_the_device(query, paths)
+    assert dispatches == len(paths) and delta["h2d_transfers"] == dispatches
+    # the scanner's `qty < 25` drops the rows whose qty is null: what reaches
+    # the ungrouped stage has nulls in `price` alone, and only that is asked
+    nullable = {"some_nulls": 2 if query is grouped else 1, "all_nulls": 1}.get(case, 0)
+    codes = 2 if query is grouped else 0
+    assert delta["h2d_planes"] == (2 + nullable + codes) * dispatches
+    assert list(got) == list(want)
+    for name in want:
+        assert len(got[name]) == len(want[name])
+        for g, w in zip(got[name], want[name]):
+            if isinstance(w, float):
+                assert g == pytest.approx(w, rel=1e-5), (name, got[name], want[name])
+            else:
+                assert g == w, (name, got[name], want[name])
