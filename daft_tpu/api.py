@@ -92,9 +92,17 @@ def from_pylist(rows: List[dict]) -> DataFrame:
 
 
 def from_arrow(tables) -> DataFrame:
+    from .observability.runtime_stats import profile_span
+
     if not isinstance(tables, (list, tuple)):
         tables = [tables]
-    parts = [MicroPartition.from_arrow(t) for t in tables]
+    # a table's load: chunked columns are combined and strings widened here,
+    # outside any query (benchmark/coldreport.py reads the span)
+    with profile_span("load.from_arrow", "host") as sp:
+        parts = [MicroPartition.from_arrow(t) for t in tables]
+        if sp is not None:
+            sp.args.update(rows=sum(t.num_rows for t in tables),
+                           bytes=sum(t.nbytes for t in tables))
     return DataFrame(LogicalPlanBuilder.from_in_memory(parts[0].schema, list(parts)))
 
 

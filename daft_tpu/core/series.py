@@ -250,23 +250,34 @@ class Series:
         to_device_replicated: the padded host planes, then `put` on each (the
         layout's placement): a column at a time and a transfer a plane, which
         is how a plane that stays resident arrives (a streamed morsel's planes
-        go together: ops/stage.batch_planes). A `device.upload` span while a
-        recorder is installed; the host's time in it is always counted
-        (`h2d_upload_us`: two clock reads a column, so set-up can be read from
-        counters alone)."""
-        import time
+        go together: ops/stage.batch_planes). A `device.upload` span with
+        `device.upload.prepare` inside it while a recorder is installed; the
+        host's time in both is always counted (`h2d_upload_us`,
+        `h2d_prepare_us`: cold sites, so set-up can be read from counters
+        alone; the put is their difference)."""
+        from ..observability.runtime_stats import timed_span
 
-        from ..observability.runtime_stats import profile_span
-
-        t0 = time.perf_counter()
-        with profile_span("device.upload", "device", rows=len(self),
-                          dtype=str(self._dtype)) as sp:
-            values, validity = self._padded_planes(pad_to, f32)
-            if sp is not None:
-                sp.args["bytes"] = int(values.nbytes) + int(validity.nbytes)
+        with timed_span("device.upload", "device", counter="h2d_upload_us",
+                        rows=len(self), dtype=str(self._dtype)) as sp:
+            values, validity = self._prepared_planes(pad_to, f32)
+            sp.args["bytes"] = int(values.nbytes) + int(validity.nbytes)
             out = put(values), put(validity)
-        note_upload(t0, transfers=2, planes=2)
+        note_upload(transfers=2, planes=2)
         return out
+
+    def _prepared_planes(self, pad_to: Optional[int], f32: bool):
+        """`_padded_planes` as `_upload` calls it: the span
+        `device.upload.prepare` (what making the host planes of this column
+        cost: Arrow to numpy, the float32 cast, the pad, the validity plane)
+        and its counter `h2d_prepare_us`, a part of the upload's own."""
+        from ..observability.runtime_stats import timed_span
+
+        with timed_span("device.upload.prepare", "host",
+                        counter="h2d_prepare_us", part=True, rows=len(self),
+                        dtype=str(self._dtype), pad_to=pad_to) as sp:
+            values, validity = self._padded_planes(pad_to, f32)
+            sp.args["bytes"] = int(values.nbytes) + int(validity.nbytes)
+        return values, validity
 
     def _padded_planes(self, pad_to: Optional[int], f32: bool,
                        own_validity: bool = True):
@@ -417,6 +428,19 @@ class Series:
             return fp
         if self._pyobjs is not None or self._arrow is None:
             return None
+        from ..observability.runtime_stats import timed_span
+
+        # reads the whole column, once: a cold site (`content_hash_us`)
+        with timed_span("series.fingerprint", "host", counter="content_hash_us",
+                        rows=len(self), dtype=str(self._dtype)):
+            fp = self._hash_content()
+        if fp is not None:
+            cache["__content_fp__"] = fp
+        return fp
+
+    def _hash_content(self) -> Optional[int]:
+        """`content_fingerprint`'s hash of an Arrow-backed column; None
+        where it cannot be hashed."""
         import hashlib
 
         h = hashlib.blake2b(digest_size=8)
@@ -443,9 +467,7 @@ class Series:
                 h.update(sink.getvalue())
             except Exception:  # lint: ignore[broad-except] -- unhashable: no content fingerprint,
                 return None  # caller keys by identity instead
-        fp = int.from_bytes(h.digest(), "little")
-        cache["__content_fp__"] = fp
-        return fp
+        return int.from_bytes(h.digest(), "little")
 
     def dict_codes(self):
         """Dictionary-encode this column: (codes int32 ndarray, values list, K).
@@ -460,16 +482,13 @@ class Series:
         cached = getattr(self, "_dict_codes", None)
         if cached is not None:
             return cached
-        import time
-
-        from ..observability.metrics import registry
-        from ..observability.runtime_stats import profile_span
+        from ..observability.runtime_stats import timed_span
         from .kernels.groupby import make_groups
 
-        # first touch of a key column: a span while a recorder is installed,
-        # the host's time always (`dict_encode_us`, once per column)
-        t0 = time.perf_counter()
-        with profile_span("series.dict_encode", "host", rows=len(self)) as sp:
+        # first touch of a key column, a cold site: a span while a recorder
+        # is installed, the host's time always (`dict_encode_us`)
+        with timed_span("series.dict_encode", "host", counter="dict_encode_us",
+                        rows=len(self)) as sp:
             fast = self._arrow_dict_codes()
             if fast is not None:
                 codes, values = fast
@@ -477,9 +496,7 @@ class Series:
                 first_idx, group_ids, _ = make_groups([self])
                 codes = group_ids.astype(np.int32, copy=False)
                 values = self.take(first_idx).to_pylist()
-            if sp is not None:
-                sp.args["cardinality"] = len(values)
-        registry().inc("dict_encode_us", int((time.perf_counter() - t0) * 1e6))
+            sp.args["cardinality"] = len(values)
         out = (codes, values, len(values))
         object.__setattr__(self, "_dict_codes", out)
         return out
@@ -1036,19 +1053,13 @@ class Series:
 # ---- helpers ---------------------------------------------------------------------
 
 
-def note_upload(t0: Optional[float], transfers: int, planes: int) -> None:
+def note_upload(transfers: int, planes: int) -> None:
     """Count an upload on the h2d path: the calls that moved host planes to
     the device and the planes they carried (planes over transfers says how
-    many travel together: 1 where a plane is put by itself), and the host's
-    seconds in it since `t0`, a `time.perf_counter()` reading (None: a plane
-    whose time was never part of `h2d_upload_us`)."""
-    import time
-
+    many travel together: 1 where a plane is put by itself)."""
     from ..observability.metrics import registry
 
     reg = registry()
-    if t0 is not None:
-        reg.inc("h2d_upload_us", int((time.perf_counter() - t0) * 1e6))
     reg.inc("h2d_transfers", transfers)
     reg.inc("h2d_planes", planes)
 

@@ -86,7 +86,7 @@ from collections import OrderedDict
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
 from ..observability.metrics import registry
-from ..observability.runtime_stats import profile_span
+from ..observability.runtime_stats import timed_span
 
 # ---- identity tokens ---------------------------------------------------------------
 
@@ -415,11 +415,13 @@ class ResidencyManager:
             return found
         # outside the lock: builds may re-enter the manager. The span names
         # the slot kind (key[0]: "col", "didx", "pack", ...) behind a miss
-        with profile_span("residency.build", "device", slot=str(key[0])) as sp:
+        # (a cold site: `residency_build_us` is the build less the uploads,
+        # encodes and program builds inside it, which count themselves)
+        with timed_span("residency.build", "device", counter="residency_build_us",
+                        slot=str(key[0])) as sp:
             value = build()
             nb = device_nbytes(value)
-            if sp is not None:
-                sp.args["bytes"] = nb
+            sp.args["bytes"] = nb
         with self._lock:
             self._store(found, value, nb, rebuild_rows)
             self._note_bytes()
@@ -447,13 +449,12 @@ class ResidencyManager:
         if not missed:
             return out
         indices = [i for i, _miss in missed]
-        with profile_span("residency.build", "device",
-                          slot=str(slots[indices[0]][1][0]),
-                          slots=len(indices)) as sp:
+        with timed_span("residency.build", "device", counter="residency_build_us",
+                        slot=str(slots[indices[0]][1][0]),
+                        slots=len(indices)) as sp:
             values = build(indices)
             sizes = [device_nbytes(v) for v in values]
-            if sp is not None:
-                sp.args["bytes"] = sum(sizes)
+            sp.args["bytes"] = sum(sizes)
         with self._lock:
             for (i, miss), value, nb in zip(missed, values, sizes):
                 self._store(miss, value, nb, slots[i][2])

@@ -10,7 +10,8 @@ device/shuffle attribution lands in EXPLAIN ANALYZE and the event log.
 
 Zero-overhead contract: nothing in the engine's hot path reads the registry;
 writes only happen on coarse events (a device dispatch, a shuffle file, a
-fetch request), never per row.
+fetch request), never per row. An unobserved host-only query moves one
+counter, its own wall time (`query_wall_us`, one `inc` as it ends).
 """
 
 from __future__ import annotations
@@ -176,9 +177,21 @@ DEVICE_COUNTER_NAMES = (
     "hbm_pins",                # entries pinned by an executing query
     "hbm_h2d_bytes",           # host->device column upload bytes
     "h2d_upload_us",           # host µs in column uploads (pad + device_put)
+    "h2d_prepare_us",          # of those, µs making the padded host planes (the put is the rest)
     "h2d_transfers",           # calls that moved host planes to the device (upload path)
     "h2d_planes",              # host planes those calls carried (÷ h2d_transfers: a morsel's travel together)
     "dict_encode_us",          # host µs dictionary-encoding key columns (first touch)
+    # the other cold sites (runtime_stats.timed_span(counter=...)): host µs of
+    # work that only a first execution does, counted where it happens, each
+    # less what the cold sites inside it counted (self times: they add up)
+    "jax_trace_us",            # JAX tracing Python to jaxprs (self time: utils/jax_setup)
+    "jax_lower_us",            # JAX lowering jaxprs to MLIR modules
+    "xla_compile_us",          # XLA compiling, or retrieving from the persistent cache
+    "calibrate_us",            # the cost model's live probes (costmodel.calibrate)
+    "content_hash_us",         # hashing a column's content for its stable residency key
+    "residency_build_us",      # residency misses building their values, less the uploads,
+                               # encodes and program builds inside them
+    "query_wall_us",           # wall µs of every query (NativeRunner), warm ones too
     "hbm_stable_rehits",       # slots rebound by content identity (repeat sub-plans)
     "hbm_evict_cost_saved",    # µs of rebuild cost avoided vs pure-LRU eviction
     # distributed cache-affinity scheduling (distributed/scheduler.py)

@@ -51,6 +51,7 @@ from contextlib import contextmanager
 from typing import Dict, List, Optional
 
 from .events import OperatorStats
+from .metrics import registry
 
 _local = threading.local()
 
@@ -358,11 +359,72 @@ def profile_span(name: str, cat: str, **args):
     return _Span(rec, name, cat, args)
 
 
-def timed_span(name: str, cat: str, **args) -> _Span:
+class _ColdSpan(_Span):
+    """A cold site (`timed_span(counter=...)`): a `_Span` whose self time, in
+    microseconds, is added to a registry counter as it closes, recorder or
+    not. A warm query opens none."""
+
+    __slots__ = ("_counter", "_part")
+
+    def __init__(self, rec, name: str, cat: str, args: dict, counter: str, part: bool):
+        super().__init__(rec, name, cat, args)
+        self._counter, self._part = counter, part
+
+    def close(self) -> None:
+        super().close()
+        own = self.seconds if self._part \
+            else cold_self_seconds(self._t0, self._t0 + self.seconds)
+        registry().inc(self._counter, int(own * 1e6))
+
+
+def timed_span(name: str, cat: str, counter: Optional[str] = None,
+               part: bool = False, **args) -> _Span:
     """`profile_span` for the caller who needs the extent either way (an
     event that carries it): always measures `.seconds`, records only while a
-    SpanRecorder is active."""
-    return _Span(current_spans(), name, cat, args)
+    SpanRecorder is active.
+
+    With `counter` the span is a COLD SITE: as it closes, its microseconds
+    are added to that registry counter whether or not anything records, so
+    that set-up, which runs before any recorder is installed, can be read
+    from counters alone (total less what a window's executions added:
+    benchmark/setup_counters.py). Two clock reads and an `inc` an event: a
+    site may name a counter only if a warm execution over a resident table
+    never reaches it (a column's first upload, a dictionary's first encode,
+    a calibration, a residency miss); tests/test_cold_sites.py holds every
+    such counter at a delta of 0 across a repeat query.
+
+    The counters are self times, so they add up: a cold site counts its
+    extent less the cold extents inside it on the same thread (an upload
+    inside a residency build, a program built inside a calibration:
+    `cold_self_seconds`). With `part=True` the site is a part of the cold
+    site around it, which keeps its whole: the part counts its own extent
+    and takes nothing from it (`h2d_prepare_us` inside `h2d_upload_us`)."""
+    if counter is None:
+        return _Span(current_spans(), name, cat, args)
+    return _ColdSpan(current_spans(), name, cat, args, counter, part)
+
+
+_COLD_KEPT = 1024
+
+
+def cold_self_seconds(start: float, end: float) -> float:
+    """What a cold extent of this thread, heard of as it ends (`time.time()`
+    readings), may count: its length less the cold extents inside it, which
+    counted themselves. The one place self time is taken, for the sites that
+    are spans and for the program builds JAX reports (utils/jax_setup), so a
+    second is counted once whichever kind encloses the other. A thread's
+    extents nest properly, so those inside this one are the last ones heard
+    that ended after it began; it then stands for them all."""
+    done = getattr(_local, "cold", None)  # [(start, end)], by end
+    if done is None:
+        done = _local.cold = []
+    inside = 0.0
+    while done and done[-1][1] > start:
+        a, b = done.pop()
+        inside += b - a
+    done.append((start, end))
+    del done[:-_COLD_KEPT]
+    return max(end - start - inside, 0.0)
 
 
 def record_span(name: str, cat: str, t0: float, t1: float, **args) -> None:

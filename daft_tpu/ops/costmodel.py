@@ -243,60 +243,73 @@ def calibrate() -> Calibration:
     if _CAL is not None:
         return _CAL
 
-    rtt = _env_f("DAFT_TPU_COST_RTT", -1.0)
-    h2d = _env_f("DAFT_TPU_COST_H2D", -1.0)
-    d2h = _env_f("DAFT_TPU_COST_D2H", -1.0)
-    if rtt < 0 or h2d < 0 or d2h < 0:
-        import numpy as np
+    from ..observability.runtime_stats import timed_span
 
-        from ..utils import jax_setup  # noqa: F401
-        import jax
-        import jax.numpy as jnp  # noqa: F401
+    # a cold site, once a process (`calibrate_us`); the probes' own programs
+    # count where they are built (utils/jax_setup) and are left out of it
+    probed = []
+    with timed_span("placement.calibrate", "placement",
+                    counter="calibrate_us") as sp:
+        rtt = _env_f("DAFT_TPU_COST_RTT", -1.0)
+        h2d = _env_f("DAFT_TPU_COST_H2D", -1.0)
+        d2h = _env_f("DAFT_TPU_COST_D2H", -1.0)
+        if rtt < 0 or h2d < 0 or d2h < 0:
+            import numpy as np
 
-        probe = jax.jit(lambda a: a.sum())
-        x = jax.device_put(np.ones(64, np.float32))
-        jax.device_get(probe(x))  # compile outside any timed region
-        if rtt < 0:
-            samples = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                jax.device_get(probe(x))
-                samples.append(time.perf_counter() - t0)
-            rtt = sorted(samples)[1]
-        if h2d < 0:
-            buf = np.ones(2 * 1024 * 1024, np.float32)  # 8 MB
-            bprobe = jax.jit(lambda a: a.sum())
-            jax.device_get(bprobe(jax.device_put(buf)))  # compile for this shape
-            best = 0.0
-            for _ in range(2):  # best-of-2: jitter biases single samples low
-                t0 = time.perf_counter()
-                jax.device_get(bprobe(jax.device_put(buf)))  # upload + tiny fetch
-                dt = max(time.perf_counter() - t0 - rtt, 1e-3)
-                best = max(best, buf.nbytes / dt)
-            h2d = best
-        if d2h < 0:
-            ident = jax.jit(lambda a: a * 1)
-            big = jax.device_put(np.ones(256 * 1024, np.float32))  # 1 MB down
-            jax.device_get(ident(big))  # compile
-            best = 0.0
-            for _ in range(2):  # best-of-2: jitter biases single samples low
-                t0 = time.perf_counter()
-                jax.device_get(ident(big))
-                dt = max(time.perf_counter() - t0 - rtt, 1e-3)
-                best = max(best, big.nbytes / dt)
-            d2h = best
+            from ..utils import jax_setup  # noqa: F401
+            import jax
+            import jax.numpy as jnp  # noqa: F401
 
-    # Mesh terms: probed LIVE like rtt/h2d when more than one local device
-    # exists and the env doesn't pin them — the auto ICI tier then prices
-    # collectives with the silicon's numbers instead of v5e constants.
-    ici = _env_f("DAFT_TPU_COST_ICI", -1.0)
-    meshd = _env_f("DAFT_TPU_COST_MESH_DISPATCH", -1.0)
-    if ici < 0 or meshd < 0:
-        p_ici, p_meshd = _probe_mesh_terms(rtt)
-        if ici < 0:
-            ici = p_ici
-        if meshd < 0:
-            meshd = p_meshd
+            probe = jax.jit(lambda a: a.sum())
+            x = jax.device_put(np.ones(64, np.float32))
+            jax.device_get(probe(x))  # compile outside any timed region
+            if rtt < 0:
+                probed.append("rtt")
+                samples = []
+                for _ in range(3):
+                    t0 = time.perf_counter()
+                    jax.device_get(probe(x))
+                    samples.append(time.perf_counter() - t0)
+                rtt = sorted(samples)[1]
+            if h2d < 0:
+                probed.append("h2d")
+                buf = np.ones(2 * 1024 * 1024, np.float32)  # 8 MB
+                bprobe = jax.jit(lambda a: a.sum())
+                jax.device_get(bprobe(jax.device_put(buf)))  # compile for this shape
+                best = 0.0
+                for _ in range(2):  # best-of-2: jitter biases single samples low
+                    t0 = time.perf_counter()
+                    jax.device_get(bprobe(jax.device_put(buf)))  # upload + tiny fetch
+                    dt = max(time.perf_counter() - t0 - rtt, 1e-3)
+                    best = max(best, buf.nbytes / dt)
+                h2d = best
+            if d2h < 0:
+                probed.append("d2h")
+                ident = jax.jit(lambda a: a * 1)
+                big = jax.device_put(np.ones(256 * 1024, np.float32))  # 1 MB down
+                jax.device_get(ident(big))  # compile
+                best = 0.0
+                for _ in range(2):  # best-of-2: jitter biases single samples low
+                    t0 = time.perf_counter()
+                    jax.device_get(ident(big))
+                    dt = max(time.perf_counter() - t0 - rtt, 1e-3)
+                    best = max(best, big.nbytes / dt)
+                d2h = best
+
+        # Mesh terms: probed LIVE like rtt/h2d when more than one local device
+        # exists and the env doesn't pin them — the auto ICI tier then prices
+        # collectives with the silicon's numbers instead of v5e constants.
+        ici = _env_f("DAFT_TPU_COST_ICI", -1.0)
+        meshd = _env_f("DAFT_TPU_COST_MESH_DISPATCH", -1.0)
+        if ici < 0 or meshd < 0:
+            p_ici, p_meshd = _probe_mesh_terms(rtt)
+            if (p_ici, p_meshd) != (_STATIC_ICI_BPS, _STATIC_MESH_DISPATCH_S):
+                probed.append("mesh")  # the static pair: no mesh to probe
+            if ici < 0:
+                ici = p_ici
+            if meshd < 0:
+                meshd = p_meshd
+        sp.args["probed"] = ",".join(probed)
 
     _CAL = Calibration(
         rtt_s=rtt,

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from ..expressions.expressions import AggExpr, Alias, Expression
-from ..observability.runtime_stats import profile_span
+from ..observability.runtime_stats import profile_span, timed_span
 from ..schema import Schema
 from . import counters
 from . import device_eval as dev
@@ -130,7 +130,7 @@ def cached_dict_code_plane(src, codes: np.ndarray, rows: int, cap: int,
 
     def build():
         padded = _padded_codes(codes, rows, cap)
-        note_upload(None, transfers=1, planes=1)
+        note_upload(transfers=1, planes=1)
         return jnp.asarray(padded) if mesh is None \
             else shard_rows(mesh, padded, cap)
 
@@ -160,8 +160,6 @@ def batch_planes(batch, names: Sequence[str], bucket: int, f32: bool,
     the dispatch's row mask (rows valid, padding not), the array the program
     is passed as `row_mask` anyway. Each plane is still a slot of its own in
     the manager, pinned for the scope and weighed against the budget."""
-    import time
-
     from ..core.series import note_upload
     from ..device.residency import manager
 
@@ -183,13 +181,14 @@ def batch_planes(batch, names: Sequence[str], bucket: int, f32: bool,
         return (values,) if validity is None else (values, validity)
 
     def build(missing: List[int]) -> list:
-        t0 = time.perf_counter()
-        with profile_span("device.upload", "device", rows=n) as sp:
-            host = [host_planes(i) for i in missing]
+        with timed_span("device.upload", "device", counter="h2d_upload_us",
+                        rows=n) as sp:
+            with timed_span("device.upload.prepare", "host", counter="h2d_prepare_us",
+                            part=True, rows=n, pad_to=bucket):
+                host = [host_planes(i) for i in missing]
             flat = [p for planes in host for p in planes]
-            if sp is not None:
-                sp.args.update(bytes=sum(int(p.nbytes) for p in flat),
-                               planes=len(flat))
+            sp.args.update(bytes=sum(int(p.nbytes) for p in flat),
+                           planes=len(flat))
             placed = iter(jax.device_put(flat))
             row_mask = device_row_mask(n, bucket)
             out = []
@@ -199,7 +198,7 @@ def batch_planes(batch, names: Sequence[str], bucket: int, f32: bool,
                     out.append(first)
                 else:
                     out.append((first, next(placed) if len(planes) == 2 else row_mask))
-        note_upload(t0, transfers=1, planes=len(flat))
+        note_upload(transfers=1, planes=len(flat))
         return out
 
     slots = [(s, s.plane_slot(bucket, f32), 0) for s in cols] \

@@ -121,10 +121,14 @@ class NativeRunner(Runner):
             set_collector(prev)
             placement.set_scope(prev_scope)
             seconds = time.perf_counter() - t_start
+            from ..observability.metrics import registry
+
+            # every query's wall time, the one counter a warm query moves:
+            # what set-up spent inside queries is then a sum a reader can
+            # take, and the cold counters' seconds have a whole to be part of
+            registry().inc("query_wall_us", int(seconds * 1e6))
             deltas = {}
             if observed or frec is not None:
-                from ..observability.metrics import registry
-
                 deltas = registry().diff(reg_before)
             placements = pscope.to_dicts() if pscope is not None else []
             if observed:

@@ -34,20 +34,44 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
     jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE)
 
 
-def _compile_span(event: str, seconds: float, **_kw) -> None:
-    """JAX's own report of one XLA compilation, as an `xla.compile` span
-    (end = now) while a SpanRecorder is installed: a timeline then shows
-    which dispatch recompiled. Off, the listener is one recorder look-up."""
-    if event != "/jax/core/compile/backend_compile_duration":
+# JAX's own reports of what building a program cost: tracing the Python
+# function to a jaxpr, lowering the jaxpr to an MLIR module, and XLA's
+# compilation (a persistent-cache hit costs its look-up and retrieval).
+_BUILD_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("xla.trace", "jax_trace_us"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("xla.lower", "jax_lower_us"),
+    "/jax/core/compile/backend_compile_duration": ("xla.compile", "xla_compile_us"),
+}
+
+
+def _build_report(event: str, seconds: float, **_kw) -> None:
+    """One report of `_BUILD_EVENTS`, heard as the work ends: its counter
+    always (the listener fires only where something is traced or compiled,
+    never in a warm query), and a leaf span of its whole extent (end = now)
+    under the context's open span while a SpanRecorder is installed: a
+    timeline then shows which dispatch built a program.
+
+    The reports nest (a jitted `jnp` function traced inside a stage program's
+    trace reports first, then the program's own trace reports an extent that
+    holds it; a constant computed eagerly in a trace compiles), and a cold
+    span can lie inside one or around one, so a counter takes its extent's
+    self time (`runtime_stats.cold_self_seconds`): every second is counted
+    once, for the innermost site, and the counters add up."""
+    site = _BUILD_EVENTS.get(event)
+    if site is None:
         return
     from ..observability import runtime_stats
+    from ..observability.metrics import registry
 
+    name, counter = site
+    now = time.time()
+    start = now - seconds
+    registry().inc(counter, int(runtime_stats.cold_self_seconds(start, now) * 1e6))
     if runtime_stats.current_spans() is not None:
-        now = time.time()
-        runtime_stats.record_span("xla.compile", "compile", now - seconds, now)
+        runtime_stats.record_span(name, "compile", start, now)
 
 
-jax.monitoring.register_event_duration_secs_listener(_compile_span)
+jax.monitoring.register_event_duration_secs_listener(_build_report)
 
 
 def get_jax():

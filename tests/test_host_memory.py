@@ -137,7 +137,7 @@ def test_query_scope_observes_peak():
 
 def test_zero_overhead_unbudgeted_query():
     """Acceptance guard: an unbudgeted in-memory query allocates no
-    manager/spill state and shows an EMPTY registry diff."""
+    manager/spill state and moves no counter but its own wall time."""
     import os
 
     from daft_tpu.memory import spill_root
@@ -155,7 +155,8 @@ def test_zero_overhead_unbudgeted_query():
         before = registry().snapshot()
         q().to_pydict()
         diff = registry().diff(before)
-    assert diff == {}, f"unbudgeted query left a registry diff: {diff}"
+    # (its own wall time is the one counter a query moves)
+    assert set(diff) == {"query_wall_us"}, f"unbudgeted query left a registry diff: {diff}"
     assert manager().tracked_bytes() == 0
     assert manager().high_water_bytes() == 0
     root = spill_root()

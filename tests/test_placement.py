@@ -250,7 +250,9 @@ def test_placement_zero_overhead_on_host_path():
                               mesh_devices=1):
         big.groupby("k").agg(col("v").sum().alias("s")).to_pydict()
     assert led.stats()["seq"] == seq_before, "ledger touched on host path"
-    assert registry().diff(before) == {}, "registry touched on host path"
+    # the two queries counted their own wall time and nothing else
+    assert set(registry().diff(before)) == {"query_wall_us"}, \
+        "registry touched on host path"
 
 
 # ---------------------------------------------------------------------------

@@ -390,7 +390,7 @@ def test_feedback_tee_keeps_only_priced_spans():
 def test_off_path_no_clock_no_record_no_counter(monkeypatch):
     """No recorder: profile_span hands out ONE shared no-op, span_iter hands
     back its input, runtime_stats never reads time.time(), and a host-only
-    query leaves the registry as it was."""
+    query adds its wall time to the registry and nothing else."""
     from daft_tpu.observability import runtime_stats as rs
     from daft_tpu.observability.metrics import registry
 
@@ -416,7 +416,8 @@ def test_off_path_no_clock_no_record_no_counter(monkeypatch):
         out = df.where(col("v") > 1).groupby("k").agg(
             col("v").sum().alias("s")).sort("k").to_pydict()
     assert out == {"k": [1, 2], "s": [3.0, 6.0]}
-    assert set(registry().diff(before)) <= {"h2d_upload_us", "dict_encode_us"}
+    assert set(registry().diff(before)) <= {"h2d_upload_us", "dict_encode_us",
+                                            "query_wall_us"}
 
 
 def test_span_iter_records_error_and_closes_upstream():
