@@ -12,6 +12,7 @@ daft_tpu.ops and are reached through the stage compiler, not through Series.
 
 from __future__ import annotations
 
+import hashlib
 from typing import Any, Iterator, List, Optional, Sequence
 
 import numpy as np
@@ -440,34 +441,86 @@ class Series:
 
     def _hash_content(self) -> Optional[int]:
         """`content_fingerprint`'s hash of an Arrow-backed column; None
-        where it cannot be hashed."""
-        import hashlib
+        where it cannot be hashed. dtype, length, how many byte buffers hold
+        the column (`_content_buffers`: one more where there are nulls), then
+        each buffer's length and the digests of its `_HASH_CHUNK_BYTES` pieces
+        in order: a function of the bytes alone, whoever hashes the pieces.
+        Over one chunk they go to the compute pool (hashlib releases the
+        interpreter's lock), unless this thread is the pool's own: a pool
+        thread never waits on its pool. The clock and the counters are the
+        caller's."""
+        from ..observability.metrics import registry
+        from ..utils.pool import compute_pool, on_pool_thread
 
         h = hashlib.blake2b(digest_size=8)
         h.update(repr(self._dtype).encode())
         h.update(len(self).to_bytes(8, "little"))
         try:
-            vals = self.to_numpy()
-            if vals.dtype == object:
-                raise TypeError("no dense repr")
-            # to_numpy fills nulls deterministically (0/NaN) — hashing the
-            # dense values + validity mask is content-exact
-            h.update(np.ascontiguousarray(vals).tobytes())
-            h.update(self.validity_numpy().tobytes())
-        except Exception:  # lint: ignore[broad-except] -- falls through to the Arrow IPC hash
-            try:
-                # strings/nested: hash the Arrow IPC serialization. Distinct
-                # logical values can never collide; equal arrays in unusual
-                # physical layouts may hash differently, which only costs a
-                # missed cache rebind, never correctness
-                sink = pa.BufferOutputStream()
-                with pa.ipc.new_stream(
-                        sink, pa.schema([pa.field("c", self._arrow.type)])) as w:
-                    w.write_batch(pa.record_batch([self._arrow], names=["c"]))
-                h.update(sink.getvalue())
-            except Exception:  # lint: ignore[broad-except] -- unhashable: no content fingerprint,
-                return None  # caller keys by identity instead
+            buffers, inplace = self._content_buffers()
+        except Exception:  # lint: ignore[broad-except] -- unhashable: no content fingerprint,
+            return None  # caller keys by identity instead
+        views = [_byte_view(b) for b in buffers]
+        nbytes = sum(len(v) for v in views)
+        pieces = [v[i:i + _HASH_CHUNK_BYTES] for v in views
+                  for i in range(0, len(v), _HASH_CHUNK_BYTES)]
+        if nbytes <= _HASH_CHUNK_BYTES or on_pool_thread():
+            digests = map(_chunk_digest, pieces)
+        else:
+            digests = compute_pool().map(_chunk_digest, pieces)
+        h.update(len(views).to_bytes(8, "little"))
+        for v in views:
+            h.update(len(v).to_bytes(8, "little"))
+        for d in digests:
+            h.update(d)
+        reg = registry()
+        reg.inc("content_hash_bytes", nbytes)
+        reg.inc("content_hash_inplace" if inplace else "content_hash_copied")
         return int.from_bytes(h.digest(), "little")
+
+    def _content_buffers(self):
+        """(buffers, in place) of this Arrow-backed column for `_hash_content`:
+        what holds its values and validity, independent of the physical
+        layout (a slice, a pickle round trip and a fresh copy of the same rows
+        give equal bytes). In place, nothing copied: the value buffer of a
+        fixed-width column and the offsets and data of a large_string or
+        large_binary, cut to the array's offset and length, where the column
+        has no nulls (validity is then no buffer at all). Normalised through a
+        copy first: a string slice's offsets, rebased to its first (pickling
+        rebases them too); a column with nulls, whose null slots may hold
+        anything (filled, and its validity bits packed); booleans, fixed-shape
+        and nested types (today's dense values, or the Arrow IPC stream:
+        equal arrays in unusual layouts may then differ, which costs a missed
+        rebind, never correctness)."""
+        arr = self._arrow
+        n = len(arr)
+        if n == 0:
+            return [], True
+        t = arr.type
+        validity = [np.packbits(self.validity_numpy())] if arr.null_count else []
+        if pa.types.is_large_string(t) or pa.types.is_large_binary(t):
+            if validity:
+                arr = arr.fill_null("" if pa.types.is_large_string(t) else b"")
+            _, offsets, data = arr.buffers()
+            offsets = np.frombuffer(offsets, np.int64, n + 1, arr.offset * 8)
+            first, last = int(offsets[0]), int(offsets[-1])
+            if first:
+                offsets = offsets - first
+            data = memoryview(data)[first:last] if last > first else b""
+            return [offsets, data] + validity, not (first or validity)
+        if pa.types.is_primitive(t) and t.bit_width % 8 == 0 and not validity:
+            width = t.bit_width // 8
+            values = memoryview(arr.buffers()[1])
+            return [values[arr.offset * width:(arr.offset + n) * width]], True
+        try:
+            vals = self.to_numpy()  # nulls filled: 0, NaN
+        except Exception:  # lint: ignore[broad-except] -- no dense form: the Arrow IPC stream below
+            vals = None
+        if vals is not None and vals.dtype != object:
+            return [vals] + validity, False
+        sink = pa.BufferOutputStream()
+        with pa.ipc.new_stream(sink, pa.schema([pa.field("c", t)])) as w:
+            w.write_batch(pa.record_batch([arr], names=["c"]))
+        return [sink.getvalue()], False
 
     def dict_codes(self):
         """Dictionary-encode this column: (codes int32 ndarray, values list, K).
@@ -1051,6 +1104,24 @@ class Series:
 
 
 # ---- helpers ---------------------------------------------------------------------
+
+
+# Bytes of a column that one blake2b hashes (`Series._hash_content`): part of
+# the fingerprint's definition, so the same in every process.
+_HASH_CHUNK_BYTES = 4 << 20
+
+
+def _chunk_digest(piece) -> bytes:
+    return hashlib.blake2b(piece, digest_size=16).digest()
+
+
+def _byte_view(buf) -> memoryview:
+    """`buf` (an Arrow buffer, a memoryview, bytes or a numpy array of any
+    fixed dtype) as a flat view of its bytes, copying only what is not
+    contiguous."""
+    if isinstance(buf, np.ndarray):
+        buf = np.ascontiguousarray(buf).reshape(-1).view(np.uint8)
+    return memoryview(buf).cast("B")
 
 
 def note_upload(transfers: int, planes: int) -> None:

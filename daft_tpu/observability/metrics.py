@@ -189,6 +189,10 @@ DEVICE_COUNTER_NAMES = (
     "xla_compile_us",          # XLA compiling, or retrieving from the persistent cache
     "calibrate_us",            # the cost model's live probes (costmodel.calibrate)
     "content_hash_us",         # hashing a column's content for its stable residency key
+    "content_hash_bytes",      # bytes those hashes read (÷ content_hash_us: the hash's rate)
+    "content_hash_inplace",    # columns hashed over their Arrow buffers where they lie
+    "content_hash_copied",     # columns hashed after a normalising copy (nulls, a string
+                               # slice's offsets, booleans, fixed-shape and nested types)
     "residency_build_us",      # residency misses building their values, less the uploads,
                                # encodes and program builds inside them
     "query_wall_us",           # wall µs of every query (NativeRunner), warm ones too

@@ -4,20 +4,30 @@ numpy/arrow kernels release the GIL, so morsel parallelism works on threads)."""
 from __future__ import annotations
 
 import os
+import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 from .env import env_int
 
 _POOL: Optional[ThreadPoolExecutor] = None
+_THREAD = threading.local()
 
 
 def compute_pool() -> ThreadPoolExecutor:
     global _POOL
     if _POOL is None:
         workers = env_int("DAFT_TPU_NUM_THREADS", os.cpu_count() or 4, lo=1)
-        _POOL = ThreadPoolExecutor(max_workers=workers, thread_name_prefix="daft-compute")
+        _POOL = ThreadPoolExecutor(max_workers=workers, thread_name_prefix="daft-compute",
+                                   initializer=setattr, initargs=(_THREAD, "pooled", True))
     return _POOL
+
+
+def on_pool_thread() -> bool:
+    """True on a thread of the compute pool. Such a caller does inline what
+    it would have handed to the pool: a pool thread that waits on its own
+    pool can wait for ever."""
+    return getattr(_THREAD, "pooled", False)
 
 
 def pool_width() -> int:

@@ -620,9 +620,9 @@ def test_unpickled_view_has_no_lineage_and_rebinds_by_content():
 
 
 def test_hit_does_not_fingerprint_the_column(monkeypatch):
-    """The content hash (to_numpy + blake2b over the column) is for the
-    stable-key rebind: computed once the identity probe has missed, never on
-    a hit."""
+    """The content hash (blake2b over the column's Arrow buffers where they
+    lie, in chunks: tests/test_content_fingerprint.py) is for the stable-key
+    rebind: computed once the identity probe has missed, never on a hit."""
     from daft_tpu.core.series import Series
 
     m = manager()
