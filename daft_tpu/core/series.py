@@ -24,6 +24,11 @@ from ..datatype import DataType, Field
 
 def _combine(arr) -> pa.Array:
     if isinstance(arr, pa.ChunkedArray):
+        # combine_chunks copies even ONE chunk (at SF10 the seven TPC-H
+        # tables the join cell loads were 10.7 GB twice over, PR 38): a
+        # single chunk that starts at its buffers' start is the column
+        if arr.num_chunks == 1 and arr.chunk(0).offset == 0:
+            return arr.chunk(0)
         return arr.combine_chunks()
     return arr
 

@@ -1251,6 +1251,10 @@ def _run_device_join(node, label: str, make_run, assemble,
         # one big batch vs eight small ones is a DIFFERENT costed decision.
         # The layout signature is computable without the second-partition
         # peek below, so cached-reject repeats pay for NO extra partition.
+        # a fused TopN whose group ids hold for the whole run takes the
+        # fact as the other join nodes do, batch after batch (what only the
+        # dims can still refuse, their size, is seen once they are made)
+        stream_wide = not topn or _topn_takes_stream(node.spec, stage)
         dk = _decision_key(node, first.num_rows, cfg, topn,
                            _batch_layout(first))
         if cfg.device_mode == "auto" and _DECISION_CACHE.get(dk) is False:
@@ -1262,7 +1266,7 @@ def _run_device_join(node, label: str, make_run, assemble,
             raw_stream.close()
             return _host()
         second = None
-        if cfg.device_mode == "auto" and not topn:
+        if cfg.device_mode == "auto" and stream_wide:
             from .batching import coalesce_target_rows
 
             if coalesce_target_rows(cfg) > 0:
@@ -1276,11 +1280,12 @@ def _run_device_join(node, label: str, make_run, assemble,
             [first] if second is None else [first, second], raw_stream)
         from ..ops.region import single_batch_horizon
 
-        # the fused TopN program is a one-batch region by construction; its
-        # RTT pricing comes from the shared region builder, not a local
-        # constant (ops/region.py single_batch_horizon)
-        coal = single_batch_horizon() if topn else _coalesce_horizon(
-            [first] if second is None else [first, second])
+        # a fused TopN held to one batch is a one-batch region by
+        # construction; its RTT pricing comes from the shared region builder,
+        # not a local constant (ops/region.py single_batch_horizon)
+        coal = _coalesce_horizon(
+            [first] if second is None else [first, second]) if stream_wide \
+            else single_batch_horizon()
         dim_batches = {}
         for name, plan in node.dim_plans:
             dim_batches[name] = _concat_parts(list(_exec(plan)), plan.schema)
@@ -1366,16 +1371,21 @@ def _run_device_join(node, label: str, make_run, assemble,
         region_ops = ("join", "agg", "topn") if topn else ("join", "agg")
         d0 = _counters.device_join_batches
         with _placement.feedback(prec) as fb, _residency().pin_scope():
-            if topn:
-                # the fused TopN program needs ONE fact batch: bail on sighting a
-                # SECOND (before any device work, without draining the stream)
+            if topn and not getattr(run, "run_wide", False):
+                # this run's group ids hold for ONE fact batch (the mesh
+                # tier's, or a group-by outside one dimension's key space):
+                # bail on sighting a SECOND (before any device work, without
+                # draining the stream)
+                why = getattr(run, "one_batch_reason", "") or "the mesh tier"
                 first_b = None
                 for part in fact_stream:
                     for b in part.batches:
                         if b.num_rows == 0:
                             continue
                         if first_b is not None:
-                            _counters.reject("runtime", f"{label}: multi-batch fact")
+                            _counters.reject(
+                                "runtime", f"{label}: multi-batch fact and no "
+                                "run-wide group ids", f"({why})")
                             fb.cancel()  # no dispatch happened: nothing to observe
                             raw_stream.close()
                             return _host()
@@ -1455,6 +1465,33 @@ def _batch_layout(part: MicroPartition) -> tuple:
     return (len(sizes), pad_bucket(int(sum(sizes) / len(sizes))))
 
 
+def _topn_takes_stream(spec, stage) -> bool:
+    """Whether a fused TopN over `spec` keeps its group tables for the whole
+    run, as far as the plan alone says (ops/device_join.run_wide_groups): its
+    fact is then fed batch after batch and priced as a coalesced stream."""
+    from ..ops.device_join import run_wide_groups
+
+    return run_wide_groups(spec)[0] is not None and stage.run_wide_reason() is None
+
+
+def _resident_rows(n) -> Optional[int]:
+    """Rows a fact plan over in-memory tables reads, or None where a leaf is
+    anything else: what tells a fact of one batch from one of 458 before the
+    stream has been walked."""
+    kids = n.children()
+    if not kids:
+        if not isinstance(n, pp.InMemoryScan):
+            return None
+        return sum(p.num_rows for p in n.partitions)
+    total = 0
+    for k in kids:
+        rows = _resident_rows(k)
+        if rows is None:
+            return None
+        total += rows
+    return total
+
+
 def _decision_key(node, rows: int, cfg, topn: bool, layout: tuple) -> tuple:
     """Structural identity of one cost decision: the captured spec's shape +
     input size + the config knobs the decision reads + the data-dependent
@@ -1469,7 +1506,11 @@ def _decision_key(node, rows: int, cfg, topn: bool, layout: tuple) -> tuple:
     config change to any keyed knob re-decides."""
     spec = node.spec
     return (
-        topn, rows, cfg.device_mode, cfg.device_amortize_runs,
+        # a fused TopN over the whole stream prices its one select against all
+        # of the fact's batches: the first partition's layout alone would let
+        # a one-batch fact's verdict serve a 458-batch one
+        topn, rows, _resident_rows(node.fact) if topn else None,
+        cfg.device_mode, cfg.device_amortize_runs,
         # the coalescing horizon feeds the costed decision: a config change to
         # the coalescer knobs OR a different fact batch layout must re-decide,
         # not hit a stale cached verdict
@@ -1624,10 +1665,22 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
 
         from ..ops.device_join import DeviceJoinTopNRun
 
+        wide = None
+        if topn:
+            from ..ops.device_join import topn_run_wide
+
+            wide, wide_cap, _why = topn_run_wide(ctx, stage)
         ceiling = DeviceJoinTopNRun.max_segments if topn \
             else DeviceJoinGroupedRun.max_segments
-        card = estimate_joined_cardinality(ctx, batch, stage.groupby)
-        cap_est = _pad_groups(min(max(card, 1), 2 * ceiling))
+        if wide is not None:
+            # the tables that will be built are the dimension's padded rows
+            # long whatever a batch holds: nothing is sampled (and the
+            # run-wide ceiling was held when `wide` was found)
+            card = max(ctx.batches[wide.dim.name].num_rows, 1)
+            cap_est = ceiling = wide_cap
+        else:
+            card = estimate_joined_cardinality(ctx, batch, stage.groupby)
+            cap_est = _pad_groups(min(max(card, 1), 2 * ceiling))
         if cap_est > ceiling and not forced:
             # both device tiers pay the same finalize-fetch/table budget.
             # A FORCED run executes regardless, so gating here would write a
@@ -1638,8 +1691,8 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
             _placement.ledger().gate(label, "est group count over ceiling",
                                      rows)
             return False, None
-        if cap_est > MAX_MATMUL_SEGMENTS and (stage._sct_specs
-                                              or stage._use_f64):
+        if wide is None and cap_est > MAX_MATMUL_SEGMENTS and (
+                stage._sct_specs or stage._use_f64):
             # single-chip-only limitation: the local-dense program cannot
             # serve 64-bit scatter/f64 stages. The MESH programs reduce in
             # native dtypes (exact int64), so the mesh arm stays eligible.
@@ -1662,15 +1715,28 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
         else:
             fetch = cap_est * (n_mm + n_ext + n_sct) * 8
             mesh_fetch = cap_est * (n_slots * 2 + 1) * 8
-        nonres += bucket * 4                   # codes plane (host-factorize case)
-        dev_cost = costmodel.device_join_agg_cost(
-            cal, rows, nonres // amort, n_gathers, n_mm, n_ext, n_sct,
-            cap_est, fetch, rows // amort, MAX_MATMUL_SEGMENTS, coalesce=coal,
-            resident_bytes=res)
+        if wide is not None:
+            from ..ops.grouped_stage import CHUNK_LOCAL
+
+            # one select and one fetch a run: this partition carries its
+            # share of them, by its rows over the fact's where those are known
+            share = min(rows / max(_resident_rows(node.fact) or rows, 1), 1.0)
+            dev_cost = costmodel.device_join_topn_run_cost(
+                cal, rows, nonres // amort, n_gathers, n_mm, cap_est,
+                min(CHUNK_LOCAL, bucket),
+                ctx.ids_locally_dense(batch, wide.dim.name), fetch, share,
+                len(node.topn.keys), rows // amort, coalesce=coal,
+                resident_bytes=res)
+        else:
+            nonres += bucket * 4               # codes plane (host-factorize case)
+            dev_cost = costmodel.device_join_agg_cost(
+                cal, rows, nonres // amort, n_gathers, n_mm, n_ext, n_sct,
+                cap_est, fetch, rows // amort, MAX_MATMUL_SEGMENTS, coalesce=coal,
+                resident_bytes=res)
         pallas_cost = costmodel.device_join_pallas_cost(
             cal, rows, nonres // amort, probe_slots, n_mm, n_ext, n_sct,
             cap_est, fetch, rows // amort, coalesce=coal, resident_bytes=res)
-        if topn:
+        if topn and wide is None:
             # device multi-key sort over the cap-length planes
             nkeys = len(node.topn.keys) + 2
             dev_cost.add("compute",
@@ -1683,8 +1749,10 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
         if spec.predicate is not None:
             host_cost.add("compute", rows / cal.host_agg_rate)  # filter pass
         if topn:
-            # host additionally sorts the aggregate's output rows
-            host_cost.add("compute", card * max(math.log2(max(card, 2)), 1.0)
+            # host additionally sorts the aggregate's output rows (once a
+            # run: a partition of a streamed fact carries its share)
+            host_cost.add("compute", (share if wide is not None else 1.0)
+                          * card * max(math.log2(max(card, 2)), 1.0)
                           / cal.host_agg_rate)
         if mesh_ndev >= 2:
             mesh_cost = costmodel.mesh_join_agg_cost(

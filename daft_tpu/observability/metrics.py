@@ -148,6 +148,12 @@ DEVICE_COUNTER_NAMES = (
     "join_provision_calls",    # join dispatches whose columns came from one traced program
     "join_provision_traces",   # provisioning programs traced (0 on a repeat query shape)
     "device_topn_runs",        # join+agg+TopN fused device programs completed
+    # the fused TopN's group tables stay on the device for a whole run where
+    # the group-by spans one dimension's key space (ops/device_join.py
+    # DeviceJoinTopNRun): its fact may then come in any number of batches
+    "device_join_topn_batches",  # fact batches fused TopN runs took in
+    "device_topn_fetched_rows",  # rows their finalizes brought back (at most a limit + offset each)
+    "device_topn_table_bytes",   # bytes of the run-wide group tables, summed over the runs that built them
     "mesh_join_runs",          # device joins executed via the mesh-sharded tier
     # intra-host ICI repartition (jax.lax.all_to_all over the local mesh —
     # the in-mesh replacement for the host shuffle between co-located workers)

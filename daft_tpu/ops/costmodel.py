@@ -183,6 +183,19 @@ class Calibration:
     # l_returnflag on the builder's host (PR 28), against 4.9 ms through
     # make_groups, which host_factorize_rate prices at 16 ms.
     host_dict_encode_rate: float = 4e7
+    # the run-wide TopN's two forms (grouped_stage._build_run_wide). Dense:
+    # one-hot cells of a chunk against its own id window (rows x chunk, once
+    # for the sums on the MXU and once for the first-row minimum), built and
+    # contracted without leaving the chip's memory: q3 at SF10 read 1.17 ms
+    # a dispatch for 2 x 131,072 x 4,096 cells on a v5e (PR 38). Scatter:
+    # beside its scatters (scatter_rows_per_s: q10 read 0.88 ms a scatter of
+    # 131,072 rows, 1.5e8 rows/s) it streams the whole of each table once a
+    # dispatch (a float32 table zeroed, scattered into, and added to the
+    # run's two float32 planes: 24 bytes an id, reckoned from the chip's
+    # 819 GB/s at two thirds), which mm_plane_rows_per_s, a reduce's rate,
+    # would price 4 times too high.
+    run_wide_cell_rate: float = 9e11
+    run_wide_pass_ids_per_s: float = 2e10
 
 
 _CAL: Optional[Calibration] = None
@@ -612,6 +625,39 @@ def device_join_agg_cost(cal: Calibration, rows: int, upload_bytes: int,
     out.add("d2h", fetch_bytes / cal.d2h_bytes_per_s)
     _segment_reduce_terms(out, cal, rows, n_mm, n_ext, n_sct, cap_est,
                           matmul_ceiling=matmul_ceiling)
+    return out
+
+
+def device_join_topn_run_cost(cal: Calibration, rows: int, upload_bytes: int,
+                              n_gathers: int, n_mm: int, cap: int, chunk: int,
+                              dense: bool, fetch_bytes: int, select_share: float, n_keys: int,
+                              index_rows: int, coalesce: float = 1.0,
+                              resident_bytes: int = 0) -> CostBreakdown:
+    """One partition of a fused join + TopN run that keeps ONE set of group
+    tables of `cap` ids on the device for the whole run
+    (ops/device_join.DeviceJoinTopNRun over GroupedAggStage._build_run_wide):
+    the gathers, the batch added into the tables in the form its ids allow
+    (`dense`: the one-hot product of each `chunk` rows on the MXU, rows x chunk
+    cells for the sums, whatever the planes, since they ride one pass of the
+    MXU, and as many for the first-row minimum; else a scatter a plane and
+    one for the first rows, and a stream over the tables), this
+    partition's share of the run's one select (`select_share`: its rows over
+    the fact's; the select sorts blocks, so `cap` ids cost cap x log2(block)
+    a key) and of the K-row fetch. No host factorization: `index_rows` is the
+    join indices' first build alone, amortized by the caller."""
+    import math
+
+    out = _base_terms(cal, upload_bytes, coalesce, resident_bytes)
+    out.add("compute", n_gathers * rows / cal.mm_plane_rows_per_s)
+    if dense:
+        out.add("compute", 2 * rows * chunk / cal.run_wide_cell_rate)
+    else:
+        out.add("compute", (n_mm + 1) * rows / cal.scatter_rows_per_s
+                + cap * n_mm / cal.run_wide_pass_ids_per_s)
+    out.add("compute", select_share * cap * math.log2(chunk)
+            * (n_keys + 2) / cal.mm_plane_rows_per_s)
+    out.add("factorize", index_rows / cal.host_factorize_rate)
+    out.add("d2h", select_share * fetch_bytes / cal.d2h_bytes_per_s)
     return out
 
 

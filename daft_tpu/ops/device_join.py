@@ -57,7 +57,8 @@ from .grouped_stage import (DeviceFallback, GroupedAggRun, GroupedAggStage,
                             _pad_groups, count_reduce,
                             try_build_grouped_agg_stage)
 from .stage import (FilterAggRun, FilterAggStage, batch_planes,
-                    cached_dict_code_plane, device_row_mask, pad_bucket)
+                    cached_dict_code_plane, device_row_mask, note_program_trace,
+                    pad_bucket)
 
 
 # ======================================================================================
@@ -441,14 +442,24 @@ def series_keyed(anchor, key: tuple, deps: tuple, build, literals=None,
                                   rebuild_rows=rebuild_rows)
 
 
-def unique_key_index(dim_key_series, probe_vals: np.ndarray,
-                     probe_valid: np.ndarray, target_dtype) -> np.ndarray:
-    """idx[i] = dim row with key == probe value i, else -1. Raises
-    DeviceFallback when dim keys are not unique (join would multiply rows) or
-    aren't integer-encodable."""
-    from ..native import native_i64_map_build, native_i64_map_lookup
+class UniqueKeyLookup(NamedTuple):
+    """What a probe of one dimension's unique key needs, made ONCE a key
+    column (unique_key_lookup) and kept with it: the uniqueness check, the
+    key range and the table are all as long as the dimension, and a fact of
+    458 batches asks 458 times (at SF10 each build was 1.4 s over `orders`'
+    15 M keys: 640 s of q3's first execution)."""
+    form: str              # "dense": table[key - lo]; "hash": the native map; "sorted": binary search
+    lo: int
+    hi: int
+    table: Optional[np.ndarray]   # dense: int32 row of each key of [lo, hi], -1 where absent
+    slots: Optional[tuple]        # hash: native_i64_map_build's (slots, cap)
+    keys: Optional[np.ndarray]    # sorted: the valid keys in ascending order
+    rows: np.ndarray              # hash: row of each valid key; sorted: rows in `keys`' order
 
-    s = dim_key_series
+
+def _build_key_lookup(s, target_dtype) -> UniqueKeyLookup:
+    from ..native import native_i64_map_build
+
     if s.dtype != target_dtype:
         s = s.cast(target_dtype)
     kind, vals, valid = canonical_key_values(s)
@@ -458,33 +469,51 @@ def unique_key_index(dim_key_series, probe_vals: np.ndarray,
     vv = vals[valid] if not valid.all() else vals
     if len(np.unique(vv)) != len(vv):
         raise DeviceFallback(f"dim key {s.name!r} is not unique")
-    pv = probe_vals.astype(np.int64, copy=False)
     lo = int(vv.min()) if len(vv) else 0
     hi = int(vv.max()) if len(vv) else -1
     domain = hi - lo + 1
+    rows = np.nonzero(valid)[0]
     if 0 < domain <= max(4096, 8 * max(len(vv), 1)):
-        table = np.full(domain, -1, dtype=np.int64)
-        rows = np.nonzero(valid)[0]
-        table[vals[valid] - lo] = rows
-        safe = np.clip(pv - lo, 0, max(domain - 1, 0))
-        idx = np.where((pv >= lo) & (pv <= hi), table[safe], -1)
+        table = np.full(domain, -1, dtype=np.int32)
+        table[vv - lo] = rows
+        return UniqueKeyLookup("dense", lo, hi, table, None, None, rows)
+    hm = native_i64_map_build(vv)
+    if hm is not None:
+        return UniqueKeyLookup("hash", lo, hi, None, hm, None, rows)
+    order = np.argsort(vv, kind="stable")
+    return UniqueKeyLookup("sorted", lo, hi, None, None, vv[order], rows[order])
+
+
+def unique_key_lookup(dim_key_series, target_dtype) -> UniqueKeyLookup:
+    """The probe structure of a dimension's key column, resident with the
+    column (a deps-free slot: found again by the column's content). Raises
+    DeviceFallback when the keys are not unique (a join would multiply rows)
+    or are not integer-encodable."""
+    return series_keyed(dim_key_series, ("uklookup", repr(target_dtype)), (),
+                        lambda: _build_key_lookup(dim_key_series, target_dtype))
+
+
+def unique_key_index(dim_key_series, probe_vals: np.ndarray,
+                     probe_valid: np.ndarray, target_dtype) -> np.ndarray:
+    """idx[i] = dim row with key == probe value i, else -1. The work here is
+    as long as the probe; what is as long as the dimension is
+    unique_key_lookup's, once."""
+    from ..native import native_i64_map_lookup
+
+    lk = unique_key_lookup(dim_key_series, target_dtype)
+    pv = probe_vals.astype(np.int64, copy=False)
+    if lk.form == "dense":
+        safe = np.clip(pv - lk.lo, 0, len(lk.table) - 1)
+        idx = np.where((pv >= lk.lo) & (pv <= lk.hi), lk.table[safe], -1)
+    elif lk.form == "hash":
+        pos = native_i64_map_lookup(lk.slots[0], lk.slots[1], pv)
+        idx = np.where(pos >= 0, lk.rows[np.clip(pos, 0, len(lk.rows) - 1)], -1) \
+            if len(lk.rows) else np.full(len(pv), -1, dtype=np.int64)
+    elif len(lk.keys):
+        pos = np.minimum(np.searchsorted(lk.keys, pv), len(lk.keys) - 1)
+        idx = np.where(lk.keys[pos] == pv, lk.rows[pos], -1)
     else:
-        hm = native_i64_map_build(vv)
-        if hm is None:
-            order = np.argsort(vv, kind="stable")
-            su = vv[order]
-            pos = np.searchsorted(su, pv)
-            pos_c = np.minimum(pos, max(len(su) - 1, 0))
-            hit = (len(su) > 0) & (su[pos_c] == pv)
-            rows = np.nonzero(valid)[0][order] if len(su) else np.empty(0, np.int64)
-            idx = np.where(hit, rows[pos_c] if len(su) else -1, -1)
-        else:
-            pos = native_i64_map_lookup(hm[0], hm[1], pv)
-            rows = np.nonzero(valid)[0]
-            if len(rows) == 0:
-                idx = np.full(len(pv), -1, dtype=np.int64)
-            else:
-                idx = np.where(pos >= 0, rows[np.clip(pos, 0, len(rows) - 1)], -1)
+        idx = np.full(len(pv), -1, dtype=np.int64)
     idx = np.where(probe_valid, idx, -1)
     return idx.astype(np.int32, copy=False)
 
@@ -989,6 +1018,29 @@ class _JoinContext:
                 total += bucket * 4
         return total
 
+    def ids_locally_dense(self, batch, dname: str) -> bool:
+        """Whether every chunk of CHUNK_LOCAL rows of `batch` holds `dname`
+        row indices within CHUNK_LOCAL of each other (a fact sorted by that
+        dimension's key): what the run-wide TopN program sees on the device
+        at every dispatch, read on the host from the first batch's cached
+        index so that placement can price the form that will run."""
+        from .grouped_stage import CHUNK_LOCAL
+
+        d = next(dd for dd in self.dims if dd.name == dname)
+        idx = self._indices_for(batch)[dname]
+
+        def build():
+            chunk = min(CHUNK_LOCAL, pad_bucket(len(idx)))
+            padded = np.full(-(-len(idx) // chunk) * chunk, -1, dtype=np.int64)
+            padded[:len(idx)] = idx
+            g = padded.reshape(-1, chunk)
+            lo = np.where(g >= 0, g, np.iinfo(np.int64).max).min(axis=1)
+            hi = g.max(axis=1)
+            return bool(np.all((hi < 0) | (hi - lo < chunk)))
+
+        return series_keyed(self._probe_anchor(batch, d),
+                            ("idxdense", d.key_col, d.parent), (idx,), build)
+
     # ---- packed per-adjacent-dim planes ------------------------------------------
     #
     # TPU dynamic gathers are INDEX-COUNT bound: on v5e a single 8M-index
@@ -1352,24 +1404,7 @@ class _FactorizedCodes:
         if key_index not in self._rank_planes:
             s_first = self.key_series[key_index].take(self.first_idx)
             n = len(s_first)
-            valid = s_first.validity_numpy()
-            # DENSE value ranks: equal key values MUST share a rank, or ties
-            # would never reach the next sort key
-            rank = np.zeros(n, dtype=np.int64)
-            dense = None
-            try:
-                vals = s_first.to_numpy()
-                if vals.dtype.kind in "biufM":
-                    _u, inv = np.unique(vals[valid], return_inverse=True)
-                    dense = inv
-            except Exception:  # lint: ignore[broad-except] -- falls back to python comparison
-                dense = None
-            if dense is None:  # strings/objects: python comparison
-                arr = s_first.to_pylist()
-                vv = [arr[i] for i in range(n) if valid[i]]
-                order = {v: r for r, v in enumerate(sorted(set(vv)))}
-                dense = np.asarray([order[v] for v in vv], dtype=np.int64)
-            rank[valid] = dense
+            rank, valid = _dense_ranks(s_first)
             plane = np.full(self.cap, float(self.cap), dtype=np.float32)
             plane[:n] = rank.astype(np.float32)
             vplane = np.zeros(self.cap, dtype=bool)
@@ -1632,10 +1667,17 @@ class DeviceJoinGroupedRun(GroupedAggRun):
                        key_rows=_LazyKeyRows(fc), fact_codes=fc)
 
 
-# segment ceiling for TopN-fused runs: the d2h fetch is K rows regardless of
-# group count, so cap is bounded by HBM for the plane tables + the device
-# sort, not by fetch bandwidth
+# What the two TopN ceilings bound. A run whose group ids hold for one batch
+# (host factorization, DeviceJoinTopNRun's one-batch form) builds a table of
+# its batch's padded group count: TOPN_MAX_SEGMENTS bounds that table and the
+# device sort over it. A run whose ids hold for the whole run (a dimension's
+# rows) builds ONE set of tables of the dimension's padded row count, whatever
+# the number of batches: TOPN_RUN_MAX_SEGMENTS bounds that, by HBM (at 2^25
+# ids a sum's two float32 planes are 268 MB, and q3's three sums, its first-row table
+# and the select's operands come to about 1.7 GB). Neither bounds a fetch:
+# both forms bring back K rows.
 TOPN_MAX_SEGMENTS = 1 << 22
+TOPN_RUN_MAX_SEGMENTS = 1 << 25
 
 
 @dataclass
@@ -1651,15 +1693,15 @@ class TopNSpec:
     offset: int
 
 
-def _agg_sort_plane(stage: GroupedAggStage, out, agg_idx: int):
+def _agg_sort_plane(stage: GroupedAggStage, mm_col, out, agg_idx: int):
     """(value f64[cap], valid bool[cap]) ordering plane for one aggregation,
     computed ON DEVICE from the group tables (mirrors
-    grouped_stage.results_from_tables; f64 is ample for ordering)."""
+    grouped_stage.results_from_tables; f64 is ample for ordering). `mm_col(j)`
+    is the matmul table's plane j; `out` holds the "ext" and "sct" tables."""
     slots = stage._agg_slots[agg_idx]
     _name, agg = stage.aggs[agg_idx]
-    mm = out["mm"]
     count_all = agg.op == "count" and agg.params.get("mode", "valid") == "all"
-    cnt = mm[:, 0] if count_all else mm[:, slots["count"][1]]
+    cnt = mm_col(0) if count_all else mm_col(slots["count"][1])
     if agg.op == "count":
         return cnt, jnp.ones(cnt.shape, dtype=bool)
     valid = cnt > 0
@@ -1669,10 +1711,10 @@ def _agg_sort_plane(stage: GroupedAggStage, out, agg_idx: int):
             _k, base, nd, lo = sl
             s = jnp.zeros(cnt.shape, dtype=jnp.float64)
             for k in range(nd):
-                s = s + mm[:, base + k] * float(1 << (8 * k))
+                s = s + mm_col(base + k) * float(1 << (8 * k))
             s = s + float(lo) * cnt
         elif sl[0] == "mm":
-            s = mm[:, sl[1]]
+            s = mm_col(sl[1])
         else:
             s = out["sct"][sl[1]].astype(jnp.float64)
         return (s / jnp.maximum(cnt, 1.0) if agg.op == "mean" else s), valid
@@ -1681,15 +1723,141 @@ def _agg_sort_plane(stage: GroupedAggStage, out, agg_idx: int):
     return plane.astype(jnp.float64), valid
 
 
+def _dense_ranks(s) -> Tuple[np.ndarray, np.ndarray]:
+    """(int64 rank of each row's value in the column's natural ascending
+    order, validity), computed on the host where any dtype sorts exactly.
+    DENSE: equal values MUST share a rank, or ties would never reach the next
+    sort key. A null row's rank is 0 and means nothing."""
+    n = len(s)
+    valid = s.validity_numpy()
+    rank = np.zeros(n, dtype=np.int64)
+    dense = None
+    try:
+        vals = s.to_numpy()
+        if vals.dtype.kind in "biufM":
+            _u, inv = np.unique(vals[valid], return_inverse=True)
+            dense = inv
+    except Exception:  # lint: ignore[broad-except] -- falls back to python comparison
+        dense = None
+    if dense is None:  # strings/objects: python comparison
+        arr = s.to_pylist()
+        vv = [arr[i] for i in range(n) if valid[i]]
+        order = {v: r for r, v in enumerate(sorted(set(vv)))}
+        dense = np.asarray([order[v] for v in vv], dtype=np.int64)
+    rank[valid] = dense
+    return rank, valid
+
+
+# rows a block of the select holds: each block is sorted on its own and gives
+# its first K on, level after level, so a table of 2^24 ids is 65,536 sorts of
+# 256, then 2,560 and 100 of them, then one of 1,000 rows, not one sort of
+# 2^24. Short blocks for the chip's compiler: a five-operand sort of blocks of
+# 4,096 took it 70-100 s, of 256 a third of that (PR 38)
+_SELECT_BLOCK = 256
+
+
+def select_top(operands: tuple, num_keys: int, k: int) -> tuple:
+    """The first `k` rows of `operands` in the order of a stable multi-key
+    sort on the first `num_keys` of them (jax.lax.sort's), found without
+    sorting the whole: a row among the first k of the whole is among the
+    first k of its block, so blocks are sorted alone (one sort along the
+    minor axis), each hands on its first k, and the survivors are sorted."""
+    n = operands[0].shape[0]
+    block = _SELECT_BLOCK
+    while block < 4 * k:
+        block *= 2
+    while n >= 2 * block and n % block == 0:
+        rows = n // block
+        operands = jax.lax.sort(tuple(o.reshape(rows, block) for o in operands),
+                                dimension=1, num_keys=num_keys)
+        operands = tuple(o[:, :k].reshape(-1) for o in operands)
+        n = rows * k
+    operands = jax.lax.sort(tuple(operands), num_keys=num_keys)
+    return tuple(o[:k] for o in operands)
+
+
+class RunWideGroups(NamedTuple):
+    """A group-id space that holds for a whole run: the rows of one dimension
+    (`dim`), whose unique join key is among the group-by columns while every
+    other group-by column is `dim`'s own or a dimension's chained from it. A
+    fact row's group id is then `dim`'s row index for it, which the join
+    resolves anyway (_JoinContext.dev_idx): RAW row indices, not ranks among
+    the rows that pass `dim`'s filters, so the tables are as long as the
+    dimension is, padded (orders at SF10: 2^24 ids), and no batch is ever
+    factorized on the host."""
+    dim: DimSpec
+    cols: tuple     # per group-by column: (dimension holding it, its column there)
+
+
+def run_wide_groups(spec: JoinAggSpec) -> Tuple[Optional[RunWideGroups], str]:
+    """(the run-wide id space of `spec`'s group-by, "") or (None, why not)."""
+    by_name = {d.name: d for d in spec.dims}
+    names = []
+    for g in spec.groupby:
+        node = g.child if isinstance(g, Alias) else g
+        names.append(node._name)
+    sides = [spec.col_side.get(c) for c in names]
+    if any(s not in by_name for s in sides):
+        return None, "a group-by column is the fact's"
+
+    def under(dname: str, root: str) -> bool:
+        while dname != root:
+            parent = by_name[dname].parent[0]
+            if parent == "fact":
+                return False
+            dname = parent
+        return True
+
+    for d in spec.dims:
+        # the key itself, or the column it was joined on (q10 groups by
+        # o_custkey, which the join made equal to customer's key)
+        keyed = [(c, s) for c, s in zip(names, sides)
+                 if (s == d.name and c == d.key_col)
+                 or (s == d.parent[0] and c == d.parent[1])]
+        if not keyed:
+            continue
+        cols = tuple((d.name, d.key_col) if (c, s) in keyed else (s, c)
+                     for c, s in zip(names, sides))
+        if all(under(s, d.name) for s, _c in cols):
+            return RunWideGroups(d, cols), ""
+    return None, "no dimension's key with its own columns spans the group-by"
+
+
+def topn_run_wide(ctx: _JoinContext, stage: GroupedAggStage
+                  ) -> Tuple[Optional[RunWideGroups], int, str]:
+    """(id space, its tables' length, "") where a fused TopN over `ctx` keeps
+    run-wide tables, else (None, 0, why it is held to one fact batch). The
+    run and the placement decision both ask here, so what is priced is what
+    runs."""
+    groups, why = run_wide_groups(ctx.spec)
+    if groups is None:
+        return None, 0, why
+    why = stage.run_wide_reason()
+    if why:
+        return None, 0, why
+    cap = pad_bucket(max(ctx.batches[groups.dim.name].num_rows, 1))
+    if cap > TOPN_RUN_MAX_SEGMENTS:
+        return None, 0, (f"the dimension's {cap} padded rows are over the "
+                         f"run-wide table ceiling {TOPN_RUN_MAX_SEGMENTS}")
+    return groups, cap, ""
+
+
 class DeviceJoinTopNRun(DeviceJoinGroupedRun):
     """Join + grouped aggregate + ORDER BY + LIMIT as one device pipeline:
-    the group tables never leave the device — a multi-key lax.sort over the
-    cap-length planes picks the K winners and ONLY their rows are fetched.
-    This is what makes orderkey-cardinality groupbys (TPC-H q3/q10: millions
-    of groups) device-viable: the full-table d2h that rules out the plain
-    grouped path shrinks to K rows. Group codes always come from the host
-    factorize (dense ids in first-occurrence order double as the stable
-    tie-break, matching the host engine's stable sort)."""
+    the group tables never leave the device — a selection over the table-long
+    planes picks the K winners and ONLY their rows are fetched. This is what
+    makes orderkey-cardinality groupbys (TPC-H q3/q10: millions of groups)
+    device-viable: the full-table d2h that rules out the plain grouped path
+    shrinks to K rows.
+
+    Two forms, by the group-by (run_wide_groups). Where it spans one
+    dimension's key space the ids are that dimension's rows and hold for the
+    run: the fact may come in any number of batches, every dispatch adds its
+    batch into ONE set of tables kept on the device
+    (GroupedAggStage._build_run_wide), and no batch is factorized. Any other
+    group-by keeps the older form: ids from the host factorization of ONE
+    batch (dense ids in first-occurrence order double as the stable
+    tie-break), a second batch raises DeviceFallback."""
 
     max_segments = TOPN_MAX_SEGMENTS
     force_host_codes = True
@@ -1697,30 +1865,206 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
     def __init__(self, stage: GroupedAggStage, ctx: _JoinContext, topn: TopNSpec):
         super().__init__(stage, ctx)
         self.topn = topn
+        self.groups, self._cap, why = topn_run_wide(ctx, stage)
+        # why this run is held to one fact batch ("" where it is not)
+        self.one_batch_reason = why
+        self._tables = None
+        self._batches = 0
+
+    @property
+    def run_wide(self) -> bool:
+        return self.groups is not None
 
     def feed_batch(self, batch) -> None:
+        if self.run_wide:
+            return self._feed_run_wide(batch)
         if self._pending and batch.num_rows:
             # bail BEFORE dispatching work the finalize would throw away
             raise DeviceFallback(
-                "device TopN path requires a single fact batch")
+                "device TopN holds this group-by to a single fact batch: "
+                + self.one_batch_reason)
         super().feed_batch(batch)
+        counters.bump("device_join_topn_batches")
+
+    def _feed_run_wide(self, batch) -> None:
+        """One fact batch into the run's tables: the dimension's index plane
+        of the batch IS the ids (a cache hit on a repeat query), so the
+        host's part is the look-ups and two launches."""
+        stage, ctx = self.stage, self.ctx
+        n = batch.num_rows
+        if n == 0:
+            return
+        bucket = pad_bucket(n)
+        needed = list(stage._input_cols) + ["__join_ok__"]
+        with profile_span("device.dispatch", "device", op="join_topn",
+                          rows=n, bucket=bucket):
+            with profile_span("join.codes", "host", strategy="dim_rows",
+                              cap=self._cap):
+                gid = ctx.dev_idx(batch, self.groups.dim.name, bucket)
+            dcols, _ = ctx.provision(batch, bucket, needed)
+            prog = stage._jit_run_wide(self._cap)
+            mask = device_row_mask(n, bucket)
+            lit_args = self.literals.args((self._row_offset,))
+            if self._tables is None:
+                self._tables = stage.run_wide_tables(self._cap)
+                counters.bump("device_topn_table_bytes", sum(
+                    int(x.nbytes) for x in jax.tree_util.tree_leaves(self._tables)))
+            with profile_span("device.launch", "device", op="join_topn",
+                              cap=self._cap, reduce="run_wide"):
+                self._tables = prog(self._tables, dcols, gid, mask, lit_args)
+        self._row_offset += n
+        self._batches += 1
+        counters.bump("device_grouped_batches")
+        counters.bump("device_join_batches")
+        counters.bump("device_join_topn_batches")
 
     def finalize_topn(self):
         """(key_rows, agg_results) for the K winners, in final output order."""
         with profile_span("stage.finalize", "host", op="join_topn") as sp:
-            key_rows, results = self._finalize_topn()
+            key_rows, results = self._finalize_run_wide() if self.run_wide \
+                else self._finalize_topn()
             if sp is not None:
                 sp.args["groups"] = len(key_rows)
             return key_rows, results
 
+    def _empty(self):
+        counters.bump("device_stage_runs")
+        return [], [(np.empty(0), np.empty(0, dtype=bool))
+                    for _ in self.stage.aggs]
+
+    # ---- the run-wide form ---------------------------------------------------------
+    def _group_series(self, index: int):
+        """Group-by column `index` as a Series over the rows of the id
+        space's dimension (its own column, or a chained dimension's gathered
+        through the chain on the host, dimension-long, once)."""
+        ctx, root = self.ctx, self.groups.dim
+        dname, col = self.groups.cols[index]
+        src = ctx._dim_source(dname, col)
+        if dname == root.name:
+            return src
+        chain = []
+        d = next(dd for dd in ctx.dims if dd.name == dname)
+        while d.name != root.name:
+            chain.append(d)
+            d = next(dd for dd in ctx.dims if dd.name == d.parent[0])
+        idxs = tuple(ctx.dim_space_idx(child) for child in reversed(chain))
+
+        def build():
+            rows = np.arange(ctx.batches[root.name].num_rows, dtype=np.int64)
+            ok = np.ones(len(rows), dtype=bool)
+            for idx in idxs:
+                step = idx[np.clip(rows, 0, max(len(idx) - 1, 0))] if len(idx) \
+                    else np.full(len(rows), -1, dtype=np.int64)
+                ok &= step >= 0
+                rows = np.where(ok, step, 0)
+            if len(src) == 0:
+                from ..core.series import Series
+
+                return Series.from_pylist([None] * len(rows), src.name, dtype=src.dtype)
+            # a row the chain does not reach joins nothing: its value is never read
+            return src.take(rows)
+
+        return series_keyed(src, ("jtopn_col", root.name, root.key_col, dname),
+                            idxs, build)
+
+    def _rank_plane(self, index: int, desc: bool, nulls_first: bool):
+        """int32[cap] device plane: each id's place in the order of group-by
+        column `index` (dense ranks, negated for a descending key; nulls and
+        the padding at the end their `nulls_first` asks for). Built once a
+        dimension column and direction, resident after."""
+        cap = self._cap
+        s = self._group_series(index)
+
+        def build():
+            rank, valid = _dense_ranks(s)
+            if desc:
+                rank = -rank
+            edge = np.int64(-(1 << 30) if nulls_first else (1 << 30))
+            plane = np.full(cap, edge, dtype=np.int32)
+            plane[:len(rank)] = np.where(valid, rank, edge).astype(np.int32)
+            return jnp.asarray(plane)
+
+        return series_keyed(s, ("jtopn_rank", cap, desc, nulls_first), (), build)
+
+    def _select_program(self, k: int):
+        """The jitted finalize of the run-wide form: the sort operands from
+        the tables, the selection, and the K winners' rows."""
+        stage, cap, keys = self.stage, self._cap, tuple(self.topn.keys)
+        key = ("topn_select", cap, k, keys)
+        if key not in stage._jitted:
+            def select(tables, ranks):
+                note_program_trace()
+                hi, lo, first = tables["hi"], tables["lo"], tables["first"][:cap]
+
+                def mm_col(j):
+                    # a sum is two float32 planes (grouped_stage._build_run_wide)
+                    return hi[j][:cap].astype(jnp.float64) + lo[j][:cap].astype(jnp.float64)
+
+                present = mm_col(0) > 0
+                operands = [jnp.where(present, 0, 1).astype(jnp.int32)]
+                ranks = list(ranks)
+                for kind, idx, desc, nf in keys:
+                    if kind == "group":
+                        operands.append(ranks.pop(0))
+                        continue
+                    v, valid = _agg_sort_plane(stage, mm_col, None, idx)
+                    if desc:
+                        v = -v
+                    operands.append(jnp.where(valid, v, -jnp.inf if nf else jnp.inf))
+                # ties: the order the groups were first seen in, as the host
+                # engine's stable sort over its first-occurrence output
+                operands.append(first)
+                gid = jnp.arange(cap, dtype=jnp.int32)
+                top = select_top(tuple(operands) + (gid,), len(operands), k)[-1]
+                rows = [hi[j][top].astype(jnp.float64) + lo[j][top].astype(jnp.float64)
+                        for j in range(len(hi))]
+                return top, jnp.stack(rows, axis=-1), present[top], tables["dense"]
+
+            stage._jitted[key] = jax.jit(select)
+        return stage._jitted[key]
+
+    def _finalize_run_wide(self):
+        stage = self.stage
+        tables, self._tables = self._tables, None
+        batches, self._batches = self._batches, 0
+        self._row_offset = 0
+        if tables is None:
+            return self._empty()
+        k_eff = min(self.topn.offset + self.topn.limit, self._cap)
+        ranks = tuple(self._rank_plane(idx, desc, nf)
+                      for kind, idx, desc, nf in self.topn.keys if kind == "group")
+        with profile_span("join.topn_select", "device", cap=self._cap,
+                          rows=int(k_eff), batches=batches) as sp:
+            fetch = self._select_program(k_eff)(tables, ranks)
+            with profile_span("device.d2h", "device", op="join_topn",
+                              rows=int(k_eff)):
+                gids, mm_rows, present_rows, dense = jax.device_get(fetch)
+            if sp is not None:
+                sp.args["dense_batches"] = int(dense)
+        del tables
+        counters.bump("device_stage_runs")
+        counters.bump("device_topn_runs")
+        counters.bump("device_topn_fetched_rows", int(k_eff))
+
+        off = self.topn.offset
+        keep = np.asarray(present_rows)[off:]
+        gids = np.asarray(gids)[off:][keep].astype(np.int64)
+        mm_rows = np.asarray(mm_rows, dtype=np.float64)[off:][keep]
+        from .grouped_stage import results_from_tables
+
+        key_cols = [self._group_series(i).take(gids).to_pylist()
+                    for i in range(len(self.groups.cols))]
+        key_rows = list(zip(*key_cols)) if len(gids) else []
+        results = results_from_tables(stage, mm_rows, [np.zeros(len(gids))], [])
+        return key_rows, results
+
+    # ---- the one-batch form --------------------------------------------------------
     def _finalize_topn(self):
         stage = self.stage
         pending, self._pending = self._pending, []
         self._row_offset = 0
         if not pending:
-            counters.bump("device_stage_runs")
-            return [], [(np.empty(0), np.empty(0, dtype=bool))
-                        for _ in stage.aggs]
+            return self._empty()
         if len(pending) > 1:
             raise DeviceFallback(
                 "device TopN path requires a single fact batch")
@@ -1732,30 +2076,33 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
         k_eff = min(self.topn.offset + self.topn.limit, cap)
 
         mm = out["mm"]
-        present = mm[:, 0] > 0
-        operands = [jnp.where(present, 0.0, 1.0).astype(jnp.float32)]
-        for kind, idx, desc, nf in self.topn.keys:
-            if kind == "agg":
-                v, valid = _agg_sort_plane(stage, out, idx)
-            else:
-                v, valid = fc.rank_plane(idx)
-                v = v.astype(jnp.float64)
-            if desc:
-                v = -v
-            v = jnp.where(valid, v, -jnp.inf if nf else jnp.inf)
-            operands.append(v)
-        gid = jnp.arange(cap, dtype=jnp.int32)
-        sorted_ops = jax.lax.sort(tuple(operands) + (gid,),
-                                  num_keys=len(operands) + 1)
-        top = sorted_ops[-1][:k_eff]
-        fetch = (top, mm[top],
-                 tuple(e[top] for e in out["ext"]),
-                 tuple(s[top] for s in out["sct"]),
-                 present[top])
-        with profile_span("device.d2h", "device", op="join_topn", rows=int(k_eff)):
-            gids, mm_rows, ext_rows, sct_rows, present_rows = jax.device_get(fetch)
+        with profile_span("join.topn_select", "device", cap=cap, rows=int(k_eff),
+                          batches=1):
+            present = mm[:, 0] > 0
+            operands = [jnp.where(present, 0.0, 1.0).astype(jnp.float32)]
+            for kind, idx, desc, nf in self.topn.keys:
+                if kind == "agg":
+                    v, valid = _agg_sort_plane(stage, lambda j: mm[:, j], out, idx)
+                else:
+                    v, valid = fc.rank_plane(idx)
+                    v = v.astype(jnp.float64)
+                if desc:
+                    v = -v
+                v = jnp.where(valid, v, -jnp.inf if nf else jnp.inf)
+                operands.append(v)
+            gid = jnp.arange(cap, dtype=jnp.int32)
+            sorted_ops = jax.lax.sort(tuple(operands) + (gid,),
+                                      num_keys=len(operands) + 1)
+            top = sorted_ops[-1][:k_eff]
+            fetch = (top, mm[top],
+                     tuple(e[top] for e in out["ext"]),
+                     tuple(s[top] for s in out["sct"]),
+                     present[top])
+            with profile_span("device.d2h", "device", op="join_topn", rows=int(k_eff)):
+                gids, mm_rows, ext_rows, sct_rows, present_rows = jax.device_get(fetch)
         counters.bump("device_stage_runs")
         counters.bump("device_topn_runs")
+        counters.bump("device_topn_fetched_rows", int(k_eff))
 
         off = self.topn.offset
         keep = np.asarray(present_rows)[off:]

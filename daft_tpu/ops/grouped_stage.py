@@ -1041,6 +1041,170 @@ class GroupedAggStage:
 
         return jax.jit(stage)
 
+    def run_wide_reason(self) -> Optional[str]:
+        """Why this stage's partials cannot be kept as run-wide tables
+        (_build_run_wide), or None where they can: the tables hold the matmul
+        planes alone (sums, counts, means)."""
+        if self._sct_specs:
+            return "the stage needs 64-bit scatter extremes"
+        if self._use_f64:
+            return "the stage computes in float64"
+        if len(self._ext_specs) > 1:
+            return "the stage has a min or max"
+        return None
+
+    def run_wide_tables(self, cap: int) -> dict:
+        """The empty tables of one run over `cap` group ids (_build_run_wide)."""
+        length = cap + min(CHUNK_LOCAL, cap)
+        zeros = tuple(jnp.zeros(length, jnp.float32) for _ in self._mm_specs)
+        return {"hi": zeros, "lo": tuple(jnp.zeros_like(z) for z in zeros),
+                "first": jnp.full(length, _NO_ROW, jnp.int32),
+                "dense": jnp.zeros((), jnp.int32)}
+
+    def _jit_run_wide(self, cap: int) -> Callable:
+        key = ("run_wide", cap)
+        if key not in self._jitted:
+            self._jitted[key] = self._build_run_wide(cap)
+        return self._jitted[key]
+
+    def _build_run_wide(self, cap: int) -> Callable:
+        """One batch into the tables of a whole run: the program of a run
+        whose group ids mean the same in every batch (device_join's fused
+        TopN: a fact row's id is a dimension's row). The tables are the
+        program's first argument and its result, donated, so a run of any
+        number of dispatches holds one set, each `cap` ids long (and a chunk
+        of slack, so a chunk's window never needs clamping): for each matmul
+        plane of _mm_specs a sum as TWO float32 planes, `hi` + `lo` (a
+        double-single: about 48 bits, each addition an error-free two-sum),
+        the int32 position in the run's stream of each id's first kept row
+        (the order the host engine's stable sort leaves ties in), and how
+        many dispatches took the dense form. Not float64 planes: the chip
+        keeps a float64 array as two float32 ones INSIDE a program only, and
+        converts the whole of it at the program's entry and exit (on a v5e
+        4-5 ms a dispatch for three planes of 2^24 ids, whatever the batch
+        added: PR 38's chip run).
+
+        A dispatch adds up in one of two forms, by what its ids are, seen on
+        the device: where every chunk of CHUNK_LOCAL rows holds ids within
+        CHUNK_LOCAL of each other (a fact sorted by the dimension's key, as
+        lineitem by order: the locally dense layout of _build_local_dense
+        with no host permutation), a chunk's float32 planes are contracted
+        with its one-hot on the MXU and added to its window of the tables;
+        any other batch scatter-adds float32 rows into a float32 table of its
+        own, added to the run's whole. Either way a batch's partial is
+        float32 and the run's sum wider, as the merge on the host was."""
+        reason = self.run_wide_reason()
+        if reason is not None:
+            raise DeviceFallback("run-wide tables: " + reason)
+        planes_of = self._chunk_planes(cap, jnp.float32, ())
+        slots = self.slots
+        n_mm = len(self._mm_specs)
+
+        def stage(tables, cols: Dict[str, dev.DCol], gid: jnp.ndarray,
+                  row_mask: jnp.ndarray, lit_args):
+            note_program_trace()
+            lits = slots.unpack(lit_args)
+            offset = slots.run_value(lit_args, 0).astype(jnp.int32)
+            bucket = gid.shape[0]
+            chunk = min(CHUNK_LOCAL, bucket, cap)
+            n_chunks = bucket // chunk
+            # filtered, padding and unjoined rows carry the id `cap`
+            seg, mm, _ext, _sct = planes_of(cols, gid, row_mask, lits)
+            seg = jnp.where(seg < 0, cap, seg)
+            kept = seg < cap
+            pos = jnp.arange(bucket, dtype=jnp.int32) + offset
+            g = seg.reshape(n_chunks, chunk)
+            lo = jnp.min(g, axis=1)                          # cap: nothing kept
+            top = jnp.max(jnp.where(g < cap, g, -1), axis=1)
+            dense = jnp.all(top - lo < chunk)
+            lo = jnp.minimum(lo, cap - 1)
+            vals = jnp.stack(mm, axis=-1)                    # [bucket, P] f32
+
+            def dense_form(acc):
+                acc_hi, acc_lo, acc_first = acc
+                local = jnp.where(g < cap, g - lo[:, None], chunk)
+                ids = jnp.arange(chunk, dtype=jnp.int32)
+                # a float32 as three bfloat16 terms (8 + 8 + 8 bits of it):
+                # the one-hot is exact in bfloat16, so ONE pass of the MXU
+                # over the three gives what Precision.HIGHEST takes six for
+                terms = _bfloat16_terms(vals).reshape(n_chunks, chunk, 3 * n_mm)
+
+                def body(carry, xs):
+                    c_hi, c_lo, c_first = carry
+                    s, v, p, at = xs
+                    oh = s[:, None] == ids[None, :]
+                    part = jnp.matmul(oh.astype(jnp.bfloat16).T, v,
+                                      preferred_element_type=jnp.float32)
+                    part = part[:, :n_mm] + part[:, n_mm:2 * n_mm] + part[:, 2 * n_mm:]
+                    new_hi, new_lo = [], []
+                    for k in range(n_mm):
+                        h, l = _two_sum_add(
+                            jax.lax.dynamic_slice(c_hi[k], (at,), (chunk,)),
+                            jax.lax.dynamic_slice(c_lo[k], (at,), (chunk,)), part[:, k])
+                        new_hi.append(jax.lax.dynamic_update_slice(c_hi[k], h, (at,)))
+                        new_lo.append(jax.lax.dynamic_update_slice(c_lo[k], l, (at,)))
+                    first = jnp.min(jnp.where(oh, p[:, None], _NO_ROW), axis=0)
+                    cur = jax.lax.dynamic_slice(c_first, (at,), (chunk,))
+                    c_first = jax.lax.dynamic_update_slice(
+                        c_first, jnp.minimum(cur, first), (at,))
+                    return (tuple(new_hi), tuple(new_lo), c_first), None
+
+                out, _ = jax.lax.scan(
+                    body, (acc_hi, acc_lo, acc_first),
+                    (local, terms, pos.reshape(n_chunks, chunk), lo))
+                return out
+
+            def scatter_form(acc):
+                acc_hi, acc_lo, acc_first = acc
+                at = jnp.where(kept, seg, acc_first.shape[0])   # out of range: dropped
+                new_hi, new_lo = zip(*(
+                    _two_sum_add(h, l, jnp.zeros(h.shape, jnp.float32)
+                                 .at[at].add(vals[:, k], mode="drop"))
+                    for k, (h, l) in enumerate(zip(acc_hi, acc_lo))))
+                return new_hi, new_lo, acc_first.at[at].min(pos, mode="drop")
+
+            acc_hi, acc_lo, acc_first = jax.lax.cond(
+                dense, dense_form, scatter_form,
+                (tables["hi"], tables["lo"], tables["first"]))
+            return {"hi": acc_hi, "lo": acc_lo, "first": acc_first,
+                    "dense": tables["dense"] + dense.astype(jnp.int32)}
+
+        return jax.jit(stage, donate_argnums=0)
+
+
+def _two_sum_add(hi, lo, x):
+    """(hi, lo) + x for a double-single sum and a float32 addend, with the
+    rounding error of hi + x kept in lo (Knuth's two-sum) and the pair
+    renormalized so that hi is the sum rounded to float32."""
+    s = hi + x
+    back = s - hi
+    lo = lo + ((hi - (s - back)) + (x - back))
+    new_hi = s + lo
+    # an infinite or NaN sum stays what it is (its error term would be a NaN)
+    ok = jnp.isfinite(s)
+    return jnp.where(ok, new_hi, s), jnp.where(ok, lo - (new_hi - s), 0.0)
+
+
+def _bfloat16_terms(vals: jnp.ndarray) -> jnp.ndarray:
+    """[rows, P] float32 -> [rows, 3P] bfloat16: each value as the three
+    bfloat16 terms that add up to it (to its 24 bits). The terms are cut
+    with reduce_precision: the chip's compiler is allowed excess precision
+    and drops a float32 -> bfloat16 -> float32 round trip, which left every
+    value as its first term alone (8 bits: q3 read 1.1e-3 off at SF10, PR
+    38's chip run)."""
+    def cut(x):
+        return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+
+    first = cut(vals)
+    # an infinite value stays one term (inf - inf would make it a NaN)
+    rest = jnp.where(jnp.isfinite(vals), vals - first, 0.0)
+    second = cut(rest)
+    return jnp.concatenate([first, second, rest - second], axis=-1).astype(jnp.bfloat16)
+
+
+# "no kept row" in a run-wide first-row table: past every position
+_NO_ROW = np.int32(np.iinfo(np.int32).max)
+
 
 def _row_offset(slots: dev.LiteralSlots, lit_args, rows_before):
     """The f64 position of a dispatch's first row in its run's stream: the

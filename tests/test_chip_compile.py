@@ -243,3 +243,97 @@ def test_sharded_q6_program_lowers_without_a_collective(topo):
     text = compiled.as_text()
     assert not [c for c in COLLECTIVES if c in text]
     assert "f64[67108864]" not in text
+
+
+# ---- the run-wide join TopN at tpch-sf10-joins-1chip's shapes ------------------------------
+# q3 over 458 batches of 131,072 lineitem rows: the group ids are orders' rows
+# (15 M, padded to 2^24), one set of tables for the run.
+JOIN_BATCH = 1 << 17
+ORDERS_CAP = 1 << 24
+
+
+def _q3_join_stage():
+    """The stage of a q3-shaped star join (built from tables of a few rows:
+    a stage is its expressions' structure, not its data)."""
+    import datetime
+
+    import daft_tpu
+    from daft_tpu import col
+    from daft_tpu.ops.device_join import build_join_stage, try_capture_join_topn
+
+    day = datetime.date(1995, 3, 15)
+    t = {"customer": {"c_custkey": [1, 2], "c_mktsegment": ["BUILDING", "X"]},
+         "orders": {"o_orderkey": [1, 2], "o_custkey": [1, 2], "o_orderdate": [day, day],
+                    "o_shippriority": [0, 0]},
+         "lineitem": {"l_orderkey": [1, 2, 2], "l_extendedprice": [1.0, 2.0, 3.0],
+                      "l_discount": [0.0, 0.1, 0.2], "l_shipdate": [day, day, day]}}
+    t = {n: daft_tpu.from_pydict(c) for n, c in t.items()}
+    q = (t["customer"].where(col("c_mktsegment") == "BUILDING")
+         .join(t["orders"], left_on="c_custkey", right_on="o_custkey")
+         .where(col("o_orderdate") < day)
+         .join(t["lineitem"], left_on="o_orderkey", right_on="l_orderkey")
+         .where(col("l_shipdate") > day)
+         .groupby(col("o_orderkey").alias("l_orderkey"), "o_orderdate", "o_shippriority")
+         .agg((col("l_extendedprice") * (1 - col("l_discount"))).sum().alias("revenue"))
+         .sort(["revenue", "o_orderdate"], desc=[True, False]).limit(10))
+    spec, topn, _out = try_capture_join_topn(q._builder.optimize()._plan)
+    stage, grouped = build_join_stage(spec)
+    assert grouped and stage.run_wide_reason() is None
+    return stage, topn
+
+
+def _run_wide_tables(stage, sharding, cap):
+    shapes = jax.eval_shape(lambda: stage.run_wide_tables(cap))
+    return jax.tree_util.tree_map(lambda x: _s(sharding, x.shape, x.dtype), shapes)
+
+
+def test_run_wide_topn_accumulate_lowers_at_sf10(one_chip):
+    """One dispatch of q3's run-wide program: a batch of 131,072 rows into
+    tables of 2^24 order ids, both forms in one program, the tables donated
+    (no second copy of them among the temporaries)."""
+    stage, _topn = _q3_join_stage()
+    tables = _run_wide_tables(stage, one_chip, ORDERS_CAP)
+    ints = {"l_shipdate"}
+    cols = {name: (_s(one_chip, (JOIN_BATCH,), jnp.bool_ if name == "__join_ok__"
+                      else jnp.int32 if name in ints else jnp.float32),
+                   _s(one_chip, (JOIN_BATCH,), jnp.bool_)) for name in stage._input_cols}
+    compiled = stage._build_run_wide(ORDERS_CAP).lower(
+        tables, cols, _s(one_chip, (JOIN_BATCH,), jnp.int32),
+        _s(one_chip, (JOIN_BATCH,), jnp.bool_), _literal_args(stage, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    table_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(tables))
+    assert table_bytes == (len(stage._mm_specs) * 2 * 4 + 4) * (ORDERS_CAP + 4096) + 4
+    assert mem.argument_size_in_bytes >= table_bytes
+    # the scatter form's float32 table of one plane at a time, never a copy of the run's tables
+    assert mem.temp_size_in_bytes < table_bytes
+    assert mem.alias_size_in_bytes >= table_bytes - 8
+
+
+def test_run_wide_topn_select_lowers_at_sf10(one_chip):
+    """q3's finalize over tables of 2^24 ids: sorts of blocks of 256 that each
+    hand on their first 10, level after level, then one sort of 1,000
+    survivors, never a sort of 2^24."""
+    from daft_tpu.ops.device_join import select_top
+
+    def select(absent, revenue, rank, first):
+        gid = jnp.arange(ORDERS_CAP, dtype=jnp.int32)
+        return select_top((absent, -revenue, rank, first, gid), 4, 10)
+
+    args = (_s(one_chip, (ORDERS_CAP,), jnp.int32), _s(one_chip, (ORDERS_CAP,), jnp.float64),
+            _s(one_chip, (ORDERS_CAP,), jnp.int32), _s(one_chip, (ORDERS_CAP,), jnp.int32))
+    compiled = jax.jit(select).lower(*args).compile()
+    text = compiled.as_text()
+    assert "sort" in text
+    assert compiled.memory_analysis().output_size_in_bytes < 4096
+
+
+def test_bfloat16_terms_survive_the_chips_compiler(one_chip):
+    """The three bfloat16 terms of a float32 are cut with reduce_precision,
+    which the chip's compiler keeps; a float32 -> bfloat16 -> float32 round
+    trip it may drop as excess precision, and then the first term is the
+    whole value and the other two are zero (q3 read 1.1e-3 off, PR 38)."""
+    from daft_tpu.ops.grouped_stage import _bfloat16_terms
+
+    compiled = jax.jit(_bfloat16_terms).lower(_s(one_chip, (4096, 3), jnp.float32)).compile()
+    assert compiled.as_text().count("reduce-precision(") >= 2

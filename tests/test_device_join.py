@@ -597,3 +597,346 @@ def test_changed_literal_gives_the_new_answer(tpch_like, shape, variants):
         answers.append(dev)
     assert answers[0] != base and answers[1] != base and answers[2] == base
     manager().clear()
+
+
+# ---- the fused TopN over a fact of any number of batches ------------------------------
+#
+# q3- and q10-shaped star joins whose group-by spans one dimension's key
+# space: the group ids are that dimension's rows, one set of tables stays on
+# the device for the run, and only the limit's rows come back.
+
+
+def _topn_tables(n_l, seed=11):
+    """Tables for the TopN shapes: a fact sorted by order key (q3's ids are
+    then locally dense, q10's customer ids are not), revenues that tie (whole
+    prices, discounts of 0 or a half), order dates that grow with the key (a
+    date cut leaves the last batches with nothing kept), order keys the dim
+    lacks and customer keys the dim lacks (join misses)."""
+    import datetime
+
+    rng = np.random.default_rng(seed)
+    n_o, n_c = max(n_l // 3, 64), 97
+    day0 = datetime.date(1994, 1, 1)
+    t = {
+        "nation": {"n_nationkey": list(range(25)), "n_name": [f"N{i:02d}" for i in range(25)]},
+        "customer": {"c_custkey": list(range(n_c)),
+                     "c_name": [f"Customer#{i:05d}" for i in range(n_c)],
+                     "c_acctbal": rng.uniform(-999, 9999, n_c).round(2).tolist(),
+                     "c_mktsegment": rng.choice(["BUILDING", "MACHINERY"], n_c).tolist(),
+                     "c_nationkey": rng.integers(0, 25, n_c).tolist()},
+        # every seventh order key is missing; a few orders name no customer
+        "orders": {"o_orderkey": [k for k in range(n_o) if k % 7 != 3],
+                   "o_custkey": [int(c) for k, c in enumerate(rng.integers(0, n_c + 5, n_o))
+                                 if k % 7 != 3],
+                   "o_orderdate": [day0 + datetime.timedelta(days=int(730 * k / n_o))
+                                   for k in range(n_o) if k % 7 != 3],
+                   "o_shippriority": [int(k % 2) for k in range(n_o) if k % 7 != 3]},
+        "lineitem": {"l_orderkey": np.sort(rng.integers(0, n_o, n_l)).tolist(),
+                     "l_extendedprice": rng.integers(1, 6, n_l).astype(float).tolist(),
+                     "l_discount": (rng.integers(0, 2, n_l) * 0.5).tolist(),
+                     "l_returnflag": rng.choice(["R", "A", "N"], n_l).tolist(),
+                     "l_shipdate": [day0 + datetime.timedelta(days=int(x))
+                                    for x in rng.integers(0, 730, n_l)]},
+    }
+    return {name: daft_tpu.from_pydict(cols).collect() for name, cols in t.items()}
+
+
+def _topn_q3(t, offset=0, cut=(1995, 3, 15)):
+    q = (t["customer"].where(col("c_mktsegment") == "BUILDING")
+         .join(t["orders"], left_on="c_custkey", right_on="o_custkey")
+         .where(col("o_orderdate") < _days(*cut))
+         .join(t["lineitem"], left_on="o_orderkey", right_on="l_orderkey")
+         .where(col("l_shipdate") > _days(1994, 2, 1))
+         .groupby(col("o_orderkey").alias("l_orderkey"), "o_orderdate", "o_shippriority")
+         .agg((col("l_extendedprice") * (1 - col("l_discount"))).sum().alias("revenue"))
+         .select("l_orderkey", "revenue", "o_orderdate", "o_shippriority")
+         .sort(["revenue", "o_shippriority"], desc=[True, False]))
+    return (q.offset(offset) if offset else q).limit(10)
+
+
+def _topn_q10(t, offset=0, cut=(1995, 3, 15)):
+    q = (t["orders"].where(col("o_orderdate") < _days(*cut))
+         .join(t["lineitem"].where(col("l_returnflag") == "R"),
+               left_on="o_orderkey", right_on="l_orderkey")
+         .join(t["customer"], left_on="o_custkey", right_on="c_custkey")
+         .join(t["nation"], left_on="c_nationkey", right_on="n_nationkey")
+         .groupby(col("o_custkey").alias("c_custkey"), "c_name", "c_acctbal", "n_name")
+         .agg((col("l_extendedprice") * (1 - col("l_discount"))).sum().alias("revenue"))
+         .select("c_custkey", "c_name", "revenue", "c_acctbal", "n_name")
+         # whole revenues tie: the order of ties is the host engine's stable sort's
+         .sort(["revenue"], desc=[True]))
+    return (q.offset(offset) if offset else q).limit(20)
+
+
+_TOPN_COUNTERS = ("device_topn_runs", "device_join_topn_batches",
+                  "device_topn_fetched_rows", "device_topn_table_bytes")
+
+
+@pytest.mark.parametrize("batches", [1, 3, 7])
+@pytest.mark.parametrize("shape,limit", [(_topn_q3, 10), (_topn_q10, 20)], ids=["q3", "q10"])
+def test_fused_topn_takes_a_fact_of_any_number_of_batches(shape, limit, batches):
+    """The fused TopN over 1, 3 and 7 fact batches gives the host engine's
+    rows in the host engine's order (ties included), fetches no more rows
+    than its limit and counts every batch; a repeat builds nothing."""
+    from daft_tpu.observability.metrics import registry
+
+    t = _topn_tables(n_l=_MORSEL * batches - 100)
+    host = _host_answer(lambda: shape(t))
+    revenues = host["revenue"]
+    assert len(revenues) == limit and len(set(revenues)) < len(revenues), \
+        "the tables are made so that the sort key ties"
+    for rep in range(2):
+        before = {k: registry().get(k) for k in _TOPN_COUNTERS + ("hbm_cache_misses",)}
+        counters.rejections.clear()
+        with _morselized("on"):
+            dev = shape(t).to_pydict()
+        d = {k: registry().get(k) - before[k] for k in before}
+        assert d["device_topn_runs"] == 1, counters.rejections
+        assert d["device_join_topn_batches"] == batches
+        assert d["device_topn_fetched_rows"] == limit
+        assert d["device_topn_table_bytes"] > 0
+        assert not any("multi-batch" in k for k in counters.rejections)
+        if rep:
+            assert d["hbm_cache_misses"] == 0, "a repeat query builds no slot"
+        _assert_close(host, dev)
+
+
+@pytest.mark.parametrize("shape", [_topn_q3, _topn_q10], ids=["q3", "q10"])
+def test_fused_topn_offset_and_a_filter_that_empties_batches(shape):
+    """An offset skips the first winners; a date cut that keeps only the
+    first fifth of the orders leaves the later batches with no kept row."""
+    t = _topn_tables(n_l=_MORSEL * 5 - 100)
+    for offset, cut in ((3, (1995, 3, 15)), (0, (1994, 5, 1)), (4, (1994, 5, 1))):
+        q = lambda: shape(t, offset=offset, cut=cut)
+        host = _host_answer(q)
+        assert host["revenue"], "the cut keeps some groups"
+        counters.reset()
+        with _morselized("on"):
+            dev = q().to_pydict()
+        assert counters.device_topn_runs == 1, counters.rejections
+        assert counters.device_join_topn_batches == 5
+        _assert_close(host, dev)
+
+
+def test_fused_topn_scatter_and_dense_forms_agree():
+    """q3's ids (a fact sorted by the order key) take the dense form at every
+    dispatch; the same rows shuffled take the scatter form, to the same answer."""
+    import daft_tpu.ops.device_join as dj
+
+    t = _topn_tables(n_l=_MORSEL * 7 - 100)    # more orders than a chunk's window is wide
+    seen = []
+    real = dj.DeviceJoinTopNRun._finalize_run_wide
+
+    def spy(self):
+        tables = self._tables
+        seen.append((self._batches, None if tables is None else int(tables["dense"])))
+        return real(self)
+
+    dj.DeviceJoinTopNRun._finalize_run_wide = spy
+    try:
+        with _morselized("on"):
+            sorted_answer = _topn_q3(t).to_pydict()
+        li = t["lineitem"].to_pydict()
+        perm = np.random.default_rng(3).permutation(len(li["l_orderkey"]))
+        shuffled = dict(t, lineitem=daft_tpu.from_pydict(
+            {c: [v[i] for i in perm] for c, v in li.items()}).collect())
+        host = _host_answer(lambda: _topn_q3(shuffled))
+        with _morselized("on"):
+            shuffled_answer = _topn_q3(shuffled).to_pydict()
+    finally:
+        dj.DeviceJoinTopNRun._finalize_run_wide = real
+    assert seen == [(7, 7), (7, 0)], seen
+    _assert_close(_host_answer(lambda: _topn_q3(t)), sorted_answer)
+    _assert_close(host, shuffled_answer)
+
+
+def test_topn_group_by_outside_a_dimension_keeps_the_one_batch_form():
+    """A group-by that holds a fact column has no run-wide id space: one
+    batch rides the fused program as before, a second one sends the query to
+    the host plan, and the rejection says why."""
+    t = _topn_tables(n_l=_MORSEL * 3 - 100)
+
+    def q():
+        return (t["orders"].join(t["lineitem"], left_on="o_orderkey", right_on="l_orderkey")
+                .groupby("l_orderkey", "o_shippriority")
+                .agg(col("l_extendedprice").sum().alias("s"))
+                .sort(["s", "l_orderkey"], desc=[True, False]).limit(5))
+
+    host = _host_answer(q)
+    counters.reset()
+    with _morselized("on"):
+        dev = q().to_pydict()
+    assert counters.device_topn_runs == 0
+    reasons = [k for k in counters.rejections if "multi-batch fact" in k]
+    assert reasons and "no run-wide group ids" in reasons[0], counters.rejections
+    assert any("a group-by column is the fact's" in why
+               for _site, why in counters.rejection_log), counters.rejection_log
+    _assert_close(host, dev)
+    # one batch: the fused program, on the host-factorized ids
+    counters.reset()
+    with execution_config_ctx(device_mode="on"):
+        dev = q().to_pydict()
+    assert counters.device_topn_runs == 1 and counters.device_join_topn_batches == 1
+    assert counters.device_topn_fetched_rows == 5
+    assert counters.device_topn_table_bytes == 0, "no run-wide table was built"
+    _assert_close(host, dev)
+
+
+def test_run_wide_groups_names_the_dimension_or_says_why():
+    from daft_tpu.ops.device_join import run_wide_groups, try_capture_join_topn
+
+    t = _topn_tables(n_l=500)
+
+    def spec_of(df):
+        return try_capture_join_topn(df._builder.optimize()._plan)[0]
+
+    g3, why = run_wide_groups(spec_of(_topn_q3(t)))
+    assert why == "" and g3.dim.key_col == "o_orderkey"
+    g10, why = run_wide_groups(spec_of(_topn_q10(t)))
+    # q10 groups by o_custkey, which the join made equal to customer's key;
+    # n_name is a dimension's chained from customer
+    assert why == "" and g10.dim.key_col == "c_custkey"
+    assert [c for _d, c in g10.cols] == ["c_custkey", "c_name", "c_acctbal", "n_name"]
+    no_key = (t["orders"].join(t["lineitem"], left_on="o_orderkey", right_on="l_orderkey")
+              .groupby("o_orderdate").agg(col("l_discount").sum().alias("s"))
+              .sort("s").limit(3))
+    g, why = run_wide_groups(spec_of(no_key))
+    assert g is None and "no dimension's key" in why
+
+
+def test_a_topn_verdict_is_keyed_on_the_whole_fact_not_its_first_partition():
+    """Two q3-shaped plans whose first fact partition looks the same (one
+    morsel of the same rows) but whose facts are 1 and 3 batches long get
+    different decision keys: a cached verdict of one never serves the other.
+    A join that is no TopN keys as before, on the first partition alone."""
+    from daft_tpu.config import execution_config as get_config
+    from daft_tpu.execution import executor
+    from daft_tpu.plan import physical as pp
+
+    def topn_node(n_l):
+        t = _topn_tables(n_l=n_l)
+        with _morselized("on"):
+            plan = pp.translate(_topn_q3(t)._builder.optimize()._plan, get_config())
+        found, todo = [], [plan]
+        while todo:
+            n = todo.pop()
+            if isinstance(n, pp.DeviceJoinTopN):
+                found.append(n)
+            todo.extend(n.children())
+        assert len(found) == 1
+        return found[0]
+
+    one, three = topn_node(_MORSEL), topn_node(_MORSEL * 3)
+    assert executor._resident_rows(one.fact) == _MORSEL
+    assert executor._resident_rows(three.fact) == _MORSEL * 3
+    cfg, layout = get_config(), (1, _MORSEL)
+    # the dims are other objects in the two plans: compare the part of the
+    # key that comes before their identity tokens
+    key = lambda node, topn: executor._decision_key(node, _MORSEL, cfg, topn, layout)[:-1]
+    assert key(one, True) != key(three, True)
+    assert key(one, True) == key(topn_node(_MORSEL), True)
+    assert key(one, False) == key(three, False)
+
+
+def test_run_wide_sums_are_double_singles():
+    """What the run-wide tables hold: a sum as two float32 planes that a
+    two-sum keeps exact to about 48 bits over hundreds of additions (a
+    float32 alone drifts by 1e-6), infinities staying what they are; and a
+    float32 as three bfloat16 terms that add up to all 24 of its bits."""
+    import jax.numpy as jnp
+    from daft_tpu.ops.grouped_stage import _bfloat16_terms, _two_sum_add
+
+    rng = np.random.default_rng(5)
+    parts = (rng.random((458, 64)) * 1e5).astype(np.float32)
+    hi, lo = jnp.zeros(64, jnp.float32), jnp.zeros(64, jnp.float32)
+    alone = jnp.zeros(64, jnp.float32)
+    for x in parts:
+        hi, lo = _two_sum_add(hi, lo, jnp.asarray(x))
+        alone = alone + jnp.asarray(x)
+    want = parts.astype(np.float64).sum(axis=0)
+    got = np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
+    assert np.max(np.abs(got - want) / want) < 1e-12
+    assert np.max(np.abs(np.asarray(alone, np.float64) - want) / want) > 1e-8
+    h, l = _two_sum_add(jnp.asarray([1.0, np.inf], jnp.float32), jnp.zeros(2, jnp.float32),
+                        jnp.asarray([np.inf, 1.0], jnp.float32))
+    assert np.isinf(np.asarray(h)).all() and not np.asarray(l).any()
+
+    vals = jnp.asarray((rng.random((1000, 2)) * 1e4).astype(np.float32))
+    terms = np.asarray(_bfloat16_terms(vals).astype(jnp.float32), np.float64)
+    back = terms[:, :2] + terms[:, 2:4] + terms[:, 4:]
+    assert np.max(np.abs(back - np.asarray(vals, np.float64)) / np.asarray(vals)) < 2.0 ** -22
+    assert np.max(np.abs(terms[:, :2] - np.asarray(vals, np.float64)) / np.asarray(vals)) > 1e-4
+
+
+def test_select_top_is_a_stable_multi_key_sort():
+    import jax
+    import jax.numpy as jnp
+    from daft_tpu.ops.device_join import select_top
+
+    rng = np.random.default_rng(0)
+    n = 1 << 15
+    a = jnp.asarray(rng.integers(0, 4, n).astype(np.int32))
+    b = jnp.asarray(-rng.integers(0, 50, n).astype(np.float64))
+    c = jnp.asarray(rng.permutation(n).astype(np.int32))
+    gid = jnp.arange(n, dtype=jnp.int32)
+    for k in (1, 20, 1500):
+        want = jax.lax.sort((a, b, c, gid), num_keys=3)[-1][:k]
+        got = select_top((a, b, c, gid), 3, k)[-1]
+        assert np.array_equal(np.asarray(want), np.asarray(got)), k
+
+
+# ---- a dimension's key lookup is made once, not once a fact batch ---------------------
+
+
+def _key_series(keys):
+    from daft_tpu.core.series import Series
+
+    return Series.from_pylist(list(keys), "k")
+
+
+@pytest.mark.parametrize("form", ["dense", "hash", "sorted"])
+def test_unique_key_index_probes_a_lookup_built_once(form, monkeypatch):
+    """idx[i] is the dimension's row of probe key i or -1 (a miss, a null
+    probe, a null key), in the three forms a key column can take; what is as
+    long as the dimension (the uniqueness check, the table) is built once a
+    key column however many batches probe it."""
+    import daft_tpu.native as native
+    import daft_tpu.ops.device_join as dj
+    from daft_tpu import DataType
+
+    rng = np.random.default_rng(5)
+    if form == "dense":
+        keys = [int(k) for k in rng.permutation(3000)[:2500]]
+    else:
+        keys = [int(k) for k in rng.choice(10**12, 2500, replace=False)]
+        if form == "sorted":
+            monkeypatch.setattr(native, "native_i64_map_build", lambda vv: None)
+    keys[17] = None                         # a null key joins nothing
+    s = _key_series(keys)
+    row_of = {k: i for i, k in enumerate(keys) if k is not None}
+    builds = []
+    real = dj._build_key_lookup
+    monkeypatch.setattr(dj, "_build_key_lookup",
+                        lambda *a: builds.append(1) or real(*a))
+    for batch in range(5):
+        present = rng.choice([k for k in keys if k is not None], 300)
+        probe = np.concatenate([present, rng.integers(-50, 10**12, 100)]).astype(np.int64)
+        valid = rng.random(len(probe)) > 0.1
+        idx = dj.unique_key_index(s, probe, valid, DataType.int64())
+        want = [row_of.get(int(p), -1) if v else -1 for p, v in zip(probe, valid)]
+        assert idx.dtype == np.int32 and idx.tolist() == want, (form, batch)
+    assert len(builds) == 1
+    assert dj.unique_key_lookup(s, DataType.int64()).form == form
+
+
+def test_unique_key_lookup_refuses_keys_that_repeat():
+    import daft_tpu.ops.device_join as dj
+    from daft_tpu import DataType
+    from daft_tpu.ops.grouped_stage import DeviceFallback
+
+    with pytest.raises(DeviceFallback, match="not unique"):
+        dj.unique_key_index(_key_series([1, 2, 2]), np.array([2]), np.array([True]),
+                            DataType.int64())
+    empty = dj.unique_key_index(_key_series([]).cast(DataType.int64()), np.array([2, 3]),
+                                np.array([True, True]), DataType.int64())
+    assert empty.tolist() == [-1, -1]
