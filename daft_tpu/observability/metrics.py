@@ -147,6 +147,7 @@ DEVICE_COUNTER_NAMES = (
     "device_join_batches",     # batches through the gather-join device stages
     "join_provision_calls",    # join dispatches whose columns came from one traced program
     "join_provision_traces",   # provisioning programs traced (0 on a repeat query shape)
+    "join_window_gathers",     # adjacent-dimension gathers that read a batch-long window of the pack, summed over those dispatches
     "device_topn_runs",        # join+agg+TopN fused device programs completed
     # the fused TopN's group tables stay on the device for a whole run where
     # the group-by spans one dimension's key space (ops/device_join.py

@@ -525,14 +525,55 @@ def _gather_col(arr, arr_valid, idx):
     return arr[safe], arr_valid[safe] & ok
 
 
-def _gather_rows(mat, idx):
+# the chip's lane width: a one-row window is read as rows of this many values
+_LANES = 128
+
+
+def _gather_rows(mat, idx, windowed: bool = False):
     """One gather of a packed [P, N] dim matrix along its MINOR axis — the
     per-batch join, traced inside the provisioning program. The pack is
     TRANSPOSED ([planes, rows], not [rows, planes]) because TPU tiled layouts
     pad the minor dimension to 128 lanes: a [64M, 5] gather output would
     materialize as [64M, 128] — 32GB — and OOM (observed at SF10); [5, 64M]
-    pads only the 5 to 8 sublanes."""
-    return mat[:, jnp.clip(idx, 0, mat.shape[1] - 1)]
+    pads only the 5 to 8 sublanes.
+
+    `windowed`: the batch's matched indices lie within len(idx) of each other
+    (_index_span; a fact ordered by this dim's key), so the gather reads a
+    [P, len(idx)] slice of the matrix that starts at the least of them, found
+    here from `idx` itself. On the chip a gather's time follows the length of
+    what it gathers FROM: 131,072 indices out of [6, 2^24] take 3.58 ms, out
+    of a [6, 2^17] slice of it 0.43 (PERF.md, PR 39). The same rows' same
+    values: a miss reads row 0, as the plain form's clip makes it."""
+    n = mat.shape[1]
+    if not windowed:
+        return mat[:, jnp.clip(idx, 0, n - 1)]
+    w = idx.shape[0]
+    hit = idx >= 0
+    lo = jnp.clip(jnp.min(jnp.where(hit, idx, jnp.int32(n))), 0, n - w)
+    win = jax.lax.dynamic_slice(mat, (jnp.int32(0), lo), (mat.shape[0], w))
+    rel = jnp.clip(idx - lo, 0, w - 1)
+    if mat.shape[0] == 1:       # (a bucket is a power of two from 512 up: whole rows of lanes)
+        # a gather of single values is bound by its index count there (0.93 ms
+        # for these indices whatever it reads from), a gather of rows is not:
+        # the window as rows of one lane width, each index's row (0.20 ms),
+        # then its lane of that row (0.10). The lane is kept by its bits, a sum
+        # over the other lanes' zeros in int32, so a NaN or -0.0 stays itself
+        taken = jax.lax.bitcast_convert_type(
+            win.reshape(w // _LANES, _LANES)[rel // _LANES], jnp.int32)
+        mine = (rel % _LANES)[:, None] == jnp.arange(_LANES, dtype=jnp.int32)
+        rows = jax.lax.bitcast_convert_type(
+            jnp.sum(jnp.where(mine, taken, jnp.int32(0)), axis=1, dtype=jnp.int32),
+            jnp.float32)[None, :]
+    else:
+        rows = win[:, rel]
+    return jnp.where(hit, rows, mat[:, :1])
+
+
+def _index_span(idx: np.ndarray) -> int:
+    """Greatest less least matched row of a batch's host index, -1 where no
+    row matched: what decides whether the batch's gather fits a window."""
+    hit = idx[idx >= 0]
+    return int(hit.max()) - int(hit.min()) if len(hit) else -1
 
 
 @dataclass(frozen=True)
@@ -543,6 +584,7 @@ class _ProvisionLayout:
     never from a filter literal, so a query with another literal finds the
     program the first one traced."""
     packs: tuple     # per adjacent dim: its pack's ok row, None = existence check only
+    windows: tuple   # per adjacent dim: the batch's indices fit one window of its pack (_gather_rows)
     columns: tuple   # per dim column handed on: (name, adjacent dim, digit rows, validity row)
     codes: tuple     # per group-by column: (adjacent dim or -1 = fact-side plane, row or position, radix)
     cap: int         # the combined codes are clipped to [0, cap); 0 where no codes are asked for
@@ -571,11 +613,12 @@ def _provision_program(layout: _ProvisionLayout):
         counters.bump("join_provision_traces")   # runs when traced, not when called
         gathered = []
         ok = None
-        for mat, didx, ok_row in zip(mats, idxs, layout.packs):
+        for mat, didx, ok_row, windowed in zip(mats, idxs, layout.packs,
+                                               layout.windows):
             aok = didx >= 0
             rows = None
             if ok_row is not None:
-                rows = _gather_rows(mat, didx)      # [P, bucket]
+                rows = _gather_rows(mat, didx, windowed)      # [P, bucket]
                 aok = aok & (rows[ok_row] > 0.5)
             gathered.append(rows)
             ok = aok if ok is None else (ok & aok)
@@ -963,12 +1006,17 @@ class _JoinContext:
                             (key_series, tbl), build, rebuild_rows=n)
 
     def dev_idx(self, batch, dname: str, bucket: int, perm=None):
-        """Padded device index plane for one dim, cached on the probe Series
-        (identity: the host idx array — itself cached — plus the dim key).
+        """(padded device index plane, span) for one dim, cached on the probe
+        Series (identity: the host idx array — itself cached — plus the dim
+        key). The span (_index_span of the host index, kept with the plane so
+        that a dispatch looks nothing else up) says whether the batch's gather
+        fits a window of the dim's pack; the perm-folded plane holds the same
+        indices in another order, so the same span.
         With `perm` (host group-sorted layout) the permutation is FOLDED INTO
         the indices, so the packed row-gather emits rows pre-sorted at zero
         extra cost. Under the Pallas gate the plain (un-permuted) plane is
-        probed in-kernel instead; a kernel that does not lower raises."""
+        probed in-kernel instead, and the host, which then holds no index,
+        gives no span (None); a kernel that does not lower raises."""
         with profile_span("join.index", "host", dim=dname, bucket=bucket):
             return self._dev_idx(batch, dname, bucket, perm)
 
@@ -980,13 +1028,13 @@ class _JoinContext:
         if perm is None:
             interp = self._pallas_probe_gate(batch, d)
             if interp is not None:
-                return self._pallas_dev_idx(batch, d, bucket, interp)
+                return self._pallas_dev_idx(batch, d, bucket, interp), None
             idx_np = self._indices_for(batch)[dname]
 
             def build():
                 padded = np.full(bucket, -1, dtype=np.int32)
                 padded[:n] = idx_np
-                return jnp.asarray(padded)
+                return jnp.asarray(padded), _index_span(idx_np)
 
             return series_keyed(anchor, ("didx", d.key_col, d.parent, bucket),
                                 (idx_np,), build, rebuild_rows=n)
@@ -997,7 +1045,7 @@ class _JoinContext:
         def build_p():
             padded = np.full(bucket, -1, dtype=np.int32)
             padded[:n] = idx_np[pperm_np[:n]]
-            return jnp.asarray(padded)
+            return jnp.asarray(padded), _index_span(idx_np)
 
         return series_keyed(anchor, ("didxp", d.key_col, d.parent, bucket),
                             (idx_np, pperm_np), build_p, rebuild_rows=n)
@@ -1257,14 +1305,19 @@ class _JoinContext:
         spec = self.spec
         gb_cols, radices, cap, fact_code_planes = codes or _CodePlan((), (), 0, {})
         adj_of: Dict[str, int] = {}
-        mats, idxs, ok_rows, layouts = [], [], [], []
+        mats, idxs, ok_rows, windows, layouts = [], [], [], [], []
         for a, adj in enumerate(self._adjacent()):
             adj_of[adj.name] = a
-            idxs.append(self.dev_idx(batch, adj.name, bucket, perm=perm))
+            didx, span = self.dev_idx(batch, adj.name, bucket, perm=perm)
+            idxs.append(didx)
             mat, layout, code_layout, ok_row, wide = \
                 self.packed_plane(adj, needed, gb_cols) or (None, {}, {}, None, {})
             mats.append(mat)
             ok_rows.append(ok_row)
+            # a window as long as the batch serves it where the matched rows
+            # lie that close and the pack is longer than one window
+            windows.append(mat is not None and span is not None
+                           and span < bucket < mat.shape[1])
             layouts.append((layout, code_layout, wide))
 
         dcols: Dict[str, dev.DCol] = {}
@@ -1310,9 +1363,11 @@ class _JoinContext:
                 code_cols.append((a, layouts[a][1][name], radix))
 
         prog = _provision_program(_ProvisionLayout(
-            tuple(ok_rows), tuple(columns), tuple(code_cols), cap))
+            tuple(ok_rows), tuple(windows), tuple(columns), tuple(code_cols), cap))
         gathered, combined = prog(tuple(mats), tuple(idxs), tuple(fact_codes))
         counters.bump("join_provision_calls")
+        if any(windows):
+            counters.bump("join_window_gathers", windows.count(True))
         dcols.update(gathered)
         return dcols, combined
 
@@ -1900,7 +1955,7 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
                           rows=n, bucket=bucket):
             with profile_span("join.codes", "host", strategy="dim_rows",
                               cap=self._cap):
-                gid = ctx.dev_idx(batch, self.groups.dim.name, bucket)
+                gid, _span = ctx.dev_idx(batch, self.groups.dim.name, bucket)
             dcols, _ = ctx.provision(batch, bucket, needed)
             prog = stage._jit_run_wide(self._cap)
             mask = device_row_mask(n, bucket)

@@ -337,3 +337,57 @@ def test_bfloat16_terms_survive_the_chips_compiler(one_chip):
 
     compiled = jax.jit(_bfloat16_terms).lower(_s(one_chip, (4096, 3), jnp.float32)).compile()
     assert compiled.as_text().count("reduce-precision(") >= 2
+
+
+def _gather_operand_shapes(text):
+    """The shape of what each gather of an optimized HLO reads from."""
+    import re
+
+    out = []
+    for block in text.split("\n\n"):
+        shapes = {}
+        for line in block.splitlines():
+            m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]", line)
+            if m:
+                shapes[m.group(1)] = [int(d) for d in m.group(2).split(",") if d]
+        for line in block.splitlines():
+            m = re.search(r" gather\((%[\w.\-]+),", line)
+            if m:
+                out.append(tuple(shapes[m.group(1)]))
+    return out
+
+
+@pytest.mark.parametrize("rows", [6, 1], ids=["q5_six_rows", "q3_q10_one_row"])
+def test_windowed_provisioning_gathers_from_a_batch_long_window(one_chip, rows):
+    """The join's provisioning program at `tpch_sf10.joins`' shapes: `orders`'
+    pack of [6, 2^24] (q5) or [1, 2^24] (q3, q10) and 131,072 indices. Where
+    the batch's verdict says window, no gather of the optimized program reads
+    from 2^24 rows: the slice stays apart from the gather (a compiler that
+    folded it back would leave the dispatch at its 3.3 ms, and no CPU test
+    would see it), and the one-row pack is gathered as rows of 128 lanes. The
+    plain program of the same layout is the control."""
+    import dataclasses
+
+    from daft_tpu.ops.device_join import _ProvisionLayout, _provision_program
+
+    if rows == 6:       # a value with its validity, a code row, the ok row; `supplier` beside it
+        layout = _ProvisionLayout(
+            packs=(5, 2), windows=(True, False),
+            columns=(("c_nationkey", 0, (0,), 1), ("o_total", 0, (2,), 3),
+                     ("s_nationkey", 1, (0,), 1)),
+            codes=((0, 4, 1),), cap=32)
+        mats = (_s(one_chip, (6, ORDERS_CAP), jnp.float32),
+                _s(one_chip, (3, JOIN_BATCH), jnp.float32))
+    else:
+        layout = _ProvisionLayout(packs=(0,), windows=(True,), columns=(), codes=(), cap=0)
+        mats = (_s(one_chip, (1, ORDERS_CAP), jnp.float32),)
+    idxs = tuple(_s(one_chip, (JOIN_BATCH,), jnp.int32) for _ in mats)
+    windowed = _gather_operand_shapes(_compile(_provision_program(layout), mats, idxs, ()))
+    plain = _gather_operand_shapes(_compile(_provision_program(dataclasses.replace(
+        layout, windows=(False,) * len(mats))), mats, idxs, ()))
+    assert len(windowed) == len(plain) == len(mats)
+    assert max(max(shape) for shape in plain) == ORDERS_CAP, \
+        "the control: the plain gather reads the whole pack"
+    assert all(int(np.prod(shape)) <= rows * JOIN_BATCH for shape in windowed), windowed
+    if rows == 1:   # rows of one lane width, not 131,072 single values (0.93 ms on the chip)
+        assert windowed == [(JOIN_BATCH // 128, 128)]

@@ -940,3 +940,235 @@ def test_unique_key_lookup_refuses_keys_that_repeat():
     empty = dj.unique_key_index(_key_series([]).cast(DataType.int64()), np.array([2, 3]),
                                 np.array([True, True]), DataType.int64())
     assert empty.tolist() == [-1, -1]
+
+
+# ---- a dispatch gathers from the window of the dimension its batch points into --------
+
+_WIN_DIM = 8000                 # its pack pads to 8,192 rows: four windows of a morsel
+_WIN_FACT = 4 * _MORSEL         # four dispatches, batch b = rows [b * _MORSEL, (b + 1) * _MORSEL)
+# the group-sorted layout wants a batch of over 4,096 groups: morsels of 8,192 rows
+_PERM_MORSEL, _PERM_DIM, _PERM_FACT = 8192, 20_000, 4 * 8192
+
+
+def _win_keys_sorted(rng):
+    """Every key of the dim one to three times, in key order: a batch's
+    matched rows lie within some 1,024 of each other."""
+    return np.repeat(np.arange(_WIN_DIM), rng.integers(1, 4, _WIN_DIM))[:_WIN_FACT].tolist()
+
+
+def _win_keys_shuffled(rng):
+    return rng.permutation(_win_keys_sorted(rng)).tolist()
+
+
+def _win_keys_edge_misses(rng):
+    keys = _win_keys_sorted(rng)
+    for b in range(0, _WIN_FACT, _MORSEL):
+        keys[b] = keys[b + 1] = keys[b + _MORSEL - 1] = None          # a null probe
+        keys[b + 2] = keys[b + _MORSEL - 2] = _WIN_DIM + 1000          # a key the dim lacks
+    return keys
+
+
+def _win_keys_all_miss_batch(rng):
+    keys = _win_keys_sorted(rng)
+    keys[_MORSEL:2 * _MORSEL] = [_WIN_DIM + 7] * _MORSEL
+    return keys
+
+
+def _win_keys_at_the_end(rng):
+    # the last batches' least row lies past pack rows - window: the slice is clamped
+    keys = np.repeat(np.arange(_WIN_DIM - _WIN_FACT // 2, _WIN_DIM), 2).tolist()
+    assert min(keys[-_MORSEL:]) > 8192 - _MORSEL
+    return keys
+
+
+def _win_keys_span_exactly_w(rng):
+    keys = _win_keys_sorted(rng)
+    first = [100] + sorted(rng.integers(101, 100 + _MORSEL, _MORSEL - 2).tolist()) + [100 + _MORSEL]
+    second = [3000] + sorted(rng.integers(3000, 2999 + _MORSEL, _MORSEL - 2).tolist()) \
+        + [2999 + _MORSEL]
+    keys[:_MORSEL], keys[_MORSEL:2 * _MORSEL] = first, second       # spans W and W - 1
+    return keys
+
+
+def _win_keys_wide_batches_sorted(rng):
+    # four batches of 8,192 rows, each over some 4,100 consecutive rows of a 20,000-row dim
+    return np.repeat(np.arange(3000, _PERM_DIM),
+                     rng.integers(1, 4, _PERM_DIM - 3000))[:_PERM_FACT].tolist()
+
+
+def _win_keys_wide_batches_shuffled(rng):
+    return rng.integers(0, _PERM_DIM, _PERM_FACT).tolist()
+
+
+def _win_tables(keys, dim_rows):
+    rng = np.random.default_rng(39)
+    n = len(keys)
+    fact = daft_tpu.from_pydict({
+        "f_k": keys,
+        "f_s": rng.integers(0, 300, n).tolist(),
+        "f_q": rng.integers(0, 4, n).tolist(),
+        "f_v": rng.uniform(0, 100, n).round(3).tolist(),
+    }).collect()
+    dim = daft_tpu.from_pydict({
+        "d_k": list(range(dim_rows)),
+        "d_grp": [f"g{i % 7}" for i in range(dim_rows)],
+        "d_w": [float(i % 13) if i % 29 else None for i in range(dim_rows)],
+        "d_big": [300_266_000_000 + i * 7_919 for i in range(dim_rows)],       # int64 past 2^24
+        "d_mid": np.asarray([16_777_216 + i * 3 for i in range(dim_rows)], dtype=np.int32),
+    }).collect()
+    small = daft_tpu.from_pydict({        # no longer than a window: always the plain gather
+        "s_k": list(range(300)), "s_w": [float(i % 5) for i in range(300)]}).collect()
+    return fact, dim, small
+
+
+def _win_q_codes(fact, dim, small):
+    return (fact.join(dim, left_on="f_k", right_on="d_k")
+            .join(small, left_on="f_s", right_on="s_k")
+            .where(col("d_w") < 11.0)
+            .groupby("d_grp")
+            .agg(col("f_v").sum().alias("sv"), (col("f_v") * col("d_w")).sum().alias("svw"),
+                 col("s_w").sum().alias("sw"), col("f_v").count().alias("c"))
+            .sort("d_grp"))
+
+
+def _win_q_wide(fact, dim, small):
+    return (fact.join(dim, left_on="f_k", right_on="d_k")
+            .groupby("d_grp")
+            .agg(col("d_big").sum().alias("s64"), col("d_big").min().alias("mn64"),
+                 col("d_big").max().alias("mx64"), col("d_mid").sum().alias("s32"),
+                 col("d_mid").max().alias("mx32"))
+            .sort("d_grp"))
+
+
+def _win_q_one_row(fact, dim, small):
+    # the dim gives a filter and no column: its pack is the ok row alone
+    return (fact.join(dim, left_on="f_k", right_on="d_k")
+            .where(col("d_w") < 11.0)
+            .groupby("f_q")
+            .agg(col("f_v").sum().alias("sv"), col("f_v").count().alias("c"))
+            .sort("f_q"))
+
+
+def _win_q_permuted(fact, dim, small):
+    # some 7,000 true groups a batch: past the one-hot ceiling, so its rows go group-sorted
+    return (fact.join(dim, left_on="f_k", right_on="d_k")
+            .where(col("d_w") < 11.0)
+            .groupby("f_k", "f_q")
+            .agg(col("f_v").sum().alias("sv"), col("d_w").sum().alias("sw"))
+            .sort(["f_k", "f_q"]))
+
+
+@pytest.mark.parametrize("keys,shape,engaged", [
+    (_win_keys_sorted, _win_q_codes, [True] * 4),
+    (_win_keys_shuffled, _win_q_codes, [False] * 4),
+    (_win_keys_edge_misses, _win_q_codes, [True] * 4),
+    (_win_keys_all_miss_batch, _win_q_codes, [True] * 4),
+    (_win_keys_at_the_end, _win_q_codes, [True] * 4),
+    (_win_keys_span_exactly_w, _win_q_codes, [False, True, True, True]),
+    (_win_keys_sorted, _win_q_wide, [True] * 4),
+    (_win_keys_edge_misses, _win_q_one_row, [True] * 4),
+    (_win_keys_shuffled, _win_q_one_row, [False] * 4),
+    (_win_keys_wide_batches_sorted, _win_q_permuted, [True] * 4),
+    (_win_keys_wide_batches_shuffled, _win_q_permuted, [False] * 4),
+], ids=["sorted", "shuffled", "misses_at_both_edges", "all_miss_batch", "clamp_at_the_end",
+        "span_exactly_w", "wide_digit_rows", "one_row_pack", "one_row_pack_shuffled",
+        "perm_folded", "perm_folded_shuffled"])
+def test_windowed_gather_is_the_plain_gather_bit_for_bit(monkeypatch, keys, shape, engaged):
+    """A batch whose matched rows of a dimension lie within one batch length
+    of each other gathers from a window of that dimension's pack, any other
+    batch from the whole of it; the verdict comes from the batch's own index.
+    Every dispatch that engages a window is run again here through the plain
+    program of the same layout: the gathered planes, `__join_ok__` and the
+    combined codes are the same bits, misses included. `join_window_gathers`
+    counts the windowed gathers, and a repeat query traces nothing."""
+    import dataclasses
+
+    import jax
+    from daft_tpu.device.residency import manager
+    from daft_tpu.ops import device_join as dj
+
+    manager().clear()
+    permuted = shape is _win_q_permuted
+    fact, dim, small = _win_tables(keys(np.random.default_rng(7)),
+                                   _PERM_DIM if permuted else _WIN_DIM)
+    morsel = _PERM_MORSEL if permuted else _MORSEL
+    config = dict(morsel_size_rows=morsel, pipeline_mode="force")
+    real = dj._provision_program
+    windows, perms = [], []
+
+    def both(layout):
+        def prog(mats, idxs, fact_codes):
+            windows.append(layout.windows)
+            assert (mats[0].shape[0] == 1) == (shape is _win_q_one_row)
+            got = real(layout)(mats, idxs, fact_codes)
+            if any(layout.windows):
+                plain = real(dataclasses.replace(
+                    layout, windows=(False,) * len(layout.windows)))(mats, idxs, fact_codes)
+                assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(plain)
+                for g, p in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(plain)):
+                    assert g.dtype == p.dtype and np.asarray(g).tobytes() == np.asarray(p).tobytes()
+            return got
+        return prog
+
+    real_provision = dj._JoinContext.provision
+
+    def spy(self, batch, bucket, needed, codes=None, perm=None):
+        perms.append(perm is not None)
+        assert bucket == morsel
+        return real_provision(self, batch, bucket, needed, codes=codes, perm=perm)
+
+    monkeypatch.setattr(dj, "_provision_program", both)
+    monkeypatch.setattr(dj._JoinContext, "provision", spy)
+    q = lambda: shape(fact, dim, small)
+    with execution_config_ctx(device_mode="off", **config):
+        host = q().to_pydict()
+    for rep in range(2):
+        del windows[:], perms[:]
+        counters.reset()
+        with execution_config_ctx(device_mode="on", **config):
+            dev = q().to_pydict()
+        assert counters.device_join_batches == len(engaged), counters.rejections
+        # the big dim is the first adjacent one; `small` (300 rows) never has a window
+        assert [w[0] for w in windows] == engaged, windows
+        assert all(not any(w[1:]) for w in windows)
+        assert set(perms) == {permuted}
+        assert counters.join_window_gathers == sum(engaged)
+        assert counters.join_provision_calls == len(engaged)
+        if rep:
+            assert counters.join_provision_traces == 0, "a repeat query traces no program"
+        if shape is _win_q_wide:
+            assert dev == host          # integers: no tolerance
+        else:
+            _assert_close(host, dev)
+    manager().clear()
+
+
+@pytest.mark.parametrize("idx,span", [
+    ([5, 9, -1, 7], 4), ([-1, -1], -1), ([], -1), ([3], 0), ([-1, 0, 2047, -1], 2047),
+], ids=["some", "all_miss", "empty", "one", "edges"])
+def test_index_span_is_the_matched_rows_reach(idx, span):
+    from daft_tpu.ops.device_join import _index_span
+
+    assert _index_span(np.asarray(idx, dtype=np.int32)) == span
+
+
+@pytest.mark.parametrize("rows", [1, 3], ids=["one_row_lanes", "rows"])
+def test_windowed_gather_keeps_every_bit(rows):
+    """NaN payloads, -0.0, infinities and denormals come through the window
+    as the plain gather hands them on: the one-row form picks a lane by its
+    bits and never multiplies or adds a float."""
+    import jax.numpy as jnp
+    from daft_tpu.ops.device_join import _gather_rows
+
+    rng = np.random.default_rng(3)
+    n, w = 8192, 1024
+    bits = rng.integers(0, 2**32, (rows, n), dtype=np.uint64).astype(np.uint32)   # every kind of float32
+    bits[:, ::5] = np.float32(-0.0).view(np.uint32)
+    bits[:, 1::5] = np.uint32(0x7FC01234)                                          # a NaN with a payload
+    mat = jnp.asarray(bits.view(np.float32))
+    for start in (0, 3000, n - w // 2):          # the last: a window clamped to the pack's end
+        idx = np.sort(rng.integers(start, min(start + w, n), w)).astype(np.int32)
+        idx[[0, 1, w - 1]] = -1
+        got = np.asarray(_gather_rows(mat, jnp.asarray(idx), True))
+        want = np.asarray(_gather_rows(mat, jnp.asarray(idx), False))
+        assert got.tobytes() == want.tobytes(), start
