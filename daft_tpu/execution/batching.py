@@ -187,17 +187,20 @@ class LatencyConstrainedBatching:
                 counters.bump("morsel_resize")
 
 
-def coalesce_target_rows(cfg) -> int:
+def coalesce_target_rows(cfg, shards: int = 1) -> int:
     """Flush threshold of the device dispatch coalescer: batch_fill_target of
     the power-of-two bucket at the configured morsel size; 0 = coalescing
     disabled. THE one definition — the executor's coalescer construction and
     the cost model's expected-horizon both read it, so the priced coalescing
-    behavior can never drift from the behavior that actually runs."""
+    behavior can never drift from the behavior that actually runs. A dispatch
+    whose rows are sharded over `shards` devices holds a bucket a shard: the
+    threshold is reached when the last of them is batch_fill_target full."""
     if cfg.batch_fill_target <= 0:
         return 0
     from ..ops.stage import pad_bucket
 
-    return int(cfg.batch_fill_target * pad_bucket(cfg.morsel_size_rows))
+    bucket = pad_bucket(cfg.morsel_size_rows)
+    return (max(shards, 1) - 1) * bucket + int(cfg.batch_fill_target * bucket)
 
 
 def make_strategy(cfg) -> BatchingStrategy:

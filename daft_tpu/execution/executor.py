@@ -1104,15 +1104,16 @@ def _try_fused_udf_agg(node, cfg) -> Optional[MicroPartition]:
     return MicroPartition(node.schema, [out.cast_to_schema(node.schema)])
 
 
-def _make_coalescer(feed, cfg):
+def _make_coalescer(feed, cfg, shards: int = 1):
     """DispatchCoalescer for one device stage run (ops/stage.py), or None when
     coalescing is disabled (batch_fill_target == 0) — morsels then dispatch
     one-to-one, the pre-coalescing behavior. The flush threshold
     (batching.coalesce_target_rows) makes one compiled dispatch cover N small
-    morsels with its bucket at least batch_fill_target full."""
+    morsels with its bucket at least batch_fill_target full. A run that
+    shards a dispatch's rows over `shards` devices fills a bucket a shard."""
     from .batching import coalesce_target_rows
 
-    target = coalesce_target_rows(cfg)
+    target = coalesce_target_rows(cfg, shards)
     if target <= 0:
         return None
     from ..ops.stage import DispatchCoalescer
@@ -1127,15 +1128,15 @@ def _exec_device_join_agg(node) -> MicroPartition:
     """
     from ..ops.device_join import DeviceJoinGroupedRun, DeviceJoinUngroupedRun
 
-    def make_run(stage, grouped, ctx, mesh_stage):
+    def make_run(stage, grouped, ctx, mesh_stage, shards=1):
         if mesh_stage is not None:
             from ..ops.mesh_stage import (MeshJoinGroupedRun,
                                           MeshJoinUngroupedRun)
 
             return (MeshJoinGroupedRun(mesh_stage, ctx) if grouped
                     else MeshJoinUngroupedRun(mesh_stage, ctx))
-        return (DeviceJoinGroupedRun(stage, ctx) if grouped
-                else DeviceJoinUngroupedRun(stage, ctx))
+        return (DeviceJoinGroupedRun(stage, ctx, shards) if grouped
+                else DeviceJoinUngroupedRun(stage, ctx, shards))
 
     def assemble(run, stage, grouped):
         if grouped:
@@ -1162,12 +1163,12 @@ def _exec_device_join_topn(node) -> MicroPartition:
     DeviceFallback)."""
     from ..ops.device_join import DeviceJoinTopNRun
 
-    def make_run(stage, grouped, ctx, mesh_stage):
+    def make_run(stage, grouped, ctx, mesh_stage, shards=1):
         if mesh_stage is not None:
             from ..ops.mesh_stage import MeshJoinTopNRun
 
             return MeshJoinTopNRun(mesh_stage, ctx, node.topn)
-        return DeviceJoinTopNRun(stage, ctx, node.topn)
+        return DeviceJoinTopNRun(stage, ctx, node.topn, shards)
 
     def assemble(run, stage, grouped):
         key_rows, results = run.finalize_topn()
@@ -1314,25 +1315,46 @@ def _run_device_join(node, label: str, make_run, assemble,
             _counters.reject(
                 "runtime", f"{label}: fewer local devices than mesh_devices",
                 f"({len(jax.devices())} < {cfg.mesh_devices})")
+        # What the mesh arm runs: the single chip's join dispatch on every
+        # shard of the fact (ops/device_join.py, `mesh_devices`), the path
+        # the four-chip join cell measures; or, for the shapes that path
+        # declines (sharded_join_reason: group codes or TopN ids that need a
+        # host factorization of every batch), the older fused tier of
+        # ops/mesh_stage.py, where its stage builds.
         mesh_stage = None
-        if mesh_width >= 2:
-            from ..ops.mesh_stage import try_build_mesh_join_stage
+        sharded = False
+        batch0 = next((b for b in first.batches if b.num_rows > 0), None)
+        if mesh_width >= 2 and batch0 is not None:
+            from ..ops.device_join import sharded_join_reason
 
-            mesh_stage = try_build_mesh_join_stage(node.spec, mesh_width)
-            if mesh_stage is None:
-                _counters.reject(
-                    "runtime", f"{label}: mesh join stage unbuildable")
-                mesh_width = 0
+            declined = sharded_join_reason(ctx, stage, grouped, topn, batch0,
+                                           mesh_width)
+            sharded = not declined
+            if declined:
+                from ..ops.mesh_stage import try_build_mesh_join_stage
+
+                mesh_stage = try_build_mesh_join_stage(node.spec, mesh_width)
+                if mesh_stage is None:
+                    _counters.reject(
+                        "runtime", f"{label}: mesh join stage unbuildable",
+                        f"(and no sharded dispatch: {declined})")
+                    mesh_width = 0
+        elif mesh_width >= 2:
+            mesh_width = 0
 
         prec = None
         tier = False
         if cfg.device_mode == "auto":
-            batch0 = next((b for b in first.batches if b.num_rows > 0), None)
             if batch0 is not None:
                 tier, prec = _join_device_wins(
                     node, ctx, batch0, first.num_rows, grouped, stage,
                     topn=topn, label=label, coalesce=coal,
-                    mesh_ndev=mesh_width,
+                    mesh_ndev=mesh_width, sharded=sharded,
+                    mesh_coalesce=_coalesce_horizon(
+                        [first] if second is None else [first, second],
+                        shards=mesh_width,
+                        stream_rows=_resident_rows(node.fact))
+                    if sharded and stream_wide else coal,
                     mesh_forced=cfg.mesh_devices >= 2 and mesh_width >= 2)
             _DECISION_CACHE.put(dk, tier)
             if not tier:
@@ -1341,8 +1363,6 @@ def _run_device_join(node, label: str, make_run, assemble,
         elif cfg.device_mode == "on":
             tier = "mesh" if mesh_width >= 2 else "chip"
             if _env_bool("DAFT_TPU_PLACEMENT_PRICE_FORCED", False):
-                batch0 = next((b for b in first.batches if b.num_rows > 0),
-                              None)
                 if batch0 is not None:
                     # forced run, priced anyway: the ledger record carries
                     # every tier's CostBreakdown (mesh arm included) so
@@ -1352,7 +1372,7 @@ def _run_device_join(node, label: str, make_run, assemble,
                     _t, prec = _join_device_wins(
                         node, ctx, batch0, first.num_rows, grouped, stage,
                         topn=topn, label=label, coalesce=coal,
-                        mesh_ndev=mesh_width, forced=True,
+                        mesh_ndev=mesh_width, sharded=sharded, forced=True,
                         forced_tier=tier)
             if prec is None:
                 prec = _placement.ledger().record(
@@ -1361,7 +1381,8 @@ def _run_device_join(node, label: str, make_run, assemble,
 
         if tier != "mesh":
             mesh_stage = None  # costed verdict picked the single chip / host
-        run = make_run(stage, grouped, ctx, mesh_stage)
+        shards = mesh_width if tier == "mesh" and sharded else 1
+        run = make_run(stage, grouped, ctx, mesh_stage, shards)
         from ..device.residency import manager as _residency
 
         # pin-scope the feed + finalize: entries this query touches (packed
@@ -1400,7 +1421,7 @@ def _run_device_join(node, label: str, make_run, assemble,
                 # morsels of a resident table is a zero-copy range of it
                 # (Series.concat), so series_keyed slots, keyed on the rows
                 # a batch views and not on its objects, hit on a repeat query.
-                coalescer = _make_coalescer(run.feed_batch, cfg)
+                coalescer = _make_coalescer(run.feed_batch, cfg, shards)
                 feed = coalescer.add if coalescer is not None else run.feed_batch
                 for part in fact_stream:
                     fed_rows += part.num_rows
@@ -1410,6 +1431,8 @@ def _run_device_join(node, label: str, make_run, assemble,
                     coalescer.close()
             fb.set_rows(fed_rows)
             out = assemble(run, stage, grouped)
+        if shards > 1:
+            _counters.bump("mesh_join_runs")
         _note_region(node, region_ops, _counters.device_join_batches - d0)
         return out
     except DeviceFallback as e:
@@ -1552,19 +1575,30 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
                       topn: bool = False, label: str = "join agg",
                       coalesce: float = 1.0, mesh_ndev: int = 0,
                       forced: bool = False, forced_tier=None,
-                      mesh_forced: bool = False):
+                      mesh_forced: bool = False, sharded: bool = False,
+                      mesh_coalesce: float = 1.0):
     """Cost-model decision for a DeviceJoinAgg node (see ops/costmodel.py).
     Returns (tier, placement_record) with tier in {"mesh", "chip", False} —
     ALL priced tiers' CostBreakdowns land in the ledger so EXPLAIN PLACEMENT
     can show per-term why a star join cost-rejected to host (the engine's
     headline loss) and what the mesh arm would have cost.
 
-    The mesh arm (mesh_ndev >= 2) prices the fused sharded program
-    (ops/mesh_stage.MeshJoin*Run): per-shard compute ÷ mesh width, the ICI
-    table-merge collective, the multi-device dispatch premium, and its OWN
-    residency picture (native-dtype sharded fact planes + replicated dim
-    planes under mesh slot keys). Mesh must beat BOTH the single chip and
-    the host — same discipline as _mesh_wins.
+    The mesh arm (mesh_ndev >= 2) prices what will run. `sharded`: the
+    single chip's join dispatch on every shard of the fact
+    (ops/device_join.py with `mesh_devices`): the chip arm's own terms at
+    rows / width (float32 planes, int32 index planes, no host
+    factorization), the round trip shared by the `mesh_coalesce` partitions
+    a sharded dispatch covers, and what spanning the devices adds
+    (costmodel.over_mesh; a run-wide TopN's tables cross the chips once a
+    run). Else the older fused program (ops/mesh_stage.MeshJoin*Run):
+    per-shard compute ÷ mesh width, the ICI table-merge collective, the
+    multi-device dispatch premium, and its OWN residency picture
+    (native-dtype sharded fact planes + replicated dim planes under mesh
+    slot keys). Mesh must beat BOTH the single chip and the host — same
+    discipline as _mesh_wins. The chip arm is not eligible where the fact's
+    planes and the run's tables would not fit one chip's HBM budget, or
+    where a fused TopN's tables pass the one chip's ceiling and the fact
+    has more batches than the one-batch form takes.
 
     One-time investments (fact column uploads, index planes, joined-key
     factorize) amortize over device_amortize_runs when the fact source is a
@@ -1623,7 +1657,22 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
     # against its OWN mesh residency slots so a warm mesh repeat prices at
     # zero transfer like the single-chip arm does
     mesh_nonres = mesh_res = 0
-    if mesh_ndev >= 2:
+    if mesh_ndev >= 2 and sharded:
+        from ..ops.stage import MESH_AXIS, mesh_total
+
+        # the chip arm's planes in the mesh's layout (a sharded dispatch
+        # covers several partitions, whose planes are slots of their own:
+        # this batch's probe finds them only where it was dispatched alone)
+        mesh_pad = mesh_total(batch.num_rows, mesh_ndev)
+        for c in fact_cols:
+            if batch.get_column(c).is_device_resident(
+                    mesh_pad, f32=True, mesh_devices=mesh_ndev):
+                mesh_res += batch.num_rows * 5
+            else:
+                mesh_nonres += batch.num_rows * 5
+        mesh_nonres += ctx.nonresident_index_bytes(
+            batch, mesh_pad, ("mesh", mesh_ndev, MESH_AXIS))
+    elif mesh_ndev >= 2:
         per = pad_bucket(max((batch.num_rows + mesh_ndev - 1) // mesh_ndev, 1))
         mesh_pad = per * mesh_ndev
         for c in fact_cols:
@@ -1660,24 +1709,34 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
             probe_slots += t
     chip_ok = True
     mesh_cost = None
+    sharded = sharded and mesh_ndev >= 2
+    fact_rows = _resident_rows(node.fact) or rows
+    # the sharded dispatch's arm is the chip arm's own terms at a shard's rows
+    shard_rows = max(-(-rows // mesh_ndev), 1) if sharded else rows
     if grouped:
         import math
 
         from ..ops.device_join import DeviceJoinTopNRun
 
-        wide = None
+        wide = mwide = None
         if topn:
             from ..ops.device_join import topn_run_wide
 
             wide, wide_cap, _why = topn_run_wide(ctx, stage)
+            if sharded:
+                # (the ceiling is held to a chip's share of the ids)
+                mwide, mwide_cap, _why = topn_run_wide(ctx, stage, mesh_ndev)
+            if wide is None and fact_rows > rows:
+                # the one chip's other form takes a fact of one batch only
+                chip_ok = False
         ceiling = DeviceJoinTopNRun.max_segments if topn \
             else DeviceJoinGroupedRun.max_segments
-        if wide is not None:
+        if wide is not None or mwide is not None:
             # the tables that will be built are the dimension's padded rows
             # long whatever a batch holds: nothing is sampled (and the
             # run-wide ceiling was held when `wide` was found)
-            card = max(ctx.batches[wide.dim.name].num_rows, 1)
-            cap_est = ceiling = wide_cap
+            card = max(ctx.batches[(wide or mwide).dim.name].num_rows, 1)
+            cap_est = ceiling = wide_cap if wide is not None else mwide_cap
         else:
             card = estimate_joined_cardinality(ctx, batch, stage.groupby)
             cap_est = _pad_groups(min(max(card, 1), 2 * ceiling))
@@ -1715,18 +1774,25 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
         else:
             fetch = cap_est * (n_mm + n_ext + n_sct) * 8
             mesh_fetch = cap_est * (n_slots * 2 + 1) * 8
-        if wide is not None:
+        # one select and one fetch a run: this partition carries its
+        # share of them, by its rows over the fact's where those are known
+        share = min(rows / max(fact_rows, 1), 1.0)
+
+        def run_wide_arm(groups, arm_rows, select_ids, arm_nonres, arm_res,
+                         arm_coal):
+            """A partition of a fused TopN run that keeps run-wide tables:
+            `arm_rows` rows a device, a select over `select_ids` ids."""
             from ..ops.grouped_stage import CHUNK_LOCAL
 
-            # one select and one fetch a run: this partition carries its
-            # share of them, by its rows over the fact's where those are known
-            share = min(rows / max(_resident_rows(node.fact) or rows, 1), 1.0)
-            dev_cost = costmodel.device_join_topn_run_cost(
-                cal, rows, nonres // amort, n_gathers, n_mm, cap_est,
+            return costmodel.device_join_topn_run_cost(
+                cal, arm_rows, arm_nonres // amort, n_gathers, n_mm, select_ids,
                 min(CHUNK_LOCAL, bucket),
-                ctx.ids_locally_dense(batch, wide.dim.name), fetch, share,
-                len(node.topn.keys), rows // amort, coalesce=coal,
-                resident_bytes=res)
+                ctx.ids_locally_dense(batch, groups.dim.name), fetch, share,
+                len(node.topn.keys), rows // amort, coalesce=arm_coal,
+                resident_bytes=arm_res)
+
+        if wide is not None:
+            dev_cost = run_wide_arm(wide, rows, cap_est, nonres, res, coal)
         else:
             nonres += bucket * 4               # codes plane (host-factorize case)
             dev_cost = costmodel.device_join_agg_cost(
@@ -1742,7 +1808,7 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
             dev_cost.add("compute",
                          cap_est * max(math.log2(max(cap_est, 2)), 1.0)
                          * nkeys / cal.mm_plane_rows_per_s)
-        if mesh_ndev >= 2:
+        if mesh_ndev >= 2 and not sharded:
             mesh_nonres += mesh_pad * 8        # joined-key codes plane (int64)
         host_cost = costmodel.host_join_agg_cost(
             cal, host_rows, len(spec.dims), len(stage.aggs), True, False)
@@ -1751,10 +1817,34 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
         if topn:
             # host additionally sorts the aggregate's output rows (once a
             # run: a partition of a streamed fact carries its share)
-            host_cost.add("compute", (share if wide is not None else 1.0)
+            host_cost.add("compute",
+                          (share if (wide or mwide) is not None else 1.0)
                           * card * max(math.log2(max(card, 2)), 1.0)
                           / cal.host_agg_rate)
-        if mesh_ndev >= 2:
+        if sharded:
+            if topn:
+                # every chip adds into tables of all the ids, selects over
+                # its share of them, and the tables cross the chips once a run
+                table_bytes = mwide_cap * (n_mm * 8 + 4)
+                mesh_cost = costmodel.over_mesh(
+                    run_wide_arm(mwide, shard_rows, mwide_cap // mesh_ndev,
+                                 mesh_nonres, mesh_res, mesh_coalesce),
+                    cal, mesh_ndev, 0, coalesce=mesh_coalesce)
+                mesh_cost.add("ici", share * table_bytes * (mesh_ndev - 1)
+                              / mesh_ndev / cal.ici_bytes_per_s)
+                mesh_cost.add("d2h", share * (mesh_ndev - 1) * fetch
+                              / cal.d2h_bytes_per_s)
+            else:
+                # dictionary group codes, combined on the device: one small
+                # table a shard comes back, merged on the host
+                mesh_cost = costmodel.over_mesh(
+                    costmodel.device_join_agg_cost(
+                        cal, shard_rows, mesh_nonres // amort, n_gathers, n_mm,
+                        n_ext, n_sct, cap_est, fetch, rows // amort,
+                        MAX_MATMUL_SEGMENTS, coalesce=mesh_coalesce,
+                        resident_bytes=mesh_res),
+                    cal, mesh_ndev, fetch, coalesce=mesh_coalesce)
+        elif mesh_ndev >= 2:
             mesh_cost = costmodel.mesh_join_agg_cost(
                 cal, rows, mesh_nonres // amort, n_gathers, n_slots, cap_est,
                 mesh_ndev, mesh_fetch, rows // amort, coalesce=coal,
@@ -1780,12 +1870,32 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
             cal, host_rows, len(spec.dims), len(stage.aggs), False, False)
         if spec.predicate is not None:
             host_cost.add("compute", rows / cal.host_agg_rate)  # filter pass
-        if mesh_ndev >= 2:
+        if sharded:
+            mesh_cost = costmodel.over_mesh(
+                costmodel.device_join_agg_cost(
+                    cal, shard_rows, mesh_nonres // amort, n_gathers,
+                    max(len(stage.aggs), 1), 0, 0, 1, fetch, rows // amort,
+                    MAX_MATMUL_SEGMENTS, coalesce=mesh_coalesce,
+                    resident_bytes=mesh_res),
+                cal, mesh_ndev, fetch, coalesce=mesh_coalesce)
+        elif mesh_ndev >= 2:
             mesh_cost = costmodel.mesh_join_agg_cost(
                 cal, rows, mesh_nonres // amort, n_gathers, n_slots, 1,
                 mesh_ndev, fetch, rows // amort, coalesce=coal,
                 resident_bytes=mesh_res, grouped=False)
         detail = f"{len(spec.dims)} dims, {len(stage.aggs)} aggs"
+
+    if chip_ok and _resident_source_rec(node.fact):
+        # what one chip would have to hold for the whole of a resident fact:
+        # the planes and index planes of every batch, and a run's tables
+        from ..device.residency import manager as _residency
+
+        chip_bytes = fact_rows * (5 * len(fact_cols) + 4 * len(ctx._adjacent()))
+        if grouped and wide is not None:
+            chip_bytes += wide_cap * (len(stage._mm_specs) * 8 + 4)
+        budget = _residency().budget_bytes()     # (0: unbounded)
+        if 0 < budget < chip_bytes:
+            chip_ok = False
 
     wins_chip = chip_ok and dev_cost < host_cost
     if mesh_forced:
@@ -2186,7 +2296,8 @@ def _device_wins(node, first: MicroPartition, grouped: bool,
     return wins, rec
 
 
-def _coalesce_horizon(parts) -> float:
+def _coalesce_horizon(parts, shards: int = 1,
+                      stream_rows: Optional[int] = None) -> float:
     """Expected dispatch-coalescing factor from the OBSERVED leading
     partitions' batch granularity (`parts`: the first partition, plus a
     peeked second when the caller got one). The coalescer merges
@@ -2211,20 +2322,28 @@ def _coalesce_horizon(parts) -> float:
     (reached via content-addressed rebind at upload time), which the
     per-batch residency probes here cannot see, so repeat uploads price at
     full h2d even when the rebind makes them free. 1.0 when coalescing is
-    disabled."""
+    disabled.
+
+    `shards`: the run shards a dispatch's rows over that many devices, so
+    its coalescer fills a bucket a shard (batching.coalesce_target_rows).
+    `stream_rows`: the rows of the whole stream where they are known (a
+    resident table): the batches still to come are then no guess."""
     from ..config import execution_config
     from ..ops.costmodel import expected_coalesce_factor
     from .batching import coalesce_target_rows
 
     cfg = execution_config()
-    target = coalesce_target_rows(cfg)
+    target = coalesce_target_rows(cfg, shards)
     if target <= 0:
         return 1.0
     sizes = [b.num_rows for p in parts for b in p.batches if b.num_rows > 0]
-    if len(sizes) <= 1:
+    if not sizes:
         return 1.0
     mean_rows = int(sum(sizes) / len(sizes))
-    return min(expected_coalesce_factor(mean_rows, target), float(len(sizes)))
+    seen = max(len(sizes), (stream_rows or 0) // max(mean_rows, 1))
+    if seen <= 1:
+        return 1.0
+    return min(expected_coalesce_factor(mean_rows, target), float(seen))
 
 
 

@@ -155,7 +155,14 @@ DEVICE_COUNTER_NAMES = (
     "device_join_topn_batches",  # fact batches fused TopN runs took in
     "device_topn_fetched_rows",  # rows their finalizes brought back (at most a limit + offset each)
     "device_topn_table_bytes",   # bytes of the run-wide group tables, summed over the runs that built them
-    "mesh_join_runs",          # device joins executed via the mesh-sharded tier
+    # a join dispatch whose fact rows were sharded over more than one local
+    # device, each running the single chip's programs on its shard
+    # (ops/device_join.py, `mesh_devices` > 1): counted as a
+    # device_join_batches and a device_mesh_batches dispatch too
+    "device_join_mesh_batches",  # join dispatches that spanned more than one device
+    "device_join_mesh_shards",   # devices summed over those dispatches
+    "device_topn_combine_bytes",  # bytes the run-wide tables' cross-chip combines moved between chips
+    "mesh_join_runs",         # device joins whose dispatches spanned a mesh (the sharded dispatch or ops/mesh_stage.py)
     # intra-host ICI repartition (jax.lax.all_to_all over the local mesh —
     # the in-mesh replacement for the host shuffle between co-located workers)
     "mesh_alltoall_dispatches",    # all_to_all exchange programs dispatched

@@ -593,7 +593,7 @@ def device_ungrouped_cost(cal: Calibration, rows: int, nonresident_bytes: int,
 
 
 def over_mesh(shard_cost: CostBreakdown, cal: Calibration, n_devices: int,
-              table_bytes: int) -> CostBreakdown:
+              table_bytes: int, coalesce: float = 1.0) -> CostBreakdown:
     """A sharded aggregate dispatch (ops/stage.over_shards): `shard_cost` is
     the single-chip arm priced at one shard's rows (every device runs the
     single chip's program on its shard at once; upload bytes are the whole
@@ -601,8 +601,10 @@ def over_mesh(shard_cost: CostBreakdown, cal: Calibration, n_devices: int,
     devices adds the multi-device launch premium on top of the round trip,
     and the fetch of one partial table of `table_bytes` from every device
     but the first, which the single chip's fetch already is. No collective
-    runs, so there is no ICI term."""
-    shard_cost.add("mesh_dispatch", cal.mesh_dispatch_s)
+    runs here (a caller whose run ends in one adds its ICI term). `coalesce`:
+    the partitions one sharded dispatch covers, which share its premium as
+    they share its round trip."""
+    shard_cost.add("mesh_dispatch", cal.mesh_dispatch_s / max(coalesce, 1.0))
     shard_cost.add("combine",
                    max(n_devices - 1, 0) * table_bytes / cal.d2h_bytes_per_s)
     return shard_cost

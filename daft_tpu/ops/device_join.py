@@ -56,9 +56,10 @@ from .grouped_stage import (DeviceFallback, GroupedAggRun, GroupedAggStage,
                             MAX_MATMUL_SEGMENTS, _Decode,
                             _pad_groups, count_reduce,
                             try_build_grouped_agg_stage)
-from .stage import (FilterAggRun, FilterAggStage, batch_planes,
-                    cached_dict_code_plane, device_row_mask, note_program_trace,
-                    pad_bucket)
+from .stage import (MESH_AXIS, FilterAggRun, FilterAggStage, batch_planes,
+                    cached_dict_code_plane, device_row_mask, local_mesh,
+                    mesh_total, note_mesh_dispatch, note_program_trace,
+                    pad_bucket, shard_rows)
 
 
 # ======================================================================================
@@ -588,6 +589,7 @@ class _ProvisionLayout:
     columns: tuple   # per dim column handed on: (name, adjacent dim, digit rows, validity row)
     codes: tuple     # per group-by column: (adjacent dim or -1 = fact-side plane, row or position, radix)
     cap: int         # the combined codes are clipped to [0, cap); 0 where no codes are asked for
+    devices: int = 1  # local devices the batch's rows are sharded over: each runs the program on its shard
 
 
 class _CodePlan(NamedTuple):
@@ -607,7 +609,11 @@ def _provision_program(layout: _ProvisionLayout):
     `__join_ok__`, and the radix-combined group codes where `layout.codes`
     asks for them. One call a dispatch; kept at module level under the
     layout because a _JoinContext lives for one query and the stages' own
-    programs live by structure."""
+    programs live by structure. With `layout.devices` > 1 the fact-long
+    arguments (index planes, code planes) are row-sharded over that many
+    local devices, the packs whole on each, and every device runs the one
+    chip's program on its shard: a shard's window is found from its own
+    indices."""
 
     def run(mats, idxs, fact_codes):
         counters.bump("join_provision_traces")   # runs when traced, not when called
@@ -648,6 +654,13 @@ def _provision_program(layout: _ProvisionLayout):
             combined = jnp.clip(combined, 0, layout.cap - 1)
         return dcols, combined
 
+    if layout.devices > 1:
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        run = shard_map(run, mesh=local_mesh(layout.devices),
+                        in_specs=(P(), P(MESH_AXIS), P(MESH_AXIS)),
+                        out_specs=P(MESH_AXIS), check_vma=False)
     return jax.jit(run)
 
 
@@ -679,6 +692,10 @@ class _JoinContext:
         # Pallas hash-probe tier preference: set by the executor's
         # device_join_pallas_cost arm, read by _pallas_probe_gate's auto branch
         self.pallas_probe_preferred = False
+        # the local devices a dispatch's fact rows are sharded over (set_mesh,
+        # by the run that drives this context); None: one chip
+        self.mesh_devices = 1
+        self.mesh = None
         self.syn_series: Dict[str, Dict[str, object]] = {}
         self._dev_filters: Dict[str, List[Expression]] = {}
         self._host_filters: Dict[str, List[Expression]] = {}
@@ -704,6 +721,26 @@ class _JoinContext:
             for name, expr in d.synthetic:
                 syn[name] = self._cached_syn(b, name, expr)
             self.syn_series[d.name] = syn
+
+    def set_mesh(self, n_devices: int) -> None:
+        """Every dispatch over this context shards its fact batch's rows over
+        `n_devices` local devices (stage.local_mesh): the batch's fact planes,
+        index planes and code planes are row-sharded (slots of their own,
+        keyed with the mesh width), a dimension's pack is whole on every
+        device, and each device runs the one chip's programs on its shard."""
+        self.mesh_devices = max(int(n_devices), 1)
+        self.mesh = local_mesh(self.mesh_devices)
+
+    def bucket_for(self, n: int) -> int:
+        """Padded rows of an n-row fact batch: a chip's bucket, or over a mesh
+        a bucket a shard (stage.mesh_total)."""
+        return pad_bucket(n) if self.mesh is None \
+            else mesh_total(n, self.mesh_devices)
+
+    def _mesh_key(self) -> tuple:
+        """What a slot's key carries where its arrays are laid out over the
+        mesh (as stage._codes_slot keys a code plane)."""
+        return () if self.mesh is None else ("mesh", self.mesh_devices, MESH_AXIS)
 
     @staticmethod
     def _filter_anchor(batch, expr: Expression):
@@ -805,14 +842,18 @@ class _JoinContext:
                              dtype=np.int32)
             null_codes = np.array([i for i, v in enumerate(vals) if v is None],
                                   dtype=np.int32)
-            dcodes = cached_dict_code_plane(s, codes, batch.num_rows, bucket)
+            dcodes = cached_dict_code_plane(s, codes, batch.num_rows, bucket,
+                                            self.mesh)
             plane = jnp.isin(dcodes, jnp.asarray(match))
-            valid = ~jnp.isin(dcodes, jnp.asarray(null_codes)) if len(null_codes) \
-                else jnp.ones(bucket, dtype=bool)
+            if len(null_codes):
+                valid = ~jnp.isin(dcodes, jnp.asarray(null_codes))
+            else:   # (over a mesh: an array laid out as the codes are)
+                valid = jnp.ones(bucket, dtype=bool) if self.mesh is None \
+                    else device_row_mask(bucket, bucket, self.mesh)
             return plane, valid
 
-        return series_keyed(s, ("fmem", syn, bucket), (), build,
-                            literals=values)
+        return series_keyed(s, ("fmem", syn, bucket) + self._mesh_key(), (),
+                            build, literals=values)
 
     def _permuted_membership(self, batch, bucket: int, syn: str, perm) -> dev.DCol:
         colname, values = self.spec.fact_synthetic[syn]
@@ -927,8 +968,8 @@ class _JoinContext:
         for this join's shape. Chained dims keep the host path — their probe
         values flow through the parent's HOST index, so an in-kernel probe
         would not remove the host work it exists to skip."""
-        if d.parent[0] != "fact":
-            return None
+        if d.parent[0] != "fact" or self.mesh is not None:
+            return None     # (the kernel is one chip's: a mesh takes the host's index)
         from ..config import execution_config
 
         mode = getattr(execution_config(), "pallas_mode", "auto")
@@ -1016,7 +1057,10 @@ class _JoinContext:
         the indices, so the packed row-gather emits rows pre-sorted at zero
         extra cost. Under the Pallas gate the plain (un-permuted) plane is
         probed in-kernel instead, and the host, which then holds no index,
-        gives no span (None); a kernel that does not lower raises."""
+        gives no span (None); a kernel that does not lower raises.
+        Over a mesh (set_mesh) the plane is row-sharded under a slot of its
+        own and the span is the widest of the shards': each shard gathers
+        from the window its own rows point into."""
         with profile_span("join.index", "host", dim=dname, bucket=bucket):
             return self._dev_idx(batch, dname, bucket, perm)
 
@@ -1031,13 +1075,20 @@ class _JoinContext:
                 return self._pallas_dev_idx(batch, d, bucket, interp), None
             idx_np = self._indices_for(batch)[dname]
 
+            mesh, ndev = self.mesh, self.mesh_devices
+
             def build():
                 padded = np.full(bucket, -1, dtype=np.int32)
                 padded[:n] = idx_np
-                return jnp.asarray(padded), _index_span(idx_np)
+                if mesh is None:
+                    return jnp.asarray(padded), _index_span(idx_np)
+                per = bucket // ndev
+                return shard_rows(mesh, padded, bucket), max(
+                    _index_span(padded[s * per:(s + 1) * per]) for s in range(ndev))
 
-            return series_keyed(anchor, ("didx", d.key_col, d.parent, bucket),
-                                (idx_np,), build, rebuild_rows=n)
+            return series_keyed(
+                anchor, ("didx", d.key_col, d.parent, bucket) + self._mesh_key(),
+                (idx_np,), build, rebuild_rows=n)
 
         idx_np = self._indices_for(batch)[dname]
         pperm_np, _pdev = perm
@@ -1050,18 +1101,20 @@ class _JoinContext:
         return series_keyed(anchor, ("didxp", d.key_col, d.parent, bucket),
                             (idx_np, pperm_np), build_p, rebuild_rows=n)
 
-    def nonresident_index_bytes(self, batch, bucket: int) -> int:
+    def nonresident_index_bytes(self, batch, bucket: int,
+                                mesh_key: tuple = ()) -> int:
         """h2d bytes the cost model should charge for dim index planes not
         already resident in HBM (advisory: mirrors dev_idx's cache keys —
         both the plain and the perm-folded local-dense variants — so a
-        repeat query is costed with zero index-plane transfer)."""
+        repeat query is costed with zero index-plane transfer). `mesh_key`:
+        the planes as a mesh of that width lays them out (_mesh_key)."""
         from ..device.residency import manager
 
         total = 0
         for d in self.dims:
             anchor = self._probe_anchor(batch, d)
             if not any(manager().is_resident(
-                    anchor, (fam, d.key_col, d.parent, bucket))
+                    anchor, (fam, d.key_col, d.parent, bucket) + mesh_key)
                     for fam in ("didx", "didxp", "pdidx")):
                 total += bucket * 4
         return total
@@ -1229,7 +1282,9 @@ class _JoinContext:
             [f for n in sub
              for f in self._dev_filters[n] + self._host_filters[n]])
         key = ("pack", tuple(my_vals), tuple(my_codes),
-               tuple((d.key_col,) + d.parent for d in sub_dims), fskels)
+               tuple((d.key_col,) + d.parent for d in sub_dims), fskels) \
+            + self._mesh_key()
+        mesh = self.mesh
 
         def build():
             planes, code_planes, ok = self._build_space(adj, vals, codes)
@@ -1269,6 +1324,12 @@ class _JoinContext:
             ok_col = len(cols)
             cols.append(ok_plane.astype(jnp.float32))
             mat = jnp.stack(cols, axis=0)   # [P, cap_d]: minor dim stays long
+            if mesh is not None:
+                # whole on every device of the mesh: a shard's rows gather from
+                # any row of the dimension (the one copy made here is dropped)
+                from jax.sharding import NamedSharding, PartitionSpec
+
+                mat = jax.device_put(mat, NamedSharding(mesh, PartitionSpec()))
             return mat, layout, code_layout, ok_col, wide
 
         return series_keyed(anchor, key, deps, build, literals=flits)
@@ -1296,7 +1357,9 @@ class _JoinContext:
 
         The host's part is the look-ups that find the arrays (every one a
         cache hit on a repeat query) and the layout key; no array is touched
-        outside the program."""
+        outside the program. Over a mesh (set_mesh) `bucket` is the batch's
+        padded rows over all shards (bucket_for) and what comes back is
+        row-sharded: every device ran the program on its shard."""
         with profile_span("join.gather", "device", planes=len(needed)):
             return self._provision(batch, bucket, needed, codes, perm)
 
@@ -1306,6 +1369,8 @@ class _JoinContext:
         gb_cols, radices, cap, fact_code_planes = codes or _CodePlan((), (), 0, {})
         adj_of: Dict[str, int] = {}
         mats, idxs, ok_rows, windows, layouts = [], [], [], [], []
+        ndev = self.mesh_devices
+        shard = bucket // ndev      # rows a device works: the length of its window
         for a, adj in enumerate(self._adjacent()):
             adj_of[adj.name] = a
             didx, span = self.dev_idx(batch, adj.name, bucket, perm=perm)
@@ -1317,16 +1382,21 @@ class _JoinContext:
             # a window as long as the batch serves it where the matched rows
             # lie that close and the pack is longer than one window
             windows.append(mat is not None and span is not None
-                           and span < bucket < mat.shape[1])
+                           and span < shard < mat.shape[1])
             layouts.append((layout, code_layout, wide))
 
         dcols: Dict[str, dev.DCol] = {}
         if perm is None:
             # the batch's own columns as they are: one request for all
-            dcols, _codes = batch_planes(
-                batch, [name for name in needed
-                        if spec.col_side.get(name) == "fact"
-                        and name not in spec.fact_synthetic], bucket, True)
+            own = [name for name in needed if spec.col_side.get(name) == "fact"
+                   and name not in spec.fact_synthetic]
+            if self.mesh is None:
+                dcols, _codes = batch_planes(batch, own, bucket, True)
+            else:
+                with profile_span("join.shard", "device", devices=ndev,
+                                  planes=len(own), bucket=bucket):
+                    dcols, _codes = batch_planes(batch, own, bucket, True,
+                                                 self.mesh)
         columns = []
         for name in needed:
             side = spec.col_side.get(name)
@@ -1363,7 +1433,8 @@ class _JoinContext:
                 code_cols.append((a, layouts[a][1][name], radix))
 
         prog = _provision_program(_ProvisionLayout(
-            tuple(ok_rows), tuple(windows), tuple(columns), tuple(code_cols), cap))
+            tuple(ok_rows), tuple(windows), tuple(columns), tuple(code_cols), cap,
+            ndev))
         gathered, combined = prog(tuple(mats), tuple(idxs), tuple(fact_codes))
         counters.bump("join_provision_calls")
         if any(windows):
@@ -1494,18 +1565,57 @@ def _with_join_ok(predicate: Optional[Expression]) -> Expression:
     return ok if predicate is None else (predicate & ok)
 
 
+def _dict_code_product(ctx: _JoinContext, batch, gb_cols) -> Optional[int]:
+    """Product of per-column dictionary cardinalities (host, cached), or
+    None when a groupby column cannot dictionary-encode."""
+    total = 1
+    for name in gb_cols:
+        side = ctx.spec.col_side.get(name)
+        src = batch.get_column(name) if side == "fact" \
+            else ctx._dim_source(side, name)
+        try:
+            _c, _v, k = src.dict_codes()
+        except Exception:  # lint: ignore[broad-except] -- estimate only; caller treats None as unknown
+            return None
+        total *= max(k, 1)
+    return total
+
+
+def _groupby_columns(stage: GroupedAggStage) -> List[str]:
+    """The joined schema's column each group-by expression names."""
+    return [(g.child if isinstance(g, Alias) else g)._name for g in stage.groupby]
+
+
+def note_join_mesh_dispatch(n_devices: int, stage_noted: bool = False) -> None:
+    """One join dispatch whose fact rows were sharded over `n_devices` > 1
+    local devices (`stage_noted`: the stage's own run has counted it as a
+    mesh dispatch already)."""
+    counters.bump("device_join_mesh_batches")
+    counters.bump("device_join_mesh_shards", n_devices)
+    if not stage_noted:
+        note_mesh_dispatch(n_devices)
+
+
 class DeviceJoinGroupedRun(GroupedAggRun):
     """GroupedAggRun over gather-joined columns: same jitted programs, same
-    finalize/merge — only column provisioning and group codes differ."""
+    finalize/merge — only column provisioning and group codes differ. With
+    `mesh_devices` > 1 a dispatch's fact rows are sharded over that many
+    local devices, each runs the one chip's programs on its shard
+    (_JoinContext.set_mesh, GroupedAggStage._program_for) and the result
+    holds one table a shard, merged on the host like successive batches'.
+    The sharded dispatch takes the dictionary strategy's group codes only
+    (sharded_join_reason: the executor asks before it makes the run)."""
 
     # group-count ceiling for the non-TopN grouped path: the full cap-sized
     # table is fetched at finalize, so cap is bounded by d2h budget, not
     # compute (TopN-fused runs raise this — they fetch K rows)
     max_segments = 1 << 16
 
-    def __init__(self, stage: GroupedAggStage, ctx: _JoinContext):
-        super().__init__(stage, _join_stage_literals(ctx.spec))
+    def __init__(self, stage: GroupedAggStage, ctx: _JoinContext,
+                 mesh_devices: int = 1):
+        super().__init__(stage, _join_stage_literals(ctx.spec), mesh_devices)
         self.ctx = ctx
+        ctx.set_mesh(self.mesh_devices)
 
     # TopN runs force the host-factorize path (dense first-occurrence ids
     # double as the stable tie-break and feed the rank planes)
@@ -1526,18 +1636,16 @@ class DeviceJoinGroupedRun(GroupedAggRun):
         n = batch.num_rows
         if n == 0:
             return
-        bucket = pad_bucket(n)
+        ndev, mesh = self.mesh_devices, self.ctx.mesh
+        bucket = self.ctx.bucket_for(n)
         needed = list(stage._input_cols) + ["__join_ok__"]
-        gb_cols = []
-        for g in stage.groupby:
-            node = g.child if isinstance(g, Alias) else g
-            gb_cols.append(node._name)
+        gb_cols = _groupby_columns(stage)
 
         total = None
         if not self.force_host_codes:
             with profile_span("join.codes", "host", strategy="dict",
                               step="product"):
-                total = self._dict_product(batch, gb_cols)
+                total = _dict_code_product(self.ctx, batch, gb_cols)
         with profile_span("device.dispatch", "device", op="join_agg",
                           rows=n, bucket=bucket):
             if total is not None and 0 < total <= min(self.max_segments,
@@ -1549,13 +1657,17 @@ class DeviceJoinGroupedRun(GroupedAggRun):
                         sp.args["cap"] = decode.cap
                 dcols, decode.dcodes = self.ctx.provision(batch, bucket, needed,
                                                           codes=codes)
-                prog, form = stage._program_for(decode.cap)
-                mask = device_row_mask(n, bucket)
+                decode.shards = ndev
+                prog, form = stage._program_for(decode.cap, mesh_devices=ndev)
+                mask = device_row_mask(n, bucket, mesh)
                 lit_args = self.literals.args((self._row_offset,))
                 with profile_span("device.launch", "device", op="join_agg",
-                                  cap=decode.cap, reduce=form):
+                                  cap=decode.cap, reduce=form, devices=ndev):
                     out = prog(dcols, decode.dcodes, mask, lit_args)
                 count_reduce(form)
+            elif mesh is not None:
+                raise DeviceFallback(
+                    "the sharded join dispatch takes dictionary group codes only")
             else:
                 with profile_span("join.codes", "host", strategy="host") as sp:
                     decode = self._host_factorized_codes(batch, n, bucket)
@@ -1596,21 +1708,8 @@ class DeviceJoinGroupedRun(GroupedAggRun):
         self._pending.append((out, decode))
         counters.bump("device_grouped_batches")
         counters.bump("device_join_batches")
-
-    def _dict_product(self, batch, gb_cols) -> Optional[int]:
-        """Product of per-column dictionary cardinalities (host, cached), or
-        None when a groupby column cannot dictionary-encode."""
-        total = 1
-        for name in gb_cols:
-            side = self.ctx.spec.col_side.get(name)
-            src = batch.get_column(name) if side == "fact" \
-                else self.ctx._dim_source(side, name)
-            try:
-                _c, _v, k = src.dict_codes()
-            except Exception:  # lint: ignore[broad-except] -- estimate only; caller treats None as unknown
-                return None
-            total *= max(k, 1)
-        return total
+        if ndev > 1:
+            note_join_mesh_dispatch(ndev)
 
     def _dict_code_plan(self, batch, n: int, bucket: int, gb_cols):
         """The host's side of the dictionary strategy: each group-by column's
@@ -1627,7 +1726,8 @@ class DeviceJoinGroupedRun(GroupedAggRun):
             if side == "fact":
                 s = batch.get_column(name)
                 codes, values, k = s.dict_codes()
-                fact_codes[name] = cached_dict_code_plane(s, codes, n, bucket)
+                fact_codes[name] = cached_dict_code_plane(s, codes, n, bucket,
+                                                          ctx.mesh)
             else:
                 _codes, values, k = ctx._dim_source(side, name).dict_codes()
             dicts.append((values, k))
@@ -1727,10 +1827,15 @@ class DeviceJoinGroupedRun(GroupedAggRun):
 # its batch's padded group count: TOPN_MAX_SEGMENTS bounds that table and the
 # device sort over it. A run whose ids hold for the whole run (a dimension's
 # rows) builds ONE set of tables of the dimension's padded row count, whatever
-# the number of batches: TOPN_RUN_MAX_SEGMENTS bounds that, by HBM (at 2^25
-# ids a sum's two float32 planes are 268 MB, and q3's three sums, its first-row table
-# and the select's operands come to about 1.7 GB). Neither bounds a fetch:
-# both forms bring back K rows.
+# the number of batches: TOPN_RUN_MAX_SEGMENTS bounds, by HBM, the ids A CHIP
+# combines and selects over at the run's end (at 2^25 ids a sum's two float32
+# planes are 268 MB, and q3's three sums, its first-row table and the
+# select's operands come to about 1.7 GB). One chip selects over the whole
+# table; over a mesh of N every chip adds its shard's rows into a table of
+# its own of all the ids (28 bytes an id for q3: 1.9 GB at 2^26), the chips
+# exchange slices at the end and each selects over ids / N, so the ceiling
+# is held to that share. Neither ceiling bounds a fetch: both forms bring
+# back K rows (a chip).
 TOPN_MAX_SEGMENTS = 1 << 22
 TOPN_RUN_MAX_SEGMENTS = 1 << 25
 
@@ -1878,12 +1983,14 @@ def run_wide_groups(spec: JoinAggSpec) -> Tuple[Optional[RunWideGroups], str]:
     return None, "no dimension's key with its own columns spans the group-by"
 
 
-def topn_run_wide(ctx: _JoinContext, stage: GroupedAggStage
+def topn_run_wide(ctx: _JoinContext, stage: GroupedAggStage,
+                  mesh_devices: int = 1
                   ) -> Tuple[Optional[RunWideGroups], int, str]:
     """(id space, its tables' length, "") where a fused TopN over `ctx` keeps
     run-wide tables, else (None, 0, why it is held to one fact batch). The
     run and the placement decision both ask here, so what is priced is what
-    runs."""
+    runs. Over `mesh_devices` > 1 a chip selects over its share of the ids,
+    and the ceiling is held to that."""
     groups, why = run_wide_groups(ctx.spec)
     if groups is None:
         return None, 0, why
@@ -1891,10 +1998,37 @@ def topn_run_wide(ctx: _JoinContext, stage: GroupedAggStage
     if why:
         return None, 0, why
     cap = pad_bucket(max(ctx.batches[groups.dim.name].num_rows, 1))
-    if cap > TOPN_RUN_MAX_SEGMENTS:
+    if cap // max(mesh_devices, 1) > TOPN_RUN_MAX_SEGMENTS:
         return None, 0, (f"the dimension's {cap} padded rows are over the "
                          f"run-wide table ceiling {TOPN_RUN_MAX_SEGMENTS}")
     return groups, cap, ""
+
+
+def sharded_join_reason(ctx: _JoinContext, stage, grouped: bool, topn: bool,
+                        batch, mesh_devices: int) -> str:
+    """Why a join over `ctx` cannot run as the one chip's dispatch on every
+    shard of a mesh of `mesh_devices` ("" where it can: the runs of this file
+    then take `mesh_devices`). What is declined needs ids made on the host a
+    batch at a time, which the sharded dispatch never makes: a grouped
+    aggregate whose group-by does not dictionary-encode under the matmul
+    ceiling (host-factorized codes, the locally dense layout), and a fused
+    TopN whose ids hold for one batch only. `batch` is a fact batch (the
+    dictionaries of fact-side group columns are read from it). And a join
+    under a forced Pallas hash probe (pallas_mode "on"): that kernel's mesh
+    form is ops/mesh_stage.py's."""
+    from ..config import execution_config
+
+    if getattr(execution_config(), "pallas_mode", "auto") == "on":
+        return "a forced Pallas hash probe runs on one chip or in the fused mesh tier"
+    if not grouped:
+        return ""
+    if topn:
+        return topn_run_wide(ctx, stage, mesh_devices)[2]
+    total = _dict_code_product(ctx, batch, _groupby_columns(stage))
+    if total is None or not 0 < total <= min(DeviceJoinGroupedRun.max_segments,
+                                             MAX_MATMUL_SEGMENTS):
+        return "the group codes need a host factorization of every batch"
+    return ""
 
 
 class DeviceJoinTopNRun(DeviceJoinGroupedRun):
@@ -1912,15 +2046,25 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
     (GroupedAggStage._build_run_wide), and no batch is factorized. Any other
     group-by keeps the older form: ids from the host factorization of ONE
     batch (dense ids in first-occurrence order double as the stable
-    tie-break), a second batch raises DeviceFallback."""
+    tie-break), a second batch raises DeviceFallback.
+
+    With `mesh_devices` > 1 (the run-wide form only) a dispatch's fact rows
+    are sharded over that many local devices and every chip adds its shard
+    into tables of its own, as long as the one chip's. At the finalize the
+    chips exchange the tables by slices of the ids (an all_to_all over
+    MESH_AXIS: a chip receives every chip's rows of ITS slice and adds them
+    up), each selects its slice's first K, and the host merges the K rows a
+    chip by the sort operands the chips sorted on, first-row positions
+    included, so ties fall as on one chip."""
 
     max_segments = TOPN_MAX_SEGMENTS
     force_host_codes = True
 
-    def __init__(self, stage: GroupedAggStage, ctx: _JoinContext, topn: TopNSpec):
-        super().__init__(stage, ctx)
+    def __init__(self, stage: GroupedAggStage, ctx: _JoinContext, topn: TopNSpec,
+                 mesh_devices: int = 1):
+        super().__init__(stage, ctx, mesh_devices)
         self.topn = topn
-        self.groups, self._cap, why = topn_run_wide(ctx, stage)
+        self.groups, self._cap, why = topn_run_wide(ctx, stage, self.mesh_devices)
         # why this run is held to one fact batch ("" where it is not)
         self.one_batch_reason = why
         self._tables = None
@@ -1933,8 +2077,9 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
     def feed_batch(self, batch) -> None:
         if self.run_wide:
             return self._feed_run_wide(batch)
-        if self._pending and batch.num_rows:
-            # bail BEFORE dispatching work the finalize would throw away
+        if batch.num_rows and (self._pending or self.mesh_devices > 1):
+            # bail BEFORE dispatching work the finalize would throw away (the
+            # one-batch form is one chip's: sharded_join_reason)
             raise DeviceFallback(
                 "device TopN holds this group-by to a single fact batch: "
                 + self.one_batch_reason)
@@ -1949,7 +2094,8 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
         n = batch.num_rows
         if n == 0:
             return
-        bucket = pad_bucket(n)
+        ndev = self.mesh_devices
+        bucket = ctx.bucket_for(n)
         needed = list(stage._input_cols) + ["__join_ok__"]
         with profile_span("device.dispatch", "device", op="join_topn",
                           rows=n, bucket=bucket):
@@ -1957,21 +2103,23 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
                               cap=self._cap):
                 gid, _span = ctx.dev_idx(batch, self.groups.dim.name, bucket)
             dcols, _ = ctx.provision(batch, bucket, needed)
-            prog = stage._jit_run_wide(self._cap)
-            mask = device_row_mask(n, bucket)
+            prog = stage._jit_run_wide(self._cap, ndev)
+            mask = device_row_mask(n, bucket, ctx.mesh)
             lit_args = self.literals.args((self._row_offset,))
             if self._tables is None:
-                self._tables = stage.run_wide_tables(self._cap)
+                self._tables = stage.run_wide_tables(self._cap, ndev)
                 counters.bump("device_topn_table_bytes", sum(
                     int(x.nbytes) for x in jax.tree_util.tree_leaves(self._tables)))
             with profile_span("device.launch", "device", op="join_topn",
-                              cap=self._cap, reduce="run_wide"):
+                              cap=self._cap, reduce="run_wide", devices=ndev):
                 self._tables = prog(self._tables, dcols, gid, mask, lit_args)
         self._row_offset += n
         self._batches += 1
         counters.bump("device_grouped_batches")
         counters.bump("device_join_batches")
         counters.bump("device_join_topn_batches")
+        if ndev > 1:
+            note_join_mesh_dispatch(ndev)
 
     def finalize_topn(self):
         """(key_rows, agg_results) for the K winners, in final output order."""
@@ -2029,6 +2177,7 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
         dimension column and direction, resident after."""
         cap = self._cap
         s = self._group_series(index)
+        mesh = self.ctx.mesh
 
         def build():
             rank, valid = _dense_ranks(s)
@@ -2037,45 +2186,87 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
             edge = np.int64(-(1 << 30) if nulls_first else (1 << 30))
             plane = np.full(cap, edge, dtype=np.int32)
             plane[:len(rank)] = np.where(valid, rank, edge).astype(np.int32)
-            return jnp.asarray(plane)
+            # over a mesh: a chip holds the ranks of the ids it selects over
+            return jnp.asarray(plane) if mesh is None else shard_rows(mesh, plane, cap)
 
-        return series_keyed(s, ("jtopn_rank", cap, desc, nulls_first), (), build)
+        return series_keyed(s, ("jtopn_rank", cap, desc, nulls_first)
+                            + self.ctx._mesh_key(), (), build)
 
     def _select_program(self, k: int):
         """The jitted finalize of the run-wide form: the sort operands from
         the tables, the selection, and the K winners' rows."""
         stage, cap, keys = self.stage, self._cap, tuple(self.topn.keys)
-        key = ("topn_select", cap, k, keys)
-        if key not in stage._jitted:
-            def select(tables, ranks):
-                note_program_trace()
-                hi, lo, first = tables["hi"], tables["lo"], tables["first"][:cap]
+        ndev = self.mesh_devices
+        key = ("topn_select", cap, k, keys) + self.ctx._mesh_key()
+        if key in stage._jitted:
+            return stage._jitted[key]
 
-                def mm_col(j):
-                    # a sum is two float32 planes (grouped_stage._build_run_wide)
-                    return hi[j][:cap].astype(jnp.float64) + lo[j][:cap].astype(jnp.float64)
+        def operands_of(mm_col, first, ranks):
+            """The select's sort operands over one span of ids, in order."""
+            present = mm_col(0) > 0
+            operands = [jnp.where(present, 0, 1).astype(jnp.int32)]
+            ranks = list(ranks)
+            for kind, idx, desc, nf in keys:
+                if kind == "group":
+                    operands.append(ranks.pop(0))
+                    continue
+                v, valid = _agg_sort_plane(stage, mm_col, None, idx)
+                if desc:
+                    v = -v
+                operands.append(jnp.where(valid, v, -jnp.inf if nf else jnp.inf))
+            # ties: the order the groups were first seen in, as the host
+            # engine's stable sort over its first-occurrence output
+            operands.append(first)
+            return present, operands
 
-                present = mm_col(0) > 0
-                operands = [jnp.where(present, 0, 1).astype(jnp.int32)]
-                ranks = list(ranks)
-                for kind, idx, desc, nf in keys:
-                    if kind == "group":
-                        operands.append(ranks.pop(0))
-                        continue
-                    v, valid = _agg_sort_plane(stage, mm_col, None, idx)
-                    if desc:
-                        v = -v
-                    operands.append(jnp.where(valid, v, -jnp.inf if nf else jnp.inf))
-                # ties: the order the groups were first seen in, as the host
-                # engine's stable sort over its first-occurrence output
-                operands.append(first)
-                gid = jnp.arange(cap, dtype=jnp.int32)
-                top = select_top(tuple(operands) + (gid,), len(operands), k)[-1]
-                rows = [hi[j][top].astype(jnp.float64) + lo[j][top].astype(jnp.float64)
-                        for j in range(len(hi))]
-                return top, jnp.stack(rows, axis=-1), present[top], tables["dense"]
+        def select(tables, ranks):
+            note_program_trace()
+            hi, lo, first = tables["hi"], tables["lo"], tables["first"][:cap]
 
-            stage._jitted[key] = jax.jit(select)
+            def mm_col(j):
+                # a sum is two float32 planes (grouped_stage._build_run_wide)
+                return hi[j][:cap].astype(jnp.float64) + lo[j][:cap].astype(jnp.float64)
+
+            present, operands = operands_of(mm_col, first, ranks)
+            gid = jnp.arange(cap, dtype=jnp.int32)
+            top = select_top(tuple(operands) + (gid,), len(operands), k)[-1]
+            rows = [hi[j][top].astype(jnp.float64) + lo[j][top].astype(jnp.float64)
+                    for j in range(len(hi))]
+            return top, jnp.stack(rows, axis=-1), present[top], tables["dense"]
+
+        part = cap // ndev      # the ids a chip of the mesh combines and selects over
+
+        def combine_and_select(tables, ranks):
+            """On every chip of the mesh: its own tables in, its slice's K out."""
+            note_program_trace()
+
+            def mine(x):
+                # [every chip, my slice of the ids]: each chip's rows of it
+                return jax.lax.all_to_all(x[:cap].reshape(ndev, part), MESH_AXIS, 0, 0)
+
+            hi = [mine(h) for h in tables["hi"]]
+            lo = [mine(l) for l in tables["lo"]]
+            first = jnp.min(mine(tables["first"]), axis=0)
+            # a chip's share of a sum is a float32 pair: the pairs add up in
+            # float64, as the one chip's select reads its own pair
+            cols = [(h.astype(jnp.float64) + l.astype(jnp.float64)).sum(axis=0)
+                    for h, l in zip(hi, lo)]
+            present, operands = operands_of(lambda j: cols[j], first, ranks)
+            base = jax.lax.axis_index(MESH_AXIS).astype(jnp.int32) * part
+            gid = base + jnp.arange(part, dtype=jnp.int32)
+            chosen = select_top(tuple(operands) + (gid,), len(operands), min(k, part))
+            top = chosen[-1]
+            rows = jnp.stack([c[top - base] for c in cols], axis=-1)
+            return top, rows, present[top - base], tuple(chosen[:-1]), tables["dense"]
+
+        if ndev > 1:
+            from jax import shard_map
+            from jax.sharding import PartitionSpec as P
+
+            select = shard_map(combine_and_select, mesh=self.ctx.mesh,
+                               in_specs=(P(MESH_AXIS), P(MESH_AXIS)),
+                               out_specs=P(MESH_AXIS), check_vma=False)
+        stage._jitted[key] = jax.jit(select)
         return stage._jitted[key]
 
     def _finalize_run_wide(self):
@@ -2088,18 +2279,43 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
         k_eff = min(self.topn.offset + self.topn.limit, self._cap)
         ranks = tuple(self._rank_plane(idx, desc, nf)
                       for kind, idx, desc, nf in self.topn.keys if kind == "group")
+        ndev = self.mesh_devices
+        fetched_rows = int(k_eff)
         with profile_span("join.topn_select", "device", cap=self._cap,
                           rows=int(k_eff), batches=batches) as sp:
-            fetch = self._select_program(k_eff)(tables, ranks)
+            if ndev == 1:
+                fetch = self._select_program(k_eff)(tables, ranks)
+            else:
+                # what crosses between the chips: of every chip's tables, the
+                # slices of the ids the other chips select over
+                moved = sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(
+                    (tables["hi"], tables["lo"], tables["first"]))) * (ndev - 1) // ndev
+                with profile_span("join.combine", "device", devices=ndev,
+                                  cap=self._cap, bytes=moved):
+                    # (the wait is the fetch's anyway: it is counted here, with
+                    # the collective and each chip's select)
+                    fetch = jax.block_until_ready(
+                        self._select_program(k_eff)(tables, ranks))
+                counters.bump("device_topn_combine_bytes", moved)
             with profile_span("device.d2h", "device", op="join_topn",
                               rows=int(k_eff)):
-                gids, mm_rows, present_rows, dense = jax.device_get(fetch)
+                fetch = jax.device_get(fetch)
+            if ndev == 1:
+                gids, mm_rows, present_rows, dense = fetch
+            else:
+                # K rows a chip, each chip's in its own order: merged by the
+                # operands the chips sorted on (np.lexsort's last key leads)
+                gids, mm_rows, present_rows, operands, dense = fetch
+                fetched_rows = len(gids)
+                order = np.lexsort(tuple(reversed(operands)))[:k_eff]
+                gids, mm_rows, present_rows = (
+                    np.asarray(x)[order] for x in (gids, mm_rows, present_rows))
             if sp is not None:
-                sp.args["dense_batches"] = int(dense)
+                sp.args["dense_batches"] = int(np.sum(dense)) // ndev   # (a count a chip)
         del tables
         counters.bump("device_stage_runs")
         counters.bump("device_topn_runs")
-        counters.bump("device_topn_fetched_rows", int(k_eff))
+        counters.bump("device_topn_fetched_rows", fetched_rows)
 
         off = self.topn.offset
         keep = np.asarray(present_rows)[off:]
@@ -2237,20 +2453,28 @@ def try_capture_join_topn(plan):
 
 
 class DeviceJoinUngroupedRun(FilterAggRun):
-    def __init__(self, stage: FilterAggStage, ctx: _JoinContext):
-        super().__init__(stage, _join_stage_literals(ctx.spec))
+    """FilterAggRun over gather-joined columns; with `mesh_devices` > 1 a
+    dispatch's fact rows are sharded over that many local devices
+    (_JoinContext.set_mesh) and the partials come back one a shard."""
+
+    def __init__(self, stage: FilterAggStage, ctx: _JoinContext,
+                 mesh_devices: int = 1):
+        super().__init__(stage, _join_stage_literals(ctx.spec), mesh_devices)
         self.ctx = ctx
+        ctx.set_mesh(self.mesh_devices)
 
     def feed_batch(self, batch) -> None:
         n = batch.num_rows
         if n == 0:
             return
-        bucket = pad_bucket(n)
+        bucket = self.ctx.bucket_for(n)
         with profile_span("device.h2d", "device", rows=n, bucket=bucket):
             dcols = self.ctx.device_cols(
                 batch, bucket, list(self.stage._input_cols) + ["__join_ok__"])
-        self._run(dcols, n, bucket)
+        self._run(dcols, n, bucket, self.ctx.mesh)
         counters.bump("device_join_batches")
+        if self.mesh_devices > 1:
+            note_join_mesh_dispatch(self.mesh_devices, stage_noted=True)   # (by _run)
 
 
 _JOINED_CARD_SAMPLE = 65536

@@ -1053,21 +1053,39 @@ class GroupedAggStage:
             return "the stage has a min or max"
         return None
 
-    def run_wide_tables(self, cap: int) -> dict:
-        """The empty tables of one run over `cap` group ids (_build_run_wide)."""
+    def run_wide_tables(self, cap: int, mesh_devices: int = 1) -> dict:
+        """The empty tables of one run over `cap` group ids (_build_run_wide).
+        With `mesh_devices` > 1 every device of the mesh holds a set of its
+        own, as long as the one chip's: each array is the devices' tables end
+        to end, row-sharded, made on the devices (no host array that long)."""
         length = cap + min(CHUNK_LOCAL, cap)
-        zeros = tuple(jnp.zeros(length, jnp.float32) for _ in self._mm_specs)
-        return {"hi": zeros, "lo": tuple(jnp.zeros_like(z) for z in zeros),
-                "first": jnp.full(length, _NO_ROW, jnp.int32),
-                "dense": jnp.zeros((), jnp.int32)}
+        n_mm = len(self._mm_specs)
 
-    def _jit_run_wide(self, cap: int) -> Callable:
-        key = ("run_wide", cap)
+        def empty(ndev: int = 1):
+            zeros = tuple(jnp.zeros(ndev * length, jnp.float32) for _ in range(n_mm))
+            return {"hi": zeros, "lo": tuple(jnp.zeros_like(z) for z in zeros),
+                    "first": jnp.full(ndev * length, _NO_ROW, jnp.int32),
+                    "dense": jnp.zeros((ndev,) if ndev > 1 else (), jnp.int32)}
+
+        if mesh_devices <= 1:
+            return empty()
+        key = ("run_wide_tables", cap, mesh_devices)
         if key not in self._jitted:
-            self._jitted[key] = self._build_run_wide(cap)
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self._jitted[key] = jax.jit(
+                lambda: empty(mesh_devices), out_shardings=NamedSharding(
+                    local_mesh(mesh_devices), PartitionSpec(MESH_AXIS)))
+        return self._jitted[key]()
+
+    def _jit_run_wide(self, cap: int, mesh_devices: int = 1) -> Callable:
+        key = ("run_wide", cap) if mesh_devices <= 1 \
+            else ("run_wide", cap, "mesh", mesh_devices)
+        if key not in self._jitted:
+            self._jitted[key] = self._build_run_wide(cap, local_mesh(mesh_devices))
         return self._jitted[key]
 
-    def _build_run_wide(self, cap: int) -> Callable:
+    def _build_run_wide(self, cap: int, mesh=None) -> Callable:
         """One batch into the tables of a whole run: the program of a run
         whose group ids mean the same in every batch (device_join's fused
         TopN: a fact row's id is a dimension's row). The tables are the
@@ -1092,7 +1110,14 @@ class GroupedAggStage:
         with its one-hot on the MXU and added to its window of the tables;
         any other batch scatter-adds float32 rows into a float32 table of its
         own, added to the run's whole. Either way a batch's partial is
-        float32 and the run's sum wider, as the merge on the host was."""
+        float32 and the run's sum wider, as the merge on the host was.
+
+        Over `mesh` every device runs this program on its shard of the
+        batch's rows and adds into tables of its own (run_wide_tables): ids
+        are the dimension's rows whichever shard a fact row fell in, so a
+        chip's tables are as long as the one chip's, and a row's position in
+        the run's stream counts the rows of the shards before its own. What
+        the chips added is combined at the run's end (device_join's select)."""
         reason = self.run_wide_reason()
         if reason is not None:
             raise DeviceFallback("run-wide tables: " + reason)
@@ -1101,10 +1126,10 @@ class GroupedAggStage:
         n_mm = len(self._mm_specs)
 
         def stage(tables, cols: Dict[str, dev.DCol], gid: jnp.ndarray,
-                  row_mask: jnp.ndarray, lit_args):
+                  row_mask: jnp.ndarray, lit_args, rows_before=0):
             note_program_trace()
             lits = slots.unpack(lit_args)
-            offset = slots.run_value(lit_args, 0).astype(jnp.int32)
+            offset = slots.run_value(lit_args, 0).astype(jnp.int32) + rows_before
             bucket = gid.shape[0]
             chunk = min(CHUNK_LOCAL, bucket, cap)
             n_chunks = bucket // chunk
@@ -1169,7 +1194,19 @@ class GroupedAggStage:
             return {"hi": acc_hi, "lo": acc_lo, "first": acc_first,
                     "dense": tables["dense"] + dense.astype(jnp.int32)}
 
-        return jax.jit(stage, donate_argnums=0)
+        if mesh is None:
+            return jax.jit(stage, donate_argnums=0)
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        def on_shard(tables, cols, gid, row_mask, lit_args):
+            before = jax.lax.axis_index(MESH_AXIS).astype(jnp.int32) * gid.shape[0]
+            return stage(tables, cols, gid, row_mask, lit_args, before)
+
+        # the literals' values and the row offset go whole to every shard
+        return jax.jit(shard_map(
+            on_shard, mesh=mesh, in_specs=(P(MESH_AXIS),) * 4 + (P(),),
+            out_specs=P(MESH_AXIS), check_vma=False), donate_argnums=0)
 
 
 def _two_sum_add(hi, lo, x):

@@ -13,12 +13,20 @@ in f64 on the host exactly as it combines the partial tables of successive
 batches, the first-row index carrying each shard's row offset so the groups'
 order is the single chip's. A mesh of one device is a chip.
 
-What is left in this module is the join tier (MeshJoin*): fact morsels
-row-sharded over the mesh, dim planes replicated as resident HBM slots, the
-probe a local gather, the cross-shard reduce one ICI collective
-(parallel/distributed.py kernels), behind the same ``feed_batch() /
-finalize()`` contract. Its planes keep their native dtypes (f64 floats, int64
-sums): no cell of the benchmark runs it (ROADMAP D1).
+A star join spans the chips the same way (ops/device_join.py with
+``mesh_devices`` > 1, the path the cell `tpch_sf30_mesh4.joins` measures): the
+single chip's join dispatch on every shard of the fact, a fused TopN's
+run-wide tables a set a chip and combined on the chips at the run's end.
+
+What is left in this module is the OLDER fused join tier (MeshJoin*), which
+the executor reaches only for the shapes that path declines
+(device_join.sharded_join_reason: group codes that need a host factorization
+of every batch, a TopN whose ids hold for one batch only, a forced Pallas
+hash probe): fact morsels row-sharded over the mesh, dim planes replicated as
+resident HBM slots, the probe a local gather, the cross-shard reduce one ICI
+collective (parallel/distributed.py kernels), behind the same ``feed_batch()
+/ finalize()`` contract. Its planes keep their native dtypes (f64 floats,
+int64 sums): no cell of the benchmark runs it (ROADMAP D1).
 
 Residency: sharded column planes go through ``Series.to_device_cached(mesh=)``
 so repeat queries hit resident shards with zero re-upload, and they

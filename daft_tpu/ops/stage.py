@@ -280,7 +280,12 @@ def _combine_partials(op: str, parts: List[Dict[str, Tuple[float, bool]]], name:
     if not good:
         return None
     if op == "sum":
-        return sum(good)
+        total = sum(good)
+        if all(isinstance(v, int) for v in good):
+            # an integer sum is int64 on the device and on the host engine,
+            # where it wraps: partials that wrapped add up the same way
+            total = (total + (1 << 63)) % (1 << 64) - (1 << 63)
+        return total
     return min(good) if op == "min" else max(good)
 
 

@@ -43,6 +43,14 @@ _POSITION_PINS = {
         "pins PR 32's mesh.* entries 18 from the end and the four-chip cell's metrics "
         "to a closed set; PR 36's setup.* entries follow and list the cell "
         "(kept by name in test_bench_setup.py)",
+    "test_bench_joins10.py::test_the_cell_and_its_metrics_are_appended_entries":
+        "counts ONE four-chip cell in BENCHMARK.json; PR 41's tpch_sf30_mesh4.joins is the "
+        "second (what else it asserts is kept by name in test_bench_joins_mesh.py::"
+        "test_the_one_chip_join_cells_entries_are_as_they_were)",
+    "test_bench_setup.py::test_the_four_chip_cells_entries_are_as_they_were":
+        "holds the benchmark's four-chip cells to tpch_sf30_mesh4.scanagg alone; PR 41's "
+        "tpch_sf30_mesh4.joins is the second (what else it asserts is kept by name in "
+        "test_bench_joins_mesh.py::test_the_four_chip_scan_cells_entries_are_as_they_were)",
 }
 
 

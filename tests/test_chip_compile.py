@@ -391,3 +391,114 @@ def test_windowed_provisioning_gathers_from_a_batch_long_window(one_chip, rows):
     assert all(int(np.prod(shape)) <= rows * JOIN_BATCH for shape in windowed), windowed
     if rows == 1:   # rows of one lane width, not 131,072 single values (0.93 ms on the chip)
         assert windowed == [(JOIN_BATCH // 128, 128)]
+
+
+# ---- the join dispatch on every shard of the 2x2 mesh, at tpch_sf30_mesh4.joins' shapes ----
+
+ORDERS_CAP_SF30 = 1 << 26     # orders' 45 M rows, padded
+MESH_CHIPS = 4
+
+
+def _mesh_shardings(topo):
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    return mesh, NamedSharding(mesh, P("dp")), NamedSharding(mesh, P())
+
+
+def _sharded_run_wide_tables(stage, rows, cap):
+    """A set of tables a chip, end to end (GroupedAggStage.run_wide_tables)."""
+    length = MESH_CHIPS * (cap + 4096)
+    planes = tuple(_s(rows, (length,), jnp.float32) for _ in stage._mm_specs)
+    return {"hi": planes, "lo": planes, "first": _s(rows, (length,), jnp.int32),
+            "dense": _s(rows, (MESH_CHIPS,), jnp.int32)}
+
+
+def test_sharded_run_wide_accumulate_lowers_at_sf30(topo):
+    """One dispatch of q3's run-wide program over the mesh: 131,072 rows a
+    chip into a chip's own tables of 2^26 order ids, donated, and no
+    collective: a dispatch leaves the chips' tables apart."""
+    stage, _topn = _q3_join_stage()
+    mesh, rows, whole = _mesh_shardings(topo)
+    total = MESH_CHIPS * JOIN_BATCH
+    tables = _sharded_run_wide_tables(stage, rows, ORDERS_CAP_SF30)
+    ints = {"l_shipdate"}
+    cols = {name: (_s(rows, (total,), jnp.bool_ if name == "__join_ok__"
+                      else jnp.int32 if name in ints else jnp.float32),
+                   _s(rows, (total,), jnp.bool_)) for name in stage._input_cols}
+    compiled = stage._build_run_wide(ORDERS_CAP_SF30, mesh).lower(
+        tables, cols, _s(rows, (total,), jnp.int32), _s(rows, (total,), jnp.bool_),
+        _literal_args(stage, whole)).compile()
+    mem = compiled.memory_analysis()
+    a_chips = (len(stage._mm_specs) * 2 * 4 + 4) * (ORDERS_CAP_SF30 + 4096)
+    assert a_chips <= mem.argument_size_in_bytes < a_chips + (64 << 20)
+    assert mem.alias_size_in_bytes >= a_chips - 8       # donated: no second copy
+    assert mem.temp_size_in_bytes < a_chips
+    text = compiled.as_text()
+    assert not [c for c in COLLECTIVES if c in text]
+
+
+def test_sharded_run_wide_combine_and_select_lowers_at_sf30(topo):
+    """The run's end on the mesh: every chip's tables of 2^26 ids in, an
+    all-to-all that hands a chip its 2^24 ids of each chip's tables, the sum,
+    the select over the slice, K rows a chip out. A chip's temporaries stay
+    under its tables' size, so tables, temporaries and what stays resident
+    fit its 16 GB."""
+    import daft_tpu.ops.device_join as dj
+
+    stage, topn = _q3_join_stage()
+    mesh, rows, _whole = _mesh_shardings(topo)
+
+    class _Ctx:     # what _select_program reads of the join context
+        def _mesh_key(self):
+            return ("mesh", MESH_CHIPS, "dp")
+
+    ctx = _Ctx()
+    ctx.mesh = mesh
+    run = dj.DeviceJoinTopNRun.__new__(dj.DeviceJoinTopNRun)
+    run.stage, run._cap, run.topn, run.mesh_devices, run.ctx = \
+        stage, ORDERS_CAP_SF30, topn, MESH_CHIPS, ctx
+    ranks = tuple(_s(rows, (ORDERS_CAP_SF30,), jnp.int32)
+                  for kind, *_rest in topn.keys if kind == "group")
+    compiled = run._select_program(10).lower(
+        _sharded_run_wide_tables(stage, rows, ORDERS_CAP_SF30), ranks).compile()
+    text = compiled.as_text()
+    assert "all-to-all" in text and "sort" in text
+    mem = compiled.memory_analysis()
+    a_chips = (len(stage._mm_specs) * 2 * 4 + 4) * (ORDERS_CAP_SF30 + 4096)
+    assert mem.temp_size_in_bytes < a_chips
+    assert mem.output_size_in_bytes < 64 * 1024  # K rows and their sort operands a chip
+
+
+@pytest.mark.parametrize("rows_of_pack", [6, 1], ids=["q5_six_rows", "q3_q10_one_row"])
+def test_sharded_provisioning_gathers_from_a_shards_own_window(topo, monkeypatch, rows_of_pack):
+    """The provisioning program on every shard: `orders`' pack of [6, 2^26]
+    (q5) or [1, 2^26] (q3, q10) whole on each chip, 131,072 indices a chip.
+    A shard's windowed gather reads a window of its own length, never the
+    2^26-row pack, and the program runs no collective."""
+    import daft_tpu.ops.device_join as dj
+
+    mesh, rows, whole = _mesh_shardings(topo)
+    monkeypatch.setattr(dj, "local_mesh", lambda n: mesh)
+    total = MESH_CHIPS * JOIN_BATCH
+    if rows_of_pack == 6:
+        layout = dj._ProvisionLayout(
+            packs=(5, 2), windows=(True, False),
+            columns=(("c_nationkey", 0, (0,), 1), ("o_total", 0, (2,), 3),
+                     ("s_nationkey", 1, (0,), 1)),
+            codes=((0, 4, 1),), cap=32, devices=MESH_CHIPS)
+        mats = (_s(whole, (6, ORDERS_CAP_SF30), jnp.float32),
+                _s(whole, (3, 1 << 19), jnp.float32))
+    else:
+        layout = dj._ProvisionLayout(packs=(0,), windows=(True,), columns=(), codes=(),
+                                     cap=0, devices=MESH_CHIPS)
+        mats = (_s(whole, (1, ORDERS_CAP_SF30), jnp.float32),)
+    idxs = tuple(_s(rows, (total,), jnp.int32) for _ in mats)
+    try:
+        text = _compile(dj._provision_program(layout), mats, idxs, ())
+    finally:
+        dj._provision_program.cache_clear()     # a program bound to the described mesh
+    shapes = _gather_operand_shapes(text)
+    assert len(shapes) == len(mats)
+    assert (JOIN_BATCH // 128, 128) in shapes if rows_of_pack == 1 \
+        else (6, JOIN_BATCH) in shapes, shapes
+    assert max(max(shape) for shape in shapes) < ORDERS_CAP_SF30
+    assert not [c for c in COLLECTIVES if c in text]

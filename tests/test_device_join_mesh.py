@@ -1,0 +1,237 @@
+"""The single chip's join dispatch on every shard of a mesh (`mesh_devices`
+> 1 in ops/device_join.py), on the CPU's virtual devices: the answers of one
+chip, the run-wide tables of one chip once the shards' are added up, the one
+chip's order where groups straddle shards and sort keys tie across chips, and
+a ceiling that is held to a chip's share of the ids."""
+
+import numpy as np
+import pytest
+
+import jax
+
+import daft_tpu
+from daft_tpu import col
+from daft_tpu.config import execution_config_ctx
+from daft_tpu.ops import counters
+
+import test_device_join as tj
+
+pytestmark = pytest.mark.skipif(
+    len(jax.devices()) < 4, reason="needs 4 (virtual) devices: see conftest")
+
+MESH = 4
+_MORSEL = tj._MORSEL
+
+
+def _run(q, mesh_devices):
+    """(answer, counters) of one forced device run over `mesh_devices`."""
+    counters.reset()
+    counters.rejections.clear()
+    with execution_config_ctx(device_mode="on", morsel_size_rows=_MORSEL,
+                              pipeline_mode="force", mesh_devices=mesh_devices):
+        out = q().to_pydict()
+    return out, counters.snapshot()
+
+
+def _ungrouped(t):
+    return (t["orders"].where(col("o_orderdate") < tj._days(1995, 3, 15))
+            .join(t["lineitem"].where(col("l_returnflag") == "R"),
+                  left_on="o_orderkey", right_on="l_orderkey")
+            .agg((col("l_extendedprice") * (1 - col("l_discount"))).sum().alias("revenue"),
+                 col("l_discount").count().alias("n")))
+
+
+def _ungrouped_numpy(t):
+    """The ungrouped join-aggregate in numpy float64 on the tables' columns."""
+    o, li = t["orders"].to_pydict(), t["lineitem"].to_pydict()
+    kept = {k for k, d in zip(o["o_orderkey"], o["o_orderdate"])
+            if d < tj._days(1995, 3, 15)}
+    keep = np.array([k in kept and f == "R"
+                     for k, f in zip(li["l_orderkey"], li["l_returnflag"])])
+    price, disc = np.array(li["l_extendedprice"]), np.array(li["l_discount"])
+    return float((price * (1 - disc))[keep].sum()), int(keep.sum())
+
+
+@pytest.fixture(scope="module")
+def topn_tables():
+    # thirteen morsels: a mesh dispatch takes four, so the run ends on a
+    # dispatch whose last shards are short or empty
+    return tj._topn_tables(n_l=_MORSEL * 13 - 100)
+
+
+@pytest.fixture(scope="module")
+def tpch_like():
+    return tj._tpch_like()
+
+
+# ---- (a) the answers are the one chip's ----------------------------------------------------
+
+@pytest.mark.parametrize("shape", ["q3", "q10", "q5", "ungrouped"])
+def test_sharded_join_answers_as_one_chip_does(topn_tables, tpch_like, shape):
+    """q3-, q10-, q5-shaped joins and an ungrouped join-aggregate over a fact
+    of several batches under mesh_devices=4: the host engine's rows in its
+    order, the one chip's answer, every join dispatch spanning four devices."""
+    if shape == "q5":
+        q = lambda: tj._q5_shaped(tpch_like)
+    elif shape == "ungrouped":
+        q = lambda: _ungrouped(topn_tables)
+    else:
+        q = lambda: {"q3": tj._topn_q3, "q10": tj._topn_q10}[shape](topn_tables)
+    host = tj._host_answer(q)
+    one, c1 = _run(q, 1)
+    four, c4 = _run(q, MESH)
+    assert c1.get("device_join_mesh_batches", 0) == 0 and c1["device_join_batches"] > 0
+    joins = c4["device_join_batches"]
+    assert joins > 0 and c4["device_join_mesh_batches"] == joins, counters.rejections
+    assert c4["device_join_mesh_shards"] == MESH * joins
+    assert c4["device_mesh_batches"] == joins and c4["mesh_join_runs"] == 1
+    tj._assert_close(host, one)
+    tj._assert_close(host, four)
+    if shape in ("q3", "q10"):
+        limit = 10 if shape == "q3" else 20
+        assert four == one, "ties included: the merge of K rows a chip is the one chip's order"
+        assert c4["device_topn_runs"] == 1 and c4["device_join_topn_batches"] == joins > 1
+        assert c4["device_topn_fetched_rows"] == MESH * limit
+        assert c4["device_topn_combine_bytes"] > 0 and not c1.get("device_topn_combine_bytes")
+        assert c1["device_join_batches"] > joins, "a mesh dispatch covers a bucket a shard"
+    if shape == "ungrouped":
+        revenue, n = _ungrouped_numpy(topn_tables)
+        assert four["n"] == [n]
+        assert four["revenue"][0] == pytest.approx(revenue, rel=1e-6)
+
+
+# ---- (b) the shards' tables add up to the one chip's ---------------------------------------
+
+def test_the_four_shards_tables_add_up_to_the_one_chips(topn_tables, monkeypatch):
+    """q3's run-wide tables at the run's end: every chip's set, added up over
+    the chips, is the one chip's. Rows and first-row positions exactly (a
+    count is a sum of ones, and a row's position in the run's stream does not
+    depend on the shard it fell in); a sum within 1e-6 of its size: a chip's
+    share is a float32 pair whose low word carries what the high word's 24
+    bits drop, and shares added in another order differ in the pair's last
+    bits."""
+    import daft_tpu.ops.device_join as dj
+
+    seen = {}
+    real = dj.DeviceJoinTopNRun._finalize_run_wide
+
+    def spy(self):
+        cap, ndev = self._cap, self.mesh_devices
+        seen[ndev] = (cap, jax.device_get(
+            {k: self._tables[k] for k in ("hi", "lo", "first")}))
+        return real(self)
+
+    monkeypatch.setattr(dj.DeviceJoinTopNRun, "_finalize_run_wide", spy)
+    one, _c = _run(lambda: tj._topn_q3(topn_tables), 1)
+    four, _c = _run(lambda: tj._topn_q3(topn_tables), MESH)
+    assert one == four
+    (cap, t1), (cap4, t4) = seen[1], seen[MESH]
+    assert cap == cap4
+
+    def of_ids(x, ndev):
+        """[ndev, cap]: a chip's table is the one chip's length; cut the tail."""
+        x = np.asarray(x)
+        return x.reshape(ndev, len(x) // ndev)[:, :cap]
+
+    for j in range(len(t1["hi"])):
+        whole = (of_ids(t1["hi"][j], 1).astype(np.float64)
+                 + of_ids(t1["lo"][j], 1).astype(np.float64))[0]
+        shares = (of_ids(t4["hi"][j], MESH).astype(np.float64)
+                  + of_ids(t4["lo"][j], MESH).astype(np.float64))
+        assert (shares != 0).any(axis=1).all(), "every chip added rows of its own"
+        np.testing.assert_allclose(shares.sum(axis=0), whole, rtol=1e-6, atol=1e-6)
+        if j == 0:      # the rows a group took in: a count
+            np.testing.assert_array_equal(shares.sum(axis=0), whole)
+    np.testing.assert_array_equal(of_ids(t4["first"], MESH).min(axis=0),
+                                  of_ids(t1["first"], 1)[0])
+
+
+# ---- (c) groups that straddle shards, keys that tie across chips -----------------------------
+
+def test_groups_straddle_shards_and_ties_fall_as_on_one_chip(topn_tables):
+    """The fact is sorted by order key and an order has several lines, so
+    orders straddle the shard boundaries of a dispatch; revenues are whole
+    numbers that tie, and the tied groups' ids lie in different chips'
+    slices: the merge of the chips' winners gives the one chip's rows in the
+    one chip's order, with and without an offset."""
+    keys = topn_tables["lineitem"].to_pydict()["l_orderkey"]
+    per = _MORSEL    # rows a shard of a full dispatch
+    cuts = [b for b in range(per, len(keys), per) if keys[b - 1] == keys[b]]
+    assert cuts, "an order's lines lie on both sides of a shard boundary"
+    for shape in (tj._topn_q3, tj._topn_q10):
+        for offset in (0, 3):
+            q = lambda: shape(topn_tables, offset=offset)
+            host = tj._host_answer(q)
+            revenue = host["revenue"]
+            assert len(set(revenue)) < len(revenue), "the sort key ties"
+            one, _c = _run(q, 1)
+            four, c4 = _run(q, MESH)
+            assert c4["device_topn_runs"] == 1, counters.rejections
+            assert four == one
+            tj._assert_close(host, four)
+    # the winners' ids come from more than one chip's slice of the id space
+    orders = topn_tables["orders"].to_pydict()["o_orderkey"]
+    from daft_tpu.ops.stage import pad_bucket
+
+    part = pad_bucket(len(orders)) // MESH
+    row_of = {k: i for i, k in enumerate(orders)}
+    four, _c = _run(lambda: tj._topn_q3(topn_tables), MESH)
+    assert len({row_of[k] // part for k in four["l_orderkey"]}) > 1
+
+
+# ---- (d) the ceiling is a chip's ---------------------------------------------------------------
+
+def test_the_table_ceiling_is_held_to_a_chips_share_of_the_ids(topn_tables, monkeypatch):
+    """A dimension whose padded rows pass TOPN_RUN_MAX_SEGMENTS is refused on
+    one chip with the ceiling's reason (the run is then held to one fact
+    batch, and a fact of several goes to the host plan) and accepted on a mesh
+    of four, where a chip combines and selects over a quarter of the ids."""
+    import daft_tpu.ops.device_join as dj
+    from daft_tpu.ops.stage import pad_bucket
+
+    cap = pad_bucket(len(topn_tables["orders"].to_pydict()["o_orderkey"]))
+    monkeypatch.setattr(dj, "TOPN_RUN_MAX_SEGMENTS", cap // MESH)
+    q = lambda: tj._topn_q3(topn_tables)
+    host = tj._host_answer(q)
+    one, c1 = _run(q, 1)
+    assert c1.get("device_topn_runs", 0) == 0
+    why = " ".join(entry for _site, entry in counters.rejection_log)
+    assert "over the run-wide table ceiling" in why and "multi-batch fact" in why, why
+    tj._assert_close(host, one)     # the host plan's answer
+    four, c4 = _run(q, MESH)
+    assert c4["device_topn_runs"] == 1 and c4["device_join_mesh_batches"] > 1, \
+        counters.rejections
+    assert c4["device_topn_fetched_rows"] == MESH * 10
+    tj._assert_close(host, four)
+    # a chip's share over the ceiling too: refused on the mesh with the same reason
+    monkeypatch.setattr(dj, "TOPN_RUN_MAX_SEGMENTS", cap // MESH // 2)
+    _, c4 = _run(q, MESH)
+    assert c4.get("device_topn_runs", 0) == 0 and c4.get("device_join_mesh_batches", 0) == 0
+
+
+def test_what_the_sharded_dispatch_declines_keeps_the_fused_mesh_tier(tpch_like):
+    """A grouped join whose group-by does not dictionary-encode under the
+    matmul ceiling needs ids factorized on the host a batch at a time: the
+    sharded dispatch declines it (sharded_join_reason) and the older fused
+    tier of ops/mesh_stage.py runs it."""
+    import daft_tpu.ops.device_join as dj
+
+    t = tpch_like
+
+    def q():    # grouped by the order key: as many groups as orders
+        return (t["orders"].join(t["lineitem"], left_on="o_orderkey", right_on="l_orderkey")
+                .groupby("o_orderkey").agg(col("l_extendedprice").sum().alias("s"))
+                .sort("o_orderkey"))
+
+    with execution_config_ctx(device_mode="off"):
+        host = q().to_pydict()
+    counters.reset()
+    with execution_config_ctx(device_mode="on", mesh_devices=MESH, device_min_rows=1):
+        mesh = q().to_pydict()
+    snap = counters.snapshot()
+    assert snap.get("device_join_mesh_batches", 0) == 0
+    if snap.get("mesh_join_runs", 0):     # the fused tier built for this shape
+        assert snap.get("mesh_dispatches", 0) > 0
+    assert mesh["o_orderkey"] == host["o_orderkey"]
+    np.testing.assert_allclose(mesh["s"], host["s"], rtol=1e-6)
+    assert dj.sharded_join_reason.__doc__

@@ -242,7 +242,10 @@ def test_auto_join_decision_prices_all_three_tiers(star, monkeypatch):
         for tier in ("device", "host", "mesh"):
             assert rec.get(tier, {}).get("total", 0) > 0, \
                 f"{tier} CostBreakdown absent from the join decision"
-        assert "ici" in rec["mesh"] and "mesh_dispatch" in rec["mesh"]
+        # the sharded dispatch's arm: the premium of spanning the devices and
+        # the fetch of a partial table a shard (a grouped join runs no
+        # collective, so it prices no ICI term; a fused TopN's combine does)
+        assert "mesh_dispatch" in rec["mesh"] and "combine" in rec["mesh"]
         assert counters.mesh_join_runs > 0, "costed mesh verdict did not run"
         with execution_config_ctx(device_mode="off"):
             host = _grouped_q(fact, dim).to_pydict()
