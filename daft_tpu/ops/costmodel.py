@@ -183,7 +183,9 @@ class Calibration:
     # l_returnflag on the builder's host (PR 28), against 4.9 ms through
     # make_groups, which host_factorize_rate prices at 16 ms.
     host_dict_encode_rate: float = 4e7
-    # the run-wide TopN's two forms (grouped_stage._build_run_wide). Dense:
+    # the run-wide TopN's forms (grouped_stage._build_run_wide; a dispatch
+    # that keeps few rows compacts them and scatters those alone, which no
+    # price can foresee: the scatter form's is the ceiling). Dense:
     # one-hot cells of a chunk against its own id window (rows x chunk, once
     # for the sums on the MXU and once for the first-row minimum), built and
     # contracted without leaving the chip's memory: q3 at SF10 read 1.17 ms

@@ -2283,6 +2283,10 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
         fetched_rows = int(k_eff)
         with profile_span("join.topn_select", "device", cap=self._cap,
                           rows=int(k_eff), batches=batches) as sp:
+            # the select programs take the leaves they were compiled for (25-28
+            # s each on a machine's first process: their text stays as it was);
+            # what the accumulate program counted beside them rides the same fetch
+            compact = tables.pop("compact")
             if ndev == 1:
                 fetch = self._select_program(k_eff)(tables, ranks)
             else:
@@ -2299,7 +2303,7 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
                 counters.bump("device_topn_combine_bytes", moved)
             with profile_span("device.d2h", "device", op="join_topn",
                               rows=int(k_eff)):
-                fetch = jax.device_get(fetch)
+                fetch, compact = jax.device_get((fetch, compact))
             if ndev == 1:
                 gids, mm_rows, present_rows, dense = fetch
             else:
@@ -2310,9 +2314,12 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
                 order = np.lexsort(tuple(reversed(operands)))[:k_eff]
                 gids, mm_rows, present_rows = (
                     np.asarray(x)[order] for x in (gids, mm_rows, present_rows))
+            compact_batches = int(np.sum(compact)) // ndev              # (a count a chip)
             if sp is not None:
                 sp.args["dense_batches"] = int(np.sum(dense)) // ndev   # (a count a chip)
+                sp.args["compact_batches"] = compact_batches
         del tables
+        counters.bump("join_topn_compact_batches", compact_batches)
         counters.bump("device_stage_runs")
         counters.bump("device_topn_runs")
         counters.bump("device_topn_fetched_rows", fetched_rows)

@@ -1065,7 +1065,8 @@ class GroupedAggStage:
             zeros = tuple(jnp.zeros(ndev * length, jnp.float32) for _ in range(n_mm))
             return {"hi": zeros, "lo": tuple(jnp.zeros_like(z) for z in zeros),
                     "first": jnp.full(ndev * length, _NO_ROW, jnp.int32),
-                    "dense": jnp.zeros((ndev,) if ndev > 1 else (), jnp.int32)}
+                    "dense": jnp.zeros((ndev,) if ndev > 1 else (), jnp.int32),
+                    "compact": jnp.zeros((ndev,) if ndev > 1 else (), jnp.int32)}
 
         if mesh_devices <= 1:
             return empty()
@@ -1096,21 +1097,27 @@ class GroupedAggStage:
         double-single: about 48 bits, each addition an error-free two-sum),
         the int32 position in the run's stream of each id's first kept row
         (the order the host engine's stable sort leaves ties in), and how
-        many dispatches took the dense form. Not float64 planes: the chip
-        keeps a float64 array as two float32 ones INSIDE a program only, and
-        converts the whole of it at the program's entry and exit (on a v5e
-        4-5 ms a dispatch for three planes of 2^24 ids, whatever the batch
-        added: PR 38's chip run).
+        many dispatches took the dense and the compact form. Not float64
+        planes: the chip keeps a float64 array as two float32 ones INSIDE a
+        program only, and converts the whole of it at the program's entry and
+        exit (on a v5e 4-5 ms a dispatch for three planes of 2^24 ids,
+        whatever the batch added: PR 38's chip run).
 
-        A dispatch adds up in one of two forms, by what its ids are, seen on
-        the device: where every chunk of CHUNK_LOCAL rows holds ids within
+        A dispatch adds up in one of three forms, by what its rows are, seen
+        on the device: where every chunk of CHUNK_LOCAL rows holds ids within
         CHUNK_LOCAL of each other (a fact sorted by the dimension's key, as
         lineitem by order: the locally dense layout of _build_local_dense
         with no host permutation), a chunk's float32 planes are contracted
         with its one-hot on the MXU and added to its window of the tables;
         any other batch scatter-adds float32 rows into a float32 table of its
-        own, added to the run's whole. Either way a batch's partial is
-        float32 and the run's sum wider, as the merge on the host was.
+        own, added to the run's whole. A scatter on the chip costs by its
+        index count, dropped indices included (0.89 ms for a bucket's 131,072,
+        0.07 for 8,192: PERF.md, PR 42), so a batch that keeps at most a
+        bucket's 1 / COMPACT_SHARE of its rows (q10: 1.3%) first compacts
+        them in stream order (_compact_kept) and scatters those; one that
+        keeps more scatters the whole bucket. Either way a batch's partial is
+        float32 and the run's sum wider, as the merge on the host was; the
+        tables count the dense and the compacted dispatches.
 
         Over `mesh` every device runs this program on its shard of the
         batch's rows and adds into tables of its own (run_wide_tables): ids
@@ -1188,11 +1195,31 @@ class GroupedAggStage:
                     for k, (h, l) in enumerate(zip(acc_hi, acc_lo))))
                 return new_hi, new_lo, acc_first.at[at].min(pos, mode="drop")
 
-            acc_hi, acc_lo, acc_first = jax.lax.cond(
-                dense, dense_form, scatter_form,
+            # (a bucket is a power of two from 512 up: whole lines of lanes)
+            n_compact = bucket // COMPACT_SHARE
+
+            def compact_form(acc):
+                acc_hi, acc_lo, acc_first = acc
+                src, ids, rows = _compact_kept(
+                    seg, [vals[:, k] for k in range(n_mm)], cap, n_compact)
+                at = jnp.where(src < bucket, ids, acc_first.shape[0])   # an empty slot: dropped
+                new_hi, new_lo = zip(*(
+                    _two_sum_add(h, l, jnp.zeros(h.shape, jnp.float32)
+                                 .at[at].add(rows[k], mode="drop"))
+                    for k, (h, l) in enumerate(zip(acc_hi, acc_lo))))
+                return new_hi, new_lo, acc_first.at[at].min(offset + src, mode="drop")
+
+            def sparse_forms(acc):
+                # (nothing of the compaction is computed for a dense dispatch)
+                few = jnp.sum(kept, dtype=jnp.int32) <= n_compact
+                return jax.lax.cond(few, compact_form, scatter_form, acc) + (few,)
+
+            acc_hi, acc_lo, acc_first, compacted = jax.lax.cond(
+                dense, lambda acc: dense_form(acc) + (jnp.bool_(False),), sparse_forms,
                 (tables["hi"], tables["lo"], tables["first"]))
             return {"hi": acc_hi, "lo": acc_lo, "first": acc_first,
-                    "dense": tables["dense"] + dense.astype(jnp.int32)}
+                    "dense": tables["dense"] + dense.astype(jnp.int32),
+                    "compact": tables["compact"] + compacted.astype(jnp.int32)}
 
         if mesh is None:
             return jax.jit(stage, donate_argnums=0)
@@ -1220,6 +1247,63 @@ def _two_sum_add(hi, lo, x):
     # an infinite or NaN sum stays what it is (its error term would be a NaN)
     ok = jnp.isfinite(s)
     return jnp.where(ok, new_hi, s), jnp.where(ok, lo - (new_hi - s), 0.0)
+
+
+# a run-wide dispatch that keeps at most a bucket's 1 / COMPACT_SHARE of its
+# rows scatters those alone (_build_run_wide's compact form). Every compacted
+# dispatch pays for the K = bucket / COMPACT_SHARE slots whatever it kept
+# (whole forms on a v5e, 131,072 rows into 2^21 ids: K = 4,096 0.26 ms, 8,192
+# 0.40, 16,384 0.81, 32,768 1.48, the scatter form 3.63; PERF.md, PR 42)
+COMPACT_SHARE = 16
+
+
+def _compact_kept(seg: jnp.ndarray, planes, cap: int, k: int):
+    """The first `k` kept rows (seg < cap) of a bucket in stream order:
+    (src, ids, rows), each [k]: the row's number in the bucket (the bucket's
+    length where fewer rows are kept), its id, and its value in each of
+    `planes`, bit for bit.
+
+    No sort, no scan and no bucket-long scatter, which the chip punishes
+    (_build_local_dense; jnp.nonzero(size=k) is such a scatter: 9.05 ms on
+    the v5e for k = 8,192 of 131,072 rows, PERF.md PR 42). The bucket is read
+    as lines of _LANES rows. A kept row's place among the kept rows is the
+    kept rows of the lines before its own plus those of the lanes before it
+    (counts of 0/1: a product with a triangle, exact in bfloat16). Slot j's
+    line is the number of lines that end before place j (k x lines compares);
+    the k lines of each plane are gathered whole, which the chip does fast
+    where a gather of single values is bound by its index count (PERF.md, PR
+    39), and the one lane whose place is j is kept by its bits, a sum over
+    the other lanes' zeros in int32, as device_join._gather_rows picks a
+    lane. A gather a plane: one gather of the planes stacked read three
+    times slower (0.37 against 0.12 ms)."""
+    lines = seg.shape[0] // _LANES
+    kept = (seg < cap).reshape(lines, _LANES)
+    count = jnp.sum(kept, axis=1, dtype=jnp.int32)
+    line = jnp.arange(lines, dtype=jnp.int32)
+    through = jnp.sum(jnp.where(line[None, :] <= line[:, None], count[None, :], 0),
+                      axis=1, dtype=jnp.int32)
+    lane = jnp.arange(_LANES, dtype=jnp.int32)
+    within = jnp.matmul(kept.astype(jnp.bfloat16),
+                        (lane[:, None] < lane[None, :]).astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32).astype(jnp.int32)
+    place = jnp.where(kept, (through - count)[:, None] + within, -1)
+    slot = jnp.arange(k, dtype=jnp.int32)
+    at = jnp.minimum(jnp.sum(through[None, :] <= slot[:, None], axis=1, dtype=jnp.int32),
+                     lines - 1)
+    mine = place[at] == slot[:, None]
+
+    def pick(plane):
+        """int32[bucket] -> [k]: slot j's lane of its line (0 where there is none)."""
+        return jnp.sum(jnp.where(mine, plane.reshape(lines, _LANES)[at], 0), axis=1,
+                       dtype=jnp.int32)
+
+    src = jnp.where(jnp.any(mine, axis=1),
+                    at * _LANES + jnp.sum(jnp.where(mine, lane[None, :], 0), axis=1,
+                                          dtype=jnp.int32),
+                    seg.shape[0])
+    rows = tuple(jax.lax.bitcast_convert_type(
+        pick(jax.lax.bitcast_convert_type(p, jnp.int32)), jnp.float32) for p in planes)
+    return src, pick(seg), rows
 
 
 def _bfloat16_terms(vals: jnp.ndarray) -> jnp.ndarray:

@@ -10,6 +10,7 @@ says "lowers", never "right" or "fast".
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -287,27 +288,70 @@ def _run_wide_tables(stage, sharding, cap):
     return jax.tree_util.tree_map(lambda x: _s(sharding, x.shape, x.dtype), shapes)
 
 
-def test_run_wide_topn_accumulate_lowers_at_sf10(one_chip):
-    """One dispatch of q3's run-wide program: a batch of 131,072 rows into
-    tables of 2^24 order ids, both forms in one program, the tables donated
-    (no second copy of them among the temporaries)."""
+# the accumulate program's branches, as jax names them in an operation's
+# metadata: cond(dense, dense_form, cond(few kept, compact_form, scatter_form))
+_SCATTER_FORM = "cond/branch_0_fun/cond/branch_0_fun/"
+_COMPACT_FORM = "cond/branch_0_fun/cond/branch_1_fun/"
+
+
+def _scatter_index_counts(text, branch):
+    """How many indices each scatter of one branch of a compiled program takes."""
+    counts = []
+    for ops, rest in re.findall(r" scatter\(([^)]*)\)(.*)", text):
+        if branch not in rest:
+            continue
+        indices = ops.split(",")[1].strip().lstrip("%")
+        shape = re.search(r"%" + re.escape(indices) + r" = s32\[(\d+)", text)
+        counts.append(int(shape.group(1)))
+    return counts
+
+
+def _assert_compacts_without_sort_or_scan(text, n_planes):
+    """The accumulate program's sparse forms, as the chip's compiler left
+    them: the scatter form's scatters (a plane each and the first-row table)
+    take a batch's 131,072 indices, the compact form's K = a sixteenth of
+    them; the compaction itself is compares, gathers of whole lines of 128 lanes
+    and selects: no sort and no loop (the compiler sorts the scatter
+    form's indices itself where the table outgrows fast memory, and the dense
+    form scans its chunks: neither is the compact form's)."""
+    from daft_tpu.ops.grouped_stage import COMPACT_SHARE
+
+    k = JOIN_BATCH // COMPACT_SHARE
+    assert _scatter_index_counts(text, _SCATTER_FORM) == [JOIN_BATCH] * (n_planes + 1)
+    assert _scatter_index_counts(text, _COMPACT_FORM) == [k] * (n_planes + 1)
+    mine = [line for line in text.splitlines() if _COMPACT_FORM in line]
+    assert not [line for line in mine if re.search(r" (sort|while)\(", line)]
+    gathers = [re.search(r"= s32\[([\d,]+)\]", line).group(1)
+               for line in mine if " gather(" in line]
+    # (a row's place, its id and each value plane: the k lines of each)
+    assert gathers == [f"{k},128"] * (n_planes + 2), gathers
+
+
+@pytest.mark.parametrize("cap", [1 << 21, ORDERS_CAP], ids=["customer_ids", "order_ids"])
+def test_run_wide_topn_accumulate_lowers_at_sf10(one_chip, cap):
+    """One dispatch of the run-wide program: a batch of 131,072 rows into
+    tables of 2^21 customer ids (q10's) and 2^24 order ids (q3's), the three
+    forms in one program, the tables donated (no second copy of them among
+    the temporaries)."""
     stage, _topn = _q3_join_stage()
-    tables = _run_wide_tables(stage, one_chip, ORDERS_CAP)
+    tables = _run_wide_tables(stage, one_chip, cap)
     ints = {"l_shipdate"}
     cols = {name: (_s(one_chip, (JOIN_BATCH,), jnp.bool_ if name == "__join_ok__"
                       else jnp.int32 if name in ints else jnp.float32),
                    _s(one_chip, (JOIN_BATCH,), jnp.bool_)) for name in stage._input_cols}
-    compiled = stage._build_run_wide(ORDERS_CAP).lower(
+    compiled = stage._build_run_wide(cap).lower(
         tables, cols, _s(one_chip, (JOIN_BATCH,), jnp.int32),
         _s(one_chip, (JOIN_BATCH,), jnp.bool_), _literal_args(stage, one_chip)).compile()
     mem = compiled.memory_analysis()
     table_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                       for x in jax.tree_util.tree_leaves(tables))
-    assert table_bytes == (len(stage._mm_specs) * 2 * 4 + 4) * (ORDERS_CAP + 4096) + 4
+    # (the two counts of dispatches, dense and compacted, are the + 8)
+    assert table_bytes == (len(stage._mm_specs) * 2 * 4 + 4) * (cap + 4096) + 8
     assert mem.argument_size_in_bytes >= table_bytes
-    # the scatter form's float32 table of one plane at a time, never a copy of the run's tables
+    # the scatter forms' float32 table of one plane at a time, never a copy of the run's tables
     assert mem.temp_size_in_bytes < table_bytes
-    assert mem.alias_size_in_bytes >= table_bytes - 8
+    assert mem.alias_size_in_bytes >= table_bytes - 16
+    _assert_compacts_without_sort_or_scan(compiled.as_text(), len(stage._mm_specs))
 
 
 def test_run_wide_topn_select_lowers_at_sf10(one_chip):
@@ -412,6 +456,13 @@ def _sharded_run_wide_tables(stage, rows, cap):
             "dense": _s(rows, (MESH_CHIPS,), jnp.int32)}
 
 
+def _sharded_accumulate_tables(stage, rows, cap):
+    """What the accumulate program takes and returns: the select's four
+    leaves and the compacted dispatches' count beside them."""
+    return dict(_sharded_run_wide_tables(stage, rows, cap),
+                compact=_s(rows, (MESH_CHIPS,), jnp.int32))
+
+
 def test_sharded_run_wide_accumulate_lowers_at_sf30(topo):
     """One dispatch of q3's run-wide program over the mesh: 131,072 rows a
     chip into a chip's own tables of 2^26 order ids, donated, and no
@@ -419,7 +470,7 @@ def test_sharded_run_wide_accumulate_lowers_at_sf30(topo):
     stage, _topn = _q3_join_stage()
     mesh, rows, whole = _mesh_shardings(topo)
     total = MESH_CHIPS * JOIN_BATCH
-    tables = _sharded_run_wide_tables(stage, rows, ORDERS_CAP_SF30)
+    tables = _sharded_accumulate_tables(stage, rows, ORDERS_CAP_SF30)
     ints = {"l_shipdate"}
     cols = {name: (_s(rows, (total,), jnp.bool_ if name == "__join_ok__"
                       else jnp.int32 if name in ints else jnp.float32),
@@ -434,6 +485,8 @@ def test_sharded_run_wide_accumulate_lowers_at_sf30(topo):
     assert mem.temp_size_in_bytes < a_chips
     text = compiled.as_text()
     assert not [c for c in COLLECTIVES if c in text]
+    # a shard's program is the one chip's: its own 131,072 rows, its own K
+    _assert_compacts_without_sort_or_scan(text, len(stage._mm_specs))
 
 
 def test_sharded_run_wide_combine_and_select_lowers_at_sf30(topo):

@@ -750,6 +750,194 @@ def test_fused_topn_scatter_and_dense_forms_agree():
     _assert_close(host, shuffled_answer)
 
 
+# ---- a dispatch that keeps few rows compacts them before its scatters -------------------
+#
+# The run-wide program's third form (GroupedAggStage._build_run_wide): not
+# locally dense, and at most a bucket's 1 / COMPACT_SHARE rows kept (128 of a
+# 2,048-row morsel here). The fact below is q3's with its rows in no order,
+# full-mantissa prices, and a ship date that only chosen rows pass: per
+# batch exactly the asked-for number of rows reach a group.
+
+_K = _MORSEL // 16
+
+
+def _compaction_fact(kept_per_batch, one_line=False, seed=23):
+    """(tables, per-batch kept ids): `_topn_tables` with a lineitem of
+    len(kept_per_batch) batches of a morsel's rows whose order keys are
+    shuffled and of which exactly kept_per_batch[b] rows of batch b join
+    through and pass q3's filters (the lowest and the highest joining id
+    among them, so that no such batch is locally dense); `one_line` puts a
+    batch's kept rows side by side, from the start of a 128-row line."""
+    import datetime
+
+    rng = np.random.default_rng(seed)
+    n_b = len(kept_per_batch)
+    n_l = _MORSEL * n_b
+    t = _topn_tables(n_l=_MORSEL * 7 - 100)      # orders over more ids than a chunk is wide
+    o, c = t["orders"].to_pydict(), t["customer"].to_pydict()
+    building = {k for k, s in zip(c["c_custkey"], c["c_mktsegment"]) if s == "BUILDING"}
+    joins = sorted(k for k, cust, d in zip(o["o_orderkey"], o["o_custkey"], o["o_orderdate"])
+                   if cust in building and d < _days(1995, 3, 15))
+    assert joins[-1] - joins[0] > _MORSEL, "ids wider than a chunk's window"
+    n_o = max(o["o_orderkey"]) + 1
+    keys = rng.integers(0, n_o, n_l)
+    late = np.zeros(n_l, dtype=bool)
+    kept_ids = []
+    for b, n in enumerate(kept_per_batch):
+        lo = b * _MORSEL
+        if one_line:
+            rows = lo + 384 + np.arange(n)
+        else:
+            rows = lo + np.sort(rng.choice(_MORSEL, n, replace=False))
+        # ids that repeat (some three times and more), the two ends among them
+        ids = rng.choice(joins[1:-1], n) if n else np.empty(0, dtype=np.int64)
+        ids[:2] = (joins[0], joins[-1])[:n]
+        keys[rows], late[rows] = ids, True
+        kept_ids.append(ids)
+    day0 = datetime.date(1994, 1, 1)
+    li = {"l_orderkey": keys.tolist(),
+          "l_extendedprice": rng.uniform(1, 1e5, n_l).astype(np.float32).astype(float).tolist(),
+          "l_discount": (rng.integers(0, 11, n_l) / 100.0).tolist(),
+          "l_returnflag": ["R"] * n_l,
+          "l_shipdate": [day0 + datetime.timedelta(days=400 if x else 0) for x in late]}
+    return dict(t, lineitem=daft_tpu.from_pydict(li).collect()), kept_ids
+
+
+def _spy_run_wide_tables(monkeypatch, seen):
+    """Every run-wide finalize leaves (batches, its tables on the host) in `seen`."""
+    import jax
+
+    import daft_tpu.ops.device_join as dj
+
+    real = dj.DeviceJoinTopNRun._finalize_run_wide
+
+    def spy(self):
+        seen.append((self._batches, jax.device_get(self._tables)))
+        return real(self)
+
+    monkeypatch.setattr(dj.DeviceJoinTopNRun, "_finalize_run_wide", spy)
+
+
+def _forms_of(kept_ids):
+    """(dense, compact, scatter) dispatches, as the program decides them (a
+    morsel is one chunk: dense where its kept ids lie within one of each other)."""
+    dense = sum(1 for ids in kept_ids if not len(ids) or ids.max() - ids.min() < _MORSEL)
+    compact = sum(1 for ids in kept_ids
+                  if len(ids) and ids.max() - ids.min() >= _MORSEL and len(ids) <= _K)
+    return dense, compact, len(kept_ids) - dense - compact
+
+
+def _assert_tables_agree(got, want, kept_ids):
+    """The compact form's tables against the scatter form's: first-row
+    positions exactly, sums bit for bit where an id has at most two kept rows
+    in every batch, within a float32 ulp a batch of the sum otherwise."""
+    np.testing.assert_array_equal(got["first"], want["first"])
+    most = {}
+    for ids in kept_ids:
+        for i, n in zip(*np.unique(ids, return_counts=True)):
+            most[int(i)] = max(most.get(int(i), 0), int(n))
+    many = np.array([i for i, n in most.items() if n > 2], dtype=np.int64)
+    for plane in ("hi", "lo"):
+        for g, w in zip(got[plane], want[plane]):
+            g, w = np.asarray(g), np.asarray(w)
+            few = np.ones(len(g), dtype=bool)
+            few[many] = False
+            np.testing.assert_array_equal(g[few].view(np.int32), w[few].view(np.int32))
+    for gh, gl, wh, wl in zip(got["hi"], got["lo"], want["hi"], want["lo"]):
+        total = lambda h, l: np.asarray(h, np.float64)[many] + np.asarray(l, np.float64)[many]
+        room = len(kept_ids) * np.spacing(np.abs(np.asarray(wh)[many]).astype(np.float32))
+        assert (np.abs(total(gh, gl) - total(wh, wl)) <= room).all()
+
+
+_COMPACT_CASES = {
+    "under_k": ([40, 90, _K - 1], False),
+    "exactly_k": ([_K, _K, _K], False),
+    "k_plus_one": ([_K + 1, _K + 1, _K + 1], False),
+    "none_kept": ([0, 0, 0], False),
+    "one_line": ([_K, 100, _K], True),
+    "mixed": ([40, _K + 1, 0, _K, 600], False),
+}
+
+
+@pytest.mark.parametrize("case", list(_COMPACT_CASES))
+def test_fused_topn_compact_and_scatter_forms_agree(case, monkeypatch):
+    """A dispatch whose kept rows fit K scatters K compacted indices, one
+    with K + 1 the whole bucket, and a batch with nothing kept is locally
+    dense as before: the same tables as the scatter form alone leaves, the
+    host engine's answer, and counters that say which form ran."""
+    import daft_tpu.ops.grouped_stage as gs
+    from daft_tpu.observability.runtime_stats import SpanRecorder, set_spans
+
+    kept, one_line = _COMPACT_CASES[case]
+    t, kept_ids = _compaction_fact(kept, one_line)
+    dense, compact, scatter = _forms_of(kept_ids)
+    assert dense + compact + scatter == len(kept)
+    host = _host_answer(lambda: _topn_q3(t))
+    seen = []
+    _spy_run_wide_tables(monkeypatch, seen)
+
+    counters.reset()
+    rec = SpanRecorder()
+    set_spans(rec)
+    try:
+        with _morselized("on"):
+            answer = _topn_q3(t).to_pydict()
+    finally:
+        set_spans(None)
+    assert counters.device_topn_runs == 1, counters.rejections
+    assert counters.device_join_topn_batches == len(kept)
+    assert counters.join_topn_compact_batches == compact
+    (select,) = [s for s in rec.drain() if s["name"] == "join.topn_select"]
+    assert select["args"]["compact_batches"] == compact
+    assert select["args"]["dense_batches"] == dense
+    _assert_close(host, answer)
+
+    # the scatter form alone: no dispatch is few enough
+    monkeypatch.setattr(gs, "COMPACT_SHARE", 1 << 30)
+    gs._STAGE_CACHE.clear()
+    try:
+        counters.reset()
+        with _morselized("on"):
+            plain = _topn_q3(t).to_pydict()
+        assert counters.join_topn_compact_batches == 0
+    finally:
+        gs._STAGE_CACHE.clear()
+    assert plain == answer
+    (batches, got), (_b, want) = seen
+    assert batches == len(kept)
+    assert int(got["dense"]) == int(want["dense"]) == dense
+    assert int(got["compact"]) == compact
+    _assert_tables_agree(got, want, kept_ids)
+
+
+def test_the_select_program_is_handed_the_leaves_it_was_compiled_for(monkeypatch):
+    """The accumulate program's tables carry the compacted dispatches' count
+    beside the select's four leaves; the select program (25-28 s to compile
+    at SF10, served by the persistent cache while its text stands) is handed
+    those four and nothing else."""
+    import daft_tpu.ops.device_join as dj
+
+    handed = []
+    real = dj.DeviceJoinTopNRun._select_program
+
+    def spy(self, k):
+        prog = real(self, k)
+
+        def call(tables, ranks):
+            handed.append(sorted(tables))
+            return prog(tables, ranks)
+        return call
+
+    monkeypatch.setattr(dj.DeviceJoinTopNRun, "_select_program", spy)
+    seen = []
+    _spy_run_wide_tables(monkeypatch, seen)
+    t = _topn_tables(n_l=_MORSEL * 3 - 100)
+    with _morselized("on"):
+        _topn_q10(t).to_pydict()
+    assert handed == [["dense", "first", "hi", "lo"]]
+    assert sorted(seen[0][1]) == ["compact", "dense", "first", "hi", "lo"]
+
+
 def test_topn_group_by_outside_a_dimension_keeps_the_one_batch_form():
     """A group-by that holds a fact column has no run-wide id space: one
     batch rides the fused program as before, a second one sends the query to

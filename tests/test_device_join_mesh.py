@@ -146,6 +146,56 @@ def test_the_four_shards_tables_add_up_to_the_one_chips(topn_tables, monkeypatch
                                   of_ids(t1["first"], 1)[0])
 
 
+# ---- (b2) every shard chooses its own form ---------------------------------------------------
+
+@pytest.mark.parametrize("case,kept", [
+    # (a dispatch is four morsels, a morsel a shard: kept rows by dispatch and shard)
+    ("one_shard_over_k", [40, tj._K + 1, tj._K, 90] * 2),
+    ("all_under_k", [40, tj._K, 1, 90] * 2),
+    ("one_shard_empty", [0, 700, tj._K - 1, 5, tj._K, 0, 0, 3]),
+])
+def test_each_shard_compacts_or_scatters_by_its_own_rows(case, kept, monkeypatch):
+    """Over the mesh a shard whose kept rows pass K takes the scatter form
+    while the others compact theirs in the same dispatch: a chip's tables are
+    what the scatter form alone leaves on it, the answer is the one chip's,
+    and the counters read a count a chip."""
+    import daft_tpu.ops.grouped_stage as gs
+
+    t, kept_ids = tj._compaction_fact(kept)
+    dense, compact, _scatter = tj._forms_of(kept_ids)
+    host = tj._host_answer(lambda: tj._topn_q3(t))
+    seen = []
+    tj._spy_run_wide_tables(monkeypatch, seen)
+    four, c4 = _run(lambda: tj._topn_q3(t), MESH)
+    assert c4["device_topn_runs"] == 1 and c4["device_join_mesh_batches"] == len(kept) // MESH, \
+        counters.rejections
+    assert c4.get("join_topn_compact_batches", 0) == compact // MESH
+    tj._assert_close(host, four)
+    one, c1 = _run(lambda: tj._topn_q3(t), 1)
+    assert one == four and c1.get("join_topn_compact_batches", 0) == compact
+
+    monkeypatch.setattr(gs, "COMPACT_SHARE", 1 << 30)
+    gs._STAGE_CACHE.clear()
+    try:
+        plain, c = _run(lambda: tj._topn_q3(t), MESH)
+        assert c.get("join_topn_compact_batches", 0) == 0
+    finally:
+        gs._STAGE_CACHE.clear()
+    assert plain == four
+    (_b, got), (_b1, _one), (_b4, want) = seen
+    assert int(np.sum(got["compact"])) == compact and int(np.sum(got["dense"])) == dense
+    assert np.array_equal(got["dense"], want["dense"]) and not np.any(want["compact"])
+    # shard s of every dispatch is the same chip: its forms are its batches'
+    for s in range(MESH):
+        mine = kept_ids[s::MESH]
+        assert (int(got["dense"][s]), int(got["compact"][s])) == tj._forms_of(mine)[:2]
+        chip = lambda tables: {
+            k: [np.split(np.asarray(x), MESH)[s] for x in v] if isinstance(v, tuple)
+            else np.split(np.asarray(v), MESH)[s] for k, v in tables.items()
+            if k in ("hi", "lo", "first")}
+        tj._assert_tables_agree(chip(got), chip(want), mine)
+
+
 # ---- (c) groups that straddle shards, keys that tie across chips -----------------------------
 
 def test_groups_straddle_shards_and_ties_fall_as_on_one_chip(topn_tables):
