@@ -187,20 +187,53 @@ class LatencyConstrainedBatching:
                 counters.bump("morsel_resize")
 
 
-def coalesce_target_rows(cfg, shards: int = 1) -> int:
+def coalesce_target_rows(cfg, shards: int = 1, resident_rows: int = 0) -> int:
     """Flush threshold of the device dispatch coalescer: batch_fill_target of
     the power-of-two bucket at the configured morsel size; 0 = coalescing
     disabled. THE one definition — the executor's coalescer construction and
     the cost model's expected-horizon both read it, so the priced coalescing
-    behavior can never drift from the behavior that actually runs. A dispatch
-    whose rows are sharded over `shards` devices holds a bucket a shard: the
-    threshold is reached when the last of them is batch_fill_target full."""
+    behavior never promises more than the behavior that actually runs. A
+    dispatch whose rows are sharded over `shards` devices holds a bucket a
+    shard: the threshold is reached when the last of them is
+    batch_fill_target full.
+
+    `resident_rows` (the rows of a resident table a JOIN reads as its fact):
+    the length of a join dispatch over contiguous morsels of that table
+    (stage.DispatchCoalescer's resident target), which glue at no copy:
+    resident_dispatch_segments buckets a shard, since the host's path a join
+    dispatch (look-ups, two launches) costs the same whatever the rows behind
+    it, and the join's programs walk a long dispatch a bucket at a time. The
+    horizon the tiers are priced with stays the plain threshold's
+    (executor._run_device_join says why): the coalescer then outdoes what
+    was priced, never the reverse."""
     if cfg.batch_fill_target <= 0:
         return 0
     from ..ops.stage import pad_bucket
 
     bucket = pad_bucket(cfg.morsel_size_rows)
-    return (max(shards, 1) - 1) * bucket + int(cfg.batch_fill_target * bucket)
+    buckets = max(shards, 1)
+    if resident_rows > 0:
+        buckets *= resident_dispatch_segments(-(-resident_rows // (buckets * bucket)))
+    return (buckets - 1) * bucket + int(cfg.batch_fill_target * bucket)
+
+
+def resident_dispatch_segments(fact_buckets: int) -> int:
+    """Buckets a device that one join dispatch over a resident fact covers,
+    the fact `fact_buckets` such buckets a device long:
+    grouped_stage.DISPATCH_SEGMENTS, or for a shorter fact the largest power
+    of two under its length. A dispatch is never the whole fact: the host's
+    look-ups for the next dispatch run while the device works the last one,
+    which a run of one dispatch has nothing to overlap with, and a fact of
+    several morsels stays a run of several batches (what the run-wide forms
+    are for, and what the join suites' warm-up checks ask of it). A power of
+    two, so that a dispatch's bucket holds no segment of padding but the
+    tail's."""
+    from ..ops.grouped_stage import DISPATCH_SEGMENTS
+
+    segments = 1
+    while segments * 2 <= DISPATCH_SEGMENTS and segments * 2 < fact_buckets:
+        segments *= 2
+    return segments
 
 
 def make_strategy(cfg) -> BatchingStrategy:

@@ -1104,13 +1104,17 @@ def _try_fused_udf_agg(node, cfg) -> Optional[MicroPartition]:
     return MicroPartition(node.schema, [out.cast_to_schema(node.schema)])
 
 
-def _make_coalescer(feed, cfg, shards: int = 1):
+def _make_coalescer(feed, cfg, shards: int = 1, resident_rows: int = 0):
     """DispatchCoalescer for one device stage run (ops/stage.py), or None when
     coalescing is disabled (batch_fill_target == 0) — morsels then dispatch
     one-to-one, the pre-coalescing behavior. The flush threshold
     (batching.coalesce_target_rows) makes one compiled dispatch cover N small
     morsels with its bucket at least batch_fill_target full. A run that
-    shards a dispatch's rows over `shards` devices fills a bucket a shard."""
+    shards a dispatch's rows over `shards` devices fills a bucket a shard.
+    `resident_rows` (a join run whose programs walk a long dispatch in
+    segments, over a fact that reads that many rows of a resident table):
+    contiguous morsels of the table, which glue at no copy, are held to the
+    longer target of coalesce_target_rows(resident_rows=...)."""
     from .batching import coalesce_target_rows
 
     target = coalesce_target_rows(cfg, shards)
@@ -1118,8 +1122,10 @@ def _make_coalescer(feed, cfg, shards: int = 1):
         return None
     from ..ops.stage import DispatchCoalescer
 
-    return DispatchCoalescer(feed, target_rows=target,
-                             latency_s=cfg.batch_latency_ms / 1e3)
+    return DispatchCoalescer(
+        feed, target_rows=target, latency_s=cfg.batch_latency_ms / 1e3,
+        resident_target_rows=coalesce_target_rows(
+            cfg, shards, resident_rows=resident_rows) if resident_rows else 0)
 
 
 def _exec_device_join_agg(node) -> MicroPartition:
@@ -1284,13 +1290,29 @@ def _run_device_join(node, label: str, make_run, assemble,
         # a fused TopN held to one batch is a one-batch region by
         # construction; its RTT pricing comes from the shared region builder,
         # not a local constant (ops/region.py single_batch_horizon)
-        coal = _coalesce_horizon(
-            [first] if second is None else [first, second]) if stream_wide \
-            else single_batch_horizon()
         dim_batches = {}
         for name, plan in node.dim_plans:
             dim_batches[name] = _concat_parts(list(_exec(plan)), plan.schema)
         ctx = _JoinContext(node.spec, dim_batches)
+        batch0 = next((b for b in first.batches if b.num_rows > 0), None)
+        # a join whose group ids are not made on the host a batch at a time
+        # takes a resident fact DISPATCH_SEGMENTS buckets a dispatch (a
+        # sharded one does by what it is: sharded_join_reason); its coalescer
+        # (below) tells a resident run from the morsels themselves
+        from ..ops.device_join import host_ids_reason
+
+        long_chip = batch0 is not None and not host_ids_reason(
+            ctx, stage, grouped, topn, batch0)
+        seen = [first] if second is None else [first, second]
+        # The tiers are PRICED at the horizon a dispatch had before it grew
+        # (batch_fill_target of a bucket a shard), which the coalescer now
+        # outdoes eight times over a resident fact. Held on purpose: priced
+        # at what is delivered, the chip arm's round trip falls from 2.3 to
+        # 0.29 ms a partition and `auto` sends TPC-H q19 at SF1 to a device
+        # tier no cell had measured (and q12 to within 3.5% of it): faster
+        # there (PERF.md, PR 43), but another placement, another set-up, and
+        # a change of its own.
+        coal = _coalesce_horizon(seen) if stream_wide else single_batch_horizon()
 
         # Mesh CANDIDATE resolution happens BEFORE pricing: the mesh arm is
         # only priced when the mesh stage actually BUILDS for this spec, so
@@ -1323,7 +1345,6 @@ def _run_device_join(node, label: str, make_run, assemble,
         # ops/mesh_stage.py, where its stage builds.
         mesh_stage = None
         sharded = False
-        batch0 = next((b for b in first.batches if b.num_rows > 0), None)
         if mesh_width >= 2 and batch0 is not None:
             from ..ops.device_join import sharded_join_reason
 
@@ -1351,8 +1372,7 @@ def _run_device_join(node, label: str, make_run, assemble,
                     topn=topn, label=label, coalesce=coal,
                     mesh_ndev=mesh_width, sharded=sharded,
                     mesh_coalesce=_coalesce_horizon(
-                        [first] if second is None else [first, second],
-                        shards=mesh_width,
+                        seen, shards=mesh_width,
                         stream_rows=_resident_rows(node.fact))
                     if sharded and stream_wide else coal,
                     mesh_forced=cfg.mesh_devices >= 2 and mesh_width >= 2)
@@ -1421,7 +1441,14 @@ def _run_device_join(node, label: str, make_run, assemble,
                 # morsels of a resident table is a zero-copy range of it
                 # (Series.concat), so series_keyed slots, keyed on the rows
                 # a batch views and not on its objects, hit on a repeat query.
-                coalescer = _make_coalescer(run.feed_batch, cfg, shards)
+                # Such morsels are held to DISPATCH_SEGMENTS buckets a device,
+                # fewer where the fact is short (a dispatch is never all of
+                # it: batching.resident_dispatch_segments); the mesh tier's
+                # fused runs keep a bucket: their programs walk no segments.
+                long_run = mesh_stage is None and (shards > 1 or long_chip)
+                coalescer = _make_coalescer(
+                    run.feed_batch, cfg, shards,
+                    resident_rows=(_resident_rows(node.fact) or 0) if long_run else 0)
                 feed = coalescer.add if coalescer is not None else run.feed_batch
                 for part in fact_stream:
                     fed_rows += part.num_rows

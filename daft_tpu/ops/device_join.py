@@ -530,7 +530,7 @@ def _gather_col(arr, arr_valid, idx):
 _LANES = 128
 
 
-def _gather_rows(mat, idx, windowed: bool = False):
+def _gather_rows(mat, idx, windowed: bool = False, segment: int = 0):
     """One gather of a packed [P, N] dim matrix along its MINOR axis — the
     per-batch join, traced inside the provisioning program. The pack is
     TRANSPOSED ([planes, rows], not [rows, planes]) because TPU tiled layouts
@@ -544,10 +544,20 @@ def _gather_rows(mat, idx, windowed: bool = False):
     here from `idx` itself. On the chip a gather's time follows the length of
     what it gathers FROM: 131,072 indices out of [6, 2^24] take 3.58 ms, out
     of a [6, 2^17] slice of it 0.43 (PERF.md, PR 39). The same rows' same
-    values: a miss reads row 0, as the plain form's clip makes it."""
+    values: a miss reads row 0, as the plain form's clip makes it.
+
+    `segment`: indices longer than that (a dispatch of DISPATCH_SEGMENTS
+    morsels of a resident fact) are gathered a segment at a time, each from
+    the window its own indices point into, and the pieces glued. Unrolled,
+    not a loop: as a loop's invariant operand the chip's compiler lays the
+    WHOLE pack out for the gather inside, a copy of it a dispatch (34 GB for
+    `orders` at SF30: tests/test_chip_compile.py caught it with no chip)."""
     n = mat.shape[1]
     if not windowed:
         return mat[:, jnp.clip(idx, 0, n - 1)]
+    if 0 < segment < idx.shape[0]:
+        return jnp.concatenate([_gather_rows(mat, idx[at:at + segment], True)
+                                for at in range(0, idx.shape[0], segment)], axis=1)
     w = idx.shape[0]
     hit = idx >= 0
     lo = jnp.clip(jnp.min(jnp.where(hit, idx, jnp.int32(n))), 0, n - w)
@@ -585,11 +595,12 @@ class _ProvisionLayout:
     never from a filter literal, so a query with another literal finds the
     program the first one traced."""
     packs: tuple     # per adjacent dim: its pack's ok row, None = existence check only
-    windows: tuple   # per adjacent dim: the batch's indices fit one window of its pack (_gather_rows)
+    windows: tuple   # per adjacent dim: a segment's indices fit one window of its pack (_gather_rows)
     columns: tuple   # per dim column handed on: (name, adjacent dim, digit rows, validity row)
     codes: tuple     # per group-by column: (adjacent dim or -1 = fact-side plane, row or position, radix)
     cap: int         # the combined codes are clipped to [0, cap); 0 where no codes are asked for
     devices: int = 1  # local devices the batch's rows are sharded over: each runs the program on its shard
+    segment: int = 0  # rows of a device's share that read one window (a longer share is gathered in segments); 0: all
 
 
 class _CodePlan(NamedTuple):
@@ -613,7 +624,11 @@ def _provision_program(layout: _ProvisionLayout):
     arguments (index planes, code planes) are row-sharded over that many
     local devices, the packs whole on each, and every device runs the one
     chip's program on its shard: a shard's window is found from its own
-    indices."""
+    indices.
+
+    A device's share longer than `layout.segment` rows (a join dispatch over a
+    resident fact: DISPATCH_SEGMENTS morsels glued) is gathered a segment at
+    a time, each from its own window (_gather_rows)."""
 
     def run(mats, idxs, fact_codes):
         counters.bump("join_provision_traces")   # runs when traced, not when called
@@ -624,7 +639,7 @@ def _provision_program(layout: _ProvisionLayout):
             aok = didx >= 0
             rows = None
             if ok_row is not None:
-                rows = _gather_rows(mat, didx, windowed)      # [P, bucket]
+                rows = _gather_rows(mat, didx, windowed, layout.segment)   # [P, bucket]
                 aok = aok & (rows[ok_row] > 0.5)
             gathered.append(rows)
             ok = aok if ok is None else (ok & aok)
@@ -696,6 +711,12 @@ class _JoinContext:
         # by the run that drives this context); None: one chip
         self.mesh_devices = 1
         self.mesh = None
+        # rows of a segment: a device's share of a dispatch that is longer (a
+        # run of morsels of a resident fact, glued by the coalescer) is
+        # gathered and added up a segment at a time, each a morsel's bucket
+        from ..config import execution_config
+
+        self.segment_rows = pad_bucket(execution_config().morsel_size_rows)
         self.syn_series: Dict[str, Dict[str, object]] = {}
         self._dev_filters: Dict[str, List[Expression]] = {}
         self._host_filters: Dict[str, List[Expression]] = {}
@@ -737,10 +758,30 @@ class _JoinContext:
         return pad_bucket(n) if self.mesh is None \
             else mesh_total(n, self.mesh_devices)
 
+    def window_rows(self, bucket: int) -> int:
+        """Rows of the stretch of a `bucket`-row dispatch whose indices read
+        one window of a dimension's pack: a segment, or a device's share of
+        the dispatch where that is no longer than one."""
+        return min(bucket // self.mesh_devices, self.segment_rows)
+
     def _mesh_key(self) -> tuple:
         """What a slot's key carries where its arrays are laid out over the
         mesh (as stage._codes_slot keys a code plane)."""
         return () if self.mesh is None else ("mesh", self.mesh_devices, MESH_AXIS)
+
+    def _idx_slot_key(self, family: str, d: DimSpec, bucket: int,
+                      mesh_key: Optional[tuple] = None) -> tuple:
+        """The slot of dim `d`'s padded device index plane of a fact batch,
+        laid out as `mesh_key` says (_mesh_key: this context's, unless given).
+        The plane's span is kept beside it, the widest of its segments', so
+        the segment's length is part of the key where a device's share of the
+        plane holds more than one."""
+        if mesh_key is None:
+            mesh_key = self._mesh_key()
+        key = (family, d.key_col, d.parent, bucket) + mesh_key
+        if bucket // (mesh_key[1] if mesh_key else 1) > self.segment_rows:
+            key += ("segment", self.segment_rows)
+        return key
 
     @staticmethod
     def _filter_anchor(batch, expr: Expression):
@@ -1059,8 +1100,10 @@ class _JoinContext:
         probed in-kernel instead, and the host, which then holds no index,
         gives no span (None); a kernel that does not lower raises.
         Over a mesh (set_mesh) the plane is row-sharded under a slot of its
-        own and the span is the widest of the shards': each shard gathers
-        from the window its own rows point into."""
+        own. The span is the widest of the plane's stretches of window_rows
+        (a device's share of the dispatch, cut into segments where it is
+        longer than one): each is gathered from the window its own rows
+        point into."""
         with profile_span("join.index", "host", dim=dname, bucket=bucket):
             return self._dev_idx(batch, dname, bucket, perm)
 
@@ -1075,20 +1118,19 @@ class _JoinContext:
                 return self._pallas_dev_idx(batch, d, bucket, interp), None
             idx_np = self._indices_for(batch)[dname]
 
-            mesh, ndev = self.mesh, self.mesh_devices
+            mesh, per = self.mesh, self.window_rows(bucket)
 
             def build():
                 padded = np.full(bucket, -1, dtype=np.int32)
                 padded[:n] = idx_np
+                span = max(_index_span(padded[at:at + per])
+                           for at in range(0, bucket, per))
                 if mesh is None:
-                    return jnp.asarray(padded), _index_span(idx_np)
-                per = bucket // ndev
-                return shard_rows(mesh, padded, bucket), max(
-                    _index_span(padded[s * per:(s + 1) * per]) for s in range(ndev))
+                    return jnp.asarray(padded), span
+                return shard_rows(mesh, padded, bucket), span
 
-            return series_keyed(
-                anchor, ("didx", d.key_col, d.parent, bucket) + self._mesh_key(),
-                (idx_np,), build, rebuild_rows=n)
+            return series_keyed(anchor, self._idx_slot_key("didx", d, bucket),
+                                (idx_np,), build, rebuild_rows=n)
 
         idx_np = self._indices_for(batch)[dname]
         pperm_np, _pdev = perm
@@ -1114,7 +1156,7 @@ class _JoinContext:
         for d in self.dims:
             anchor = self._probe_anchor(batch, d)
             if not any(manager().is_resident(
-                    anchor, (fam, d.key_col, d.parent, bucket) + mesh_key)
+                    anchor, self._idx_slot_key(fam, d, bucket, mesh_key))
                     for fam in ("didx", "didxp", "pdidx")):
                 total += bucket * 4
         return total
@@ -1370,7 +1412,8 @@ class _JoinContext:
         adj_of: Dict[str, int] = {}
         mats, idxs, ok_rows, windows, layouts = [], [], [], [], []
         ndev = self.mesh_devices
-        shard = bucket // ndev      # rows a device works: the length of its window
+        # rows of a device's share that read one window: a segment's
+        window = self.window_rows(bucket)
         for a, adj in enumerate(self._adjacent()):
             adj_of[adj.name] = a
             didx, span = self.dev_idx(batch, adj.name, bucket, perm=perm)
@@ -1379,10 +1422,10 @@ class _JoinContext:
                 self.packed_plane(adj, needed, gb_cols) or (None, {}, {}, None, {})
             mats.append(mat)
             ok_rows.append(ok_row)
-            # a window as long as the batch serves it where the matched rows
-            # lie that close and the pack is longer than one window
+            # a window as long as the segment serves it where the matched
+            # rows lie that close and the pack is longer than one window
             windows.append(mat is not None and span is not None
-                           and span < shard < mat.shape[1])
+                           and span < window < mat.shape[1])
             layouts.append((layout, code_layout, wide))
 
         dcols: Dict[str, dev.DCol] = {}
@@ -1434,7 +1477,7 @@ class _JoinContext:
 
         prog = _provision_program(_ProvisionLayout(
             tuple(ok_rows), tuple(windows), tuple(columns), tuple(code_cols), cap,
-            ndev))
+            ndev, window if window < bucket // ndev else 0))
         gathered, combined = prog(tuple(mats), tuple(idxs), tuple(fact_codes))
         counters.bump("join_provision_calls")
         if any(windows):
@@ -2004,22 +2047,18 @@ def topn_run_wide(ctx: _JoinContext, stage: GroupedAggStage,
     return groups, cap, ""
 
 
-def sharded_join_reason(ctx: _JoinContext, stage, grouped: bool, topn: bool,
-                        batch, mesh_devices: int) -> str:
-    """Why a join over `ctx` cannot run as the one chip's dispatch on every
-    shard of a mesh of `mesh_devices` ("" where it can: the runs of this file
-    then take `mesh_devices`). What is declined needs ids made on the host a
-    batch at a time, which the sharded dispatch never makes: a grouped
-    aggregate whose group-by does not dictionary-encode under the matmul
-    ceiling (host-factorized codes, the locally dense layout), and a fused
-    TopN whose ids hold for one batch only. `batch` is a fact batch (the
-    dictionaries of fact-side group columns are read from it). And a join
-    under a forced Pallas hash probe (pallas_mode "on"): that kernel's mesh
-    form is ops/mesh_stage.py's."""
-    from ..config import execution_config
-
-    if getattr(execution_config(), "pallas_mode", "auto") == "on":
-        return "a forced Pallas hash probe runs on one chip or in the fused mesh tier"
+def host_ids_reason(ctx: _JoinContext, stage, grouped: bool, topn: bool,
+                    batch, mesh_devices: int = 1) -> str:
+    """Why a join over `ctx` makes its group ids on the host a batch at a
+    time ("" where it does not): a grouped aggregate whose group-by does not
+    dictionary-encode under the matmul ceiling (host-factorized codes, the
+    locally dense layout: a permutation and a table as long as the batch's
+    own groups), and a fused TopN whose ids hold for one batch only. Such a
+    run keeps today's dispatch: it is not sharded over a mesh
+    (sharded_join_reason), and over a resident fact its coalescer flushes a
+    bucket at a time, not DISPATCH_SEGMENTS (executor._run_device_join).
+    `batch` is a fact batch (the dictionaries of fact-side group columns are
+    read from it)."""
     if not grouped:
         return ""
     if topn:
@@ -2029,6 +2068,21 @@ def sharded_join_reason(ctx: _JoinContext, stage, grouped: bool, topn: bool,
                                              MAX_MATMUL_SEGMENTS):
         return "the group codes need a host factorization of every batch"
     return ""
+
+
+def sharded_join_reason(ctx: _JoinContext, stage, grouped: bool, topn: bool,
+                        batch, mesh_devices: int) -> str:
+    """Why a join over `ctx` cannot run as the one chip's dispatch on every
+    shard of a mesh of `mesh_devices` ("" where it can: the runs of this file
+    then take `mesh_devices`). What is declined needs ids made on the host a
+    batch at a time, which the sharded dispatch never makes
+    (host_ids_reason). And a join under a forced Pallas hash probe
+    (pallas_mode "on"): that kernel's mesh form is ops/mesh_stage.py's."""
+    from ..config import execution_config
+
+    if getattr(execution_config(), "pallas_mode", "auto") == "on":
+        return "a forced Pallas hash probe runs on one chip or in the fused mesh tier"
+    return host_ids_reason(ctx, stage, grouped, topn, batch, mesh_devices)
 
 
 class DeviceJoinTopNRun(DeviceJoinGroupedRun):
@@ -2089,7 +2143,10 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
     def _feed_run_wide(self, batch) -> None:
         """One fact batch into the run's tables: the dimension's index plane
         of the batch IS the ids (a cache hit on a repeat query), so the
-        host's part is the look-ups and two launches."""
+        host's part is the look-ups and two launches, whatever the batch's
+        length: over a resident fact it is DISPATCH_SEGMENTS morsels glued
+        (a device's share of it, over a mesh), which the two programs walk a
+        segment at a time (_provision_program, GroupedAggStage._build_run_wide)."""
         stage, ctx = self.stage, self.ctx
         n = batch.num_rows
         if n == 0:
@@ -2103,7 +2160,7 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
                               cap=self._cap):
                 gid, _span = ctx.dev_idx(batch, self.groups.dim.name, bucket)
             dcols, _ = ctx.provision(batch, bucket, needed)
-            prog = stage._jit_run_wide(self._cap, ndev)
+            prog = stage._jit_run_wide(self._cap, ndev, ctx.segment_rows)
             mask = device_row_mask(n, bucket, ctx.mesh)
             lit_args = self.literals.args((self._row_offset,))
             if self._tables is None:

@@ -1079,14 +1079,18 @@ class GroupedAggStage:
                     local_mesh(mesh_devices), PartitionSpec(MESH_AXIS)))
         return self._jitted[key]()
 
-    def _jit_run_wide(self, cap: int, mesh_devices: int = 1) -> Callable:
+    def _jit_run_wide(self, cap: int, mesh_devices: int = 1,
+                      segment: int = 0) -> Callable:
         key = ("run_wide", cap) if mesh_devices <= 1 \
             else ("run_wide", cap, "mesh", mesh_devices)
+        if segment:
+            key += ("segment", segment)
         if key not in self._jitted:
-            self._jitted[key] = self._build_run_wide(cap, local_mesh(mesh_devices))
+            self._jitted[key] = self._build_run_wide(
+                cap, local_mesh(mesh_devices), segment)
         return self._jitted[key]
 
-    def _build_run_wide(self, cap: int, mesh=None) -> Callable:
+    def _build_run_wide(self, cap: int, mesh=None, segment: int = 0) -> Callable:
         """One batch into the tables of a whole run: the program of a run
         whose group ids mean the same in every batch (device_join's fused
         TopN: a fact row's id is a dimension's row). The tables are the
@@ -1117,7 +1121,18 @@ class GroupedAggStage:
         them in stream order (_compact_kept) and scatters those; one that
         keeps more scatters the whole bucket. Either way a batch's partial is
         float32 and the run's sum wider, as the merge on the host was; the
-        tables count the dense and the compacted dispatches.
+        tables count the dense and the compacted segments.
+
+        A dispatch is one or more SEGMENTS of `segment` rows (0: the whole
+        bucket is one): a join over a resident fact sends DISPATCH_SEGMENTS
+        morsels' rows at once, so that the host's path a dispatch is paid
+        once for all of them, and the program walks them a segment at a time
+        (a loop that carries the donated tables and stops after the last
+        segment that holds a row, so a tail's padding costs nothing). The
+        segment is the unit of everything above: its form is chosen from its
+        own rows, its partial is its own, and the compaction, whose compares
+        grow with the square of what it compacts, and every temporary stay a
+        morsel's size whatever the dispatch's.
 
         Over `mesh` every device runs this program on its shard of the
         batch's rows and adds into tables of its own (run_wide_tables): ids
@@ -1132,11 +1147,10 @@ class GroupedAggStage:
         slots = self.slots
         n_mm = len(self._mm_specs)
 
-        def stage(tables, cols: Dict[str, dev.DCol], gid: jnp.ndarray,
-                  row_mask: jnp.ndarray, lit_args, rows_before=0):
-            note_program_trace()
-            lits = slots.unpack(lit_args)
-            offset = slots.run_value(lit_args, 0).astype(jnp.int32) + rows_before
+        def one_segment(acc, cols: Dict[str, dev.DCol], gid: jnp.ndarray,
+                        row_mask: jnp.ndarray, offset, lits):
+            """`acc` (hi, lo, first, dense, compact) with one segment's rows
+            added, the segment's first row at `offset` of the run's stream."""
             bucket = gid.shape[0]
             chunk = min(CHUNK_LOCAL, bucket, cap)
             n_chunks = bucket // chunk
@@ -1210,16 +1224,43 @@ class GroupedAggStage:
                 return new_hi, new_lo, acc_first.at[at].min(offset + src, mode="drop")
 
             def sparse_forms(acc):
-                # (nothing of the compaction is computed for a dense dispatch)
+                # (nothing of the compaction is computed for a dense segment)
                 few = jnp.sum(kept, dtype=jnp.int32) <= n_compact
                 return jax.lax.cond(few, compact_form, scatter_form, acc) + (few,)
 
             acc_hi, acc_lo, acc_first, compacted = jax.lax.cond(
                 dense, lambda acc: dense_form(acc) + (jnp.bool_(False),), sparse_forms,
-                (tables["hi"], tables["lo"], tables["first"]))
-            return {"hi": acc_hi, "lo": acc_lo, "first": acc_first,
-                    "dense": tables["dense"] + dense.astype(jnp.int32),
-                    "compact": tables["compact"] + compacted.astype(jnp.int32)}
+                acc[:3])
+            return (acc_hi, acc_lo, acc_first, acc[3] + dense.astype(jnp.int32),
+                    acc[4] + compacted.astype(jnp.int32))
+
+        def stage(tables, cols: Dict[str, dev.DCol], gid: jnp.ndarray,
+                  row_mask: jnp.ndarray, lit_args, rows_before=0):
+            note_program_trace()
+            lits = slots.unpack(lit_args)
+            offset = slots.run_value(lit_args, 0).astype(jnp.int32) + rows_before
+            bucket = gid.shape[0]
+            rows = min(segment or bucket, bucket)
+            acc = (tables["hi"], tables["lo"], tables["first"],
+                   tables["dense"], tables["compact"])
+            if rows == bucket:
+                acc = one_segment(acc, cols, gid, row_mask, offset, lits)
+            else:
+                # the mask's set rows come first: the segments past them are padding
+                live = (jnp.sum(row_mask, dtype=jnp.int32) + (rows - 1)) // rows
+
+                def walk(i, acc):
+                    # a segment is a stretch of each plane as it lies (as rows
+                    # of a [segments, rows] view the chip would tile eight
+                    # segments into one another: a copy of every plane, and
+                    # strided reads of it)
+                    c, g, m = jax.tree_util.tree_map(
+                        lambda x: jax.lax.dynamic_slice_in_dim(x, i * rows, rows),
+                        (cols, gid, row_mask))
+                    return one_segment(acc, c, g, m, offset + i * rows, lits)
+
+                acc = jax.lax.fori_loop(0, live, walk, acc)
+            return dict(zip(("hi", "lo", "first", "dense", "compact"), acc))
 
         if mesh is None:
             return jax.jit(stage, donate_argnums=0)
@@ -1255,6 +1296,16 @@ def _two_sum_add(hi, lo, x):
 # (whole forms on a v5e, 131,072 rows into 2^21 ids: K = 4,096 0.26 ms, 8,192
 # 0.40, 16,384 0.81, 32,768 1.48, the scatter form 3.63; PERF.md, PR 42)
 COMPACT_SHARE = 16
+
+# Morsel-long buckets a device that ONE join dispatch over a resident fact
+# covers (batching.coalesce_target_rows(resident_rows=...), which takes fewer
+# of a fact shorter than two such dispatches: a dispatch is never the whole
+# fact; the programs walk them as segments). The host's path a join dispatch is look-ups and two
+# launches, 1.8-3.4 ms on a v5e's host whatever the rows behind it, against
+# 0.74-1.54 ms of device time for a bucket of 131,072 rows: at one bucket a
+# dispatch every template of the SF10 join cell waited on the host (PERF.md,
+# PR 43, with the micro-benchmark that chose the value).
+DISPATCH_SEGMENTS = 8
 
 
 def _compact_kept(seg: jnp.ndarray, planes, cap: int, k: int):

@@ -542,6 +542,35 @@ class FilterAggRun:
         return out
 
 
+def resident_rows_after(batch, prev=None) -> Optional[int]:
+    """Rows of its table that lie after `batch`, where every column of it is
+    a zero-copy view (Series.lineage) of the same rows of one longer column
+    each, and, with `prev`, those rows start where `prev`'s end in the same
+    columns: a morsel of a resident table, the next one of its run. None for
+    anything else (a column that is its own root, columns that view other
+    rows than their neighbours, a gap, another table)."""
+    cols = batch.columns
+    if not cols:
+        return None
+    before = None if prev is None else prev.columns
+    if before is not None and len(before) != len(cols):
+        return None
+    start = after = None
+    for i, s in enumerate(cols):
+        root, off = s.lineage()
+        if root is s:
+            return None
+        if start is None:
+            start, after = off, len(root) - off - len(s)
+        elif off != start or len(root) - off - len(s) != after:
+            return None
+        if before is not None:
+            proot, poff = before[i].lineage()
+            if proot is not root or poff + len(before[i]) != off:
+                return None
+    return after
+
+
 class DispatchCoalescer:
     """Morsel→super-batch accumulator for one device stage run.
 
@@ -570,6 +599,18 @@ class DispatchCoalescer:
     zero-copy range of it, so device caches keyed on the rows a batch views
     (device_join series_keyed slots, resident-table repeat queries) still hit.
 
+    ``resident_target_rows`` (a join run's, executor._make_coalescer): the
+    length of a dispatch over a RESIDENT input. What is pending is told from
+    what it is, not from a setting: while every pending morsel is a zero-copy
+    view of one table's rows, each starting where the one before ended
+    (resident_rows_after), and the table has rows left to come, gluing them
+    costs no copy and nothing waits on the next (a slice of a table that is
+    there), so they are held until they reach this longer target, whatever
+    the deadline, and one dispatch pays the host's look-ups and launches for
+    all of them. A morsel that is no such view (a streamed or concatenated
+    input, a gap, another table) puts the run back under ``target_rows``:
+    there the concat copies.
+
     Counters (coarse, per flush — never per row): ``coalesce_morsels_in`` /
     ``dispatch_coalesced`` give the amortization factor,
     ``bucket_fill_rows`` / ``bucket_capacity_rows`` the padding efficiency —
@@ -581,13 +622,18 @@ class DispatchCoalescer:
     queries it shows the most recent run, not an aggregate.
     """
 
-    def __init__(self, feed: Callable, target_rows: int, latency_s: float):
+    def __init__(self, feed: Callable, target_rows: int, latency_s: float,
+                 resident_target_rows: int = 0):
         self._feed = feed
         self._target = max(int(target_rows), 1)
+        self._resident_target = max(int(resident_target_rows), self._target)
         self._latency = max(float(latency_s), 0.0)
         self._pending: List = []
         self._rows = 0
         self._oldest: Optional[float] = None
+        # rows of their table left after the pending morsels, while those are
+        # contiguous views of one resident table; None where they are not
+        self._rows_after: Optional[int] = None
         # this RUN's fill accounting (the gauge must reflect the current
         # query, not a process-lifetime blend of every query's counters)
         self._filled = 0
@@ -599,8 +645,23 @@ class DispatchCoalescer:
         if batch.num_rows == 0:
             return
         counters.bump("coalesce_morsels_in")
+        if self._resident_target > self._target:
+            prev = self._pending[-1] if self._pending else None
+            after = resident_rows_after(batch, prev)
+            if after is None and self._rows_after is not None \
+                    and self._rows >= self._target:
+                # a resident run ends before this morsel: it goes as it is,
+                # and the morsel may start the next one
+                self.flush()
+                after = resident_rows_after(batch)
+            self._rows_after = after
         self._pending.append(batch)
         self._rows += batch.num_rows
+        if self._rows_after:
+            # a resident run with rows to come: held to the longer target
+            if self._rows >= self._resident_target:
+                self.flush()
+            return
         now = time.perf_counter()
         if self._oldest is None:
             self._oldest = now
@@ -620,6 +681,7 @@ class DispatchCoalescer:
         self._pending = []
         self._rows = 0
         self._oldest = None
+        self._rows_after = None
         with profile_span("device.coalesce_flush", "device",
                           morsels_in=morsels_in, rows=batch.num_rows,
                           fill_ratio=round(

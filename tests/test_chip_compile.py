@@ -327,21 +327,30 @@ def _assert_compacts_without_sort_or_scan(text, n_planes):
     assert gathers == [f"{k},128"] * (n_planes + 2), gathers
 
 
+# a join dispatch over a resident fact: DISPATCH_SEGMENTS morsels' rows (PR 43)
+SEGMENTS = [1, 8]
+SEGMENT_IDS = ["one_bucket", "eight_segments"]
+
+
+@pytest.mark.parametrize("segments", SEGMENTS, ids=SEGMENT_IDS)
 @pytest.mark.parametrize("cap", [1 << 21, ORDERS_CAP], ids=["customer_ids", "order_ids"])
-def test_run_wide_topn_accumulate_lowers_at_sf10(one_chip, cap):
-    """One dispatch of the run-wide program: a batch of 131,072 rows into
-    tables of 2^21 customer ids (q10's) and 2^24 order ids (q3's), the three
-    forms in one program, the tables donated (no second copy of them among
-    the temporaries)."""
+def test_run_wide_topn_accumulate_lowers_at_sf10(one_chip, cap, segments):
+    """One dispatch of the run-wide program: a batch of 131,072 rows, or
+    eight such segments walked one after the other, into tables of 2^21
+    customer ids (q10's) and 2^24 order ids (q3's), the three forms in one
+    program, the tables donated (no second copy of them among the
+    temporaries, loop or no loop), and a segment's scatters and compaction
+    the length they had when it was a dispatch of its own."""
     stage, _topn = _q3_join_stage()
     tables = _run_wide_tables(stage, one_chip, cap)
     ints = {"l_shipdate"}
-    cols = {name: (_s(one_chip, (JOIN_BATCH,), jnp.bool_ if name == "__join_ok__"
+    rows = segments * JOIN_BATCH
+    cols = {name: (_s(one_chip, (rows,), jnp.bool_ if name == "__join_ok__"
                       else jnp.int32 if name in ints else jnp.float32),
-                   _s(one_chip, (JOIN_BATCH,), jnp.bool_)) for name in stage._input_cols}
-    compiled = stage._build_run_wide(cap).lower(
-        tables, cols, _s(one_chip, (JOIN_BATCH,), jnp.int32),
-        _s(one_chip, (JOIN_BATCH,), jnp.bool_), _literal_args(stage, one_chip)).compile()
+                   _s(one_chip, (rows,), jnp.bool_)) for name in stage._input_cols}
+    compiled = stage._build_run_wide(cap, segment=JOIN_BATCH).lower(
+        tables, cols, _s(one_chip, (rows,), jnp.int32),
+        _s(one_chip, (rows,), jnp.bool_), _literal_args(stage, one_chip)).compile()
     mem = compiled.memory_analysis()
     table_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                       for x in jax.tree_util.tree_leaves(tables))
@@ -401,10 +410,12 @@ def _gather_operand_shapes(text):
     return out
 
 
+@pytest.mark.parametrize("segments", SEGMENTS, ids=SEGMENT_IDS)
 @pytest.mark.parametrize("rows", [6, 1], ids=["q5_six_rows", "q3_q10_one_row"])
-def test_windowed_provisioning_gathers_from_a_batch_long_window(one_chip, rows):
+def test_windowed_provisioning_gathers_from_a_batch_long_window(one_chip, rows, segments):
     """The join's provisioning program at `tpch_sf10.joins`' shapes: `orders`'
-    pack of [6, 2^24] (q5) or [1, 2^24] (q3, q10) and 131,072 indices. Where
+    pack of [6, 2^24] (q5) or [1, 2^24] (q3, q10) and 131,072 indices, or
+    eight segments of as many, each gathered from a window of its own. Where
     the batch's verdict says window, no gather of the optimized program reads
     from 2^24 rows: the slice stays apart from the gather (a compiler that
     folded it back would leave the dispatch at its 3.3 ms, and no CPU test
@@ -419,22 +430,24 @@ def test_windowed_provisioning_gathers_from_a_batch_long_window(one_chip, rows):
             packs=(5, 2), windows=(True, False),
             columns=(("c_nationkey", 0, (0,), 1), ("o_total", 0, (2,), 3),
                      ("s_nationkey", 1, (0,), 1)),
-            codes=((0, 4, 1),), cap=32)
+            codes=((0, 4, 1),), cap=32, segment=JOIN_BATCH)
         mats = (_s(one_chip, (6, ORDERS_CAP), jnp.float32),
                 _s(one_chip, (3, JOIN_BATCH), jnp.float32))
     else:
-        layout = _ProvisionLayout(packs=(0,), windows=(True,), columns=(), codes=(), cap=0)
+        layout = _ProvisionLayout(packs=(0,), windows=(True,), columns=(), codes=(), cap=0,
+                                  segment=JOIN_BATCH)
         mats = (_s(one_chip, (1, ORDERS_CAP), jnp.float32),)
-    idxs = tuple(_s(one_chip, (JOIN_BATCH,), jnp.int32) for _ in mats)
+    idxs = tuple(_s(one_chip, (segments * JOIN_BATCH,), jnp.int32) for _ in mats)
     windowed = _gather_operand_shapes(_compile(_provision_program(layout), mats, idxs, ()))
     plain = _gather_operand_shapes(_compile(_provision_program(dataclasses.replace(
         layout, windows=(False,) * len(mats))), mats, idxs, ()))
-    assert len(windowed) == len(plain) == len(mats)
+    # a windowed gather a segment; the plain ones take the dispatch's indices at once
+    assert len(plain) == len(mats) and len(windowed) == segments + len(mats) - 1
     assert max(max(shape) for shape in plain) == ORDERS_CAP, \
         "the control: the plain gather reads the whole pack"
     assert all(int(np.prod(shape)) <= rows * JOIN_BATCH for shape in windowed), windowed
     if rows == 1:   # rows of one lane width, not 131,072 single values (0.93 ms on the chip)
-        assert windowed == [(JOIN_BATCH // 128, 128)]
+        assert windowed == [(JOIN_BATCH // 128, 128)] * segments
 
 
 # ---- the join dispatch on every shard of the 2x2 mesh, at tpch_sf30_mesh4.joins' shapes ----
@@ -463,19 +476,21 @@ def _sharded_accumulate_tables(stage, rows, cap):
                 compact=_s(rows, (MESH_CHIPS,), jnp.int32))
 
 
-def test_sharded_run_wide_accumulate_lowers_at_sf30(topo):
+@pytest.mark.parametrize("segments", SEGMENTS, ids=SEGMENT_IDS)
+def test_sharded_run_wide_accumulate_lowers_at_sf30(topo, segments):
     """One dispatch of q3's run-wide program over the mesh: 131,072 rows a
-    chip into a chip's own tables of 2^26 order ids, donated, and no
-    collective: a dispatch leaves the chips' tables apart."""
+    chip, or eight such segments a chip, into a chip's own tables of 2^26
+    order ids, donated, and no collective: a dispatch leaves the chips'
+    tables apart."""
     stage, _topn = _q3_join_stage()
     mesh, rows, whole = _mesh_shardings(topo)
-    total = MESH_CHIPS * JOIN_BATCH
+    total = MESH_CHIPS * segments * JOIN_BATCH
     tables = _sharded_accumulate_tables(stage, rows, ORDERS_CAP_SF30)
     ints = {"l_shipdate"}
     cols = {name: (_s(rows, (total,), jnp.bool_ if name == "__join_ok__"
                       else jnp.int32 if name in ints else jnp.float32),
                    _s(rows, (total,), jnp.bool_)) for name in stage._input_cols}
-    compiled = stage._build_run_wide(ORDERS_CAP_SF30, mesh).lower(
+    compiled = stage._build_run_wide(ORDERS_CAP_SF30, mesh, JOIN_BATCH).lower(
         tables, cols, _s(rows, (total,), jnp.int32), _s(rows, (total,), jnp.bool_),
         _literal_args(stage, whole)).compile()
     mem = compiled.memory_analysis()
@@ -521,28 +536,31 @@ def test_sharded_run_wide_combine_and_select_lowers_at_sf30(topo):
     assert mem.output_size_in_bytes < 64 * 1024  # K rows and their sort operands a chip
 
 
+@pytest.mark.parametrize("segments", SEGMENTS, ids=SEGMENT_IDS)
 @pytest.mark.parametrize("rows_of_pack", [6, 1], ids=["q5_six_rows", "q3_q10_one_row"])
-def test_sharded_provisioning_gathers_from_a_shards_own_window(topo, monkeypatch, rows_of_pack):
+def test_sharded_provisioning_gathers_from_a_shards_own_window(topo, monkeypatch, rows_of_pack,
+                                                               segments):
     """The provisioning program on every shard: `orders`' pack of [6, 2^26]
-    (q5) or [1, 2^26] (q3, q10) whole on each chip, 131,072 indices a chip.
-    A shard's windowed gather reads a window of its own length, never the
-    2^26-row pack, and the program runs no collective."""
+    (q5) or [1, 2^26] (q3, q10) whole on each chip, 131,072 indices a chip or
+    eight segments of as many. A windowed gather reads a window of a
+    segment's length, never the 2^26-row pack, and the program runs no
+    collective."""
     import daft_tpu.ops.device_join as dj
 
     mesh, rows, whole = _mesh_shardings(topo)
     monkeypatch.setattr(dj, "local_mesh", lambda n: mesh)
-    total = MESH_CHIPS * JOIN_BATCH
+    total = MESH_CHIPS * segments * JOIN_BATCH
     if rows_of_pack == 6:
         layout = dj._ProvisionLayout(
             packs=(5, 2), windows=(True, False),
             columns=(("c_nationkey", 0, (0,), 1), ("o_total", 0, (2,), 3),
                      ("s_nationkey", 1, (0,), 1)),
-            codes=((0, 4, 1),), cap=32, devices=MESH_CHIPS)
+            codes=((0, 4, 1),), cap=32, devices=MESH_CHIPS, segment=JOIN_BATCH)
         mats = (_s(whole, (6, ORDERS_CAP_SF30), jnp.float32),
                 _s(whole, (3, 1 << 19), jnp.float32))
     else:
         layout = dj._ProvisionLayout(packs=(0,), windows=(True,), columns=(), codes=(),
-                                     cap=0, devices=MESH_CHIPS)
+                                     cap=0, devices=MESH_CHIPS, segment=JOIN_BATCH)
         mats = (_s(whole, (1, ORDERS_CAP_SF30), jnp.float32),)
     idxs = tuple(_s(rows, (total,), jnp.int32) for _ in mats)
     try:
@@ -550,7 +568,7 @@ def test_sharded_provisioning_gathers_from_a_shards_own_window(topo, monkeypatch
     finally:
         dj._provision_program.cache_clear()     # a program bound to the described mesh
     shapes = _gather_operand_shapes(text)
-    assert len(shapes) == len(mats)
+    assert len(shapes) == segments + len(mats) - 1      # (a windowed gather a segment)
     assert (JOIN_BATCH // 128, 128) in shapes if rows_of_pack == 1 \
         else (6, JOIN_BATCH) in shapes, shapes
     assert max(max(shape) for shape in shapes) < ORDERS_CAP_SF30

@@ -34,6 +34,37 @@ def _assert_close(host, dev):
                 assert a == b, (c, a, b)
 
 
+@pytest.fixture
+def one_bucket(monkeypatch):
+    """A join dispatch over a resident fact covers ONE bucket, as every
+    dispatch did before a resident run's morsels were held to
+    DISPATCH_SEGMENTS of them: the tests that count a dispatch a morsel."""
+    import daft_tpu.ops.grouped_stage as gs
+
+    monkeypatch.setattr(gs, "DISPATCH_SEGMENTS", 1)
+
+
+@pytest.fixture(params=[1, 8], ids=["one_bucket", "segments"])
+def segments(request, monkeypatch):
+    """Both lengths of a join dispatch over a resident fact: one bucket, and
+    the shipped DISPATCH_SEGMENTS buckets walked as segments."""
+    import daft_tpu.ops.grouped_stage as gs
+
+    assert gs.DISPATCH_SEGMENTS == 8
+    monkeypatch.setattr(gs, "DISPATCH_SEGMENTS", request.param)
+    return request.param
+
+
+def _dispatches(morsels, segments, shards=1):
+    """Join dispatches over a resident fact of `morsels` morsels: `segments`
+    buckets a shard each, fewer where the fact is short (a dispatch is never
+    the whole fact: batching.resident_dispatch_segments)."""
+    from daft_tpu.execution.batching import resident_dispatch_segments
+
+    segments = min(segments, resident_dispatch_segments(-(-morsels // shards)))
+    return -(-morsels // (segments * shards))
+
+
 @pytest.fixture(scope="module")
 def star():
     rng = np.random.default_rng(9)
@@ -425,11 +456,11 @@ def _days(y, m, d):
     return datetime.date(y, m, d)
 
 
-def _tpch_like(seed=5, orders_shuffle=None):
+def _tpch_like(seed=5, orders_shuffle=None, n_l=7000):
     import datetime
 
     rng = np.random.default_rng(seed)
-    n_l, n_o, n_c, n_s = 7000, 5000, 500, 100
+    n_o, n_c, n_s = 5000, 500, 100
     assert n_l > 3 * _MORSEL and n_o > 2 * _MORSEL
     day0 = datetime.date(1994, 1, 1)
     dates = lambda n: [day0 + datetime.timedelta(days=int(x)) for x in rng.integers(0, 730, n)]
@@ -516,20 +547,22 @@ def tpch_like():
 
 
 @pytest.mark.parametrize("shape", [_q3_shaped, _q5_shaped], ids=["q3", "q5"])
-def test_morselized_repeat_query_hits_every_slot(tpch_like, shape):
+def test_morselized_repeat_query_hits_every_slot(tpch_like, shape, segments):
     from daft_tpu.device.residency import manager
 
     manager().clear()
     q = lambda: shape(tpch_like)
     host = _host_answer(q)
     first, cold, dispatches = _device_run(q)
-    assert dispatches >= 4, "one join dispatch per fact morsel"
+    # one join dispatch per fact morsel, or one for every two of the four, glued
+    assert dispatches == _dispatches(4, segments)
     assert cold["hbm_cache_misses"] > 0 and cold["hbm_h2d_bytes"] > 0
     _assert_close(host, first)
     for _ in range(2):      # from the second execution on: nothing built, nothing uploaded
         again, warm, d = _device_run(q)
         assert warm["hbm_cache_misses"] == 0 and warm["hbm_h2d_bytes"] == 0, warm
-        assert warm["hbm_lineage_hits"] > 0, "fresh morsel objects found their rows' slots"
+        if segments == 1:
+            assert warm["hbm_lineage_hits"] > 0, "fresh morsel objects found their rows' slots"
         assert d == dispatches, "the dispatch shape is what it was"
         assert again == first
     manager().clear()
@@ -674,10 +707,11 @@ _TOPN_COUNTERS = ("device_topn_runs", "device_join_topn_batches",
 
 @pytest.mark.parametrize("batches", [1, 3, 7])
 @pytest.mark.parametrize("shape,limit", [(_topn_q3, 10), (_topn_q10, 20)], ids=["q3", "q10"])
-def test_fused_topn_takes_a_fact_of_any_number_of_batches(shape, limit, batches):
+def test_fused_topn_takes_a_fact_of_any_number_of_batches(shape, limit, batches, segments):
     """The fused TopN over 1, 3 and 7 fact batches gives the host engine's
     rows in the host engine's order (ties included), fetches no more rows
-    than its limit and counts every batch; a repeat builds nothing."""
+    than its limit and counts every dispatch (a batch each, or several of
+    them glued, never all of a fact of several); a repeat builds nothing."""
     from daft_tpu.observability.metrics import registry
 
     t = _topn_tables(n_l=_MORSEL * batches - 100)
@@ -692,7 +726,7 @@ def test_fused_topn_takes_a_fact_of_any_number_of_batches(shape, limit, batches)
             dev = shape(t).to_pydict()
         d = {k: registry().get(k) - before[k] for k in before}
         assert d["device_topn_runs"] == 1, counters.rejections
-        assert d["device_join_topn_batches"] == batches
+        assert d["device_join_topn_batches"] == _dispatches(batches, segments)
         assert d["device_topn_fetched_rows"] == limit
         assert d["device_topn_table_bytes"] > 0
         assert not any("multi-batch" in k for k in counters.rejections)
@@ -702,7 +736,7 @@ def test_fused_topn_takes_a_fact_of_any_number_of_batches(shape, limit, batches)
 
 
 @pytest.mark.parametrize("shape", [_topn_q3, _topn_q10], ids=["q3", "q10"])
-def test_fused_topn_offset_and_a_filter_that_empties_batches(shape):
+def test_fused_topn_offset_and_a_filter_that_empties_batches(shape, segments):
     """An offset skips the first winners; a date cut that keeps only the
     first fifth of the orders leaves the later batches with no kept row."""
     t = _topn_tables(n_l=_MORSEL * 5 - 100)
@@ -714,13 +748,13 @@ def test_fused_topn_offset_and_a_filter_that_empties_batches(shape):
         with _morselized("on"):
             dev = q().to_pydict()
         assert counters.device_topn_runs == 1, counters.rejections
-        assert counters.device_join_topn_batches == 5
+        assert counters.device_join_topn_batches == _dispatches(5, segments)
         _assert_close(host, dev)
 
 
-def test_fused_topn_scatter_and_dense_forms_agree():
-    """q3's ids (a fact sorted by the order key) take the dense form at every
-    dispatch; the same rows shuffled take the scatter form, to the same answer."""
+def test_fused_topn_scatter_and_dense_forms_agree(segments):
+    """q3's ids (a fact sorted by the order key) take the dense form in every
+    segment; the same rows shuffled take the scatter form, to the same answer."""
     import daft_tpu.ops.device_join as dj
 
     t = _topn_tables(n_l=_MORSEL * 7 - 100)    # more orders than a chunk's window is wide
@@ -745,7 +779,8 @@ def test_fused_topn_scatter_and_dense_forms_agree():
             shuffled_answer = _topn_q3(shuffled).to_pydict()
     finally:
         dj.DeviceJoinTopNRun._finalize_run_wide = real
-    assert seen == [(7, 7), (7, 0)], seen
+    # (dispatches, segments that took the dense form)
+    assert seen == [(_dispatches(7, segments), 7), (_dispatches(7, segments), 0)], seen
     _assert_close(_host_answer(lambda: _topn_q3(t)), sorted_answer)
     _assert_close(host, shuffled_answer)
 
@@ -860,11 +895,15 @@ _COMPACT_CASES = {
 
 
 @pytest.mark.parametrize("case", list(_COMPACT_CASES))
-def test_fused_topn_compact_and_scatter_forms_agree(case, monkeypatch):
-    """A dispatch whose kept rows fit K scatters K compacted indices, one
-    with K + 1 the whole bucket, and a batch with nothing kept is locally
+def test_fused_topn_compact_and_scatter_forms_agree(case, monkeypatch, segments):
+    """A segment whose kept rows fit K scatters K compacted indices, one
+    with K + 1 the whole segment, and one with nothing kept is locally
     dense as before: the same tables as the scatter form alone leaves, the
-    host engine's answer, and counters that say which form ran."""
+    host engine's answer, and counters that say which form ran. A segment is
+    a dispatch of its own or, glued with the other morsels of its table, one
+    of a dispatch whose segments each choose their form ("mixed": a dispatch
+    of four segments, one dense, two compacted and one scattered, and the
+    table's last morsel, scattered, in a dispatch of its own)."""
     import daft_tpu.ops.grouped_stage as gs
     from daft_tpu.observability.runtime_stats import SpanRecorder, set_spans
 
@@ -885,7 +924,7 @@ def test_fused_topn_compact_and_scatter_forms_agree(case, monkeypatch):
     finally:
         set_spans(None)
     assert counters.device_topn_runs == 1, counters.rejections
-    assert counters.device_join_topn_batches == len(kept)
+    assert counters.device_join_topn_batches == _dispatches(len(kept), segments)
     assert counters.join_topn_compact_batches == compact
     (select,) = [s for s in rec.drain() if s["name"] == "join.topn_select"]
     assert select["args"]["compact_batches"] == compact
@@ -904,7 +943,7 @@ def test_fused_topn_compact_and_scatter_forms_agree(case, monkeypatch):
         gs._STAGE_CACHE.clear()
     assert plain == answer
     (batches, got), (_b, want) = seen
-    assert batches == len(kept)
+    assert batches == _dispatches(len(kept), segments)
     assert int(got["dense"]) == int(want["dense"]) == dense
     assert int(got["compact"]) == compact
     _assert_tables_agree(got, want, kept_ids)
@@ -936,6 +975,205 @@ def test_the_select_program_is_handed_the_leaves_it_was_compiled_for(monkeypatch
         _topn_q10(t).to_pydict()
     assert handed == [["dense", "first", "hi", "lo"]]
     assert sorted(seen[0][1]) == ["compact", "dense", "first", "hi", "lo"]
+
+
+# ---- a dispatch over a resident fact covers several buckets ------------------------------
+#
+# Contiguous morsels of a resident table glue at no copy, so a join's
+# coalescer holds them to DISPATCH_SEGMENTS buckets and the run dispatches the
+# range once; the programs walk it a segment (a morsel's bucket) at a time.
+
+
+def _coalesce_counters():
+    from daft_tpu.observability.metrics import registry
+
+    names = ("coalesce_morsels_in", "dispatch_coalesced", "device_join_batches",
+             "hbm_cache_misses", "hbm_h2d_bytes")
+    return {k: registry().get(k) for k in names}
+
+
+def _counted(q):
+    before = _coalesce_counters()
+    with _morselized("on"):
+        out = q().to_pydict()
+    after = _coalesce_counters()
+    return out, {k: after[k] - before[k] for k in before}
+
+
+_LONG_MORSELS = 19      # two dispatches of eight and a tail of three: its bucket holds four segments
+
+
+@pytest.mark.parametrize("shape", ["q3", "q10", "q5"])
+def test_a_long_dispatch_gives_the_one_bucket_answer(shape, monkeypatch):
+    """A run-wide TopN (q3 dense, q10 sparse) and a grouped join (q5, dictionary
+    codes) over a resident fact of 19 morsels: at DISPATCH_SEGMENTS buckets a
+    dispatch (8, 8, and a tail of 3 whose bucket's last segment is all
+    padding and whose third is part full) the host engine's answer, and the
+    answer of a bucket a dispatch (the TopN's to the bit: a segment adds what
+    a dispatch of its own added, in the same order); 3 dispatches for 19
+    morsels; and a repeat query misses no slot and uploads nothing."""
+    import daft_tpu.ops.grouped_stage as gs
+    from daft_tpu.device.residency import manager
+
+    manager().clear()
+    n_l = _MORSEL * _LONG_MORSELS - 100
+    if shape == "q5":
+        t = _tpch_like(n_l=n_l)
+        q = lambda: _q5_shaped(t)
+    else:
+        t = _topn_tables(n_l=n_l)
+        q = lambda: {"q3": _topn_q3, "q10": _topn_q10}[shape](t)
+    host = _host_answer(q)
+    assert gs.DISPATCH_SEGMENTS == 8
+    first, cold = _counted(q)
+    assert cold["coalesce_morsels_in"] == _LONG_MORSELS
+    assert cold["dispatch_coalesced"] == cold["device_join_batches"] == 3
+    assert cold["hbm_cache_misses"] > 0
+    _assert_close(host, first)
+    again, warm = _counted(q)
+    assert again == first
+    assert warm["hbm_cache_misses"] == 0 and warm["hbm_h2d_bytes"] == 0, warm
+    assert warm["dispatch_coalesced"] == 3
+    monkeypatch.setattr(gs, "DISPATCH_SEGMENTS", 1)
+    one, c1 = _counted(q)
+    assert c1["dispatch_coalesced"] == c1["device_join_batches"] == _LONG_MORSELS
+    if shape == "q5":
+        _assert_close(one, first)
+    else:
+        assert one == first
+    manager().clear()
+
+
+def _resident_morsels(table, rows):
+    """The morsels a pipeline cuts of a collected table: zero-copy views."""
+    (batch,) = table._result[0].batches
+    return [batch.slice(at, min(at + rows, batch.num_rows))
+            for at in range(0, batch.num_rows, rows)]
+
+
+def _coalescer(fed, rows, segments=8, shards=1, morsel=_MORSEL):
+    """A join run's coalescer over a resident fact of `rows` rows, which
+    makes a dispatch `segments` buckets a shard long."""
+    from daft_tpu.config import execution_config
+    from daft_tpu.execution.batching import coalesce_target_rows
+    from daft_tpu.execution.executor import _make_coalescer
+
+    with execution_config_ctx(morsel_size_rows=morsel):
+        cfg = execution_config()
+        assert coalesce_target_rows(cfg, shards, resident_rows=rows) \
+            == (segments * shards - 1) * morsel + morsel // 2
+        return _make_coalescer(fed.append, cfg, shards, resident_rows=rows)
+
+
+@pytest.mark.parametrize("fact_buckets,segments", [
+    (1, 1), (2, 1), (3, 2), (4, 2), (5, 4), (8, 4), (9, 8), (16, 8), (46, 8), (458, 8)])
+def test_a_dispatch_is_never_the_whole_fact(fact_buckets, segments):
+    """A join dispatch over a resident fact covers DISPATCH_SEGMENTS buckets a
+    device, or the largest power of two under the fact's own length where it
+    is shorter: a fact of several morsels is a run of several dispatches."""
+    from daft_tpu.config import execution_config
+    from daft_tpu.execution.batching import coalesce_target_rows, resident_dispatch_segments
+
+    assert resident_dispatch_segments(fact_buckets) == segments
+    assert segments == 1 or -(-fact_buckets // segments) > 1
+    for shards in (1, 4):
+        with execution_config_ctx(morsel_size_rows=_MORSEL):
+            rows = fact_buckets * shards * _MORSEL - 100
+            assert coalesce_target_rows(execution_config(), shards, resident_rows=rows) \
+                == (segments * shards - 1) * _MORSEL + _MORSEL // 2
+
+
+def test_the_coalescer_glues_a_resident_run_at_no_copy():
+    """Contiguous views of one resident table are held to the join's length
+    and handed on as ONE view of the table's rows (every column's lineage is a
+    range of the table's own column: nothing was copied); the table's last
+    morsels go as they are when the table ends."""
+    t = daft_tpu.from_pydict({"k": list(range(_MORSEL * 19 - 100)),
+                              "v": [float(i) for i in range(_MORSEL * 19 - 100)],
+                              "s": [f"s{i % 7}" for i in range(_MORSEL * 19 - 100)]}).collect()
+    (whole,) = t._result[0].batches
+    fed = []
+    coal = _coalescer(fed, whole.num_rows)
+    for i, m in enumerate(_resident_morsels(t, _MORSEL)):
+        coal.add(m)
+        assert len(fed) == (i + 1) // 8 if i < 18 else 3, "a flush every eighth morsel, and at the table's end"
+    coal.close()
+    assert [b.num_rows for b in fed] == [8 * _MORSEL, 8 * _MORSEL, 3 * _MORSEL - 100]
+    at = 0
+    for b in fed:
+        for name in b.column_names():
+            root, off = b.get_column(name).lineage()
+            assert root is whole.get_column(name) and off == at
+        at += b.num_rows
+
+
+@pytest.mark.parametrize("case", ["streamed", "gap", "another_table", "a_computed_column"])
+def test_morsels_that_are_no_resident_run_flush_at_the_threshold_they_had(case):
+    """What is not a run of contiguous views of one resident table keeps
+    batch_fill_target of a bucket: morsels that are tables of their own (a
+    stream's), views with a gap between them, views of another table, and
+    views beside a computed column flush one by one as they did."""
+    n = _MORSEL * 6
+    make = lambda: daft_tpu.from_pydict({"k": list(range(n)), "v": [float(i) for i in range(n)]}).collect()
+    t = make()
+    morsels = _resident_morsels(t, _MORSEL)
+    if case == "streamed":
+        morsels = [daft_tpu.from_pydict(m.to_pydict()).collect()._result[0].batches[0] for m in morsels]
+        want = [_MORSEL] * 6
+    elif case == "gap":
+        morsels = morsels[:2] + morsels[3:]
+        # (the run that ends at the gap goes as it is, the next one starts anew)
+        want = [2 * _MORSEL, 3 * _MORSEL]
+    elif case == "another_table":
+        morsels = morsels[:2] + _resident_morsels(make(), _MORSEL)[2:4] + morsels[4:]
+        want = [2 * _MORSEL, 2 * _MORSEL, 2 * _MORSEL]
+    else:
+        from daft_tpu.core.recordbatch import RecordBatch
+        from daft_tpu.core.series import Series
+        from daft_tpu.schema import Schema
+
+        def with_computed(m):
+            cols = [m.get_column("k"), m.get_column("v"),
+                    Series.from_numpy(np.asarray(m.get_column("v").to_numpy()) * 2, "v2")]
+            return RecordBatch(Schema([c.field() for c in cols]), cols, m.num_rows)
+        morsels = [with_computed(m) for m in morsels]
+        want = [_MORSEL] * 6
+    fed = []
+    coal = _coalescer(fed, n, segments=4)     # (a table of six morsels: four a dispatch)
+    for m in morsels:
+        coal.add(m)
+    coal.close()
+    assert [b.num_rows for b in fed] == want
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_the_priced_horizon_is_held_where_it_was_and_never_over_what_runs(shards):
+    """Over a resident fact the coalescer delivers DISPATCH_SEGMENTS morsels
+    a shard a dispatch; _coalesce_horizon, which the tiers are priced with,
+    stays at the plain threshold's factor (batch_fill_target of the last
+    shard's bucket), so a verdict is what it was and the price never
+    promises more than runs. Both read coalesce_target_rows."""
+    from daft_tpu.config import execution_config
+    from daft_tpu.core.micropartition import MicroPartition
+    from daft_tpu.execution.batching import coalesce_target_rows
+    from daft_tpu.execution.executor import _coalesce_horizon
+
+    n_morsels = 16 * shards
+    n = _MORSEL * n_morsels
+    t = daft_tpu.from_pydict({"k": list(range(n)), "v": [float(i) for i in range(n)]}).collect()
+    morsels = _resident_morsels(t, _MORSEL)
+    parts = [MicroPartition(m.schema, [m]) for m in morsels[:2]]
+    fed = []
+    coal = _coalescer(fed, n, shards=shards)
+    for m in morsels:
+        coal.add(m)
+    coal.close()
+    delivered = n_morsels / len(fed)
+    assert delivered == 8 * shards
+    with execution_config_ctx(morsel_size_rows=_MORSEL):
+        priced = _coalesce_horizon(parts, shards=shards, stream_rows=n)
+        assert priced == max(coalesce_target_rows(execution_config(), shards) / _MORSEL, 1.0)
+        assert priced == max(shards - 0.5, 1) <= delivered
 
 
 def test_topn_group_by_outside_a_dimension_keeps_the_one_batch_form():
@@ -1261,10 +1499,15 @@ def _win_q_permuted(fact, dim, small):
 ], ids=["sorted", "shuffled", "misses_at_both_edges", "all_miss_batch", "clamp_at_the_end",
         "span_exactly_w", "wide_digit_rows", "one_row_pack", "one_row_pack_shuffled",
         "perm_folded", "perm_folded_shuffled"])
-def test_windowed_gather_is_the_plain_gather_bit_for_bit(monkeypatch, keys, shape, engaged):
+def test_windowed_gather_is_the_plain_gather_bit_for_bit(monkeypatch, keys, shape, engaged,
+                                                        segments):
     """A batch whose matched rows of a dimension lie within one batch length
     of each other gathers from a window of that dimension's pack, any other
     batch from the whole of it; the verdict comes from the batch's own index.
+    Two morsels glued into one dispatch (the fact is four: a dispatch is
+    never all of it) are gathered a segment at a time, each from its own
+    window, where BOTH segments' rows lie that close (the host-permuted
+    layout keeps a bucket a dispatch: its ids are a batch's).
     Every dispatch that engages a window is run again here through the plain
     program of the same layout: the gathered planes, `__join_ok__` and the
     combined codes are the same bits, misses included. `join_window_gathers`
@@ -1280,6 +1523,9 @@ def test_windowed_gather_is_the_plain_gather_bit_for_bit(monkeypatch, keys, shap
     fact, dim, small = _win_tables(keys(np.random.default_rng(7)),
                                    _PERM_DIM if permuted else _WIN_DIM)
     morsel = _PERM_MORSEL if permuted else _MORSEL
+    glued = segments > 1 and not permuted
+    if glued:
+        engaged = [all(engaged[:2]), all(engaged[2:])]
     config = dict(morsel_size_rows=morsel, pipeline_mode="force")
     real = dj._provision_program
     windows, perms = [], []
@@ -1302,7 +1548,7 @@ def test_windowed_gather_is_the_plain_gather_bit_for_bit(monkeypatch, keys, shap
 
     def spy(self, batch, bucket, needed, codes=None, perm=None):
         perms.append(perm is not None)
-        assert bucket == morsel
+        assert bucket == (2 * morsel if glued else morsel)
         return real_provision(self, batch, bucket, needed, codes=codes, perm=perm)
 
     monkeypatch.setattr(dj, "_provision_program", both)

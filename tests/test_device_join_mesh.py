@@ -52,6 +52,9 @@ def _ungrouped_numpy(t):
     return float((price * (1 - disc))[keep].sum()), int(keep.sum())
 
 
+one_bucket = tj.one_bucket      # (a bucket a shard a dispatch: the tests that count one)
+
+
 @pytest.fixture(scope="module")
 def topn_tables():
     # thirteen morsels: a mesh dispatch takes four, so the run ends on a
@@ -66,6 +69,7 @@ def tpch_like():
 
 # ---- (a) the answers are the one chip's ----------------------------------------------------
 
+@pytest.mark.usefixtures("one_bucket")
 @pytest.mark.parametrize("shape", ["q3", "q10", "q5", "ungrouped"])
 def test_sharded_join_answers_as_one_chip_does(topn_tables, tpch_like, shape):
     """q3-, q10-, q5-shaped joins and an ungrouped join-aggregate over a fact
@@ -102,6 +106,7 @@ def test_sharded_join_answers_as_one_chip_does(topn_tables, tpch_like, shape):
 
 # ---- (b) the shards' tables add up to the one chip's ---------------------------------------
 
+@pytest.mark.usefixtures("one_bucket")
 def test_the_four_shards_tables_add_up_to_the_one_chips(topn_tables, monkeypatch):
     """q3's run-wide tables at the run's end: every chip's set, added up over
     the chips, is the one chip's. Rows and first-row positions exactly (a
@@ -148,6 +153,7 @@ def test_the_four_shards_tables_add_up_to_the_one_chips(topn_tables, monkeypatch
 
 # ---- (b2) every shard chooses its own form ---------------------------------------------------
 
+@pytest.mark.usefixtures("one_bucket")
 @pytest.mark.parametrize("case,kept", [
     # (a dispatch is four morsels, a morsel a shard: kept rows by dispatch and shard)
     ("one_shard_over_k", [40, tj._K + 1, tj._K, 90] * 2),
@@ -196,8 +202,87 @@ def test_each_shard_compacts_or_scatters_by_its_own_rows(case, kept, monkeypatch
         tj._assert_tables_agree(chip(got), chip(want), mine)
 
 
+# ---- (b3) a dispatch of DISPATCH_SEGMENTS buckets a shard --------------------------------------
+
+_LONG = 45      # morsels: a dispatch of 8 a shard takes 32, the tail's last shard is part padding
+
+
+@pytest.fixture(scope="module")
+def long_tables():
+    return tj._topn_tables(n_l=_MORSEL * _LONG - 100)
+
+
+@pytest.mark.parametrize("shape", ["q3", "q10", "q5", "ungrouped"])
+def test_a_long_sharded_dispatch_answers_as_a_bucket_a_shard_does(long_tables, shape, monkeypatch):
+    """Over a resident fact of 45 morsels a sharded join dispatch covers
+    DISPATCH_SEGMENTS buckets a shard (32 morsels, then a tail of 13 over
+    shards of 8, 5 and 0): two dispatches that each span the four devices,
+    the host engine's answer, the one chip's answer and the answer of a
+    bucket a shard (12 dispatches); a repeat builds nothing."""
+    import daft_tpu.ops.grouped_stage as gs
+
+    if shape == "q5":
+        t = tj._tpch_like(n_l=_MORSEL * _LONG - 100)
+        q = lambda: tj._q5_shaped(t)
+    elif shape == "ungrouped":
+        q = lambda: _ungrouped(long_tables)
+    else:
+        q = lambda: {"q3": tj._topn_q3, "q10": tj._topn_q10}[shape](long_tables)
+    host = tj._host_answer(q)
+    assert gs.DISPATCH_SEGMENTS == 8
+    four, c4 = _run(q, MESH)
+    assert c4["coalesce_morsels_in"] == _LONG and c4["dispatch_coalesced"] == 2
+    assert c4["device_join_batches"] == c4["device_join_mesh_batches"] == 2, counters.rejections
+    assert c4["device_join_mesh_shards"] == 2 * MESH and c4["hbm_cache_misses"] > 0
+    tj._assert_close(host, four)
+    again, ca = _run(q, MESH)
+    assert again == four and ca.get("hbm_cache_misses", 0) == 0
+    one, c1 = _run(q, 1)
+    assert c1["device_join_batches"] == tj._dispatches(_LONG, 8)
+    tj._assert_close(host, one)
+    monkeypatch.setattr(gs, "DISPATCH_SEGMENTS", 1)
+    short, cs = _run(q, MESH)
+    assert cs["device_join_mesh_batches"] == tj._dispatches(_LONG, 1, MESH)
+    if shape in ("q3", "q10"):
+        assert four == one == short, "ties included"
+        assert c4["device_topn_runs"] == 1 and c4["device_join_topn_batches"] == 2
+    else:
+        tj._assert_close(short, four)
+
+
+def test_every_segment_of_every_shard_chooses_its_own_form(monkeypatch):
+    """A sharded dispatch of sixteen morsels, four segments a shard (the fact
+    is twenty-three: a dispatch is never all of it), and the tail's seven,
+    two segments a shard: dense segments (nothing kept), compacted ones and
+    scattered ones side by side on one chip; the counts over the chips are
+    the segments' own, a shard's padding is never walked, and the answer is
+    the one chip's."""
+    kept = [40, tj._K + 1, 0, tj._K,   600, 3, tj._K - 1, 0,
+            tj._K + 5, tj._K + 9, 1, 2,   0, 0, 90, tj._K + 2,
+            7, 0,   tj._K + 1, 30,   0, tj._K + 3,   tj._K]    # the tail: the last shard's second segment is padding
+    t, kept_ids = tj._compaction_fact(kept)
+    dense, compact, _scatter = tj._forms_of(kept_ids)
+    assert dense and compact and _scatter
+    host = tj._host_answer(lambda: tj._topn_q3(t))
+    seen = []
+    tj._spy_run_wide_tables(monkeypatch, seen)
+    four, c4 = _run(lambda: tj._topn_q3(t), MESH)
+    assert c4["device_topn_runs"] == 1 and c4["device_join_mesh_batches"] == 2, counters.rejections
+    tj._assert_close(host, four)
+    (_b, got), = seen
+    per, tail = 4, 2    # morsels a shard: sixteen over four shards of a 8,192-row bucket, then seven over 4,096
+    for s in range(MESH):
+        mine = kept_ids[s * per:(s + 1) * per] \
+            + kept_ids[MESH * per + s * tail:MESH * per + (s + 1) * tail]
+        assert (int(got["dense"][s]), int(got["compact"][s])) == tj._forms_of(mine)[:2], s
+    assert int(np.sum(got["dense"])) == dense and int(np.sum(got["compact"])) == compact
+    one, c1 = _run(lambda: tj._topn_q3(t), 1)
+    assert one == four and c1["join_topn_compact_batches"] == compact
+
+
 # ---- (c) groups that straddle shards, keys that tie across chips -----------------------------
 
+@pytest.mark.usefixtures("one_bucket")
 def test_groups_straddle_shards_and_ties_fall_as_on_one_chip(topn_tables):
     """The fact is sorted by order key and an order has several lines, so
     orders straddle the shard boundaries of a dispatch; revenues are whole
@@ -231,6 +316,7 @@ def test_groups_straddle_shards_and_ties_fall_as_on_one_chip(topn_tables):
 
 # ---- (d) the ceiling is a chip's ---------------------------------------------------------------
 
+@pytest.mark.usefixtures("one_bucket")
 def test_the_table_ceiling_is_held_to_a_chips_share_of_the_ids(topn_tables, monkeypatch):
     """A dimension whose padded rows pass TOPN_RUN_MAX_SEGMENTS is refused on
     one chip with the ceiling's reason (the run is then held to one fact

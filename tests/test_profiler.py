@@ -281,8 +281,10 @@ def test_every_join_dispatch_holds_its_parts(keys):
     q = lambda: (fact.join(dim, left_on="fk", right_on="k").groupby(*keys)
                  .agg(col("v").sum().alias("s")).sort(list(keys)))
     rec = SpanRecorder()
+    # (40 morsels: five dispatches of DISPATCH_SEGMENTS where the codes are the
+    # dictionaries', a dispatch a morsel where the host factorizes every batch)
     with execution_config_ctx(device_mode="on", device_min_rows=1, mesh_devices=1,
-                              morsel_size_rows=4096, pipeline_mode="force"):
+                              morsel_size_rows=512, pipeline_mode="force"):
         expect = q().to_pydict()
         set_spans(rec)
         try:
@@ -302,7 +304,7 @@ def test_every_join_dispatch_holds_its_parts(keys):
 
     eps = 1e-6
     dispatches = [s for s in spans if s["name"] == "device.dispatch"]
-    assert len(dispatches) >= 4, "one join dispatch per fact morsel"
+    assert len(dispatches) == (5 if keys == ("g",) else 40), "several join dispatches a query"
     parts = ("join.codes", "join.index", "join.gather", "device.launch")
     for d in dispatches:
         inside = [s for s in spans if under(s, d)]
