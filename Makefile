@@ -56,11 +56,12 @@ test-pallas:
 		$(PY) -m pytest tests/test_pallas_join.py tests/test_fused_region.py \
 		-q -p no:cacheprovider
 
-# In-mesh SPMD suite under 8 forced host devices: bit-exact mesh vs
-# single-chip vs host parity, sharded residency, cost-tier flips.
+# In-mesh SPMD suite under 8 forced host devices: the sharded stages and the
+# sharded join dispatch against one chip and the host, sharded residency, the
+# mesh arm of the placement decisions, what the sharded dispatch declines.
 test-mesh:
 	env JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-		$(PY) -m pytest tests/test_mesh_stage.py tests/test_mesh_join.py \
+		$(PY) -m pytest tests/test_mesh_*.py tests/test_device_join_mesh.py \
 		tests/test_distributed.py \
 		-q -p no:cacheprovider
 

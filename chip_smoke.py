@@ -453,7 +453,8 @@ def phase_ring(tables, n: int) -> None:
 
 def phase_mesh(tables, n: int) -> None:
     """The mesh tier against one chip: q1/q6 through the grouped and
-    filter-agg stages sharded over the mesh, q12/q14 through the mesh join tier, one hash
+    filter-agg stages sharded over the mesh, q12/q14 through the join dispatch on every
+    shard (ops/device_join.py with mesh_devices > 1), one hash
     repartition over the all_to_all step, and where one resident sharded
     plane's shards live."""
     from benchmarking.tpch.queries import ALL_QUERIES

@@ -223,8 +223,9 @@ class ExecutionConfig:
     # over the mesh and runs the single chip's program on every shard (f32
     # planes resident per shard, predicate and group codes on the device, no
     # collective; the shards' partial tables are combined in f64 on the host:
-    # ops/stage.py over_shards); a star join may take the mesh join tier
-    # (ops/mesh_stage.py).
+    # ops/stage.py over_shards); a star join runs the single chip's join
+    # dispatch on every shard of the fact (ops/device_join.py), and a shape
+    # that dispatch declines (sharded_join_reason) runs on one chip.
     #   - 0 (default) = auto: the cost model decides host vs single-chip vs
     #     mesh per stage shape; the mesh must WIN its placement
     #     (executor._mesh_wins), never be config-forced.

@@ -252,10 +252,10 @@ class Series:
         return self._upload(pad_to, f32, jnp.asarray)
 
     def _upload(self, pad_to: Optional[int], f32: bool, put):
-        """The one upload body behind to_device / to_device_sharded /
-        to_device_replicated: the padded host planes, then `put` on each (the
-        layout's placement): a column at a time and a transfer a plane, which
-        is how a plane that stays resident arrives (a streamed morsel's planes
+        """The one upload body behind to_device and to_device_sharded: the
+        padded host planes, then `put` on each (the layout's placement): a
+        column at a time and a transfer a plane, which is how a plane that
+        stays resident arrives (a streamed morsel's planes
         go together: ops/stage.batch_planes). A `device.upload` span with
         `device.upload.prepare` inside it while a recorder is installed; the
         host's time in both is always counted (`h2d_upload_us`,
@@ -331,22 +331,6 @@ class Series:
         sharding = NamedSharding(mesh, PartitionSpec(axis))
         return self._upload(pad_to, f32, lambda a: jax.device_put(a, sharding))
 
-    def to_device_replicated(self, mesh, pad_to: Optional[int] = None,
-                             f32: bool = False):
-        """(values, validity) broadcast to EVERY device of the mesh
-        (replicated NamedSharding) — the dim-plane layout of the mesh join
-        feed: the probe is then a purely local gather on each shard, no
-        collective until the reduce. h2d attribution counts the host bytes
-        once (the broadcast fan-out is the link's business, not the
-        ledger's); residency accounting counts the copy each device holds
-        (device_nbytes reckons per device, as the budget does)."""
-        from ..utils import jax_setup  # noqa: F401
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        sharding = NamedSharding(mesh, PartitionSpec())
-        return self._upload(pad_to, f32, lambda a: jax.device_put(a, sharding))
-
     @staticmethod
     def plane_slot(pad_to: Optional[int], f32: bool) -> tuple:
         """The residency slot key of a column's (values, validity) planes on
@@ -354,7 +338,7 @@ class Series:
         return ("col", pad_to, bool(f32))
 
     def to_device_cached(self, pad_to: Optional[int] = None, f32: bool = False,
-                         mesh=None, axis: str = "dp", replicated: bool = False):
+                         mesh=None, axis: str = "dp"):
         """to_device through the process-wide HBM residency manager.
 
         Collected tables queried repeatedly keep their columns resident in HBM
@@ -374,12 +358,6 @@ class Series:
             return manager().get_or_build(
                 self, self.plane_slot(pad_to, f32), (),
                 lambda: self.to_device(pad_to, f32=f32))
-        if replicated:
-            key = ("col", pad_to, bool(f32), "meshR", int(mesh.shape[axis]),
-                   axis)
-            return manager().get_or_build(
-                self, key, (),
-                lambda: self.to_device_replicated(mesh, pad_to, f32=f32))
         key = ("col", pad_to, bool(f32), "mesh", int(mesh.shape[axis]), axis)
         return manager().get_or_build(
             self, key, (),
@@ -399,18 +377,15 @@ class Series:
         object.__setattr__(self, "_pyobjs", pyobjs)
 
     def is_device_resident(self, pad_to: Optional[int] = None, f32: bool = False,
-                           mesh_devices: int = 0, axis: str = "dp",
-                           replicated: bool = False) -> bool:
+                           mesh_devices: int = 0, axis: str = "dp") -> bool:
         """True if this column is already in HBM for the given layout (cost-model
         hook — resident inputs are costed with zero transfer bytes).
-        mesh_devices > 0 probes the row-sharded mesh layout instead
-        (replicated=True: the broadcast dim-plane layout of the join feed)."""
+        mesh_devices > 0 probes the row-sharded mesh layout instead."""
         from ..device.residency import manager
 
         if mesh_devices > 0:
-            fam = "meshR" if replicated else "mesh"
             return manager().is_resident(
-                self, ("col", pad_to, bool(f32), fam, int(mesh_devices), axis))
+                self, ("col", pad_to, bool(f32), "mesh", int(mesh_devices), axis))
         return manager().is_resident(self, self.plane_slot(pad_to, f32))
 
     def content_fingerprint(self) -> Optional[int]:

@@ -1134,13 +1134,7 @@ def _exec_device_join_agg(node) -> MicroPartition:
     """
     from ..ops.device_join import DeviceJoinGroupedRun, DeviceJoinUngroupedRun
 
-    def make_run(stage, grouped, ctx, mesh_stage, shards=1):
-        if mesh_stage is not None:
-            from ..ops.mesh_stage import (MeshJoinGroupedRun,
-                                          MeshJoinUngroupedRun)
-
-            return (MeshJoinGroupedRun(mesh_stage, ctx) if grouped
-                    else MeshJoinUngroupedRun(mesh_stage, ctx))
+    def make_run(stage, grouped, ctx, shards):
         return (DeviceJoinGroupedRun(stage, ctx, shards) if grouped
                 else DeviceJoinUngroupedRun(stage, ctx, shards))
 
@@ -1169,11 +1163,7 @@ def _exec_device_join_topn(node) -> MicroPartition:
     DeviceFallback)."""
     from ..ops.device_join import DeviceJoinTopNRun
 
-    def make_run(stage, grouped, ctx, mesh_stage, shards=1):
-        if mesh_stage is not None:
-            from ..ops.mesh_stage import MeshJoinTopNRun
-
-            return MeshJoinTopNRun(mesh_stage, ctx, node.topn)
+    def make_run(stage, grouped, ctx, shards):
         return DeviceJoinTopNRun(stage, ctx, node.topn, shards)
 
     def assemble(run, stage, grouped):
@@ -1315,12 +1305,10 @@ def _run_device_join(node, label: str, make_run, assemble,
         coal = _coalesce_horizon(seen) if stream_wide else single_batch_horizon()
 
         # Mesh CANDIDATE resolution happens BEFORE pricing: the mesh arm is
-        # only priced when the mesh stage actually BUILDS for this spec, so
-        # a "mesh" verdict is always executable (an unbuildable mesh must
-        # lose the decision to chip/host at cost time, never silently run a
-        # tier the model rejected) and forced-priced records name the tier
-        # that will really execute — the calibrate tool keys samples on
-        # `chosen`, so a mismatch there poisons its suggestions.
+        # only priced where the sharded dispatch takes this join, so a
+        # "mesh" verdict is always executable and forced-priced records name
+        # the tier that will really execute — the calibrate tool keys samples
+        # on `chosen`, so a mismatch there poisons its suggestions.
         mesh_width = _join_mesh_width(cfg)
         if cfg.device_mode == "on" and cfg.mesh_devices < 2:
             # "on" forces the SINGLE-CHIP device path: the mesh engages only
@@ -1338,28 +1326,23 @@ def _run_device_join(node, label: str, make_run, assemble,
                 "runtime", f"{label}: fewer local devices than mesh_devices",
                 f"({len(jax.devices())} < {cfg.mesh_devices})")
         # What the mesh arm runs: the single chip's join dispatch on every
-        # shard of the fact (ops/device_join.py, `mesh_devices`), the path
-        # the four-chip join cell measures; or, for the shapes that path
-        # declines (sharded_join_reason: group codes or TopN ids that need a
-        # host factorization of every batch), the older fused tier of
-        # ops/mesh_stage.py, where its stage builds.
-        mesh_stage = None
-        sharded = False
+        # shard of the fact (ops/device_join.py, `mesh_devices`). A shape
+        # that dispatch declines (sharded_join_reason: group codes or TopN
+        # ids that need a host factorization of every batch, a forced Pallas
+        # probe) is not over the mesh: from here it is priced and run as on
+        # a one-chip host, and the rejection log and the placement record
+        # say why.
+        declined = ""
         if mesh_width >= 2 and batch0 is not None:
             from ..ops.device_join import sharded_join_reason
 
             declined = sharded_join_reason(ctx, stage, grouped, topn, batch0,
                                            mesh_width)
-            sharded = not declined
             if declined:
-                from ..ops.mesh_stage import try_build_mesh_join_stage
-
-                mesh_stage = try_build_mesh_join_stage(node.spec, mesh_width)
-                if mesh_stage is None:
-                    _counters.reject(
-                        "runtime", f"{label}: mesh join stage unbuildable",
-                        f"(and no sharded dispatch: {declined})")
-                    mesh_width = 0
+                _counters.reject(
+                    "runtime", f"{label}: not sharded over the mesh",
+                    f"({declined})")
+                mesh_width = 0
         elif mesh_width >= 2:
             mesh_width = 0
 
@@ -1370,16 +1353,13 @@ def _run_device_join(node, label: str, make_run, assemble,
                 tier, prec = _join_device_wins(
                     node, ctx, batch0, first.num_rows, grouped, stage,
                     topn=topn, label=label, coalesce=coal,
-                    mesh_ndev=mesh_width, sharded=sharded,
+                    mesh_ndev=mesh_width,
                     mesh_coalesce=_coalesce_horizon(
                         seen, shards=mesh_width,
                         stream_rows=_resident_rows(node.fact))
-                    if sharded and stream_wide else coal,
+                    if mesh_width >= 2 and stream_wide else coal,
                     mesh_forced=cfg.mesh_devices >= 2 and mesh_width >= 2)
             _DECISION_CACHE.put(dk, tier)
-            if not tier:
-                raw_stream.close()
-                return _host()
         elif cfg.device_mode == "on":
             tier = "mesh" if mesh_width >= 2 else "chip"
             if _env_bool("DAFT_TPU_PLACEMENT_PRICE_FORCED", False):
@@ -1392,17 +1372,20 @@ def _run_device_join(node, label: str, make_run, assemble,
                     _t, prec = _join_device_wins(
                         node, ctx, batch0, first.num_rows, grouped, stage,
                         topn=topn, label=label, coalesce=coal,
-                        mesh_ndev=mesh_width, sharded=sharded, forced=True,
-                        forced_tier=tier)
+                        mesh_ndev=mesh_width, forced=True, forced_tier=tier)
             if prec is None:
                 prec = _placement.ledger().record(
                     label, "mesh" if tier == "mesh" else "device",
                     first.num_rows, forced=True)
 
-        if tier != "mesh":
-            mesh_stage = None  # costed verdict picked the single chip / host
-        shards = mesh_width if tier == "mesh" and sharded else 1
-        run = make_run(stage, grouped, ctx, mesh_stage, shards)
+        if declined:
+            _placement.ledger().annotate(
+                prec, f"not sharded over the mesh: {declined}")
+        if not tier:    # `auto`: the host won
+            raw_stream.close()
+            return _host()
+        shards = mesh_width if tier == "mesh" else 1
+        run = make_run(stage, grouped, ctx, shards)
         from ..device.residency import manager as _residency
 
         # pin-scope the feed + finalize: entries this query touches (packed
@@ -1412,12 +1395,11 @@ def _run_device_join(node, label: str, make_run, assemble,
         region_ops = ("join", "agg", "topn") if topn else ("join", "agg")
         d0 = _counters.device_join_batches
         with _placement.feedback(prec) as fb, _residency().pin_scope():
-            if topn and not getattr(run, "run_wide", False):
-                # this run's group ids hold for ONE fact batch (the mesh
-                # tier's, or a group-by outside one dimension's key space):
-                # bail on sighting a SECOND (before any device work, without
-                # draining the stream)
-                why = getattr(run, "one_batch_reason", "") or "the mesh tier"
+            if topn and not run.run_wide:
+                # this run's group ids hold for ONE fact batch (a group-by
+                # outside one dimension's key space): bail on sighting a
+                # SECOND (before any device work, without draining the stream)
+                why = run.one_batch_reason
                 first_b = None
                 for part in fact_stream:
                     for b in part.batches:
@@ -1443,9 +1425,8 @@ def _run_device_join(node, label: str, make_run, assemble,
                 # a batch views and not on its objects, hit on a repeat query.
                 # Such morsels are held to DISPATCH_SEGMENTS buckets a device,
                 # fewer where the fact is short (a dispatch is never all of
-                # it: batching.resident_dispatch_segments); the mesh tier's
-                # fused runs keep a bucket: their programs walk no segments.
-                long_run = mesh_stage is None and (shards > 1 or long_chip)
+                # it: batching.resident_dispatch_segments).
+                long_run = shards > 1 or long_chip
                 coalescer = _make_coalescer(
                     run.feed_batch, cfg, shards,
                     resident_rows=(_resident_rows(node.fact) or 0) if long_run else 0)
@@ -1602,26 +1583,22 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
                       topn: bool = False, label: str = "join agg",
                       coalesce: float = 1.0, mesh_ndev: int = 0,
                       forced: bool = False, forced_tier=None,
-                      mesh_forced: bool = False, sharded: bool = False,
-                      mesh_coalesce: float = 1.0):
+                      mesh_forced: bool = False, mesh_coalesce: float = 1.0):
     """Cost-model decision for a DeviceJoinAgg node (see ops/costmodel.py).
     Returns (tier, placement_record) with tier in {"mesh", "chip", False} —
     ALL priced tiers' CostBreakdowns land in the ledger so EXPLAIN PLACEMENT
     can show per-term why a star join cost-rejected to host (the engine's
     headline loss) and what the mesh arm would have cost.
 
-    The mesh arm (mesh_ndev >= 2) prices what will run. `sharded`: the
-    single chip's join dispatch on every shard of the fact
+    The mesh arm (mesh_ndev >= 2: the caller passes a width only where
+    device_join.sharded_join_reason takes the join) prices what will run,
+    the single chip's join dispatch on every shard of the fact
     (ops/device_join.py with `mesh_devices`): the chip arm's own terms at
     rows / width (float32 planes, int32 index planes, no host
     factorization), the round trip shared by the `mesh_coalesce` partitions
     a sharded dispatch covers, and what spanning the devices adds
     (costmodel.over_mesh; a run-wide TopN's tables cross the chips once a
-    run). Else the older fused program (ops/mesh_stage.MeshJoin*Run):
-    per-shard compute ÷ mesh width, the ICI table-merge collective, the
-    multi-device dispatch premium, and its OWN residency picture
-    (native-dtype sharded fact planes + replicated dim planes under mesh
-    slot keys). Mesh must beat BOTH the single chip and the host — same
+    run). Mesh must beat BOTH the single chip and the host — same
     discipline as _mesh_wins. The chip arm is not eligible where the fact's
     planes and the run's tables would not fit one chip's HBM budget, or
     where a fused TopN's tables pass the one chip's ceiling and the fact
@@ -1679,12 +1656,11 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
     nonres += ctx.nonresident_index_bytes(batch, bucket)
     n_gathers = len(dim_cols) + len(spec.dims)  # value planes + visibility
 
-    # mesh arm inputs: native-dtype (~9B/row incl. validity) sharded fact
-    # planes + int64 index/code planes + replicated dim planes, each probed
-    # against its OWN mesh residency slots so a warm mesh repeat prices at
-    # zero transfer like the single-chip arm does
+    # mesh arm inputs, probed against the mesh's OWN residency slots so a
+    # warm mesh repeat prices at zero transfer like the single-chip arm does
     mesh_nonres = mesh_res = 0
-    if mesh_ndev >= 2 and sharded:
+    sharded = mesh_ndev >= 2
+    if sharded:
         from ..ops.stage import MESH_AXIS, mesh_total
 
         # the chip arm's planes in the mesh's layout (a sharded dispatch
@@ -1699,28 +1675,7 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
                 mesh_nonres += batch.num_rows * 5
         mesh_nonres += ctx.nonresident_index_bytes(
             batch, mesh_pad, ("mesh", mesh_ndev, MESH_AXIS))
-    elif mesh_ndev >= 2:
-        per = pad_bucket(max((batch.num_rows + mesh_ndev - 1) // mesh_ndev, 1))
-        mesh_pad = per * mesh_ndev
-        for c in fact_cols:
-            if batch.get_column(c).is_device_resident(
-                    mesh_pad, f32=False, mesh_devices=mesh_ndev):
-                mesh_res += batch.num_rows * 9
-            else:
-                mesh_nonres += batch.num_rows * 9
-        mesh_nonres += mesh_pad * 8 * len(spec.dims)   # int64 index planes
-        for c in dim_cols:
-            side = spec.col_side[c]
-            dim_rows = ctx.batches[side].num_rows
-            src = ctx._dim_source(side, c)
-            if not src.is_device_resident(
-                    pad_bucket(max(dim_rows, 1)), f32=False,
-                    mesh_devices=mesh_ndev, replicated=True):
-                mesh_nonres += dim_rows * 9
 
-    from ..ops.stage import _decompose_agg
-
-    n_slots = sum(len(_decompose_agg(agg.op)) for _n, agg in stage.aggs)
     # Pallas hash-probe what-if arm: total padded table slots over the
     # fact-adjacent dims (the kernel's brute-force probe is rows x slots
     # cells; chained dims keep the host probe, so they contribute none).
@@ -1736,7 +1691,6 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
             probe_slots += t
     chip_ok = True
     mesh_cost = None
-    sharded = sharded and mesh_ndev >= 2
     fact_rows = _resident_rows(node.fact) or rows
     # the sharded dispatch's arm is the chip arm's own terms at a shard's rows
     shard_rows = max(-(-rows // mesh_ndev), 1) if sharded else rows
@@ -1779,11 +1733,12 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
             return False, None
         if wide is None and cap_est > MAX_MATMUL_SEGMENTS and (
                 stage._sct_specs or stage._use_f64):
-            # single-chip-only limitation: the local-dense program cannot
-            # serve 64-bit scatter/f64 stages. The MESH programs reduce in
-            # native dtypes (exact int64), so the mesh arm stays eligible.
+            # the local-dense program (group codes factorized on the host)
+            # cannot serve 64-bit scatter/f64 stages. A mesh arm is priced
+            # only for a join that makes no such codes (sharded_join_reason),
+            # so it stays eligible.
             chip_ok = False
-            if mesh_ndev < 2 and not forced:
+            if not sharded and not forced:
                 _counters.reject(
                     "cost", f"{label}: high-cardinality stage needs 64-bit "
                     "scatter/f64 (no local-dense program)")
@@ -1797,10 +1752,8 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
         if topn:
             k_total = node.topn.offset + node.topn.limit
             fetch = k_total * (n_mm + n_ext + n_sct + 1) * 8
-            mesh_fetch = k_total * (n_slots + 1) * 8
         else:
             fetch = cap_est * (n_mm + n_ext + n_sct) * 8
-            mesh_fetch = cap_est * (n_slots * 2 + 1) * 8
         # one select and one fetch a run: this partition carries its
         # share of them, by its rows over the fact's where those are known
         share = min(rows / max(fact_rows, 1), 1.0)
@@ -1835,8 +1788,6 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
             dev_cost.add("compute",
                          cap_est * max(math.log2(max(cap_est, 2)), 1.0)
                          * nkeys / cal.mm_plane_rows_per_s)
-        if mesh_ndev >= 2 and not sharded:
-            mesh_nonres += mesh_pad * 8        # joined-key codes plane (int64)
         host_cost = costmodel.host_join_agg_cost(
             cal, host_rows, len(spec.dims), len(stage.aggs), True, False)
         if spec.predicate is not None:
@@ -1871,16 +1822,6 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
                         MAX_MATMUL_SEGMENTS, coalesce=mesh_coalesce,
                         resident_bytes=mesh_res),
                     cal, mesh_ndev, fetch, coalesce=mesh_coalesce)
-        elif mesh_ndev >= 2:
-            mesh_cost = costmodel.mesh_join_agg_cost(
-                cal, rows, mesh_nonres // amort, n_gathers, n_slots, cap_est,
-                mesh_ndev, mesh_fetch, rows // amort, coalesce=coal,
-                resident_bytes=mesh_res, grouped=True)
-            if topn:
-                nkeys = len(node.topn.keys) + 2
-                mesh_cost.add("compute",
-                              cap_est * max(math.log2(max(cap_est, 2)), 1.0)
-                              * nkeys / cal.mm_plane_rows_per_s)
         detail = (f"{len(spec.dims)} dims, {len(stage.aggs)} aggs, "
                   f"~{card} joined groups")
     else:
@@ -1905,11 +1846,6 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
                     MAX_MATMUL_SEGMENTS, coalesce=mesh_coalesce,
                     resident_bytes=mesh_res),
                 cal, mesh_ndev, fetch, coalesce=mesh_coalesce)
-        elif mesh_ndev >= 2:
-            mesh_cost = costmodel.mesh_join_agg_cost(
-                cal, rows, mesh_nonres // amort, n_gathers, n_slots, 1,
-                mesh_ndev, fetch, rows // amort, coalesce=coal,
-                resident_bytes=mesh_res, grouped=False)
         detail = f"{len(spec.dims)} dims, {len(stage.aggs)} aggs"
 
     if chip_ok and _resident_source_rec(node.fact):
@@ -3381,7 +3317,7 @@ def _mesh_repartition_exchange(node, batches: List[RecordBatch], rows: int,
     from ..core.series import Series
     from ..ops import counters as _counters
     from ..ops.grouped_stage import DeviceFallback
-    from ..ops.mesh_stage import _shard_np, mesh_row_mask, mesh_total
+    from ..ops.stage import mesh_row_mask, mesh_total, shard_rows
     from ..parallel.distributed import (default_mesh,
                                         sharded_alltoall_repartition_step,
                                         sharded_ring_repartition_step)
@@ -3416,11 +3352,11 @@ def _mesh_repartition_exchange(node, batches: List[RecordBatch], rows: int,
     flat = []
     ici_bytes = 0
     for vals, valid in cols:
-        flat += [_shard_np(mesh, vals, total), _shard_np(mesh, valid, total)]
+        flat += [shard_rows(mesh, vals, total), shard_rows(mesh, valid, total)]
         # the exchanged scratch is [n, S] per shard per plane: every plane
         # crosses the interconnect once at its padded size
         ici_bytes += n * total * vals.dtype.itemsize + n * total
-    args = (_shard_np(mesh, dest, total), mesh_row_mask(mesh, rows, total))
+    args = (shard_rows(mesh, dest, total), mesh_row_mask(mesh, rows, total))
     if ring is not None:
         # a kernel that does not lower raises: no tier replaces it
         step = sharded_ring_repartition_step(mesh, dtypes, interpret=ring)

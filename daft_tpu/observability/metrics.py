@@ -163,7 +163,7 @@ DEVICE_COUNTER_NAMES = (
     "device_join_mesh_batches",  # join dispatches that spanned more than one device
     "device_join_mesh_shards",   # devices summed over those dispatches
     "device_topn_combine_bytes",  # bytes the run-wide tables' cross-chip combines moved between chips
-    "mesh_join_runs",         # device joins whose dispatches spanned a mesh (the sharded dispatch or ops/mesh_stage.py)
+    "mesh_join_runs",         # device joins whose dispatches spanned a mesh (ops/device_join.py, `mesh_devices` > 1)
     # intra-host ICI repartition (jax.lax.all_to_all over the local mesh —
     # the in-mesh replacement for the host shuffle between co-located workers)
     "mesh_alltoall_dispatches",    # all_to_all exchange programs dispatched

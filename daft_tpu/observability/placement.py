@@ -275,6 +275,15 @@ class PlacementLedger:
         if scope is not None:
             scope._add(rec)
 
+    def annotate(self, rec: Optional[PlacementRecord], reason: str) -> None:
+        """Add to a record's reason what the executor learnt beside the
+        pricing (a join a mesh declined runs on one chip): EXPLAIN PLACEMENT
+        shows it on the decision's line."""
+        if rec is None:
+            return
+        with self._lock:
+            rec.reason = f"{rec.reason}; {reason}" if rec.reason else reason
+
     def observe(self, rec: Optional[PlacementRecord], total_s: float,
                 term_seconds: Optional[Dict[str, float]] = None,
                 rows: int = 0, dispatches: int = 0,
@@ -378,8 +387,8 @@ class _TeeSpans(SpanRecorder):
 
 def _span_term(name: str) -> Optional[str]:
     """Map a device span name to its cost-model term: device.h2d /
-    device.udf_h2d / device.mesh_h2d -> h2d, *_dispatch -> dispatch (the
-    rtt + on-device compute window), *_d2h -> d2h."""
+    device.udf_h2d -> h2d, *_dispatch -> dispatch (the rtt + on-device
+    compute window), *_d2h -> d2h."""
     if not name.startswith("device."):
         return None
     leaf = name.rsplit(".", 1)[-1]

@@ -156,9 +156,9 @@ class Calibration:
     host_probe_rate: float        # host hash-join probe rows per sec per dim
     # mesh (multi-chip SPMD) dispatches: one spans every local chip, so it
     # pays an extra multi-device launch and the gathering of a partial from
-    # every shard on top of rtt_s (over_mesh); the join tier's cross-shard
-    # exchange moves bytes over ICI. Defaulted so single-chip call sites can
-    # construct a Calibration without mesh terms.
+    # every shard on top of rtt_s (over_mesh); a run-wide TopN's tables and
+    # a repartition cross the chips over ICI. Defaulted so single-chip call
+    # sites can construct a Calibration without mesh terms.
     ici_bytes_per_s: float = 4.5e10  # per-link ICI collective bandwidth
     mesh_dispatch_s: float = 2e-3    # extra fixed cost of a multi-device dispatch
     # device-UDF tier (ops/udf_stage.py): model-forward throughput on the
@@ -685,36 +685,6 @@ def device_join_pallas_cost(cal: Calibration, rows: int, upload_bytes: int,
             rows * max(probe_slots, 128) / cal.pallas_probe_cell_rate)
     out.add("compute", rows * max(cap_est, 8) * max(n_mm + n_ext + n_sct, 1)
             / cal.pallas_cell_rate)
-    out.add("factorize", factorize_rows / cal.host_factorize_rate)
-    out.add("d2h", fetch_bytes / cal.d2h_bytes_per_s)
-    return out
-
-
-def mesh_join_agg_cost(cal: Calibration, rows: int, nonresident_bytes: int,
-                       n_gathers: int, n_slots: int, cap_est: int,
-                       n_devices: int, fetch_bytes: int, factorize_rows: int,
-                       coalesce: float = 1.0, resident_bytes: int = 0,
-                       grouped: bool = True) -> CostBreakdown:
-    """One mesh-sharded gather-join + aggregate dispatch (ops/mesh_stage.py
-    MeshJoin*Run over the fused parallel/distributed.py program): per-shard
-    gathers + the segment/masked reduce run on rows/N, the cross-shard merge
-    is one psum/pmin/pmax per partial table moving cap x slots x 8 bytes over
-    ICI (ungrouped: scalars), and the dispatch pays the multi-device launch
-    premium on top of the coalesce-amortized round trip. Host factorize work
-    (join indices, joined-key codes) is unchanged by sharding — full rows,
-    amortized by the caller exactly like the single-chip arm."""
-    n = max(n_devices, 1)
-    out = _base_terms(cal, nonresident_bytes, coalesce, resident_bytes)
-    out.add("mesh_dispatch", cal.mesh_dispatch_s)
-    out.add("compute",
-            rows * (max(n_gathers, 1) + max(n_slots, 1))
-            / (cal.mm_plane_rows_per_s * n))
-    if grouped:
-        cap = max(cap_est, 16)
-        out.add("ici", cap * (max(n_slots, 1) + 1) * 8 * n
-                / cal.ici_bytes_per_s)
-    else:
-        out.add("ici", max(n_slots, 1) * 8 * n / cal.ici_bytes_per_s)
     out.add("factorize", factorize_rows / cal.host_factorize_rate)
     out.add("d2h", fetch_bytes / cal.d2h_bytes_per_s)
     return out

@@ -423,8 +423,8 @@ def build_constant_device_expr(expr: Expression, schema: Schema,
                                float_dtype=None) -> Callable[[Dict[str, DCol]], DCol]:
     """Return fn(cols) -> (values, validity) with the literal values of
     `expr` itself as constants of the traced program: for the callers whose
-    compiled programs are kept under the values (the mesh join steps of
-    parallel/distributed.py, a dim filter's visibility plane). The aggregate
+    compiled programs are kept under the values (a dim filter's visibility
+    plane, parallel/distributed.py's aggregate step). The aggregate
     stages keep theirs under the skeleton, use build_device_expr and pass
     each execution's values."""
     fdt = float_dtype or jnp.float64

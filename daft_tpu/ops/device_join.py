@@ -2077,11 +2077,13 @@ def sharded_join_reason(ctx: _JoinContext, stage, grouped: bool, topn: bool,
     then take `mesh_devices`). What is declined needs ids made on the host a
     batch at a time, which the sharded dispatch never makes
     (host_ids_reason). And a join under a forced Pallas hash probe
-    (pallas_mode "on"): that kernel's mesh form is ops/mesh_stage.py's."""
+    (pallas_mode "on"): that kernel probes a whole batch on one chip. A
+    declined join is not over the mesh: it runs on one chip, with this
+    reason in the rejection log (executor._run_device_join)."""
     from ..config import execution_config
 
     if getattr(execution_config(), "pallas_mode", "auto") == "on":
-        return "a forced Pallas hash probe runs on one chip or in the fused mesh tier"
+        return "a forced Pallas hash probe runs on one chip"
     return host_ids_reason(ctx, stage, grouped, topn, batch, mesh_devices)
 
 

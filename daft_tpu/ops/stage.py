@@ -86,7 +86,7 @@ def device_row_mask(n: int, bucket: int, mesh=None):
 
 def mesh_row_mask(mesh, n: int, total: int):
     """device_row_mask over a mesh, under the name and argument order the
-    mesh join tier and the repartition step call it by."""
+    repartition step calls it by."""
     return device_row_mask(n, total, mesh)
 
 

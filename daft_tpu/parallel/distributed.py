@@ -266,260 +266,6 @@ def _shard_map(local, mesh: Mesh, in_specs, out_specs):
                      out_specs=out_specs, check_vma=False)
 
 
-def sharded_gather_step(mesh: Mesh, n_cols: int, axis: str = "dp") -> Callable:
-    """Build the mesh join-feed probe: fact rows row-sharded, dim planes
-    REPLICATED on every device — the probe is a purely local gather (the
-    'broadcast probe' of the two-tier design; no collective until the reduce).
-
-    Returns fn(idx, row_mask, *[(vals, valid) x n_cols flattened]) ->
-    tuple of (gathered_vals, gathered_valid) pairs, row-sharded like `idx`.
-    idx: int64 fact->dim row indices, < 0 = no dim match (inner-join
-    semantics: the row's gathered validity goes False). Output planes feed
-    straight into sharded_groupby_step / sharded_filter_agg-style reduces.
-    """
-    cache_key = ("gather", mesh, n_cols, axis)
-    cached = _STEP_CACHE.get(cache_key)
-    if cached is not None:
-        return cached
-
-    def local(idx, row_mask, *flat):
-        keep = row_mask & (idx >= 0)
-        safe = jnp.maximum(idx, 0)
-        out = []
-        for i in range(n_cols):
-            v, m = flat[2 * i], flat[2 * i + 1]
-            out.append((v[safe], m[safe] & keep))
-        return tuple(out)
-
-    in_specs = tuple([P(axis), P(axis)] + [P()] * (2 * n_cols))
-    out_specs = tuple((P(axis), P(axis)) for _ in range(n_cols))
-    step = jax.jit(_shard_map(local, mesh, in_specs, out_specs))
-    with _CACHE_LOCK:
-        _STEP_CACHE[cache_key] = step
-    return step
-
-
-def sharded_join_agg_step(mesh: Mesh, specs: Sequence[Tuple[str, int]],
-                          n_dims: int, axis: str = "dp") -> Callable:
-    """Sharded star-join fact feed + ungrouped aggregate in ONE program.
-
-    Fact rows are row-sharded along the mesh axis; each dim's value plane is
-    replicated (broadcast) so the probe is a local gather through the dim's
-    sharded fact->dim index plane; the reduce is one ICI collective per
-    partial (psum for sum/count — exact for int64 — pmin/pmax for extremes).
-
-    specs: per aggregate (op, src) with op in {sum, count, mean, min, max}
-    and src = dim index whose replicated value plane the aggregate reads
-    (gathered to fact rows), or -1 for a fact-local row-sharded plane.
-
-    Returns fn(row_mask, idx_planes_tuple, *[(vals, valid) per spec]) ->
-    {(i, partial_op): (value, valid)} replicated — combine across batches on
-    the host with ops.stage._combine_partials.
-    """
-    specs = tuple((op, int(src)) for op, src in specs)
-    cache_key = ("joinagg", mesh, specs, n_dims, axis)
-    cached = _STEP_CACHE.get(cache_key)
-    if cached is not None:
-        return cached
-
-    def local(row_mask, idxs, *flat):
-        keep = row_mask
-        for ix in idxs:
-            keep = keep & (ix >= 0)
-        safe = [jnp.maximum(ix, 0) for ix in idxs]
-        out = {}
-        for i, (op, src) in enumerate(specs):
-            v, m = flat[2 * i], flat[2 * i + 1]
-            if src >= 0:
-                v, m = v[safe[src]], m[safe[src]]
-            mask = m & keep
-            cnt = jax.lax.psum(jnp.sum(mask), axis)
-            for partial in _decompose_agg(op):
-                if partial == "count":
-                    out[(i, "count")] = (cnt, jnp.asarray(True))
-                elif partial == "sum":
-                    pv, _ok = dev.device_agg("sum", v, mask)
-                    out[(i, "sum")] = (jax.lax.psum(pv, axis), cnt > 0)
-                else:  # min / max
-                    big = dev._extreme(v.dtype, partial == "min")
-                    masked = jnp.where(mask, v, big)
-                    red = jnp.min(masked) if partial == "min" else jnp.max(masked)
-                    out[(i, partial)] = (
-                        _pextreme(red, axis, partial == "min"), cnt > 0)
-        return out
-
-    in_specs = (
-        P(axis),
-        tuple(P(axis) for _ in range(n_dims)),
-    ) + tuple(P(axis) if specs[i // 2][1] < 0 else P()
-              for i in range(2 * len(specs)))
-    out_specs = {(i, partial): (P(), P())
-                 for i, (op, _src) in enumerate(specs)
-                 for partial in _decompose_agg(op)}
-    step = jax.jit(_shard_map(local, mesh, in_specs, out_specs))
-    with _CACHE_LOCK:
-        _STEP_CACHE[cache_key] = step
-    return step
-
-
-def _joined_cols(schema, col_specs, idxs, flat):
-    """Shared join-feed plumbing for the fused mesh join programs: assemble
-    the joined column dict (fact planes row-sharded as-is; dim planes
-    replicated, gathered through the per-dim sharded index planes) plus the
-    all-dims-matched inner-join mask. idx < 0 = no dim match; a gathered
-    column's validity additionally drops rows whose OWN dim missed (a row can
-    match dim A but miss dim B — its A-columns stay valid until join_ok
-    kills the row)."""
-    join_ok = None
-    for ix in idxs:
-        ok = ix >= 0
-        join_ok = ok if join_ok is None else (join_ok & ok)
-    cols: Dict[str, Tuple[jnp.ndarray, jnp.ndarray]] = {}
-    for i, (name, src) in enumerate(col_specs):
-        v, m = flat[2 * i], flat[2 * i + 1]
-        if src >= 0:
-            ix = idxs[src]
-            safe = jnp.maximum(ix, 0)
-            v, m = v[safe], m[safe] & (ix >= 0)
-        cols[name] = (v, m)
-    return cols, join_ok
-
-
-def _keep_mask(pred_fn, cols, join_ok, row_mask):
-    keep = row_mask if join_ok is None else (row_mask & join_ok)
-    if pred_fn is not None:
-        pv, pm = pred_fn(cols)
-        keep = keep & pv.astype(bool) & pm
-    return keep
-
-
-def sharded_join_ungrouped_stage_step(mesh: Mesh, schema: Schema,
-                                      predicate: Optional[Expression],
-                                      col_specs: Sequence[Tuple[str, int]],
-                                      agg_specs: Sequence[Tuple[str, str, bool, Expression]],
-                                      n_dims: int, axis: str = "dp") -> Callable:
-    """Fused mesh star-join fact feed, ungrouped: gather + predicate +
-    partial aggregates + ICI reduce in ONE program.
-
-    Fact rows (and the per-dim fact->dim index planes) are row-sharded along
-    the mesh axis; dim value planes are replicated so the probe is a purely
-    local gather (the broadcast-probe half of SURVEY §7's two-tier shuffle
-    mapping). The cross-shard exchange is one psum/pmin/pmax per partial —
-    exact for int64 sums, which accumulate in int64 end to end.
-
-    col_specs: (column name, src) with src = the dim index plane the column
-    gathers through, or -1 for a fact-local row-sharded plane.
-    agg_specs: (name, op, count_all, child expression) per aggregate.
-    Returns fn(row_mask, idxs_tuple, *flat) -> {(name, partial): (val, ok)}
-    replicated — combined across batches with ops.stage._combine_partials.
-    """
-    pred_fn = dev.build_constant_device_expr(predicate, schema) \
-        if predicate is not None else None
-    built = [(name, op, count_all, dev.build_constant_device_expr(child, schema))
-             for name, op, count_all, child in agg_specs]
-    col_specs = tuple((str(n), int(s)) for n, s in col_specs)
-
-    def local(row_mask, idxs, *flat):
-        cols, join_ok = _joined_cols(schema, col_specs, idxs, flat)
-        keep = _keep_mask(pred_fn, cols, join_ok, row_mask)
-        out = {}
-        for name, op, count_all, child_fn in built:
-            v, m = child_fn(cols)
-            mask = dev._broadcast_valid(v, m) & keep
-            if count_all:
-                mask = dev._broadcast_valid(v, keep)
-            cnt = jax.lax.psum(jnp.sum(mask), axis)
-            for partial in _decompose_agg(op):
-                if partial == "count":
-                    out[(name, "count")] = (cnt, jnp.asarray(True))
-                elif partial == "sum":
-                    pv, _ok = dev.device_agg("sum", v, mask)
-                    out[(name, "sum")] = (jax.lax.psum(pv, axis), cnt > 0)
-                else:  # min / max
-                    big = dev._extreme(v.dtype, partial == "min")
-                    masked = jnp.where(mask, v, big)
-                    red = jnp.min(masked) if partial == "min" \
-                        else jnp.max(masked)
-                    out[(name, partial)] = (
-                        _pextreme(red, axis, partial == "min"), cnt > 0)
-        return out
-
-    in_specs = (
-        P(axis),
-        tuple(P(axis) for _ in range(n_dims)),
-    ) + tuple(P(axis) if col_specs[i // 2][1] < 0 else P()
-              for i in range(2 * len(col_specs)))
-    out_specs = {(name, partial): (P(), P())
-                 for name, op, _ca, _child in built
-                 for partial in _decompose_agg(op)}
-    return jax.jit(_shard_map(local, mesh, in_specs, out_specs))
-
-
-def sharded_join_grouped_stage_step(mesh: Mesh, schema: Schema,
-                                    predicate: Optional[Expression],
-                                    col_specs: Sequence[Tuple[str, int]],
-                                    slot_specs: Sequence[Tuple[str, bool, Expression]],
-                                    capacity: int, n_dims: int,
-                                    axis: str = "dp") -> Callable:
-    """Fused mesh star-join fact feed, grouped: gather + predicate + DENSE
-    group-code segment reduce + ICI table merge in ONE program.
-
-    Group codes come from the host factorize of the JOINED keys (dense
-    first-occurrence ids, exact true group count — any key dtype), so the
-    per-shard reduce is a straight segment_sum/min/max into a [capacity+1]
-    table (no sort, no unique, no searchsorted: dense codes ARE the segment
-    ids) and the cross-shard 'shuffle' is one psum (sum/count) or pmin/pmax
-    (extremes) per partial table — the ICI replacing the host repartition
-    that a two-phase host groupby would pay.
-
-    slot_specs: (partial_op, count_all, child expression) per kernel slot —
-    aggregates arrive decomposed (mean -> sum+count) so per-batch tables
-    merge exactly on host across the stream.
-    Returns fn(codes, row_mask, idxs_tuple, *flat) ->
-      (rows[cap] int64, overflow scalar, ((vals[cap], ok[cap]) per slot))
-    replicated; rows = real joined rows per group (group_valid = rows > 0).
-    """
-    pred_fn = dev.build_constant_device_expr(predicate, schema) \
-        if predicate is not None else None
-    built = [(op, count_all, dev.build_constant_device_expr(child, schema))
-             for op, count_all, child in slot_specs]
-    col_specs = tuple((str(n), int(s)) for n, s in col_specs)
-    cap1 = capacity + 1  # spare slot: masked/garbage codes land there
-
-    def local(codes, row_mask, idxs, *flat):
-        cols, join_ok = _joined_cols(schema, col_specs, idxs, flat)
-        keep = _keep_mask(pred_fn, cols, join_ok, row_mask)
-        in_range = (codes >= 0) & (codes < capacity)
-        seg = jnp.where(keep & in_range, codes, capacity)
-        rows = jax.lax.psum(
-            _segment_reduce("count", codes, keep & in_range, seg, cap1), axis)
-        overflow = jax.lax.psum(jnp.sum(keep & ~in_range), axis) > 0
-        results = []
-        for op, count_all, child_fn in built:
-            v, m = child_fn(cols)
-            mask = dev._broadcast_valid(v, keep) if count_all \
-                else dev._broadcast_valid(v, m) & keep
-            table = _segment_reduce(op, v, mask, seg, cap1)
-            cnt = jax.lax.psum(
-                _segment_reduce("count", v, mask, seg, cap1), axis)
-            if op in ("sum", "count"):
-                merged = jax.lax.psum(table, axis)
-            else:
-                merged = _pextreme(table, axis, op == "min")
-            ok = cnt > 0 if op != "count" else jnp.ones(cap1, dtype=bool)
-            results.append((merged[:capacity], ok[:capacity]))
-        return rows[:capacity], overflow, tuple(results)
-
-    in_specs = (
-        P(axis),
-        P(axis),
-        tuple(P(axis) for _ in range(n_dims)),
-    ) + tuple(P(axis) if col_specs[i // 2][1] < 0 else P()
-              for i in range(2 * len(col_specs)))
-    out_specs = (P(), P(), tuple((P(), P()) for _ in built))
-    return jax.jit(_shard_map(local, mesh, in_specs, out_specs))
-
-
 def sharded_alltoall_repartition_step(mesh: Mesh, dtypes: Sequence,
                                       axis: str = "dp") -> Callable:
     """Intra-host repartition over ICI: each shard stable-sorts its rows by

@@ -59,7 +59,6 @@ TIER_FORBIDDEN = (
     "daft_tpu.checkpoint.stages",
     "daft_tpu.ops.stage",
     "daft_tpu.ops.grouped_stage",
-    "daft_tpu.ops.mesh_stage",
     "daft_tpu.ops.udf_stage",
     "daft_tpu.ops.device_join",
     "daft_tpu.ops.device_eval",
@@ -75,7 +74,6 @@ TIER_MEMBERS = (
     "daft_tpu.utils.jax_setup",
     "daft_tpu.ops.stage",
     "daft_tpu.ops.grouped_stage",
-    "daft_tpu.ops.mesh_stage",
     "daft_tpu.ops.udf_stage",
     "daft_tpu.ops.device_join",
     "daft_tpu.ops.device_eval",

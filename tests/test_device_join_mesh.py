@@ -15,6 +15,7 @@ from daft_tpu.config import execution_config_ctx
 from daft_tpu.ops import counters
 
 import test_device_join as tj
+import test_mesh_join as tm
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 4, reason="needs 4 (virtual) devices: see conftest")
@@ -345,29 +346,140 @@ def test_the_table_ceiling_is_held_to_a_chips_share_of_the_ids(topn_tables, monk
     assert c4.get("device_topn_runs", 0) == 0 and c4.get("device_join_mesh_batches", 0) == 0
 
 
-def test_what_the_sharded_dispatch_declines_keeps_the_fused_mesh_tier(tpch_like):
-    """A grouped join whose group-by does not dictionary-encode under the
-    matmul ceiling needs ids factorized on the host a batch at a time: the
-    sharded dispatch declines it (sharded_join_reason) and the older fused
-    tier of ops/mesh_stage.py runs it."""
-    import daft_tpu.ops.device_join as dj
+# ---- (e) what the sharded dispatch declines runs on one chip -------------------------------
 
-    t = tpch_like
+WIDE = 8    # (conftest's eight virtual devices)
 
-    def q():    # grouped by the order key: as many groups as orders
-        return (t["orders"].join(t["lineitem"], left_on="o_orderkey", right_on="l_orderkey")
-                .groupby("o_orderkey").agg(col("l_extendedprice").sum().alias("s"))
-                .sort("o_orderkey"))
 
-    with execution_config_ctx(device_mode="off"):
-        host = q().to_pydict()
+star = tm.star      # (a fact of one batch with int64 values past 2^53, a dimension grouped by a column that is not its key)
+
+
+def _declined(shape, tpch_like, star):
+    """(query, site, config, sharded_join_reason's words) of a join the
+    sharded dispatch declines: group codes that need a host factorization (as
+    many groups as orders), a fused TopN whose ids hold for one batch (grouped
+    by a dimension's column that is not its key), a forced Pallas probe."""
+    t, (fact, dim) = tpch_like, star
+    joined = lambda: fact.join(dim, left_on="fk", right_on="dk")
+    if shape == "host_codes":
+        q = lambda: (t["orders"].join(t["lineitem"], left_on="o_orderkey", right_on="l_orderkey")
+                     .groupby("o_orderkey").agg(col("l_extendedprice").sum().alias("s"))
+                     .sort("o_orderkey"))
+        return q, "join agg", {}, "the group codes need a host factorization of every batch"
+    if shape == "one_batch_topn":
+        q = lambda: (joined().groupby("grp").agg(col("qty").sum().alias("s"))
+                     .sort("s", desc=True).limit(3))
+        return q, "join topn", {}, "no dimension's key with its own columns spans the group-by"
+    q = lambda: (joined().groupby("grp")
+                 .agg(col("qty").sum().alias("s"), col("big").sum().alias("sb")).sort("grp"))
+    return q, "join agg", {"pallas_mode": "on"}, "a forced Pallas hash probe runs on one chip"
+
+
+def _assert_answers(host, got):
+    assert host.keys() == got.keys()
+    for name, want in host.items():
+        if name == "s" and isinstance(want[0], float):
+            np.testing.assert_allclose(got[name], want, rtol=1e-6)
+        else:
+            assert got[name] == want, name      # (2^53-scale int64 sums included)
+
+
+SHAPES = ["host_codes", "one_batch_topn", "forced_pallas"]
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+def test_a_join_the_sharded_dispatch_declines_runs_on_one_chip(tpch_like, star, shape):
+    """A forced mesh of eight and a join the sharded dispatch declines: no
+    dispatch spans the mesh, the join runs on one chip and answers as the
+    host does, and the rejection log and the placement record carry
+    sharded_join_reason's words."""
+    from daft_tpu.observability import placement
+
+    q, site, extra, words = _declined(shape, tpch_like, star)
+    host = tj._host_answer(q)
     counters.reset()
-    with execution_config_ctx(device_mode="on", mesh_devices=MESH, device_min_rows=1):
-        mesh = q().to_pydict()
+    with execution_config_ctx(device_mode="on", mesh_devices=WIDE, device_min_rows=1, **extra):
+        with placement.query_scope() as scope:
+            got = q().to_pydict()
     snap = counters.snapshot()
-    assert snap.get("device_join_mesh_batches", 0) == 0
-    if snap.get("mesh_join_runs", 0):     # the fused tier built for this shape
-        assert snap.get("mesh_dispatches", 0) > 0
-    assert mesh["o_orderkey"] == host["o_orderkey"]
-    np.testing.assert_allclose(mesh["s"], host["s"], rtol=1e-6)
-    assert dj.sharded_join_reason.__doc__
+    assert snap.get("device_join_mesh_batches", 0) == 0 and snap.get("mesh_join_runs", 0) == 0
+    assert snap.get("mesh_dispatches", 0) == 0 and snap.get("mesh_unavailable_fallbacks", 0) == 0
+    assert snap["device_join_batches"] > 0, counters.rejections
+    assert snap.get("device_topn_runs", 0) == (shape == "one_batch_topn")
+    assert snap.get("pallas_probe_dispatches", 0) == (shape == "forced_pallas")
+    _assert_answers(host, got)
+    assert ("runtime", f"{site}: not sharded over the mesh ({words})") in counters.rejection_log
+    rec, = [r for r in scope.to_dicts() if r["site"] == site]
+    assert rec["chosen"] == "device" and rec["forced"]
+    assert rec["reason"] == f"not sharded over the mesh: {words}"
+    assert words in placement.render(scope.records())    # (explain_placement()'s text)
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+def test_auto_prices_a_declined_join_as_a_one_chip_host_does(tpch_like, star, shape, monkeypatch):
+    """`auto` on a (simulated) accelerator host of eight devices, under terms
+    that make a mesh win every join it is offered (tests/test_mesh_join.py):
+    a declined join is priced chip against host, with no mesh arm, and where
+    the chip wins its run is built for one device (the test stops there: what
+    a chip then answers is the test above, and the kernels of a simulated
+    chip do not lower)."""
+    import daft_tpu.ops.device_join as dj
+    from daft_tpu.execution import executor
+    from daft_tpu.observability import placement
+    from daft_tpu.ops import costmodel
+
+    q, site, extra, words = _declined(shape, tpch_like, star)
+    # (and a host slow enough that one chip can win too)
+    pins = dict(tm._MESH_WINS_PINS, DAFT_TPU_COST_HOST_AGG="1e4", DAFT_TPU_COST_HOST_PROBE="1e4")
+    for k, v in pins.items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    built = []
+
+    class _Decided(Exception):
+        pass
+
+    def stop(self, stage, ctx, mesh_devices=1, *a, **k):
+        built.append(mesh_devices)
+        raise _Decided
+
+    monkeypatch.setattr(dj.DeviceJoinGroupedRun, "__init__", stop)
+    costmodel.reset_calibration()
+    executor._DECISION_CACHE.clear()
+    try:
+        counters.reset()
+        with execution_config_ctx(device_mode="auto", mesh_devices=0, device_min_rows=1, **extra):
+            with placement.query_scope() as scope:
+                try:
+                    q().to_pydict()
+                except _Decided:
+                    pass
+        rec, = [r for r in scope.to_dicts() if r["site"] == site]
+        assert rec["chosen"] in ("device", "host") and "mesh" not in rec
+        assert rec["device"]["total"] > 0 and rec["host"]["total"] > 0
+        assert rec["reason"] == f"not sharded over the mesh: {words}"
+        assert built == ([1] if rec["chosen"] == "device" else [])
+        if shape != "host_codes":   # (as many groups as orders: the host's under any terms)
+            assert rec["chosen"] == "device"
+        assert ("runtime", f"{site}: not sharded over the mesh ({words})") in counters.rejection_log
+    finally:
+        costmodel.reset_calibration()
+        executor._DECISION_CACHE.clear()
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+def test_a_declined_joins_repeat_uploads_nothing(tpch_like, star, shape):
+    """The one chip a declined join runs on keeps its planes: the repeat of
+    the query under the forced mesh moves no byte to the device and builds no
+    slot."""
+    from daft_tpu.observability.metrics import registry
+
+    q, _site, extra, _words = _declined(shape, tpch_like, star)
+    with execution_config_ctx(device_mode="on", mesh_devices=WIDE, device_min_rows=1, **extra):
+        first = q().to_pydict()
+        h2d, misses = registry().get("hbm_h2d_bytes"), registry().get("hbm_cache_misses")
+        joins = registry().get("device_join_batches")
+        again = q().to_pydict()
+    assert again == first and registry().get("device_join_batches") > joins
+    assert registry().get("hbm_h2d_bytes") == h2d, "the repeat uploaded planes"
+    assert registry().get("hbm_cache_misses") == misses

@@ -90,7 +90,7 @@ def test_perf_md_spans_and_counters_exist():
     assert len(names) > 40, names
     missing = []
     for n in names:
-        prefix = re.split(r"[*<]", n)[0]      # `op.<Node>`, `device.mesh_*`
+        prefix = re.split(r"[*<]", n)[0]      # `op.<Node>`, `device.udf_*`
         if not re.search(r"[\"']" + re.escape(prefix) + (r"[\"']" if prefix == n else ""), src):
             missing.append(n)
     assert not missing, f"PERF.md §3 names what daft_tpu/ never emits: {missing}"

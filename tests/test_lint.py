@@ -312,8 +312,8 @@ def f():
 
 def test_import_discipline_tier_member_exempt():
     src = "import jax\nfrom .stage import pad_bucket\n"
-    findings = lint_source(src, rel="daft_tpu/ops/mesh_stage.py",
-                           module="daft_tpu.ops.mesh_stage")
+    findings = lint_source(src, rel="daft_tpu/ops/grouped_stage.py",
+                           module="daft_tpu.ops.grouped_stage")
     assert "import-discipline" not in rules_of(findings)
 
 

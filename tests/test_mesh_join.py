@@ -1,15 +1,17 @@
-"""Mesh-sharded device joins (ops/mesh_stage.py MeshJoin*Run) under 8 forced
-host devices — the r15 tentpole: star joins as a first-class mesh tier.
+"""Star joins over a mesh of local devices (ops/device_join.py with
+`mesh_devices` > 1: the single chip's join dispatch on every shard of the
+fact) under 8 forced host devices.
 
-Covers: 3-way bit-identity (mesh vs single-chip vs host) for grouped /
-ungrouped / TopN join shapes including int64 exactness and null group keys,
-dim-filter visibility folding, repeat-query h2d-flat dim planes (including
-the filtered/unfiltered slot-thrash regression), tiny-HBM-budget pin safety,
-the loud forced-mesh-unavailable fallback, the three-tier cost decision with
-all three CostBreakdowns in the placement ledger, the intra-host all_to_all
-repartition (bit-identical partitions, zero shuffle wire bytes), the mesh
-join cost function, the calibrate tool's mesh-term suggestions, and the
-persistent-compile-cache knob. Run standalone via `make test-mesh`.
+Covers: 3-way identity (mesh vs single-chip vs host) for grouped and ungrouped
+join shapes including int64 exactness and null group keys, dim-filter
+visibility, repeat-query h2d-flat planes (including the filtered/unfiltered
+slot-thrash regression), tiny-HBM-budget pin safety, the loud
+forced-mesh-unavailable fallback, the three-tier cost decision with all three
+CostBreakdowns in the placement ledger, the intra-host all_to_all repartition
+(bit-identical partitions, zero shuffle wire bytes), the calibrate tool's
+mesh-term suggestions, and the persistent-compile-cache knob. What the
+sharded dispatch declines is in tests/test_device_join_mesh.py. Run
+standalone via `make test-mesh`.
 """
 
 import os
@@ -124,29 +126,6 @@ def test_ungrouped_mesh_join_parity(star):
     assert mesh["c"] == host["c"]
     assert mesh["lo"] == host["lo"] and mesh["hi"] == host["hi"]
     np.testing.assert_allclose(mesh["m"], host["m"], rtol=1e-12)
-
-
-def test_topn_mesh_join_parity(star):
-    """Fused TopN join on the mesh: only K winners fetch; order, keys and
-    aggregates match the host engine exactly (integer sums -> exact in any
-    reduction order)."""
-    fact, dim = star
-
-    def q():
-        return (fact.join(dim, left_on="fk", right_on="dk")
-                .groupby("grp")
-                .agg(col("qty").sum().alias("s"))
-                .sort("s", desc=True).limit(3))
-
-    with execution_config_ctx(device_mode="off"):
-        host = q().to_pydict()
-    counters.reset()
-    with execution_config_ctx(device_mode="on", mesh_devices=8,
-                              device_min_rows=1):
-        mesh = q().to_pydict()
-    assert counters.mesh_join_runs > 0
-    assert counters.device_topn_runs > 0
-    assert mesh == host
 
 
 def test_repeat_join_queries_h2d_flat(star):
@@ -289,29 +268,6 @@ def test_auto_join_host_reject_still_prices_mesh_arm(star, monkeypatch):
     finally:
         costmodel.reset_calibration()
         executor._DECISION_CACHE.clear()
-
-
-def test_mesh_join_cost_function_scales():
-    """Unit sanity: the mesh join amortizes gather+reduce compute by the mesh
-    width but pays the dispatch premium and the ICI table merge."""
-    from daft_tpu.ops import costmodel
-
-    cal = costmodel.Calibration(
-        rtt_s=0.001, h2d_bytes_per_s=1e9, d2h_bytes_per_s=1e9,
-        mm_plane_rows_per_s=1e9, mm_cell_rate=5e10, scatter_rows_per_s=1e8,
-        ext_cell_rate=5e9, host_agg_rate=1.5e8, host_factorize_rate=8e6,
-        host_probe_rate=3e7, ici_bytes_per_s=4.5e10, mesh_dispatch_s=2e-3)
-    small = costmodel.mesh_join_agg_cost(cal, 10_000, 0, 2, 2, 64, 8,
-                                         1024, 0)
-    single_small = costmodel.device_join_agg_cost(cal, 10_000, 0, 2, 1, 0, 0,
-                                                  64, 1024, 0)
-    assert small > single_small, "tiny joins must not prefer the mesh"
-    big = costmodel.mesh_join_agg_cost(cal, 800_000_000, 0, 4, 3, 4096, 8,
-                                       1 << 16, 0)
-    big_single = costmodel.device_join_agg_cost(cal, 800_000_000, 0, 4, 2, 1,
-                                                0, 4096, 1 << 16, 0)
-    assert big < big_single, "huge joins must amortize across the mesh"
-    assert {"mesh_dispatch", "ici", "compute"} <= set(big.terms)
 
 
 # ---- intra-host all_to_all repartition -----------------------------------------------
@@ -464,8 +420,9 @@ def test_join_aggregate_input_literals_are_this_querys(star, grouped, mesh_devic
     """The join tiers take a query's aggregates from the query: the
     aggregate stage that says whether the shape qualifies is kept under the
     skeleton (ops/stage.bind_filter_agg_stage), so its expressions are those
-    of the FIRST query of the shape, and a tier that compiles values in (the
-    mesh join steps) must not read them there. Two queries of one skeleton,
+    of the FIRST query of the shape, and a tier that compiled values in (the
+    fused mesh join steps did, until PR 44) must not read them there. Two
+    queries of one skeleton,
     another literal inside the aggregates' inputs and in the predicate, each
     against the host's answer."""
     fact, dim = star

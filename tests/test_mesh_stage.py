@@ -1,6 +1,6 @@
 """The filter-aggregate and grouped-aggregate stages over a mesh of local
-devices (ops/stage.py, ops/grouped_stage.py with ``mesh_devices`` > 1) and
-the sharded join feed (ops/mesh_stage.py), under 8 forced host devices.
+devices (ops/stage.py, ops/grouped_stage.py with ``mesh_devices`` > 1), under
+8 forced host devices.
 
 A sharded run is the single chip's program on every shard: the answers equal
 the plain reference's (benchmark/reference/tpch.py, loaded by path) inside
@@ -15,7 +15,6 @@ the host, and the off switch. Run standalone via `make test-mesh`.
 import importlib.util
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -481,59 +480,6 @@ def test_the_budget_reckons_a_sharded_plane_per_device(ndev):
         == host.nbytes // ndev + host.nbytes
 
 
-# ---- sharded join feed ---------------------------------------------------------------
-
-
-def test_sharded_join_feed_ungrouped_int64_exact():
-    """Fact rows sharded, dim planes replicated: probe = local gather,
-    reduce = psum over ICI. int64 dim sums must be bit-exact."""
-    from daft_tpu.ops.mesh_stage import mesh_join_ungrouped_agg
-    from daft_tpu.parallel.distributed import default_mesh
-
-    mesh = default_mesh(8)
-    rng = np.random.default_rng(0)
-    n, dim_n = 10_000, 64
-    idx = rng.integers(-1, dim_n, n).astype(np.int64)  # -1 = no match
-    dim_vals = (2**53 + rng.integers(0, 10_000, dim_n)).astype(np.int64)
-    fact_vals = rng.normal(size=n)
-    fact_valid = rng.random(n) > 0.1
-    before = counters.mesh_dispatches
-    res = mesh_join_ungrouped_agg(
-        mesh, n, [idx],
-        [(dim_vals, np.ones(dim_n, bool)), (fact_vals, fact_valid),
-         (dim_vals, np.ones(dim_n, bool))],
-        [("sum", 0), ("mean", -1), ("max", 0)])
-    assert counters.mesh_dispatches > before
-    keep = idx >= 0
-    assert res[0] == int(dim_vals[idx[keep]].sum()), "int64 join sum not exact"
-    np.testing.assert_allclose(
-        res[1], fact_vals[keep & fact_valid].mean(), rtol=1e-12)
-    assert res[2] == int(dim_vals[idx[keep]].max())
-
-
-def test_sharded_join_feed_grouped_matches_numpy():
-    """Grouped join feed: dim group codes gathered to fact rows (broadcast
-    probe), exact sharded groupby merges per-shard tables over ICI."""
-    from daft_tpu.ops.mesh_stage import mesh_join_grouped_agg
-    from daft_tpu.parallel.distributed import default_mesh
-
-    mesh = default_mesh(8)
-    rng = np.random.default_rng(1)
-    n, dim_n, n_codes = 8_000, 50, 7
-    idx = rng.integers(-1, dim_n, n).astype(np.int64)
-    dim_codes = rng.integers(0, n_codes, dim_n).astype(np.int64)
-    fact_vals = (2**53 + rng.integers(0, 1000, n)).astype(np.int64)
-    gk, cols = mesh_join_grouped_agg(
-        mesh, n, idx, dim_codes,
-        [(fact_vals, np.ones(n, bool), -1)], ["sum"], num_codes=n_codes)
-    keep = idx >= 0
-    codes = dim_codes[idx[keep]]
-    expected = {int(c): int(fact_vals[keep][codes == c].sum())
-                for c in np.unique(codes)}
-    got = dict(zip(gk.tolist(), cols[0][0].tolist()))
-    assert got == expected, "grouped join feed not bit-exact"
-
-
 # ---- the tier decision ---------------------------------------------------------------
 
 
@@ -680,35 +626,23 @@ def test_config_rejects_negative_mesh_devices():
 
 
 def test_mesh_off_means_no_mesh_imports():
-    """mesh_devices=1 (the off switch): a device query must not import the
-    mesh join machinery nor build a mesh."""
+    """mesh_devices=1 (the off switch): a device query builds no mesh. And
+    there is no mesh module to import: the stages over a mesh are the single
+    chip's (ops/stage.py, ops/grouped_stage.py, ops/device_join.py with
+    ``mesh_devices`` > 1); the fused mesh join tier and its steps are gone."""
     from daft_tpu.parallel import distributed
 
-    sys.modules.pop("daft_tpu.ops.mesh_stage", None)
     distributed._MESH_CACHE.clear()
     df = daft_tpu.from_pydict({"k": ["a", "b"] * 50, "v": list(range(100))})
     with execution_config_ctx(device_mode="on", mesh_devices=1):
         df.groupby("k").agg(col("v").sum().alias("s")).to_pydict()
         df.agg(col("v").sum().alias("s")).to_pydict()
-    assert "daft_tpu.ops.mesh_stage" not in sys.modules, \
-        "mesh stage imported with the mesh disabled"
     assert not distributed._MESH_CACHE, "a mesh was built with the mesh disabled"
-
-
-def test_a_sharded_aggregate_needs_no_mesh_join_module():
-    """The sharded aggregate stages live with the single chip's: a forced
-    mesh runs them without ops/mesh_stage.py (the join tier's)."""
-    sys.modules.pop("daft_tpu.ops.mesh_stage", None)
-    df = daft_tpu.from_pydict({"k": ["a", "b"] * 50, "v": list(range(100))})
-    with execution_config_ctx(device_mode="on", mesh_devices=2):
-        df.groupby("k").agg(col("v").sum().alias("s")).to_pydict()
-    assert "daft_tpu.ops.mesh_stage" not in sys.modules
-    import daft_tpu.ops.mesh_stage as ms
-
-    for gone in ("MeshFilterAggStage", "MeshFilterAggRun", "MeshGroupedStage",
-                 "MeshGroupedRun", "_host_filter_batch", "_batch_group_codes",
-                 "_cached_code_plane", "_value_planes"):
-        assert not hasattr(ms, gone), gone
+    assert importlib.util.find_spec("daft_tpu.ops.mesh_stage") is None
+    for gone in ("sharded_gather_step", "sharded_join_agg_step", "_joined_cols",
+                 "_keep_mask", "sharded_join_ungrouped_stage_step",
+                 "sharded_join_grouped_stage_step"):
+        assert not hasattr(distributed, gone), gone
 
 
 # ---- EXPLAIN ANALYZE -----------------------------------------------------------------

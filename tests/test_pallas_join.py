@@ -331,56 +331,6 @@ def test_device_join_probe_failure_replays_on_host_tier(monkeypatch):
     assert counters.pallas_probe_dispatches == 0
 
 
-@needs_mesh
-def test_mesh_join_probe_end_to_end_parity():
-    """Mesh star join: the sharded index plane builds through the probe
-    kernel inside the shard_map program; a filtered dim declines the kernel
-    (host visibility folding) and stays identical."""
-    rng = np.random.default_rng(11)
-    n_fact, n_dim = 12_000, 60
-    fact = daft_tpu.from_pydict({
-        "fk": rng.integers(0, n_dim + 5, n_fact).tolist(),
-        "qty": rng.integers(0, 50, n_fact).tolist(),
-        "big": (2**53 + rng.integers(0, 1000, n_fact)).tolist(),
-    })
-    dim = daft_tpu.from_pydict({
-        "dk": list(range(n_dim)),
-        "grp": [None if i % 13 == 0 else f"g{i % 7}" for i in range(n_dim)],
-        "weight": [float(i % 11) for i in range(n_dim)],
-    })
-
-    def q():
-        return (fact.join(dim, left_on="fk", right_on="dk")
-                .groupby("grp")
-                .agg(col("qty").sum().alias("sq"),
-                     col("big").sum().alias("sb"))
-                .sort("grp").collect())
-
-    with execution_config_ctx(device_mode="off"):
-        host = q().to_pydict()
-    counters.reset()
-    with execution_config_ctx(device_mode="on", mesh_devices=8,
-                              pallas_mode="on"):
-        mesh_out = q().to_pydict()
-    snap = counters.snapshot()
-    assert snap.get("mesh_join_runs", 0) > 0
-    assert snap.get("pallas_probe_dispatches", 0) > 0
-    assert host == mesh_out
-
-    def qf():
-        return (fact.join(dim, left_on="fk", right_on="dk")
-                .where(col("weight") < 8)
-                .groupby("grp").agg(col("qty").sum().alias("sq"))
-                .sort("grp").collect())
-
-    with execution_config_ctx(device_mode="off"):
-        host_f = qf().to_pydict()
-    with execution_config_ctx(device_mode="on", mesh_devices=8,
-                              pallas_mode="on"):
-        mesh_f = qf().to_pydict()
-    assert host_f == mesh_f
-
-
 # ---- widened groupby eligibility ---------------------------------------------
 
 
