@@ -63,8 +63,10 @@ Design:
 
 - Observability: hbm_cache_hits / hbm_cache_misses / hbm_lineage_hits (hits
   under another object than the one the entry was built under: what only
-  lineage could find) / hbm_evictions /
-  hbm_eviction_bytes / hbm_pins counters plus hbm_bytes_resident /
+  lineage could find) / hbm_literal_rebuilds (an entry found under its key
+  whose literals differed and which is therefore rebuilt in place: what a
+  query's value costs where no program takes it as an argument) /
+  hbm_evictions / hbm_eviction_bytes / hbm_pins counters plus hbm_bytes_resident /
   hbm_bytes_high_water gauges in the process metrics registry
   (observability/metrics.py), so per-query deltas land in QueryEnd.metrics,
   EXPLAIN ANALYZE's engine-counter table and worker heartbeats.
@@ -478,9 +480,12 @@ class ResidencyManager:
         with self._lock:
             self._sweep_dead()
             e = self._entries.get(full_key)
-            if e is not None and _same_deps(e.deps, deps) \
-                    and e.literals == literals:
-                return True, self._hit(full_key, e, anchor)
+            if e is not None and _same_deps(e.deps, deps):
+                if e.literals == literals:
+                    return True, self._hit(full_key, e, anchor)
+                # the slot of this query SHAPE holds another query's values:
+                # what a value costs where it is no program's argument
+                registry().inc("hbm_literal_rebuilds")
         # only now, the identity probe having missed, is the column hashed:
         # a hit never pays for a fingerprint (outside the lock: it reads the
         # whole column)

@@ -26,7 +26,7 @@ import numpy as np
 from ..core import relational as rel
 from ..core.micropartition import MicroPartition
 from ..core.recordbatch import RecordBatch
-from ..device.residency import identity_token
+from ..device.residency import expr_structure, identity_token
 from ..expressions import ColumnRef, Expression
 from ..expressions.eval import eval_expression, eval_projection
 from ..observability import placement as _placement
@@ -1548,9 +1548,11 @@ def _decision_key(node, rows: int, cfg, topn: bool, layout: tuple) -> tuple:
         cfg.batch_fill_target, cfg.morsel_size_rows, layout,
         # the mesh arm reads the mesh knob: flipping it re-decides the tier
         cfg.mesh_devices,
-        # with its literals' values: _device_join_wins prices the host arm by
-        # the predicate's selectivity, which it reads from them
-        repr(spec.predicate),
+        # the predicate's skeleton, not its values: _join_device_wins prices
+        # the host arm by plan/stats.selectivity, which reads the operators
+        # and never a literal, so another DATE is the same decision (an is_in
+        # of another length is another skeleton)
+        None if spec.predicate is None else expr_structure(spec.predicate)[0],
         tuple(repr(g) for g in spec.groupby),
         tuple(repr(a) for a in spec.aggregations),
         tuple((d.key_col, d.parent) for d in spec.dims),

@@ -148,6 +148,11 @@ DEVICE_COUNTER_NAMES = (
     "join_provision_calls",    # join dispatches whose columns came from one traced program
     "join_provision_traces",   # provisioning programs traced (0 on a repeat query shape)
     "join_window_gathers",     # adjacent-dimension gathers that read a batch-long window of the pack, summed over those dispatches
+    # a dim filter's literal values are arguments of the subtree's visibility
+    # program (ops/device_join.py verdict_plane): traced for a list of filter
+    # skeletons, a dimension length and a mesh width, never for a value
+    "join_filter_program_traces",  # visibility programs traced (0 on a repeat query shape)
+    "join_filter_literal_args",    # literal values passed to visibility programs, summed over calls
     "device_topn_runs",        # join+agg+TopN fused device programs completed
     # the fused TopN's group tables stay on the device for a whole run where
     # the group-by spans one dimension's key space (ops/device_join.py
@@ -187,6 +192,7 @@ DEVICE_COUNTER_NAMES = (
     "hbm_cache_hits",          # residency lookups served from HBM
     "hbm_cache_misses",        # residency lookups that built/uploaded
     "hbm_lineage_hits",        # hits under another view object of the same rows
+    "hbm_literal_rebuilds",    # entries found under their key whose literals differed: rebuilt in place
     "hbm_evictions",           # entries evicted under the HBM budget
     "hbm_eviction_bytes",      # device bytes released by evictions
     "hbm_pins",                # entries pinned by an executing query

@@ -423,10 +423,10 @@ def build_constant_device_expr(expr: Expression, schema: Schema,
                                float_dtype=None) -> Callable[[Dict[str, DCol]], DCol]:
     """Return fn(cols) -> (values, validity) with the literal values of
     `expr` itself as constants of the traced program: for the callers whose
-    compiled programs are kept under the values (a dim filter's visibility
-    plane, parallel/distributed.py's aggregate step). The aggregate
-    stages keep theirs under the skeleton, use build_device_expr and pass
-    each execution's values."""
+    compiled programs are kept under the values (parallel/distributed.py's
+    aggregate step). The aggregate stages and a join's dim filters
+    (ops/device_join.py _visibility_program) keep theirs under the skeleton,
+    use build_device_expr and pass each execution's values."""
     fdt = float_dtype or jnp.float64
     fn = build_device_expr(expr, schema, float_dtype=fdt)
     constants = [None if n.value is None

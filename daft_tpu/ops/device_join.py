@@ -11,8 +11,10 @@ the join never materializes. Each dim becomes
                         int32 array cached per (fact column, dim key) pair
 
 and every dim column the query touches is one device GATHER dim_col[idx_d].
-Per query, only the dim-side filter masks and dictionary codes change (small,
-dim-sized uploads); the fact columns and join indices are resident in HBM.
+Per query, only the dim filters' literal values change, and they are
+arguments of a compiled program (_JoinContext.verdict_plane): the fact
+columns, the join indices, the dims' packed planes and the planes their
+filters read are resident in HBM and hold no value.
 The aggregation then rides the existing MXU segment-reduction machinery
 (ops/grouped_stage.py) / ungrouped stage (ops/stage.py) unchanged — the fused
 program is filter -> gather-join -> segment-reduce in one XLA computation
@@ -34,6 +36,7 @@ then runs the untouched host plan (exact same semantics, tested side-by-side).
 from __future__ import annotations
 
 import functools
+import threading
 from dataclasses import dataclass, field
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -70,7 +73,7 @@ from .stage import (MESH_AXIS, FilterAggRun, FilterAggStage, batch_planes,
 @dataclass
 class DimSpec:
     base: object                     # LOGICAL plan of the dim without trailing filters
-    filters: List[Expression]        # dim-local filters (host-evaluated per run)
+    filters: List[Expression]        # dim-local filters (their values: arguments of the visibility program)
     key_col: str                     # dim-side unique join key column
     parent: Tuple[str, str]          # ("fact"|dim_name, column) providing probe values
     name: str                        # dim identifier (for caches/debug)
@@ -433,9 +436,14 @@ def series_keyed(anchor, key: tuple, deps: tuple, build, literals=None,
 
     `literals` carries the per-query predicate literal values for slots whose
     `key` is the filter STRUCTURE: varying-literal queries then reuse ONE slot
-    per query shape (rebuilt in place on a literal change) instead of growing
-    HBM by one entry per distinct literal. The manager accounts every entry's
-    device bytes and evicts LRU under DAFT_TPU_HBM_BUDGET.
+    per query shape (rebuilt in place on a literal change, counted as
+    `hbm_literal_rebuilds`) instead of growing HBM by one entry per distinct
+    literal. What is left of them on the join path: a dim filter the HOST
+    evaluates (host_visible, _host_ok_plane), a synthetic dim column and a
+    fact string membership plane. A dim filter the device evaluates has no
+    such slot: its values are arguments of a program
+    (_JoinContext.verdict_plane), and a pack holds none. The manager accounts
+    every entry's device bytes and evicts LRU under DAFT_TPU_HBM_BUDGET.
     """
     from ..device.residency import manager
 
@@ -594,7 +602,7 @@ class _ProvisionLayout:
     asked for. It comes from the plan's shape and the dims' dictionaries,
     never from a filter literal, so a query with another literal finds the
     program the first one traced."""
-    packs: tuple     # per adjacent dim: its pack's ok row, None = existence check only
+    packs: tuple     # per adjacent dim: the row of what it gathers from that holds the query's verdict, None = existence check only
     windows: tuple   # per adjacent dim: a segment's indices fit one window of its pack (_gather_rows)
     columns: tuple   # per dim column handed on: (name, adjacent dim, digit rows, validity row)
     codes: tuple     # per group-by column: (adjacent dim or -1 = fact-side plane, row or position, radix)
@@ -615,8 +623,10 @@ class _CodePlan(NamedTuple):
 @functools.lru_cache(maxsize=256)
 def _provision_program(layout: _ProvisionLayout):
     """The jitted program that turns one fact batch's cache hits (each
-    adjacent dim's packed matrix and index plane, the fact-side code planes)
-    into what the stage's program takes: the gathered dim columns with
+    adjacent dim's packed matrix with the query's filter verdict as its last
+    row, _JoinContext.query_pack: the one argument that holds a value of the
+    query; its index plane; the fact-side code planes) into what the stage's
+    program takes: the gathered dim columns with
     `__join_ok__`, and the radix-combined group codes where `layout.codes`
     asks for them. One call a dispatch; kept at module level under the
     layout because a _JoinContext lives for one query and the stages' own
@@ -638,8 +648,9 @@ def _provision_program(layout: _ProvisionLayout):
                                                layout.windows):
             aok = didx >= 0
             rows = None
-            if ok_row is not None:
+            if mat is not None:
                 rows = _gather_rows(mat, didx, windowed, layout.segment)   # [P, bucket]
+            if ok_row is not None:
                 aok = aok & (rows[ok_row] > 0.5)
             gathered.append(rows)
             ok = aok if ok is None else (ok & aok)
@@ -679,25 +690,137 @@ def _provision_program(layout: _ProvisionLayout):
     return jax.jit(run)
 
 
+def _code_column(name: str) -> str:
+    """The name a string column's dictionary code plane goes by inside a
+    visibility program."""
+    return f"__code_{name}__"
+
+
+def _string_comparison(f: Expression, schema: Schema
+                       ) -> Optional[Tuple[str, str, tuple]]:
+    """(column, "eq" | "neq" | "is_in", the literal strings) where the dim
+    filter `f` is, as a whole, `col == lit`, `col != lit` or
+    `col.is_in([lits])` over a string column of `schema` with non-null string
+    literals; else None. Such a filter is a comparison of dictionary codes on
+    the device (_JoinContext._lowered_filter). Only a whole conjunct: under a
+    `not` or an `or` a null row's verdict needs the column's validity, which
+    a code plane does not carry."""
+    while isinstance(f, Alias):
+        f = f.child
+
+    def string_col(e) -> Optional[str]:
+        if isinstance(e, ColumnRef) and e._name in schema.column_names() \
+                and schema[e._name].dtype.is_string():
+            return e._name
+        return None
+
+    def string_lit(e) -> bool:
+        return isinstance(e, Literal) and isinstance(e.value, str)
+
+    if isinstance(f, IsIn):
+        c = string_col(f.child)
+        if c is not None and f.items and all(string_lit(it) for it in f.items):
+            return c, "is_in", tuple(it.value for it in f.items)
+    elif isinstance(f, BinaryOp) and f.op in ("eq", "neq"):
+        for a, b in ((f.left, f.right), (f.right, f.left)):
+            c = string_col(a)
+            if c is not None and string_lit(b):
+                return c, f.op, (b.value,)
+    return None
+
+
+_VISIBILITY_PROGRAMS: Dict[tuple, tuple] = {}
+_VISIBILITY_LOCK = threading.Lock()
+
+
+def _visibility_program(filters: Sequence[Expression], structure: Tuple[tuple, tuple],
+                        dims: Sequence[DimSpec]):
+    """(the jitted visibility program of the dim filters `filters`, its
+    LiteralSlots): ONE program a list of filter skeletons, kept at module
+    level under them (a _JoinContext lives for one query) and traced by JAX
+    once a dimension length and mesh width. `structure` is
+    exprs_structure(filters); `dims` are the subtree's, whose columns the
+    filters read (a string column's code plane under _code_column's name).
+
+    The program takes the filters' columns as planes in the subtree root's row
+    space (name -> (values, validity); a code plane's validity is None: its
+    nulls are a code of their own), the subtree's value-free verdict (the
+    padding mask and the chain's links), the host-evaluated filters' plane or
+    None, and the execution's literal values as LiteralSlots packs them
+    (dates as days, a string as its dictionary code), and returns the
+    float32 verdict plane the provisioning program gathers. No value of
+    `filters` is read: whoever runs it passes its own query's."""
+    skels, lits = structure
+    key = (skels, tuple((dtype, value is None) for dtype, value in lits))
+    hit = _VISIBILITY_PROGRAMS.get(key)
+    if hit is not None:
+        return hit
+    fdt = jnp.float32
+    fields = [f for d in dims for f in d.base.schema.fields]
+    schema = Schema(fields + [Field(_code_column(f.name), DataType.int32())
+                              for f in fields if f.dtype.is_string()])
+    slots = dev.LiteralSlots(filters, fdt)
+    fns = [dev.build_device_expr(f, schema, float_dtype=fdt, first_slot=first)
+           for f, first in zip(filters, slots.offsets)]
+
+    def join_filter_verdict(cols, base_ok, host_ok, lit_args):
+        counters.bump("join_filter_program_traces")   # runs when traced, not when called
+        values = slots.unpack(lit_args)
+        always = jnp.ones((), dtype=bool)
+        cols = {name: (v, always if m is None else m) for name, (v, m) in cols.items()}
+        ok = base_ok if host_ok is None else (base_ok & host_ok)
+        for fn in fns:
+            v, m = fn(cols, values)
+            ok = ok & v.astype(bool) & m
+        return ok.astype(jnp.float32)
+
+    # (its name is the device trace's: `jit_join_filter_verdict(...)` on the
+    # XLA Modules line, which benchmark/filterbytes.py reads)
+    with _VISIBILITY_LOCK:
+        return _VISIBILITY_PROGRAMS.setdefault(
+            key, (jax.jit(join_filter_verdict), slots))
+
+
+@jax.jit
+def _stack_verdict(mat, verdict):
+    """A pack with the query's verdict plane laid under it as one more row:
+    what a dispatch gathers from, made once a query (a copy of the pack: two
+    queries with different values may hold one pack at once, so nothing is
+    written into it)."""
+    return jnp.concatenate([mat, verdict[None, :]], axis=0)
+
+
 class _JoinContext:
     """Materialized dims + per-fact-batch index/gather preparation.
 
     Everything expensive is cached keyed on the identity of a Series' DATA
     (series_keyed: a resident column, or the rows of one a morsel views):
-    host join indices, padded device index planes, dim visibility planes,
-    synthetic dim columns. Per-fact-batch slots anchor on a column of the
-    fact batch (_probe_anchor, fact_anchor), so each morsel has its own and
-    finds it again on the next query. Per-query work is then only: tiny per-query
-    literal uploads + ONE d2h fetch, and per fact batch the look-ups that find
-    the cached arrays, one call of the traced provisioning program
+    host join indices, padded device index planes, the packs, the planes a
+    dim filter reads, synthetic dim columns. Per-fact-batch slots anchor on a
+    column of the fact batch (_probe_anchor, fact_anchor), so each morsel has
+    its own and finds it again on the next query. Per-query work is then
+    only: one call of the visibility program a filtered adjacent dim
+    (verdict_plane) and ONE d2h fetch, and per fact batch the look-ups that
+    find the cached arrays, one call of the traced provisioning program
     (_provision_program: row gathers, join-validity mask, plane splits,
     wide-integer recombination, group codes) and one of the stage's program.
-    The context itself lives for one query; the provisioning programs live at
-    module level under their layout, so a repeat query traces nothing.
-    Dim filters that are device-evaluable over numeric resident columns are
-    computed ON DEVICE (no dim-sized visibility upload at all); the host
-    part (strings etc.) is evaluated once per query shape and its upload
-    cached.
+    The context itself lives for one query; the provisioning and visibility
+    programs live at module level under their layout and skeletons, so a
+    repeat query traces nothing.
+
+    What a filter VALUE costs: nothing that is kept. A dim filter that is
+    device-evaluable over f32-exact columns (dates, booleans, small
+    integers), or that compares a string column with literals (`==`, `!=`,
+    `is_in`: a comparison of dictionary codes, the literal's code looked up
+    on the host), takes its values as arguments of the visibility program:
+    the query's verdict plane is made once, at its first dispatch, from
+    resident planes that hold no value (the filters' columns carried into
+    the adjacent dim's row space, the chain's links), laid under a copy of
+    the dim's pack (query_pack) and kept by this context alone, so two
+    queries with different values share every slot and neither waits for
+    the other. Only a filter that stays on the host (LIKE,
+    a function) keeps a slot whose literals are compared (host_visible,
+    _host_ok_plane) and is rebuilt on a new value (`hbm_literal_rebuilds`).
     """
 
     def __init__(self, spec: JoinAggSpec, dim_batches: Dict[str, object]):
@@ -720,6 +843,11 @@ class _JoinContext:
         self.syn_series: Dict[str, Dict[str, object]] = {}
         self._dev_filters: Dict[str, List[Expression]] = {}
         self._host_filters: Dict[str, List[Expression]] = {}
+        # the query's filter verdicts, an adjacent dim each (verdict_plane),
+        # and the packs with them laid under (query_pack: the pack is kept
+        # beside its copy so that its id stays its own)
+        self._verdicts: Dict[str, object] = {}
+        self._query_packs: Dict[tuple, tuple] = {}
         for d in self.dims:
             b = dim_batches[d.name]
             devf: List[Expression] = []
@@ -728,11 +856,14 @@ class _JoinContext:
                 # device filter eval reads f32 planes: only dtypes whose every
                 # value is f32-exact qualify (dates < 2^24 days, small ints,
                 # bools) — int64/timestamp/float comparisons stay on host,
-                # which evaluated ALL dim filters exactly before this path
-                if dev.is_device_evaluable(f, d.base.schema) and all(
-                        d.base.schema[c].dtype.kind in
-                        ("date", "bool", "int8", "int16", "uint8", "uint16")
-                        for c in f.referenced_columns()):
+                # which evaluated ALL dim filters exactly before this path.
+                # A string column compared with literals is compared by its
+                # dictionary codes (_lowered_filter)
+                if _string_comparison(f, d.base.schema) is not None or (
+                        dev.is_device_evaluable(f, d.base.schema) and all(
+                            d.base.schema[c].dtype.kind in
+                            ("date", "bool", "int8", "int16", "uint8", "uint16")
+                            for c in f.referenced_columns())):
                     devf.append(f)
                 else:
                     hostf.append(f)
@@ -826,45 +957,6 @@ class _JoinContext:
         skels, lits = exprs_structure(hostf)
         return series_keyed(anchor, ("hostvis",) + skels, deps, build,
                             literals=lits)
-
-    def vis_plane(self, d: DimSpec, cap_d: int):
-        """bool[cap_d] device plane: dim row passes all its filters. Device-
-        evaluable filters run on device over resident columns; host-part
-        visibility uploads once per query shape (both cached)."""
-        b = self.batches[d.name]
-        devf = self._dev_filters[d.name]
-        hostf = self._host_filters[d.name]
-        ref_cols = sorted({c for f in devf + hostf for c in f.referenced_columns()})
-        deps = tuple(b.get_column(c) for c in ref_cols)
-        anchor = deps[0] if deps else b.get_column(b.column_names()[0])
-        skels, lits = exprs_structure(devf + hostf)
-        key = ("visplane", cap_d) + skels
-
-        def build():
-            vis = None
-            for f in devf:
-                fn = dev.build_constant_device_expr(f, d.base.schema)
-                dcols = {c: b.get_column(c).to_device_cached(cap_d, f32=True)
-                         for c in f.referenced_columns()}
-                v, m = fn(dcols)
-                plane = v.astype(bool) & m
-                vis = plane if vis is None else (vis & plane)
-            hv = self.host_visible(d)
-            if hv is not None:
-                padded = np.zeros(cap_d, dtype=bool)
-                padded[:b.num_rows] = hv
-                hplane = jnp.asarray(padded)
-                vis = hplane if vis is None else (vis & hplane)
-            if vis is None:
-                padded = np.zeros(cap_d, dtype=bool)
-                padded[:b.num_rows] = True
-                vis = jnp.asarray(padded)
-            else:
-                # padding rows (>= num_rows) must read as not-visible
-                vis = vis & (jnp.arange(cap_d) < b.num_rows)
-            return vis
-
-        return series_keyed(anchor, key, deps, build, literals=lits)
 
     def _fact_membership_plane(self, batch, bucket: int, syn: str) -> dev.DCol:
         """bool plane for a fact string membership predicate: resident dict
@@ -1247,91 +1339,125 @@ class _JoinContext:
             return self.syn_series[dname][col]
         return self.batches[dname].get_column(col)
 
-    def _build_space(self, d: DimSpec, vals: Dict[str, List[str]],
-                     codes: Dict[str, List[str]]):
-        """(value planes, code planes, ok plane or None) for d's subtree, all
-        in d's row space on device. Called inside packed_plane's cached build."""
-        b = self.batches[d.name]
-        cap_d = pad_bucket(b.num_rows)
+    def _subtree(self, adj: DimSpec) -> List[DimSpec]:
+        """`adj` and the dims chained from it, parents first."""
+        return [adj] + [d for d in self.dims
+                        if d.name != adj.name and self._root_of(d.name) == adj.name]
+
+    def _subtree_deps(self, sub_dims: Sequence[DimSpec]) -> tuple:
+        """Each subtree dim's key and parent-link columns: what a slot built
+        through the chain depends on beside the columns it reads (a different
+        chain through the same root must NOT reuse it)."""
+        return tuple(self.batches[d.name].get_column(d.key_col) for d in sub_dims) \
+            + tuple(self.batches[d.parent[0]].get_column(d.parent[1])
+                    for d in sub_dims if d.parent[0] != "fact")
+
+    @staticmethod
+    def _chain_shape(sub_dims: Sequence[DimSpec]) -> tuple:
+        return tuple((d.key_col,) + d.parent for d in sub_dims)
+
+    def _child_idx_plane(self, child: DimSpec):
+        """int32 device plane as long as the PARENT dim padded: the child row
+        each parent row joins (-1: none). A slot of its own beside the packs
+        and the filter planes that are built through it, so none of them
+        uploads it again (64 MB for `orders` -> `customer` at SF10)."""
+        b = self.batches[child.parent[0]]
+        cap = pad_bucket(b.num_rows)
+        idx = self.dim_space_idx(child)
+
+        def build():
+            padded = np.full(cap, -1, dtype=np.int32)
+            padded[:b.num_rows] = idx
+            return jnp.asarray(padded)
+
+        return series_keyed(b.get_column(child.parent[1]),
+                            ("dsdidx", child.key_col, cap), (idx,), build)
+
+    def _at_root(self, root: DimSpec, d: DimSpec, v, m):
+        """A (values, validity) plane over `d`'s rows carried into `root`'s
+        row space: one dim-sized gather a link of the chain between them. A
+        row the chain does not reach is invalid."""
+        while d.name != root.name:
+            v, m = _gather_col(v, m, self._child_idx_plane(d))
+            d = next(dd for dd in self.dims if dd.name == d.parent[0])
+        return v, m
+
+    def _build_space(self, adj: DimSpec, sub_dims: Sequence[DimSpec],
+                     vals: Dict[str, List[str]], codes: Dict[str, List[str]]):
+        """(value planes, code planes, chain verdict or None) of `adj`'s
+        subtree, all in adj's row space on device: the columns asked for of
+        each dim (`vals`: name -> (values, validity); `codes`: name -> int32
+        dictionary codes), and whether every link of the chain below a row
+        finds its row (None where nothing is chained). Holds no filter value.
+        Called inside the cached builds of packed_plane and _filter_planes."""
         planes: Dict[str, dev.DCol] = {}
         code_planes: Dict[str, object] = {}
-        for c in vals[d.name]:
-            planes[c] = self._dim_source(d.name, c).to_device_cached(cap_d, f32=True)
-        for c in codes[d.name]:
-            src = self._dim_source(d.name, c)
-            cds, _values, _k = src.dict_codes()
-            code_planes[c] = cached_dict_code_plane(src, cds, b.num_rows, cap_d)
-        ok = None
-        if self._dev_filters[d.name] or self._host_filters[d.name]:
-            ok = self.vis_plane(d, cap_d)
-        for child in self._children_of(d.name):
-            cplanes, ccodes, cok = self._build_space(child, vals, codes)
-            idx = self.dim_space_idx(child)
-            padded = np.full(cap_d, -1, dtype=np.int32)
-            padded[:b.num_rows] = idx
-            didx = jnp.asarray(padded)
-            for c, (v, m) in cplanes.items():
-                planes[c] = _gather_col(v, m, didx)
-            for c, cp in ccodes.items():
-                g, _m = _gather_col(cp, jnp.ones(cp.shape[0], dtype=bool), didx)
+        chain_ok = None
+        for d in sub_dims:
+            b = self.batches[d.name]
+            cap_d = pad_bucket(b.num_rows)
+            for c in vals[d.name]:
+                planes[c] = self._at_root(adj, d, *self._dim_source(
+                    d.name, c).to_device_cached(cap_d, f32=True))
+            for c in codes[d.name]:
+                src = self._dim_source(d.name, c)
+                cds, _values, _k = src.dict_codes()
+                cp = cached_dict_code_plane(src, cds, b.num_rows, cap_d)
+                g, _m = self._at_root(adj, d, cp, jnp.ones(cp.shape[0], dtype=bool))
                 code_planes[c] = g.astype(jnp.int32)
-            child_ok = didx >= 0
-            if cok is not None:
-                okv, okm = _gather_col(cok.astype(jnp.float32), cok, didx)
-                child_ok = child_ok & (okv > 0.5) & okm
-            ok = child_ok if ok is None else (ok & child_ok)
-        return planes, code_planes, ok
+            if d.name != adj.name:
+                parent = next(dd for dd in self.dims if dd.name == d.parent[0])
+                link = self._child_idx_plane(d) >= 0
+                lv, lm = self._at_root(adj, parent, link, jnp.ones_like(link))
+                chain_ok = (lv & lm) if chain_ok is None else (chain_ok & lv & lm)
+        return planes, code_planes, chain_ok
+
+    def _whole_on_mesh(self, tree):
+        """`tree`'s arrays whole on every device of the mesh (as they are on
+        one chip): a shard's rows gather from any row of the dimension."""
+        if self.mesh is None:
+            return tree
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.device_put(tree, NamedSharding(self.mesh, PartitionSpec()))
 
     def packed_plane(self, adj: DimSpec, needed: Sequence[str],
                      groupby_cols: Sequence[str]):
-        """Packed [cap_d, P] f32 matrix + layout for one adjacency subtree, or
-        None when the subtree is a pure existence check (idx >= 0 suffices).
+        """Packed [P, cap_d] f32 matrix + layout of the columns one adjacency
+        subtree hands to the fact's rows, or None when it hands none (the
+        subtree is an existence check, or only filters: verdict_plane).
 
-        Returns (mat, layout, code_layout, ok_col, wide) where layout[col] =
+        Returns (mat, layout, code_layout, wide) where layout[col] =
         (val_idx, valid_idx); 32- and 64-bit int columns split into two or
         three base-2^24 f32 digit planes (wide[col] = (digit rows, most
         significant first, then valid_idx)), which the provisioning program
         recombines in int64 after the fact gather, preserving exact values
-        past 2^24."""
+        past 2^24.
+
+        The pack holds NO filter value and no verdict: its slot is keyed on
+        the chain's shape and the columns alone, so a query with other filter
+        values hits it (what a value costs is one call of the visibility
+        program, verdict_plane; a pack of `orders` at SF10 is 64 MB a row,
+        and before it was rebuilt whole, eagerly, for every new value)."""
         spec = self.spec
         vals, codes = self._needed_split(needed, groupby_cols)
-        sub = [adj.name] + [d.name for d in self.dims
-                            if self._root_of(d.name) == adj.name
-                            and d.name != adj.name]
-        my_vals = [c for n in sub for c in vals[n]]
-        my_codes = [c for n in sub for c in codes[n]]
-        has_filters = any(self._dev_filters[n] or self._host_filters[n]
-                          for n in sub)
-        has_chain = len(sub) > 1
-        if not my_vals and not my_codes and not has_filters and not has_chain:
+        sub_dims = self._subtree(adj)
+        my_vals = [c for d in sub_dims for c in vals[d.name]]
+        my_codes = [c for d in sub_dims for c in codes[d.name]]
+        if not my_vals and not my_codes:
             return None
 
         anchor = self.batches[adj.name].get_column(adj.key_col)
-        sub_dims = [adj] + [d for d in self.dims
-                            if d.name in sub and d.name != adj.name]
         # deps: every source Series the pack reads — value/code columns, each
-        # subtree dim's key and parent-link columns (a different chain through
-        # the same root must NOT reuse this pack); key: the chain SHAPE
+        # subtree dim's key and parent-link columns; key: the chain SHAPE
         deps = tuple(self._dim_source(spec.col_side[c], c)
-                     for c in my_vals + my_codes)
-        deps += tuple(self.batches[d.name].get_column(d.key_col)
-                      for d in sub_dims)
-        deps += tuple(self.batches[d.parent[0]].get_column(d.parent[1])
-                      for d in sub_dims if d.parent[0] != "fact")
-        # filters enter the key by STRUCTURE; their literals live in the slot,
-        # so varying-literal reps rebuild one pack instead of accumulating
-        fskels, flits = exprs_structure(
-            [f for n in sub
-             for f in self._dev_filters[n] + self._host_filters[n]])
+                     for c in my_vals + my_codes) + self._subtree_deps(sub_dims)
         key = ("pack", tuple(my_vals), tuple(my_codes),
-               tuple((d.key_col,) + d.parent for d in sub_dims), fskels) \
-            + self._mesh_key()
-        mesh = self.mesh
+               self._chain_shape(sub_dims)) + self._mesh_key()
 
         def build():
-            planes, code_planes, ok = self._build_space(adj, vals, codes)
-            b = self.batches[adj.name]
-            cap_d = pad_bucket(b.num_rows)
+            planes, code_planes, _chain_ok = self._build_space(
+                adj, sub_dims, vals, codes)
             cols = []
             layout: Dict[str, Tuple[int, int]] = {}
             wide: Dict[str, Tuple[int, int, int]] = {}
@@ -1362,19 +1488,197 @@ class _JoinContext:
             for c in my_codes:
                 code_layout[c] = len(cols)
                 cols.append(code_planes[c].astype(jnp.float32))
-            ok_plane = ok if ok is not None else jnp.ones(cap_d, dtype=bool)
-            ok_col = len(cols)
-            cols.append(ok_plane.astype(jnp.float32))
-            mat = jnp.stack(cols, axis=0)   # [P, cap_d]: minor dim stays long
-            if mesh is not None:
-                # whole on every device of the mesh: a shard's rows gather from
-                # any row of the dimension (the one copy made here is dropped)
-                from jax.sharding import NamedSharding, PartitionSpec
+            # [P, cap_d]: minor dim stays long; over a mesh whole on every
+            # device (the one copy made here is dropped)
+            mat = self._whole_on_mesh(jnp.stack(cols, axis=0))
+            return mat, layout, code_layout, wide
 
-                mat = jax.device_put(mat, NamedSharding(mesh, PartitionSpec()))
-            return mat, layout, code_layout, ok_col, wide
+        return series_keyed(anchor, key, deps, build)
 
-        return series_keyed(anchor, key, deps, build, literals=flits)
+    # ---- the query's filter verdict ------------------------------------------------
+    #
+    # Whether a row of a fact-adjacent dim lets a fact row through: its own
+    # filters, its chained dims' filters and the chain's links. The pack
+    # carried it as a row of its own once, so a new SEGMENT, REGION or DATE
+    # rebuilt the pack. Now everything that is kept holds no value: the
+    # filters' columns carried into the adjacent dim's row space
+    # (_filter_planes) and one jitted program a list of filter skeletons
+    # (_visibility_program), which takes the values as arguments. The verdict
+    # itself is made once a query and dies with the context.
+
+    def _dict_lookup(self, src) -> Dict[object, int]:
+        """value -> dictionary code of a string column, made once a column
+        (a handful of entries for a segment or a region's name)."""
+        return series_keyed(
+            src, ("dictlookup",), (),
+            lambda: {v: i for i, v in enumerate(src.dict_codes()[1])})
+
+    def _lowered_filter(self, d: DimSpec, f: Expression) -> Expression:
+        """The device filter `f` of dim `d` as the visibility program
+        compiles and binds it: itself, or for a string comparison
+        (_string_comparison) the same comparison of the column's dictionary
+        codes with the literals' codes, looked up on the host. A value the
+        dictionary lacks gives -1, a code no row has; a null row's code
+        equals no literal's, and `!=` names the nulls' code besides, so a
+        null fails the filter as on the host."""
+        cmp = _string_comparison(f, d.base.schema)
+        if cmp is None:
+            return f
+        colname, op, values = cmp
+        lookup = self._dict_lookup(self.batches[d.name].get_column(colname))
+        code = ColumnRef(_code_column(colname))
+
+        def lit(value) -> Literal:
+            return Literal(int(lookup.get(value, -1)), DataType.int32())
+
+        if op == "is_in":
+            return IsIn(code, [lit(v) for v in values])
+        if op == "eq":
+            return BinaryOp("eq", code, lit(values[0]))
+        return BinaryOp("and", BinaryOp("neq", code, lit(values[0])),
+                        BinaryOp("neq", code, lit(None)))
+
+    def _filter_columns(self, sub_dims: Sequence[DimSpec]):
+        """(value columns, code columns) the subtree's device filters read,
+        by dim name, each sorted."""
+        vals: Dict[str, List[str]] = {d.name: [] for d in self.dims}
+        codes: Dict[str, List[str]] = {d.name: [] for d in self.dims}
+        for d in sub_dims:
+            for f in self._dev_filters[d.name]:
+                cmp = _string_comparison(f, d.base.schema)
+                into, cols = (codes, [cmp[0]]) if cmp is not None \
+                    else (vals, f.referenced_columns())
+                into[d.name] = sorted(set(into[d.name]) | set(cols))
+        return vals, codes
+
+    def _filter_planes(self, adj: DimSpec, sub_dims: Sequence[DimSpec], cap: int):
+        """({column: (values, validity)}, value-free verdict) in `adj`'s row
+        space, whole on every device: the planes the subtree's device filters
+        read (a string column's int32 dictionary codes under _code_column's
+        name, validity None) and the bool plane of what no value decides (the
+        padding mask and the chain's links). One slot a (chain, column set):
+        resident, hit by every query whatever its values. A chained dim's
+        columns are carried through the chain once, here; the adjacent dim's
+        own are the resident column planes themselves on one chip (looked up,
+        not kept twice) and copies whole on every device over a mesh."""
+        vals, codes = self._filter_columns(sub_dims)
+        own = self.mesh is None     # adj's own planes: read where they lie
+        kept_vals = dict(vals, **({adj.name: []} if own else {}))
+        kept_codes = dict(codes, **({adj.name: []} if own else {}))
+        b = self.batches[adj.name]
+        sources = [(d.name, c) for d in sub_dims
+                   for c in vals[d.name] + codes[d.name]]
+        key = ("jfilter", self._chain_shape(sub_dims), tuple(sources), cap) \
+            + self._mesh_key()
+        deps = tuple(self._dim_source(n, c) for n, c in sources) \
+            + self._subtree_deps(sub_dims)
+
+        def build():
+            planes, code_planes, chain_ok = self._build_space(
+                adj, sub_dims, kept_vals, kept_codes)
+            base = jnp.arange(cap) < b.num_rows     # padding rows pass nothing
+            if chain_ok is not None:
+                base = base & chain_ok
+            cols = dict(planes)
+            cols.update((_code_column(c), (p, None)) for c, p in code_planes.items())
+            return self._whole_on_mesh((cols, base))
+
+        cols, base = series_keyed(b.get_column(adj.key_col), key, deps, build)
+        if own:
+            cols = dict(cols)
+            for c in vals[adj.name]:
+                cols[c] = b.get_column(c).to_device_cached(cap, f32=True)
+            for c in codes[adj.name]:
+                src = b.get_column(c)
+                cols[_code_column(c)] = (cached_dict_code_plane(
+                    src, src.dict_codes()[0], b.num_rows, cap), None)
+        return cols, base
+
+    def _host_ok_plane(self, adj: DimSpec, sub_dims: Sequence[DimSpec], cap: int):
+        """bool[cap] device plane in `adj`'s row space: a row passes the
+        subtree's HOST-evaluated filters (host_visible, carried through the
+        chain); None where the subtree has none. These are the filters whose
+        values no program takes (LIKE, functions): the slot is keyed on their
+        skeletons, holds their values and is rebuilt on a new one."""
+        filtered = [d for d in sub_dims if self._host_filters[d.name]]
+        if not filtered:
+            return None
+        hostf = [f for d in filtered for f in self._host_filters[d.name]]
+        skels, lits = exprs_structure(hostf)
+        deps = tuple(self.batches[d.name].get_column(c) for d in filtered
+                     for f in self._host_filters[d.name]
+                     for c in f.referenced_columns()) + self._subtree_deps(sub_dims)
+
+        def build():
+            ok = None
+            for d in filtered:
+                rows = self.batches[d.name].num_rows
+                padded = np.zeros(pad_bucket(rows), dtype=bool)
+                padded[:rows] = self.host_visible(d)
+                plane = jnp.asarray(padded)
+                v, m = self._at_root(adj, d, plane, jnp.ones_like(plane))
+                ok = (v & m) if ok is None else (ok & v & m)
+            return self._whole_on_mesh(ok)
+
+        return series_keyed(
+            self.batches[adj.name].get_column(adj.key_col),
+            ("hostok", self._chain_shape(sub_dims), cap) + skels + self._mesh_key(),
+            deps, build, literals=lits)
+
+    def verdict_plane(self, adj: DimSpec):
+        """float32[cap_d] device plane, 1.0 where a row of the adjacent dim
+        `adj` lets a fact row through (its filters, its chained dims' and the
+        chain's links; padding rows 0.0), whole on every device; None where
+        the subtree has no filter and no chain (`idx >= 0` says it all).
+        Made ONCE A QUERY, at the first dispatch that asks, by one call of the
+        subtree's visibility program with this query's literal values; kept
+        by this context and nowhere else, so nothing is cached a value."""
+        if adj.name in self._verdicts:
+            return self._verdicts[adj.name]
+        sub_dims = self._subtree(adj)
+        filtered = any(self._dev_filters[d.name] or self._host_filters[d.name]
+                       for d in sub_dims)
+        verdict = None
+        if filtered or len(sub_dims) > 1:
+            rows = self.batches[adj.name].num_rows
+            cap = pad_bucket(rows)
+            with profile_span("join.filter", "device", dims=len(sub_dims),
+                              rows=rows) as sp:
+                lowered = [self._lowered_filter(d, f) for d in sub_dims
+                           for f in self._dev_filters[d.name]]
+                structure = exprs_structure(lowered)
+                program, slots = _visibility_program(lowered, structure, sub_dims)
+                cols, base = self._filter_planes(adj, sub_dims, cap)
+                host_ok = self._host_ok_plane(adj, sub_dims, cap)
+                if sp is not None:
+                    sp.args["slots"] = slots.n_args
+                counters.bump("join_filter_literal_args", slots.n_args)
+                verdict = program(cols, base, host_ok,
+                                  slots.with_run_values(slots.pack(structure[1])))
+        self._verdicts[adj.name] = verdict
+        return verdict
+
+    def query_pack(self, adj: DimSpec, pack):
+        """(what a dispatch gathers from for `adj`, the row of it that holds
+        the query's verdict): `pack` (packed_plane's matrix, or None) with the
+        verdict plane as one more row, the verdict alone as a one-row matrix
+        where the dim hands on no column, the pack as it is (None) where the
+        subtree has no filter and no chain, or (None, None) for a plain
+        existence check. The stacked copy is made once a query and pack by
+        this context (0.9 ms on the chip for `orders`' five rows of 2^24 at
+        SF10), so the provisioning program gathers ONE matrix a dimension,
+        the rows the pack carried while it held the verdict itself: laid
+        under the pack's window inside the program instead, the chip's
+        compiler no longer kept the window in fast memory and a q5 dispatch
+        of eight segments took 0.67 ms longer (PERF.md section 6, PR 45)."""
+        verdict = self.verdict_plane(adj)
+        if verdict is None:
+            return pack, None
+        key = (adj.name, id(pack))
+        if key not in self._query_packs:    # (once a query, not a dispatch)
+            self._query_packs[key] = (pack, verdict[None, :] if pack is None
+                                      else _stack_verdict(pack, verdict))
+        return self._query_packs[key][1], 0 if pack is None else pack.shape[0]
 
     def _permuted_fact_plane(self, series, bucket: int, perm) -> dev.DCol:
         """Resident fact plane reordered by the group-sorted permutation —
@@ -1418,8 +1722,9 @@ class _JoinContext:
             adj_of[adj.name] = a
             didx, span = self.dev_idx(batch, adj.name, bucket, perm=perm)
             idxs.append(didx)
-            mat, layout, code_layout, ok_row, wide = \
-                self.packed_plane(adj, needed, gb_cols) or (None, {}, {}, None, {})
+            pack, layout, code_layout, wide = \
+                self.packed_plane(adj, needed, gb_cols) or (None, {}, {}, {})
+            mat, ok_row = self.query_pack(adj, pack)
             mats.append(mat)
             ok_rows.append(ok_row)
             # a window as long as the segment serves it where the matched

@@ -51,6 +51,10 @@ _POSITION_PINS = {
         "holds the benchmark's four-chip cells to tpch_sf30_mesh4.scanagg alone; PR 41's "
         "tpch_sf30_mesh4.joins is the second (what else it asserts is kept by name in "
         "test_bench_joins_mesh.py::test_the_four_chip_scan_cells_entries_are_as_they_were)",
+    "test_bench_joins_mesh.py::test_the_one_chip_join_cells_entries_are_as_they_were":
+        "pins PR 41's cell and configuration to the end of BENCHMARK.json's lists; PR 45's "
+        "tpch_sf10.adhoc_joins follows them (what else it asserts is kept by name in "
+        "test_bench_adhoc_joins.py::test_the_join_cells_entries_are_as_they_were)",
 }
 
 

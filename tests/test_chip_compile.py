@@ -450,6 +450,49 @@ def test_windowed_provisioning_gathers_from_a_batch_long_window(one_chip, rows, 
         assert windowed == [(JOIN_BATCH // 128, 128)] * segments
 
 
+CUSTOMER_CAP = 1 << 21      # customer's 1.5 M rows at SF10, padded
+
+
+@pytest.mark.parametrize("query", ["q3", "q5"])
+def test_visibility_program_is_one_pass_over_the_dimension(one_chip, query):
+    """The join's visibility program at `tpch_sf10.adhoc_joins`' shapes: the
+    filters of q3's `orders` subtree (`o_orderdate < DATE` and `customer`'s
+    `c_mktsegment == SEGMENT`, its codes carried to `orders`' rows) or q5's
+    (two dates and `region`'s `r_name == REGION`, three links down), their
+    values one small argument. It compiles for the chip with NO gather (the
+    chain is walked when the carried planes are built, not a query) and gives
+    one float32 plane as long as `orders` padded."""
+    import datetime
+    import types
+
+    from daft_tpu import col, lit
+    from daft_tpu.datatype import DataType, Field
+    from daft_tpu.device.residency import exprs_structure
+    from daft_tpu.expressions.expressions import BinaryOp, ColumnRef, Literal
+    from daft_tpu.ops import device_join as dj
+    from daft_tpu.schema import Schema
+
+    day = lit(datetime.date(1995, 3, 15))
+    code = dj._code_column("c_mktsegment" if query == "q3" else "r_name")
+    by_code = BinaryOp("eq", ColumnRef(code), Literal(2, DataType.int32()))
+    dates = col("o_orderdate") < day if query == "q3" \
+        else (col("o_orderdate") >= day) & (col("o_orderdate") < day)
+    filters = [dates, by_code]
+    orders = dj.DimSpec(base=types.SimpleNamespace(schema=Schema(
+        [Field("o_orderdate", DataType.date()), Field(code, DataType.int32())])),
+        filters=filters, key_col="o_orderkey", parent=("fact", "l_orderkey"), name="d0")
+    program, slots = dj._visibility_program(filters, exprs_structure(filters), [orders])
+    plane = lambda dt: _s(one_chip, (ORDERS_CAP,), dt)
+    cols = {"o_orderdate": (plane(jnp.float32), plane(jnp.bool_)),
+            code: (plane(jnp.int32), None)}
+    lit_args = tuple(jax.ShapeDtypeStruct(shape, dt) for shape, dt in slots.arg_shapes())
+    compiled = program.lower(cols, plane(jnp.bool_), None, lit_args).compile()
+    text = compiled.as_text()
+    assert " gather(" not in text
+    assert slots.n_args == (2 if query == "q3" else 3)
+    assert compiled.memory_analysis().output_size_in_bytes == 4 * ORDERS_CAP
+
+
 # ---- the join dispatch on every shard of the 2x2 mesh, at tpch_sf30_mesh4.joins' shapes ----
 
 ORDERS_CAP_SF30 = 1 << 26     # orders' 45 M rows, padded

@@ -632,6 +632,218 @@ def test_changed_literal_gives_the_new_answer(tpch_like, shape, variants):
     manager().clear()
 
 
+# ---- a dim filter's values are arguments ------------------------------------------------
+#
+# The dim filters of a star join take their literal values as arguments of the
+# subtree's visibility program: a pack holds no value, a string comparison is a
+# comparison of dictionary codes on the device, and the verdict plane belongs
+# to the query. What a new value may cost: one call of a program traced before.
+
+_VALUE_COUNTERS = ("hbm_literal_rebuilds", "join_filter_program_traces",
+                   "join_provision_traces", "device_stage_program_traces",
+                   "hbm_cache_misses", "hbm_h2d_bytes")
+
+
+def _value_run(q):
+    """(answer, what the run counted of _VALUE_COUNTERS and the filter's
+    literal arguments) of one forced device run."""
+    from daft_tpu.observability.metrics import registry
+
+    names = _VALUE_COUNTERS + ("join_filter_literal_args",)
+    before = {k: registry().get(k) for k in names}
+    jb = counters.device_join_batches
+    with _morselized("on"):
+        out = q().to_pydict()
+    assert counters.device_join_batches > jb, counters.rejections
+    return out, {k: registry().get(k) - before[k] for k in names}
+
+
+def _with_a_null_segment(t):
+    """`t` with every seventh customer's segment null."""
+    c = t["customer"].to_pydict()
+    c["c_mktsegment"] = [None if i % 7 == 0 else v for i, v in enumerate(c["c_mktsegment"])]
+    return dict(t, customer=daft_tpu.from_pydict(c).collect())
+
+
+def _q3_where(t, segment_filter, cut=(1995, 3, 15)):
+    """_q3_shaped with any filter over `customer` in the segment's place."""
+    return (t["customer"].where(segment_filter)
+            .join(t["orders"], left_on="c_custkey", right_on="o_custkey")
+            .where(col("o_orderdate") < _days(*cut))
+            .join(t["lineitem"], left_on="o_orderkey", right_on="l_orderkey")
+            .where(col("l_shipdate") > _days(*cut))
+            .groupby(col("o_orderkey").alias("l_orderkey"), "o_orderdate", "o_shippriority")
+            .agg((col("l_extendedprice") * (1 - col("l_discount"))).sum().alias("revenue"))
+            .select("l_orderkey", "revenue", "o_orderdate", "o_shippriority")
+            .sort(["revenue", "o_orderdate"], desc=[True, False])
+            .limit(10))
+
+
+_SEG = col("c_mktsegment")
+
+
+@pytest.mark.parametrize("case, nulls, first, then, args, empty", [
+    # a value the dictionary lacks: a code no row has, an empty answer, no rebuild
+    ("absent_value", False, lambda: _SEG == "BUILDING", lambda: _SEG == "NO SUCH SEGMENT", 2, True),
+    # a null in the filtered column fails `==`, and `!=` too, as on the host
+    ("null_eq", True, lambda: _SEG == "BUILDING", lambda: _SEG == "MACHINERY", 2, False),
+    ("null_neq", True, lambda: _SEG != "BUILDING", lambda: _SEG != "AUTOMOBILE", 3, False),
+    ("neq_absent", True, lambda: _SEG != "BUILDING", lambda: _SEG != "NO SUCH SEGMENT", 3, False),
+    # is_in of two segments: one skeleton a length, two codes
+    ("is_in_two", True, lambda: _SEG.is_in(["BUILDING", "MACHINERY"]),
+     lambda: _SEG.is_in(["AUTOMOBILE", "NO SUCH SEGMENT"]), 3, False),
+    # the literal on the left
+    ("lit_left", False, lambda: lit("BUILDING") == _SEG, lambda: lit("AUTOMOBILE") == _SEG, 2, False),
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_a_string_filter_on_a_dim_is_a_comparison_of_codes(tpch_like, case, nulls, first, then,
+                                                           args, empty):
+    """`col == lit`, `!=` and `is_in` over a dimension's string column run as
+    comparisons of its dictionary codes inside the visibility program: the
+    host tier's answer, the literal's code an argument (with the date, and
+    for `!=` the nulls' code), and the second value set costs no rebuild, no
+    trace, no build and no upload."""
+    from daft_tpu.device.residency import manager
+
+    manager().clear()
+    t = _with_a_null_segment(tpch_like) if nulls else tpch_like
+    for k, make in enumerate((first, then, first)):
+        q = lambda: _q3_where(t, make())
+        dev, counted = _value_run(q)
+        _assert_close(_host_answer(q), dev)
+        assert counted["join_filter_literal_args"] == args, (case, counted)
+        if k:
+            assert not any(counted[c] for c in _VALUE_COUNTERS), (case, k, counted)
+        if k == 1:
+            assert (len(dev["l_orderkey"]) == 0) == empty, (case, dev)
+    manager().clear()
+
+
+def test_a_filter_two_links_from_the_fact_takes_its_value_as_an_argument(tpch_like):
+    """q5's `r_name == REGION` lies on `region`, chained to the fact through
+    `nation`, `customer` and `orders`: its codes are carried to `orders`' rows
+    once, and each REGION and DATE is an argument after."""
+    from daft_tpu.device.residency import manager
+
+    manager().clear()
+    answers = []
+    for k, kw in enumerate([dict(), dict(region="EUROPE"), dict(region="AFRICA", cut=(1994, 6, 1)),
+                            dict(region="ATLANTIS"), dict()]):
+        q = lambda: _q5_shaped(tpch_like, **kw)
+        dev, counted = _value_run(q)
+        _assert_close(_host_answer(q), dev)
+        assert counted["join_filter_literal_args"] == 3       # two dates and the region's code
+        if k:
+            assert not any(counted[c] for c in _VALUE_COUNTERS), (kw, counted)
+        answers.append(dev)
+    assert answers[0] == answers[4] and answers[0] != answers[1] != answers[2]
+    assert answers[3] == {"n_name": [], "revenue": []}
+    manager().clear()
+
+
+def test_a_host_filter_keeps_its_slot_and_is_counted_as_a_rebuild(tpch_like):
+    """A filter no program takes the values of (a LIKE) stays on the host: its
+    visibility is kept under its skeleton with its values, and another value
+    rebuilds it in place, which `hbm_literal_rebuilds` counts. The pack and
+    the device filters' planes beside it hold no value and are not rebuilt."""
+    from daft_tpu.device.residency import manager
+
+    manager().clear()
+    counts = []
+    for prefix in ("BUILD", "MACH", "MACH"):
+        q = lambda: _q3_where(tpch_like, _SEG.str.startswith(prefix))
+        dev, counted = _value_run(q)
+        _assert_close(_host_answer(q), dev)
+        assert counted["join_filter_literal_args"] == 1       # the date alone
+        counts.append(counted)
+    assert counts[0]["hbm_literal_rebuilds"] == 0
+    assert counts[1]["hbm_literal_rebuilds"] == 2             # the host's plane, and its upload
+    assert counts[2]["hbm_literal_rebuilds"] == 0 and counts[2]["hbm_cache_misses"] == 0
+    assert all(c["join_filter_program_traces"] == 0 for c in counts[1:])
+    manager().clear()
+
+
+def test_two_queries_with_different_values_at_once_each_get_their_own_answer(tpch_like):
+    """Two threads run q3 with different SEGMENTs and DATEs over the same
+    tables, again and again, at the same time: they share the pack and every
+    filter plane, each makes a verdict of its own, and neither ever reads the
+    other's."""
+    import threading
+
+    from daft_tpu.device.residency import manager
+
+    manager().clear()
+    variants = [dict(segment="BUILDING", cut=(1995, 3, 15)),
+                dict(segment="MACHINERY", cut=(1994, 9, 1))]
+    want = [_host_answer(lambda kw=kw: _q3_shaped(tpch_like, **kw)) for kw in variants]
+    assert want[0] != want[1]
+    start = threading.Barrier(2)
+    got, failed = [[], []], []
+
+    def client(k):
+        try:
+            start.wait()
+            for _ in range(6):
+                got[k].append(_q3_shaped(tpch_like, **variants[k]).to_pydict())
+        except BaseException as e:  # noqa: BLE001 - reported by the asserting thread
+            failed.append(e)
+
+    jb = counters.device_join_batches
+    with _morselized("on"):     # (the configuration is the process's: both threads run under it)
+        _q3_shaped(tpch_like, **variants[0]).to_pydict()       # warm: the slots are resident
+        threads = [threading.Thread(target=client, args=(k,)) for k in (0, 1)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+    assert not failed, failed
+    assert counters.device_join_batches - jb >= 13, counters.rejections
+    for k in (0, 1):
+        assert len(got[k]) == 6
+        for answer in got[k]:
+            _assert_close(want[k], answer)
+    manager().clear()
+
+
+def test_a_join_verdict_is_keyed_on_the_predicates_skeleton_not_its_values(tpch_like, star):
+    """Two DATEs of q3's `l_shipdate > DATE` share one placement verdict
+    (plan/stats.selectivity reads the operators, never a value); an `is_in`
+    of another length is another skeleton, and another decision."""
+    from daft_tpu.config import execution_config as get_config
+    from daft_tpu.execution import executor
+    from daft_tpu.plan import physical as pp
+
+    def node_of(df, kind):
+        with _morselized("on"):
+            plan = pp.translate(df._builder.optimize()._plan, get_config())
+        found, todo = [], [plan]
+        while todo:
+            n = todo.pop()
+            if isinstance(n, kind):
+                found.append(n)
+            todo.extend(n.children())
+        assert len(found) == 1
+        return found[0]
+
+    cfg, layout = get_config(), (1, _MORSEL)
+    # the plans hold the same tables: their dims' identity tokens agree too
+    key = lambda node, topn: executor._decision_key(node, _MORSEL, cfg, topn, layout)
+    march, june = (node_of(_q3_shaped(tpch_like, cut=cut), pp.DeviceJoinTopN)
+                   for cut in ((1995, 3, 15), (1995, 6, 1)))
+    assert repr(march.spec.predicate) != repr(june.spec.predicate)
+    assert key(march, True) == key(june, True)
+
+    fact, d1, _d2 = star
+
+    def by_quantities(qs):
+        return node_of(fact.where(col("f_q").is_in(qs))
+                       .join(d1, left_on="f_k1", right_on="d1_k")
+                       .groupby("d1_grp").agg(col("f_v").sum().alias("sv")), pp.DeviceJoinAgg)
+
+    two, other_two, three = (by_quantities(qs) for qs in ([1, 2], [3, 4], [1, 2, 3]))
+    assert repr(two.spec.predicate) != repr(other_two.spec.predicate)
+    assert key(two, False) == key(other_two, False) != key(three, False)
+
+
 # ---- the fused TopN over a fact of any number of batches ------------------------------
 #
 # q3- and q10-shaped star joins whose group-by spans one dimension's key
@@ -1467,7 +1679,7 @@ def _win_q_wide(fact, dim, small):
 
 
 def _win_q_one_row(fact, dim, small):
-    # the dim gives a filter and no column: its pack is the ok row alone
+    # the dim gives a filter and no column: what is gathered from is the verdict's one row alone
     return (fact.join(dim, left_on="f_k", right_on="d_k")
             .where(col("d_w") < 11.0)
             .groupby("f_q")

@@ -105,6 +105,40 @@ def test_sharded_join_answers_as_one_chip_does(topn_tables, tpch_like, shape):
         assert four["revenue"][0] == pytest.approx(revenue, rel=1e-6)
 
 
+@pytest.mark.usefixtures("one_bucket")
+@pytest.mark.parametrize("shape", ["q3", "q5"])
+def test_a_sharded_joins_filter_values_are_arguments(tpch_like, shape):
+    """q3- and q5-shaped joins under mesh_devices=4 with one value set after
+    another (a SEGMENT or REGION, a DATE, a value the dictionary lacks): the
+    host tier's answers, every join dispatch spanning the four devices, the
+    verdict made once a query whole on every chip, and from the second value
+    set on no slot rebuilt, no program traced, nothing built and nothing
+    uploaded: the pack is never copied to the chips again for a value."""
+    from daft_tpu.device.residency import manager
+
+    manager().clear()
+    make = tj._q3_shaped if shape == "q3" else tj._q5_shaped
+    name = "segment" if shape == "q3" else "region"
+    other = "MACHINERY" if shape == "q3" else "EUROPE"
+    answers = []
+    for k, kw in enumerate([dict(), {name: other}, {name: other, "cut": (1994, 6, 1)},
+                            {name: "NO SUCH VALUE"}, dict()]):
+        q = lambda: make(tpch_like, **kw)
+        four, c4 = _run(q, MESH)
+        tj._assert_close(tj._host_answer(q), four)
+        joins = c4["device_join_batches"]
+        assert joins > 0 and c4["device_join_mesh_batches"] == joins, counters.rejections
+        assert c4["join_filter_literal_args"] == (2 if shape == "q3" else 3)
+        if k:
+            assert not any(c4.get(c, 0) for c in (
+                "hbm_literal_rebuilds", "join_filter_program_traces", "join_provision_traces",
+                "device_stage_program_traces", "hbm_cache_misses", "hbm_h2d_bytes")), (kw, c4)
+        answers.append(four)
+    assert answers[0] == answers[4] and answers[0] != answers[1] != answers[2]
+    assert not next(iter(answers[3].values()))
+    manager().clear()
+
+
 # ---- (b) the shards' tables add up to the one chip's ---------------------------------------
 
 @pytest.mark.usefixtures("one_bucket")
