@@ -98,11 +98,14 @@ class ExecutionConfig:
     # bucket at morsel_size_rows — one compiled dispatch then covers N morsels
     # and the dispatch RTT amortizes N-fold. 0 disables coalescing
     # (every morsel dispatches individually, the pre-coalescing behavior).
-    # A join over contiguous morsels of a resident table, which glue at no
-    # copy, holds them to grouped_stage.DISPATCH_SEGMENTS such buckets a
-    # device (batching.coalesce_target_rows(resident_rows=...); fewer of a
-    # short fact, which is never one dispatch): told from the morsels and the
-    # table's length, not from a setting.
+    # A join over a resident table takes it in zero-copy ranges of
+    # grouped_stage.DISPATCH_SEGMENTS such buckets a device
+    # (batching.coalesce_target_rows(resident_rows=...); fewer of a short
+    # fact, which is never one dispatch): ranges its driver cuts of a table
+    # it reads directly (a select over one in-memory table), or contiguous
+    # morsels the coalescer glues back where the fact came through the
+    # pipeline. Told from the plan, the morsels and the table's length, not
+    # from a setting.
     batch_fill_target: float = field(
         default_factory=lambda: _env_float("DAFT_TPU_BATCH_FILL", 0.5)
     )

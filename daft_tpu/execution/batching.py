@@ -198,8 +198,11 @@ def coalesce_target_rows(cfg, shards: int = 1, resident_rows: int = 0) -> int:
     batch_fill_target full.
 
     `resident_rows` (the rows of a resident table a JOIN reads as its fact):
-    the length of a join dispatch over contiguous morsels of that table
-    (stage.DispatchCoalescer's resident target), which glue at no copy:
+    the length of a join dispatch over that table: of the ranges the join
+    driver cuts of a table it reads directly (executor._feed_resident: the
+    whole morsels that reach this threshold), and of the contiguous morsels
+    a coalescer glues at no copy where the fact came through the pipeline
+    (stage.DispatchCoalescer's resident target):
     resident_dispatch_segments buckets a shard, since the host's path a join
     dispatch (look-ups, two launches) costs the same whatever the rows behind
     it, and the join's programs walk a long dispatch a bucket at a time. The

@@ -1112,9 +1112,11 @@ def _make_coalescer(feed, cfg, shards: int = 1, resident_rows: int = 0):
     morsels with its bucket at least batch_fill_target full. A run that
     shards a dispatch's rows over `shards` devices fills a bucket a shard.
     `resident_rows` (a join run whose programs walk a long dispatch in
-    segments, over a fact that reads that many rows of a resident table):
-    contiguous morsels of the table, which glue at no copy, are held to the
-    longer target of coalesce_target_rows(resident_rows=...)."""
+    segments, over a fact that reads that many rows of resident tables and
+    still comes through the pipeline, being more than a select over one of
+    them): contiguous morsels of a table, which glue at no copy, are held to
+    the longer target of coalesce_target_rows(resident_rows=...), the
+    length _feed_resident cuts a table it reads directly."""
     from .batching import coalesce_target_rows
 
     target = coalesce_target_rows(cfg, shards)
@@ -1196,6 +1198,11 @@ def _run_device_join(node, label: str, make_run, assemble,
     traffic is tiny (gathers read resident planes; every dim-sized upload is
     series_keyed-cached), so the decision weighs the amortized upload and
     factorize investment + one d2h round trip against host probe+agg passes.
+
+    A fact or a dimension whose plan is a select over ONE in-memory table is
+    read from the table on this thread (_resident_select: the dimension
+    whole, the fact in the ranges it is dispatched in, _feed_resident); any
+    other plan, the host plan and every fallback run through the pipeline.
     """
     from ..config import execution_config
     from ..ops import counters as _counters
@@ -1227,18 +1234,36 @@ def _run_device_join(node, label: str, make_run, assemble,
     if stage is None or (grouped_required and not grouped):
         return _host()
 
-    raw_stream = _exec(node.fact)  # closeable generator (cancellation target)
-    try:
-        first = next(raw_stream, None)
-        if first is None:
+    # A fact that is a select over one resident table is read as ranges of
+    # the table, cut here, on the thread that dispatches: no stage thread, no
+    # pool task and no channel stand between the table and its dispatch. The
+    # decision below still looks at the first two MORSELS the pipeline would
+    # have cut (iter_morsels makes those two and no more). Any other fact
+    # comes through the pipeline, a closeable generator (cancellation target).
+    table = _resident_select(node.fact) if _cuts_fixed_morsels(cfg) else None
+    if table is not None:
+        raw_stream = None
+        fact_morsels = _table_morsels(table, cfg)
+    else:
+        raw_stream = fact_morsels = _exec(node.fact)
+
+    def _close_fact() -> None:
+        # a table read in ranges leaves nothing to close: no stage was
+        # started and no generator is suspended over it
+        if raw_stream is not None:
             raw_stream.close()
+
+    try:
+        first = next(fact_morsels, None)
+        if first is None:
+            _close_fact()
             return _host()
         if cfg.device_mode == "auto" and first.num_rows < cfg.device_min_rows:
             _counters.reject("cost", f"{label}: below device_min_rows",
                              f"({first.num_rows} rows)")
             _placement.ledger().gate(label, "below device_min_rows",
                                      first.num_rows, only_scoped=True)
-            raw_stream.close()
+            _close_fact()
             return _host()
         # a previously-rejected query shape skips dim materialization + the
         # sampled-cardinality estimate entirely (repeated interactive queries
@@ -1260,7 +1285,7 @@ def _run_device_join(node, label: str, make_run, assemble,
             _placement.ledger().record(label, "host", first.num_rows,
                                        cached=True,
                                        reason="host wins (cached decision)")
-            raw_stream.close()
+            _close_fact()
             return _host()
         second = None
         if cfg.device_mode == "auto" and stream_wide:
@@ -1272,9 +1297,13 @@ def _run_device_join(node, label: str, make_run, assemble,
                 # the device path anyway): observed second-partition morsels
                 # widen the coalesce horizon. Skipped entirely when
                 # coalescing is disabled — the horizon is 1.0 regardless.
-                second = next(raw_stream, None)
-        fact_stream = itertools.chain(
-            [first] if second is None else [first, second], raw_stream)
+                second = next(fact_morsels, None)
+        if table is not None:
+            # the peeks consumed nothing: the table is walked from its start
+            fact_stream = _table_morsels(table, cfg)
+        else:
+            fact_stream = itertools.chain(
+                [first] if second is None else [first, second], raw_stream)
         from ..ops.region import single_batch_horizon
 
         # a fused TopN held to one batch is a one-batch region by
@@ -1282,13 +1311,21 @@ def _run_device_join(node, label: str, make_run, assemble,
         # not a local constant (ops/region.py single_batch_horizon)
         dim_batches = {}
         for name, plan in node.dim_plans:
-            dim_batches[name] = _concat_parts(list(_exec(plan)), plan.schema)
+            dim = _resident_select(plan)
+            if dim is not None:
+                # a select over one resident table is the table's own
+                # columns: taken whole, here, not cut into morsels and glued
+                _counters.bump("join_resident_dims")
+            else:
+                dim = list(_exec(plan))
+            dim_batches[name] = _concat_parts(dim, plan.schema)
         ctx = _JoinContext(node.spec, dim_batches)
         batch0 = next((b for b in first.batches if b.num_rows > 0), None)
         # a join whose group ids are not made on the host a batch at a time
         # takes a resident fact DISPATCH_SEGMENTS buckets a dispatch (a
-        # sharded one does by what it is: sharded_join_reason); its coalescer
-        # (below) tells a resident run from the morsels themselves
+        # sharded one does by what it is: sharded_join_reason): the ranges
+        # of a table read directly, or what the coalescer of a fact that came
+        # through the pipeline tells from the morsels themselves (below)
         from ..ops.device_join import host_ids_reason
 
         long_chip = batch0 is not None and not host_ids_reason(
@@ -1382,7 +1419,7 @@ def _run_device_join(node, label: str, make_run, assemble,
             _placement.ledger().annotate(
                 prec, f"not sharded over the mesh: {declined}")
         if not tier:    # `auto`: the host won
-            raw_stream.close()
+            _close_fact()
             return _host()
         shards = mesh_width if tier == "mesh" else 1
         run = make_run(stage, grouped, ctx, shards)
@@ -1410,33 +1447,38 @@ def _run_device_join(node, label: str, make_run, assemble,
                                 "runtime", f"{label}: multi-batch fact and no "
                                 "run-wide group ids", f"({why})")
                             fb.cancel()  # no dispatch happened: nothing to observe
-                            raw_stream.close()
+                            _close_fact()
                             return _host()
                         first_b = b
                 if first_b is not None:
                     fed_rows = first_b.num_rows
                     run.feed_batch(first_b)
             else:
-                # coalesce fact morsels like the agg paths: one gather-join
-                # dispatch per super-batch. A single-batch flush hands the
-                # batch through as it is, and one of several contiguous
-                # morsels of a resident table is a zero-copy range of it
-                # (Series.concat), so series_keyed slots, keyed on the rows
-                # a batch views and not on its objects, hit on a repeat query.
-                # Such morsels are held to DISPATCH_SEGMENTS buckets a device,
-                # fewer where the fact is short (a dispatch is never all of
-                # it: batching.resident_dispatch_segments).
+                # One gather-join dispatch covers DISPATCH_SEGMENTS buckets a
+                # device of a resident fact, fewer where the fact is short (a
+                # dispatch is never all of it: batching.
+                # resident_dispatch_segments): a zero-copy range of the table,
+                # so series_keyed slots, keyed on the rows a batch views and
+                # not on its objects, hit on a repeat query. A table read
+                # directly is cut into those ranges here (_feed_resident); a
+                # fact that came through the pipeline is coalesced like the
+                # agg paths' (a single-batch flush hands the batch through as
+                # it is, contiguous morsels of a resident table glue back to
+                # the range they were cut from: Series.concat).
                 long_run = shards > 1 or long_chip
-                coalescer = _make_coalescer(
-                    run.feed_batch, cfg, shards,
-                    resident_rows=(_resident_rows(node.fact) or 0) if long_run else 0)
-                feed = coalescer.add if coalescer is not None else run.feed_batch
-                for part in fact_stream:
-                    fed_rows += part.num_rows
-                    for b in part.batches:
-                        feed(b)
-                if coalescer is not None:
-                    coalescer.close()
+                resident_rows = (_resident_rows(node.fact) or 0) if long_run else 0
+                if table is not None:
+                    fed_rows = _feed_resident(table, run, cfg, shards, resident_rows)
+                else:
+                    coalescer = _make_coalescer(run.feed_batch, cfg, shards,
+                                                resident_rows=resident_rows)
+                    feed = coalescer.add if coalescer is not None else run.feed_batch
+                    for part in fact_stream:
+                        fed_rows += part.num_rows
+                        for b in part.batches:
+                            feed(b)
+                    if coalescer is not None:
+                        coalescer.close()
             fb.set_rows(fed_rows)
             out = assemble(run, stage, grouped)
         if shards > 1:
@@ -1445,7 +1487,7 @@ def _run_device_join(node, label: str, make_run, assemble,
         return out
     except DeviceFallback as e:
         _counters.reject("runtime", f"{label}: device fallback", str(e))
-        raw_stream.close()
+        _close_fact()
         return _host()
 
 
@@ -1521,6 +1563,97 @@ def _resident_rows(n) -> Optional[int]:
             return None
         total += rows
     return total
+
+
+def _is_column_select(e: Expression) -> bool:
+    from ..expressions.expressions import Alias
+
+    while isinstance(e, Alias):
+        e = e.child
+    return isinstance(e, ColumnRef)
+
+
+def _resident_select(plan) -> Optional[List[MicroPartition]]:
+    """The partitions of the one in-memory table a join's fact or dimension
+    plan selects from, the plan's projection applied, or None. It answers
+    only where the plan is a chain of Projects whose expressions are column
+    references or aliases of them over ONE InMemoryScan: a select, which
+    views a range of any length at no copy, so the pool's width has no work
+    to do on it. A computed projection, a filter, a streamed or task scan, a
+    concat: None, and the plan runs through the pipeline (_exec)."""
+    selects = []
+    n = plan
+    while isinstance(n, pp.Project):
+        if not all(_is_column_select(e) for e in n.projection):
+            return None
+        selects.append(n)
+        n = n.input
+    if not selects or not isinstance(n, pp.InMemoryScan):
+        return None
+    parts = n.partitions
+    for sel in reversed(selects):
+        parts = [MicroPartition(
+            sel.schema, [eval_projection(b, sel.projection) for b in p.batches]
+            or [RecordBatch.empty(sel.schema)]) for p in parts]
+    return parts
+
+
+def _cuts_fixed_morsels(cfg) -> bool:
+    """Whether a Project over a table would hand it on as morsels of the
+    fixed size, through a stage thread and the pool: what the join driver
+    can cut itself. With the pipeline off nothing is cut and nothing is
+    handed over; a feedback-driven strategy sizes its morsels from the pool's
+    timings: such a fact keeps the road through _exec."""
+    return cfg.batching_mode == "static" and _pipeline_on()
+
+
+def _table_morsels(table: List[MicroPartition], cfg) -> Iterator[MicroPartition]:
+    """The morsels a Project over `table` hands on (_map_op), made one at a
+    time on the calling thread."""
+    from .pipeline import iter_morsels
+
+    return (m for part in table for m in iter_morsels(part, cfg.morsel_size_rows))
+
+
+def _feed_resident(table: List[MicroPartition], run, cfg, shards: int,
+                   resident_rows: int) -> int:
+    """Hand a resident fact to `run.feed_batch` on this thread; the rows fed.
+
+    A batch the pipeline would have cut into morsels (pipeline.cut_batches)
+    goes as zero-copy ranges of itself: as many whole morsels as reach
+    coalesce_target_rows(resident_rows=...) where the run takes a long
+    dispatch (`resident_rows` > 0), one morsel where it does not. Those are
+    the starts and lengths a DispatchCoalescer flushes of the same morsels,
+    so the programs, their shapes and the slots keyed on the rows a range
+    views are the same; a range never spans two batches. A batch that is not
+    cut (a short one) is no view of a longer one: it goes through a
+    coalescer at the plain threshold, which glues short batches by copy."""
+    from ..ops import counters as _counters
+    from .batching import coalesce_target_rows
+    from .pipeline import cut_batches
+
+    morsel = cfg.morsel_size_rows
+    target = coalesce_target_rows(cfg, shards, resident_rows=resident_rows) \
+        if resident_rows else 0
+    # whole morsels until the target is reached
+    range_rows = max(-(-target // morsel), 1) * morsel
+    coalescer = _make_coalescer(run.feed_batch, cfg, shards)
+    fed = 0
+    for part in table:
+        fed += part.num_rows
+        for b, cut in cut_batches(part, morsel, range_rows):
+            if not cut:
+                (coalescer.add if coalescer is not None else run.feed_batch)(b)
+                continue
+            if coalescer is not None:
+                coalescer.flush()    # what came whole before this batch goes first
+            # the extent a coalescer's flush had around a dispatch
+            with _profile_span("join.range", "device", rows=b.num_rows):
+                run.feed_batch(b)
+            _counters.bump("join_resident_ranges")
+    if coalescer is not None:
+        coalescer.close()
+    return fed
 
 
 def _decision_key(node, rows: int, cfg, topn: bool, layout: tuple) -> tuple:
@@ -3202,10 +3335,13 @@ def _concat_parts(parts: List[MicroPartition], schema) -> RecordBatch:
         return RecordBatch.empty(schema)
     if len(batches) == 1:
         return batches[0]
-    # the morsels a Project cut a resident table into glue back to the
-    # table's own columns without a copy (Series.concat: contiguous views of
-    # one root), so a dim keeps its identity, its dictionary codes and its
-    # residency slots across queries; anything else concatenates
+    # a join's dimension that is a select over one resident table arrives
+    # here as the table's own batch (_resident_select: one batch, returned
+    # above); the morsels a Project cut of a table on the pipeline's road
+    # glue back to the table's own columns without a copy (Series.concat:
+    # contiguous views of one root). Either way a dim keeps its identity, its
+    # dictionary codes and its residency slots across queries; anything else
+    # concatenates
     return RecordBatch.concat(batches)
 
 

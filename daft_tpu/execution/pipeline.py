@@ -249,18 +249,35 @@ def morsels(part: MicroPartition, morsel_rows: int) -> List[MicroPartition]:
     """Split one partition into ~morsel_rows zero-copy slices (arrow slicing)
     so a single large in-memory partition can fan out across the pool. Small
     partitions pass through untouched."""
-    n = part.num_rows
-    if n <= morsel_rows * 2 or not part.batches:
-        return [part]
-    out: List[MicroPartition] = []
+    return list(iter_morsels(part, morsel_rows))
+
+
+def iter_morsels(part: MicroPartition, morsel_rows: int) -> Iterator[MicroPartition]:
+    """`morsels`, cut one at a time as the caller pulls: whoever needs a
+    table's first morsels (the join driver's placement decision over a
+    resident fact) makes no more of them."""
+    if part.num_rows <= morsel_rows * 2 or not part.batches:
+        yield part
+        return
+    for b, _cut in cut_batches(part, morsel_rows):
+        if b.num_rows:
+            yield MicroPartition(part.schema, [b])
+
+
+def cut_batches(part: MicroPartition, morsel_rows: int, piece_rows: int = 0):
+    """(batch, cut) over a partition's batches under the one rule of what is
+    cut: a batch of more than two morsels, in a partition of more than two,
+    goes as zero-copy slices of `piece_rows` rows (a morsel's by default; the
+    join driver asks for a resident dispatch's length) with `cut` true; any
+    other goes whole, as the object it is, with `cut` false."""
+    step = piece_rows or morsel_rows
+    small = part.num_rows <= morsel_rows * 2
     for b in part.batches:
-        if b.num_rows <= morsel_rows * 2:
-            if b.num_rows:
-                out.append(MicroPartition(part.schema, [b]))
+        if small or b.num_rows <= morsel_rows * 2:
+            yield b, False
             continue
-        for s in range(0, b.num_rows, morsel_rows):
-            out.append(MicroPartition(part.schema, [b.slice(s, min(s + morsel_rows, b.num_rows))]))
-    return out or [part]
+        for s in range(0, b.num_rows, step):
+            yield b.slice(s, min(s + step, b.num_rows)), True
 
 
 def morsel_stream(stream: Iterator, morsel_rows: int) -> Iterator:

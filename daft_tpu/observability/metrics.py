@@ -145,6 +145,12 @@ DEVICE_COUNTER_NAMES = (
     "mesh_dispatches",         # multi-device shard_map/pjit dispatches issued
     "mesh_unavailable_fallbacks",  # forced mesh_devices > local devices -> single-chip
     "device_join_batches",     # batches through the gather-join device stages
+    # a join whose fact or dimension plan is a select over one resident
+    # table reads the table on the dispatching thread (executor.
+    # _resident_select): no morsel comes in, so the coalescer's counters
+    # below stand still for it
+    "join_resident_ranges",    # zero-copy ranges of a resident fact handed to a join run's feed_batch
+    "join_resident_dims",      # dimensions taken whole from their resident table
     "join_provision_calls",    # join dispatches whose columns came from one traced program
     "join_provision_traces",   # provisioning programs traced (0 on a repeat query shape)
     "join_window_gathers",     # adjacent-dimension gathers that read a batch-long window of the pack, summed over those dispatches

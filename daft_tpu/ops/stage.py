@@ -600,19 +600,26 @@ class DispatchCoalescer:
     (device_join series_keyed slots, resident-table repeat queries) still hit.
 
     ``resident_target_rows`` (a join run's, executor._make_coalescer): the
-    length of a dispatch over a RESIDENT input. What is pending is told from
-    what it is, not from a setting: while every pending morsel is a zero-copy
-    view of one table's rows, each starting where the one before ended
-    (resident_rows_after), and the table has rows left to come, gluing them
-    costs no copy and nothing waits on the next (a slice of a table that is
-    there), so they are held until they reach this longer target, whatever
-    the deadline, and one dispatch pays the host's look-ups and launches for
-    all of them. A morsel that is no such view (a streamed or concatenated
-    input, a gap, another table) puts the run back under ``target_rows``:
-    there the concat copies.
+    length of a dispatch over a RESIDENT input that came through the
+    pipeline as morsels. (A join whose fact is a select over ONE in-memory
+    table never gets here: its driver cuts the table into ranges of this
+    length itself, executor._feed_resident, and no morsel comes in. What
+    still does is a fact whose plan holds more than that select, such as two
+    concatenated tables: the Projects above them cut morsels, which are
+    views.) What is pending is told from what it is, not from a setting:
+    while every pending morsel is a zero-copy view of one table's rows, each
+    starting where the one before ended (resident_rows_after), and the table
+    has rows left to come, gluing them costs no copy and nothing waits on
+    the next (a slice of a table that is there), so they are held until they
+    reach this longer target, whatever the deadline, and one dispatch pays
+    the host's look-ups and launches for all of them. A morsel that is no
+    such view (a streamed or computed input, a gap, another table) puts the
+    run back under ``target_rows``: there the concat copies.
 
     Counters (coarse, per flush — never per row): ``coalesce_morsels_in`` /
-    ``dispatch_coalesced`` give the amortization factor,
+    ``dispatch_coalesced`` give the amortization factor (of what came in as
+    morsels: a join's ranges of a table read directly count as
+    ``join_resident_ranges``),
     ``bucket_fill_rows`` / ``bucket_capacity_rows`` the padding efficiency —
     the counter DELTAS are the per-query source of truth (they land in
     QueryEnd.metrics).

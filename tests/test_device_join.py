@@ -1191,15 +1191,18 @@ def test_the_select_program_is_handed_the_leaves_it_was_compiled_for(monkeypatch
 
 # ---- a dispatch over a resident fact covers several buckets ------------------------------
 #
-# Contiguous morsels of a resident table glue at no copy, so a join's
-# coalescer holds them to DISPATCH_SEGMENTS buckets and the run dispatches the
-# range once; the programs walk it a segment (a morsel's bucket) at a time.
+# A resident table is read as zero-copy ranges of itself, DISPATCH_SEGMENTS
+# buckets each, cut by the join driver on its own thread (a fact that comes
+# through the pipeline has its contiguous morsels glued back to the same
+# ranges by the coalescer); the programs walk a range a segment (a morsel's
+# bucket) at a time.
 
 
 def _coalesce_counters():
     from daft_tpu.observability.metrics import registry
 
     names = ("coalesce_morsels_in", "dispatch_coalesced", "device_join_batches",
+             "join_resident_ranges", "join_resident_dims",
              "hbm_cache_misses", "hbm_h2d_bytes")
     return {k: registry().get(k) for k in names}
 
@@ -1215,6 +1218,17 @@ def _counted(q):
 _LONG_MORSELS = 19      # two dispatches of eight and a tail of three: its bucket holds four segments
 
 
+def _long_query(shape, morsels=_LONG_MORSELS):
+    """(tables, query) of a q3-, q10- or q5-shaped join over a resident fact
+    of `morsels` morsels less a hundred rows."""
+    n_l = _MORSEL * morsels - 100
+    if shape == "q5":
+        t = _tpch_like(n_l=n_l)
+        return t, lambda: _q5_shaped(t)
+    t = _topn_tables(n_l=n_l)
+    return t, lambda: {"q3": _topn_q3, "q10": _topn_q10}[shape](t)
+
+
 @pytest.mark.parametrize("shape", ["q3", "q10", "q5"])
 def test_a_long_dispatch_gives_the_one_bucket_answer(shape, monkeypatch):
     """A run-wide TopN (q3 dense, q10 sparse) and a grouped join (q5, dictionary
@@ -1222,38 +1236,328 @@ def test_a_long_dispatch_gives_the_one_bucket_answer(shape, monkeypatch):
     dispatch (8, 8, and a tail of 3 whose bucket's last segment is all
     padding and whose third is part full) the host engine's answer, and the
     answer of a bucket a dispatch (the TopN's to the bit: a segment adds what
-    a dispatch of its own added, in the same order); 3 dispatches for 19
-    morsels; and a repeat query misses no slot and uploads nothing."""
+    a dispatch of its own added, in the same order); 3 ranges of the table
+    for its 19 morsels, handed on by the driver itself (no morsel reaches a
+    coalescer); and a repeat query misses no slot and uploads nothing."""
     import daft_tpu.ops.grouped_stage as gs
     from daft_tpu.device.residency import manager
 
     manager().clear()
-    n_l = _MORSEL * _LONG_MORSELS - 100
-    if shape == "q5":
-        t = _tpch_like(n_l=n_l)
-        q = lambda: _q5_shaped(t)
-    else:
-        t = _topn_tables(n_l=n_l)
-        q = lambda: {"q3": _topn_q3, "q10": _topn_q10}[shape](t)
+    _t, q = _long_query(shape)
     host = _host_answer(q)
     assert gs.DISPATCH_SEGMENTS == 8
     first, cold = _counted(q)
-    assert cold["coalesce_morsels_in"] == _LONG_MORSELS
-    assert cold["dispatch_coalesced"] == cold["device_join_batches"] == 3
+    assert cold["coalesce_morsels_in"] == cold["dispatch_coalesced"] == 0
+    assert cold["join_resident_ranges"] == cold["device_join_batches"] == 3
     assert cold["hbm_cache_misses"] > 0
     _assert_close(host, first)
     again, warm = _counted(q)
     assert again == first
     assert warm["hbm_cache_misses"] == 0 and warm["hbm_h2d_bytes"] == 0, warm
-    assert warm["dispatch_coalesced"] == 3
+    assert warm["join_resident_ranges"] == 3 and warm["coalesce_morsels_in"] == 0
     monkeypatch.setattr(gs, "DISPATCH_SEGMENTS", 1)
     one, c1 = _counted(q)
-    assert c1["dispatch_coalesced"] == c1["device_join_batches"] == _LONG_MORSELS
+    assert c1["join_resident_ranges"] == c1["device_join_batches"] == _LONG_MORSELS
+    assert c1["coalesce_morsels_in"] == 0
     if shape == "q5":
         _assert_close(one, first)
     else:
         assert one == first
     manager().clear()
+
+
+def _stage_threads_left(within=5.0):
+    """Names of the `daft-stage` threads still alive after `within` seconds."""
+    import threading
+    import time
+
+    stages = lambda: [th.name for th in threading.enumerate() if th.name.startswith("daft-stage")]
+    deadline = time.time() + within
+    while stages() and time.time() < deadline:
+        time.sleep(0.01)
+    return stages()
+
+
+def _spy_fed_batches(monkeypatch, fed):
+    """Append every batch a join run is fed to `fed`."""
+    import daft_tpu.ops.device_join as dj
+
+    for cls in (dj.DeviceJoinTopNRun, dj.DeviceJoinGroupedRun, dj.DeviceJoinUngroupedRun):
+        def feed_batch(self, batch, _real=cls.feed_batch):
+            fed.append(batch)
+            return _real(self, batch)
+        monkeypatch.setattr(cls, "feed_batch", feed_batch)
+
+
+def _views_of(table, batches):
+    """[(start, rows)] of `batches`, each of which must view those rows of
+    `table`'s one batch in every column."""
+    (whole,) = table._result[0].batches
+    out = []
+    for b in batches:
+        spans = set()
+        for name in b.column_names():
+            root, off = b.get_column(name).lineage()
+            assert root is whole.get_column(name), name
+            spans.add((off, b.num_rows))
+        (span,) = spans
+        out.append(span)
+    return out
+
+
+@pytest.mark.parametrize("shape", ["q3", "q10", "q5"])
+def test_the_driver_cuts_the_ranges_the_coalescer_flushed(shape, monkeypatch):
+    """The ranges of a resident fact the join driver hands to feed_batch are,
+    start for start and length for length, what a DispatchCoalescer at the
+    resident target makes of the table's morsels: views of the table's own
+    columns (nothing copied), eight morsels, eight, and the tail."""
+    t, q = _long_query(shape)
+    fed = []
+    _spy_fed_batches(monkeypatch, fed)
+    with _morselized("on"):
+        q().to_pydict()
+    glued = []
+    coal = _coalescer(glued, t["lineitem"].count_rows())
+    for m in _resident_morsels(t["lineitem"], _MORSEL):
+        coal.add(m)
+    coal.close()
+    want = _views_of(t["lineitem"], glued)
+    assert want == [(0, 8 * _MORSEL), (8 * _MORSEL, 8 * _MORSEL),
+                    (16 * _MORSEL, 3 * _MORSEL - 100)]
+    assert _views_of(t["lineitem"], fed) == want
+
+
+def _spy_pipeline(monkeypatch):
+    """(stage nodes spawned, pool fan-outs started) while the spy stands."""
+    from daft_tpu.execution import pipeline as pl
+
+    spawned, fanned = [], []
+    real_spawn, real_pmap = pl.spawn_stage, pl.pmap_stream
+
+    def spawn_stage(gen, maxsize=4, node=None):
+        spawned.append(type(node).__name__)
+        return real_spawn(gen, maxsize=maxsize, node=node)
+
+    def pmap_stream(stream, fn, window=0, strategy=None):
+        fanned.append(fn)
+        return real_pmap(stream, fn, window=window, strategy=strategy)
+
+    monkeypatch.setattr(pl, "spawn_stage", spawn_stage)
+    monkeypatch.setattr(pl, "pmap_stream", pmap_stream)
+    return spawned, fanned
+
+
+@pytest.mark.parametrize("shape", ["q3", "q10", "q5"])
+def test_a_select_over_a_resident_table_starts_no_stage_and_no_pool_task(shape, monkeypatch):
+    """The fact and the dimensions of a captured join are selects over
+    in-memory tables: the driver reads them on its own thread, so no
+    `daft-stage` thread starts for a Project and nothing is fanned out over
+    the pool (q5's sort above the join is a stage of its own, as before)."""
+    t, q = _long_query(shape)
+    with _morselized("on"):
+        q().to_pydict()     # the cold run may hash and encode on the pool
+    spawned, fanned = _spy_pipeline(monkeypatch)
+    _answer, c = _counted(q)
+    assert "Project" not in spawned and spawned == (["PhysSort"] if shape == "q5" else [])
+    assert fanned == []
+    assert c["join_resident_ranges"] == 3
+    # the dimensions behind a Project are taken whole; a bare scan yields its
+    # partitions as it always did
+    assert c["join_resident_dims"] == {"q3": 1, "q10": 2, "q5": 2}[shape]
+
+
+def _q3_over(t, lineitem):
+    return _topn_q3({**t, "lineitem": lineitem})
+
+
+_NO_SELECT = {
+    # (the fact, morsels in, dispatches): what the pipeline and the coalescer
+    # made of it before the driver read tables itself
+    "computed": (lambda t: t["lineitem"].with_column(
+        "l_extendedprice", col("l_extendedprice") * 2.0), 19, 19),
+    "concat": (lambda t: t["lineitem"].concat(
+        _topn_tables(n_l=_MORSEL * 9)["lineitem"]), 28, 5),
+}
+
+
+@pytest.mark.parametrize("case", list(_NO_SELECT))
+def test_a_fact_that_is_no_select_over_one_table_takes_the_pipeline(case, monkeypatch):
+    """A computed projection (its filter stays above the scan) and two
+    concatenated tables are no select over one table: the fact comes through
+    the pipeline's stages and the coalescer, morsel for morsel and dispatch
+    for dispatch as it did, and the answer is the host engine's."""
+    t, _q = _long_query("q3")
+    make, morsels_in, dispatches = _NO_SELECT[case]
+    q = lambda: _q3_over(t, make(t))
+    host = _host_answer(q)
+    spawned, fanned = _spy_pipeline(monkeypatch)
+    answer, c = _counted(q)
+    assert c["join_resident_ranges"] == 0
+    assert c["coalesce_morsels_in"] == morsels_in
+    assert c["dispatch_coalesced"] == c["device_join_batches"] == dispatches
+    assert "Project" in spawned and fanned
+    _assert_close(host, answer)
+
+
+def _physical(df):
+    from daft_tpu.config import execution_config as get_config
+    from daft_tpu.plan import physical as pp
+
+    return pp.translate(df._builder.optimize()._plan, get_config())
+
+
+@pytest.mark.parametrize("case", ["select", "alias", "two_selects", "computed", "filter",
+                                  "concat", "bare_scan", "parquet"])
+def test_resident_select_answers_for_a_select_over_one_table_only(case, tmp_path):
+    """executor._resident_select: the table's partitions with the projection
+    applied where the plan is Projects of column references (or aliases of
+    them) over one InMemoryScan, the columns the table's own; None for
+    anything else, which keeps the pipeline."""
+    from daft_tpu.execution import executor
+    from daft_tpu.plan import physical as pp
+
+    n = _MORSEL * 5
+    df = daft_tpu.from_pydict({"k": list(range(n)), "v": [float(i) for i in range(n)],
+                               "w": [i % 3 for i in range(n)]}).collect()
+    (whole,) = df._result[0].batches
+    if case == "select":
+        plan, names = _physical(df.select("v", "k")), {"v": "v", "k": "k"}
+    elif case == "alias":
+        plan, names = _physical(df.select(col("v").alias("x"), "k")), {"x": "v", "k": "k"}
+    elif case == "two_selects":
+        inner = _physical(df.select("v", "k"))
+        plan = pp.Project(inner, [col("k")], _physical(df.select("k")).schema)
+        names = {"k": "k"}
+    elif case == "computed":
+        plan, names = _physical(df.select((col("v") * 2).alias("v2"), "k")), None
+    elif case == "filter":
+        plan, names = _physical(df.where(col("w") == 1).select("v", "k")), None
+    elif case == "concat":
+        plan, names = _physical(df.select("v", "k").concat(df.select("v", "k"))), None
+    elif case == "bare_scan":
+        plan, names = _physical(df), None
+    else:
+        df.write_parquet(str(tmp_path))
+        plan, names = _physical(daft_tpu.read_parquet(str(tmp_path) + "/*.parquet").select("v", "k")), None
+    got = executor._resident_select(plan)
+    if names is None:
+        assert got is None
+        return
+    assert isinstance(plan, pp.Project)
+    (part,) = got
+    (batch,) = part.batches
+    assert batch.column_names() == list(names) and batch.num_rows == n
+    for out, src in names.items():
+        if out == src:
+            assert batch.get_column(out) is whole.get_column(src), "the table's own column"
+        else:
+            assert batch.get_column(out).to_arrow() is whole.get_column(src).to_arrow() \
+                or batch.get_column(out).to_arrow().equals(whole.get_column(src).to_arrow())
+
+
+@pytest.mark.parametrize("morsels", [1, 2, 19])
+def test_the_decision_reads_what_it_read(morsels, monkeypatch):
+    """What `auto` decides from is unchanged by the road the fact takes: the
+    decision key, the first morsel's layout, the rows tested against
+    device_min_rows and the horizons handed to _join_device_wins are, for a
+    fact of one morsel, of two and of nineteen, what the pipeline's first two
+    morsels gave (the road a plan that is no select still takes)."""
+    import jax
+    from daft_tpu.execution import executor
+
+    t = _topn_tables(n_l=_MORSEL * morsels)
+    q = lambda: _topn_q3(t)
+    host = _host_answer(q)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    seen = []
+    real_key, real_layout = executor._decision_key, executor._batch_layout
+
+    def decision_key(node, rows, cfg, topn, layout):
+        key = real_key(node, rows, cfg, topn, layout)
+        seen.append(("key", key))
+        return key
+
+    def batch_layout(part):
+        seen.append(("layout", real_layout(part), part.num_rows))
+        return real_layout(part)
+
+    def join_device_wins(node, ctx, batch, rows, grouped, stage, **kw):
+        seen.append(("wins", batch.num_rows, rows, kw["coalesce"], kw["mesh_ndev"],
+                     kw["mesh_coalesce"], kw["topn"]))
+        return False, None      # the host's answer, whatever was priced
+
+    monkeypatch.setattr(executor, "_decision_key", decision_key)
+    monkeypatch.setattr(executor, "_batch_layout", batch_layout)
+    monkeypatch.setattr(executor, "_join_device_wins", join_device_wins)
+
+    def decided():
+        seen.clear()
+        executor._DECISION_CACHE.clear()
+        with execution_config_ctx(device_mode="auto", device_min_rows=_MORSEL // 2,
+                                  morsel_size_rows=_MORSEL, pipeline_mode="force"):
+            out = q().to_pydict()
+        executor._DECISION_CACHE.clear()
+        return out, list(seen)
+
+    direct, mine = decided()
+    monkeypatch.setattr(executor, "_resident_select", lambda plan: None)
+    piped, theirs = decided()
+    assert direct == piped == host
+    # (the fused TopN's decision, then that of the join-aggregate its host
+    # plan holds)
+    assert mine == theirs and [s[0] for s in mine] == ["layout", "key", "wins"] * 2
+    first_rows = _MORSEL * morsels if morsels <= 2 else _MORSEL
+    assert {s[2] for s in mine if s[0] != "key"} == {first_rows}, \
+        "a morsel is what the decision sees"
+
+
+def test_a_resident_fact_under_device_min_rows_goes_to_the_host(monkeypatch):
+    """device_min_rows is tested against the first MORSEL of a table read
+    directly, as it was against the pipeline's: a fact whose first morsel is
+    shorter runs the host plan and dispatches nothing."""
+    import jax
+
+    t = _topn_tables(n_l=_MORSEL * 19)
+    host = _host_answer(lambda: _topn_q3(t))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    counters.reset()
+    with execution_config_ctx(device_mode="auto", device_min_rows=_MORSEL + 1,
+                              morsel_size_rows=_MORSEL, pipeline_mode="force"):
+        out = _topn_q3(t).to_pydict()
+    assert out == host
+    assert counters.device_join_batches == 0 and counters.join_resident_ranges == 0
+    assert any("below device_min_rows" in k for k in counters.rejections), counters.rejections
+
+
+@pytest.mark.parametrize("shape", ["q3", "q5"])
+def test_a_fallback_after_some_ranges_answers_from_the_host_plan(shape, monkeypatch):
+    """A DeviceFallback raised at the third range of a resident fact: the
+    host plan's answer, the rejection on record, and nothing left behind
+    (no stage was started for the fact, so there is none to close)."""
+    import daft_tpu.ops.device_join as dj
+    from daft_tpu.ops.grouped_stage import DeviceFallback
+
+    t, q = _long_query(shape)
+    host = _host_answer(q)
+    cls = dj.DeviceJoinTopNRun if shape == "q3" else dj.DeviceJoinGroupedRun
+    real, fed = cls.feed_batch, []
+
+    def feed_batch(self, batch):
+        if len(fed) == 2:
+            raise DeviceFallback("the third range will not go")
+        fed.append(batch.num_rows)
+        return real(self, batch)
+
+    monkeypatch.setattr(cls, "feed_batch", feed_batch)
+    counters.reset()
+    with _morselized("on"):
+        answer = q().to_pydict()
+    assert fed == [8 * _MORSEL, 8 * _MORSEL]
+    if shape == "q5":   # (q3's host plan holds a join-aggregate that reads the table again)
+        assert counters.join_resident_ranges == 2
+    assert any("device fallback" in k for k in counters.rejections), counters.rejections
+    _assert_close(host, answer)
+    assert not _stage_threads_left()
 
 
 def _resident_morsels(table, rows):

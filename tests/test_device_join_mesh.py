@@ -251,7 +251,8 @@ def long_tables():
 def test_a_long_sharded_dispatch_answers_as_a_bucket_a_shard_does(long_tables, shape, monkeypatch):
     """Over a resident fact of 45 morsels a sharded join dispatch covers
     DISPATCH_SEGMENTS buckets a shard (32 morsels, then a tail of 13 over
-    shards of 8, 5 and 0): two dispatches that each span the four devices,
+    shards of 8, 5 and 0): two ranges of the table, cut by the driver, two
+    dispatches that each span the four devices,
     the host engine's answer, the one chip's answer and the answer of a
     bucket a shard (12 dispatches); a repeat builds nothing."""
     import daft_tpu.ops.grouped_stage as gs
@@ -266,7 +267,7 @@ def test_a_long_sharded_dispatch_answers_as_a_bucket_a_shard_does(long_tables, s
     host = tj._host_answer(q)
     assert gs.DISPATCH_SEGMENTS == 8
     four, c4 = _run(q, MESH)
-    assert c4["coalesce_morsels_in"] == _LONG and c4["dispatch_coalesced"] == 2
+    assert c4["join_resident_ranges"] == 2 and c4.get("coalesce_morsels_in", 0) == 0
     assert c4["device_join_batches"] == c4["device_join_mesh_batches"] == 2, counters.rejections
     assert c4["device_join_mesh_shards"] == 2 * MESH and c4["hbm_cache_misses"] > 0
     tj._assert_close(host, four)
@@ -283,6 +284,129 @@ def test_a_long_sharded_dispatch_answers_as_a_bucket_a_shard_does(long_tables, s
         assert c4["device_topn_runs"] == 1 and c4["device_join_topn_batches"] == 2
     else:
         tj._assert_close(short, four)
+
+
+@pytest.mark.parametrize("shape", ["q3", "q5"])
+def test_the_driver_cuts_the_sharded_ranges_the_coalescer_flushed(long_tables, shape, monkeypatch):
+    """Over four shards the driver hands on the two ranges a coalescer at the
+    sharded resident target made of the table's 45 morsels (32, and the tail
+    of 13), views of the table's own columns; no Project stage starts, nothing
+    is fanned out over the pool, and no morsel reaches a coalescer."""
+    if shape == "q5":
+        t = tj._tpch_like(n_l=_MORSEL * _LONG - 100)
+        q = lambda: tj._q5_shaped(t)
+    else:
+        t, q = long_tables, lambda: tj._topn_q3(long_tables)
+    fed = []
+    tj._spy_fed_batches(monkeypatch, fed)
+    spawned, fanned = tj._spy_pipeline(monkeypatch)
+    _out, c = _run(q, MESH)
+    assert c["join_resident_ranges"] == c["device_join_mesh_batches"] == 2
+    assert c.get("coalesce_morsels_in", 0) == c.get("dispatch_coalesced", 0) == 0
+    assert "Project" not in spawned and fanned == []
+    glued = []
+    coal = tj._coalescer(glued, t["lineitem"].count_rows(), shards=MESH)
+    for m in tj._resident_morsels(t["lineitem"], _MORSEL):
+        coal.add(m)
+    coal.close()
+    want = tj._views_of(t["lineitem"], glued)
+    assert want == [(0, 32 * _MORSEL), (32 * _MORSEL, 13 * _MORSEL - 100)]
+    assert tj._views_of(t["lineitem"], fed) == want
+
+
+class _ProgramsAskedFor:
+    """Programs handed to the backend's compiler, or found in the persistent
+    cache instead, while `on` (jax.monitoring has no way to take a listener
+    off again: one for the module)."""
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.on, self.n = False, 0
+        monitoring.register_event_duration_secs_listener(self._duration)
+        monitoring.register_event_listener(self._event)
+
+    def _duration(self, event, _secs, **_kw):
+        if self.on and event == "/jax/core/compile/backend_compile_duration":
+            self.n += 1
+
+    def _event(self, event, **_kw):
+        if self.on and event == "/jax/compilation_cache/cache_hits":
+            self.n += 1
+
+    def during(self, fn):
+        self.on, self.n = True, 0
+        try:
+            fn()
+        finally:
+            self.on = False
+        return self.n
+
+
+@pytest.fixture(scope="module")
+def asked_for():
+    return _ProgramsAskedFor()
+
+
+@pytest.mark.parametrize("mesh", [1, MESH])
+def test_the_ranges_changed_no_program(long_tables, asked_for, mesh, monkeypatch):
+    """q3, q5 and q10 over one chip and over a mesh of four ask for the same
+    programs whichever road the fact and the dimensions take. With every
+    cache dropped the pipeline's road (what a plan that is no select takes,
+    and what every join took before) asks for N programs; the driver's own
+    ranges, run next with those programs still there, ask for none (every
+    program of theirs is one of the N); dropped again and run cold they ask
+    for N (and so for each of them)."""
+    from daft_tpu.device.residency import manager
+    from daft_tpu.execution import executor
+
+    t5 = tj._tpch_like(n_l=_MORSEL * _LONG - 100)
+    queries = [lambda: tj._topn_q3(long_tables), lambda: tj._q5_shaped(t5),
+               lambda: tj._topn_q10(long_tables)]
+
+    def passes():
+        for q in queries:
+            _run(q, mesh)
+
+    def cold():
+        manager().clear()
+        jax.clear_caches()
+        return asked_for.during(passes)
+
+    with monkeypatch.context() as piped:
+        piped.setattr(executor, "_resident_select", lambda plan: None)
+        piped_cold = cold()
+        assert counters.snapshot().get("join_resident_ranges", 0) == 0
+    assert piped_cold > 0
+    assert asked_for.during(passes) == 0, "a range's program is a glued run's"
+    assert counters.snapshot()["join_resident_ranges"] == tj._dispatches(_LONG, 8, mesh)
+    assert cold() == piped_cold
+    manager().clear()
+
+
+def test_a_fallback_after_a_sharded_range_answers_from_the_host_plan(monkeypatch):
+    """A DeviceFallback at the second sharded range of a resident fact: the
+    host plan's answer, and no stage thread left behind."""
+    import daft_tpu.ops.device_join as dj
+    from daft_tpu.ops.grouped_stage import DeviceFallback
+
+    t = tj._tpch_like(n_l=_MORSEL * _LONG - 100)
+    q = lambda: tj._q5_shaped(t)
+    host = tj._host_answer(q)
+    real, fed = dj.DeviceJoinGroupedRun.feed_batch, []
+
+    def feed_batch(self, batch):
+        if fed:
+            raise DeviceFallback("the second range will not go")
+        fed.append(batch.num_rows)
+        return real(self, batch)
+
+    monkeypatch.setattr(dj.DeviceJoinGroupedRun, "feed_batch", feed_batch)
+    answer, c = _run(q, MESH)
+    assert fed == [32 * _MORSEL] and c["join_resident_ranges"] == 1
+    assert any("device fallback" in k for k in counters.rejections), counters.rejections
+    tj._assert_close(host, answer)
+    assert not tj._stage_threads_left()
 
 
 def test_every_segment_of_every_shard_chooses_its_own_form(monkeypatch):
