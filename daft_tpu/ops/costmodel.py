@@ -185,18 +185,24 @@ class Calibration:
     host_dict_encode_rate: float = 4e7
     # the run-wide TopN's forms (grouped_stage._build_run_wide; a dispatch
     # that keeps few rows compacts them and scatters those alone, which no
-    # price can foresee: the scatter form's is the ceiling). Dense:
-    # one-hot cells of a chunk against its own id window (rows x chunk, once
-    # for the sums on the MXU and once for the first-row minimum), built and
-    # contracted without leaving the chip's memory: q3 at SF10 read 1.17 ms
-    # a dispatch for 2 x 131,072 x 4,096 cells on a v5e (PR 38). Scatter:
+    # price can foresee: the scatter form's is the ceiling). Dense: a
+    # chunk's id window is addressed in two digits (32 x 128 of 4,096), so a
+    # row costs the digits' compares and a select a term and high digit
+    # (grouped_stage.dense_row_cells: 32 + 128 + 12 x 32 = 544 cells for
+    # q3's three planes, where the whole one-hot and its masked minimum were
+    # 2 x 4,096), the first rows riding the product, which a fact sorted by
+    # the dimension's key allows (one whose ids are dense in no order pays
+    # the masked minimum besides, which no price can foresee either: 0.78 ms
+    # against 0.30). A segment of 131,072 rows read 0.302 ms on a v5e, 7.1e7
+    # cells (PR 47's chip run; 1.22 ms before, at 9e11 of the old cells a
+    # second). Scatter:
     # beside its scatters (scatter_rows_per_s: q10 read 0.88 ms a scatter of
     # 131,072 rows, 1.5e8 rows/s) it streams the whole of each table once a
     # dispatch (a float32 table zeroed, scattered into, and added to the
     # run's two float32 planes: 24 bytes an id, reckoned from the chip's
     # 819 GB/s at two thirds), which mm_plane_rows_per_s, a reduce's rate,
     # would price 4 times too high.
-    run_wide_cell_rate: float = 9e11
+    run_wide_cell_rate: float = 2.4e11
     run_wide_pass_ids_per_s: float = 2e10
 
 
@@ -641,10 +647,10 @@ def device_join_topn_run_cost(cal: Calibration, rows: int, upload_bytes: int,
     tables of `cap` ids on the device for the whole run
     (ops/device_join.DeviceJoinTopNRun over GroupedAggStage._build_run_wide):
     the gathers, the batch added into the tables in the form its ids allow
-    (`dense`: the one-hot product of each `chunk` rows on the MXU, rows x chunk
-    cells for the sums, whatever the planes, since they ride one pass of the
-    MXU, and as many for the first-row minimum; else a scatter a plane and
-    one for the first rows, and a stream over the tables), this
+    (`dense`: each `chunk` rows' product with the two digits of their id
+    window, grouped_stage.dense_row_cells a row, the first rows riding it;
+    else a scatter a plane and one for the first rows, and a stream over the
+    tables), this
     partition's share of the run's one select (`select_share`: its rows over
     the fact's; the select sorts blocks, so `cap` ids cost cap x log2(block)
     a key) and of the K-row fetch. No host factorization: `index_rows` is the
@@ -654,7 +660,9 @@ def device_join_topn_run_cost(cal: Calibration, rows: int, upload_bytes: int,
     out = _base_terms(cal, upload_bytes, coalesce, resident_bytes)
     out.add("compute", n_gathers * rows / cal.mm_plane_rows_per_s)
     if dense:
-        out.add("compute", 2 * rows * chunk / cal.run_wide_cell_rate)
+        from .grouped_stage import dense_row_cells
+
+        out.add("compute", rows * dense_row_cells(chunk, n_mm) / cal.run_wide_cell_rate)
     else:
         out.add("compute", (n_mm + 1) * rows / cal.scatter_rows_per_s
                 + cap * n_mm / cal.run_wide_pass_ids_per_s)

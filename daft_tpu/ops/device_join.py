@@ -2650,7 +2650,7 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
             # the select programs take the leaves they were compiled for (25-28
             # s each on a machine's first process: their text stays as it was);
             # what the accumulate program counted beside them rides the same fetch
-            compact = tables.pop("compact")
+            counted = {n: tables.pop(n) for n in ("compact", "ordered")}
             if ndev == 1:
                 fetch = self._select_program(k_eff)(tables, ranks)
             else:
@@ -2667,7 +2667,7 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
                 counters.bump("device_topn_combine_bytes", moved)
             with profile_span("device.d2h", "device", op="join_topn",
                               rows=int(k_eff)):
-                fetch, compact = jax.device_get((fetch, compact))
+                fetch, counted = jax.device_get((fetch, counted))
             if ndev == 1:
                 gids, mm_rows, present_rows, dense = fetch
             else:
@@ -2678,12 +2678,16 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
                 order = np.lexsort(tuple(reversed(operands)))[:k_eff]
                 gids, mm_rows, present_rows = (
                     np.asarray(x)[order] for x in (gids, mm_rows, present_rows))
-            compact_batches = int(np.sum(compact)) // ndev              # (a count a chip)
+            # (a count a chip)
+            compact_batches, ordered_batches = (
+                int(np.sum(counted[n])) // ndev for n in ("compact", "ordered"))
             if sp is not None:
-                sp.args["dense_batches"] = int(np.sum(dense)) // ndev   # (a count a chip)
+                sp.args["dense_batches"] = int(np.sum(dense)) // ndev
                 sp.args["compact_batches"] = compact_batches
+                sp.args["ordered_batches"] = ordered_batches
         del tables
         counters.bump("join_topn_compact_batches", compact_batches)
+        counters.bump("join_topn_ordered_batches", ordered_batches)
         counters.bump("device_stage_runs")
         counters.bump("device_topn_runs")
         counters.bump("device_topn_fetched_rows", fetched_rows)

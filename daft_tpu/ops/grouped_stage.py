@@ -1065,8 +1065,8 @@ class GroupedAggStage:
             zeros = tuple(jnp.zeros(ndev * length, jnp.float32) for _ in range(n_mm))
             return {"hi": zeros, "lo": tuple(jnp.zeros_like(z) for z in zeros),
                     "first": jnp.full(ndev * length, _NO_ROW, jnp.int32),
-                    "dense": jnp.zeros((ndev,) if ndev > 1 else (), jnp.int32),
-                    "compact": jnp.zeros((ndev,) if ndev > 1 else (), jnp.int32)}
+                    **{count: jnp.zeros((ndev,) if ndev > 1 else (), jnp.int32)
+                       for count in _RUN_WIDE_COUNTS}}
 
         if mesh_devices <= 1:
             return empty()
@@ -1112,8 +1112,19 @@ class GroupedAggStage:
         CHUNK_LOCAL of each other (a fact sorted by the dimension's key, as
         lineitem by order: the locally dense layout of _build_local_dense
         with no host permutation), a chunk's float32 planes are contracted
-        with its one-hot on the MXU and added to its window of the tables;
-        any other batch scatter-adds float32 rows into a float32 table of its
+        with its one-hot on the MXU and added to its window of the tables.
+        The one-hot is never built whole: an id of the window is two digits
+        (DENSE_DIGITS), so a row costs the SUM of the digits' ranges in
+        compares and selects and not their product (_digit_product). Where,
+        besides, the kept ids of every chunk of the segment never decrease
+        in stream order (the same sorted fact), an id's first row is the one
+        whose id exceeds every kept id before it, exactly one row an id, and
+        its position rides the same product as a sum of one term; a dense
+        segment whose ids are in no order keeps the masked minimum over the
+        whole one-hot for its first rows. Both verdicts are the segment's
+        own, from its ids, on the device; the tables count the segments of
+        each.
+        Any other batch scatter-adds float32 rows into a float32 table of its
         own, added to the run's whole. A scatter on the chip costs by its
         index count, dropped indices included (0.89 ms for a bucket's 131,072,
         0.07 for 8,192: PERF.md, PR 42), so a batch that keeps at most a
@@ -1121,7 +1132,7 @@ class GroupedAggStage:
         them in stream order (_compact_kept) and scatters those; one that
         keeps more scatters the whole bucket. Either way a batch's partial is
         float32 and the run's sum wider, as the merge on the host was; the
-        tables count the dense and the compacted segments.
+        tables count the dense, the ordered and the compacted segments.
 
         A dispatch is one or more SEGMENTS of `segment` rows (0: the whole
         bucket is one): a join over a resident fact sends DISPATCH_SEGMENTS
@@ -1149,7 +1160,7 @@ class GroupedAggStage:
 
         def one_segment(acc, cols: Dict[str, dev.DCol], gid: jnp.ndarray,
                         row_mask: jnp.ndarray, offset, lits):
-            """`acc` (hi, lo, first, dense, compact) with one segment's rows
+            """`acc` (hi, lo, first and _RUN_WIDE_COUNTS) with one segment's rows
             added, the segment's first row at `offset` of the run's stream."""
             bucket = gid.shape[0]
             chunk = min(CHUNK_LOCAL, bucket, cap)
@@ -1167,38 +1178,75 @@ class GroupedAggStage:
             vals = jnp.stack(mm, axis=-1)                    # [bucket, P] f32
 
             def dense_form(acc):
-                acc_hi, acc_lo, acc_first = acc
                 local = jnp.where(g < cap, g - lo[:, None], chunk)
-                ids = jnp.arange(chunk, dtype=jnp.int32)
                 # a float32 as three bfloat16 terms (8 + 8 + 8 bits of it):
                 # the one-hot is exact in bfloat16, so ONE pass of the MXU
                 # over the three gives what Precision.HIGHEST takes six for
                 terms = _bfloat16_terms(vals).reshape(n_chunks, chunk, 3 * n_mm)
+                # the greatest kept id among the rows before, chunk by chunk
+                # (a row that is not kept carries `cap`, over every kept id)
+                before = _max_before(jnp.where(g < cap, g, -1))
+                ordered = jnp.all(g >= before)
+                # where the kept ids never decrease, a kept row is its id's
+                # first exactly where its id exceeds every kept id before it:
+                # ONE row an id, so the row's number in the chunk comes
+                # through the product as a sum of one term, in two
+                # bfloat16-exact digits (a chunk has at most CHUNK_LOCAL = 64
+                # x 64 rows) and a presence flag; in a segment in no order
+                # the three columns are zeros and say "no row"
+                is_first = ordered & (g < cap) & (g > before)
+                row = jnp.arange(chunk, dtype=jnp.int32)
+                marks = jnp.stack(
+                    [jnp.where(is_first, d, 0) for d in (1, row >> 6, row & 63)],
+                    axis=-1).astype(jnp.bfloat16)
+                rows_at = pos.reshape(n_chunks, chunk)
 
-                def body(carry, xs):
+                def firsts_by_minimum():
+                    # ids in no order: each id's least position among the
+                    # chunk's rows that hold it, over the whole one-hot (made
+                    # apart from the tables: a loop of its own that carried
+                    # them had the chip's compiler copy each table whole, a
+                    # chunk: 78 ms a segment; PERF.md, PR 47)
+                    ids = jnp.arange(chunk, dtype=jnp.int32)
+
+                    def body(_, xs):
+                        s, p = xs
+                        return None, jnp.min(
+                            jnp.where(s[:, None] == ids[None, :], p[:, None], _NO_ROW), axis=0)
+
+                    return jax.lax.scan(body, None, (local, rows_at))[1]
+
+                unordered_firsts = jax.lax.cond(
+                    ordered, lambda: jnp.full((n_chunks, chunk), _NO_ROW, jnp.int32),
+                    firsts_by_minimum)
+
+                def add_chunk(carry, xs):
                     c_hi, c_lo, c_first = carry
-                    s, v, p, at = xs
-                    oh = s[:, None] == ids[None, :]
-                    part = jnp.matmul(oh.astype(jnp.bfloat16).T, v,
-                                      preferred_element_type=jnp.float32)
-                    part = part[:, :n_mm] + part[:, n_mm:2 * n_mm] + part[:, 2 * n_mm:]
+                    s, v, p, other, at = xs
+                    part = _digit_product(s, v)
                     new_hi, new_lo = [], []
                     for k in range(n_mm):
                         h, l = _two_sum_add(
                             jax.lax.dynamic_slice(c_hi[k], (at,), (chunk,)),
-                            jax.lax.dynamic_slice(c_lo[k], (at,), (chunk,)), part[:, k])
+                            jax.lax.dynamic_slice(c_lo[k], (at,), (chunk,)),
+                            part[k] + part[n_mm + k] + part[2 * n_mm + k])
                         new_hi.append(jax.lax.dynamic_update_slice(c_hi[k], h, (at,)))
                         new_lo.append(jax.lax.dynamic_update_slice(c_lo[k], l, (at,)))
-                    first = jnp.min(jnp.where(oh, p[:, None], _NO_ROW), axis=0)
+                    here, high, low = part[3 * n_mm:]
+                    first = jnp.where(
+                        here > 0, p + (high * 64 + low).astype(jnp.int32), other)
                     cur = jax.lax.dynamic_slice(c_first, (at,), (chunk,))
                     c_first = jax.lax.dynamic_update_slice(
                         c_first, jnp.minimum(cur, first), (at,))
                     return (tuple(new_hi), tuple(new_lo), c_first), None
 
-                out, _ = jax.lax.scan(
-                    body, (acc_hi, acc_lo, acc_first),
-                    (local, terms, pos.reshape(n_chunks, chunk), lo))
-                return out
+                # (two chunks a step: 0.357 -> 0.302 ms a segment on a v5e, four
+                # read the same; PERF.md, PR 47)
+                return jax.lax.scan(
+                    add_chunk, acc,
+                    (local, jnp.concatenate([terms, marks], axis=-1), rows_at[:, 0],
+                     unordered_firsts, lo),
+                    unroll=min(2, n_chunks))[0] + (ordered,)
 
             def scatter_form(acc):
                 acc_hi, acc_lo, acc_first = acc
@@ -1224,15 +1272,19 @@ class GroupedAggStage:
                 return new_hi, new_lo, acc_first.at[at].min(offset + src, mode="drop")
 
             def sparse_forms(acc):
-                # (nothing of the compaction is computed for a dense segment)
+                # (nothing of the compaction is computed for a dense segment,
+                # nothing of the ids' order for a sparse one)
                 few = jnp.sum(kept, dtype=jnp.int32) <= n_compact
-                return jax.lax.cond(few, compact_form, scatter_form, acc) + (few,)
+                return jax.lax.cond(few, compact_form, scatter_form, acc) \
+                    + (jnp.bool_(False), few)
 
-            acc_hi, acc_lo, acc_first, compacted = jax.lax.cond(
+            acc_hi, acc_lo, acc_first, ordered, compacted = jax.lax.cond(
                 dense, lambda acc: dense_form(acc) + (jnp.bool_(False),), sparse_forms,
                 acc[:3])
-            return (acc_hi, acc_lo, acc_first, acc[3] + dense.astype(jnp.int32),
-                    acc[4] + compacted.astype(jnp.int32))
+            # (_RUN_WIDE_COUNTS' order)
+            return (acc_hi, acc_lo, acc_first) + tuple(
+                n + took.astype(jnp.int32)
+                for n, took in zip(acc[3:], (dense, compacted, ordered)))
 
         def stage(tables, cols: Dict[str, dev.DCol], gid: jnp.ndarray,
                   row_mask: jnp.ndarray, lit_args, rows_before=0):
@@ -1241,8 +1293,7 @@ class GroupedAggStage:
             offset = slots.run_value(lit_args, 0).astype(jnp.int32) + rows_before
             bucket = gid.shape[0]
             rows = min(segment or bucket, bucket)
-            acc = (tables["hi"], tables["lo"], tables["first"],
-                   tables["dense"], tables["compact"])
+            acc = tuple(tables[leaf] for leaf in ("hi", "lo", "first") + _RUN_WIDE_COUNTS)
             if rows == bucket:
                 acc = one_segment(acc, cols, gid, row_mask, offset, lits)
             else:
@@ -1260,7 +1311,7 @@ class GroupedAggStage:
                     return one_segment(acc, c, g, m, offset + i * rows, lits)
 
                 acc = jax.lax.fori_loop(0, live, walk, acc)
-            return dict(zip(("hi", "lo", "first", "dense", "compact"), acc))
+            return dict(zip(("hi", "lo", "first") + _RUN_WIDE_COUNTS, acc))
 
         if mesh is None:
             return jax.jit(stage, donate_argnums=0)
@@ -1288,6 +1339,54 @@ def _two_sum_add(hi, lo, x):
     # an infinite or NaN sum stays what it is (its error term would be a NaN)
     ok = jnp.isfinite(s)
     return jnp.where(ok, new_hi, s), jnp.where(ok, lo - (new_hi - s), 0.0)
+
+
+# what a run-wide dispatch's tables count beside their sums: the segments
+# that took the dense form, those whose kept rows were compacted before their
+# scatters, and the dense ones whose first rows rode the product (the select
+# programs read "dense" alone; device_join pops the others before the select)
+_RUN_WIDE_COUNTS = ("dense", "compact", "ordered")
+
+
+def _digit_product(local: jnp.ndarray, terms: jnp.ndarray) -> jnp.ndarray:
+    """[chunk] local ids (chunk: no id) and [chunk, T] bfloat16 terms -> [T,
+    chunk] float32: each id's sum of each term over the rows that hold it,
+    as `one_hot(local).T @ terms` gives it, with no chunk x chunk one-hot:
+    the id is two digits, id = a * low + b, and
+
+        part[(t, a), b] = sum_r (A[r, a] * terms[r, t]) * B[r, b]
+
+    is one product [T * high, chunk] x [chunk, low] whose rows are the ids'
+    in order. A row costs high + low compares and T * high selects; the
+    one-hots are exact in bfloat16, a one-hot times a term is that term, and
+    the MXU adds in float32."""
+    chunk, n_terms = terms.shape
+    high, low = _digits_of(chunk)
+    # (the id `chunk` has the digit `high`, which matches nothing)
+    a = (local // low)[:, None] == jnp.arange(high, dtype=jnp.int32)[None, :]
+    b = (local % low)[:, None] == jnp.arange(low, dtype=jnp.int32)[None, :]
+    spread = jnp.where(a[:, None, :], terms[:, :, None], jnp.zeros((), terms.dtype))
+    part = jax.lax.dot_general(
+        spread.reshape(chunk, n_terms * high), b.astype(terms.dtype),
+        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    return part.reshape(n_terms, chunk)
+
+
+def _max_before(x: jnp.ndarray) -> jnp.ndarray:
+    """int32[n, m] (values >= -1) -> [n, m]: the greatest value of the row's
+    entries before each (-1 before the first). Shifted maxima in log2(m)
+    steps: 0.007 ms for [32, 4096] on a v5e, where jax.lax.cummax read 0.019
+    and an associative scan 0.032 (PERF.md, PR 47)."""
+    m = x.shape[1]
+
+    def shifted(y, by):
+        return jnp.pad(y, ((0, 0), (by, 0)), constant_values=-1)[:, :m]
+
+    out, by = shifted(x, 1), 1
+    while by < m:
+        out = jnp.maximum(out, shifted(out, by))
+        by *= 2
+    return out
 
 
 # a run-wide dispatch that keeps at most a bucket's 1 / COMPACT_SHARE of its
@@ -1690,6 +1789,29 @@ def results_from_tables(stage: GroupedAggStage, mm_acc, ext_acc, sct_acc):
 
 
 CHUNK_LOCAL = 4096
+# The two digits a local id of the dense form's window is addressed in: id =
+# a * 128 + b, a in [0, 32). One segment's 32 chunks into tables of 2^24 ids
+# on a v5e, three planes and the first rows through the product (PERF.md, PR
+# 47's chip run; the whole one-hot and its masked minimum read 1.223 ms): 32 x
+# 128 0.391 ms, 16 x 256 0.416, 64 x 64 0.485; with the terms on the low
+# digit's side 0.486 / 0.638 / 0.444; the high digit outermost 0.693 / 0.545 /
+# 1.068; 8 x 512 no better than 16 x 256 (0.305 against 0.297, sums alone,
+# where 32 x 128 reads 0.279).
+DENSE_DIGITS = (CHUNK_LOCAL // _LANES, _LANES)
+
+
+def _digits_of(chunk: int):
+    """(high, low): the two digits' ranges for a window of `chunk` ids."""
+    low = min(DENSE_DIGITS[1], chunk)
+    return chunk // low, low
+
+
+def dense_row_cells(chunk: int, n_mm: int) -> int:
+    """The compares and selects a row costs in the dense form with its first
+    rows through the product (_digit_product over 3 * n_mm + 3 terms): what
+    costmodel.device_join_topn_run_cost prices."""
+    high, low = _digits_of(chunk)
+    return high + low + (3 * n_mm + 3) * high
 
 
 def build_permuted_layout(group_ids: np.ndarray, n: int, bucket: int):

@@ -327,6 +327,30 @@ def _assert_compacts_without_sort_or_scan(text, n_planes):
     assert gathers == [f"{k},128"] * (n_planes + 2), gathers
 
 
+# the dense form, and inside it the masked minimum that finds the first rows
+# of a segment whose ids are in no order: cond(dense, dense_form, ...) and
+# cond(ordered, nothing, firsts_by_minimum)
+_DENSE_FORM = re.compile(r'op_name="(?:(?!cond/)[^"])*cond/branch_1_fun/')   # (the outermost cond's)
+_UNORDERED_FIRSTS = "cond/branch_1_fun/cond/branch_0_fun/"
+
+
+def _assert_dense_form_in_two_digits(text, n_planes):
+    """The accumulate program's dense form, as the chip's compiler left it: a
+    chunk's product is [terms x 32 high digits, 4,096 rows] x [rows, 128 low
+    digits], three bfloat16 terms a plane and three more columns for the
+    first rows that ride it; an operand of 4,096 x 4,096 cells exists for the
+    masked minimum of a segment whose ids are in no order, and nowhere else."""
+    from daft_tpu.ops.grouped_stage import CHUNK_LOCAL, DENSE_DIGITS
+
+    high, low = DENSE_DIGITS
+    assert (high, low) == (32, 128) and high * low == CHUNK_LOCAL
+    whole = [line for line in text.splitlines() if f"[{CHUNK_LOCAL},{CHUNK_LOCAL}]" in line]
+    assert whole and all(_UNORDERED_FIRSTS in line for line in whole)
+    products = [re.search(r"= f32\[([\d,]+)\]", line).group(1) for line in text.splitlines()
+                if " convolution(" in line and _DENSE_FORM.search(line)]
+    assert products and set(products) == {f"{(3 * n_planes + 3) * high},{low}"}, products
+
+
 # a join dispatch over a resident fact: DISPATCH_SEGMENTS morsels' rows (PR 43)
 SEGMENTS = [1, 8]
 SEGMENT_IDS = ["one_bucket", "eight_segments"]
@@ -354,13 +378,16 @@ def test_run_wide_topn_accumulate_lowers_at_sf10(one_chip, cap, segments):
     mem = compiled.memory_analysis()
     table_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                       for x in jax.tree_util.tree_leaves(tables))
-    # (the two counts of dispatches, dense and compacted, are the + 8)
-    assert table_bytes == (len(stage._mm_specs) * 2 * 4 + 4) * (cap + 4096) + 8
+    # (the three counts of segments, dense, compacted and ordered, are the + 12;
+    # without the last two the leaves are the select program's: PR 42's, unchanged)
+    assert sorted(tables) == ["compact", "dense", "first", "hi", "lo", "ordered"]
+    assert table_bytes == (len(stage._mm_specs) * 2 * 4 + 4) * (cap + 4096) + 12
     assert mem.argument_size_in_bytes >= table_bytes
     # the scatter forms' float32 table of one plane at a time, never a copy of the run's tables
     assert mem.temp_size_in_bytes < table_bytes
     assert mem.alias_size_in_bytes >= table_bytes - 16
     _assert_compacts_without_sort_or_scan(compiled.as_text(), len(stage._mm_specs))
+    _assert_dense_form_in_two_digits(compiled.as_text(), len(stage._mm_specs))
 
 
 def test_run_wide_topn_select_lowers_at_sf10(one_chip):
@@ -379,6 +406,40 @@ def test_run_wide_topn_select_lowers_at_sf10(one_chip):
     text = compiled.as_text()
     assert "sort" in text
     assert compiled.memory_analysis().output_size_in_bytes < 4096
+
+
+def test_the_select_program_takes_the_four_leaves_it_took(one_chip):
+    """The one chip's select program is traced over the sums, the first rows
+    and the dense count, as before the tables counted anything else: what
+    the accumulate program returns beside them (`compact`, `ordered`) is
+    popped before the call, so the select's text, and with it its key in the
+    persistent cache (25-28 s of compile at SF10), stands."""
+    import daft_tpu.ops.device_join as dj
+    from daft_tpu.ops.grouped_stage import _RUN_WIDE_COUNTS
+
+    stage, topn = _q3_join_stage()
+
+    class _Ctx:     # what _select_program reads of the join context
+        mesh = None
+
+        def _mesh_key(self):
+            return ()
+
+    run = dj.DeviceJoinTopNRun.__new__(dj.DeviceJoinTopNRun)
+    run.stage, run._cap, run.topn, run.mesh_devices, run.ctx = \
+        stage, ORDERS_CAP, topn, 1, _Ctx()
+    tables = _run_wide_tables(stage, one_chip, ORDERS_CAP)
+    assert set(tables) - {"hi", "lo", "first"} == set(_RUN_WIDE_COUNTS)
+    for count in ("compact", "ordered"):
+        tables.pop(count)
+    ranks = tuple(_s(one_chip, (ORDERS_CAP,), jnp.int32)
+                  for kind, *_rest in topn.keys if kind == "group")
+    lowered = run._select_program(10).lower(tables, ranks)
+    (handed, _ranks), _kw = lowered.in_tree.unflatten(
+        list(range(lowered.in_tree.num_leaves)))
+    assert sorted(handed) == ["dense", "first", "hi", "lo"]
+    # hi and lo a plane each, first, dense, and the rank planes
+    assert lowered.in_tree.num_leaves == 2 * len(stage._mm_specs) + 2 + len(ranks)
 
 
 def test_bfloat16_terms_survive_the_chips_compiler(one_chip):
@@ -514,9 +575,10 @@ def _sharded_run_wide_tables(stage, rows, cap):
 
 def _sharded_accumulate_tables(stage, rows, cap):
     """What the accumulate program takes and returns: the select's four
-    leaves and the compacted dispatches' count beside them."""
+    leaves and the compacted and the ordered segments' counts beside them."""
     return dict(_sharded_run_wide_tables(stage, rows, cap),
-                compact=_s(rows, (MESH_CHIPS,), jnp.int32))
+                compact=_s(rows, (MESH_CHIPS,), jnp.int32),
+                ordered=_s(rows, (MESH_CHIPS,), jnp.int32))
 
 
 @pytest.mark.parametrize("segments", SEGMENTS, ids=SEGMENT_IDS)
@@ -543,8 +605,10 @@ def test_sharded_run_wide_accumulate_lowers_at_sf30(topo, segments):
     assert mem.temp_size_in_bytes < a_chips
     text = compiled.as_text()
     assert not [c for c in COLLECTIVES if c in text]
-    # a shard's program is the one chip's: its own 131,072 rows, its own K
+    # a shard's program is the one chip's: its own 131,072 rows, its own K,
+    # its own chunks' windows in two digits
     _assert_compacts_without_sort_or_scan(text, len(stage._mm_specs))
+    _assert_dense_form_in_two_digits(text, len(stage._mm_specs))
 
 
 def test_sharded_run_wide_combine_and_select_lowers_at_sf30(topo):

@@ -1106,6 +1106,21 @@ _COMPACT_CASES = {
 }
 
 
+def _run_spanned(q):
+    """(answer, the run's join.topn_select span)."""
+    from daft_tpu.observability.runtime_stats import SpanRecorder, set_spans
+
+    rec = SpanRecorder()
+    set_spans(rec)
+    try:
+        with _morselized("on"):
+            answer = q().to_pydict()
+    finally:
+        set_spans(None)
+    (select,) = [s for s in rec.drain() if s["name"] == "join.topn_select"]
+    return answer, select
+
+
 @pytest.mark.parametrize("case", list(_COMPACT_CASES))
 def test_fused_topn_compact_and_scatter_forms_agree(case, monkeypatch, segments):
     """A segment whose kept rows fit K scatters K compacted indices, one
@@ -1117,7 +1132,6 @@ def test_fused_topn_compact_and_scatter_forms_agree(case, monkeypatch, segments)
     of four segments, one dense, two compacted and one scattered, and the
     table's last morsel, scattered, in a dispatch of its own)."""
     import daft_tpu.ops.grouped_stage as gs
-    from daft_tpu.observability.runtime_stats import SpanRecorder, set_spans
 
     kept, one_line = _COMPACT_CASES[case]
     t, kept_ids = _compaction_fact(kept, one_line)
@@ -1128,17 +1142,10 @@ def test_fused_topn_compact_and_scatter_forms_agree(case, monkeypatch, segments)
     _spy_run_wide_tables(monkeypatch, seen)
 
     counters.reset()
-    rec = SpanRecorder()
-    set_spans(rec)
-    try:
-        with _morselized("on"):
-            answer = _topn_q3(t).to_pydict()
-    finally:
-        set_spans(None)
+    answer, select = _run_spanned(lambda: _topn_q3(t))
     assert counters.device_topn_runs == 1, counters.rejections
     assert counters.device_join_topn_batches == _dispatches(len(kept), segments)
     assert counters.join_topn_compact_batches == compact
-    (select,) = [s for s in rec.drain() if s["name"] == "join.topn_select"]
     assert select["args"]["compact_batches"] == compact
     assert select["args"]["dense_batches"] == dense
     _assert_close(host, answer)
@@ -1162,8 +1169,8 @@ def test_fused_topn_compact_and_scatter_forms_agree(case, monkeypatch, segments)
 
 
 def test_the_select_program_is_handed_the_leaves_it_was_compiled_for(monkeypatch):
-    """The accumulate program's tables carry the compacted dispatches' count
-    beside the select's four leaves; the select program (25-28 s to compile
+    """The accumulate program's tables carry the compacted and the ordered
+    segments' counts beside the select's four leaves; the select program (25-28 s to compile
     at SF10, served by the persistent cache while its text stands) is handed
     those four and nothing else."""
     import daft_tpu.ops.device_join as dj
@@ -1186,7 +1193,223 @@ def test_the_select_program_is_handed_the_leaves_it_was_compiled_for(monkeypatch
     with _morselized("on"):
         _topn_q10(t).to_pydict()
     assert handed == [["dense", "first", "hi", "lo"]]
-    assert sorted(seen[0][1]) == ["compact", "dense", "first", "hi", "lo"]
+    assert sorted(seen[0][1]) == ["compact", "dense", "first", "hi", "lo", "ordered"]
+
+
+# ---- the dense form: a chunk's id window in two digits -----------------------------------
+#
+# GroupedAggStage._build_run_wide's dense form never builds a chunk's whole
+# one-hot: a local id is two digits (_digit_product), and where a segment's
+# kept ids never decrease an id's first row rides the same product. The facts
+# below are q3's in which EVERY order joins through and an order's row is its
+# key, so a lineitem row's id is its l_orderkey and the ship date alone says
+# whether it is kept.
+
+_DENSE_ORDERS = 9000        # more ids than two chunks' windows are wide
+
+
+def _dense_fact(keys, kept, whole_prices=False, seed=31):
+    """(tables, revenue a row) for lineitem rows of order keys `keys` of
+    which exactly those of `kept` reach a group. Discounts of 0 or a half, so
+    a revenue is exact in float32: prices of all 24 bits, or whole ones that tie."""
+    import datetime
+
+    rng = np.random.default_rng(seed)
+    n_l, n_o, n_c = len(keys), _DENSE_ORDERS, 5
+    day0 = datetime.date(1994, 1, 1)
+    price = rng.integers(1, 6, n_l).astype(np.float32) if whole_prices \
+        else rng.uniform(1, 1e5, n_l).astype(np.float32)
+    discount = rng.integers(0, 2, n_l) * 0.5
+    t = {
+        "customer": {"c_custkey": list(range(n_c)),
+                     "c_name": [f"Customer#{i:05d}" for i in range(n_c)],
+                     "c_acctbal": [0.0] * n_c, "c_mktsegment": ["BUILDING"] * n_c,
+                     "c_nationkey": [0] * n_c},
+        "orders": {"o_orderkey": list(range(n_o)), "o_custkey": [k % n_c for k in range(n_o)],
+                   "o_orderdate": [day0 + datetime.timedelta(days=k % 300) for k in range(n_o)],
+                   "o_shippriority": [k % 2 for k in range(n_o)]},
+        "lineitem": {"l_orderkey": [int(k) for k in keys],
+                     "l_extendedprice": price.astype(float).tolist(),
+                     "l_discount": discount.tolist(), "l_returnflag": ["R"] * n_l,
+                     "l_shipdate": [day0 + datetime.timedelta(days=200 if x else 0)
+                                    for x in kept]},
+    }
+    return ({name: daft_tpu.from_pydict(cols).collect() for name, cols in t.items()},
+            price.astype(np.float64) * (1 - discount))
+
+
+def _dense_rows(case, morsels, chunk, seed=37):
+    """(keys, kept) of `morsels` morsels whose every `chunk` rows hold kept
+    ids within `chunk` of each other. The rows that are not kept carry keys
+    from anywhere."""
+    rng = np.random.default_rng(seed)
+    n_l = _MORSEL * morsels
+    kept = rng.random(n_l) < 0.4
+    if case in ("straddle", "straddle_unordered"):
+        # a window that starts off a 128-boundary, its first and last id and
+        # the ids on both sides of its 128-boundaries among the kept ones
+        keys = np.empty(n_l, dtype=np.int64)
+        for c in range(n_l // chunk):
+            lo = 77 + 300 * c
+            edges = [lo, lo + 127, lo + 128, lo + chunk - 129, lo + chunk - 128, lo + chunk - 1]
+            ids = np.concatenate([np.repeat(edges, 2), rng.integers(lo, lo + chunk, chunk - 12)])
+            keys[c * chunk:(c + 1) * chunk] = np.sort(ids)
+            # (each edge id is kept at its second row, not always at its first)
+            where = c * chunk + np.searchsorted(np.sort(ids), edges) + 1
+            kept[where] = True
+    else:
+        keys = np.sort(rng.integers(0, min(n_l // 3, _DENSE_ORDERS), n_l))
+    if case == "empty_and_one":
+        kept[:_MORSEL] = False                      # a morsel with nothing kept,
+        kept[_MORSEL:2 * _MORSEL] = False           # one with a single kept row,
+        kept[_MORSEL + 700] = True
+        kept[2 * _MORSEL:3 * _MORSEL] = False       # one that keeps its first and its last
+        kept[[2 * _MORSEL, 3 * _MORSEL - 1]] = True
+    if case.endswith("unordered"):
+        for c in range(n_l // chunk):
+            block = slice(c * chunk, (c + 1) * chunk)
+            perm = rng.permutation(chunk)
+            keys[block], kept[block] = keys[block][perm], kept[block][perm]
+    keys = np.where(kept, keys, rng.integers(0, _DENSE_ORDERS, n_l))
+    return keys, kept
+
+
+def _dense_verdicts(keys, kept, chunk):
+    """(dense, ordered) segments, a morsel each, as the program decides them."""
+    dense = ordered = 0
+    for m in range(len(keys) // _MORSEL):
+        chunks = [keys[lo:lo + chunk][kept[lo:lo + chunk]]
+                  for lo in range(m * _MORSEL, (m + 1) * _MORSEL, chunk)]
+        if all(not len(ids) or ids.max() - ids.min() < chunk for ids in chunks):
+            dense += 1
+            ordered += all((np.diff(ids) >= 0).all() for ids in chunks)
+    return dense, ordered
+
+
+def _dense_reference(keys, kept, revenue, length):
+    """float64 (rows, revenue, first row) an id of tables `length` long."""
+    from daft_tpu.ops.grouped_stage import _NO_ROW
+
+    rows, sums = np.zeros(length), np.zeros(length)
+    first = np.full(length, _NO_ROW, dtype=np.int64)
+    np.add.at(rows, keys[kept], 1.0)
+    np.add.at(sums, keys[kept], revenue[kept])
+    np.minimum.at(first, keys[kept], np.flatnonzero(kept))
+    return rows, sums, first
+
+
+@pytest.fixture(params=[_MORSEL, 512], ids=["one_chunk", "four_chunks"])
+def chunk_rows(request, monkeypatch):
+    """A segment of one chunk (a morsel here is shorter than CHUNK_LOCAL) and
+    of four, each with an id window of its own."""
+    import daft_tpu.ops.grouped_stage as gs
+
+    monkeypatch.setattr(gs, "CHUNK_LOCAL", request.param)
+    monkeypatch.setattr(gs, "_STAGE_CACHE", {})      # (the stages built for it go with the test)
+    return request.param
+
+
+_DENSE_CASES = ("ordered", "unordered", "straddle", "straddle_unordered", "empty_and_one")
+
+
+@pytest.mark.parametrize("case", _DENSE_CASES)
+def test_the_dense_forms_tables_against_float64(case, chunk_rows, segments, monkeypatch):
+    """The dense form's tables against a float64 numpy reference: sorted ids
+    (first rows through the product), ids within a window in no order (the
+    masked minimum), ids on both sides of a 128-boundary and at both ends of
+    a window that starts off one, a segment with nothing kept, with one kept
+    row, and with its first and last row alone. Counts exactly, sums to a
+    float32's rounding, first rows exactly; the counts say which segments'
+    first rows rode the product."""
+    morsels = 5
+    keys, kept = _dense_rows(case, morsels, chunk_rows)
+    dense, ordered = _dense_verdicts(keys, kept, chunk_rows)
+    assert dense == morsels
+    assert ordered == {"unordered": 0, "straddle_unordered": 0}.get(case, morsels)
+    t, revenue = _dense_fact(keys, kept)
+    host = _host_answer(lambda: _topn_q3(t))
+    seen = []
+    _spy_run_wide_tables(monkeypatch, seen)
+    counters.reset()
+    answer, select = _run_spanned(lambda: _topn_q3(t))
+    assert counters.device_topn_runs == 1, counters.rejections
+    assert counters.device_join_topn_batches == _dispatches(morsels, segments)
+    assert counters.join_topn_ordered_batches == ordered
+    assert counters.join_topn_compact_batches == 0
+    assert (select["args"]["dense_batches"], select["args"]["ordered_batches"],
+            select["args"]["compact_batches"]) == (dense, ordered, 0)
+    _assert_close(host, answer)
+
+    (_batches, got), = seen
+    assert (int(got["dense"]), int(got["ordered"]), int(got["compact"])) == (dense, ordered, 0)
+    rows, sums, first = _dense_reference(keys, kept, revenue, len(got["first"]))
+    total = lambda k: np.asarray(got["hi"][k], np.float64) + np.asarray(got["lo"][k], np.float64)
+    # the planes: kept rows, counted values, revenue (stage._mm_specs)
+    np.testing.assert_array_equal(total(0), rows)
+    np.testing.assert_array_equal(total(1), rows)
+    np.testing.assert_allclose(total(2), sums, rtol=2.0 ** -22, atol=0)
+    np.testing.assert_array_equal(np.asarray(got["first"], np.int64), first)
+
+
+@pytest.mark.parametrize("case", ["ordered", "unordered"])
+def test_first_rows_decide_ties_as_the_host_engines_sort_does(case, chunk_rows, segments):
+    """Whole revenues tie: the winners' order among equals is the order their
+    groups were first seen in, whichever way the dense form found the first rows."""
+    keys, kept = _dense_rows(case, 5, chunk_rows)
+    t, _revenue = _dense_fact(keys, kept, whole_prices=True)
+    host = _host_answer(lambda: _topn_q3(t))
+    assert len(set(zip(host["revenue"], host["o_shippriority"]))) < len(host["revenue"]), \
+        "the sort keys tie among the winners"
+    counters.reset()
+    with _morselized("on"):
+        answer = _topn_q3(t).to_pydict()
+    assert counters.device_topn_runs == 1, counters.rejections
+    assert counters.join_topn_ordered_batches == (5 if case == "ordered" else 0)
+    assert answer == host
+
+
+@pytest.mark.parametrize("shape", [_topn_q3, _topn_q10], ids=["q3", "q10"])
+def test_the_ordered_count_follows_what_the_ids_are(shape, segments, monkeypatch):
+    """q3's ids (the fact is sorted by the order's key) are dense and in order
+    in every segment; q10's customer ids are neither (a chunk is held to 64
+    rows here, under the tables' 97 customers), and nothing of the dense form
+    runs for them."""
+    import daft_tpu.ops.grouped_stage as gs
+
+    morsels = 7
+    t = _topn_tables(n_l=_MORSEL * morsels - 100)
+    monkeypatch.setattr(gs, "CHUNK_LOCAL", 64)
+    monkeypatch.setattr(gs, "_STAGE_CACHE", {})      # (the stages built for it go with the test)
+    q = lambda: shape(t, cut=(1996, 6, 1))      # (every order: no segment is left empty)
+    counters.reset()
+    answer, select = _run_spanned(q)
+    assert counters.device_topn_runs == 1, counters.rejections
+    dense = morsels if shape is _topn_q3 else 0
+    assert (select["args"]["dense_batches"], select["args"]["ordered_batches"]) == (dense, dense)
+    assert counters.join_topn_ordered_batches == dense
+    _assert_close(_host_answer(q), answer)
+
+
+def test_digit_product_is_the_one_hot_product():
+    """_digit_product against `one_hot(ids).T @ terms` in numpy, bit for bit,
+    for windows of one line of lanes and less up to CHUNK_LOCAL; _max_before
+    against a running maximum."""
+    import jax.numpy as jnp
+    from daft_tpu.ops.grouped_stage import CHUNK_LOCAL, _digit_product, _max_before
+
+    rng = np.random.default_rng(2)
+    for chunk in (CHUNK_LOCAL, 2048, 512, 128, 64):
+        local = rng.integers(0, chunk + 1, chunk).astype(np.int32)      # `chunk`: no id
+        local[:4] = (0, 127 % chunk, chunk - 1, chunk)
+        terms = jnp.asarray(rng.integers(-64, 64, (chunk, 5)), jnp.bfloat16)
+        got = np.asarray(_digit_product(jnp.asarray(local), terms))
+        one_hot = (local[:, None] == np.arange(chunk)[None, :]).astype(np.float32)
+        np.testing.assert_array_equal(got, (one_hot.T @ np.asarray(terms, np.float32)).T)
+    for shape in ((3, 1), (3, 2), (4, 512)):
+        x = rng.integers(-1, 40, shape).astype(np.int32)
+        want = np.concatenate([np.full((shape[0], 1), -1),
+                               np.maximum.accumulate(x, axis=1)[:, :-1]], axis=1)
+        np.testing.assert_array_equal(np.asarray(_max_before(jnp.asarray(x))), want)
 
 
 # ---- a dispatch over a resident fact covers several buckets ------------------------------
