@@ -206,9 +206,9 @@ def coalesce_target_rows(cfg, shards: int = 1, resident_rows: int = 0) -> int:
     resident_dispatch_segments buckets a shard, since the host's path a join
     dispatch (look-ups, two launches) costs the same whatever the rows behind
     it, and the join's programs walk a long dispatch a bucket at a time. The
-    horizon the tiers are priced with stays the plain threshold's
-    (executor._run_device_join says why): the coalescer then outdoes what
-    was priced, never the reverse."""
+    horizon a join's tiers are priced with is resident_dispatch_rows over a
+    morsel, the same arithmetic (executor._run_device_join): the rows a
+    dispatch is priced at are the rows a dispatch delivers."""
     if cfg.batch_fill_target <= 0:
         return 0
     from ..ops.stage import pad_bucket
@@ -218,6 +218,19 @@ def coalesce_target_rows(cfg, shards: int = 1, resident_rows: int = 0) -> int:
     if resident_rows > 0:
         buckets *= resident_dispatch_segments(-(-resident_rows // (buckets * bucket)))
     return (buckets - 1) * bucket + int(cfg.batch_fill_target * bucket)
+
+
+def resident_dispatch_rows(cfg, shards: int = 1, resident_rows: int = 0) -> int:
+    """Rows of one join dispatch over a resident fact that is read as ranges
+    of its table: the whole morsels that reach
+    coalesce_target_rows(resident_rows=...), one morsel where the run takes no
+    long dispatch (`resident_rows` 0) or coalescing is off. THE one
+    definition: executor._feed_resident cuts the ranges by it and
+    executor._run_device_join prices the join's tiers at it."""
+    morsel = cfg.morsel_size_rows
+    target = coalesce_target_rows(cfg, shards, resident_rows=resident_rows) \
+        if resident_rows else 0
+    return max(-(-target // morsel), 1) * morsel
 
 
 def resident_dispatch_segments(fact_buckets: int) -> int:

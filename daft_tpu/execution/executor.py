@@ -69,8 +69,9 @@ def _exec(node: pp.PhysicalPlan) -> Iterator[MicroPartition]:
 
 def _decide_span(decide):
     """One `placement.decide` span over a decider's whole body (peek and
-    pricing included), carrying the tier it chose and whether the verdict
-    came from a cache. The deciders nest (`_select_mesh_tier` asks
+    pricing included), carrying the tier it chose, whether the verdict came
+    from a cache and the rows a dispatch a join's device arm was priced at.
+    The deciders nest (`_select_mesh_tier` asks
     `_mesh_wins`): a reader takes the union."""
     @functools.wraps(decide)
     def decided(*args, **kwargs):
@@ -80,6 +81,9 @@ def _decide_span(decide):
             if sp is not None:
                 sp.args["tier"] = str(out[0])
                 sp.args["cached"] = bool(getattr(out[-1], "cached", False))
+                # rows a dispatch the chosen device arm was priced at (a
+                # join's decision; 0 where the host won or nothing was priced)
+                sp.args["priced_rows"] = int(getattr(out[-1], "priced_rows", 0))
             return out
 
     return decided
@@ -1203,6 +1207,15 @@ def _run_device_join(node, label: str, make_run, assemble,
     read from the table on this thread (_resident_select: the dimension
     whole, the fact in the ranges it is dispatched in, _feed_resident); any
     other plan, the host plan and every fallback run through the pipeline.
+
+    The tiers are priced at the dispatch the run delivers: a fact read as
+    ranges at the range _feed_resident cuts (batching.resident_dispatch_rows,
+    the one arithmetic: eight buckets a device of a long fact, so the device
+    arms' round trip is shared by eight partitions, 32 over a mesh of four;
+    _resident_horizon), a fact that comes through the pipeline at what its
+    leading morsels promise of the coalescer (_coalesce_horizon). The rows a
+    dispatch the chosen device arm was priced at are counted
+    (`join_priced_dispatch_rows`) and ride the `placement.decide` span.
     """
     from ..config import execution_config
     from ..ops import counters as _counters
@@ -1331,15 +1344,25 @@ def _run_device_join(node, label: str, make_run, assemble,
         long_chip = batch0 is not None and not host_ids_reason(
             ctx, stage, grouped, topn, batch0)
         seen = [first] if second is None else [first, second]
-        # The tiers are PRICED at the horizon a dispatch had before it grew
-        # (batch_fill_target of a bucket a shard), which the coalescer now
-        # outdoes eight times over a resident fact. Held on purpose: priced
-        # at what is delivered, the chip arm's round trip falls from 2.3 to
-        # 0.29 ms a partition and `auto` sends TPC-H q19 at SF1 to a device
-        # tier no cell had measured (and q12 to within 3.5% of it): faster
-        # there (PERF.md, PR 43), but another placement, another set-up, and
-        # a change of its own.
-        coal = _coalesce_horizon(seen) if stream_wide else single_batch_horizon()
+        fact_rows = _resident_rows(node.fact) or 0
+
+        def horizon(shards: int) -> float:
+            """Partitions a dispatch over `shards` devices covers, as the
+            tiers are priced (the docstring above says by what)."""
+            if not stream_wide:
+                return single_batch_horizon()
+            if table is not None:
+                ranged = _resident_horizon(
+                    table, cfg, shards,
+                    fact_rows if shards > 1 or long_chip else 0)
+                if ranged is not None:
+                    return ranged
+            # (the one chip's arm never took the stream's length: as it was)
+            return _coalesce_horizon(
+                seen, shards=shards,
+                stream_rows=(fact_rows or None) if shards > 1 else None)
+
+        coal = horizon(1)
 
         # Mesh CANDIDATE resolution happens BEFORE pricing: the mesh arm is
         # only priced where the sharded dispatch takes this join, so a
@@ -1385,16 +1408,13 @@ def _run_device_join(node, label: str, make_run, assemble,
 
         prec = None
         tier = False
+        mesh_coal = horizon(mesh_width) if mesh_width >= 2 else coal
         if cfg.device_mode == "auto":
             if batch0 is not None:
                 tier, prec = _join_device_wins(
                     node, ctx, batch0, first.num_rows, grouped, stage,
                     topn=topn, label=label, coalesce=coal,
-                    mesh_ndev=mesh_width,
-                    mesh_coalesce=_coalesce_horizon(
-                        seen, shards=mesh_width,
-                        stream_rows=_resident_rows(node.fact))
-                    if mesh_width >= 2 and stream_wide else coal,
+                    mesh_ndev=mesh_width, mesh_coalesce=mesh_coal,
                     mesh_forced=cfg.mesh_devices >= 2 and mesh_width >= 2)
             _DECISION_CACHE.put(dk, tier)
         elif cfg.device_mode == "on":
@@ -1409,7 +1429,8 @@ def _run_device_join(node, label: str, make_run, assemble,
                     _t, prec = _join_device_wins(
                         node, ctx, batch0, first.num_rows, grouped, stage,
                         topn=topn, label=label, coalesce=coal,
-                        mesh_ndev=mesh_width, forced=True, forced_tier=tier)
+                        mesh_ndev=mesh_width, mesh_coalesce=mesh_coal,
+                        forced=True, forced_tier=tier)
             if prec is None:
                 prec = _placement.ledger().record(
                     label, "mesh" if tier == "mesh" else "device",
@@ -1465,8 +1486,7 @@ def _run_device_join(node, label: str, make_run, assemble,
                 # agg paths' (a single-batch flush hands the batch through as
                 # it is, contiguous morsels of a resident table glue back to
                 # the range they were cut from: Series.concat).
-                long_run = shards > 1 or long_chip
-                resident_rows = (_resident_rows(node.fact) or 0) if long_run else 0
+                resident_rows = fact_rows if shards > 1 or long_chip else 0
                 if table is not None:
                     fed_rows = _feed_resident(table, run, cfg, shards, resident_rows)
                 else:
@@ -1620,23 +1640,22 @@ def _feed_resident(table: List[MicroPartition], run, cfg, shards: int,
     """Hand a resident fact to `run.feed_batch` on this thread; the rows fed.
 
     A batch the pipeline would have cut into morsels (pipeline.cut_batches)
-    goes as zero-copy ranges of itself: as many whole morsels as reach
-    coalesce_target_rows(resident_rows=...) where the run takes a long
-    dispatch (`resident_rows` > 0), one morsel where it does not. Those are
+    goes as zero-copy ranges of itself: batching.resident_dispatch_rows, as
+    many whole morsels as reach coalesce_target_rows(resident_rows=...) where
+    the run takes a long dispatch (`resident_rows` > 0), one morsel where it
+    does not: the length the join's tiers were priced at. Those are
     the starts and lengths a DispatchCoalescer flushes of the same morsels,
     so the programs, their shapes and the slots keyed on the rows a range
     views are the same; a range never spans two batches. A batch that is not
     cut (a short one) is no view of a longer one: it goes through a
     coalescer at the plain threshold, which glues short batches by copy."""
     from ..ops import counters as _counters
-    from .batching import coalesce_target_rows
+    from .batching import resident_dispatch_rows
     from .pipeline import cut_batches
 
     morsel = cfg.morsel_size_rows
-    target = coalesce_target_rows(cfg, shards, resident_rows=resident_rows) \
-        if resident_rows else 0
     # whole morsels until the target is reached
-    range_rows = max(-(-target // morsel), 1) * morsel
+    range_rows = resident_dispatch_rows(cfg, shards, resident_rows)
     coalescer = _make_coalescer(run.feed_batch, cfg, shards)
     fed = 0
     for part in table:
@@ -1656,6 +1675,25 @@ def _feed_resident(table: List[MicroPartition], run, cfg, shards: int,
     return fed
 
 
+def _resident_horizon(table: List[MicroPartition], cfg, shards: int,
+                      resident_rows: int) -> Optional[float]:
+    """Morsels one dispatch of _feed_resident covers of `table`: the first
+    range it cuts (batching.resident_dispatch_rows, by pipeline.cut_batches'
+    own rule) over the morsel the decision looks at. None where the table's
+    first batch is not cut into ranges (a short one goes whole, through a
+    coalescer at the plain threshold)."""
+    from .batching import resident_dispatch_rows
+    from .pipeline import cut_batches
+
+    morsel = cfg.morsel_size_rows
+    range_rows = resident_dispatch_rows(cfg, shards, resident_rows)
+    for part in table:
+        for b, cut in cut_batches(part, morsel, range_rows):
+            if b.num_rows:
+                return b.num_rows / morsel if cut else None
+    return None
+
+
 def _decision_key(node, rows: int, cfg, topn: bool, layout: tuple) -> tuple:
     """Structural identity of one cost decision: the captured spec's shape +
     input size + the config knobs the decision reads + the data-dependent
@@ -1671,9 +1709,11 @@ def _decision_key(node, rows: int, cfg, topn: bool, layout: tuple) -> tuple:
     spec = node.spec
     return (
         # a fused TopN over the whole stream prices its one select against all
-        # of the fact's batches: the first partition's layout alone would let
-        # a one-batch fact's verdict serve a 458-batch one
-        topn, rows, _resident_rows(node.fact) if topn else None,
+        # of the fact's batches, and every join over a resident fact prices
+        # the dispatch the fact's length delivers (_resident_horizon): the
+        # first partition's layout alone would let a one-batch fact's verdict
+        # serve a 458-batch one
+        topn, rows, _resident_rows(node.fact),
         cfg.device_mode, cfg.device_amortize_runs,
         # the coalescing horizon feeds the costed decision: a config change to
         # the coalescer knobs OR a different fact batch layout must re-decide,
@@ -1926,7 +1966,7 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
         host_cost = costmodel.host_join_agg_cost(
             cal, host_rows, len(spec.dims), len(stage.aggs), True, False)
         if spec.predicate is not None:
-            host_cost.add("compute", rows / cal.host_agg_rate)  # filter pass
+            host_cost.add("compute", _host_filter_seconds(spec, rows, cal))
         if topn:
             # host additionally sorts the aggregate's output rows (once a
             # run: a partition of a streamed fact carries its share)
@@ -1972,7 +2012,7 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
         host_cost = costmodel.host_join_agg_cost(
             cal, host_rows, len(spec.dims), len(stage.aggs), False, False)
         if spec.predicate is not None:
-            host_cost.add("compute", rows / cal.host_agg_rate)  # filter pass
+            host_cost.add("compute", _host_filter_seconds(spec, rows, cal))
         if sharded:
             mesh_cost = costmodel.over_mesh(
                 costmodel.device_join_agg_cost(
@@ -2028,7 +2068,27 @@ def _join_device_wins(node, ctx, batch, rows: int, grouped: bool, stage,
         forced=forced, device=dev_cost, host=host_cost, mesh=mesh_cost,
         pallas=pallas_cost,
         detail=detail + (f", mesh x{mesh_ndev}" if mesh_ndev >= 2 else ""))
+    if chosen != "host":
+        # the rows a dispatch the arm that will run was priced at: beside
+        # device_join_batches and the rows fed it says whether price and
+        # delivery still agree
+        rec.priced_rows = int(round(rows * max(
+            mesh_coalesce if chosen == "mesh" else coal, 1.0)))
+        _counters.bump("join_priced_dispatch_rows", rec.priced_rows)
     return tier, rec
+
+
+def _host_filter_seconds(spec, rows: int, cal) -> float:
+    """What the host plan's filter costs a partition of `rows` rows: one
+    vectorized pass, and for each string membership on a FACT column
+    (`spec.fact_synthetic`: an `is_in` or an equality the device reads as a
+    resident plane) the dictionary encoding of the partition's fresh slice,
+    which is how the host evaluates it (series.dict_encode: the larger half of
+    what TPC-H q12 and q19 cost on the host tier). Without the term the two
+    tiers of such a join priced within a few per cent of each other and the
+    probed round trip of the minute decided."""
+    return rows / cal.host_agg_rate \
+        + len(spec.fact_synthetic) * rows / cal.host_dict_encode_rate
 
 
 def _dict_build_rows(key_series, rows: int, cal) -> int:
@@ -2398,7 +2458,10 @@ def _coalesce_horizon(parts, shards: int = 1,
                       stream_rows: Optional[int] = None) -> float:
     """Expected dispatch-coalescing factor from the OBSERVED leading
     partitions' batch granularity (`parts`: the first partition, plus a
-    peeked second when the caller got one). The coalescer merges
+    peeked second when the caller got one): what prices a stream that comes
+    through the pipeline and a DispatchCoalescer. A join's fact that is read
+    as ranges of its resident table is not priced here: its dispatch is cut
+    by arithmetic, not promised by morsels (_resident_horizon). The coalescer merges
     RecordBatches, so the morsel size that matters is the mean nonempty
     BATCH size, not the partition row count — a 128Ki-row partition of
     8Ki-row batches genuinely coalesces 8:1 even though the partition

@@ -154,6 +154,8 @@ DEVICE_COUNTER_NAMES = (
     "join_provision_calls",    # join dispatches whose columns came from one traced program
     "join_provision_traces",   # provisioning programs traced (0 on a repeat query shape)
     "join_window_gathers",     # adjacent-dimension gathers that read a batch-long window of the pack, summed over those dispatches
+    "join_unwindowed_gathers",  # adjacent-dimension gathers that read the whole pack (the fact is not ordered by that dimension's key), summed over those dispatches
+    "join_priced_dispatch_rows",  # rows a dispatch the chosen device arm of a join's costed decision was priced at, summed over those decisions
     # a dim filter's literal values are arguments of the subtree's visibility
     # program (ops/device_join.py verdict_plane): traced for a list of filter
     # skeletons, a dimension length and a mesh width, never for a value

@@ -62,7 +62,7 @@ class PlacementRecord:
 
     __slots__ = ("seq", "site", "chosen", "rows", "cached", "forced", "reason",
                  "detail", "ts", "device", "host", "mesh", "pallas",
-                 "observed", "error_ratio", "query_tag")
+                 "observed", "error_ratio", "query_tag", "priced_rows")
 
     def __init__(self, seq: int, site: str, chosen: str, rows: int,
                  cached: bool, forced: bool, reason: str, detail: str,
@@ -91,6 +91,9 @@ class PlacementRecord:
         self.observed: Optional[Dict[str, float]] = None
         self.error_ratio: Optional[float] = None
         self.query_tag = query_tag
+        # a join's costed decision: the rows a dispatch its chosen device arm
+        # was priced at (executor._join_device_wins); 0 for any other record
+        self.priced_rows = 0
 
     def margin(self) -> Optional[float]:
         """How close the losing tier was: losing total / winning total
@@ -125,6 +128,8 @@ class PlacementRecord:
             out["margin"] = round(m, 4)
         if self.error_ratio is not None:
             out["error_ratio"] = round(self.error_ratio, 4)
+        if self.priced_rows:
+            out["priced_rows"] = self.priced_rows
         return out
 
 

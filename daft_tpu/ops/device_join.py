@@ -588,6 +588,71 @@ def _gather_rows(mat, idx, windowed: bool = False, segment: int = 0):
     return jnp.where(hit, rows, mat[:, :1])
 
 
+# dimension rows _pack_lines lays as lines at a time (a power of two)
+_LINES_PIECE = 1 << 17
+# the longest pack a dispatch gathers from whole as the [P, N] matrix it is:
+# up to here the chip's compiler moves the whole operand into fast memory
+# ahead of the gather (`cross_program_prefetch` in the compiled text: 64 MB
+# yes, 96 MB no, tests/test_chip_compile.py), where a gather costs what it
+# costs out of a window (PERF.md, PR 39); a longer pack is read from HBM, P
+# values a row apart an index, and is laid as lines instead
+_FAST_PACK_BYTES = 64 << 20
+
+
+def _lane_width(rows: int) -> int:
+    """Lanes a dimension row's `rows` values take in a line (_pack_lines): the
+    next power of two, so that a line holds a whole number of rows."""
+    width = 1
+    while width < rows:
+        width *= 2
+    return width
+
+
+@jax.jit
+def _pack_lines(mat):
+    """A packed [P, N] dim matrix as lines of one lane width, a dimension
+    row's P values SIDE BY SIDE (padded to a power of two, _lane_width) and
+    128 // width rows a line: [N * width / 128, 128]. What a fact that is NOT
+    ordered by this dimension's key gathers from (_gather_lines): its indices
+    fit no window, and out of the [P, N] form every index reads P values N
+    apart, where a line holds them together."""
+    width = _lane_width(mat.shape[0])
+    padded = jnp.pad(mat, ((0, width - mat.shape[0]), (0, 0)))
+    # a piece at a time: the transposed [rows, width] form is held padded to a
+    # lane width (1 GB for `part`'s 2^21 rows at once, 64 MB a piece)
+    n = mat.shape[1]
+    piece = min(n, _LINES_PIECE)
+    return jnp.concatenate([
+        padded[:, at:at + piece].T.reshape(piece * width // _LANES, _LANES)
+        for at in range(0, n, piece)])
+
+
+def _gather_lines(lines, idx, rows: int, segment: int = 0):
+    """_gather_rows's plain form, value for value and bit for bit, out of the
+    same pack laid as lines (_pack_lines): each index's line, then its own
+    `rows` lanes of it, kept by their bits (a sum over the other rows' zeros
+    in int32, so a NaN or -0.0 stays itself). A miss reads row 0, as the
+    plain form's clip makes it. Indices longer than `segment` are gathered a
+    segment at a time and the pieces glued: a gathered [segment, 128] piece
+    is 64 MB at 131,072 rows, eight of them at once are not held."""
+    width = _lane_width(rows)
+    per = _LANES // width
+    n = lines.shape[0] * per
+    if 0 < segment < idx.shape[0]:
+        return jnp.concatenate([_gather_lines(lines, idx[at:at + segment], rows)
+                                for at in range(0, idx.shape[0], segment)], axis=1)
+    safe = jnp.clip(idx, 0, n - 1)
+    taken = jax.lax.bitcast_convert_type(lines[safe // per], jnp.int32)     # [indices, 128]
+    lane = jnp.arange(_LANES, dtype=jnp.int32)
+    kept = jnp.where(lane // width == (safe % per)[:, None], taken, jnp.int32(0))
+    # turned lanes-to-rows first, so that the fold of the `per` rows' lanes
+    # onto the first `width` adds vectors as long as the indices (a reshape of
+    # the lines to [indices, per, width] would pad every `width` to a lane
+    # width), and what comes out is [width, indices]: the planes' own form
+    got = jnp.sum(kept.T.reshape(per, width, idx.shape[0]), axis=0, dtype=jnp.int32)
+    return jax.lax.bitcast_convert_type(got, jnp.float32)[:rows]
+
+
 def _index_span(idx: np.ndarray) -> int:
     """Greatest less least matched row of a batch's host index, -1 where no
     row matched: what decides whether the batch's gather fits a window."""
@@ -609,6 +674,7 @@ class _ProvisionLayout:
     cap: int         # the combined codes are clipped to [0, cap); 0 where no codes are asked for
     devices: int = 1  # local devices the batch's rows are sharded over: each runs the program on its shard
     segment: int = 0  # rows of a device's share that read one window (a longer share is gathered in segments); 0: all
+    lines: tuple = ()  # per adjacent dim: the rows of its pack where it comes as lines (_pack_lines: an unordered dimension's whole pack), 0 where as the pack; () where none does
 
 
 class _CodePlan(NamedTuple):
@@ -644,11 +710,14 @@ def _provision_program(layout: _ProvisionLayout):
         counters.bump("join_provision_traces")   # runs when traced, not when called
         gathered = []
         ok = None
-        for mat, didx, ok_row, windowed in zip(mats, idxs, layout.packs,
-                                               layout.windows):
+        lines = layout.lines or (0,) * len(mats)
+        for mat, didx, ok_row, windowed, as_lines in zip(mats, idxs, layout.packs,
+                                                         layout.windows, lines):
             aok = didx >= 0
             rows = None
-            if mat is not None:
+            if as_lines:
+                rows = _gather_lines(mat, didx, as_lines, layout.segment)   # [P, bucket]
+            elif mat is not None:
                 rows = _gather_rows(mat, didx, windowed, layout.segment)   # [P, bucket]
             if ok_row is not None:
                 aok = aok & (rows[ok_row] > 0.5)
@@ -968,8 +1037,10 @@ class _JoinContext:
         predicates rebuild in place."""
         colname, values = self.spec.fact_synthetic[syn]
         s = batch.get_column(colname)
+        built = []
 
         def build():
+            built.append(syn)
             codes, vals, _k = s.dict_codes()
             match = np.array([i for i, v in enumerate(vals) if v in values],
                              dtype=np.int32)
@@ -985,8 +1056,14 @@ class _JoinContext:
                     else device_row_mask(bucket, bucket, self.mesh)
             return plane, valid
 
-        return series_keyed(s, ("fmem", syn, bucket) + self._mesh_key(), (),
-                            build, literals=values)
+        # the look-up a dispatch: a hit on a repeat query, a build where the
+        # plane was never made (or the query's match values changed)
+        with profile_span("join.membership", "device", rows=batch.num_rows) as sp:
+            plane = series_keyed(s, ("fmem", syn, bucket) + self._mesh_key(), (),
+                                 build, literals=values)
+            if sp is not None:
+                sp.args["hit"] = not built
+        return plane
 
     def _permuted_membership(self, batch, bucket: int, syn: str, perm) -> dev.DCol:
         colname, values = self.spec.fact_synthetic[syn]
@@ -1680,6 +1757,24 @@ class _JoinContext:
                                       else _stack_verdict(pack, verdict))
         return self._query_packs[key][1], 0 if pack is None else pack.shape[0]
 
+    def _gathers_lines(self, mat) -> bool:
+        """Whether a dispatch that reads the whole of `mat` (a pack longer
+        than a window, the fact not ordered by its dimension's key) gathers
+        from the pack laid as lines (_pack_lines, _gather_lines) and not from
+        the [P, N] matrix itself: a pack too long for the chip's fast memory
+        (_FAST_PACK_BYTES), on one chip (over a mesh the packs are laid out
+        whole on every chip as they are)."""
+        return self.mesh is None and mat.nbytes > _FAST_PACK_BYTES
+
+    def _lines_of(self, adj: DimSpec, mat):
+        """`mat` (what query_pack gives for `adj`) as lines (_pack_lines), made
+        once a query and matrix by this context: the stacked copy that holds
+        the query's verdict is a query's own, so its lines are too."""
+        key = (adj.name, "lines", id(mat))
+        if key not in self._query_packs:    # (once a query, not a dispatch)
+            self._query_packs[key] = (mat, _pack_lines(mat))
+        return self._query_packs[key][1]
+
     def _permuted_fact_plane(self, series, bucket: int, perm) -> dev.DCol:
         """Resident fact plane reordered by the group-sorted permutation —
         one device gather, cached per (series, perm) identity."""
@@ -1714,10 +1809,11 @@ class _JoinContext:
         spec = self.spec
         gb_cols, radices, cap, fact_code_planes = codes or _CodePlan((), (), 0, {})
         adj_of: Dict[str, int] = {}
-        mats, idxs, ok_rows, windows, layouts = [], [], [], [], []
+        mats, idxs, ok_rows, windows, layouts, lines = [], [], [], [], [], []
         ndev = self.mesh_devices
         # rows of a device's share that read one window: a segment's
         window = self.window_rows(bucket)
+        unwindowed = 0
         for a, adj in enumerate(self._adjacent()):
             adj_of[adj.name] = a
             didx, span = self.dev_idx(batch, adj.name, bucket, perm=perm)
@@ -1731,6 +1827,13 @@ class _JoinContext:
             # rows lie that close and the pack is longer than one window
             windows.append(mat is not None and span is not None
                            and span < window < mat.shape[1])
+            # the whole of a pack longer than a window: a fact not ordered by
+            # this dimension's key
+            whole = mat is not None and not windows[-1] and mat.shape[1] > window
+            lines.append(mat.shape[0] if whole and self._gathers_lines(mat) else 0)
+            if lines[-1]:
+                mats[-1] = self._lines_of(adj, mat)
+            unwindowed += whole
             layouts.append((layout, code_layout, wide))
 
         dcols: Dict[str, dev.DCol] = {}
@@ -1782,11 +1885,16 @@ class _JoinContext:
 
         prog = _provision_program(_ProvisionLayout(
             tuple(ok_rows), tuple(windows), tuple(columns), tuple(code_cols), cap,
-            ndev, window if window < bucket // ndev else 0))
+            ndev, window if window < bucket // ndev else 0,
+            tuple(lines) if any(lines) else ()))
         gathered, combined = prog(tuple(mats), tuple(idxs), tuple(fact_codes))
         counters.bump("join_provision_calls")
         if any(windows):
             counters.bump("join_window_gathers", windows.count(True))
+        # the complement: a fact not ordered by this dimension's key reads the
+        # whole of a pack that is longer than a window
+        if unwindowed:
+            counters.bump("join_unwindowed_gathers", unwindowed)
         dcols.update(gathered)
         return dcols, combined
 
@@ -1913,16 +2021,29 @@ def _with_join_ok(predicate: Optional[Expression]) -> Expression:
     return ok if predicate is None else (predicate & ok)
 
 
+def _fact_dictionary(s) -> tuple:
+    """(values, K) of the dictionary of a fact column's rows, kept on the
+    ROWS (series_keyed: the residency manager's lineage), not on the object:
+    Series._dict_codes lives on the Series, and a range of a resident table
+    is a new object every query, so a join grouped by a fact column (TPC-H
+    q12: `l_shipmode`) encoded every range anew on the host every query,
+    1.15 s of a 1.26 s q12 at SF10 (PERF.md, PR 49). The codes themselves
+    are the resident plane (cached_dict_code_plane), made from the same
+    encoding where it has to be built."""
+    return series_keyed(s, ("factdict",), (), lambda: tuple(s.dict_codes()[1:]))
+
+
 def _dict_code_product(ctx: _JoinContext, batch, gb_cols) -> Optional[int]:
     """Product of per-column dictionary cardinalities (host, cached), or
     None when a groupby column cannot dictionary-encode."""
     total = 1
     for name in gb_cols:
         side = ctx.spec.col_side.get(name)
-        src = batch.get_column(name) if side == "fact" \
-            else ctx._dim_source(side, name)
         try:
-            _c, _v, k = src.dict_codes()
+            if side == "fact":
+                _v, k = _fact_dictionary(batch.get_column(name))
+            else:
+                _c, _v, k = ctx._dim_source(side, name).dict_codes()
         except Exception:  # lint: ignore[broad-except] -- estimate only; caller treats None as unknown
             return None
         total *= max(k, 1)
@@ -2073,9 +2194,9 @@ class DeviceJoinGroupedRun(GroupedAggRun):
             side = spec.col_side.get(name)
             if side == "fact":
                 s = batch.get_column(name)
-                codes, values, k = s.dict_codes()
-                fact_codes[name] = cached_dict_code_plane(s, codes, n, bucket,
-                                                          ctx.mesh)
+                values, k = _fact_dictionary(s)
+                fact_codes[name] = cached_dict_code_plane(
+                    s, lambda s=s: s.dict_codes()[0], n, bucket, ctx.mesh)
             else:
                 _codes, values, k = ctx._dim_source(side, name).dict_codes()
             dicts.append((values, k))
@@ -2843,10 +2964,14 @@ class DeviceJoinUngroupedRun(FilterAggRun):
         if n == 0:
             return
         bucket = self.ctx.bucket_for(n)
-        with profile_span("device.h2d", "device", rows=n, bucket=bucket):
+        # one `device.dispatch` a join dispatch, the provisioning (`join.*`)
+        # and the launch inside it, as the grouped run's: the readers count a
+        # join's dispatches by the `device.dispatch` spans that hold a `join.*`
+        with profile_span("device.dispatch", "device", op="join_filter_agg",
+                          rows=n, bucket=bucket):
             dcols = self.ctx.device_cols(
                 batch, bucket, list(self.stage._input_cols) + ["__join_ok__"])
-        self._run(dcols, n, bucket, self.ctx.mesh)
+            self._launch(dcols, n, bucket, self.ctx.mesh)
         counters.bump("device_join_batches")
         if self.mesh_devices > 1:
             note_join_mesh_dispatch(self.mesh_devices, stage_noted=True)   # (by _run)

@@ -119,17 +119,18 @@ def _codes_slot(cap: int, mesh=None) -> tuple:
         ("dictcodes", cap, "mesh", int(mesh.shape[MESH_AXIS]), MESH_AXIS)
 
 
-def cached_dict_code_plane(src, codes: np.ndarray, rows: int, cap: int,
-                           mesh=None):
+def cached_dict_code_plane(src, codes, rows: int, cap: int, mesh=None):
     """Device plane of dictionary codes padded to `cap`, registered in the
     HBM residency manager anchored on the Series (grouped stages and the
     join stage share it). With `mesh` the plane is row-sharded over it, under
-    a slot key of its own like a column plane's (Series.to_device_cached)."""
+    a slot key of its own like a column plane's (Series.to_device_cached).
+    `codes`: the rows' codes, or a callable that makes them, asked only where
+    the plane has to be built."""
     from ..core.series import note_upload
     from ..device.residency import manager
 
     def build():
-        padded = _padded_codes(codes, rows, cap)
+        padded = _padded_codes(codes() if callable(codes) else codes, rows, cap)
         note_upload(transfers=1, planes=1)
         return jnp.asarray(padded) if mesh is None \
             else shard_rows(mesh, padded, cap)
@@ -482,15 +483,21 @@ class FilterAggRun:
              mesh=None) -> None:
         """One dispatch over planes of `bucket` rows: on the default device,
         or with `mesh` (the planes row-sharded over it) on every device of it."""
-        ndev = self.mesh_devices if mesh is not None else 1
         with profile_span("device.dispatch", "device", op="filter_agg",
                           rows=n, bucket=bucket):
-            prog = self.stage._jit_for(bucket, ndev)
-            mask = device_row_mask(n, bucket, mesh)
-            lit_args = self.literals.args(mesh=mesh)
-            with profile_span("device.launch", "device", op="filter_agg",
-                              bucket=bucket, devices=ndev):
-                res = prog(dcols, mask, lit_args)
+            self._launch(dcols, n, bucket, mesh)
+
+    def _launch(self, dcols: Dict[str, dev.DCol], n: int, bucket: int,
+                mesh=None) -> None:
+        """`_run` inside the caller's own `device.dispatch` span (a join run's
+        dispatch holds its provisioning too)."""
+        ndev = self.mesh_devices if mesh is not None else 1
+        prog = self.stage._jit_for(bucket, ndev)
+        mask = device_row_mask(n, bucket, mesh)
+        lit_args = self.literals.args(mesh=mesh)
+        with profile_span("device.launch", "device", op="filter_agg",
+                          bucket=bucket, devices=ndev):
+            res = prog(dcols, mask, lit_args)
         counters.bump("device_stage_batches")
         if ndev > 1:
             note_mesh_dispatch(ndev)

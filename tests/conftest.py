@@ -55,6 +55,15 @@ _POSITION_PINS = {
         "pins PR 41's cell and configuration to the end of BENCHMARK.json's lists; PR 45's "
         "tpch_sf10.adhoc_joins follows them (what else it asserts is kept by name in "
         "test_bench_adhoc_joins.py::test_the_join_cells_entries_are_as_they_were)",
+    "test_bench_adhoc_joins.py::test_the_cell_and_its_metrics_are_entries_by_name":
+        "counts EIGHT cells in BENCHMARK.json; PR 49's tpch_sf10.filtered_joins is the ninth "
+        "(what else it asserts is kept by name in test_bench_filtered_joins.py::"
+        "test_the_ad_hoc_join_cells_entries_are_as_they_were)",
+    "test_bench_adhoc_joins.py::test_the_join_cells_entries_are_as_they_were":
+        "pins PR 45's cell, configuration and adhocjoin.* metrics to the end of BENCHMARK.json's "
+        "lists; PR 49's tpch_sf10.filtered_joins follows them (what else it asserts is kept by "
+        "name in test_bench_filtered_joins.py::"
+        "test_the_ad_hoc_join_cells_entries_are_as_they_were)",
 }
 
 

@@ -511,6 +511,124 @@ def test_windowed_provisioning_gathers_from_a_batch_long_window(one_chip, rows, 
         assert windowed == [(JOIN_BATCH // 128, 128)] * segments
 
 
+def _star_join_layout(one_chip, query):
+    """(layout, packs) of q3's, q10's or q5's provisioning program at
+    `tpch_sf10.joins`' shapes, as `_JoinContext._provision` makes them: every
+    fact-adjacent dimension longer than a window is gathered windowed."""
+    from daft_tpu.ops.device_join import _ProvisionLayout
+
+    if query == "q5":
+        return _ProvisionLayout(
+            packs=(5, 2), windows=(True, False),
+            columns=(("c_nationkey", 0, (0,), 1), ("o_total", 0, (2,), 3),
+                     ("s_nationkey", 1, (0,), 1)),
+            codes=((0, 4, 1),), cap=32, segment=JOIN_BATCH), (
+            _s(one_chip, (6, ORDERS_CAP), jnp.float32),
+            _s(one_chip, (3, JOIN_BATCH), jnp.float32))
+    return _ProvisionLayout(packs=(0,), windows=(True,), columns=(), codes=(), cap=0,
+                            segment=JOIN_BATCH), (_s(one_chip, (1, ORDERS_CAP), jnp.float32),)
+
+
+@pytest.mark.parametrize("query", ["q3", "q5", "q10"])
+def test_the_star_joins_provisioning_gathers_a_packs_rows_together(one_chip, query):
+    """q3's, q5's and q10's provisioning programs at a dispatch of 2^20 rows
+    (eight segments): a windowed dimension's rows are gathered TOGETHER, a
+    segment at a time, out of its window, and no plane of the dispatch's
+    length is re-laid on the way. PR 48's tree took the rows out of their
+    packs and gathered them a plane at a time for the sake of an unordered
+    dimension, and the ledger read it as two dispatch-long copies of a
+    `[1, 1048576]` plane and +1.93 ms a dispatch in the three join cells:
+    what an unordered dimension needs is chosen for THAT dimension, and a
+    layout whose dimensions are all windowed keeps this program."""
+    layout, mats = _star_join_layout(one_chip, query)
+    idxs = tuple(_s(one_chip, (8 * JOIN_BATCH,), jnp.int32) for _ in mats)
+    from daft_tpu.ops.device_join import _provision_program
+
+    text = _compile(_provision_program(layout), mats, idxs, ())
+    relaid = re.findall(r"= (\w+)\[1,1048576\]\{[^}]*\} copy\(", text)
+    # (q5's `supplier` pack is shorter than a window: its three rows are
+    # gathered whole and handed on a row each, as they were)
+    assert relaid == (["f32"] * 3 if query == "q5" else []), relaid
+    read = _gather_operand_shapes(text)
+    if query == "q5":
+        # six rows of `orders`' window a segment, and `supplier`'s short pack whole
+        assert sorted(read) == sorted([(6, JOIN_BATCH)] * 8 + [(3, JOIN_BATCH)])
+    else:       # the one-row pack as rows of a lane width, a segment at a time
+        assert read == [(JOIN_BATCH // 128, 128)] * 8
+
+
+PART_CAP = 1 << 21      # part's 2 M rows at SF10, padded (customer's 1.5 M too)
+
+
+def _unwindowed_layout(query, lines):
+    """q14's or q19's provisioning layout at `tpch_sf10.filtered_joins`'
+    shapes and the rows of `part`'s pack it gathers from: [2, 2^21] for q14's
+    prefix flag, [16, 2^21] for q19's six flags, `p_size` in two digits and
+    the verdict."""
+    from daft_tpu.ops.device_join import _ProvisionLayout
+
+    if query == "q14":
+        return 2, _ProvisionLayout(
+            packs=(None,), windows=(False,), columns=(("promo", 0, (0,), 1),), codes=(),
+            cap=0, segment=JOIN_BATCH, lines=(2,) if lines else ())
+    flags = tuple((f"flag{i}", 0, (at,), at + 1) for i, at in enumerate((0, 2, 7, 9, 11, 13)))
+    return 16, _ProvisionLayout(
+        packs=(15,), windows=(False,), columns=flags[:2] + (("p_size", 0, (4, 5), 6),)
+        + flags[2:], codes=(), cap=0, segment=JOIN_BATCH, lines=(16,) if lines else ())
+
+
+def test_an_unordered_dimensions_pack_is_gathered_whole_from_fast_memory_where_it_fits(one_chip):
+    """`l_partkey` is uniform over `part`, so a dispatch's 2^20 indices fit no
+    window and the gather reads `part`'s whole pack. q14's (16 MB) the chip's
+    compiler moves into fast memory ahead of the gather, and so it does any
+    pack up to `_FAST_PACK_BYTES`; q19's (128 MB) it reads from HBM, which is
+    why that one is laid as lines (`_JoinContext._gathers_lines`)."""
+    from daft_tpu.ops.device_join import _FAST_PACK_BYTES, _provision_program
+
+    idxs = (_s(one_chip, (8 * JOIN_BATCH,), jnp.int32),)
+    for query, prefetched in (("q14", True), ("q19", False)):
+        rows, layout = _unwindowed_layout(query, lines=False)
+        assert (4 * rows * PART_CAP <= _FAST_PACK_BYTES) == prefetched
+        text = _compile(_provision_program(layout), (_s(one_chip, (rows, PART_CAP), jnp.float32),),
+                        idxs, ())
+        assert ("cross_program_prefetch_index" in text) == prefetched, query
+        assert max(max(shape) for shape in _gather_operand_shapes(text)) == PART_CAP
+    # the threshold itself: the longest pack of `part`'s length that is still moved
+    gather = jax.jit(lambda mat, idx: mat[:, jnp.clip(idx, 0, PART_CAP - 1)])
+    for rows in (8, 12):
+        text = _compile(gather, _s(one_chip, (rows, PART_CAP), jnp.float32), idxs[0])
+        assert ("cross_program_prefetch_index" in text) == (4 * rows * PART_CAP <= _FAST_PACK_BYTES)
+
+
+def test_a_pack_laid_as_lines_lowers_at_sf10(one_chip):
+    """q19's provisioning program at `tpch_sf10.filtered_joins`' shapes, its
+    pack laid as lines of one lane width with a dimension row's values side
+    by side (`_pack_lines`). The chip's compiler accepts both programs: the
+    one that lays the lines (once a query, a piece at a time: it holds no
+    whole padded transpose) and the one that gathers a line an index, a
+    segment at a time, and hands on what the layout names."""
+    from daft_tpu.ops.device_join import (_LINES_PIECE, _lane_width, _pack_lines,
+                                          _provision_program)
+
+    rows, layout = _unwindowed_layout("q19", lines=True)
+    laid = _pack_lines.lower(_s(one_chip, (rows, PART_CAP), jnp.float32)).compile()
+    lines_shape = (PART_CAP * _lane_width(rows) // 128, 128)
+    memory = laid.memory_analysis()
+    assert memory.output_size_in_bytes == 4 * lines_shape[0] * 128
+    # a piece's rows padded to a lane width and the pieces before they are glued
+    assert memory.temp_size_in_bytes <= memory.output_size_in_bytes + 2 * 4 * _LINES_PIECE * 128
+    mats = (_s(one_chip, lines_shape, jnp.float32),)
+    idxs = (_s(one_chip, (8 * JOIN_BATCH,), jnp.int32),)
+    compiled = _provision_program(layout).lower(mats, idxs, ()).compile()
+    # eight gathers of a segment's lines, each out of the whole pack
+    assert _gather_operand_shapes(compiled.as_text()) == [lines_shape] * 8
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == 4 * (lines_shape[0] * 128 + 8 * JOIN_BATCH)
+    # (the gathered lines of the segments, and the joined columns with their validity)
+    assert memory.temp_size_in_bytes + memory.output_size_in_bytes \
+        <= 9 * 4 * JOIN_BATCH * 128 + 2 * 4 * rows * 8 * JOIN_BATCH
+
+
 CUSTOMER_CAP = 1 << 21      # customer's 1.5 M rows at SF10, padded
 
 

@@ -1681,10 +1681,13 @@ def test_resident_select_answers_for_a_select_over_one_table_only(case, tmp_path
 @pytest.mark.parametrize("morsels", [1, 2, 19])
 def test_the_decision_reads_what_it_read(morsels, monkeypatch):
     """What `auto` decides from is unchanged by the road the fact takes: the
-    decision key, the first morsel's layout, the rows tested against
-    device_min_rows and the horizons handed to _join_device_wins are, for a
-    fact of one morsel, of two and of nineteen, what the pipeline's first two
-    morsels gave (the road a plan that is no select still takes)."""
+    decision key, the first morsel's layout and the rows tested against
+    device_min_rows are, for a fact of one morsel, of two and of nineteen,
+    what the pipeline's first two morsels gave (the road a plan that is no
+    select still takes). The horizon is each road's own: a fact read as
+    ranges is priced at the range it is dispatched in (eight morsels of
+    nineteen), a fact through the pipeline at what its leading morsels
+    promise of the coalescer, as before."""
     import jax
     from daft_tpu.execution import executor
 
@@ -1728,7 +1731,21 @@ def test_the_decision_reads_what_it_read(morsels, monkeypatch):
     assert direct == piped == host
     # (the fused TopN's decision, then that of the join-aggregate its host
     # plan holds)
-    assert mine == theirs and [s[0] for s in mine] == ["layout", "key", "wins"] * 2
+    assert [s[0] for s in mine] == ["layout", "key", "wins"] * 2
+
+    def less_the_horizon(s):
+        return s[:3] + s[4:5] + s[6:] if s[0] == "wins" else s
+
+    assert [less_the_horizon(s) for s in mine] == [less_the_horizon(s) for s in theirs]
+    ranged = [s[3] for s in mine if s[0] == "wins"]
+    streamed = [s[3] for s in theirs if s[0] == "wins"]
+    if morsels <= 2:        # a table of two morsels is not cut: one road, one price
+        assert ranged == streamed
+    else:
+        # (the join-aggregate of the host plan factorizes its group ids on
+        # the host a morsel at a time: its dispatch is one morsel, and so is
+        # its price)
+        assert ranged == [8.0, 1.0] and all(h <= 2.0 for h in streamed)
     first_rows = _MORSEL * morsels if morsels <= 2 else _MORSEL
     assert {s[2] for s in mine if s[0] != "key"} == {first_rows}, \
         "a morsel is what the decision sees"
@@ -1887,11 +1904,13 @@ def test_morsels_that_are_no_resident_run_flush_at_the_threshold_they_had(case):
 
 @pytest.mark.parametrize("shards", [1, 4])
 def test_the_priced_horizon_is_held_where_it_was_and_never_over_what_runs(shards):
-    """Over a resident fact the coalescer delivers DISPATCH_SEGMENTS morsels
-    a shard a dispatch; _coalesce_horizon, which the tiers are priced with,
-    stays at the plain threshold's factor (batch_fill_target of the last
-    shard's bucket), so a verdict is what it was and the price never
-    promises more than runs. Both read coalesce_target_rows."""
+    """Over a resident fact that comes through the PIPELINE the coalescer
+    delivers DISPATCH_SEGMENTS morsels a shard a dispatch; _coalesce_horizon,
+    which such a fact's tiers are priced with, stays at the plain threshold's
+    factor (batch_fill_target of the last shard's bucket), so its verdict is
+    what it was and the price never promises more than runs. Both read
+    coalesce_target_rows. (A fact read as ranges of its table is priced at
+    the range itself: test_a_join_is_priced_at_the_dispatch_the_run_delivers.)"""
     from daft_tpu.config import execution_config
     from daft_tpu.core.micropartition import MicroPartition
     from daft_tpu.execution.batching import coalesce_target_rows
@@ -1973,7 +1992,8 @@ def test_a_topn_verdict_is_keyed_on_the_whole_fact_not_its_first_partition():
     """Two q3-shaped plans whose first fact partition looks the same (one
     morsel of the same rows) but whose facts are 1 and 3 batches long get
     different decision keys: a cached verdict of one never serves the other.
-    A join that is no TopN keys as before, on the first partition alone."""
+    So does a join that is no TopN, since a resident fact's length sets the
+    dispatch its tiers are priced at (executor._resident_horizon)."""
     from daft_tpu.config import execution_config as get_config
     from daft_tpu.execution import executor
     from daft_tpu.plan import physical as pp
@@ -2000,7 +2020,8 @@ def test_a_topn_verdict_is_keyed_on_the_whole_fact_not_its_first_partition():
     key = lambda node, topn: executor._decision_key(node, _MORSEL, cfg, topn, layout)[:-1]
     assert key(one, True) != key(three, True)
     assert key(one, True) == key(topn_node(_MORSEL), True)
-    assert key(one, False) == key(three, False)
+    assert key(one, False) != key(three, False)
+    assert key(one, False) == key(topn_node(_MORSEL), False)
 
 
 def test_run_wide_sums_are_double_singles():
@@ -2272,7 +2293,9 @@ def test_windowed_gather_is_the_plain_gather_bit_for_bit(monkeypatch, keys, shap
     def both(layout):
         def prog(mats, idxs, fact_codes):
             windows.append(layout.windows)
-            assert (mats[0].shape[0] == 1) == (shape is _win_q_one_row)
+            # (a pack gathered whole by an unordered batch comes as lines: its rows ride the layout)
+            pack_rows = layout.lines[0] if layout.lines and layout.lines[0] else mats[0].shape[0]
+            assert (pack_rows == 1) == (shape is _win_q_one_row)
             got = real(layout)(mats, idxs, fact_codes)
             if any(layout.windows):
                 plain = real(dataclasses.replace(
@@ -2345,3 +2368,267 @@ def test_windowed_gather_keeps_every_bit(rows):
         got = np.asarray(_gather_rows(mat, jnp.asarray(idx), True))
         want = np.asarray(_gather_rows(mat, jnp.asarray(idx), False))
         assert got.tobytes() == want.tobytes(), start
+
+
+@pytest.mark.parametrize("rows", [1, 2, 3, 15, 16])
+@pytest.mark.parametrize("segment", [0, 1024], ids=["at_once", "in_segments"])
+def test_a_pack_laid_as_lines_gathers_the_plain_gathers_bits(rows, segment, monkeypatch):
+    """A pack of 1, 2, 3, 15 or 16 rows laid as lines of one lane width (a
+    dimension row's values side by side, padded to a power of two) hands on,
+    for unordered indices with misses among them, the bits the plain gather
+    of the [P, N] pack hands on: NaN payloads, -0.0, infinities and
+    denormals, at once and a segment at a time."""
+    import jax
+    import jax.numpy as jnp
+    from daft_tpu.ops import device_join as dj
+    from daft_tpu.ops.device_join import _gather_lines, _gather_rows, _lane_width, _pack_lines
+
+    rng = np.random.default_rng(5)
+    n, count = 8192, 4096
+    bits = rng.integers(0, 2**32, (rows, n), dtype=np.uint64).astype(np.uint32)
+    bits[:, ::5] = np.float32(-0.0).view(np.uint32)
+    bits[:, 1::5] = np.uint32(0x7FC01234)
+    mat = jnp.asarray(bits.view(np.float32))
+    lines = _pack_lines(mat)
+    assert lines.shape == (n * _lane_width(rows) // 128, 128)
+    # laid eight pieces at a time, the same lines
+    monkeypatch.setattr(dj, "_LINES_PIECE", n // 8)
+    assert np.asarray(jax.jit(_pack_lines.__wrapped__)(mat)).tobytes() == np.asarray(lines).tobytes()
+    idx = rng.integers(0, n, count).astype(np.int32)
+    idx[[0, 7, count - 1]] = -1
+    idx[[1, 2]] = [0, n - 1]
+    got = np.asarray(_gather_lines(lines, jnp.asarray(idx), rows, segment))
+    want = np.asarray(_gather_rows(mat, jnp.asarray(idx), False))
+    assert got.shape == want.shape == (rows, count) and got.tobytes() == want.tobytes()
+
+
+# ---- filtered joins: q12-, q14- and q19-shaped, priced at the delivered dispatch ---------
+
+_PART_ROWS = 5000       # longer than a window of one morsel: an unordered gather reads it whole
+
+
+def _filtered_like(morsels, seed=41, tail=100, part_rows=_PART_ROWS):
+    """`orders`, `part` and a `lineitem` of `morsels` morsels less `tail` rows
+    that follows `orders` (l_orderkey never decreases) and draws l_partkey
+    uniformly over `part`, with the columns q12, q14 and q19 read."""
+    import datetime
+
+    rng = np.random.default_rng(seed)
+    n_l = _MORSEL * morsels - tail
+    n_o = max(n_l // 4, 100)
+    day0 = datetime.date(1994, 1, 1)
+    days = lambda lo, hi, n: [day0 + datetime.timedelta(days=int(x)) for x in rng.integers(lo, hi, n)]
+    containers = [f"{a} {b}" for a in ("SM", "MED", "LG") for b in ("CASE", "BOX", "PACK", "PKG", "BAG")]
+    t = {
+        "orders": {"o_orderkey": list(range(n_o)),
+                   "o_orderpriority": rng.choice(["1-URGENT", "2-HIGH", "3-MEDIUM", "5-LOW"], n_o).tolist()},
+        "part": {"p_partkey": list(range(part_rows)),
+                 "p_brand": rng.choice(["Brand#12", "Brand#23", "Brand#34", "Brand#45"], part_rows).tolist(),
+                 "p_container": rng.choice(containers, part_rows).tolist(),
+                 "p_size": rng.integers(1, 51, part_rows).tolist(),
+                 "p_type": rng.choice(["PROMO TIN", "STANDARD TIN", "PROMO BRASS", "SMALL STEEL"],
+                                      part_rows).tolist()},
+        "lineitem": {"l_orderkey": np.sort(rng.integers(0, n_o, n_l)).tolist(),
+                     "l_partkey": rng.integers(0, part_rows, n_l).tolist(),
+                     "l_shipmode": rng.choice(["MAIL", "SHIP", "AIR", "REG AIR", "RAIL"], n_l).tolist(),
+                     "l_shipinstruct": rng.choice(["DELIVER IN PERSON", "COLLECT COD", "NONE"], n_l).tolist(),
+                     "l_quantity": rng.integers(1, 51, n_l).astype(float).tolist(),
+                     "l_extendedprice": rng.uniform(900, 90000, n_l).round(2).tolist(),
+                     "l_discount": (rng.integers(0, 11, n_l) / 100).tolist(),
+                     "l_shipdate": days(0, 300, n_l), "l_commitdate": days(100, 400, n_l),
+                     "l_receiptdate": days(200, 500, n_l)},
+    }
+    return {name: daft_tpu.from_pydict(cols).collect() for name, cols in t.items()}
+
+
+def _q12_shaped(t):
+    high = col("o_orderpriority").is_in(["1-URGENT", "2-HIGH"])
+    return (t["lineitem"].where(
+        col("l_shipmode").is_in(["MAIL", "SHIP"])
+        & (col("l_commitdate") < col("l_receiptdate")) & (col("l_shipdate") < col("l_commitdate"))
+        & (col("l_receiptdate") >= _days(1994, 9, 1)) & (col("l_receiptdate") < _days(1995, 3, 1)))
+        .join(t["orders"], left_on="l_orderkey", right_on="o_orderkey")
+        .with_column("high_line", high.if_else(lit(1), lit(0)))
+        .with_column("low_line", (~high).if_else(lit(1), lit(0)))
+        .groupby("l_shipmode")
+        .agg(col("high_line").sum().alias("high_line_count"),
+             col("low_line").sum().alias("low_line_count"))
+        .sort("l_shipmode"))
+
+
+def _q14_shaped(t):
+    return (t["lineitem"].where((col("l_shipdate") >= _days(1994, 3, 1))
+                                & (col("l_shipdate") < _days(1994, 6, 1)))
+            .join(t["part"], left_on="l_partkey", right_on="p_partkey")
+            .with_column("revenue", col("l_extendedprice") * (1 - col("l_discount")))
+            .with_column("promo", col("p_type").str.startswith("PROMO").if_else(col("revenue"), lit(0.0)))
+            .agg(col("promo").sum().alias("promo_sum"), col("revenue").sum().alias("total_sum"))
+            .select((lit(100.0) * col("promo_sum") / col("total_sum")).alias("promo_revenue")))
+
+
+def _q19_shaped(t):
+    joined = t["lineitem"].where(
+        col("l_shipmode").is_in(["AIR", "REG AIR"]) & (col("l_shipinstruct") == "DELIVER IN PERSON")
+    ).join(t["part"], left_on="l_partkey", right_on="p_partkey")
+    sm = (col("p_brand") == "Brand#12") & col("p_container").is_in(
+        ["SM CASE", "SM BOX", "SM PACK", "SM PKG"]
+    ) & (col("l_quantity") >= 1) & (col("l_quantity") <= 11) & (col("p_size") <= 5)
+    med = (col("p_brand") == "Brand#23") & col("p_container").is_in(
+        ["MED BAG", "MED BOX", "MED PKG", "MED PACK"]
+    ) & (col("l_quantity") >= 10) & (col("l_quantity") <= 20) & (col("p_size") <= 10)
+    lg = (col("p_brand") == "Brand#34") & col("p_container").is_in(
+        ["LG CASE", "LG BOX", "LG PACK", "LG PKG"]
+    ) & (col("l_quantity") >= 20) & (col("l_quantity") <= 30) & (col("p_size") <= 15)
+    return (joined.where((col("p_size") >= 1) & (sm | med | lg))
+            .agg((col("l_extendedprice") * (1 - col("l_discount"))).sum().alias("revenue")))
+
+
+_FILTERED = {"q12": _q12_shaped, "q14": _q14_shaped, "q19": _q19_shaped}
+
+
+@pytest.mark.parametrize("shape", ["q12", "q19"])
+@pytest.mark.parametrize("morsels, shards", [(1, 1), (6, 1), (58, 1), (58, 4)],
+                         ids=["1_bucket", "6_buckets", "58_buckets", "58_over_a_mesh_of_four"])
+def test_a_join_is_priced_at_the_dispatch_the_run_delivers(shape, morsels, shards, monkeypatch):
+    """The rows a dispatch a join's chosen device arm is priced at
+    (`join_priced_dispatch_rows`, and `priced_rows` on the placement record)
+    are the rows of the ranges the driver then hands to feed_batch: the
+    whole fact where it is one bucket, four morsels of six (a dispatch is
+    never the whole fact), eight of fifty-eight, and eight a shard over a
+    mesh of four. A grouped join on fact-side codes (q12's shape) and a global
+    aggregate (q19's); the forced tier priced anyway so that the decision
+    runs off the chip."""
+    from daft_tpu.observability import placement
+
+    # (a fact of one bucket beside dimensions shorter than itself)
+    t = _filtered_like(morsels) if morsels > 1 else _filtered_like(1, tail=0, part_rows=1000)
+    q = lambda: _FILTERED[shape](t)
+    host = _host_answer(q)
+    fed = []
+    _spy_fed_batches(monkeypatch, fed)
+    monkeypatch.setenv("DAFT_TPU_PLACEMENT_PRICE_FORCED", "1")
+    priced0 = counters.join_priced_dispatch_rows
+    with placement.query_scope() as scope, execution_config_ctx(
+            device_mode="on", morsel_size_rows=_MORSEL, pipeline_mode="force",
+            mesh_devices=shards if shards > 1 else 1):
+        got = q().to_pydict()
+    _assert_close(host, got)
+    priced = counters.join_priced_dispatch_rows - priced0
+    (rec,) = [r for r in scope.records() if r.priced_rows]
+    assert rec.priced_rows == priced and rec.to_dict()["priced_rows"] == priced
+    assert rec.chosen == ("mesh" if shards > 1 else "device")
+    delivered = [b.num_rows for b in fed]
+    want = {(1, 1): [_MORSEL], (6, 1): [4 * _MORSEL, 2 * _MORSEL - 100],
+            (58, 1): [8 * _MORSEL] * 7 + [2 * _MORSEL - 100],
+            (58, 4): [32 * _MORSEL, 26 * _MORSEL - 100]}[(morsels, shards)]
+    assert delivered == want
+    assert priced == max(delivered) and all(rows == priced for rows in delivered[:-1])
+
+
+def test_a_fact_through_the_pipeline_is_priced_as_it_was(monkeypatch):
+    """A fact that is no select over one table (a computed column under the
+    join) comes through the pipeline and the coalescer, and its tiers are
+    priced by _coalesce_horizon as before: what its leading morsels promise,
+    never the resident range."""
+    t = _filtered_like(19)
+    computed = dict(t, lineitem=t["lineitem"].with_column(
+        "l_quantity", col("l_quantity") + 0.0))
+    q = lambda: _q19_shaped(computed)
+    host = _host_answer(q)
+    monkeypatch.setenv("DAFT_TPU_PLACEMENT_PRICE_FORCED", "1")
+    priced0 = counters.join_priced_dispatch_rows
+    with _morselized("on"):
+        got = q().to_pydict()
+    _assert_close(host, got)
+    assert 0 < counters.join_priced_dispatch_rows - priced0 <= 2 * _MORSEL
+
+
+@pytest.mark.parametrize("shape, unwindowed", [("q12", False), ("q14", True), ("q19", True),
+                                               ("q3", False), ("q5", False)])
+def test_unwindowed_gathers_count_a_fact_its_dimension_is_not_ordered_by(shape, unwindowed):
+    """`join_unwindowed_gathers` is the complement of `join_window_gathers`:
+    l_partkey is uniform over a `part` longer than a window, so every
+    dispatch of a q14- or q19-shaped join reads the whole pack (one a
+    dispatch); `lineitem` follows `orders`, so q12's, q3's and q5's gathers
+    read a window and count none (q5's `supplier` pack is shorter than a
+    window: neither counter)."""
+    if shape in _FILTERED:
+        t = _filtered_like(19)
+        q = lambda: _FILTERED[shape](t)
+    else:
+        _t, q = _long_query(shape)
+    host = _host_answer(q)
+    before = {k: getattr(counters, k) for k in
+              ("join_unwindowed_gathers", "join_window_gathers", "device_join_batches")}
+    with _morselized("on"):
+        got = q().to_pydict()
+    _assert_close(host, got)
+    grown = {k: getattr(counters, k) - v for k, v in before.items()}
+    assert grown["device_join_batches"] == 3
+    assert grown["join_unwindowed_gathers"] == (3 if unwindowed else 0)
+    assert grown["join_window_gathers"] == (0 if unwindowed else 3)
+
+
+@pytest.mark.parametrize("fast_pack_bytes", [None, 0], ids=["as_the_pack", "as_lines"])
+@pytest.mark.parametrize("shape", ["q12", "q14", "q19"])
+def test_filtered_joins_over_eight_segment_ranges_give_the_host_engines_answers(
+        shape, segments, fast_pack_bytes, monkeypatch):
+    """q12's fact-coded groups beside a windowed `orders` pack, q14's
+    synthetic dimension column and q19's disjunction hoisted over fact and
+    dimension with two fact-side membership planes, over a resident fact of
+    19 morsels: at a bucket a dispatch and at eight segments a dispatch the
+    host engine's answer, and a repeat misses no slot and uploads nothing;
+    `part`'s whole pack gathered as the [P, N] matrix it is (a test's pack
+    fits any fast memory) and, the threshold taken away, laid as lines: the
+    same answer to the bit."""
+    import daft_tpu.ops.device_join as dj
+    from daft_tpu.device.residency import manager
+
+    if fast_pack_bytes is not None:
+        monkeypatch.setattr(dj, "_FAST_PACK_BYTES", fast_pack_bytes)
+    laid = []
+    real_lines_of = dj._JoinContext._lines_of
+    monkeypatch.setattr(dj._JoinContext, "_lines_of", lambda self, adj, mat: (
+        laid.append(adj.name), real_lines_of(self, adj, mat))[1])
+    manager().clear()
+    t = _filtered_like(19)
+    q = lambda: _FILTERED[shape](t)
+    host = _host_answer(q)
+    first, _deltas, dispatches = _device_run(q)
+    _assert_close(host, first)
+    assert dispatches == _dispatches(19, segments)
+    again, warm, _ = _device_run(q)
+    assert again == first
+    assert warm["hbm_cache_misses"] == 0 and warm["hbm_h2d_bytes"] == 0, warm
+    # the lines are laid once a query, for the unordered dimension alone
+    assert len(laid) == (2 * dispatches if fast_pack_bytes == 0 and shape != "q12" else 0)
+    if fast_pack_bytes == 0:
+        monkeypatch.setattr(dj, "_FAST_PACK_BYTES", 1 << 40)
+        plain, _w, _d = _device_run(q)
+        assert plain == first, "the lines hand on the plain gather's bits"
+    manager().clear()
+
+
+def test_a_membership_look_up_has_a_span_that_says_whether_it_hit():
+    """`join.membership` spans `_fact_membership_plane`'s look-up a plane a
+    dispatch: `hit` false where the plane was built, true on a repeat."""
+    from daft_tpu.device.residency import manager
+    from daft_tpu.observability.runtime_stats import SpanRecorder, set_spans
+
+    manager().clear()
+    t = _filtered_like(19)
+    seen = []
+    for _ in (1, 2):
+        rec = SpanRecorder()
+        set_spans(rec)
+        try:
+            with _morselized("on"):
+                _q19_shaped(t).to_pydict()
+        finally:
+            set_spans(None)
+        spans = [s for s in rec.drain() if s["name"] == "join.membership"]
+        assert len(spans) == 2 * 3      # two planes a dispatch, three dispatches
+        assert all(s["args"]["rows"] > 0 for s in spans)
+        seen.append({s["args"]["hit"] for s in spans})
+    assert seen == [{False}, {True}]
+    manager().clear()
