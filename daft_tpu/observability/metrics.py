@@ -170,6 +170,7 @@ DEVICE_COUNTER_NAMES = (
     "device_topn_table_bytes",   # bytes of the run-wide group tables, summed over the runs that built them
     "join_topn_compact_batches",  # of those batches, the ones whose kept rows were compacted on the device before the scatters (a count a chip over a mesh)
     "join_topn_ordered_batches",  # of the segments that took the dense form, the ones whose kept ids never decrease, so that their first rows rode the product (a count a chip over a mesh)
+    "join_topn_folds",  # the dispatches of those runs that held a sparse (compacted or scattered) segment and so folded their float32 partial into the run's sums, one table-long two-sum a plane (a count a chip over a mesh; 0 where every segment was dense)
     # a join dispatch whose fact rows were sharded over more than one local
     # device, each running the single chip's programs on its shard
     # (ops/device_join.py, `mesh_devices` > 1): counted as a

@@ -196,12 +196,15 @@ class Calibration:
     # against 0.30). A segment of 131,072 rows read 0.302 ms on a v5e, 7.1e7
     # cells (PR 47's chip run; 1.22 ms before, at 9e11 of the old cells a
     # second). Scatter:
-    # beside its scatters (scatter_rows_per_s: q10 read 0.88 ms a scatter of
-    # 131,072 rows, 1.5e8 rows/s) it streams the whole of each table once a
-    # dispatch (a float32 table zeroed, scattered into, and added to the
-    # run's two float32 planes: 24 bytes an id, reckoned from the chip's
-    # 819 GB/s at two thirds), which mm_plane_rows_per_s, a reduce's rate,
-    # would price 4 times too high.
+    # a segment pays its scatters (scatter_rows_per_s: q10 read 0.88 ms a
+    # scatter of 131,072 rows, 1.5e8 rows/s), into one float32 partial a
+    # plane a DISPATCH, which a dispatch that wrote it folds into the run's
+    # two float32 planes and zeroes, once: a stream over the whole of each
+    # table (24 bytes an id, reckoned from the chip's 819 GB/s at two
+    # thirds, which mm_plane_rows_per_s, a reduce's rate, would price 4
+    # times too high) a dispatch. The price keeps the stream a PARTITION,
+    # the ceiling of a dispatch of one segment: the margins that place these
+    # joins are five-fold and more.
     run_wide_cell_rate: float = 2.4e11
     run_wide_pass_ids_per_s: float = 2e10
 
@@ -650,7 +653,8 @@ def device_join_topn_run_cost(cal: Calibration, rows: int, upload_bytes: int,
     (`dense`: each `chunk` rows' product with the two digits of their id
     window, grouped_stage.dense_row_cells a row, the first rows riding it;
     else a scatter a plane and one for the first rows, and a stream over the
-    tables), this
+    tables, which the program pays once a dispatch of up to eight such
+    partitions and the price keeps whole, as a ceiling), this
     partition's share of the run's one select (`select_share`: its rows over
     the fact's; the select sorts blocks, so `cap` ids cost cap x log2(block)
     a key) and of the K-row fetch. No host factorization: `index_rows` is the

@@ -2301,7 +2301,9 @@ class DeviceJoinGroupedRun(GroupedAggRun):
 # planes are 268 MB, and q3's three sums, its first-row table and the
 # select's operands come to about 1.7 GB). One chip selects over the whole
 # table; over a mesh of N every chip adds its shard's rows into a table of
-# its own of all the ids (28 bytes an id for q3: 1.9 GB at 2^26), the chips
+# its own of all the ids (40 bytes an id for q3 while it accumulates, 2.7 GB
+# at 2^26: the sums' pairs, the first rows and the dispatches' float32
+# partial a plane, which is dropped before the select; 28 bytes then), the chips
 # exchange slices at the end and each selects over ids / N, so the ceiling
 # is held to that share. Neither ceiling bounds a fetch: both forms bring
 # back K rows (a chip).
@@ -2771,7 +2773,9 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
             # the select programs take the leaves they were compiled for (25-28
             # s each on a machine's first process: their text stays as it was);
             # what the accumulate program counted beside them rides the same fetch
-            counted = {n: tables.pop(n) for n in ("compact", "ordered")}
+            counted = {n: tables.pop(n) for n in ("compact", "ordered", "folds")}
+            # (the dispatches' partial is all zeros and the select's memory now)
+            del tables["part"]
             if ndev == 1:
                 fetch = self._select_program(k_eff)(tables, ranks)
             else:
@@ -2800,15 +2804,17 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
                 gids, mm_rows, present_rows = (
                     np.asarray(x)[order] for x in (gids, mm_rows, present_rows))
             # (a count a chip)
-            compact_batches, ordered_batches = (
-                int(np.sum(counted[n])) // ndev for n in ("compact", "ordered"))
+            compact_batches, ordered_batches, folds = (
+                int(np.sum(counted[n])) // ndev for n in ("compact", "ordered", "folds"))
             if sp is not None:
                 sp.args["dense_batches"] = int(np.sum(dense)) // ndev
                 sp.args["compact_batches"] = compact_batches
                 sp.args["ordered_batches"] = ordered_batches
+                sp.args["folds"] = folds
         del tables
         counters.bump("join_topn_compact_batches", compact_batches)
         counters.bump("join_topn_ordered_batches", ordered_batches)
+        counters.bump("join_topn_folds", folds)
         counters.bump("device_stage_runs")
         counters.bump("device_topn_runs")
         counters.bump("device_topn_fetched_rows", fetched_rows)

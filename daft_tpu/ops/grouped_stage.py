@@ -1065,18 +1065,24 @@ class GroupedAggStage:
             zeros = tuple(jnp.zeros(ndev * length, jnp.float32) for _ in range(n_mm))
             return {"hi": zeros, "lo": tuple(jnp.zeros_like(z) for z in zeros),
                     "first": jnp.full(ndev * length, _NO_ROW, jnp.int32),
+                    # a dispatch's sparse segments' float32 partial: all zeros
+                    # between dispatches (_build_run_wide folds it and zeroes it)
+                    "part": tuple(jnp.zeros_like(z) for z in zeros),
                     **{count: jnp.zeros((ndev,) if ndev > 1 else (), jnp.int32)
                        for count in _RUN_WIDE_COUNTS}}
 
-        if mesh_devices <= 1:
-            return empty()
+        # ONE launch makes every leaf (a leaf made by itself is a launch of
+        # its own on the dispatching thread, 0.8 ms on a v5e's host)
         key = ("run_wide_tables", cap, mesh_devices)
         if key not in self._jitted:
-            from jax.sharding import NamedSharding, PartitionSpec
+            if mesh_devices <= 1:
+                self._jitted[key] = jax.jit(empty)
+            else:
+                from jax.sharding import NamedSharding, PartitionSpec
 
-            self._jitted[key] = jax.jit(
-                lambda: empty(mesh_devices), out_shardings=NamedSharding(
-                    local_mesh(mesh_devices), PartitionSpec(MESH_AXIS)))
+                self._jitted[key] = jax.jit(
+                    lambda: empty(mesh_devices), out_shardings=NamedSharding(
+                        local_mesh(mesh_devices), PartitionSpec(MESH_AXIS)))
         return self._jitted[key]()
 
     def _jit_run_wide(self, cap: int, mesh_devices: int = 1,
@@ -1100,8 +1106,9 @@ class GroupedAggStage:
         plane of _mm_specs a sum as TWO float32 planes, `hi` + `lo` (a
         double-single: about 48 bits, each addition an error-free two-sum),
         the int32 position in the run's stream of each id's first kept row
-        (the order the host engine's stable sort leaves ties in), and how
-        many dispatches took the dense and the compact form. Not float64
+        (the order the host engine's stable sort leaves ties in), one float32
+        partial a plane for a dispatch's sparse segments (below; all zeros
+        between dispatches), and the counts of _RUN_WIDE_COUNTS. Not float64
         planes: the chip keeps a float64 array as two float32 ones INSIDE a
         program only, and converts the whole of it at the program's entry and
         exit (on a v5e 4-5 ms a dispatch for three planes of 2^24 ids,
@@ -1124,15 +1131,24 @@ class GroupedAggStage:
         whole one-hot for its first rows. Both verdicts are the segment's
         own, from its ids, on the device; the tables count the segments of
         each.
-        Any other batch scatter-adds float32 rows into a float32 table of its
-        own, added to the run's whole. A scatter on the chip costs by its
-        index count, dropped indices included (0.89 ms for a bucket's 131,072,
-        0.07 for 8,192: PERF.md, PR 42), so a batch that keeps at most a
-        bucket's 1 / COMPACT_SHARE of its rows (q10: 1.3%) first compacts
-        them in stream order (_compact_kept) and scatters those; one that
-        keeps more scatters the whole bucket. Either way a batch's partial is
-        float32 and the run's sum wider, as the merge on the host was; the
-        tables count the dense, the ordered and the compacted segments.
+        Any other segment scatter-adds its float32 rows into the DISPATCH's
+        partial, a float32 table a plane that the segment loop carries beside
+        the sums, and touches neither `hi` nor `lo`. A scatter on the chip
+        costs by its index count, dropped indices included (0.89 ms for a
+        bucket's 131,072, 0.07 for 8,192: PERF.md, PR 42), so a segment that
+        keeps at most a bucket's 1 / COMPACT_SHARE of its rows (q10: 1.3%)
+        first compacts them in stream order (_compact_kept) and scatters
+        those; one that keeps more scatters the whole bucket. After the last
+        segment a dispatch that wrote the partial folds it into the sums,
+        ONE table-long two-sum a plane a dispatch (24 bytes an id, for up to
+        eight segments that change at most K of millions of ids each), and
+        writes it back as zeros, which is what the next dispatch finds. A dispatch whose segments were all
+        dense (every one of q3's) wrote nothing and folds nothing: it pays
+        no stream over the tables at all. So a dispatch's partial is float32
+        (the rows of an id that a dispatch's sparse segments hold meet in
+        float32 before they are widened) and the run's sum wider, as the
+        merge on the host was; the tables count the dense, the ordered and
+        the compacted segments and the dispatches that folded.
 
         A dispatch is one or more SEGMENTS of `segment` rows (0: the whole
         bucket is one): a join over a resident fact sends DISPATCH_SEGMENTS
@@ -1140,9 +1156,9 @@ class GroupedAggStage:
         once for all of them, and the program walks them a segment at a time
         (a loop that carries the donated tables and stops after the last
         segment that holds a row, so a tail's padding costs nothing). The
-        segment is the unit of everything above: its form is chosen from its
-        own rows, its partial is its own, and the compaction, whose compares
-        grow with the square of what it compacts, and every temporary stay a
+        segment is the unit of everything above but the fold: its form is
+        chosen from its own rows, and the compaction, whose compares grow
+        with the square of what it compacts, and every temporary stay a
         morsel's size whatever the dispatch's.
 
         Over `mesh` every device runs this program on its shard of the
@@ -1160,8 +1176,10 @@ class GroupedAggStage:
 
         def one_segment(acc, cols: Dict[str, dev.DCol], gid: jnp.ndarray,
                         row_mask: jnp.ndarray, offset, lits):
-            """`acc` (hi, lo, first and _RUN_WIDE_COUNTS) with one segment's rows
+            """`acc` ((hi, lo, first, part), whether the dispatch wrote `part`,
+            the _SEGMENT_COUNTS) with one segment's rows
             added, the segment's first row at `offset` of the run's stream."""
+            sums, wrote, counts = acc
             bucket = gid.shape[0]
             chunk = min(CHUNK_LOCAL, bucket, cap)
             n_chunks = bucket // chunk
@@ -1176,6 +1194,15 @@ class GroupedAggStage:
             dense = jnp.all(top - lo < chunk)
             lo = jnp.minimum(lo, cap - 1)
             vals = jnp.stack(mm, axis=-1)                    # [bucket, P] f32
+
+            def untouched(table):
+                # a table the dense form has no use for, handed on through ONE
+                # element written as it is: a branch that returns its parameter
+                # itself is given a copy of the whole table by the chip's
+                # compiler, a dense segment (three planes of 2^24 ids a segment
+                # of q3's; tests/test_chip_compile.py reads the compiled text
+                # for it)
+                return table.at[0].add(0.0)
 
             def dense_form(acc):
                 local = jnp.where(g < cap, g - lo[:, None], chunk)
@@ -1223,16 +1250,16 @@ class GroupedAggStage:
                 def add_chunk(carry, xs):
                     c_hi, c_lo, c_first = carry
                     s, v, p, other, at = xs
-                    part = _digit_product(s, v)
+                    prod = _digit_product(s, v)
                     new_hi, new_lo = [], []
                     for k in range(n_mm):
                         h, l = _two_sum_add(
                             jax.lax.dynamic_slice(c_hi[k], (at,), (chunk,)),
                             jax.lax.dynamic_slice(c_lo[k], (at,), (chunk,)),
-                            part[k] + part[n_mm + k] + part[2 * n_mm + k])
+                            prod[k] + prod[n_mm + k] + prod[2 * n_mm + k])
                         new_hi.append(jax.lax.dynamic_update_slice(c_hi[k], h, (at,)))
                         new_lo.append(jax.lax.dynamic_update_slice(c_lo[k], l, (at,)))
-                    here, high, low = part[3 * n_mm:]
+                    here, high, low = prod[3 * n_mm:]
                     first = jnp.where(
                         here > 0, p + (high * 64 + low).astype(jnp.int32), other)
                     cur = jax.lax.dynamic_slice(c_first, (at,), (chunk,))
@@ -1243,33 +1270,31 @@ class GroupedAggStage:
                 # (two chunks a step: 0.357 -> 0.302 ms a segment on a v5e, four
                 # read the same; PERF.md, PR 47)
                 return jax.lax.scan(
-                    add_chunk, acc,
+                    add_chunk, acc[:3],
                     (local, jnp.concatenate([terms, marks], axis=-1), rows_at[:, 0],
                      unordered_firsts, lo),
-                    unroll=min(2, n_chunks))[0] + (ordered,)
+                    unroll=min(2, n_chunks))[0] + (tuple(untouched(p) for p in acc[3]), ordered)
 
+            # the sparse forms add into the dispatch's partial and touch
+            # neither hi nor lo: the table-long two-sum is the dispatch's, once
             def scatter_form(acc):
-                acc_hi, acc_lo, acc_first = acc
+                acc_hi, acc_lo, acc_first, part = acc
                 at = jnp.where(kept, seg, acc_first.shape[0])   # out of range: dropped
-                new_hi, new_lo = zip(*(
-                    _two_sum_add(h, l, jnp.zeros(h.shape, jnp.float32)
-                                 .at[at].add(vals[:, k], mode="drop"))
-                    for k, (h, l) in enumerate(zip(acc_hi, acc_lo))))
-                return new_hi, new_lo, acc_first.at[at].min(pos, mode="drop")
+                return (acc_hi, acc_lo, acc_first.at[at].min(pos, mode="drop"),
+                        tuple(p.at[at].add(vals[:, k], mode="drop")
+                              for k, p in enumerate(part)))
 
             # (a bucket is a power of two from 512 up: whole lines of lanes)
             n_compact = bucket // COMPACT_SHARE
 
             def compact_form(acc):
-                acc_hi, acc_lo, acc_first = acc
+                acc_hi, acc_lo, acc_first, part = acc
                 src, ids, rows = _compact_kept(
                     seg, [vals[:, k] for k in range(n_mm)], cap, n_compact)
                 at = jnp.where(src < bucket, ids, acc_first.shape[0])   # an empty slot: dropped
-                new_hi, new_lo = zip(*(
-                    _two_sum_add(h, l, jnp.zeros(h.shape, jnp.float32)
-                                 .at[at].add(rows[k], mode="drop"))
-                    for k, (h, l) in enumerate(zip(acc_hi, acc_lo))))
-                return new_hi, new_lo, acc_first.at[at].min(offset + src, mode="drop")
+                return (acc_hi, acc_lo, acc_first.at[at].min(offset + src, mode="drop"),
+                        tuple(p.at[at].add(rows[k], mode="drop")
+                              for k, p in enumerate(part)))
 
             def sparse_forms(acc):
                 # (nothing of the compaction is computed for a dense segment,
@@ -1278,13 +1303,13 @@ class GroupedAggStage:
                 return jax.lax.cond(few, compact_form, scatter_form, acc) \
                     + (jnp.bool_(False), few)
 
-            acc_hi, acc_lo, acc_first, ordered, compacted = jax.lax.cond(
+            *sums, ordered, compacted = jax.lax.cond(
                 dense, lambda acc: dense_form(acc) + (jnp.bool_(False),), sparse_forms,
-                acc[:3])
-            # (_RUN_WIDE_COUNTS' order)
-            return (acc_hi, acc_lo, acc_first) + tuple(
+                sums)
+            # (_SEGMENT_COUNTS' order)
+            return tuple(sums), wrote | ~dense, tuple(
                 n + took.astype(jnp.int32)
-                for n, took in zip(acc[3:], (dense, compacted, ordered)))
+                for n, took in zip(counts, (dense, compacted, ordered)))
 
         def stage(tables, cols: Dict[str, dev.DCol], gid: jnp.ndarray,
                   row_mask: jnp.ndarray, lit_args, rows_before=0):
@@ -1293,7 +1318,8 @@ class GroupedAggStage:
             offset = slots.run_value(lit_args, 0).astype(jnp.int32) + rows_before
             bucket = gid.shape[0]
             rows = min(segment or bucket, bucket)
-            acc = tuple(tables[leaf] for leaf in ("hi", "lo", "first") + _RUN_WIDE_COUNTS)
+            acc = (tuple(tables[leaf] for leaf in ("hi", "lo", "first", "part")),
+                   jnp.bool_(False), tuple(tables[n] for n in _SEGMENT_COUNTS))
             if rows == bucket:
                 acc = one_segment(acc, cols, gid, row_mask, offset, lits)
             else:
@@ -1311,7 +1337,21 @@ class GroupedAggStage:
                     return one_segment(acc, c, g, m, offset + i * rows, lits)
 
                 acc = jax.lax.fori_loop(0, live, walk, acc)
-            return dict(zip(("hi", "lo", "first") + _RUN_WIDE_COUNTS, acc))
+            (hi, lo, first, part), wrote, counts = acc
+
+            def fold(sums):
+                # ONE table-long two-sum a plane a dispatch, and the partial
+                # left all zeros for the next
+                hi, lo, part = sums
+                new_hi, new_lo = zip(*(_two_sum_add(h, l, p) for h, l, p in zip(hi, lo, part)))
+                return new_hi, new_lo, tuple(jnp.zeros_like(p) for p in part)
+
+            # a dispatch whose segments were all dense (every one of q3's)
+            # wrote no partial and folds none: no stream over the tables
+            hi, lo, part = jax.lax.cond(wrote, fold, lambda sums: sums, (hi, lo, part))
+            return dict(zip(("hi", "lo", "first", "part") + _RUN_WIDE_COUNTS,
+                            (hi, lo, first, part) + counts
+                            + (tables["folds"] + wrote.astype(jnp.int32),)))
 
         if mesh is None:
             return jax.jit(stage, donate_argnums=0)
@@ -1343,9 +1383,11 @@ def _two_sum_add(hi, lo, x):
 
 # what a run-wide dispatch's tables count beside their sums: the segments
 # that took the dense form, those whose kept rows were compacted before their
-# scatters, and the dense ones whose first rows rode the product (the select
-# programs read "dense" alone; device_join pops the others before the select)
-_RUN_WIDE_COUNTS = ("dense", "compact", "ordered")
+# scatters, the dense ones whose first rows rode the product, and the
+# dispatches that folded a partial (the select programs read "dense" alone;
+# device_join pops the others, and the partial, before the select)
+_SEGMENT_COUNTS = ("dense", "compact", "ordered")
+_RUN_WIDE_COUNTS = _SEGMENT_COUNTS + ("folds",)
 
 
 def _digit_product(local: jnp.ndarray, terms: jnp.ndarray) -> jnp.ndarray:
@@ -1389,11 +1431,14 @@ def _max_before(x: jnp.ndarray) -> jnp.ndarray:
     return out
 
 
-# a run-wide dispatch that keeps at most a bucket's 1 / COMPACT_SHARE of its
+# a run-wide segment that keeps at most a bucket's 1 / COMPACT_SHARE of its
 # rows scatters those alone (_build_run_wide's compact form). Every compacted
-# dispatch pays for the K = bucket / COMPACT_SHARE slots whatever it kept
-# (whole forms on a v5e, 131,072 rows into 2^21 ids: K = 4,096 0.26 ms, 8,192
-# 0.40, 16,384 0.81, 32,768 1.48, the scatter form 3.63; PERF.md, PR 42)
+# segment pays for the K = bucket / COMPACT_SHARE slots whatever it kept: the
+# compaction and a scatter of K indices a plane and one for the first rows.
+# The stream over the tables is the DISPATCH's, once for its eight segments.
+# On a v5e a compacted segment of 131,072 rows reads 0.41 ms into 2^21 ids
+# and 0.79 into 2^23, its four scatters 0.24 and 0.47 of it; K = 4,096 reads
+# 0.25 and 1.30, and 16,384 and up grow with K (PERF.md, PR 42 and PR 50)
 COMPACT_SHARE = 16
 
 # Morsel-long buckets a device that ONE join dispatch over a resident fact

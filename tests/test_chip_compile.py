@@ -351,6 +351,25 @@ def _assert_dense_form_in_two_digits(text, n_planes):
     assert products and set(products) == {f"{(3 * n_planes + 3) * high},{low}"}, products
 
 
+def _assert_folds_once_a_dispatch(text, length, n_planes):
+    """The table-long two-sums of a dispatch of eight segments, as the chip's
+    compiler left them (a two-sum is where `is-finite` is taken): none over
+    the tables' length inside the segment loop's body, whose sparse forms
+    scatter into the dispatch's partial and leave the sums alone, and one a
+    plane after the loop, in the branch that only a dispatch which wrote the
+    partial takes; and no table copied whole anywhere (a branch that handed
+    the partial on untouched was given a copy of it, a segment: the dense
+    form writes one element of it)."""
+    long = [line for line in text.splitlines()
+            if " is-finite(" in line and f"[{length}]" in line]
+    assert len(long) == n_planes, long
+    assert not [line for line in long if "/while/body/" in line]
+    # (over a mesh the branch stands inside the shard's scope)
+    after = re.compile(r'op_name="jit\((?:stage|on_shard)\)/(?:shard_map/)?cond/branch_1_fun/is_finite"')
+    assert all(after.search(line) for line in long), long
+    assert not re.findall(rf"= [fs]32\[{length}\]\S* copy\(", text)
+
+
 # a join dispatch over a resident fact: DISPATCH_SEGMENTS morsels' rows (PR 43)
 SEGMENTS = [1, 8]
 SEGMENT_IDS = ["one_bucket", "eight_segments"]
@@ -363,8 +382,10 @@ def test_run_wide_topn_accumulate_lowers_at_sf10(one_chip, cap, segments):
     eight such segments walked one after the other, into tables of 2^21
     customer ids (q10's) and 2^24 order ids (q3's), the three forms in one
     program, the tables donated (no second copy of them among the
-    temporaries, loop or no loop), and a segment's scatters and compaction
-    the length they had when it was a dispatch of its own."""
+    temporaries, loop or no loop: the sparse forms scatter into the
+    dispatch's partial, a leaf of the tables, so not even one plane's),
+    a segment's scatters and compaction the length they had when it was a
+    dispatch of its own, and the table-long two-sums once a dispatch."""
     stage, _topn = _q3_join_stage()
     tables = _run_wide_tables(stage, one_chip, cap)
     ints = {"l_shipdate"}
@@ -378,16 +399,22 @@ def test_run_wide_topn_accumulate_lowers_at_sf10(one_chip, cap, segments):
     mem = compiled.memory_analysis()
     table_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                       for x in jax.tree_util.tree_leaves(tables))
-    # (the three counts of segments, dense, compacted and ordered, are the + 12;
-    # without the last two the leaves are the select program's: PR 42's, unchanged)
-    assert sorted(tables) == ["compact", "dense", "first", "hi", "lo", "ordered"]
-    assert table_bytes == (len(stage._mm_specs) * 2 * 4 + 4) * (cap + 4096) + 12
+    # (hi, lo and the dispatch's partial a plane, the first rows; the counts
+    # of dense, compacted and ordered segments and of folds are the + 16;
+    # without the partial and the last three the leaves are the select
+    # program's: PR 42's, unchanged)
+    assert sorted(tables) == ["compact", "dense", "first", "folds", "hi", "lo", "ordered", "part"]
+    assert table_bytes == (len(stage._mm_specs) * 3 * 4 + 4) * (cap + 4096) + 16
     assert mem.argument_size_in_bytes >= table_bytes
-    # the scatter forms' float32 table of one plane at a time, never a copy of the run's tables
-    assert mem.temp_size_in_bytes < table_bytes
+    # never a copy of the run's tables, and no table of a scatter form's own:
+    # the temporaries are a segment's, under ONE plane of the tables
+    assert mem.temp_size_in_bytes < 4 * (cap + 4096)
     assert mem.alias_size_in_bytes >= table_bytes - 16
-    _assert_compacts_without_sort_or_scan(compiled.as_text(), len(stage._mm_specs))
-    _assert_dense_form_in_two_digits(compiled.as_text(), len(stage._mm_specs))
+    text = compiled.as_text()
+    _assert_compacts_without_sort_or_scan(text, len(stage._mm_specs))
+    _assert_dense_form_in_two_digits(text, len(stage._mm_specs))
+    if segments > 1:
+        _assert_folds_once_a_dispatch(text, cap + 4096, len(stage._mm_specs))
 
 
 def test_run_wide_topn_select_lowers_at_sf10(one_chip):
@@ -429,9 +456,9 @@ def test_the_select_program_takes_the_four_leaves_it_took(one_chip):
     run.stage, run._cap, run.topn, run.mesh_devices, run.ctx = \
         stage, ORDERS_CAP, topn, 1, _Ctx()
     tables = _run_wide_tables(stage, one_chip, ORDERS_CAP)
-    assert set(tables) - {"hi", "lo", "first"} == set(_RUN_WIDE_COUNTS)
-    for count in ("compact", "ordered"):
-        tables.pop(count)
+    assert set(tables) - {"hi", "lo", "first", "part"} == set(_RUN_WIDE_COUNTS)
+    for leaf in ("compact", "ordered", "folds", "part"):
+        tables.pop(leaf)
     ranks = tuple(_s(one_chip, (ORDERS_CAP,), jnp.int32)
                   for kind, *_rest in topn.keys if kind == "group")
     lowered = run._select_program(10).lower(tables, ranks)
@@ -693,10 +720,12 @@ def _sharded_run_wide_tables(stage, rows, cap):
 
 def _sharded_accumulate_tables(stage, rows, cap):
     """What the accumulate program takes and returns: the select's four
-    leaves and the compacted and the ordered segments' counts beside them."""
-    return dict(_sharded_run_wide_tables(stage, rows, cap),
-                compact=_s(rows, (MESH_CHIPS,), jnp.int32),
-                ordered=_s(rows, (MESH_CHIPS,), jnp.int32))
+    leaves, the dispatches' partial a plane, and the compacted and the
+    ordered segments' and the folds' counts beside them."""
+    tables = _sharded_run_wide_tables(stage, rows, cap)
+    return dict(tables, part=tables["hi"],
+                **{count: _s(rows, (MESH_CHIPS,), jnp.int32)
+                   for count in ("compact", "ordered", "folds")})
 
 
 @pytest.mark.parametrize("segments", SEGMENTS, ids=SEGMENT_IDS)
@@ -704,7 +733,7 @@ def test_sharded_run_wide_accumulate_lowers_at_sf30(topo, segments):
     """One dispatch of q3's run-wide program over the mesh: 131,072 rows a
     chip, or eight such segments a chip, into a chip's own tables of 2^26
     order ids, donated, and no collective: a dispatch leaves the chips'
-    tables apart."""
+    tables apart, and a chip folds its own partial into its own sums, once."""
     stage, _topn = _q3_join_stage()
     mesh, rows, whole = _mesh_shardings(topo)
     total = MESH_CHIPS * segments * JOIN_BATCH
@@ -717,16 +746,18 @@ def test_sharded_run_wide_accumulate_lowers_at_sf30(topo, segments):
         tables, cols, _s(rows, (total,), jnp.int32), _s(rows, (total,), jnp.bool_),
         _literal_args(stage, whole)).compile()
     mem = compiled.memory_analysis()
-    a_chips = (len(stage._mm_specs) * 2 * 4 + 4) * (ORDERS_CAP_SF30 + 4096)
+    a_chips = (len(stage._mm_specs) * 3 * 4 + 4) * (ORDERS_CAP_SF30 + 4096)
     assert a_chips <= mem.argument_size_in_bytes < a_chips + (64 << 20)
     assert mem.alias_size_in_bytes >= a_chips - 8       # donated: no second copy
-    assert mem.temp_size_in_bytes < a_chips
+    assert mem.temp_size_in_bytes < 4 * (ORDERS_CAP_SF30 + 4096)    # (under one plane)
     text = compiled.as_text()
     assert not [c for c in COLLECTIVES if c in text]
     # a shard's program is the one chip's: its own 131,072 rows, its own K,
     # its own chunks' windows in two digits
     _assert_compacts_without_sort_or_scan(text, len(stage._mm_specs))
     _assert_dense_form_in_two_digits(text, len(stage._mm_specs))
+    if segments > 1:
+        _assert_folds_once_a_dispatch(text, ORDERS_CAP_SF30 + 4096, len(stage._mm_specs))
 
 
 def test_sharded_run_wide_combine_and_select_lowers_at_sf30(topo):

@@ -1050,8 +1050,10 @@ def _compaction_fact(kept_per_batch, one_line=False, seed=23):
     return dict(t, lineitem=daft_tpu.from_pydict(li).collect()), kept_ids
 
 
-def _spy_run_wide_tables(monkeypatch, seen):
-    """Every run-wide finalize leaves (batches, its tables on the host) in `seen`."""
+def _spy_run_wide_tables(monkeypatch, seen, partials=None):
+    """Every run-wide finalize leaves (batches, its tables on the host) in
+    `seen`, and every dispatch whether the sparse segments' partial read all
+    zeros after it in `partials`."""
     import jax
 
     import daft_tpu.ops.device_join as dj
@@ -1063,6 +1065,15 @@ def _spy_run_wide_tables(monkeypatch, seen):
         return real(self)
 
     monkeypatch.setattr(dj.DeviceJoinTopNRun, "_finalize_run_wide", spy)
+    if partials is None:
+        return
+    feed = dj.DeviceJoinTopNRun._feed_run_wide
+
+    def fed(self, batch):
+        feed(self, batch)
+        partials.append(not any(np.any(np.asarray(p)) for p in self._tables["part"]))
+
+    monkeypatch.setattr(dj.DeviceJoinTopNRun, "_feed_run_wide", fed)
 
 
 def _forms_of(kept_ids):
@@ -1074,14 +1085,35 @@ def _forms_of(kept_ids):
     return dense, compact, len(kept_ids) - dense - compact
 
 
-def _assert_tables_agree(got, want, kept_ids):
+def _by_dispatch(kept_ids, segments):
+    """A run's segments' kept ids, dispatch by dispatch (_dispatches)."""
+    from daft_tpu.execution.batching import resident_dispatch_segments
+
+    step = min(segments, resident_dispatch_segments(len(kept_ids)))
+    return [kept_ids[lo:lo + step] for lo in range(0, len(kept_ids), step)]
+
+
+def _holds_sparse(group):
+    """Whether one dispatch's segments hold one that is not dense: the
+    dispatch then folds its partial into the sums, once."""
+    return _forms_of(group)[0] < len(group)
+
+
+def _folds_of(kept_ids, segments):
+    """The dispatches of a run that fold."""
+    return sum(map(_holds_sparse, _by_dispatch(kept_ids, segments)))
+
+
+def _assert_tables_agree(got, want, kept_ids, segments):
     """The compact form's tables against the scatter form's: first-row
     positions exactly, sums bit for bit where an id has at most two kept rows
-    in every batch, within a float32 ulp a batch of the sum otherwise."""
+    in every dispatch (a dispatch's sparse segments meet in one float32
+    partial), within a float32 ulp a dispatch of the sum otherwise."""
     np.testing.assert_array_equal(got["first"], want["first"])
     most = {}
-    for ids in kept_ids:
-        for i, n in zip(*np.unique(ids, return_counts=True)):
+    groups = _by_dispatch(kept_ids, segments)
+    for group in groups:
+        for i, n in zip(*np.unique(np.concatenate(group), return_counts=True)):
             most[int(i)] = max(most.get(int(i), 0), int(n))
     many = np.array([i for i, n in most.items() if n > 2], dtype=np.int64)
     for plane in ("hi", "lo"):
@@ -1092,7 +1124,7 @@ def _assert_tables_agree(got, want, kept_ids):
             np.testing.assert_array_equal(g[few].view(np.int32), w[few].view(np.int32))
     for gh, gl, wh, wl in zip(got["hi"], got["lo"], want["hi"], want["lo"]):
         total = lambda h, l: np.asarray(h, np.float64)[many] + np.asarray(l, np.float64)[many]
-        room = len(kept_ids) * np.spacing(np.abs(np.asarray(wh)[many]).astype(np.float32))
+        room = len(groups) * np.spacing(np.abs(np.asarray(wh)[many]).astype(np.float32))
         assert (np.abs(total(gh, gl) - total(wh, wl)) <= room).all()
 
 
@@ -1130,24 +1162,33 @@ def test_fused_topn_compact_and_scatter_forms_agree(case, monkeypatch, segments)
     a dispatch of its own or, glued with the other morsels of its table, one
     of a dispatch whose segments each choose their form ("mixed": a dispatch
     of four segments, one dense, two compacted and one scattered, and the
-    table's last morsel, scattered, in a dispatch of its own)."""
+    table's last morsel, scattered, in a dispatch of its own). The sparse
+    segments of a dispatch add into one float32 partial, which the dispatch
+    folds into the sums once and leaves all zeros: `join_topn_folds` counts
+    the dispatches that held one ("none_kept": every segment dense, 0)."""
     import daft_tpu.ops.grouped_stage as gs
 
     kept, one_line = _COMPACT_CASES[case]
     t, kept_ids = _compaction_fact(kept, one_line)
     dense, compact, scatter = _forms_of(kept_ids)
     assert dense + compact + scatter == len(kept)
+    folds = _folds_of(kept_ids, segments)
+    assert folds == {"none_kept": 0, "mixed": 2 if segments > 1 else 4}.get(
+        case, _dispatches(len(kept), segments))
     host = _host_answer(lambda: _topn_q3(t))
-    seen = []
-    _spy_run_wide_tables(monkeypatch, seen)
+    seen, partials = [], []
+    _spy_run_wide_tables(monkeypatch, seen, partials)
 
     counters.reset()
     answer, select = _run_spanned(lambda: _topn_q3(t))
     assert counters.device_topn_runs == 1, counters.rejections
     assert counters.device_join_topn_batches == _dispatches(len(kept), segments)
     assert counters.join_topn_compact_batches == compact
+    assert counters.join_topn_folds == folds
     assert select["args"]["compact_batches"] == compact
     assert select["args"]["dense_batches"] == dense
+    assert select["args"]["folds"] == folds
+    assert partials == [True] * _dispatches(len(kept), segments)
     _assert_close(host, answer)
 
     # the scatter form alone: no dispatch is few enough
@@ -1165,12 +1206,101 @@ def test_fused_topn_compact_and_scatter_forms_agree(case, monkeypatch, segments)
     assert batches == _dispatches(len(kept), segments)
     assert int(got["dense"]) == int(want["dense"]) == dense
     assert int(got["compact"]) == compact
-    _assert_tables_agree(got, want, kept_ids)
+    assert int(got["folds"]) == folds and int(want["folds"]) == _folds_of(kept_ids, segments)
+    _assert_tables_agree(got, want, kept_ids, segments)
+
+
+def test_a_dispatch_folds_its_sparse_segments_into_the_sums_once(monkeypatch):
+    """Eight compacted segments in ONE dispatch whose ids meet again from
+    segment to segment: the rows of all eight add into one float32 partial,
+    which the dispatch folds into the double-single sums once (one fold,
+    eight compacted segments) and leaves all zeros. First rows exactly and
+    sums within a float32 ulp of the sum against a float64 reference over the
+    kept rows, against the scatter form alone, and the host engine's answer."""
+    import daft_tpu.ops.grouped_stage as gs
+    from daft_tpu.execution.batching import resident_dispatch_segments
+
+    kept = [_K, 40, _K - 1, 90, _K, 7, 100, _K] * 2
+    assert resident_dispatch_segments(len(kept)) == gs.DISPATCH_SEGMENTS == 8
+    t, kept_ids = _compaction_fact(kept)
+    assert _forms_of(kept_ids) == (0, len(kept), 0)
+    shared = set(kept_ids[0].tolist())
+    assert all(shared & set(ids.tolist()) for ids in kept_ids[1:8]), "segments share ids"
+    host = _host_answer(lambda: _topn_q3(t))
+    seen, partials = [], []
+    _spy_run_wide_tables(monkeypatch, seen, partials)
+
+    counters.reset()
+    answer, select = _run_spanned(lambda: _topn_q3(t))
+    assert counters.device_topn_runs == 1, counters.rejections
+    assert counters.device_join_topn_batches == 2
+    assert (counters.join_topn_compact_batches, counters.join_topn_folds) == (len(kept), 2)
+    assert (select["args"]["compact_batches"], select["args"]["folds"]) == (len(kept), 2)
+    assert partials == [True, True]
+    _assert_close(host, answer)
+
+    monkeypatch.setattr(gs, "COMPACT_SHARE", 1 << 30)       # the scatter form alone
+    monkeypatch.setattr(gs, "_STAGE_CACHE", {})
+    with _morselized("on"):
+        assert _topn_q3(t).to_pydict() == answer
+    (_b, got), (_b, want) = seen
+    assert (int(want["compact"]), int(want["folds"])) == (0, 2)
+    _assert_tables_agree(got, want, kept_ids, 8)
+
+    # a float64 reference over the kept rows: rows, revenue, the first row an id
+    li, o = t["lineitem"].to_pydict(), t["orders"].to_pydict()
+    late = np.array([d > _days(1994, 2, 1) for d in li["l_shipdate"]])
+    assert late.sum() == sum(kept)
+    row_of = {k: i for i, k in enumerate(o["o_orderkey"])}
+    ids = np.array([row_of.get(k, 0) for k in li["l_orderkey"]])
+    revenue = np.asarray(li["l_extendedprice"]) * (1 - np.asarray(li["l_discount"]))
+    rows, sums, first = _dense_reference(ids, late, revenue, len(got["first"]))
+    np.testing.assert_array_equal(np.asarray(got["first"], np.int64), first)
+    total = lambda k: np.asarray(got["hi"][k], np.float64) + np.asarray(got["lo"][k], np.float64)
+    np.testing.assert_array_equal(total(0), rows)
+    # (a row's revenue is rounded to float32 on the way in, and a dispatch's
+    # rows of an id meet in float32: an ulp of the sum a dispatch and a row)
+    np.testing.assert_allclose(total(2), sums, rtol=2.0 ** -20, atol=0)
+
+
+_JAX_EVENTS = None       # where the one registered listener writes, while a test listens
+
+
+def _jax_events():
+    """A list that every duration event of JAX's (a trace, a lowering, a
+    backend compile) is appended to from now on, by name."""
+    global _JAX_EVENTS
+    import jax.monitoring
+
+    if _JAX_EVENTS is None:
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda name, _secs, **_kw: _JAX_EVENTS.append(name))
+    _JAX_EVENTS = []
+    return _JAX_EVENTS
+
+
+@pytest.mark.parametrize("mesh", [1, 4])
+def test_a_repeat_run_wide_topn_traces_and_compiles_nothing(mesh):
+    """The run's tables are made by one jitted launch (`run_wide_tables`),
+    one chip or four: the stage keeps that program beside the accumulate
+    programs, so a template's second execution, dense, compacted and
+    scattered segments among its dispatches, builds no program at all."""
+    t, _kept_ids = _compaction_fact([40, _K + 1, 0, _K, 600, 3, 9, 0, 5, 7])
+    run = lambda: _topn_q3(t).to_pydict()
+    with execution_config_ctx(device_mode="on", mesh_devices=mesh,
+                              morsel_size_rows=_MORSEL, pipeline_mode="force"):
+        first = run()
+        events = _jax_events()
+        counters.reset()
+        assert run() == first
+    assert counters.device_topn_runs == 1 and counters.join_topn_folds, counters.rejections
+    assert events == []
 
 
 def test_the_select_program_is_handed_the_leaves_it_was_compiled_for(monkeypatch):
     """The accumulate program's tables carry the compacted and the ordered
-    segments' counts beside the select's four leaves; the select program (25-28 s to compile
+    segments' counts, the dispatches that folded and the sparse segments'
+    partial beside the select's four leaves; the select program (25-28 s to compile
     at SF10, served by the persistent cache while its text stands) is handed
     those four and nothing else."""
     import daft_tpu.ops.device_join as dj
@@ -1193,7 +1323,8 @@ def test_the_select_program_is_handed_the_leaves_it_was_compiled_for(monkeypatch
     with _morselized("on"):
         _topn_q10(t).to_pydict()
     assert handed == [["dense", "first", "hi", "lo"]]
-    assert sorted(seen[0][1]) == ["compact", "dense", "first", "hi", "lo", "ordered"]
+    assert sorted(seen[0][1]) == ["compact", "dense", "first", "folds", "hi", "lo",
+                                  "ordered", "part"]
 
 
 # ---- the dense form: a chunk's id window in two digits -----------------------------------
@@ -1328,20 +1459,24 @@ def test_the_dense_forms_tables_against_float64(case, chunk_rows, segments, monk
     assert ordered == {"unordered": 0, "straddle_unordered": 0}.get(case, morsels)
     t, revenue = _dense_fact(keys, kept)
     host = _host_answer(lambda: _topn_q3(t))
-    seen = []
-    _spy_run_wide_tables(monkeypatch, seen)
+    seen, partials = [], []
+    _spy_run_wide_tables(monkeypatch, seen, partials)
     counters.reset()
     answer, select = _run_spanned(lambda: _topn_q3(t))
     assert counters.device_topn_runs == 1, counters.rejections
     assert counters.device_join_topn_batches == _dispatches(morsels, segments)
     assert counters.join_topn_ordered_batches == ordered
     assert counters.join_topn_compact_batches == 0
+    # (a dispatch of dense segments writes no partial and folds none)
+    assert counters.join_topn_folds == 0
     assert (select["args"]["dense_batches"], select["args"]["ordered_batches"],
-            select["args"]["compact_batches"]) == (dense, ordered, 0)
+            select["args"]["compact_batches"], select["args"]["folds"]) == (dense, ordered, 0, 0)
+    assert partials == [True] * _dispatches(morsels, segments)
     _assert_close(host, answer)
 
     (_batches, got), = seen
-    assert (int(got["dense"]), int(got["ordered"]), int(got["compact"])) == (dense, ordered, 0)
+    assert (int(got["dense"]), int(got["ordered"]), int(got["compact"]), int(got["folds"])) \
+        == (dense, ordered, 0, 0)
     rows, sums, first = _dense_reference(keys, kept, revenue, len(got["first"]))
     total = lambda k: np.asarray(got["hi"][k], np.float64) + np.asarray(got["lo"][k], np.float64)
     # the planes: kept rows, counted values, revenue (stage._mm_specs)

@@ -199,21 +199,26 @@ def test_each_shard_compacts_or_scatters_by_its_own_rows(case, kept, monkeypatch
     """Over the mesh a shard whose kept rows pass K takes the scatter form
     while the others compact theirs in the same dispatch: a chip's tables are
     what the scatter form alone leaves on it, the answer is the one chip's,
-    and the counters read a count a chip."""
+    and the counters read a count a chip. A chip folds its own partial into
+    its own tables, in the dispatches in which ITS shard was not dense, and
+    leaves it all zeros."""
     import daft_tpu.ops.grouped_stage as gs
 
     t, kept_ids = tj._compaction_fact(kept)
     dense, compact, _scatter = tj._forms_of(kept_ids)
     host = tj._host_answer(lambda: tj._topn_q3(t))
-    seen = []
-    tj._spy_run_wide_tables(monkeypatch, seen)
+    seen, partials = [], []
+    tj._spy_run_wide_tables(monkeypatch, seen, partials)
     four, c4 = _run(lambda: tj._topn_q3(t), MESH)
     assert c4["device_topn_runs"] == 1 and c4["device_join_mesh_batches"] == len(kept) // MESH, \
         counters.rejections
     assert c4.get("join_topn_compact_batches", 0) == compact // MESH
+    assert c4.get("join_topn_folds", 0) == (len(kept) - dense) // MESH      # (a count a chip)
+    assert partials == [True] * (len(kept) // MESH)
     tj._assert_close(host, four)
     one, c1 = _run(lambda: tj._topn_q3(t), 1)
     assert one == four and c1.get("join_topn_compact_batches", 0) == compact
+    assert c1.get("join_topn_folds", 0) == len(kept) - dense
 
     monkeypatch.setattr(gs, "COMPACT_SHARE", 1 << 30)
     gs._STAGE_CACHE.clear()
@@ -230,11 +235,12 @@ def test_each_shard_compacts_or_scatters_by_its_own_rows(case, kept, monkeypatch
     for s in range(MESH):
         mine = kept_ids[s::MESH]
         assert (int(got["dense"][s]), int(got["compact"][s])) == tj._forms_of(mine)[:2]
+        assert int(got["folds"][s]) == int(want["folds"][s]) == tj._folds_of(mine, 1)
         chip = lambda tables: {
             k: [np.split(np.asarray(x), MESH)[s] for x in v] if isinstance(v, tuple)
             else np.split(np.asarray(v), MESH)[s] for k, v in tables.items()
             if k in ("hi", "lo", "first")}
-        tj._assert_tables_agree(chip(got), chip(want), mine)
+        tj._assert_tables_agree(chip(got), chip(want), mine, 1)
 
 
 # ---- (b3) a dispatch of DISPATCH_SEGMENTS buckets a shard --------------------------------------
@@ -417,8 +423,9 @@ def test_every_segment_of_every_shard_chooses_its_own_form(monkeypatch):
     is twenty-three: a dispatch is never all of it), and the tail's seven,
     two segments a shard: dense segments (nothing kept), compacted ones and
     scattered ones side by side on one chip; the counts over the chips are
-    the segments' own, a shard's padding is never walked, and the answer is
-    the one chip's."""
+    the segments' own, a shard's padding is never walked, a chip folds once
+    in each dispatch in which one of ITS segments was not dense, and the
+    answer is the one chip's."""
     kept = [40, tj._K + 1, 0, tj._K,   600, 3, tj._K - 1, 0,
             tj._K + 5, tj._K + 9, 1, 2,   0, 0, 90, tj._K + 2,
             7, 0,   tj._K + 1, 30,   0, tj._K + 3,   tj._K]    # the tail: the last shard's second segment is padding
@@ -426,20 +433,35 @@ def test_every_segment_of_every_shard_chooses_its_own_form(monkeypatch):
     dense, compact, _scatter = tj._forms_of(kept_ids)
     assert dense and compact and _scatter
     host = tj._host_answer(lambda: tj._topn_q3(t))
-    seen = []
-    tj._spy_run_wide_tables(monkeypatch, seen)
+    seen, partials = [], []
+    tj._spy_run_wide_tables(monkeypatch, seen, partials)
     four, c4 = _run(lambda: tj._topn_q3(t), MESH)
     assert c4["device_topn_runs"] == 1 and c4["device_join_mesh_batches"] == 2, counters.rejections
+    assert partials == [True, True]
     tj._assert_close(host, four)
     (_b, got), = seen
     per, tail = 4, 2    # morsels a shard: sixteen over four shards of a 8,192-row bucket, then seven over 4,096
     for s in range(MESH):
-        mine = kept_ids[s * per:(s + 1) * per] \
-            + kept_ids[MESH * per + s * tail:MESH * per + (s + 1) * tail]
-        assert (int(got["dense"][s]), int(got["compact"][s])) == tj._forms_of(mine)[:2], s
+        first = kept_ids[s * per:(s + 1) * per]
+        last = kept_ids[MESH * per + s * tail:MESH * per + (s + 1) * tail]
+        assert (int(got["dense"][s]), int(got["compact"][s])) == tj._forms_of(first + last)[:2], s
+        assert int(got["folds"][s]) == tj._holds_sparse(first) + tj._holds_sparse(last), s
     assert int(np.sum(got["dense"])) == dense and int(np.sum(got["compact"])) == compact
+    assert c4["join_topn_folds"] == int(np.sum(got["folds"])) // MESH == 2     # (a count a chip)
     one, c1 = _run(lambda: tj._topn_q3(t), 1)
-    assert one == four and c1["join_topn_compact_batches"] == compact
+    # the one chip's dispatch is eight other segments than a shard's four, so
+    # other rows of an id meet in a float32 partial: keys, dates and order
+    # equal, a sum within a float32 ulp of itself a dispatch (the one chip's
+    # three), as _assert_tables_agree holds the tables
+    assert list(one) == list(four)
+    for name in one:
+        if name == "revenue":
+            for a, b in zip(one[name], four[name], strict=True):
+                assert abs(a - b) <= 3 * float(np.spacing(np.float32(abs(a)))), (a, b)
+        else:
+            assert one[name] == four[name], name
+    assert c1["join_topn_compact_batches"] == compact
+    assert c1["join_topn_folds"] == tj._folds_of(kept_ids, 8) == 3
 
 
 # ---- (b4) the dense form's two digits and its first rows, a shard at a time -------------------
@@ -469,6 +491,7 @@ def test_the_shards_dense_forms_add_up_to_the_float64_reference(case, per_shard,
     assert c4["device_topn_runs"] == 1 and c4["device_join_mesh_batches"] == 2, counters.rejections
     assert c4.get("join_topn_ordered_batches", 0) == ordered // MESH      # (a count a chip)
     assert c4.get("join_topn_compact_batches", 0) == 0
+    assert c4.get("join_topn_folds", 0) == 0        # (no sparse segment: no chip folds)
     tj._assert_close(host, four)
     one, c1 = _run(lambda: tj._topn_q3(t), 1)
     assert one == four and c1.get("join_topn_ordered_batches", 0) == ordered
@@ -477,6 +500,8 @@ def test_the_shards_dense_forms_add_up_to_the_float64_reference(case, per_shard,
     assert got["ordered"].shape == (MESH,)
     assert (int(np.sum(got["dense"])), int(np.sum(got["ordered"])), int(np.sum(got["compact"]))) \
         == (dense, ordered, 0)
+    assert got["folds"].shape == (MESH,) and not np.any(got["folds"])
+    assert not any(np.any(p) for p in got["part"])
     assert (int(alone["dense"]), int(alone["ordered"])) == (dense, ordered)
     length = len(alone["first"])
     rows, sums, first = tj._dense_reference(keys, kept, revenue, length)
