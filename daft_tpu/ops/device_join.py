@@ -1753,8 +1753,10 @@ class _JoinContext:
             return pack, None
         key = (adj.name, id(pack))
         if key not in self._query_packs:    # (once a query, not a dispatch)
-            self._query_packs[key] = (pack, verdict[None, :] if pack is None
-                                      else _stack_verdict(pack, verdict))
+            with profile_span("join.query_pack", "device", dim=adj.name,
+                              rows=0 if pack is None else int(pack.shape[0])):
+                self._query_packs[key] = (pack, verdict[None, :] if pack is None
+                                          else _stack_verdict(pack, verdict))
         return self._query_packs[key][1], 0 if pack is None else pack.shape[0]
 
     def _gathers_lines(self, mat) -> bool:
@@ -1772,7 +1774,9 @@ class _JoinContext:
         the query's verdict is a query's own, so its lines are too."""
         key = (adj.name, "lines", id(mat))
         if key not in self._query_packs:    # (once a query, not a dispatch)
-            self._query_packs[key] = (mat, _pack_lines(mat))
+            with profile_span("join.pack_lines", "device", rows=int(mat.shape[1]),
+                              bytes=int(mat.nbytes)):
+                self._query_packs[key] = (mat, _pack_lines(mat))
         return self._query_packs[key][1]
 
     def _permuted_fact_plane(self, series, bucket: int, perm) -> dev.DCol:
@@ -2594,9 +2598,15 @@ class DeviceJoinTopNRun(DeviceJoinGroupedRun):
             mask = device_row_mask(n, bucket, ctx.mesh)
             lit_args = self.literals.args((self._row_offset,))
             if self._tables is None:
-                self._tables = stage.run_wide_tables(self._cap, ndev)
-                counters.bump("device_topn_table_bytes", sum(
-                    int(x.nbytes) for x in jax.tree_util.tree_leaves(self._tables)))
+                # once a run, on the dispatching thread
+                with profile_span("join.tables", "device", cap=self._cap,
+                                  devices=ndev) as sp:
+                    self._tables = stage.run_wide_tables(self._cap, ndev)
+                    nbytes = sum(int(x.nbytes) for x in
+                                 jax.tree_util.tree_leaves(self._tables))
+                    counters.bump("device_topn_table_bytes", nbytes)
+                    if sp is not None:
+                        sp.args["bytes"] = nbytes
             with profile_span("device.launch", "device", op="join_topn",
                               cap=self._cap, reduce="run_wide", devices=ndev):
                 self._tables = prog(self._tables, dcols, gid, mask, lit_args)

@@ -75,11 +75,13 @@ def test_named_env_variables_are_read(doc):
     assert not unread, f"{doc} names variables nothing under daft_tpu/ reads: {unread}"
 
 
-def test_perf_md_spans_and_counters_exist():
+def perf_md_spans_and_counters():
+    """The names in the first column of PERF.md section 3's table of spans and
+    counters (`a.b/c` gives `a.b` and `a.c`; what stands in brackets is left
+    out)."""
     text = _read("PERF.md")
     table = text[text.index("| Span or counter | Site |"):]
     table = table[:table.index("\n\n")]
-    src = _python_sources()
     names = []
     for row in table.split("\n")[2:]:
         first = re.sub(r"\([^()]*\)", "", row.split("|")[1])
@@ -87,6 +89,12 @@ def test_perf_md_spans_and_counters_exist():
             head, *alts = tick.split("/")
             stem = head[:head.rfind(".") + 1]
             names += [head] + [stem + a for a in alts]
+    return names
+
+
+def test_perf_md_spans_and_counters_exist():
+    names = perf_md_spans_and_counters()
+    src = _python_sources()
     assert len(names) > 40, names
     missing = []
     for n in names:
